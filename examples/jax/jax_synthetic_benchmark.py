@@ -93,7 +93,7 @@ def main():
     loss = jnp.zeros(())
     for _ in range(args.warmup):
         state, loss = step(state, images, labels)
-    float(loss)  # the only sync some remote backends honor
+    float(loss)
 
     if hvd.rank() == 0:
         print(f"Model: {args.model}, batch {args.batch_size}/worker, "

@@ -21,10 +21,6 @@ Typical JAX use::
 
 from __future__ import annotations
 
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.install()  # jax.shard_map spelling on older jax images
-
 from .common import basics as _basics
 from .common.basics import (
     init,

@@ -70,9 +70,7 @@ def _maybe_init_distributed() -> None:
         return
     # NB: do NOT call jax.process_count()/jax.devices() here — that forces
     # backend initialization and jax.distributed.initialize must run first.
-    from jax._src import distributed as _jax_distributed
-
-    if getattr(_jax_distributed.global_state, "client", None) is not None:
+    if jax.distributed.is_initialized():
         return  # coordination service already joined (runtime or prior init)
     # launcher-set world shape: a garbled value must fail loudly here —
     # a silent default would desynchronize the fleet
@@ -102,20 +100,6 @@ def _maybe_init_distributed() -> None:
             "HVD_TPU_HEARTBEAT_TIMEOUT", 30)
         kwargs["shutdown_timeout_seconds"] = env_int(
             "HVD_TPU_SHUTDOWN_TIMEOUT", 8)
-    # older jax (< 0.5) lacks the heartbeat/shutdown timeout knobs on
-    # initialize(); passing them would TypeError and kill every elastic
-    # worker at boot — drop what this jax can't take and say so (the
-    # native-transport heartbeats still provide liveness there)
-    import inspect
-
-    accepted = inspect.signature(jax.distributed.initialize).parameters
-    dropped = [k for k in kwargs if k not in accepted]
-    if dropped:
-        get_logger().info(
-            "jax.distributed.initialize does not accept %s on this jax "
-            "version; continuing without", dropped,
-        )
-        kwargs = {k: v for k, v in kwargs.items() if k in accepted}
     jax.distributed.initialize(
         coordinator_address=coord, num_processes=num, process_id=pid,
         **kwargs,
@@ -157,9 +141,13 @@ def _register_early_distributed_shutdown() -> None:
         except Exception:
             pass
         try:
-            from jax._src import distributed as _jd
+            if jax.distributed.is_initialized():
+                # jax's own exit order (api.clean_up): backends first, then
+                # the coordination service.  The TPU client is handed the
+                # coordination client at creation and must not outlive it.
+                from jax.extend.backend import clear_backends
 
-            if getattr(_jd.global_state, "client", None) is not None:
+                clear_backends()
                 jax.distributed.shutdown()
         except Exception as e:
             get_logger().info("early distributed shutdown raised (%s)", e)

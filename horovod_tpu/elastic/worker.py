@@ -490,9 +490,9 @@ def _teardown_jax() -> None:
         gs.service = None
     gs.process_id = 0
     gs.coordinator_address = None
-    import jax._src.api as _api
+    from jax.extend.backend import clear_backends
 
-    _api.clear_backends()
+    clear_backends()
 
 
 def recovery_pending() -> bool:
@@ -552,10 +552,10 @@ def clean_shutdown() -> None:
     training returns, while all workers are still in controlled code.
 
     With recovery IN FLIGHT, the barrier is skipped entirely: the
-    restarting peers will never arrive, and old jax (< 0.5, no
-    shutdown-timeout knob) would hold this process in the barrier until
-    the restarting service host's execv kills it through the fatal
-    PollForError handler (chaos-soak finding)."""
+    restarting peers will never arrive, and the barrier would hold this
+    process until its shutdown timeout or until the restarting service
+    host's execv kills it through the fatal PollForError handler
+    (chaos-soak finding)."""
     import jax
 
     if recovery_pending():
@@ -565,9 +565,7 @@ def clean_shutdown() -> None:
         _abandon_distributed()
         return
     try:
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
+        if jax.distributed.is_initialized():
             jax.distributed.shutdown()
     except Exception as e:
         get_logger().info("elastic: clean shutdown raised (%s)", e)

@@ -9,17 +9,16 @@ StallInspector and ParameterManager — the C++ components SURVEY.md §7.1
 requires as native, dispatching into XLA executables owned by the Python
 engine.
 
-Until the library is built (or on platforms where it fails to load) a
-Python fallback controller with the same interface keeps the framework
-fully functional — mirroring how the reference degrades from NCCL to MPI to
-Gloo (operation_manager.cc priority list).
+The library is built from source at the first ``hvd.init()`` of a
+checkout (git carries no binary).  A Python controller with the same
+interface stands in only where it is CHOSEN: ``HVD_TPU_DISABLE_NATIVE``,
+or a multi-process world started without the launcher's negotiation
+port.  When the native core is wanted, a build or load that fails raises.
 """
 
 from __future__ import annotations
 
-import ctypes
 import os
-from typing import Optional
 
 from ..common.topology import Topology
 from ..utils.env_parser import Config
@@ -29,7 +28,7 @@ _LIB_NAME = "libhvd_tpu_core.so"
 
 
 class PyFallbackController:
-    """Interface-compatible stand-in while the native core is unavailable.
+    """Interface-compatible stand-in where the native core is not wanted.
 
     Single-controller SPMD needs no negotiation (every collective is a
     deterministic compiled program), so the fallback only tracks lifecycle.
@@ -57,27 +56,39 @@ def _maybe_build() -> None:
     """Lazy build: run make once per process; make itself decides staleness
     from source timestamps, so edited sources always rebuild (reference
     analog: setup.py's build_ext compiling the CMake tree — §2.5; here a
-    plain Makefile, no third-party deps)."""
+    plain Makefile, no third-party deps).
+
+    Processes that start together (launcher ranks, test workers) take
+    turns on a lock over the Makefile, so one builds and the rest find the
+    library fresh.  Without a toolchain an existing library is used as it
+    is; a failed build, or no library and no toolchain, raises."""
     global _build_attempted
     if _build_attempted:
         return
-    _build_attempted = True
+    import fcntl
     import shutil
     import subprocess
 
-    if shutil.which("make") is None or shutil.which("g++") is None:
-        return
     src = os.path.join(os.path.dirname(__file__), "src")
-    try:
-        subprocess.run(
-            ["make"], cwd=src, check=True, capture_output=True, timeout=120
-        )
-    except (subprocess.SubprocessError, OSError) as e:
-        get_logger().warning("native core build failed (%s)", e)
+    if shutil.which("make") and shutil.which("g++"):
+        with open(os.path.join(src, "Makefile")) as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            try:
+                subprocess.run(["make"], cwd=src, check=True,
+                               capture_output=True, text=True, timeout=300)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    f"native core build failed:\n{e.stderr[-4000:]}") from e
+    elif not os.path.exists(_lib_path()):
+        raise RuntimeError(
+            f"native core: {_lib_path()} is not built and make/g++ are not "
+            "on PATH (set HVD_TPU_DISABLE_NATIVE=1 to choose the python "
+            "controller)")
+    _build_attempted = True
 
 
 def load_controller(topology: Topology, config: Config):
-    """Load the native controller, falling back to Python.
+    """Load the native controller, or the Python one where it is chosen.
 
     Reference: horovod/common/basics.py __init__ (extension dlopen) +
     horovod_init (operations.cc).
@@ -97,16 +108,6 @@ def load_controller(topology: Topology, config: Config):
         )
         return PyFallbackController(topology, config)
     _maybe_build()
-    path = _lib_path()
-    if os.path.exists(path):
-        try:
-            from .controller import NativeController  # deferred: needs lib
+    from .controller import NativeController  # deferred: needs lib
 
-            return NativeController(path, topology, config)
-        except (OSError, AttributeError) as e:
-            # AttributeError: a stale prebuilt .so missing newly added C
-            # symbols (ctypes raises it at the restype/argtypes
-            # declarations) — degrade like any other load failure
-            get_logger().warning("native core failed to load (%s); using "
-                                 "python fallback controller", e)
-    return PyFallbackController(topology, config)
+    return NativeController(_lib_path(), topology, config)

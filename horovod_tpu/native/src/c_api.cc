@@ -363,6 +363,10 @@ void hvdtpu_shutdown() {
     std::lock_guard<std::mutex> wk(s->wake_mu);
   }
   s->wake_cv.notify_one();  // wake an idle loop so join() is immediate
+  // ...and a loop blocked on peers inside a cycle: without this, join()
+  // returns only when a peer's process dies (a four-process TPU job hung
+  // at exit that way, every rank waiting for another to die first)
+  if (s->controller) s->controller->Interrupt();
   if (s->background.joinable()) s->background.join();
   if (s->timeline) s->timeline->Close();
   s->loop_dead.store(false);

@@ -122,6 +122,7 @@ bool Controller::RunLoopOnce() {
     if (why.empty()) why = "peer died or disconnected";
     size_t n = FailAllPending(
         "negotiation transport failed: " + why, "");
+    if (interrupted_.load()) return false;  // our own shutdown, not news
     if (n) {
       logger_(2, "negotiation transport failed (" + why +
                  ") with collectives in flight; background loop stopping");

@@ -60,6 +60,14 @@ class Controller {
   int rank() const { return transport_->rank(); }
   int size() const { return transport_->size(); }
 
+  // Called by hvdtpu_shutdown before it joins the loop: unblocks a cycle
+  // waiting on peers (Transport::Interrupt); the failure it causes is this
+  // rank's own leaving, so it is not logged.
+  void Interrupt() {
+    interrupted_.store(true);
+    transport_->Interrupt();
+  }
+
   // Process-set membership (process ranks), mirrored from the Python
   // registry on every process (reference: ProcessSetTable).  Readiness for
   // a set's tensors is counted against its members, not the world.
@@ -112,6 +120,7 @@ class Controller {
 
   std::atomic<int64_t> last_request_bytes_{0};
   std::atomic<bool> last_cycle_progress_{false};
+  std::atomic<bool> interrupted_{false};
   // coordinator-side unrecoverable negotiation failure (e.g. replicated
   // cache divergence); broadcast as a no-names error response
   std::string protocol_error_;

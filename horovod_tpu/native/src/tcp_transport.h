@@ -144,6 +144,14 @@ class TcpTransport : public Transport {
 
   long long heartbeat_misses() const override { return hb_misses_.load(); }
 
+  void Interrupt() override {
+    // connections are published before the controller exists and closed
+    // only by the destructor, so the fds are stable here
+    for (auto& peer : peers_)
+      if (peer.fd >= 0) ::shutdown(peer.fd, SHUT_RDWR);
+    if (root_.fd >= 0) ::shutdown(root_.fd, SHUT_RDWR);
+  }
+
   std::vector<std::string> GatherRequests(const std::string& mine) override {
     if (failed_) return {};
     if (rank_ == 0) {

@@ -43,6 +43,14 @@ class Transport {
 
   // Heartbeat read-deadline expiries observed (TCP transport only).
   virtual long long heartbeat_misses() const { return 0; }
+
+  // Shutdown: make every thread blocked in (or later entering) a frame
+  // read or write on this rank's connections fail as on a closed
+  // connection.  The cycle is lockstep and has no goodbye message, so a
+  // loop that leaves it would otherwise keep its peers — and a loop still
+  // inside it keep itself — blocked until some process DIES; exit must
+  // not depend on the order in which processes manage to die.
+  virtual void Interrupt() {}
 };
 
 // Single-process world: negotiation degenerates to identity.

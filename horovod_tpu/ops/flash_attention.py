@@ -45,14 +45,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # scalar params belong in SMEM on TPU; interpret mode accepts it too
-    from jax.experimental.pallas import tpu as _pltpu
+from jax.experimental.pallas import tpu as _pltpu
 
-    _SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
-    _HAVE_SMEM = True
-except Exception:  # pragma: no cover - CPU-only images without pallas.tpu
-    _SCALAR_SPEC = pl.BlockSpec((1,), lambda *_: (0,))
-    _HAVE_SMEM = False
+# scalar params belong in SMEM on TPU; interpret mode accepts it too
+_SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
 
 _NEG_INF = -1e30
 
@@ -207,14 +203,6 @@ def _off_arr(kv_offset):
     if kv_offset is None:
         return jnp.zeros((1,), jnp.int32)
     return jnp.asarray(kv_offset, jnp.int32).reshape(1)
-
-
-def _off_spec(n):
-    """BlockSpec for an (n,) int32 offset vector — SMEM where available
-    (the module-level _SCALAR_SPEC probe), whole-array block otherwise."""
-    if _HAVE_SMEM:
-        return _SCALAR_SPEC
-    return pl.BlockSpec((n,), lambda *_: (0,))  # pragma: no cover
 
 
 def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
@@ -644,7 +632,7 @@ def flash_chunk_attention(q, k, v, q_starts, *, window=None, kv_start=None,
         kernel,
         grid=(b * h, s_q_pad // block_q),
         in_specs=[
-            _off_spec(b),
+            _SCALAR_SPEC,
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, s_k_pad, d),
                          lambda bh, qi: (bh // group, 0, 0)),

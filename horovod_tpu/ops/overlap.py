@@ -48,6 +48,7 @@ from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 import numpy as np
 
 from ..metrics import instruments as _metrics
@@ -87,12 +88,8 @@ def used_leaf_mask(fn: Callable, params: Any, x: Any) -> List[bool]:
     jaxpr = closed.jaxpr
     used = set()
     for eqn in jaxpr.eqns:
-        used.update(
-            v for v in eqn.invars if not isinstance(v, jax.core.Literal)
-        )
-    used.update(
-        v for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)
-    )
+        used.update(v for v in eqn.invars if not isinstance(v, Literal))
+    used.update(v for v in jaxpr.outvars if not isinstance(v, Literal))
     return [v in used for v in jaxpr.invars[: len(flat)]]
 
 

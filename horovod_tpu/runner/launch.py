@@ -17,6 +17,7 @@ everything on first failure.  Differences, by TPU design:
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import socket
@@ -25,6 +26,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
+from ..utils import compile_cache
 from .config_parser import config_to_env, load_config_file
 
 
@@ -206,7 +208,10 @@ def check_build() -> str:
 
     from ..native import _lib_path, _maybe_build
 
-    _maybe_build()
+    try:
+        _maybe_build()
+    except RuntimeError as e:
+        print(e, file=sys.stderr)
     native = os.path.exists(_lib_path())
     lines = [
         f"horovod_tpu v{horovod_tpu.__version__}",
@@ -249,12 +254,74 @@ def _with_job_secret(knob_env: Dict[str, str]) -> Dict[str, str]:
     return env
 
 
+# TPU chips by their PCI ids (vendor Google; v2/v3, v4, v5p, v5e, v6e, 7x)
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027", "0x005e", "0x0062", "0x0063", "0x006f",
+                    "0x0076"}
+# libtpu's grid of processes over the chips of one host, one chip each
+# (the 2x2 of a four-chip v5e host is the one layout run on hardware)
+_TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips this host gives us: those on the PCI bus (sysfs) that also
+    have a device node — a sandbox may pass through fewer than the bus
+    shows.  The launcher must not ask jax: a parent that has touched the
+    backend holds every chip, and its children then fail or hang."""
+    chips = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor) as f:
+                if f.read().strip() != _TPU_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+                chips += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    nodes = len(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*"))
+    return min(chips, nodes)
+
+
+def _tpu_process_ports(base: Dict[str, str],
+                       num_local: int) -> Optional[List[int]]:
+    """Ports for libtpu's per-process settings when ``num_local`` processes
+    have to share this host's TPU chips, one chip each; None where there is
+    nothing to bind (one process drives all chips, the job is pinned to the
+    CPU, or the host has no TPU).  A process count the chips cannot be
+    dealt to is refused here, before anything hangs on a chip."""
+    if num_local <= 1 or base.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return None
+    chips = _local_tpu_chips()
+    if chips == 0:
+        return None
+    if chips != num_local or chips not in _TPU_PROCESS_BOUNDS:
+        raise SystemExit(
+            f"tpurun: {num_local} processes on a host with {chips} TPU "
+            "chip(s): one process per chip needs as many processes as "
+            f"chips ({sorted(_TPU_PROCESS_BOUNDS)} supported); a single "
+            "process drives all chips of its host by itself")
+    return [_free_port() for _ in range(num_local)]
+
+
 def _worker_env(base: Dict[str, str], knob_env: Dict[str, str],
                 coordinator: str, native_port: int, num_proc: int,
                 rank: int, disable_native: bool,
-                local_rank: int = 0, local_size: int = 1) -> Dict[str, str]:
+                local_rank: int = 0, local_size: int = 1,
+                tpu_ports: Optional[List[int]] = None) -> Dict[str, str]:
     env = dict(base)
     env.update(knob_env)
+    # ranks share one compile cache: JAX_COMPILATION_CACHE_DIR, kept when
+    # set, else the checkout's (utils/compile_cache.py's rule)
+    env.setdefault(compile_cache.ENV, compile_cache.default_dir())
+    if tpu_ports:
+        # one chip for each local rank, by libtpu's own per-process settings
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        env["TPU_PROCESS_BOUNDS"] = _TPU_PROCESS_BOUNDS[local_size]
+        env["TPU_PROCESS_ADDRESSES"] = ",".join(
+            f"localhost:{port}" for port in tpu_ports)
+        env["TPU_PROCESS_PORT"] = str(tpu_ports[local_rank])
+        env["TPU_VISIBLE_CHIPS"] = env["TPU_VISIBLE_DEVICES"] = str(local_rank)
+        env["CLOUD_TPU_TASK_ID"] = str(local_rank)
     env["HVD_TPU_COORDINATOR"] = coordinator
     # second port for the native controller's TCP negotiation star
     # (reference analog: the Gloo rendezvous port horovodrun exports)
@@ -321,13 +388,15 @@ def _launch_local(command: List[str], num_proc: int,
     coordinator = f"127.0.0.1:{_free_port()}"
     native_port = _free_port()
     knob_env = _with_job_secret(knob_env)
+    tpu_ports = _tpu_process_ports(os.environ, num_proc)
     procs: List[subprocess.Popen] = []
     outputs = []
     try:
         for rank in range(num_proc):
             env = _worker_env(os.environ.copy(), knob_env, coordinator,
                               native_port, num_proc, rank, disable_native,
-                              local_rank=rank, local_size=num_proc)
+                              local_rank=rank, local_size=num_proc,
+                              tpu_ports=tpu_ports)
             stdout = stderr = None
             if output_filename:
                 f = open(f"{output_filename}.{rank}", "w")

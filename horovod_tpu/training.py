@@ -590,7 +590,7 @@ def fit_epoch(step: Callable, state: TrainState, loader,
                 keep=checkpoint_keep,
             )
     if loss is not None:
-        loss = float(loss)  # the only sync some remote backends honor
+        loss = float(loss)
     return state, loss
 
 

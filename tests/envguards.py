@@ -1,43 +1,6 @@
-"""Environment capability guards for integration tests.
-
-jax < 0.5 cannot execute MULTI-PROCESS XLA computations on the CPU
-backend ("Multiprocess computations aren't implemented on the CPU
-backend"), and every guarded test pins ``JAX_PLATFORMS=cpu`` in its
-worker environment — so with jax < 0.5 these tests fail on ANY image
-(TPU hosts included), slowly, through elastic restart loops and
-rendezvous timeouts.  Guarding on the jax version alone is therefore
-exact; on jax >= 0.5 the guard is inert.
-``HVD_TPU_TEST_FORCE_MULTIPROC=1`` forces the tests to run anyway
-(e.g. to re-probe a new jax).
-
-Note the boundary: multi-process *control-plane* tests (rendezvous,
-native negotiation/auth frames, heartbeats, exec-restart recovery,
-chaos soak) do NOT need this guard — only cross-process data-plane
-collectives are unsupported.
-"""
+"""Environment selection for the ctypes integration tests."""
 
 import os
-
-import pytest
-
-
-def cpu_multiprocess_collectives_supported() -> bool:
-    if os.environ.get("HVD_TPU_TEST_FORCE_MULTIPROC") == "1":
-        return True
-    import jax
-
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:
-        return True  # unparseable dev version: let the test decide
-    return (major, minor) >= (0, 5)
-
-
-requires_multiprocess_collectives = pytest.mark.skipif(
-    not cpu_multiprocess_collectives_supported(),
-    reason="jax < 0.5 cannot run multi-process XLA collectives on the "
-           "CPU backend (set HVD_TPU_TEST_FORCE_MULTIPROC=1 to force)",
-)
 
 
 # -- native-library selection (sanitizer reruns) ------------------------------

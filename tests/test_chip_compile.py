@@ -1,0 +1,232 @@
+"""Ask the chip's compiler, without the chip (on-chip-measurement guide §2).
+
+The TPU compiler is installed here and compiles for a v5e:2x2 that is
+described, not attached: the kernels of the main path at real widths, and
+the whole train steps chip_smoke.py runs.  Nothing executes, so nothing here
+is a result or a time — a compile that passes is not a chip run.
+
+Only one process may load libtpu, so the topology is described inside a
+module-scoped fixture (never at import, never in conftest.py), everything
+compiles in this test's own process, and all cases live in this one file.
+The persistent compile cache is off around them: an entry written for a
+described chip cannot be read back without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu import models, training
+from horovod_tpu.common.topology import WORLD_AXIS
+from horovod_tpu.models.transformer import Transformer, gpt_small
+from horovod_tpu.ops.flash_attention import (
+    flash_attention, flash_chunk_attention, flash_decode_attention,
+)
+from horovod_tpu.ops.fused_norm import fused_batch_norm_act
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# -- flash attention (training kernels) ---------------------------------------
+
+FLASH_CASES = {
+    "gqa_d128_causal": dict(b=2, s=2048, h=32, kv=8, d=128, causal=True,
+                            window=None),
+    "gqa_d128_window": dict(b=2, s=2048, h=32, kv=8, d=128, causal=True,
+                            window=512),
+    "mha_d64_causal": dict(b=4, s=2048, h=12, kv=12, d=64, causal=True,
+                           window=None),
+    "mha_d64_noncausal": dict(b=4, s=2048, h=12, kv=12, d=64, causal=False,
+                              window=None),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("case", FLASH_CASES)
+def test_flash_attention_compiles(one_chip, case, backward):
+    c = FLASH_CASES[case]
+    q = _sds((c["b"], c["s"], c["h"], c["d"]), jnp.bfloat16, one_chip)
+    kv = _sds((c["b"], c["s"], c["kv"], c["d"]), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=c["causal"],
+                               window=c["window"], interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    assert _has_kernel(_compile(fn, q, kv, kv))
+
+
+# -- serving kernels: decode and chunked prefill ------------------------------
+
+
+def _decode(one_chip, b, s_kv):
+    q = _sds((b, 1, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((b, s_kv, 8, 128), jnp.bfloat16, one_chip)
+    lens = _sds((b,), jnp.int32, one_chip)
+    return _compile(
+        lambda q, k, v, n: flash_decode_attention(q, k, v, n,
+                                                  interpret=False),
+        q, kv, kv, lens)
+
+
+@pytest.mark.parametrize("s_kv", [2048, 8192])
+def test_flash_decode_compiles(one_chip, s_kv):
+    assert _has_kernel(_decode(one_chip, 16, s_kv))
+
+
+def test_flash_decode_32k_keys_exceed_vmem(one_chip):
+    """The ceiling, pinned until someone lifts it: the chunk/decode kernel
+    keeps one row's whole gathered K and V resident (BlockSpec
+    ``(1, s_k_pad, d)``), and at D128 32768 keys ask for 32 MB of a 16 MB
+    scoped-VMEM limit.  About 16k keys is what this chip takes."""
+    with pytest.raises(Exception, match=r"(?i)vmem"):
+        _decode(one_chip, 8, 32768)
+
+
+@pytest.mark.parametrize("chunk,s_kv", [(256, 4096), (512, 8192)])
+def test_flash_chunk_compiles(one_chip, chunk, s_kv):
+    b = 4
+    q = _sds((b, chunk, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((b, s_kv, 8, 128), jnp.bfloat16, one_chip)
+    starts = _sds((b,), jnp.int32, one_chip)
+    compiled = _compile(
+        lambda q, k, v, st: flash_chunk_attention(q, k, v, st,
+                                                  interpret=False),
+        q, kv, kv, starts)
+    assert _has_kernel(compiled)
+
+
+# -- fused norm at the three ResNet-50 shapes ---------------------------------
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize(
+    "shape", [(128, 56, 56, 256), (128, 7, 7, 2048), (128, 112, 112, 64)],
+    ids=["56x56x256", "7x7x2048", "112x112x64"])
+def test_fused_norm_compiles(one_chip, shape, backward):
+    x = _sds(shape, jnp.bfloat16, one_chip)
+    g = _sds(shape[-1:], jnp.float32, one_chip)
+
+    def fwd(x, gamma, beta):
+        return fused_batch_norm_act(x, gamma, beta, impl="pallas")[0]
+
+    def loss(x, gamma, beta):
+        return jnp.sum(fwd(x, gamma, beta).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    assert _has_kernel(_compile(fn, x, g, g))
+
+
+# -- whole train steps, as chip_smoke.py runs them ----------------------------
+
+
+def _step_compiled(model, optimizer, mesh, sample, inputs, labels):
+    """training.data_parallel_train_step over ``mesh`` (described devices),
+    lowered from shapes: replicated state, batch sharded over the axis."""
+    replicated = NamedSharding(mesh, P())
+    batch = NamedSharding(mesh, P(WORLD_AXIS))
+    state = jax.eval_shape(lambda: training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), sample))
+    state = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, replicated), state)
+    step = training.data_parallel_train_step(model, optimizer, mesh=mesh)
+    return step.lower(state, _sds(*inputs, batch),
+                      _sds(*labels, batch)).compile()
+
+
+def _resnet_step(topo, n_devices, batch, dtype, bn_axis_name=None):
+    mesh = Mesh(np.array(topo.devices[:n_devices]), (WORLD_AXIS,))
+    model = models.ResNet50(num_classes=1000, dtype=dtype,
+                            stem="space_to_depth", bn_axis_name=bn_axis_name)
+    return _step_compiled(
+        model, optax.sgd(0.1, momentum=0.9), mesh,
+        jnp.zeros((1, 224, 224, 3), jnp.float32),
+        ((batch, 224, 224, 3), jnp.float32), ((batch,), jnp.int32))
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_resnet50_b128_step_fits_one_chip(topo):
+    compiled = _resnet_step(topo, 1, 128, jnp.bfloat16)
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_resnet50_b512_step_over_four_chips_allreduces(topo):
+    compiled = _resnet_step(topo, 4, 512, jnp.bfloat16)
+    assert "all-reduce" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("n_devices", [4, 1])
+def test_multichip_smoke_step_compiles(topo, n_devices):
+    """chip_smoke.py --multichip: float32 sync-BN ResNet-50, global batch
+    128, over the four chips and on one of them."""
+    compiled = _resnet_step(topo, n_devices, 128, jnp.float32,
+                            bn_axis_name=WORLD_AXIS)
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert ("all-reduce" in compiled.as_text()) == (n_devices > 1)
+
+
+def test_gpt_small_flash_step_fits_one_chip(topo, monkeypatch):
+    """chip_smoke.py phase B(ii): 12 layers, S2048, batch 4, bf16, AdamW.
+    The model lets flash_attention pick interpret mode from the backend's
+    name, and here that is the CPU: steer it to the kernel for the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    cfg = gpt_small(attention_impl="flash", max_seq_len=2048,
+                    dtype=jnp.bfloat16)
+    compiled = _step_compiled(
+        Transformer(cfg), optax.adamw(1e-3), mesh,
+        jnp.zeros((1, 2048), jnp.int32),
+        ((4, 2048), jnp.int32), ((4, 2048), jnp.int32))
+    assert _has_kernel(compiled)
+    assert _device_bytes(compiled) < HBM_BYTES

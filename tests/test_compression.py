@@ -57,7 +57,7 @@ class TestCompression:
         assert Compression.none.decompress(wired, ctx) is tree
 
     def test_fp64_leaves_compress_and_restore(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = {"p": jnp.asarray([1.0, -2.5], jnp.float64)}
             assert x["p"].dtype == jnp.float64
             wired, ctx = Compression.bf16.compress(x)

@@ -13,8 +13,7 @@ Three layers, mirroring the implementation:
 * engine/routing — ``CollectiveEngine.hierarchical_allreduce_multi``,
   the ``HVD_TPU_HIERARCHICAL_ALLREDUCE`` gating, and the per-tier byte
   accounting, with an 8-contributor world simulated through the member
-  bookkeeping (one real process; jax 0.4.37 CPU cannot run multi-process
-  collectives — the SPMD oracle carries the reduction math through the
+  bookkeeping (one real process — the SPMD oracle carries the reduction math through the
   shared ``_two_level_sum_leaf`` core).
 
 The modeled-vs-measured byte contract (``ops.comm_model``) is pinned

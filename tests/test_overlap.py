@@ -380,10 +380,24 @@ def tiny_lm():
     return model, rng, tokens, targets
 
 
+# Overlapped vs unoverlapped updates: gradients and an elementwise-exact
+# inner (sgd) are bit-equal; adamw's moment updates are fma-bearing and
+# XLA:CPU contracts them differently between globally-different programs
+# (first seen under ZeRO, PR 11; the replicated and multi-axis programs
+# show the same 1-ulp moment drift on jax 0.9's XLA, from the second step
+# on, while sgd stays exact through both steps).  The adamw bound is the
+# ZeRO test's.
+_INNER_BOUNDS = [
+    pytest.param(lambda: optax.sgd(0.1), 0.0, id="sgd"),
+    pytest.param(lambda: optax.adamw(1e-2), 4e-7, id="adamw"),
+]
+
+
 class TestTrainStepOracles:
-    def test_replicated_overlap_bit_equal_adamw(self, tiny_lm):
+    @pytest.mark.parametrize("make_opt,bound", _INNER_BOUNDS)
+    def test_replicated_overlap_bit_equal(self, tiny_lm, make_opt, bound):
         model, rng, tokens, targets = tiny_lm
-        opt = optax.adamw(1e-2)
+        opt = make_opt()
         st_a = training.replicate_state(
             training.create_train_state(model, opt, rng, tokens[:1])
         )
@@ -396,7 +410,7 @@ class TestTrainStepOracles:
             st_a, la = step_a(st_a, tokens, targets)
             st_b, lb = step_b(st_b, tokens, targets)
             assert float(la) == float(lb)
-            assert _tree_max_diff(st_a.params, st_b.params) == 0.0
+            assert _tree_max_diff(st_a.params, st_b.params) <= bound
 
     def test_zero_overlap_bit_equal_sgd(self, tiny_lm):
         # the ISSUE-11 oracle: updates bit-equal, ZeRO ON, overlapped vs
@@ -528,7 +542,8 @@ class TestTrainStepOracles:
 
 
 class TestMultiAxisOverlap:
-    def test_sharded_step_bit_equal(self):
+    @pytest.mark.parametrize("make_opt,bound", _INNER_BOUNDS)
+    def test_sharded_step_bit_equal(self, make_opt, bound):
         from horovod_tpu.parallel import sharded as sh
 
         mesh = sh.multi_axis_mesh(dp=2, sp=1, tp=2,
@@ -539,7 +554,7 @@ class TestMultiAxisOverlap:
         )
         rng = jax.random.PRNGKey(0)
         variables, pspecs = sh.init_sharded(model, mesh, rng)
-        opt = optax.adamw(1e-2)
+        opt = make_opt()
         opt_state, ospecs = sh.init_opt_sharded(
             opt, variables, mesh, pspecs
         )
@@ -559,7 +574,7 @@ class TestMultiAxisOverlap:
             pa, oa, la = step_a(pa, oa, tokc, tgtc)
             pb, ob, lb = step_b(pb, ob, tokc, tgtc)
         assert float(la) == float(lb)
-        assert _tree_max_diff(pa, pb) == 0.0
+        assert _tree_max_diff(pa, pb) <= bound
 
     def test_sharded_overlap_interleaves(self):
         from horovod_tpu.parallel import sharded as sh

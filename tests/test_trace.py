@@ -43,8 +43,8 @@ def test_span_event_add_span_record():
     with trace.span("train.step", step=7):
         time.sleep(0.002)
     trace.event("chaos.inject", site="elastic.commit", action="kill")
-    trace.add_span("serve.queued", trace.now() - 0.25, trace.now(),
-                   rid=987654)
+    t1 = trace.now()  # read once: two reads are >1 us apart under load
+    trace.add_span("serve.queued", t1 - 0.25, t1, rid=987654)
     # the retroactive queued span STARTS before t0 — widen the window;
     # other suites' engines may have recorded at these sites too, so
     # select THIS test's records by their args

@@ -287,6 +287,9 @@ def main():
                    help="sweep mode batch list")
     p.add_argument("--sim-step-ms", type=float, default=20.0)
     args = p.parse_args()
+    from horovod_tpu.utils import compile_cache
+
+    compile_cache.enable()
 
     import jax
 
