@@ -17,6 +17,17 @@ Checked equivalences:
   catalogue entry nothing records is dead);
 * the docs/TRACING.md site table is exactly the catalogue (both
   directions).
+
+The names the program gives its DEVICE work are held together the same
+way (``DEVICE_SCOPES`` and ``DEVICE_KERNELS`` beside ``SITES``): every
+``named_scope("...")`` literal names a catalogued scope, every
+``pallas_call(..., name="...")`` literal a catalogued kernel (and no
+kernel of ops/flash_attention.py goes unnamed — an unnamed one reads as
+the enclosing jit's name in a capture, the same for every kernel), every
+catalogued name has a call site, and docs/TRACING.md's "Device names"
+table mirrors both tuples.  A renamed scope would otherwise silently
+empty a phase of ``trace/device.py``'s table, a renamed kernel a
+per-kernel metric of the benchmark.
 """
 
 from __future__ import annotations
@@ -31,8 +42,8 @@ CHECK = "trace"
 
 TRACE_INIT_PY = "horovod_tpu/trace/__init__.py"
 TRACING_MD = "docs/TRACING.md"
+FLASH_PY = "horovod_tpu/ops/flash_attention.py"
 
-_SITES_RE = re.compile(r"^SITES\s*=\s*\(", re.MULTILINE)
 _STR_RE = re.compile(r"\"([a-z0-9_.]+)\"")
 # matches trace.span("x") / _trace.event("x") / trace.add_span("x") —
 # any alias ending in `trace.`; the method set keeps collective_ops'
@@ -41,14 +52,27 @@ _CALL_RE = re.compile(
     r"\w*trace\.(?:span|event|add_span)\(\s*[\"']([a-z0-9_.]+)[\"']")
 _DOC_ROW_RE = re.compile(
     r"^\|\s*`([a-z0-9_]+(?:\.[a-z0-9_]+)+)`\s*\|", re.MULTILINE)
+# device names: (catalogue tuple, kind in the docs table, call-site regex)
+_SCOPE_RE = re.compile(r"named_scope\(\s*[\"']([a-z0-9_]+)[\"']")
+# a pallas_call's name= among its own arguments (one level of nested
+# parentheses: functools.partial(...), grid=(...)); an unnamed call
+# cannot match into the next one, its closing parenthesis stops it
+_KERNEL_RE = re.compile(
+    r"pallas_call\((?:[^()]|\([^()]*\))*?\bname\s*=\s*[\"']([a-z0-9_]+)[\"']")
+_DEVICE_NAMES = (
+    ("DEVICE_SCOPES", "scope", _SCOPE_RE),
+    ("DEVICE_KERNELS", "kernel", _KERNEL_RE),
+)
+_DEVICE_ROW_RE = re.compile(
+    r"^\|\s*`([a-z0-9_]+)`\s*\|\s*(scope|kernel)\s*\|", re.MULTILINE)
 
 
-def catalogue(root: str) -> Dict[str, int]:
-    """site -> line of the SITES tuple in trace/__init__.py."""
+def catalogue(root: str, name: str = "SITES") -> Dict[str, int]:
+    """entry -> its line, for the tuple ``name`` of trace/__init__.py."""
     text = read_text(os.path.join(root, TRACE_INIT_PY))
     if text is None:
         return {}
-    m = _SITES_RE.search(text)
+    m = re.search(rf"^{name}\s*=\s*\(", text, re.MULTILINE)
     if not m:
         return {}
     i = text.index("(", m.start())
@@ -79,12 +103,14 @@ def run(root: str) -> List[Finding]:
 
     # -- call sites ----------------------------------------------------------
     used: Set[str] = set()
+    sources: Dict[str, str] = {}
     for rel in iter_py_files(root,
                              exclude_dirs=("analysis", "trace",
                                            "__pycache__")):
         text = read_text(os.path.join(root, rel))
         if text is None:
             continue
+        sources[rel] = text
         for m in _CALL_RE.finditer(text):
             site = m.group(1)
             used.add(site)
@@ -128,5 +154,67 @@ def run(root: str) -> List[Finding]:
                 CHECK, TRACING_MD, lineno, site,
                 f"docs/TRACING.md documents trace site {site!r} but the "
                 "SITES catalogue does not contain it",
+            ))
+    findings.extend(_device_names(root, sources, doc_text))
+    return findings
+
+
+def _device_names(root: str, sources: Dict[str, str],
+                  doc_text: str) -> List[Finding]:
+    """DEVICE_SCOPES / DEVICE_KERNELS against their call sites in
+    ``sources`` (path -> text of the package's files) and the docs'
+    "Device names" table.  A tree whose catalogue has neither tuple
+    (before PR 24) has nothing to hold together."""
+    findings: List[Finding] = []
+    doc_rows: Dict[Tuple[str, str], int] = {}
+    for m in _DEVICE_ROW_RE.finditer(doc_text):
+        doc_rows[(m.group(1), m.group(2))] = (
+            doc_text.count("\n", 0, m.start()) + 1)
+
+    for tuple_name, kind, call_re in _DEVICE_NAMES:
+        names = catalogue(root, tuple_name)
+        used: Set[str] = set()
+        for rel, text in sources.items():
+            for m in call_re.finditer(text):
+                used.add(m.group(1))
+                if m.group(1) not in names:
+                    findings.append(Finding(
+                        CHECK, rel, text.count("\n", 0, m.start()) + 1,
+                        m.group(1),
+                        f"device {kind} {m.group(1)!r} is named here but "
+                        f"not in the trace {tuple_name} catalogue — a "
+                        "capture carries a name no table explains",
+                    ))
+        for name, lineno in sorted(names.items()):
+            if name not in used:
+                findings.append(Finding(
+                    CHECK, TRACE_INIT_PY, lineno, name,
+                    f"catalogued device {kind} {name!r} has no call site "
+                    "in the package (dead catalogue entry)",
+                ))
+            if (name, kind) not in doc_rows:
+                findings.append(Finding(
+                    CHECK, TRACE_INIT_PY, lineno, name,
+                    f"device {kind} {name!r} is catalogued but missing "
+                    "from the docs/TRACING.md \"Device names\" table",
+                ))
+        for (name, row_kind), lineno in sorted(doc_rows.items()):
+            if row_kind == kind and name not in names:
+                findings.append(Finding(
+                    CHECK, TRACING_MD, lineno, name,
+                    f"docs/TRACING.md documents device {kind} {name!r} "
+                    f"but {tuple_name} does not contain it",
+                ))
+
+    flash = sources.get(FLASH_PY)
+    if flash is not None and catalogue(root, "DEVICE_KERNELS"):
+        unnamed = (len(re.findall(r"pallas_call\(", flash))
+                   - len(_KERNEL_RE.findall(flash)))
+        if unnamed:
+            findings.append(Finding(
+                CHECK, FLASH_PY, 0, "pallas_call",
+                f"{unnamed} pallas_call(s) of ops/flash_attention.py "
+                "carry no name= — in a capture they read as the "
+                "enclosing jit's name, one name for every kernel",
             ))
     return findings

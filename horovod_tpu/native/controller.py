@@ -17,6 +17,7 @@ Flow (the §3.2 hot path, TPU edition):
 from __future__ import annotations
 
 import ctypes
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import chaos as _chaos
+from .. import trace as _trace
 from ..common.exceptions import HorovodInternalError
 from ..common.topology import Topology
 from ..metrics import instruments as _metrics
@@ -33,7 +35,6 @@ from ..metrics.exposition import (
     register_health_source, unregister_health_source,
 )
 from ..metrics.registry import REGISTRY as _METRICS_REGISTRY
-from ..utils import profiler
 from ..utils.env_parser import Config
 from ..utils.logging import get_logger
 
@@ -56,6 +57,17 @@ _DTYPES = [
 ]
 _DTYPE_TO_ENUM = {name: val for name, val in _DTYPES}
 _ENUM_TO_DTYPE = {val: name for name, val in _DTYPES}
+
+# HVD_TPU_PROFILER_BRIDGE=0 keeps the negotiated-collective spans out of
+# jax.profiler captures (the trace ring still records them)
+_BRIDGE = os.environ.get("HVD_TPU_PROFILER_BRIDGE", "1") != "0"
+
+
+def _xname(name: str, activity: str):
+    """The name a negotiated-collective span carries into an XPlane
+    capture — the timeline's, ``hvd_tpu::<name>::<activity>``."""
+    return f"hvd_tpu::{name}::{activity}" if _BRIDGE else False
+
 
 _EXEC_CB = ctypes.CFUNCTYPE(
     None, ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
@@ -491,9 +503,10 @@ class NativeController:
                 self._entries[self._name_counter] = _Entry(
                     None, fut, op_type, name=name)
             return fut
-        # the ENQUEUE span also lands in any active jax.profiler capture
-        # (utils/profiler.py bridge), same activity name as the timeline
-        with profiler.span(name, "ENQUEUE"):
+        # the ENQUEUE span also lands in any active jax.profiler capture,
+        # same activity name as the timeline
+        with _trace.span("collective.enqueue",
+                         _xname=_xname(name, "ENQUEUE"), name=name):
             arr = jnp.asarray(array)
             dtype_enum = _DTYPE_TO_ENUM.get(str(arr.dtype))
             if dtype_enum is None:
@@ -592,8 +605,10 @@ class NativeController:
                     dropped.append(fut)
             return dropped
         futs = []
-        with profiler.span(names[0] if len(names) == 1
-                           else f"{names[0]}+{len(names) - 1}", "ENQUEUE"):
+        label = (names[0] if len(names) == 1
+                 else f"{names[0]}+{len(names) - 1}")
+        with _trace.span("collective.enqueue",
+                         _xname=_xname(label, "ENQUEUE"), name=label):
             for arr in arrs:
                 enum = _DTYPE_TO_ENUM.get(str(arr.dtype))
                 if enum is None:
@@ -715,11 +730,12 @@ class NativeController:
             # XLA_COMM span on the exec thread for jax.profiler captures —
             # covers dispatch of the fused program (through data-ready when
             # the timeline is active, which blocks in resolve()); matches
-            # the timeline's span of the same name (utils/profiler.py)
+            # the timeline's span of the same name
             label = entries[0].name or f"op{op}"
             if len(entries) > 1:
                 label += f"+{len(entries) - 1}"
-            with profiler.span(label, "XLA_COMM"):
+            with _trace.span("collective.exec",
+                             _xname=_xname(label, "XLA_COMM"), name=label):
                 self._execute(op, process_set, root_or_rop, prescale,
                               postscale, entries, extents)
         except BaseException as exc:  # never let exceptions cross into C++

@@ -232,6 +232,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b * h, s_q // block_q),
         in_specs=[
             _SCALAR_SPEC,
@@ -419,6 +420,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
               block_k=block_k, seq_len=orig_s, window=window)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
+        name="flash_attention_bwd_dq",
         grid=(bh, s_q // block_q),
         in_specs=[
             _SCALAR_SPEC,
@@ -442,6 +444,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     delta_g = delta_f.reshape(bh_kv, group * s_q, 1)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, group=group, **kw),
+        name="flash_attention_bwd_dkv",
         grid=(bh_kv, s_k // block_k),
         in_specs=[
             _SCALAR_SPEC,
@@ -630,6 +633,7 @@ def flash_chunk_attention(q, k, v, q_starts, *, window=None, kv_start=None,
     )
     out, _ = pl.pallas_call(
         kernel,
+        name="flash_attention_chunk",
         grid=(b * h, s_q_pad // block_q),
         in_specs=[
             _SCALAR_SPEC,

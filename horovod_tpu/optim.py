@@ -756,33 +756,40 @@ def ZeroSpmdOptimizer(
                 else [None] * len(g_bufs)
             )
             g_shards, new_residual = [], []
-            for buf, res in zip(g_bufs, residuals):
-                shard, nr = spmd_ops._two_level_reduce_scatter_flat(
-                    buf, ici_axis, dcn_axis, dcn_compression, res
-                )
-                if op == ReduceOp.AVERAGE:
-                    shard = shard / jnp.asarray(world, shard.dtype)
-                g_shards.append(shard)
-                new_residual.append(nr)
+            # "exchange" (trace.DEVICE_SCOPES): the training step scopes
+            # this whole update "optimizer"; the innermost scope names
+            # the phase, so the split all-reduce reads as the exchange
+            with jax.named_scope("exchange"):
+                for buf, res in zip(g_bufs, residuals):
+                    shard, nr = spmd_ops._two_level_reduce_scatter_flat(
+                        buf, ici_axis, dcn_axis, dcn_compression, res
+                    )
+                    if op == ReduceOp.AVERAGE:
+                        shard = shard / jnp.asarray(world, shard.dtype)
+                    g_shards.append(shard)
+                    new_residual.append(nr)
             if not feedback:
                 new_residual = None
         else:
-            g_shards = [spmd_ops.reducescatter(buf, op=op, axis=axis)
-                        for buf in g_bufs]
+            with jax.named_scope("exchange"):
+                g_shards = [spmd_ops.reducescatter(buf, op=op, axis=axis)
+                            for buf in g_bufs]
         p_bufs = plan.flatten(jax.tree_util.tree_leaves(params))
         p_shards = _slice_shards(plan, p_bufs, me)
         u_shards, new_inner = optimizer.update(
             g_shards, state.inner, p_shards
         )
-        if hierarchical:
-            u_bufs = [
-                spmd_ops._two_level_all_gather_flat(
-                    u, ici_axis, dcn_axis, dcn_compression
-                )
-                for u in u_shards
-            ]
-        else:
-            u_bufs = [spmd_ops.allgather(u, axis=axis) for u in u_shards]
+        with jax.named_scope("exchange"):
+            if hierarchical:
+                u_bufs = [
+                    spmd_ops._two_level_all_gather_flat(
+                        u, ici_axis, dcn_axis, dcn_compression
+                    )
+                    for u in u_shards
+                ]
+            else:
+                u_bufs = [spmd_ops.allgather(u, axis=axis)
+                          for u in u_shards]
         updates = jax.tree_util.tree_unflatten(
             treedef, plan.unflatten(u_bufs)
         )

@@ -23,9 +23,15 @@ recorder).  Three properties are load-bearing:
 Sites are catalogued in :data:`SITES` (the analysis ``trace`` pass
 holds code ≡ catalogue ≡ docs/TRACING.md in both directions).  Spans
 bridge into any active ``jax.profiler`` XPlane capture through the same
-instrumentation point (``TraceAnnotation``; utils/profiler.py is now a
-thin alias), so the Chrome-trace export and the profiler see ONE set of
-span names.
+instrumentation point (``TraceAnnotation``), so the Chrome-trace export
+and the profiler see ONE set of span names.
+
+Inside the compiled step no host span can record, so the program names
+its device work instead: :data:`DEVICE_SCOPES` (``jax.named_scope``
+phases of every step builder in ``training.py``) and
+:data:`DEVICE_KERNELS` (one ``name=`` a Pallas kernel).  Both are
+metadata: the operations are the same with tracing on or off.
+:mod:`.device` reduces a profiler capture by them.
 
 Export: :mod:`.export` renders per-rank Chrome trace-event JSON
 (perfetto-loadable; ``GET /trace`` on the PR-1 exposition endpoint,
@@ -45,8 +51,9 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
-    "SITES", "add_span", "configure", "enabled", "event",
-    "install_from_env", "new_trace_id", "now", "snapshot", "span",
+    "DEVICE_KERNELS", "DEVICE_SCOPES", "SITES", "add_span", "configure",
+    "enabled", "event", "install_from_env", "new_trace_id", "now",
+    "snapshot", "span",
 ]
 
 #: Span/event site catalogue — every ``trace.span("...")`` /
@@ -55,6 +62,8 @@ __all__ = [
 #: site, and docs/TRACING.md's table mirrors this tuple exactly (the
 #: analysis ``trace`` pass checks all directions).
 SITES = (
+    "train.create_state",  # create_train_state: model.init + optimizer.init
+    "train.replicate",     # replicate_state: the state placed over the mesh
     "train.step",          # fit_epoch loop body: dispatch + host work
     "data.wait",           # consumer wait on the prefetch queue
     "data.produce",        # host batch production (producer thread)
@@ -81,6 +90,29 @@ SITES = (
     "guard.exchange",      # cross-rank digest/vote exchange (cadence)
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
+)
+
+#: Device phase scopes — every ``jax.named_scope("...")`` literal in the
+#: package names an entry here (the step builders of training.py, and
+#: ZeroSpmdOptimizer's exchange), every entry has a call site, and
+#: docs/TRACING.md's "Device names" table mirrors both tuples.  They
+#: show in an operation's ``op_name`` (``jit(_step)/.../jvp(forward)/...``);
+#: the backward has no scope of its own: it is ``transpose(jvp(forward))``.
+DEVICE_SCOPES = (
+    "forward",    # the loss computation value_and_grad differentiates
+    "exchange",   # gradient / loss / batch-stats collectives
+    "optimizer",  # optimizer.update + apply_updates
+)
+
+#: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
+#: ops/flash_attention.py, one name a kernel; the HLO instruction (and
+#: the profiler's event) is ``%<name>.<n>``.  All start with
+#: ``flash_attention`` so one pattern still reads them together.
+DEVICE_KERNELS = (
+    "flash_attention_fwd",      # _forward_impl
+    "flash_attention_bwd_dq",   # _backward_folded: dQ
+    "flash_attention_bwd_dkv",  # _backward_folded: dK/dV per kv head
+    "flash_attention_chunk",    # flash_chunk_attention (prefill, decode)
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"
@@ -202,19 +234,24 @@ def _ring() -> _Ring:
 
 
 class _Span:
-    __slots__ = ("site", "xname", "args", "t0", "ann")
+    __slots__ = ("site", "xname", "xargs", "args", "t0", "ann")
 
-    def __init__(self, site: str, xname: Optional[str], args):
+    def __init__(self, site: str, xname: Optional[str], xargs, args):
         self.site = site
         self.xname = xname
+        self.xargs = xargs
         self.args = args
         self.ann = None
+
+    def set(self, **args) -> None:
+        """Add args known only once the spanned work is done."""
+        self.args = {**(self.args or {}), **args}
 
     def __enter__(self):
         if self.xname is not None:
             cls = _annotation_cls()
             if cls is not None:
-                self.ann = cls(self.xname)
+                self.ann = cls(self.xname, **(self.xargs or {}))
                 self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
@@ -231,7 +268,8 @@ class _Span:
 _NULL = contextlib.nullcontext()
 
 
-def span(site: str, /, _xname: Optional[str] = None, **args):
+def span(site: str, /, _xname: Optional[str] = None,
+         _xargs: Optional[Dict[str, Any]] = None, **args):
     """Context manager recording one host-side span at ``site``.
 
     ``args`` ride into the Chrome export's ``args`` field (keep them
@@ -239,7 +277,10 @@ def span(site: str, /, _xname: Optional[str] = None, **args):
     anchoring conventions).  ``_xname`` overrides the name the span
     carries into an active jax.profiler capture (default
     ``hvd_tpu::<site>``); ``_xname=False`` suppresses the bridge for
-    this span.  One module-bool check when tracing is off."""
+    this span.  ``_xargs`` are the annotation's own keyword arguments
+    (``{"_r": 1, "step_num": n}`` makes it a step annotation); the ring
+    record does not carry them.  One module-bool check when tracing is
+    off."""
     if not _enabled:
         # HVD_TPU_TRACE=0 drops the ring record, but a caller that
         # asked for a specific XPlane name (the profiler bridge) still
@@ -247,11 +288,11 @@ def span(site: str, /, _xname: Optional[str] = None, **args):
         if _xname:
             cls = _annotation_cls()
             if cls is not None:
-                return cls(_xname)
+                return cls(_xname, **(_xargs or {}))
         return _NULL
     xname = (None if _xname is False
              else (_xname or f"hvd_tpu::{site}"))
-    return _Span(site, xname, args or None)
+    return _Span(site, xname, _xargs, args or None)
 
 
 def event(site: str, /, **args) -> None:

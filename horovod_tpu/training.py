@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import trace
 from .common import basics
 from .common.retry import env_int
 from .common.topology import WORLD_AXIS
@@ -49,9 +50,51 @@ def softmax_cross_entropy(logits, labels):
     ).mean()
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# backend compiles of this process, [count, seconds]: fed by ONE
+# jax.monitoring listener, registered the first time a traced
+# create_train_state asks (a persistent-cache hit reports its load time
+# under the same event)
+_compiles = [0, 0.0]
+_compile_listener_on = False
+
+
+def _compile_totals():
+    global _compile_listener_on
+    if not _compile_listener_on:
+        _compile_listener_on = True
+
+        def on_duration(name, seconds, **_):
+            if name == _COMPILE_EVENT:
+                _compiles[0] += 1
+                _compiles[1] += seconds
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return _compiles[0], _compiles[1]
+
+
 def create_train_state(
     model, optimizer: optax.GradientTransformation, rng, sample_input
 ) -> TrainState:
+    """Initialize the model and the optimizer state.  Recorded at the
+    ``train.create_state`` site with the parameter count and the backend
+    compiles the call paid (``model.init`` runs op by op: this is where
+    a job's start-up time goes)."""
+    if not trace.enabled():
+        return _create_train_state(model, optimizer, rng, sample_input)
+    c0, s0 = _compile_totals()
+    with trace.span("train.create_state") as sp:
+        state = _create_train_state(model, optimizer, rng, sample_input)
+        c1, s1 = _compile_totals()
+        sp.set(
+            params=sum(int(x.size) for x in
+                       jax.tree_util.tree_leaves(state.params)),
+            compiles=c1 - c0, compile_s=s1 - s0,
+        )
+    return state
+
+
+def _create_train_state(model, optimizer, rng, sample_input) -> TrainState:
     variables = model.init(rng, sample_input)
     params = variables["params"]
     batch_stats = variables.get("batch_stats")
@@ -61,6 +104,26 @@ def create_train_state(
         opt_state=optimizer.init(params),
         batch_stats=batch_stats,
     )
+
+
+def _forward_scoped(segments):
+    """The overlap chain with every segment under the ``forward`` device
+    scope, so the staged backward reads ``transpose(jvp(forward))`` like
+    the plain step's."""
+    from .ops.overlap import Segment
+
+    def scoped(fn):
+        def seg(params, x):
+            with jax.named_scope("forward"):
+                return fn(params, x)
+
+        return seg
+
+    return [
+        s._replace(fn=scoped(s.fn)) if isinstance(s, Segment)
+        else Segment(scoped(s))
+        for s in segments
+    ]
 
 
 def _resolve_segmenter(model, segmenter):
@@ -87,6 +150,7 @@ def _overlap_bucket_reduce(axis, op, world):
     (psum, then divide for Average), so overlapped and unoverlapped
     steps stay bit-equal."""
 
+    @jax.named_scope("exchange")
     def bucket_reduce(buf):
         return spmd_ops.allreduce(buf, op=op, axis=axis)
 
@@ -157,17 +221,19 @@ def data_parallel_train_step(
                     "overlap=True does not support batch_stats models"
                 )
             loss, grads, _ = overlapped_value_and_grad(
-                segmenter(model, images, labels, loss_fn),
+                _forward_scoped(segmenter(model, images, labels, loss_fn)),
                 state.params, images,
                 bucket_reduce=_overlap_bucket_reduce(axis, op, world),
                 bucket_bytes=bucket_bytes,
             )
             new_stats = None
-            loss = spmd_ops.allreduce(loss, axis=axis)
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("exchange"):
+                loss = spmd_ops.allreduce(loss, axis=axis)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
             new_state = TrainState(
                 step=state.step + 1,
                 params=new_params,
@@ -180,33 +246,41 @@ def data_parallel_train_step(
                 return new_state, loss, step_diag(loss, grads)
             return new_state, loss
 
+        # forward / exchange / optimizer: device scopes
+        # (trace.DEVICE_SCOPES).  Metadata only: the program is the same
+        # operations with or without them; value_and_grad's transpose of
+        # the forward scope is the backward
         def compute_loss(params):
-            variables = {"params": params}
-            if state.batch_stats is not None:
-                variables["batch_stats"] = state.batch_stats
-                out, updates = model.apply(
-                    variables, images, mutable=["batch_stats"]
-                )
-                logits = out
-                new_stats = updates["batch_stats"]
-            else:
-                logits = model.apply(variables, images)
-                new_stats = None
-            return loss_fn(logits, labels), new_stats
+            with jax.named_scope("forward"):
+                variables = {"params": params}
+                if state.batch_stats is not None:
+                    variables["batch_stats"] = state.batch_stats
+                    out, updates = model.apply(
+                        variables, images, mutable=["batch_stats"]
+                    )
+                    logits = out
+                    new_stats = updates["batch_stats"]
+                else:
+                    logits = model.apply(variables, images)
+                    new_stats = None
+                return loss_fn(logits, labels), new_stats
 
         (loss, new_stats), grads = jax.value_and_grad(
             compute_loss, has_aux=True
         )(state.params)
-        grads = spmd_ops.allreduce(grads, op=op, axis=axis)
-        loss = spmd_ops.allreduce(loss, axis=axis)
-        if new_stats is not None:
-            # replicas see different batches -> average the running stats
-            # (sync-BN semantics; reference: torch/sync_batch_norm.py)
-            new_stats = spmd_ops.allreduce(new_stats, axis=axis)
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("exchange"):
+            grads = spmd_ops.allreduce(grads, op=op, axis=axis)
+            loss = spmd_ops.allreduce(loss, axis=axis)
+            if new_stats is not None:
+                # replicas see different batches -> average the running
+                # stats (sync-BN semantics; reference:
+                # torch/sync_batch_norm.py)
+                new_stats = spmd_ops.allreduce(new_stats, axis=axis)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -363,6 +437,7 @@ def zero_train_setup(
             )
         return spmd_ops.allreduce(x, axis=axis)
 
+    @jax.named_scope("exchange")
     def _overlap_zero_reduce(buf):
         """Full (pre-ZeRO) reduction of one bucket, run as the SAME
         reduce-scatter (+ allgather) primitives the wrapper's own
@@ -427,17 +502,19 @@ def zero_train_setup(
                     "overlap=True does not support batch_stats models"
                 )
             loss, grads, _ = overlapped_value_and_grad(
-                segmenter(model, images, labels, loss_fn),
+                _forward_scoped(segmenter(model, images, labels, loss_fn)),
                 state.params, images,
                 bucket_reduce=_overlap_zero_reduce,
                 bucket_bytes=bucket_bytes,
             )
             new_stats = None
-            loss = _mean(loss)
-            updates, new_opt_state = zopt.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("exchange"):
+                loss = _mean(loss)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = zopt.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
             new_state = TrainState(
                 step=state.step + 1,
                 params=new_params,
@@ -449,28 +526,33 @@ def zero_train_setup(
             return new_state, loss
 
         def compute_loss(params):
-            variables = {"params": params}
-            if state.batch_stats is not None:
-                variables["batch_stats"] = state.batch_stats
-                out, updates = model.apply(
-                    variables, images, mutable=["batch_stats"]
-                )
-                return loss_fn(out, labels), updates["batch_stats"]
-            return loss_fn(model.apply(variables, images), labels), None
+            with jax.named_scope("forward"):
+                variables = {"params": params}
+                if state.batch_stats is not None:
+                    variables["batch_stats"] = state.batch_stats
+                    out, updates = model.apply(
+                        variables, images, mutable=["batch_stats"]
+                    )
+                    return loss_fn(out, labels), updates["batch_stats"]
+                return (loss_fn(model.apply(variables, images), labels),
+                        None)
 
         (loss, new_stats), grads = jax.value_and_grad(
             compute_loss, has_aux=True
         )(state.params)
 
         # no separate gradient allreduce: the ZeRO update IS the
-        # reduction (reduce-scatter + allgather = the split allreduce)
-        loss = _mean(loss)
-        if new_stats is not None:
-            new_stats = _mean(new_stats)
-        updates, new_opt_state = zopt.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        # reduction (reduce-scatter + allgather = the split allreduce;
+        # ZeroSpmdOptimizer scopes that pair "exchange" itself)
+        with jax.named_scope("exchange"):
+            loss = _mean(loss)
+            if new_stats is not None:
+                new_stats = _mean(new_stats)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = zopt.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -537,7 +619,6 @@ def fit_epoch(step: Callable, state: TrainState, loader,
     """
     from . import chaos as _chaos
     from . import checkpoint as _checkpoint
-    from . import trace
     from .utils.logging import set_log_context
 
     if epoch is not None and hasattr(loader, "set_epoch"):
@@ -563,7 +644,11 @@ def fit_epoch(step: Callable, state: TrainState, loader,
         if tracing:
             step_no = trace_base + batches + 1
             set_log_context(step=step_no)
-            with trace.span("train.step", step=step_no,
+            # bridged as a STEP annotation: a capture groups device
+            # work by the program's own step number
+            with trace.span("train.step",
+                            _xargs={"_r": 1, "step_num": step_no},
+                            step=step_no,
                             epoch=-1 if epoch is None else epoch):
                 out = step(state, inputs, labels)
         else:
@@ -601,4 +686,7 @@ def replicate_state(state: TrainState, mesh: Optional[Mesh] = None) -> TrainStat
     if mesh is None:
         mesh = basics._require_init().process_set_registry.get(0).mesh
     sharding = NamedSharding(mesh, P())
-    return jax.device_put(state, sharding)
+    with trace.span("train.replicate", bytes=sum(
+            getattr(x, "nbytes", 0)
+            for x in jax.tree_util.tree_leaves(state))):
+        return jax.device_put(state, sharding)

@@ -24,7 +24,9 @@ from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu import models, training
 from horovod_tpu.common.topology import WORLD_AXIS
-from horovod_tpu.models.transformer import Transformer, gpt_small
+from horovod_tpu.models.transformer import (
+    Transformer, TransformerConfig, gpt_small,
+)
 from horovod_tpu.ops.flash_attention import (
     flash_attention, flash_chunk_attention, flash_decode_attention,
 )
@@ -230,3 +232,40 @@ def test_gpt_small_flash_step_fits_one_chip(topo, monkeypatch):
         ((4, 2048), jnp.int32), ((4, 2048), jnp.int32))
     assert _has_kernel(compiled)
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_internlm2_block_step_names_its_device_work(topo, monkeypatch):
+    """One block at InternLM2-1.8B's widths (2048 = 16 x 128 heads, 8 kv
+    heads, SwiGLU 8192) through the train step, 1 x 4096 tokens: each
+    flash kernel is an instruction of its own name (before PR 24 all
+    three read ``flash_attention.<n>``, the enclosing jit's), and every
+    phase scope of training.py reaches the instructions' ``op_name`` —
+    what ``trace/device.py`` and the benchmark's per-kernel metrics read
+    off a capture (docs/TRACING.md, "Device names")."""
+    import re
+
+    from horovod_tpu import trace
+    from horovod_tpu.trace import device
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=1, num_heads=16, num_kv_heads=8,
+        head_dim=128, mlp_ratio=4, max_seq_len=4096, dtype=jnp.bfloat16,
+        attention_impl="flash")
+    text = _step_compiled(
+        Transformer(cfg), optax.adamw(1e-3), mesh,
+        jnp.zeros((1, 4096), jnp.int32),
+        ((1, 4096), jnp.int32), ((1, 4096), jnp.int32)).as_text()
+    kernels = re.findall(r"%(flash_attention\w*)\.\d+ = [^\n]*tpu_custom_call",
+                         text)
+    assert sorted(kernels) == sorted(trace.DEVICE_KERNELS[:3])
+    op_names = set(re.findall(r'op_name="([^"]+)"', text))
+    for component in ("jvp(forward)", "transpose(jvp(forward))", "optimizer"):
+        assert any(component in o.split("/") for o in op_names), component
+    table = device.phase_table(text)
+    phase_of = {k: table[next(n for n in table if n.startswith(k + "."))][0]
+                for k in kernels}
+    assert phase_of == {"flash_attention_fwd": "forward",
+                        "flash_attention_bwd_dq": "backward",
+                        "flash_attention_bwd_dkv": "backward"}

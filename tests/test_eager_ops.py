@@ -156,8 +156,8 @@ def test_scalar_allreduce_preserves_zero_d_shape():
 
 
 def test_profiler_bridge_spans_in_xplane_capture(tmp_path):
-    """The jax.profiler bridge (utils/profiler.py) puts ENQUEUE/XLA_COMM
-    spans into an XPlane capture with the same names the Chrome timeline
+    """The jax.profiler bridge (the controller's trace.span) puts
+    ENQUEUE/XLA_COMM spans into an XPlane capture with the same names the Chrome timeline
     uses — SURVEY.md §5.1's 'framework spans next to XLA ops' view."""
     import glob
     import gzip

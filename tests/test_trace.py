@@ -1,6 +1,7 @@
 """Tests for horovod_tpu.trace — the span recorder, Chrome export,
 /trace control endpoint, cross-rank merge, flight recorder, and the
-analysis ``trace`` pass (ISSUE 15).
+analysis ``trace`` pass (ISSUE 15); the device names, their reducer
+(``trace/device.py``) and the entry path's two sites (ISSUE 24).
 
 The endpoint tests bind an ephemeral port explicitly (tier-1 never
 binds a port outside these tests — the exposition opt-in discipline
@@ -22,8 +23,8 @@ from horovod_tpu import trace
 from horovod_tpu.metrics import exposition
 from horovod_tpu.metrics.registry import MetricsRegistry
 from horovod_tpu.trace import export as trace_export
+from horovod_tpu.trace import device as trace_device
 from horovod_tpu.trace import flight
-from horovod_tpu.utils import profiler
 
 
 @pytest.fixture(autouse=True)
@@ -108,14 +109,59 @@ def test_main_ring_survives_worker_thread_churn():
 
 
 def test_profiler_span_unifies_into_recorder():
+    """The controller's spans: one ``trace.span`` puts the ring record
+    at the catalogued site and carries the timeline's name into an
+    XPlane capture (there is no second emitter)."""
+    from horovod_tpu.native import controller
+
     t0 = trace.now()
-    with profiler.span("grad_3", "ENQUEUE"):
-        pass
-    with profiler.span("ALLREDUCE", "XLA_COMM"):
+    with trace.span("collective.enqueue",
+                    _xname=controller._xname("grad_3", "ENQUEUE"),
+                    name="grad_3") as sp:
+        assert sp.xname == "hvd_tpu::grad_3::ENQUEUE"
+    with trace.span("collective.exec",
+                    _xname=controller._xname("ALLREDUCE", "XLA_COMM"),
+                    name="ALLREDUCE"):
         pass
     sites = {r[0]: r[3] for r in trace.snapshot(since=t0)}
     assert sites.get("collective.enqueue") == {"name": "grad_3"}
     assert sites.get("collective.exec") == {"name": "ALLREDUCE"}
+
+
+def test_span_set_adds_args_known_at_the_end():
+    t0 = trace.now()
+    with trace.span("train.create_state", params=3) as sp:
+        sp.set(compiles=2)
+    (rec,) = [r for r in trace.snapshot(since=t0)
+              if r[0] == "train.create_state"]
+    assert rec[3] == {"params": 3, "compiles": 2}
+
+
+def test_step_annotation_args_reach_the_bridge_not_the_ring(monkeypatch):
+    made = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kwargs):
+            made.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_annotation_cls", lambda: FakeAnnotation)
+    t0 = trace.now()
+    with trace.span("train.step", _xargs={"_r": 1, "step_num": 41},
+                    step=41, epoch=0):
+        pass
+    assert made == [("hvd_tpu::train.step", {"_r": 1, "step_num": 41})]
+    (rec,) = [r for r in trace.snapshot(since=t0) if r[0] == "train.step"]
+    assert rec[3] == {"step": 41, "epoch": 0}
+    # HVD_TPU_TRACE=0 with an explicit name keeps the annotation
+    trace.configure(enabled=False)
+    trace.span("train.step", _xname="x", _xargs={"step_num": 1})
+    assert made[-1] == ("x", {"step_num": 1})
 
 
 def test_trace_context_ids_are_unique():
@@ -444,6 +490,238 @@ def test_structured_log_context_and_json_formatter():
     hvd_logging.set_log_context(rank="-", step="-")
 
 
+# -- the entry path's sites (ISSUE 24) ---------------------------------------
+
+
+def _tiny_step(overlap=False):
+    """A compiled two-layer MLP step over the 8-device CPU mesh: state,
+    the jitted step, a batch."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu import training
+
+    class Mlp(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(4)(nn.relu(nn.Dense(16)(x)))
+
+    hvd.init()
+    model, optimizer = Mlp(), optax.adamw(1e-3)
+    x = jnp.ones((hvd.size(), 8), jnp.float32)
+    y = jnp.zeros((hvd.size(),), jnp.int32)
+    state = training.replicate_state(training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), x[:1]))
+    return state, training.data_parallel_train_step(model, optimizer), x, y
+
+
+def test_create_state_and_replicate_sites_record_their_args():
+    t0 = trace.now()
+    state, _, _, _ = _tiny_step()
+    recs = {r[0]: r for r in trace.snapshot(since=t0)
+            if r[0] in ("train.create_state", "train.replicate")}
+    created = recs["train.create_state"][3]
+    # Dense(8->16) + Dense(16->4), kernels and biases
+    assert created["params"] == 8 * 16 + 16 + 16 * 4 + 4
+    assert created["compiles"] >= 0 and created["compile_s"] >= 0.0
+    assert recs["train.create_state"][2] > 0
+    import jax
+
+    want = sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
+    assert recs["train.replicate"][3] == {"bytes": want} and want > 0
+    assert recs["train.replicate"][1] >= (recs["train.create_state"][1]
+                                          + recs["train.create_state"][2])
+
+
+def test_create_state_untraced_records_nothing_and_returns_the_same():
+    import jax
+
+    trace.configure(enabled=False)
+    t0 = trace.now()
+    off, _, _, _ = _tiny_step()
+    trace.configure(enabled=True)
+    on, _, _, _ = _tiny_step()
+    assert not [r for r in trace.snapshot(since=t0)
+                if r[0] == "train.create_state" and r[1] < t0]
+    for a, b in zip(jax.tree_util.tree_leaves(off),
+                    jax.tree_util.tree_leaves(on)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- device names and their reducer (ISSUE 24) -------------------------------
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(_step)/shard_map/jvp(forward)/Mlp/Dense_0/dot_general",
+     ("forward", False)),
+    ("jit(_step)/jvp(forward)/Transformer/layer_0/transpose",
+     ("forward", False)),           # a jnp transpose is not the transform
+    ("jit(_step)/shard_map/transpose(jvp(forward))/Mlp/Dense_0/dot_general",
+     ("backward", False)),
+    ("jit(_step)/transpose(jvp(forward))/Block/checkpoint/"
+     "rematted_computation/dot_general", ("backward", True)),
+    ("jit(_step)/jvp(forward)/Block/checkpoint/dot_general",
+     ("forward", False)),           # recompute only beneath the backward
+    ("jit(_step)/shard_map/exchange/psum", ("exchange", False)),
+    ("jit(_step)/shard_map/optimizer/mul", ("optimizer", False)),
+    ("jit(_step)/shard_map/optimizer/exchange/reduce_scatter",
+     ("exchange", False)),          # the innermost scope names the phase
+    ("jit(_step)/shard_map/add", ("unattributed", False)),
+    ("", ("unattributed", False)),
+])
+def test_classify_op_name(op_name, want):
+    assert trace_device.classify(op_name) == want
+
+
+def test_phase_table_classifies_a_compiled_cpu_step():
+    state, step, x, y = _tiny_step()
+    text = step.lower(state, x, y).compile().as_text()
+    table = trace_device.phase_table(text)
+    lines = {}
+    for line in text.splitlines():
+        m = trace_device._INSTR_RE.match(line)
+        if m:
+            lines[m.group(1)] = line
+    # every instruction of the text is in the table, under a known phase
+    assert set(table) == set(lines)
+    assert {v[0] for v in table.values()} == set(trace_device.PHASES)
+    # what stays unattributed is what the compiler made or moved
+    # (parameters, constants, copies: no op_name) or what sits outside
+    # every scope by its own op_name (the step counter); never an
+    # instruction whose op_name names a scope
+    for name, (phase, _, _) in table.items():
+        if phase == "unattributed":
+            op_name = trace_device._OP_NAME_RE.search(lines[name])
+            assert not op_name or not any(
+                s in op_name.group(1) for s in trace.DEVICE_SCOPES), lines[name]
+    # the scopes reached the instructions that do the work
+    by_opcode = {}
+    for name, line in lines.items():
+        for opcode in ("dot(", "all-reduce("):
+            if f" {opcode}" in line:
+                by_opcode.setdefault(opcode, set()).add(table[name][0])
+    assert by_opcode["dot("] == {"forward", "backward"}
+    assert by_opcode["all-reduce("] == {"exchange"}
+    # the serialized module, which a capture carries, reads the same
+    proto = step.lower(state, x, y).compile().runtime_executable() \
+        .hlo_modules()[0].as_serialized_hlo_module_proto()
+    assert trace_device.phase_table(proto) == table
+
+
+def test_phase_table_fallbacks_for_instructions_without_a_scoped_op_name():
+    text = """HloModule m
+%fused_computation.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %a = f32[4]{0} add(%p, %p), metadata={op_name="jit(_step)/jvp(forward)/add"}
+  %b = f32[4]{0} add(%a, %p), metadata={op_name="jit(_step)/jvp(forward)/add"}
+  %o = f32[4]{0} multiply(%b, %p), metadata={op_name="jit(_step)/optimizer/mul"}
+  ROOT %c = f32[4]{0} convert(%o)
+}
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  %fusion.1 = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(_step)/convert.9"}
+  %copy.1 = f32[4]{0} copy(%fusion.1)
+  %copy.2 = f32[4]{0} copy(%x)
+  %mul.1 = f32[4]{0} multiply(%copy.1, %copy.1), metadata={op_name="jit(_step)/optimizer/mul"}
+  ROOT %add.2 = f32[4]{0} add(%copy.1, %mul.1)
+}
+"""
+    table = trace_device.phase_table(text)
+    # most of its callee; the optimizer work fused into it is named
+    assert table["fusion.1"] == ("forward", False, ("optimizer",))
+    assert table["copy.1"] == ("forward", False, ())     # its operand's
+    assert table["copy.2"] == ("unattributed", False, ())
+    assert table["mul.1"] == ("optimizer", False, ())
+    assert table["add.2"] == ("unattributed", False, ())  # operands disagree
+
+
+def test_reduce_phases_sums_to_the_busy_time():
+    """A hand-made event list: nested, overlapping and idle stretches;
+    two devices with different step counts."""
+    devices = {
+        "0": {"steps": 2, "ops": [
+            ("fusion.1", 0.0, 100.0),
+            ("while.1", 100.0, 300.0),
+            ("fusion.2", 120.0, 50.0),      # nested in the while
+            ("psum.1", 200.0, 100.0),       # nested in the while
+            ("copy.1", 500.0, 20.0),        # after an idle gap; not in the table
+            ("fusion.3", 510.0, 90.0),      # overlaps the copy
+        ]},
+        "1": {"steps": 1, "ops": [("fusion.1", 0.0, 250.0)]},
+    }
+    table = {"fusion.1": ("forward", False, ()),
+             "while.1": ("backward", False, ("optimizer",)),
+             "fusion.2": ("backward", True, ()),
+             "psum.1": ("exchange", False, ()),
+             "fusion.3": ("optimizer", False, ())}
+    r = trace_device.reduce_phases(devices, table)
+    assert r["devices"] == 2 and r["steps"] == 1
+    # device 0: union 0..400 and 500..600 = 500 ns over 2 steps; device 1:
+    # 250 ns over 1; the mean of 250 and 250 ns, in ms
+    assert r["busy_ms"] == pytest.approx(250e-6)
+    assert r["sum_ms"] == pytest.approx(r["busy_ms"], rel=1e-12)
+    want = {"forward": (50 + 250) / 2, "backward": (150 + 50) / 2 / 2,
+            "exchange": 100 / 2 / 2, "optimizer": 90 / 2 / 2,
+            "unattributed": 10 / 2 / 2}
+    for phase, ns in want.items():
+        assert r["phases"][phase] == pytest.approx(ns * 1e-6), phase
+    assert r["recompute_ms"] == pytest.approx(50 / 2 / 2 * 1e-6)
+    # the while's own 150 ns sit in a fusion that also holds optimizer work
+    assert r["shared_ms"] == {
+        "backward+optimizer": pytest.approx(150 / 2 / 2 * 1e-6)}
+    assert "also hold optimizer work" in trace_device.format_phases(r)
+    assert r["unattributed_top"] == [["copy.1", pytest.approx(2.5e-6)]]
+    # without a table everything is unattributed, and the sum holds
+    r = trace_device.reduce_phases(devices)
+    assert r["sum_ms"] == pytest.approx(r["busy_ms"], rel=1e-12)
+    assert r["phases"]["unattributed"] == pytest.approx(r["busy_ms"])
+    assert "largest unattributed" in trace_device.format_phases(r)
+
+
+def test_a_capture_carries_its_program_and_phases_need_a_device_plane(tmp_path):
+    import jax
+
+    with pytest.raises(FileNotFoundError):
+        trace_device.phase_ms(str(tmp_path))
+    state, step, x, y = _tiny_step()
+    state, _ = step(state, x, y)
+    jax.profiler.start_trace(str(tmp_path))
+    jax.block_until_ready(step(state, x, y))
+    jax.profiler.stop_trace()
+    # the step program's HloModuleProto is in the capture, read with no
+    # protobuf library: every scope shows in its table
+    hlo = trace_device.embedded_hlo(str(tmp_path))
+    assert hlo is not None
+    assert trace_device.embedded_hlo(str(tmp_path), "no_such_program") is None
+    phases = {v[0] for v in trace_device.phase_table(hlo).values()}
+    assert phases == set(trace_device.PHASES)
+    # a CPU capture has no TPU device plane to take times from
+    with pytest.raises(ValueError, match="capture from the chip"):
+        trace_device.phase_ms(str(tmp_path))
+
+
+def test_scopes_leave_the_lowered_step_untouched():
+    """The device scopes are metadata: with them stripped from the
+    lowered text nothing names them, and the operations of the step are
+    the same with tracing on and off."""
+    import re
+
+    state, step, x, y = _tiny_step()
+    on = step.lower(state, x, y).as_text()
+    trace.configure(enabled=False)
+    try:
+        _, step_off, _, _ = _tiny_step()
+        off = step_off.lower(state, x, y).as_text()
+    finally:
+        trace.configure(enabled=True)
+    assert on == off
+    assert not re.search(r"\b(forward|exchange|optimizer)\b",
+                         re.sub(r"loc\(.*", "", on))
+
+
 # -- the analysis `trace` pass -----------------------------------------------
 
 
@@ -497,3 +775,78 @@ def test_trace_pass_registered_and_repo_clean():
     assert "trace" in analysis.PASSES
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert analysis.PASSES["trace"](repo) == []
+
+
+def _device_tree(tmp_path, scopes, kernels, code, flash, doc_rows):
+    root = _tree(tmp_path, ["train.step"],
+                 'from . import trace\ntrace.event("train.step")\n' + code,
+                 ["train.step"])
+    init = tmp_path / "horovod_tpu" / "trace" / "__init__.py"
+    init.write_text(
+        init.read_text()
+        + "DEVICE_SCOPES = (\n" + "".join(f'    "{s}",\n' for s in scopes)
+        + ")\nDEVICE_KERNELS = (\n"
+        + "".join(f'    "{k}",  # a comment (with parentheses)\n'
+                  for k in kernels) + ")\n")
+    (tmp_path / "horovod_tpu" / "ops").mkdir()
+    (tmp_path / "horovod_tpu" / "ops" / "flash_attention.py").write_text(flash)
+    doc = tmp_path / "docs" / "TRACING.md"
+    doc.write_text(doc.read_text() + "\n| name | kind |\n|---|---|\n" + "".join(
+        f"| `{n}` | {k} | x |\n" for n, k in doc_rows))
+    return root
+
+
+_FLASH_OK = (
+    "out = pl.pallas_call(\n    functools.partial(k, a=1),\n"
+    '    name="flash_attention_fwd",\n    grid=(b * h, s // q),\n)(x)\n')
+
+
+def test_trace_pass_device_names_clean_tree(tmp_path):
+    from horovod_tpu.analysis import trace_sites
+
+    root = _device_tree(
+        tmp_path, ["forward", "exchange"], ["flash_attention_fwd"],
+        'with jax.named_scope("forward"):\n    pass\n'
+        '@jax.named_scope("exchange")\ndef f(): pass\n',
+        _FLASH_OK,
+        [("forward", "scope"), ("exchange", "scope"),
+         ("flash_attention_fwd", "kernel")])
+    assert trace_sites.run(root) == []
+
+
+def test_trace_pass_catches_device_name_drift(tmp_path):
+    from horovod_tpu.analysis import trace_sites
+
+    root = _device_tree(
+        tmp_path, ["forward", "dead_scope"],
+        ["flash_attention_fwd", "dead_kernel"],
+        'with jax.named_scope("forward"):\n    pass\n'
+        'with jax.named_scope("rogue_scope"):\n    pass\n',
+        _FLASH_OK
+        + 'o = pl.pallas_call(k, name="rogue_kernel", grid=(1,))(x)\n'
+        + "o = pl.pallas_call(k, grid=(1,))(x)\n",
+        [("forward", "scope"), ("ghost_scope", "scope"),
+         ("flash_attention_fwd", "kernel"), ("ghost_kernel", "kernel")])
+    keys = {(f.key, f.file.split("/")[-1]) for f in trace_sites.run(root)}
+    assert ("rogue_scope", "mod.py") in keys             # uncatalogued scope
+    assert ("rogue_kernel", "flash_attention.py") in keys
+    assert ("dead_scope", "__init__.py") in keys          # no call site / no row
+    assert ("dead_kernel", "__init__.py") in keys
+    assert ("ghost_scope", "TRACING.md") in keys          # stale doc rows
+    assert ("ghost_kernel", "TRACING.md") in keys
+    assert ("pallas_call", "flash_attention.py") in keys  # an unnamed kernel
+
+
+def test_device_names_catalogue_matches_the_code():
+    """DEVICE_KERNELS are the names the kernels carry, each starting
+    with the prefix the benchmark's one pattern reads them all by."""
+    from horovod_tpu.analysis import trace_sites
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert tuple(trace_sites.catalogue(repo, "DEVICE_KERNELS")) == \
+        trace.DEVICE_KERNELS
+    assert tuple(trace_sites.catalogue(repo, "DEVICE_SCOPES")) == \
+        trace.DEVICE_SCOPES
+    assert all(k.startswith("flash_attention_") for k in trace.DEVICE_KERNELS)
+    assert not os.path.exists(
+        os.path.join(repo, "horovod_tpu", "utils", "profiler.py"))

@@ -1,29 +1,39 @@
 #!/usr/bin/env python
-"""Capture a jax.profiler (XPlane) trace with framework spans in it.
+"""Capture a jax.profiler (XPlane) trace of the framework, or reduce one.
 
-Runs a short burst of negotiated collectives inside a profiler capture
-so the resulting trace shows ``hvd_tpu::<name>::ENQUEUE`` /
-``hvd_tpu::<op>::XLA_COMM`` spans (utils/profiler.py bridge) next to
-XLA's own op activity — the reference's NVTX-next-to-kernels view,
-TPU edition (SURVEY.md §5.1).
+    python tools/profile_capture.py <dir> [--hlo <compiled step's text>]
 
-Usage (single process; works on the virtual CPU mesh or a TPU)::
+``<dir>`` already holds a capture (a ``.xplane.pb`` anywhere beneath it,
+e.g. what ``benchmark/run.py --trace 1`` leaves in
+``.bench_out/trace/<cell>/``): print the device time a step by phase —
+forward / backward / exchange / optimizer / unattributed
+(``horovod_tpu.trace.device``; docs/TRACING.md, "Device names").  The
+instructions' ``op_name`` is read from the compiled program the capture
+carries; ``--hlo`` takes it from a text file instead.
 
-    python tools/profile_capture.py /tmp/hvd-trace
-    tensorboard --logdir /tmp/hvd-trace           # Profile plugin
-    # or load plugins/profile/<ts>/<host>.trace.json.gz in
-    # ui.perfetto.dev
+``<dir>`` holds none: capture there first — a burst of negotiated
+collectives (``hvd_tpu::<name>::ENQUEUE`` / ``hvd_tpu::<op>::XLA_COMM``
+spans next to XLA's own op activity; SURVEY.md §5.1) and a few steps of a
+small compiled train step through ``fit_epoch`` (``hvd_tpu::train.step``
+as a step annotation, the phase scopes inside the program) — then print
+the same table.  The phases need a TPU's device plane: on the virtual CPU
+mesh (the default without ``JAX_PLATFORMS``) only the capture is written.
+
+    tensorboard --logdir <dir>           # Profile plugin, or load
+    # plugins/profile/<ts>/<host>.trace.json.gz in ui.perfetto.dev
 
 docs/example_trace.json.gz in the repo is one committed capture from
 the 8-device virtual CPU mesh (see PERF.md round 4).
 """
 
+import argparse
 import os
 import sys
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-def main() -> int:
-    logdir = sys.argv[1] if len(sys.argv) > 1 else "/tmp/hvd-trace"
+
+def capture(logdir: str) -> None:
     if os.environ.get("JAX_PLATFORMS", "") == "":
         # default to the virtual CPU mesh so the tool runs anywhere
         os.environ.setdefault("XLA_FLAGS",
@@ -31,8 +41,12 @@ def main() -> int:
         os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import jax.numpy as jnp
+    import optax
 
     import horovod_tpu as hvd
+    from horovod_tpu import trace, training
+    from horovod_tpu.models.transformer import Transformer, gpt_tiny
+    from horovod_tpu.trace import export as trace_export
 
     hvd.init()
     # timeline active => XLA_COMM spans end at data-ready (controller
@@ -42,8 +56,12 @@ def main() -> int:
     x = jnp.arange(1 << 16, dtype=jnp.float32)
     hvd.allreduce(x, name="warmup")  # compile outside the capture
 
-    from horovod_tpu import trace
-    from horovod_tpu.trace import export as trace_export
+    model, optimizer = Transformer(gpt_tiny()), optax.adamw(1e-3)
+    tokens = jnp.zeros((hvd.size(), 128), jnp.int32)
+    state = training.replicate_state(training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), tokens[:1]))
+    step = training.data_parallel_train_step(model, optimizer)
+    state, _ = step(state, tokens, tokens)  # compile outside the capture
 
     since = trace.now()
     jax.profiler.start_trace(logdir)
@@ -52,15 +70,40 @@ def main() -> int:
     jax.block_until_ready(y)
     # a grouped submission so a fused XLA_COMM span appears too
     hvd.grouped_allreduce([x, x * 2, x * 3], name="bucket")
+    state, _ = training.fit_epoch(step, state, [(tokens, tokens)] * 4)
     jax.profiler.stop_trace()
     hvd.stop_timeline()
     # ONE instrumentation point, two views (docs/TRACING.md): the same
-    # collective.enqueue/exec spans that just landed in the XPlane
-    # capture also export as standalone Chrome trace-event JSON
+    # spans that just landed in the XPlane capture also export as
+    # standalone Chrome trace-event JSON
     chrome = os.path.join(logdir, "hvd_framework_spans.json")
     trace_export.write_dump(chrome, since=since)
     print(f"trace written under {logdir}/plugins/profile/")
     print(f"framework spans (Chrome trace-event JSON): {chrome}")
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("dir", nargs="?", default="/tmp/hvd-trace",
+                   help="a capture (a directory holding an .xplane.pb, or "
+                   "the file), or where to write one")
+    p.add_argument("--hlo", help="text of the compiled step "
+                   "(step.lower(...).compile().as_text())")
+    args = p.parse_args(argv)
+    from horovod_tpu.trace import device
+
+    try:
+        device.find_xplane(args.dir)
+    except FileNotFoundError:
+        capture(args.dir)
+    table = None
+    if args.hlo:
+        with open(args.hlo) as f:
+            table = device.phase_table(f.read())
+    try:
+        print(device.format_phases(device.phase_ms(args.dir, table)))
+    except ValueError as e:
+        print(f"no phases: {e}")
     return 0
 
 
