@@ -517,6 +517,44 @@ def overlap_inventory(
     }
 
 
+_HLO_COMPUTATION_RE = re.compile(
+    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$"
+)
+_HLO_FUSION_CALLS_RE = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
+
+
+def compiled_collective_counts(compiled_text: str) -> Dict[str, int]:
+    """How the BACKEND scheduled a step's all-reduces, counted in the
+    compiled text (``step.lower(...).compile().as_text()``: scheduled HLO,
+    after the compiler's passes) — the counterpart of
+    :func:`overlap_inventory`, which reads the lowered program and so
+    cannot see what the TPU scheduler does with it.
+
+    ``async_pairs``: start/done pairs of asynchronous collectives — the
+    TPU's ``AsyncCollectiveStart``/``AsyncCollectiveDone`` custom calls
+    (each in a fusion of its own; the collective itself is repeated
+    inside the compute fusions it runs beside) and XLA's generic
+    ``all-reduce-start``/``-done``.  ``sync_all_reduces``: ``all-reduce``
+    instructions outside every fused computation, during which the core
+    waits.  The dp4 benchmark step compiled with no option: 0 and 26."""
+    fused = set(_HLO_FUSION_CALLS_RE.findall(compiled_text))
+    starts = dones = sync = 0
+    computation = ""
+    for line in compiled_text.splitlines():
+        m = _HLO_COMPUTATION_RE.match(line)
+        if m:
+            computation = m.group(1)
+        elif 'custom_call_target="AsyncCollectiveStart"' in line \
+                or " all-reduce-start(" in line:
+            starts += 1
+        elif 'custom_call_target="AsyncCollectiveDone"' in line \
+                or " all-reduce-done(" in line:
+            dones += 1
+        elif " all-reduce(" in line and computation not in fused:
+            sync += 1
+    return {"async_pairs": min(starts, dones), "sync_all_reduces": sync}
+
+
 def modeled_overlap_exposed(
     bucket_bytes: Sequence[int],
     t_compute_s: float,

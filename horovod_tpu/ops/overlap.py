@@ -20,13 +20,23 @@ last gradient is produced and issuing its reduction *there* — between
 segment computations, not after them.  An ``optimization_barrier`` at
 each bucket boundary pins the dataflow: the bucket's collective and the
 next segment's backward both depend on the boundary but not on each
-other, so XLA may run them concurrently (its async collective pass +
-latency-hiding scheduler does exactly that on TPU) but can hoist
-neither above the segment that produced the bucket.  The lowered
-StableHLO therefore carries the collectives interleaved with the
-segment computations — pinned by the ``overlap_inventory`` check in
-``ops/comm_model.py`` (the PR-7 ``measured_tier_bytes`` idiom), not
-assumed.
+other, and neither can be hoisted above the segment that produced the
+bucket.  The lowered StableHLO therefore carries the collectives
+interleaved with the segment computations — pinned by the
+``overlap_inventory`` check in ``ops/comm_model.py`` (the PR-7
+``measured_tier_bytes`` idiom), not assumed.
+
+Lowered order is not the schedule.  What runs beside what is the
+backend scheduler's decision, and on the TPU compiler an all-reduce is
+SYNCHRONOUS unless the compile asks otherwise: compiled for a v5e with
+no option, this chain's bucket all-reduces are all synchronous and the
+scheduler sinks them behind the whole backward, barriers or not (ISSUE
+25's compiles; PERF.md §6, PR 25).  Asynchrony comes from the compile
+options the step builder attaches
+(``spmd_ops.exchange_compile_options``, to ``overlap=False`` and
+``overlap=True`` alike); ``overlap_inventory`` and
+``hvd_tpu_overlap_exposed_comm_fraction`` describe the lowered program
+only, and ``comm_model.compiled_collective_counts`` the compiled one.
 
 Exactness contract: ``overlap=True`` and ``overlap=False`` run the SAME
 arithmetic (same fusion, same per-bucket reduction, only the program

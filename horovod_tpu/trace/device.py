@@ -36,6 +36,18 @@ The rules, stated and not hidden:
   covering it, so the phases and ``unattributed`` sum to the busy time —
   the union of the operation intervals, as the benchmark's
   ``device_step_ms`` is taken.
+* ``exchange hidden`` is the time with a reduction IN FLIGHT during
+  which an operation of another phase ran on that device.  A
+  synchronous all-reduce is in flight while its one event runs, with
+  nothing beside it.  An asynchronous one (what the data-parallel step
+  asks for on more than one TPU chip, ``spmd_ops.
+  exchange_compile_options``) has no event of its own on the ``XLA Ops``
+  line: it is in flight from the start of ``async-collective-start.<n>``
+  to the end of ``async-collective-done.<n>`` (or ``all-reduce-start`` /
+  ``-done``), and the fusions between the two are the compute it hides
+  behind.  The ``exchange`` row itself is then what stayed exposed: the
+  start and done operations (the done waits for what is left), the
+  synchronous all-reduces and the divisions.
 
 Reads the capture through ``jax.profiler.ProfileData`` and a few lines
 of protobuf wire format (no TensorFlow, no xprof); imports nothing of
@@ -369,6 +381,28 @@ def _union_ns(events) -> float:
     return total
 
 
+_ASYNC_START = re.compile(r"^(.*)-start((?:\.\d+)?)$")
+
+
+def _in_flight(ops, phase_of) -> List[Tuple[float, float]]:
+    """``(start, duration)`` of the stretches with an exchange operation
+    in flight on one device: every ``exchange`` event's own interval,
+    and for an asynchronous pair the whole stretch from ``<x>-start.<n>``
+    to the end of the next ``<x>-done.<n>`` (rule: module docstring)."""
+    spans, open_starts = [], {}
+    for name, start, dur in sorted(ops, key=lambda e: e[1]):
+        if phase_of(name) != "exchange":
+            continue
+        spans.append((start, dur))
+        m = _ASYNC_START.match(name)
+        if m:
+            open_starts[f"{m.group(1)}-done{m.group(2)}"] = start
+        elif name in open_starts:
+            began = open_starts.pop(name)
+            spans.append((began, start + dur - began))
+    return spans
+
+
 def device_events(xplane_path: str, module: str = "step") -> Dict[str, dict]:
     """``{device: {"steps": n, "ops": [(name, start_ns, duration_ns)]}}``
     of a capture: the operations of the ``XLA Ops`` line that started
@@ -413,15 +447,22 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
 
     Returns ``{"steps", "devices", "busy_ms", "sum_ms", "phases":
     {phase: ms}, "recompute_ms", "shared_ms": {"<phase>+<other>": ms},
+    "exchange_in_flight_ms", "exchange_hidden_ms",
     "unattributed_top": [[name, ms], ...]}``.
     ``busy_ms`` is the union of the operation intervals, taken on its
     own; ``sum_ms``, the phases' sum, equals it.  ``shared_ms`` is the
     part of a phase spent in fusions that also hold another phase's
-    instructions."""
+    instructions.  ``exchange_in_flight_ms`` is the time with a
+    reduction in flight and ``exchange_hidden_ms`` the part of it during
+    which an operation of another phase ran (module docstring)."""
     nothing = ("unattributed", False, ())
     n_dev = len(devices)
     phases = dict.fromkeys(PHASES, 0.0)
-    busy = recompute_ms = 0.0
+    busy = recompute_ms = in_flight_ms = hidden_ms = 0.0
+
+    def phase_of(name: str) -> str:
+        return (table or {}).get(name, nothing)[0]
+
     shared: Dict[str, float] = {}
     unattributed: Dict[str, float] = {}
     for dev in devices.values():
@@ -429,6 +470,14 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
                  for name, start, dur in dev["ops"]]
         scale = 1e-6 / dev["steps"] / n_dev
         busy += _union_ns(keyed) * scale
+        flying = _in_flight(dev["ops"], phase_of)
+        others = [(start, dur) for name, start, dur in dev["ops"]
+                  if phase_of(name) != "exchange"]
+        flying_ns = _union_ns(flying)
+        in_flight_ms += flying_ns * scale
+        # |flying and others| = |flying| + |others| - |flying or others|
+        hidden_ms += (flying_ns + _union_ns(others)
+                      - _union_ns(flying + others)) * scale
         for (phase, recompute, also, name), ns in _self_time(keyed).items():
             phases[phase] += ns * scale
             if recompute:
@@ -443,6 +492,8 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
         "steps": min(d["steps"] for d in devices.values()), "devices": n_dev,
         "busy_ms": busy, "sum_ms": sum(phases.values()),
         "phases": phases, "recompute_ms": recompute_ms, "shared_ms": shared,
+        "exchange_in_flight_ms": in_flight_ms,
+        "exchange_hidden_ms": hidden_ms,
         "unattributed_top": [[name, ms] for name, ms in top],
     }
 
@@ -472,6 +523,13 @@ def format_phases(result: dict) -> str:
             if k.startswith(phase + "+") and v >= 0.01 * busy)
         rows.append(f"  {phase:<13}{ms:10.3f} ms  {100 * ms / busy:5.1f} %"
                     + (f"  ({fused})" if fused else ""))
+        flying = result["exchange_in_flight_ms"]
+        if phase == "exchange" and flying > 0:
+            hidden = result["exchange_hidden_ms"]
+            rows.append(
+                f"  {'exchange hidden':<16}{hidden:7.3f} ms  "
+                f"{100 * hidden / flying:5.1f} % of the {flying:.3f} ms "
+                "with a reduction in flight")
     rows.append(f"  {'busy':<13}{result['busy_ms']:10.3f} ms  (phases sum "
                 f"{result['sum_ms']:.3f}; recompute, inside backward, "
                 f"{result['recompute_ms']:.3f})")

@@ -6,7 +6,11 @@ SURVEY.md §3.2) packaged as a library: a ``TrainState`` and a compiled
 SPMD train step over the world mesh.  One call produces the whole hot
 path — forward, backward, fused gradient allreduce over ICI, optimizer
 update — as a single XLA program, which is the TPU-native replacement for
-the reference's background-thread overlap machinery.
+the reference's background-thread overlap machinery.  The program alone
+does not hide the exchange: on the TPU compiler an all-reduce is
+synchronous unless the compile asks otherwise, so the data-parallel step
+builder attaches ``spmd_ops.exchange_compile_options`` to its ``jit``
+(TPU devices and more than one of them on the axis; else nothing).
 """
 
 from __future__ import annotations
@@ -180,11 +184,19 @@ def data_parallel_train_step(
     allreduce is inserted here (equivalent to wrapping with
     DistributedOptimizer; don't do both or gradients reduce twice).
 
+    On TPU devices with more than one of them on ``axis`` the step is
+    compiled with ``spmd_ops.exchange_compile_options``: the gradients'
+    all-reduces become asynchronous and run beside the backward's
+    weight-gradient matmuls and the optimizer's update (on one chip, on
+    the CPU and on any other backend: no option, the step as it always
+    was).  The arithmetic is the same either way.
+
     ``overlap=True`` stages the backward at bucket boundaries
-    (``ops/overlap.py``): each :class:`~horovod_tpu.ops.fusion.
-    BucketSchedule` bucket's allreduce launches while earlier segments'
-    gradients are still computing, instead of the whole reduction
-    trailing the backward.  Gradients and updates stay bit-equal to the
+    (``ops/overlap.py``): in the LOWERED program each :class:`~horovod_tpu.
+    ops.fusion.BucketSchedule` bucket's allreduce sits between the
+    segments' backward computations instead of trailing them; what the
+    backend schedules beside what is decided by the compile options
+    above, not by that order.  Gradients and updates stay bit-equal to the
     unoverlapped step at fp32.  Requires a segment-chain model
     (:func:`models.transformer.overlap_segments` is used for the
     flagship ``Transformer``; pass ``segmenter`` otherwise) and no
@@ -300,7 +312,13 @@ def data_parallel_train_step(
         out_specs=(P(), P(), P()) if guard else (P(), P()),
         check_vma=False,
     )
-    return jax.jit(sharded, donate_argnums=(0,))
+    # the all-reduces run beside the backward only if the compile asks
+    # for it (TPU, more than one device on the axis; else no option)
+    return jax.jit(
+        sharded, donate_argnums=(0,),
+        compiler_options=spmd_ops.exchange_compile_options(mesh, axis)
+        or None,
+    )
 
 
 def zero_train_setup(

@@ -269,3 +269,63 @@ def test_internlm2_block_step_names_its_device_work(topo, monkeypatch):
     assert phase_of == {"flash_attention_fwd": "forward",
                         "flash_attention_bwd_dq": "backward",
                         "flash_attention_bwd_dkv": "backward"}
+
+
+# -- the data-parallel step asks for asynchronous all-reduces (ISSUE 25) ------
+
+
+def _internlm2_step(topo, monkeypatch, n_devices, depth):
+    """The dp4 cell's step (benchmark/configs/internlm2-1.8b.json: every
+    width as published, 1 x 4096 tokens a chip, AdamW) through the step
+    builder alone, at ``depth`` of the cell's 8 layers: the whole step
+    compiles in 90 s and more here, two layers in a third of that, and
+    all layers are of one kind."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:n_devices]), (WORLD_AXIS,))
+    cfg = TransformerConfig(
+        vocab_size=92544, num_layers=depth, num_heads=16, num_kv_heads=8,
+        head_dim=128, mlp_ratio=4, max_seq_len=32768, dtype=jnp.bfloat16,
+        attention_impl="flash")
+    return _step_compiled(
+        Transformer(cfg), optax.adamw(1e-3), mesh,
+        jnp.zeros((1, 4096), jnp.int32),
+        ((n_devices, 4096), jnp.int32), ((n_devices, 4096), jnp.int32))
+
+
+def test_dp4_step_all_reduces_are_asynchronous(topo, monkeypatch):
+    """Over four described chips the builder attaches the options itself
+    (``spmd_ops.exchange_compile_options``): the compiled schedule holds
+    asynchronous collective pairs, and of the synchronous all-reduces
+    (26 at the cell's depth with no option, one a leaf or tuple) only the
+    loss's scalar and at most one tuple of small leaves are left."""
+    from horovod_tpu.ops import spmd_ops
+    from horovod_tpu.ops.comm_model import compiled_collective_counts
+
+    mesh = Mesh(np.array(topo.devices), (WORLD_AXIS,))
+    assert spmd_ops.exchange_compile_options(mesh) \
+        == spmd_ops._ASYNC_ALL_REDUCE_OPTIONS
+    compiled = _internlm2_step(topo, monkeypatch, 4, depth=2)
+    counts = compiled_collective_counts(compiled.as_text())
+    assert counts["async_pairs"] >= 1
+    assert counts["sync_all_reduces"] <= 2 < 26
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_one_chip_step_gets_no_option(topo, monkeypatch):
+    """An axis of one has no exchange to hide: no option, no
+    asynchronous pair, the same compiled text as with the helper forced
+    to return nothing."""
+    from horovod_tpu.ops import spmd_ops
+    from horovod_tpu.ops.comm_model import compiled_collective_counts
+
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    assert spmd_ops.exchange_compile_options(mesh) == {}
+    texts = []
+    for forced in (False, True):   # one call site: the same stack frames
+        if forced:
+            monkeypatch.setattr(spmd_ops, "exchange_compile_options",
+                                lambda mesh, axis=WORLD_AXIS: {})
+        texts.append(_internlm2_step(topo, monkeypatch, 1, depth=1).as_text())
+    assert compiled_collective_counts(texts[0]) == {
+        "async_pairs": 0, "sync_all_reduces": 0}
+    assert texts[0] == texts[1]

@@ -20,6 +20,8 @@ Covers, against the 8-virt-device session mesh:
 * the torch bridge's deterministic bucket-ordered submission.
 """
 
+import types
+
 import numpy as np
 import pytest
 
@@ -35,7 +37,9 @@ from horovod_tpu.metrics import instruments as _metrics
 from horovod_tpu.models.transformer import (
     Transformer, gpt_tiny, overlap_segments,
 )
+from horovod_tpu.ops import spmd_ops
 from horovod_tpu.ops.comm_model import (
+    compiled_collective_counts,
     measured_tier_bytes, mesh_slice_ids, modeled_collective_bytes,
     modeled_overlap_exposed, overlap_inventory,
 )
@@ -752,3 +756,133 @@ class TestModeledOverlap:
     def test_world_one_is_free(self):
         m = modeled_overlap_exposed([1 << 20], 0.01, 1e9, 1)
         assert m["t_comm_s"] == 0.0 and m["exposed_fraction"] == 0.0
+
+
+# -- asynchronous all-reduces: what the step asks of the compiler (ISSUE 25) --
+
+
+def _fake_mesh(platforms, axis=WORLD_AXIS):
+    """What ``exchange_compile_options`` reads of a mesh (its shape and
+    its devices' platforms), for backends this suite cannot attach; the
+    described v5e is in tests/test_chip_compile.py."""
+    devices = np.empty(len(platforms), dtype=object)
+    devices[:] = [types.SimpleNamespace(platform=p) for p in platforms]
+    return types.SimpleNamespace(shape={axis: len(platforms)}, devices=devices)
+
+
+def _tiny_lm(n_devices):
+    from jax.sharding import Mesh
+
+    hvd.init()
+    mesh = Mesh(np.array(jax.devices()[:n_devices]), (WORLD_AXIS,))
+    model, optimizer = Transformer(gpt_tiny()), optax.adamw(1e-3)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (n_devices, 32), 0, gpt_tiny().vocab_size)
+
+    def state():
+        return training.replicate_state(training.create_train_state(
+            model, optimizer, jax.random.PRNGKey(0), tokens[:1]), mesh)
+
+    return mesh, model, optimizer, tokens, state
+
+
+class TestExchangeCompileOptions:
+    def test_cpu_mesh_of_four_gets_none_and_the_step_is_the_plain_jit(self):
+        mesh, model, optimizer, tokens, state = _tiny_lm(4)
+        assert spmd_ops.exchange_compile_options(mesh) == {}
+        step = training.data_parallel_train_step(model, optimizer, mesh=mesh)
+        plain = jax.jit(step.__wrapped__, donate_argnums=(0,))
+        got_state, got_loss = step(state(), tokens, tokens)
+        want_state, want_loss = plain(state(), tokens, tokens)
+        assert np.asarray(got_loss) == np.asarray(want_loss)
+        assert _tree_bit_equal(got_state.params, want_state.params)
+        # nothing asynchronous on this backend, and the counter says so
+        counts = compiled_collective_counts(
+            step.lower(state(), tokens, tokens).compile().as_text())
+        assert counts["async_pairs"] == 0 and counts["sync_all_reduces"] >= 1
+
+    @pytest.mark.parametrize("mesh", [
+        pytest.param(lambda: _tiny_lm(1)[0], id="one_cpu_device"),
+        pytest.param(lambda: _fake_mesh(["tpu"]), id="one_tpu"),
+        pytest.param(lambda: _fake_mesh(["gpu"] * 4), id="four_gpus"),
+        pytest.param(lambda: _fake_mesh(["tpu", "tpu", "cpu", "tpu"]),
+                     id="mixed"),
+    ])
+    def test_none_without_an_exchange_or_off_the_tpu(self, mesh):
+        assert spmd_ops.exchange_compile_options(mesh()) == {}
+
+    def test_tpu_axis_of_four_gets_the_asynchronous_set(self):
+        mesh = _fake_mesh(["tpu"] * 4)
+        options = spmd_ops.exchange_compile_options(mesh)
+        assert options["xla_enable_async_all_reduce"] is True
+        assert options["xla_tpu_enable_async_collective_fusion"] is True
+        assert options[
+            "xla_tpu_enable_async_collective_fusion_fuse_all_reduce"] is True
+        # a fresh dict each call: a caller may add to it
+        options["x"] = 1
+        assert "x" not in spmd_ops.exchange_compile_options(mesh)
+        # the axis that is reduced over decides, not the mesh's size
+        two_axes = types.SimpleNamespace(
+            shape={"data": 4, "model": 1}, devices=mesh.devices)
+        assert (spmd_ops.exchange_compile_options(two_axes, "data")
+                == spmd_ops.exchange_compile_options(mesh))
+        assert spmd_ops.exchange_compile_options(two_axes, "model") == {}
+
+
+_COMPILED_TEXT = """HloModule jit__step, is_scheduled=true
+%region_1.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.1 = f32[] add(%a, %b)
+}
+%fused_computation.1 (param_0.1: f32[8]) -> (f32[8], u32[]) {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %all-reduce.7 = f32[8]{0} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  ROOT %custom-call.1 = (f32[8]{0}, u32[]) custom-call(%all-reduce.7), custom_call_target="AsyncCollectiveStart"
+}
+%fused_computation.2 (param_0.2: f32[8], param_1.2: f32[8,8]) -> f32[8,8] {
+  %param_0.2 = f32[8]{0} parameter(0)
+  %param_1.2 = f32[8,8]{1,0} parameter(1)
+  %all-reduce.8 = f32[8]{0} all-reduce(%param_0.2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  ROOT %convolution.1 = f32[8,8]{1,0} convolution(%param_1.2, %param_1.2), dim_labels=bf_io->bf
+}
+%fused_computation.3 (param_0.3: f32[8]) -> f32[8] {
+  %param_0.3 = f32[8]{0} parameter(0)
+  %all-reduce.9 = f32[8]{0} all-reduce(%param_0.3), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  ROOT %custom-call.2 = f32[8]{0} custom-call(%all-reduce.9), custom_call_target="AsyncCollectiveDone"
+}
+ENTRY %main (p0: f32[8], p1: f32[8,8], p2: f32[]) -> (f32[8], f32[8,8], f32[]) {
+  %p0 = f32[8]{0} parameter(0)
+  %p1 = f32[8,8]{1,0} parameter(1)
+  %p2 = f32[] parameter(2)
+  %async-collective-start = (f32[8]{0}, u32[]) fusion(%p0), kind=kCustom, calls=%fused_computation.1
+  %fusion.5 = f32[8,8]{1,0} fusion(%p0, %p1), kind=kOutput, calls=%fused_computation.2
+  %async-collective-done = f32[8]{0} fusion(%p0), kind=kCustom, calls=%fused_computation.3
+  %psum.3 = f32[] all-reduce(%p2), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  SYNC_LINE
+  ROOT %tuple.1 = (f32[8]{0}, f32[8,8]{1,0}, f32[]) tuple(%async-collective-done, %fusion.5, %psum.3)
+}
+"""
+
+
+@pytest.mark.parametrize("extra,want", [
+    pytest.param("", {"async_pairs": 1, "sync_all_reduces": 1},
+                 id="one_fused_pair_and_the_scalar"),
+    pytest.param(
+        "%all-reduce.3 = f32[8]{0} all-reduce(%p0), channel_id=3, "
+        "replica_groups={{0,1,2,3}}, to_apply=%region_1.1, "
+        'frontend_attributes={async_collective_name="all-reduce-start.1"}',
+        {"async_pairs": 1, "sync_all_reduces": 2}, id="one_turned_back"),
+    pytest.param(
+        "%all-reduce-start.1 = f32[8]{0} all-reduce-start(%p0), "
+        "channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%region_1.1\n"
+        "  %all-reduce-done.1 = f32[8]{0} all-reduce-done("
+        "%all-reduce-start.1)",
+        {"async_pairs": 2, "sync_all_reduces": 1}, id="the_generic_pair"),
+])
+def test_compiled_collective_counts_reads_the_schedule(extra, want):
+    """The all-reduce repeated inside the fusions of an asynchronous
+    collective is not a synchronous one; one in ENTRY is, whatever
+    name it still carries."""
+    text = _COMPILED_TEXT.replace("SYNC_LINE", extra)
+    assert compiled_collective_counts(text) == want

@@ -681,6 +681,73 @@ def test_reduce_phases_sums_to_the_busy_time():
     assert "largest unattributed" in trace_device.format_phases(r)
 
 
+_HIDDEN_TABLE = {
+    "fusion.1": ("backward", False, ()),
+    "fusion.2": ("backward", False, ("exchange",)),   # a partner fusion
+    "fusion.3": ("optimizer", False, ()),
+    "psum.1": ("exchange", False, ()),
+    "divide.1": ("exchange", False, ()),
+    "async-collective-start.4": ("exchange", False, ()),
+    "async-collective-done.4": ("exchange", False, ()),
+    "all-reduce-start": ("exchange", False, ()),
+    "all-reduce-done": ("exchange", False, ()),
+}
+
+
+@pytest.mark.parametrize("ops,in_flight,hidden", [
+    pytest.param(
+        # a synchronous all-reduce: in flight while it runs, alone
+        [("fusion.1", 0.0, 100.0), ("psum.1", 100.0, 60.0),
+         ("divide.1", 160.0, 10.0), ("fusion.3", 170.0, 30.0)],
+        70.0, 0.0, id="synchronous_nothing_beside_it"),
+    pytest.param(
+        # an asynchronous pair around a partner fusion and an idle gap:
+        # in flight 100..200, of which fusion.2 covers 110..170
+        [("fusion.1", 0.0, 100.0), ("async-collective-start.4", 100.0, 10.0),
+         ("fusion.2", 110.0, 60.0), ("async-collective-done.4", 180.0, 20.0),
+         ("fusion.3", 200.0, 30.0)],
+        100.0, 60.0, id="asynchronous_behind_a_fusion"),
+    pytest.param(
+        # one of each in one step, and the generic start/done names; the
+        # second pair has nothing between its start and its done
+        [("async-collective-start.4", 0.0, 10.0), ("fusion.2", 10.0, 50.0),
+         ("fusion.1", 60.0, 20.0), ("async-collective-done.4", 80.0, 5.0),
+         ("psum.1", 90.0, 40.0),
+         ("all-reduce-start", 130.0, 5.0), ("all-reduce-done", 135.0, 25.0),
+         ("fusion.3", 160.0, 40.0)],
+        85.0 + 40.0 + 30.0, 70.0, id="one_hidden_one_exposed"),
+    pytest.param(
+        # a done without its start in the window (the capture began
+        # mid-step) is its own interval only
+        [("fusion.1", 0.0, 50.0), ("async-collective-done.4", 50.0, 20.0)],
+        20.0, 0.0, id="done_without_a_start"),
+])
+def test_exchange_hidden_is_the_in_flight_time_beside_other_work(
+        ops, in_flight, hidden):
+    devices = {"0": {"steps": 1, "ops": ops},
+               # a second device, twice the steps, the same events twice
+               "1": {"steps": 2, "ops": ops + [
+                   (n, s + 1000.0, d) for n, s, d in ops]}}
+    r = trace_device.reduce_phases(devices, _HIDDEN_TABLE)
+    assert r["exchange_in_flight_ms"] == pytest.approx(in_flight * 1e-6)
+    assert r["exchange_hidden_ms"] == pytest.approx(hidden * 1e-6, abs=1e-15)
+    # the phases still partition the busy time; exchange is what the
+    # exchange operations themselves took (the exposed part)
+    assert r["sum_ms"] == pytest.approx(r["busy_ms"], rel=1e-12)
+    own = sum(d for n, _, d in ops if _HIDDEN_TABLE[n][0] == "exchange")
+    assert r["phases"]["exchange"] == pytest.approx(own * 1e-6)
+    text = trace_device.format_phases(r)
+    assert "exchange hidden" in text
+    assert f"{100 * hidden / in_flight:5.1f} % of the" in text
+
+
+def test_no_exchange_no_hidden_row():
+    devices = {"0": {"steps": 1, "ops": [("fusion.1", 0.0, 10.0)]}}
+    r = trace_device.reduce_phases(devices, _HIDDEN_TABLE)
+    assert r["exchange_in_flight_ms"] == 0.0 == r["exchange_hidden_ms"]
+    assert "exchange hidden" not in trace_device.format_phases(r)
+
+
 def test_a_capture_carries_its_program_and_phases_need_a_device_plane(tmp_path):
     import jax
 
