@@ -1,9 +1,36 @@
-"""The model families the harness can build: a small registry.
+"""The model families the harness can build.
 
 A family says how a configuration's file becomes the program's model, what a
-batch looks like, what a sample is, and where its plain reference and its
-required-FLOP function live.  A configuration names its family; a new model
-of a family that is here needs no code.
+batch looks like, what a sample is, and where its plain reference lives.  A
+configuration names its family (``"family"``); a new model of a family that
+is here needs no code.  ``resnet`` and ``decoder_lm`` are in the table below;
+any other name is ``<module>:<attribute>`` under ``benchmark/`` (the rule in
+``benchmark/__init__.py``), so a new family is a new file.
+
+The contract: a family is an object (a class used as a namespace will do) with
+
+  ``model(config)``                   the program's model: a flax module whose
+                                      ``init(key, sample)`` gives ``params`` (and
+                                      maybe ``batch_stats``) and whose ``apply``
+                                      gives what the step's loss takes
+  ``batch(key, config, traffic, rows)``  (inputs, labels) of ``rows`` rows from
+                                      the key, traceable, every row different
+  ``samples_per_row(traffic)``        samples (images, tokens) a row holds
+  ``expects_kernel(config)``          whether the lowered step has to hold a
+                                      ``tpu_custom_call`` on a TPU
+  ``sample_unit``                     ``"images"``, ``"tokens"``
+  ``throughput_metric``               the end-to-end metric of its rate
+  ``reference``                       the module of its plain reference, under
+                                      ``benchmark/``, with ``build(config, traffic)
+                                      -> (stages, loss_backward)`` for
+                                      ``reference.chain.train_steps``
+
+and, optionally (today's two families have none, so their runs do not change),
+
+  ``step_options(config, traffic)``   keyword arguments for
+                                      ``training.data_parallel_train_step`` that a
+                                      JSON file cannot carry (a ``loss_fn``); the
+                                      traffic file's ``step_options`` go on top.
 """
 
 from __future__ import annotations
@@ -13,7 +40,7 @@ import importlib
 import jax
 import jax.numpy as jnp
 
-from benchmark import flops
+from benchmark import check_module, flops, resolve
 
 _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
@@ -91,18 +118,22 @@ FAMILIES = {"resnet": Resnet, "decoder_lm": DecoderLm}
 
 
 def family(config: dict):
-    try:
-        return FAMILIES[config["family"]]
-    except KeyError:
-        raise KeyError(f"unknown family {config.get('family')!r}; have {sorted(FAMILIES)}")
+    return resolve(config["family"], FAMILIES, "family")
 
 
 def reference(config: dict):
-    return importlib.import_module(family(config).reference)
+    return importlib.import_module(check_module(family(config).reference, "reference"))
+
+
+def step_options(config: dict, traffic: dict) -> dict:
+    """Keyword arguments for ``data_parallel_train_step``: the family's, where
+    it has any, under the traffic file's."""
+    own = getattr(family(config), "step_options", None)
+    return {**(own(config, traffic) if own else {}), **traffic.get("step_options", {})}
 
 
 def flops_per_sample(config: dict, traffic: dict) -> float:
-    return flops.FUNCTIONS[config["flops"]["function"]](config, traffic)
+    return flops.function(config["flops"]["function"])(config, traffic)
 
 
 def optimizer(spec: dict):

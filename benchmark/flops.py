@@ -18,6 +18,8 @@ Pallas calls.
 
 from __future__ import annotations
 
+from benchmark import resolve
+
 
 def _conv_macs(out_hw: int, k: int, cin: int, cout: int) -> int:
     return out_hw * out_hw * k * k * cin * cout
@@ -95,3 +97,12 @@ FUNCTIONS = {
     "decoder_lm_train_flops_per_token": decoder_lm_train_flops_per_token,
     "flash_attention_train_flops_per_step": flash_attention_train_flops_per_step,
 }
+
+
+def function(name: str):
+    """The function a configuration or a metric's file names: one of the
+    above, or ``<module>:<attribute>`` under ``benchmark/``, taking ``(config,
+    traffic)`` for a sample or ``(config, traffic, rows)`` for a kernel's step.
+    What it counts is its own (operations, or bytes against
+    ``hbm_bytes_per_s``)."""
+    return resolve(name, FUNCTIONS, "FLOP function")

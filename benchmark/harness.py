@@ -24,8 +24,11 @@ import math
 import os
 import shutil
 import statistics
+import sys
 import time
 from dataclasses import dataclass, field
+
+from benchmark import check_module, check_name, resolve
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmark")
@@ -74,13 +77,34 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; have {sorted(cells)}")
     w = cells[name]
     (cfg,) = [c for c in bench["configs"] if c["name"] == w["config"]]
-    return Cell(
+    cell = Cell(
         name=name, config_name=w["config"], config=load_json(root, cfg["file"]),
         traffic_name=w["traffic"],
         traffic=load_json(root, "benchmark", "traffic", w["traffic"] + ".json"),
         chips=w["chips"],
         end_to_end=[m["name"] for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m["name"] for m in bench["per_layer"] if _applies(m, name)])
+    check_names(cell, root)
+    return cell
+
+
+def check_names(cell: Cell, root: str = ROOT):
+    """Before any device work: every name of the cell that is not one of a
+    table's (it holds a colon) obeys the rule of ``benchmark/__init__.py``.
+    Nothing is imported but a family of that form, whose ``reference`` has to
+    obey it too; a name with no colon meets its table when the run needs it."""
+    named = [("family", cell.config["family"]),
+             ("FLOP function", cell.config["flops"]["function"])]
+    for metric in cell.per_layer:
+        spec = load_json(root, "benchmark", "metrics", metric + ".json")
+        named.append(("reader", spec["reader"]))
+        if "flops_function" in spec:
+            named.append(("FLOP function", spec["flops_function"]))
+    for what, name in named:
+        if ":" in name:
+            check_name(name, what)
+    if ":" in cell.config["family"]:
+        check_module(resolve(cell.config["family"], {}, "family").reference, "reference")
 
 
 class CompileMeter:
@@ -231,7 +255,7 @@ def prepare(cell: Cell, seed: int, devices, meter: CompileMeter | None = None) -
 
     t = time.perf_counter()
     step = training.data_parallel_train_step(
-        model, optimizer, mesh=mesh, **traffic.get("step_options", {}))
+        model, optimizer, mesh=mesh, **families.step_options(config, traffic))
     lowered = step.lower(state, inputs, labels)
     kernel = None
     if fam.expects_kernel(config) and devices[0].platform == "tpu":
@@ -467,7 +491,7 @@ def per_layer(cell: Cell, readings) -> dict:
     out = {}
     for name in cell.per_layer:
         spec = load_json(HERE, "metrics", name + ".json")
-        value = readers.READERS[spec["reader"]](readings, spec)
+        value = readers.reader(spec["reader"])(readings, spec)
         if value is not None:
             readings.values[name] = value
             out[name] = (value, units[name])
@@ -523,6 +547,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     for r in rows:
         log(f"# check {r['name']}: {r['value']:.6g} (limit {r['limit']}) "
             f"{'ok' if r['ok'] else 'NOT OK'} {r['detail']}")
+    for r in rows:  # the numbers compared, each beside its limit: stderr's last lines
+        print(f"check {r['name']}: {r['value']!r} (limit {r['limit']})", file=sys.stderr)
+    sys.stderr.flush()
 
     result = {"correct": all(r["ok"] for r in rows), "attempted": len(w.losses),
               "failed": failed, "device": dict(record, memory_peak_bytes=int(memory_peak))}
@@ -531,7 +558,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         readings = readers.Readings(
             config=cell.config, traffic=cell.traffic, peaks=peaks, chips=cell.chips,
             rows_per_step=p.rows, spans=p.spans, compile_seconds=p.compile_seconds,
-            trace=t, steps_traced=w.steps_traced)
+            trace=t, trace_dir=w.trace_dir, steps_traced=w.steps_traced)
         result["metrics"] = per_layer(cell, readings)
         result["device"].update(busy_s=tr.busy_ns(t) / 1e9, window_s=tr.window_ns(t) / 1e9)
         result["breakdown"] = {"device_ops": tr.top_ops(t), "idle_gaps": tr.idle_gaps(t)}
@@ -541,4 +568,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     else:
         result["metrics"] = {k: v for k, v in metrics.items() if k in cell.end_to_end}
     result["metrics"] = {k: {"value": v, "unit": u} for k, (v, u) in result["metrics"].items()}
+    # last in the line: each number compared, beside its limit
+    result["checks"] = {
+        r["name"]: {"value": r["value"] if math.isfinite(r["value"]) else str(r["value"]),
+                    "limit": r["limit"]} for r in rows}
     return result

@@ -4,7 +4,8 @@
     JAX_PLATFORMS=cpu python3 benchmark/selftest.py
 
 It drives ``harness.run_cell`` (set-up, window, reference, comparison) for a
-tiny ResNet and a tiny decoder on one virtual CPU device and on four, reduces
+tiny ResNet, a tiny decoder and the worked example of a family brought by new
+files alone (``tests/example_moe/``) on one virtual CPU device and on four, reduces
 the small recorded trace in ``fixtures/`` and compares with the numbers
 recorded beside it, and loads every file under ``configs/``, ``traffic/`` and
 ``metrics/`` against ``BENCHMARK.json`` and the driver's rules for names.
@@ -53,6 +54,7 @@ TINY_TRAFFIC = {
     "resnet": {"samples_per_chip": 8, "span_steps": 2, "trace_steps": 3},
     "decoder_lm": {"samples_per_chip": 2, "seq_len": 64, "span_steps": 2, "trace_steps": 3},
 }
+EXAMPLE = os.path.join(ROOT, "benchmark", "tests", "example_moe")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -68,14 +70,29 @@ def tiny_cell(config: dict, chips: int):
         per_layer=["init_s", "compile_s"])
 
 
+def example_cell(chips: int):
+    """The worked example's cell: its configuration (with its own limits) and
+    traffic from its own files, every name of the ``<module>:<attribute>`` form."""
+    from benchmark import harness
+
+    cell = harness.Cell(
+        name=f"example-moe-{chips}", config_name="example_moe",
+        config=harness.load_json(EXAMPLE, "config.json"), traffic_name="example_moe",
+        traffic=harness.load_json(EXAMPLE, "traffic.json"), chips=chips,
+        end_to_end=["setup_s", "train_tokens_per_s", "step_ms_p90", "mfu"], per_layer=[])
+    harness.check_names(cell)
+    return cell
+
+
 def check_cells():
     import jax
 
     from benchmark import harness
 
-    for config in (TINY_RESNET, TINY_LM):
+    for make in (lambda n: tiny_cell(TINY_RESNET, n), lambda n: tiny_cell(TINY_LM, n),
+                 example_cell):
         for chips in (1, 4):
-            cell = tiny_cell(config, chips)
+            cell = make(chips)
             result = harness.run_cell(cell, seed=2 ** 31 + 11, seconds=0.5,
                                       trace=False, devices=jax.devices()[:chips])
             assert result["correct"], result
@@ -113,8 +130,18 @@ def check_trace_reducer():
     assert tr.matching_ns(t, "^all-reduce") == 20.0 and tr.exposed_ns(t, "^all-reduce") == 8.0
 
 
+def check_spec_names(spec: dict):
+    """A metric's file: its reader and its FLOP function are in the harness's
+    tables, or of the new form with the module a file under ``benchmark/``."""
+    from benchmark import check_name, flops, readers
+
+    for key, table in (("reader", readers.READERS), ("flops_function", flops.FUNCTIONS)):
+        if key in spec and spec[key] not in table:
+            check_name(spec[key], key)
+
+
 def check_files():
-    from benchmark import families, flops, harness, readers
+    from benchmark import families, harness
 
     bench = harness.load_json(ROOT, "BENCHMARK.json")
     for w in bench["workloads"]:
@@ -137,13 +164,14 @@ def check_files():
         assert m["better"] in ("lower", "higher")
     for m in bench["per_layer"]:
         spec = harness.load_json(ROOT, "benchmark", "metrics", m["name"] + ".json")
-        assert spec["reader"] in readers.READERS, m["name"]
-        if "flops_function" in spec:
-            assert spec["flops_function"] in flops.FUNCTIONS
+        assert "reader" in spec, m["name"]
+        check_spec_names(spec)
     for sub in ("configs", "traffic", "metrics"):
         for name in os.listdir(os.path.join(ROOT, "benchmark", sub)):
             harness.load_json(ROOT, "benchmark", sub, name)
             assert NAME.match(name), name
+    for name in sorted(os.listdir(os.path.join(EXAMPLE, "metrics"))):
+        check_spec_names(harness.load_json(EXAMPLE, "metrics", name))
     print("ok files")
 
 

@@ -14,8 +14,20 @@ configuration's ``init`` block and the seed:
   ``kernel``, other -> normal(0, ``dense_std``), or 1/sqrt(fan in) when that
                    is null (fan in: every axis but the last)
 
+A family whose leaves these rules do not cover (another name, or a fan in that
+is not "every axis but the last": a stack of experts) states its own, as data,
+under ``init.rules``: a list of ``{"match": <regex on the leaf's path>, ...}``
+tried in order before the rules above, the first match giving
+
+  ``"constant": c``            -> c everywhere
+  ``"std": s``                 -> normal(0, s)
+  ``"fan_in_axes": [axes]``    -> normal(0, ``gain`` / sqrt(product of those
+                                 axes' sizes)); ``gain`` defaults to 1.  A stacked
+                                 (experts, d, f) tensor's fan in is axis 1.
+
 Each leaf's key is the seed's key folded with the leaf's index, so a leaf
-can be made again alone.
+can be made again alone; with no ``rules`` a leaf's values are what they
+were before rules existed.
 """
 
 from __future__ import annotations
@@ -36,7 +48,22 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
 
 
+def _ruled(key, shape, dtype, rule: dict):
+    if "constant" in rule:
+        return jnp.full(shape, rule["constant"], dtype)
+    if "std" in rule:
+        std = rule["std"]
+    elif "fan_in_axes" in rule:
+        std = rule.get("gain", 1.0) / math.sqrt(math.prod(shape[a] for a in rule["fan_in_axes"]))
+    else:
+        raise ValueError(f"init rule {rule} gives no constant, std or fan_in_axes")
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
 def _leaf(key, path: str, shape, dtype, init: dict):
+    for rule in init.get("rules", ()):
+        if re.search(rule["match"], path):
+            return _ruled(key, shape, dtype, rule)
     name = path.rsplit("/", 1)[-1]
     if name == "scale":
         zero = init.get("zero_scale") and re.search(init["zero_scale"], path)
@@ -50,7 +77,8 @@ def _leaf(key, path: str, shape, dtype, init: dict):
     elif name == "kernel":
         std = init.get("dense_std") or 1.0 / math.sqrt(math.prod(shape[:-1]))
     else:
-        raise ValueError(f"no rule for a parameter leaf named {name!r}")
+        raise ValueError(f"no rule for a parameter leaf named {name!r} ({path}); state "
+                         f"one under init.rules in the configuration's file")
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
