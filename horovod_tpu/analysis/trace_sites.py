@@ -19,8 +19,9 @@ Checked equivalences:
   directions).
 
 The names the program gives its DEVICE work are held together the same
-way (``DEVICE_SCOPES`` and ``DEVICE_KERNELS`` beside ``SITES``): every
-``named_scope("...")`` literal names a catalogued scope, every
+way (``DEVICE_SCOPES``, ``DEVICE_SUBSCOPES`` and ``DEVICE_KERNELS`` beside
+``SITES``): every ``named_scope("...")`` literal names a catalogued scope
+or sub-scope, every
 ``pallas_call(..., name="...")`` literal a catalogued kernel (and no
 kernel of ops/flash_attention.py goes unnamed — an unnamed one reads as
 the enclosing jit's name in a capture, the same for every kernel), every
@@ -61,10 +62,13 @@ _KERNEL_RE = re.compile(
     r"pallas_call\((?:[^()]|\([^()]*\))*?\bname\s*=\s*[\"']([a-z0-9_]+)[\"']")
 _DEVICE_NAMES = (
     ("DEVICE_SCOPES", "scope", _SCOPE_RE),
+    # parts of a phase: the same literal, a tuple and a docs kind of their own
+    ("DEVICE_SUBSCOPES", "subscope", _SCOPE_RE),
     ("DEVICE_KERNELS", "kernel", _KERNEL_RE),
 )
 _DEVICE_ROW_RE = re.compile(
-    r"^\|\s*`([a-z0-9_]+)`\s*\|\s*(scope|kernel)\s*\|", re.MULTILINE)
+    r"^\|\s*`([a-z0-9_]+)`\s*\|\s*(scope|subscope|kernel)\s*\|",
+    re.MULTILINE)
 
 
 def catalogue(root: str, name: str = "SITES") -> Dict[str, int]:
@@ -173,11 +177,17 @@ def _device_names(root: str, sources: Dict[str, str],
 
     for tuple_name, kind, call_re in _DEVICE_NAMES:
         names = catalogue(root, tuple_name)
+        # a literal is catalogued if ANY tuple read by the same pattern
+        # holds it (a named_scope is a phase or a part of one)
+        siblings: Set[str] = set()
+        for other, _, other_re in _DEVICE_NAMES:
+            if other_re is call_re and other != tuple_name:
+                siblings |= set(catalogue(root, other))
         used: Set[str] = set()
         for rel, text in sources.items():
             for m in call_re.finditer(text):
                 used.add(m.group(1))
-                if m.group(1) not in names:
+                if m.group(1) not in names and m.group(1) not in siblings:
                     findings.append(Finding(
                         CHECK, rel, text.count("\n", 0, m.start()) + 1,
                         m.group(1),

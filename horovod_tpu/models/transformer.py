@@ -152,6 +152,39 @@ class TransformerConfig:
     # size (validated at trace).  Inference-first: the serving engine
     # is the consumer; training paths keep using parallel/sharded.py.
     shard_axis: Optional[str] = None
+    # Width of the residual stream when it is not num_heads * head_dim
+    # (a model whose attention is wider than its residual: 32 heads of
+    # 128 on a 2048-wide stream).  None = num_heads * head_dim.
+    hidden_size: Optional[int] = None
+    # What used to be fixed in code; the defaults are those values.
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    # False = an output matrix of its own (``head/kernel``, logits in
+    # float32) instead of the embedding's transpose.
+    tie_word_embeddings: bool = True
+    # RMSNorm over head_dim with a learned scale on q and on k, before
+    # RoPE (the Qwen3 family).
+    qk_norm: bool = False
+    # Routed feed-forward (parallel/moe.py RoutedExperts): a router over
+    # ``num_experts`` SwiGLU experts of width ``moe_intermediate_size``,
+    # ``num_experts_per_tok`` a token, of which this chip holds
+    # ``held_experts = (first, count)`` (None: all).  None = the dense
+    # MlpBlock.  With it the model returns ``(logits, aux)``: ``aux`` has
+    # the router's ``aux_loss`` (mean over layers) for the step's loss,
+    # the counters ``expert_assignments`` (sum over layers),
+    # ``expert_load_max_over_mean`` (largest over layers),
+    # ``dropped_assignments`` (sum; 0) and ``expert_index`` (layers, T, k).
+    num_experts: Optional[int] = None
+    num_experts_per_tok: int = 1
+    moe_intermediate_size: Optional[int] = None
+    held_experts: Optional[Any] = None
+    # Block-diffusion training (BD3-LM, arXiv:2503.09573; SDAR): the
+    # block length B.  The input is ``[noisy || clean]``, two copies of L
+    # positions; positions run ``[0..L) || [0..L)``; attention takes the
+    # block-diffusion mask (``block_diffusion_mask``) in place of
+    # ``causal``/``window``; the head runs on the noisy half only, so the
+    # logits are (B, L, V).  'dot' and 'flash' attention only.
+    block_diffusion: Optional[int] = None
 
     def __post_init__(self):
         kv = self.num_kv_heads
@@ -169,6 +202,22 @@ class TransformerConfig:
                 else tuple(self.remat_policy),
             )
             resolve_remat_policies(self.remat_policy, self.num_layers)
+        if self.held_experts is not None:
+            object.__setattr__(
+                self, "held_experts", tuple(int(v) for v in self.held_experts))
+        if self.num_experts is not None and not self.moe_intermediate_size:
+            raise ValueError("num_experts needs moe_intermediate_size")
+        if self.block_diffusion is not None:
+            if self.block_diffusion < 1:
+                raise ValueError(
+                    f"block_diffusion is a block length >= 1, got "
+                    f"{self.block_diffusion}")
+            if self.attention_impl not in ("dot", "flash"):
+                raise ValueError(
+                    "block_diffusion supports attention_impl 'dot'/'flash', "
+                    f"not {self.attention_impl!r}")
+            if self.window is not None:
+                raise ValueError("block_diffusion takes no window")
 
     def block_remat_policies(self):
         """Per-block policy names (``remat_policy`` resolved, with the
@@ -180,13 +229,16 @@ class TransformerConfig:
 
     @property
     def d_model(self) -> int:
+        if self.hidden_size is not None:
+            return self.hidden_size
         return self.num_heads * self.head_dim
 
 
-def rope(x: jax.Array, positions: jax.Array) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array,
+         theta: float = 10000.0) -> jax.Array:
     """Rotary position embedding; x: (B, S, H, D), positions: (B, S)."""
     d = x.shape[-1]
-    freqs = 1.0 / (10000.0 ** (np.arange(0, d, 2) / d))
+    freqs = 1.0 / (theta ** (np.arange(0, d, 2) / d))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -214,8 +266,22 @@ def sliding_mask(q_pos, k_pos, causal=True, window=None):
     return mask
 
 
+def block_diffusion_mask(half: int, block: int):
+    """(2L, 2L) bool mask of block-diffusion training over ``[noisy ||
+    clean]``, ``L = half`` positions each in blocks of ``block``: with
+    ``b(i) = i // block``, noisy -> noisy iff ``b(j) == b(i)``; noisy ->
+    clean iff ``b(j) < b(i)``; clean -> clean iff ``b(j) <= b(i)``; clean
+    -> noisy never.  Every row sees at least itself.  The flash kernels
+    compute the same mask tile by tile (ops/flash_attention.py)."""
+    pos = np.arange(2 * half)
+    clean = pos >= half
+    blk = np.where(clean, pos - half, pos) // block
+    qc, kc, qb, kb = clean[:, None], clean[None, :], blk[:, None], blk[None, :]
+    return np.where(qc, kc & (kb <= qb), np.where(kc, kb < qb, kb == qb))
+
+
 def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
-                         window=None):
+                         window=None, mask=None):
     """Standard attention; offsets support sequence-sharded blocks.
 
     q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv | H — under GQA
@@ -226,7 +292,8 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
     MXU in bf16.  ``causal=False`` is the bidirectional (encoder /
     BERT-family) form — no mask at all.  ``window``: Mistral-style
     sliding window — each token attends the last ``window`` positions,
-    itself included (see ``sliding_mask``).
+    itself included (see ``sliding_mask``).  ``mask``: an explicit (Sq, Sk)
+    bool mask (``block_diffusion_mask``) in place of ``causal``/``window``.
     """
     b, s_q, h, d = q.shape
     s_k, h_kv = k.shape[1], k.shape[2]
@@ -243,7 +310,9 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d).astype(
             q.dtype)
     logits = logits.astype(jnp.float32)
-    if causal or window is not None:
+    if mask is not None:
+        logits = jnp.where(mask[None, None], logits, -1e30)
+    elif causal or window is not None:
         mask = sliding_mask(
             q_offset + jnp.arange(q.shape[1]),
             k_offset + jnp.arange(k.shape[1]),
@@ -302,8 +371,15 @@ class Attention(nn.Module):
         q = dense(features=(heads, cfg.head_dim), name="q")(x)
         k = dense(features=(kv_heads, cfg.head_dim), name="k")(x)
         v = dense(features=(kv_heads, cfg.head_dim), name="v")(x)
-        q = rope(q, positions)
-        k = rope(k, positions)
+        if cfg.qk_norm:
+            q = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                           name="k_norm")(k)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        if paged is not None and cfg.block_diffusion is not None:
+            raise ValueError("paged serving takes no block_diffusion model")
         if paged is not None:
             # serving path (docs/SERVING.md): K/V live in the paged
             # cache's block pools, not in this activation.  Chunk (the
@@ -343,6 +419,21 @@ class Attention(nn.Module):
         # its query-head group, so the group factor is saved in
         # attention HBM bytes, FLOPs and ring comms, not just in the
         # projections.
+        elif cfg.block_diffusion is not None:
+            half = x.shape[1] // 2
+            if x.shape[1] != 2 * half:
+                raise ValueError(
+                    "block_diffusion takes [noisy || clean], an even "
+                    f"number of rows, got {x.shape[1]}")
+            if cfg.attention_impl == "flash":
+                from ..ops.flash_attention import flash_attention
+
+                out = flash_attention(
+                    q, k, v, block_diffusion=(half, cfg.block_diffusion))
+            else:
+                out = causal_dot_attention(
+                    q, k, v,
+                    mask=block_diffusion_mask(half, cfg.block_diffusion))
         elif cfg.attention_impl in ("ring", "ring_flash"):
             from ..parallel.ring_attention import ring_attention
 
@@ -405,16 +496,29 @@ class Block(nn.Module):
     def __call__(self, x, positions, paged=None, layer: int = 0):
         cfg = self.cfg
         norm = functools.partial(
-            nn.RMSNorm, dtype=cfg.dtype, epsilon=1e-5
+            nn.RMSNorm, dtype=cfg.dtype, epsilon=cfg.rms_norm_eps
         )
         x = x + Attention(cfg, name="attn")(
             norm(name="ln1")(x), positions, paged=paged, layer=layer)
-        x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
-        return x
+        if cfg.num_experts is None:
+            x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
+            return x
+        # routed feed-forward: (x, the layer's routing statistics)
+        from ..parallel.moe import RoutedExperts
+
+        y, stats = RoutedExperts(
+            num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
+            d_model=cfg.d_model, d_ff=cfg.moe_intermediate_size,
+            held=cfg.held_experts, dtype=cfg.dtype, name="moe",
+        )(norm(name="ln2")(x))
+        return x + y, stats
 
 
 class Transformer(nn.Module):
-    """Decoder-only LM.  ``__call__(tokens, positions=None) -> logits``."""
+    """Decoder-only LM.  ``__call__(tokens, positions=None) -> logits``;
+    ``(logits, aux)`` with a routed feed-forward (``cfg.num_experts``); with
+    ``cfg.block_diffusion`` the tokens are ``[noisy || clean]`` (B, 2L) and
+    the logits those of the noisy half, (B, L, V)."""
 
     cfg: TransformerConfig
 
@@ -422,6 +526,13 @@ class Transformer(nn.Module):
     def __call__(self, tokens, positions=None, train: bool = True,
                  paged=None):
         cfg = self.cfg
+        routed = cfg.num_experts is not None
+        if paged is not None and routed:
+            raise ValueError("paged serving takes no routed feed-forward")
+        if positions is None and cfg.block_diffusion is not None:
+            half = jnp.arange(tokens.shape[1] // 2)
+            positions = jnp.broadcast_to(
+                jnp.concatenate([half, half]), tokens.shape)
         if positions is None:
             local = jnp.arange(tokens.shape[1])
             if cfg.attention_impl in ("ring", "ring_flash") and \
@@ -444,6 +555,7 @@ class Transformer(nn.Module):
         # transform
         policies = cfg.block_remat_policies() if train else None
         block_cls_for = {"none": Block}
+        layer_stats = []
         for i in range(cfg.num_layers):
             pol = policies[i] if policies is not None else "none"
             block_cls = block_cls_for.get(pol)
@@ -460,11 +572,56 @@ class Transformer(nn.Module):
                     x, positions, paged, i)
             else:
                 x = block_cls(cfg, name=f"layer_{i}")(x, positions)
-        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=1e-5, name="ln_f")(x)
-        logits = emb.attend(x.astype(jnp.float32))
+            if routed:
+                x, stats = x
+                layer_stats.append(stats)
+        if cfg.block_diffusion is not None:
+            x = x[:, : x.shape[1] // 2]  # the head on the noisy half only
+        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                       name="ln_f")(x)
+        if cfg.tie_word_embeddings:
+            logits = emb.attend(x.astype(jnp.float32))
+        else:
+            logits = nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="head",
+                dot_general=functools.partial(
+                    jax.lax.dot_general,
+                    preferred_element_type=jnp.float32),
+            )(x)
         if paged is not None:
             return logits, paged
+        if routed:
+            per = {k: jnp.stack([s[k] for s in layer_stats])
+                   for k in layer_stats[0]}
+            return logits, {
+                "aux_loss": jnp.mean(per["aux_loss"]),
+                "expert_assignments": jnp.sum(per["assigned"]),
+                "expert_load_max_over_mean": jnp.max(
+                    per["load_max_over_mean"]),
+                "dropped_assignments": jnp.sum(per["dropped"]),
+                "expert_index": per["expert_index"],
+            }
         return logits
+
+
+def block_diffusion_loss(outputs, labels, aux_coef: float = 0.0):
+    """The masked-diffusion loss of block-diffusion training, a ``loss_fn``
+    for the train step: ``outputs`` is the model's ``(logits, aux)`` (or the
+    logits alone), logits (B, L, V) of the noisy half; ``labels`` is
+    ``(targets, weights)``, both (B, L): the clean tokens, and for each
+    position ``masked / t`` of its block (0 where the position was not
+    masked).  Loss = mean over the L positions of weight x cross-entropy,
+    in float32, plus ``aux_coef`` x the router's auxiliary loss."""
+    import optax
+
+    logits, aux = outputs if isinstance(outputs, tuple) else (outputs, None)
+    targets, weights = labels
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), targets)
+    loss = jnp.mean(weights.astype(jnp.float32) * ce)
+    if aux is not None and aux_coef:
+        loss = loss + aux_coef * aux["aux_loss"]
+    return loss
 
 
 def overlap_segments(model: "Transformer", tokens, targets,
@@ -491,6 +648,11 @@ def overlap_segments(model: "Transformer", tokens, targets,
     from ..ops.overlap import Segment
 
     cfg = model.cfg
+    if cfg.num_experts is not None or cfg.block_diffusion is not None \
+            or not cfg.tie_word_embeddings:
+        raise ValueError(
+            "overlap_segments has no chain for a routed feed-forward, "
+            "block diffusion or an untied head; use the plain step")
     if cfg.attention_impl in ("ring", "ring_flash"):
         raise ValueError(
             "overlap_segments does not support the sequence-sharded ring "
@@ -524,7 +686,7 @@ def overlap_segments(model: "Transformer", tokens, targets,
         return Segment(seg, keys=(f"layer_{i}",))
 
     def seg_head(params, x):
-        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=1e-5).apply(
+        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps).apply(
             {"params": params["ln_f"]}, x
         )
         logits = embed_mod.apply(

@@ -97,9 +97,94 @@ def _kb_range(q_off, block_q, block_k, padded_kb, causal, window, kv_off=0):
     return lo, hi
 
 
+def _bd_tile_mask(row_off, col_off, rows, cols, seq_len, bd, cols_are_keys):
+    """(rows, cols) bool mask of the block-diffusion kind (Arriola et al.,
+    BD3-LM, arXiv:2503.09573, the vectorised training input): the sequence
+    is ``[noisy || clean]``, two copies of ``L`` positions each in blocks of
+    ``B``; with ``b(i) = i // B`` a query sees, noisy -> noisy its own block,
+    noisy -> clean the blocks before its own, clean -> clean the blocks up to
+    its own, clean -> noisy nothing.  ``bd = (L, B)``, ``seq_len = 2 L``.
+    Block ids are taken on a column and on a row vector and only compared
+    at tile size.  ``cols_are_keys=False`` is the transposed tile (keys on
+    the rows) of the dK/dV kernel.  Rows and columns past ``seq_len`` (the
+    padding) are masked on both sides."""
+    half, blk = bd
+    r = row_off + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    c = col_off + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    q, k = (r, c) if cols_are_keys else (c, r)
+    q_clean, k_clean = q >= half, k >= half
+    qb = jnp.floor_divide(jnp.where(q_clean, q - half, q), blk)
+    kb = jnp.floor_divide(jnp.where(k_clean, k - half, k), blk)
+    q_real, k_real = q < seq_len, k < seq_len
+    # two integer comparisons at tile size (Mosaic selects no booleans):
+    # a clean key up to the query's bound (its own block for a clean query,
+    # the one before for a noisy one), or a noisy key of a noisy query's own
+    # block; padding and the other kinds get codes that never compare true
+    bound = jnp.where(q_real, qb - 1 + q_clean.astype(jnp.int32), -1)
+    own = jnp.where(jnp.logical_and(q_real, jnp.logical_not(q_clean)), qb, -2)
+    k_as_clean = jnp.where(jnp.logical_and(k_real, k_clean), kb, 2 ** 30)
+    k_as_noisy = jnp.where(jnp.logical_or(k_clean, jnp.logical_not(k_real)),
+                           -1, kb)
+    return jnp.logical_or(k_as_clean <= bound, k_as_noisy == own)
+
+
+def _bd_ranges(off, rows, other_block, n_other, seq_len, bd, rows_are_queries):
+    """Loop bounds, in tiles of ``other_block`` positions, over the other
+    side of the block-diffusion mask for the tile of ``rows`` positions at
+    ``off``: two half-open tile ranges ``((lo1, hi1), (lo2, hi2))``, the
+    first inside the noisy half and the second inside the clean half, the
+    second starting no earlier than the first ends (a tile is never visited
+    twice).  They cover every allowed pair and little else: of the (2L)^2
+    score tiles only about L^2 + L B entries are allowed, and the tiles
+    outside these ranges are never computed.  Either range may be empty."""
+    half, blk = bd
+    first = off
+    last = jnp.minimum(off + rows, seq_len) - 1   # last real position
+    has_noisy = first < half
+    has_clean = last >= half
+    n0 = first                                    # noisy positions [n0, n1]
+    n1 = jnp.minimum(last, half - 1)
+    c0 = jnp.maximum(first, half) - half          # clean positions [c0, c1]
+    c1 = last - half
+    own_lo = jnp.floor_divide(n0, blk) * blk      # own blocks of the noisy rows
+    own_hi = jnp.minimum(half, jnp.floor_divide(n1, blk) * blk + blk)
+    if rows_are_queries:
+        # noisy keys: the noisy queries' own blocks
+        a_lo, a_hi = own_lo, jnp.where(has_noisy, own_hi, own_lo)
+        # clean keys: before the last noisy query's block, up to and with
+        # the last clean query's block
+        upto = jnp.maximum(
+            jnp.where(has_noisy, jnp.floor_divide(n1, blk) * blk, 0),
+            jnp.where(has_clean, jnp.floor_divide(c1, blk) * blk + blk, 0))
+        b_lo, b_hi = half, half + jnp.minimum(upto, half)
+    else:
+        # noisy queries: the noisy keys' own blocks, and for clean keys
+        # every block after the first clean key's
+        after = jnp.minimum(half, jnp.floor_divide(c0, blk) * blk + blk)
+        a_lo = jnp.where(
+            has_noisy,
+            jnp.where(has_clean, jnp.minimum(own_lo, after), own_lo), after)
+        a_hi = jnp.where(has_clean, half, own_hi)
+        # clean queries: from the first clean key's block on
+        b_lo = half + jnp.floor_divide(c0, blk) * blk
+        b_hi = jnp.where(has_clean, seq_len, b_lo)
+
+    def tiles(lo, hi):
+        t_lo = jnp.floor_divide(lo, other_block)
+        t_hi = jnp.minimum(
+            jnp.floor_divide(hi + other_block - 1, other_block), n_other)
+        some = hi > lo      # an empty range is (0, 0)
+        return jnp.where(some, t_lo, 0), jnp.where(some, t_hi, 0)
+
+    lo1, hi1 = tiles(a_lo, a_hi)
+    lo2, hi2 = tiles(b_lo, b_hi)
+    lo2 = jnp.maximum(lo2, hi1)
+    return (lo1, hi1), (lo2, jnp.maximum(hi2, lo2))
+
+
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
                 causal, block_q, block_k, seq_len, window=None,
-                off_div=None):
+                off_div=None, bd=None):
     qi = pl.program_id(1)
     # off_div=None: one kv_offset for the whole grid (self/ring blocks).
     # off_div=H: kvoff_ref holds one offset PER BATCH ROW and grid row bh
@@ -123,13 +208,17 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        q_pos = q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = k_off + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off)
+        if bd is None:
+            q_pos = q_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            )
+            k_pos = k_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            mask = _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off)
+        else:
+            mask = _bd_tile_mask(q_off, k_off, block_q, block_k, seq_len,
+                                 bd, True)
         s = jnp.where(mask, s, _NEG_INF)
         new_m = jnp.maximum(m, jnp.max(s, axis=-1))
         # explicit zeroing: a fully-masked row keeps new_m at the -inf
@@ -148,9 +237,14 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
     l = jnp.zeros((block_q,), jnp.float32)
     m = jnp.full((block_q,), _NEG_INF, jnp.float32)
     padded_len = k_ref.shape[1]
-    lo_kb, n_kb = _kb_range(q_off, block_q, block_k,
-                            padded_len // block_k, causal, window, kv_off)
-    acc, l, m = jax.lax.fori_loop(lo_kb, n_kb, body, (acc, l, m))
+    if bd is None:
+        ranges = (_kb_range(q_off, block_q, block_k,
+                            padded_len // block_k, causal, window, kv_off),)
+    else:
+        ranges = _bd_ranges(q_off, block_q, block_k, padded_len // block_k,
+                            seq_len, bd, True)
+    for lo_kb, n_kb in ranges:
+        acc, l, m = jax.lax.fori_loop(lo_kb, n_kb, body, (acc, l, m))
     # rows past the true sequence (or wholly out of window) are
     # all-masked (l == 0): emit zeros
     safe_l = jnp.where(l > 0, l, 1.0)
@@ -206,7 +300,7 @@ def _off_arr(kv_offset):
 
 
 def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
-                  with_lse=False, window=None, kv_offset=None):
+                  with_lse=False, window=None, kv_offset=None, bd=None):
     b, s, h, d = q.shape
     group = _group_of(q, k)
     h_kv = h // group
@@ -229,6 +323,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
         block_k=block_k,
         seq_len=orig_s,
         window=window,
+        bd=bd,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -264,7 +359,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
 
 
 def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
-                 seq_len, block_q, block_k, window=None, kv_off=0):
+                 seq_len, block_q, block_k, window=None, kv_off=0, bd=None):
     """Exact softmax probabilities of one (block_q, block_k) tile from
     the saved logsumexp — shared by both backward kernels.  Masked
     entries are zeroed EXPLICITLY (not via the lse sentinel), so padded
@@ -274,6 +369,10 @@ def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    if bd is not None:
+        mask = _bd_tile_mask(q_off, k_off, block_q, block_k, seq_len, bd,
+                             True)
+        return jnp.where(mask, jnp.exp(s - lse_blk[:, None]), 0.0)
     q_pos = q_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -289,7 +388,7 @@ def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
 
 def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, *, sm_scale, causal, block_q,
-                   block_k, seq_len, window=None):
+                   block_k, seq_len, window=None, bd=None):
     qi = pl.program_id(1)
     kv_off = kvoff_ref[0]
     q_off = qi * block_q
@@ -305,7 +404,7 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         p = _recompute_p(
             q, k_blk, lse, q_off, k_off, sm_scale=sm_scale, causal=causal,
             seq_len=seq_len, block_q=block_q, block_k=block_k,
-            window=window, kv_off=kv_off,
+            window=window, kv_off=kv_off, bd=bd,
         )
         dp = jax.lax.dot_general(
             do, v_blk.astype(jnp.float32),
@@ -319,12 +418,16 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    lo_kb, n_kb = _kb_range(q_off, block_q, block_k,
+    if bd is None:
+        ranges = (_kb_range(q_off, block_q, block_k,
                             k_ref.shape[1] // block_k, causal, window,
-                            kv_off)
-    dq = jax.lax.fori_loop(
-        lo_kb, n_kb, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    )
+                            kv_off),)
+    else:
+        ranges = _bd_ranges(q_off, block_q, block_k,
+                            k_ref.shape[1] // block_k, seq_len, bd, True)
+    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    for lo_kb, n_kb in ranges:
+        dq = jax.lax.fori_loop(lo_kb, n_kb, body, dq)
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
@@ -397,9 +500,75 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _bwd_dkv_bd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block_q,
+                       block_k, seq_len, bd, group):
+    """dK/dV under the block-diffusion mask, for ONE kv head's K block and
+    ONE query head of its group a program: the grid's last axis walks the
+    group and the (block_k, d) sums live in VMEM scratch across it, so only
+    one query head's rows are resident at a time (the causal kernel above
+    holds the whole group: at 8 query heads a kv head and 8,192 rows that is
+    beyond the chip's VMEM).  Tiles are computed transposed, keys on the
+    rows, so the per-query ``lse`` and ``delta`` arrive as row vectors
+    ((1, 1, s_q) blocks, not lane-padded columns)."""
+    ki, g = pl.program_id(1), pl.program_id(2)
+    k_off = ki * block_k
+
+    @pl.when(g == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    k_blk = k_ref[0]
+    v_blk = v_ref[0]
+
+    def body(qb, carry):
+        dk, dv = carry
+        q_off = qb * block_q
+        q_blk = q_ref[0, pl.ds(q_off, block_q), :]
+        do_blk = do_ref[0, pl.ds(q_off, block_q), :]
+        lse_blk = lse_ref[0, :, pl.ds(q_off, block_q)]      # (1, block_q)
+        delta_blk = delta_ref[0, :, pl.ds(q_off, block_q)]
+        st = jax.lax.dot_general(                           # (block_k, block_q)
+            k_blk, q_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale
+        mask = _bd_tile_mask(k_off, q_off, block_k, block_q, seq_len, bd,
+                             False)
+        pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
+        dv = dv + jax.lax.dot_general(
+            pt, do_blk.astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            v_blk, do_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dst = pt * (dpt - delta_blk)
+        dk = dk + jax.lax.dot_general(
+            dst, q_blk.astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dk, dv
+
+    carry = (dk_acc[...], dv_acc[...])
+    for lo_qb, n_qb in _bd_ranges(k_off, block_k, block_q,
+                                  q_ref.shape[1] // block_q, seq_len, bd,
+                                  False):
+        carry = jax.lax.fori_loop(lo_qb, n_qb, body, carry)
+    dk_acc[...], dv_acc[...] = carry
+
+    @pl.when(g == group - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
 def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
                      block_q, block_k, interpret, window=None,
-                     kv_offset=None):
+                     kv_offset=None, bd=None):
     """Backward kernels over already folded+padded operands — the ring
     calls this directly so the fold/pad of the step-invariant q/g/lse/
     delta happens once, not once per ring step.  Shapes: qf/gf
@@ -419,7 +588,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     kw = dict(sm_scale=1.0 / (d ** 0.5), causal=causal, block_q=block_q,
               block_k=block_k, seq_len=orig_s, window=window)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw),
+        functools.partial(_bwd_dq_kernel, bd=bd, **kw),
         name="flash_attention_bwd_dq",
         grid=(bh, s_q // block_q),
         in_specs=[
@@ -435,6 +604,40 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qf.dtype),
         interpret=interpret,
     )(off, qf, kf, vf, gf, lse_f, delta_f)
+    if bd is not None:
+        # one query head a program, the group on the grid's last axis
+        row = lambda x: x.reshape(bh, 1, s_q)
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_dkv_bd_kernel, sm_scale=kw["sm_scale"], block_q=block_q,
+                block_k=block_k, seq_len=orig_s, bd=bd, group=group),
+            name="flash_attention_bwd_dkv_bd",
+            grid=(bh_kv, s_k // block_k, group),
+            in_specs=[
+                pl.BlockSpec((1, s_q, d),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, s_q, d),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, 1, s_q),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, 1, s_q),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
+                jax.ShapeDtypeStruct((bh_kv, s_k, d), vf.dtype),
+            ],
+            scratch_shapes=[_pltpu.VMEM((block_k, d), jnp.float32),
+                            _pltpu.VMEM((block_k, d), jnp.float32)],
+            interpret=interpret,
+        )(qf, kf, vf, gf, row(lse_f), row(delta_f))
+        return dq, dk, dv
     # dK/dV per KV head: regroup the q-side operands so each kv-head
     # program sees its whole query-head group on the row axis — a free
     # reshape of the head-major fold (B, H_kv, G, s_q, d contiguity)
@@ -485,7 +688,7 @@ def _fold_bwd_invariants(q, out, lse, g, block_q):
 
 
 def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
-                   interpret, window=None):
+                   interpret, window=None, bd=None):
     b, s, h, d = q.shape
     h_kv = k.shape[2]
     orig_s = s
@@ -500,7 +703,7 @@ def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
     dq, dk, dv = _backward_folded(
         qf, kf, vf, gf, lse_f, delta_f, orig_s=orig_s, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window,
+        window=window, bd=bd,
     )
     dq = _unfold(dq, b, h, s_q, d)[:, :orig_s]
     dk = _unfold(dk, b, h_kv, s_k, d)[:, :orig_s]
@@ -679,21 +882,22 @@ def flash_decode_attention(q, k, v, kv_lens, *, window=None, kv_start=None,
         block_q=block_q, block_k=block_k, interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, window, bd=None):
     return _forward_impl(q, k, v, causal, block_q, block_k, interpret,
-                         window=window)
+                         window=window, bd=bd)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window, bd):
     out, lse = _forward_impl(
         q, k, v, causal, block_q, block_k, interpret, with_lse=True,
-        window=window,
+        window=window, bd=bd,
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, window, residuals, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, bd, residuals,
+               g):
     # FlashAttention-2-style backward: two pallas kernels (dq; dk+dv)
     # recompute the probability tiles from the forward's saved logsumexp
     # — no (S x S) materialization, so training keeps the memory win too.
@@ -701,7 +905,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, window, residuals, g):
     q, k, v, out, lse = residuals
     return _backward_impl(
         q, k, v, out, lse, g, causal, block_q, block_k, interpret,
-        window=window,
+        window=window, bd=bd,
     )
 
 
@@ -710,7 +914,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window",
+                     "block_diffusion"),
 )
 def flash_attention(
     q: jax.Array,
@@ -721,6 +926,7 @@ def flash_attention(
     block_k: int = 256,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[tuple] = None,
 ) -> jax.Array:
     """Flash attention over (B, S, H, D) tensors (same layout and
     numerics contract as ``models.transformer.causal_dot_attention``:
@@ -746,10 +952,26 @@ def flash_attention(
     bidirectional).  Blocks wholly outside the window are SKIPPED, so
     compute drops from O(S²) to O(S·window) — unlike the mask-level
     window on the dot path, which still does the full-matrix work.
+
+    ``block_diffusion=(L, B)``: the block-diffusion mask kind (see
+    ``_bd_tile_mask``) over a sequence of ``S = 2 L`` rows, ``[noisy ||
+    clean]`` in blocks of ``B``; takes the place of ``causal`` and
+    ``window``.  The three kernels visit only the tiles the mask reaches
+    (``_bd_ranges``): about ``L^2 + L B`` of the ``4 L^2`` entries.
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
     if k.shape != v.shape:
         raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
     _group_of(q, k)  # validate the GQA head split early
-    return _flash(q, k, v, causal, block_q, block_k, interpret, window)
+    if block_diffusion is None:
+        return _flash(q, k, v, causal, block_q, block_k, interpret, window)
+    half, blk = (int(x) for x in block_diffusion)
+    if window is not None:
+        raise ValueError("block_diffusion takes no window")
+    if half < 1 or blk < 1 or q.shape[1] != 2 * half or k.shape[1] != 2 * half:
+        raise ValueError(
+            f"block_diffusion=(L, B) needs L, B >= 1 and 2 L = {2 * half} "
+            f"rows of queries and keys, got {q.shape[1]} and {k.shape[1]}")
+    return _flash(q, k, v, False, block_q, block_k, interpret, None,
+                  (half, blk))

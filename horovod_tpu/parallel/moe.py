@@ -17,11 +17,30 @@ dropping):
 
 Everything is static-shaped: scatter/gather by one-hot matmuls, the
 standard TPU MoE formulation.
+
+Two layers live here, and they are different mechanisms:
+
+  * :class:`ExpertParallelMoe` (above): top-1, capacity buffers that DROP
+    what overflows, a dense ``(T, E, C)`` one-hot dispatch, gelu experts,
+    the ``all_to_all`` exchange over ``ep``.  Small expert counts; not
+    reachable from ``models/transformer.py``.
+  * :class:`RoutedExperts`: top-k over ALL experts of the model with the
+    chosen weights renormalised, SwiGLU experts, of which this chip holds
+    a share (a range of expert ids) and computes only that share's part
+    of the sum; DROPLESS (assignments sorted by expert, grouped matrix
+    products through ``jax.lax.ragged_dot``, no capacity), the router in
+    float32.  This is the feed-forward ``models/transformer.py`` builds
+    when ``TransformerConfig.num_experts`` is set.  It has no exchange
+    yet: on one chip it runs on the tokens it is given and what the
+    absent experts would add is left out; the ``all_to_all`` that sends
+    every chip's assignments to their owners is a later change.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+import functools
+import math
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -131,3 +150,204 @@ class ExpertParallelMoe(nn.Module):
         )
         combined = combined * gate_val[:, None].astype(self.dtype)
         return combined.reshape(b, s, d), aux_loss
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _expert_chunk(z, w_gate, w_up, w_down, token, weight, valid, sizes):
+    """One chunk of the sorted assignments through the held experts.
+
+    ``token`` (C,) the row of ``z`` each assignment reads and adds to,
+    ``weight`` (C,) its combine weight, ``valid`` (C,) whether the slot
+    holds an assignment at all, ``sizes`` (held,) how many of the chunk's
+    rows each held expert owns, in order.  Rows that hold no assignment
+    are zeroed on the way in, between the products and on the way out:
+    the grouped product leaves what lies beyond its groups undefined.
+    Returns the chunk's part of the output, (T, D) float32."""
+    keep = valid[:, None]
+    x = jnp.where(keep, z[token], 0)
+    gate = jax.lax.ragged_dot(x, w_gate, sizes)
+    up = jax.lax.ragged_dot(x, w_up, sizes)
+    h = jnp.where(keep, nn.silu(gate) * up, 0)
+    y = jnp.where(keep, jax.lax.ragged_dot(h, w_down, sizes), 0)
+    y = y.astype(jnp.float32) * weight[:, None]
+    return jnp.zeros(z.shape, jnp.float32).at[token].add(y)
+
+
+def _chunk_sizes(lo, starts, ends, chunk):
+    """How many of the sorted rows ``[lo, lo + chunk)`` each held expert
+    owns: its run ``[start, end)`` of the sort, clipped to the chunk."""
+    return jnp.clip(ends - lo, 0, chunk) - jnp.clip(starts - lo, 0, chunk)
+
+
+def _chunk_out(lo, z, mats, weight, index, k, chunk):
+    """The part of the output that the sorted rows ``[lo, lo + chunk)``
+    give.  ``index = (order, starts, ends, assigned)``: the sort of the
+    (row, slot) assignments by held expert, each held expert's run in it,
+    and how many assignments are held at all."""
+    order, starts, ends, assigned = index
+    sel = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
+    valid = lo + jnp.arange(chunk) < assigned
+    return _expert_chunk(z, *mats, sel // k, weight[sel], valid,
+                         _chunk_sizes(lo, starts, ends, chunk))
+
+
+def _active_chunks(index, chunk):
+    return (index[3] + chunk - 1) // chunk
+
+
+def _later_chunks(out, z, mats, weight, index, k, chunk):
+    """``out`` plus the parts of the chunks after the first that the sort
+    reached: a loop whose trip count is read on the device."""
+    return jax.lax.fori_loop(
+        1, _active_chunks(index, chunk),
+        lambda c, out: out + _chunk_out(c * chunk, z, mats, weight, index,
+                                        k, chunk), out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _routed_sum(z, mats, weight, index, k, chunk):
+    """All chunks' parts of the output, summed.  The first chunk always
+    runs, as ordinary code with an ordinary backward pass.  A later chunk
+    runs only if the sort reached it, so a chunk that holds nothing costs
+    nothing, forward or backward (a ``lax.cond`` a chunk would still fill
+    and add a zero gradient for every expert matrix).  The backward pass
+    of a later chunk recomputes it."""
+    out = _chunk_out(0, z, mats, weight, index, k, chunk)
+    return _later_chunks(out, z, mats, weight, index, k, chunk)
+
+
+def _routed_sum_fwd(z, mats, weight, index, k, chunk):
+    out, first_vjp = jax.vjp(
+        lambda z, mats, weight: _chunk_out(0, z, mats, weight, index, k,
+                                           chunk), z, mats, weight)
+    out = _later_chunks(out, z, mats, weight, index, k, chunk)
+    return out, (first_vjp, z, mats, weight, index)
+
+
+def _routed_sum_bwd(k, chunk, residuals, g):
+    first_vjp, z, mats, weight, index = residuals
+
+    def later(c, grads):
+        _, vjp = jax.vjp(
+            lambda z, mats, weight: _chunk_out(c * chunk, z, mats, weight,
+                                               index, k, chunk),
+            z, mats, weight)
+        return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
+
+    grads = jax.lax.fori_loop(1, _active_chunks(index, chunk), later,
+                              first_vjp(g))
+    return (*grads, None)   # the index arrays are integers
+
+
+_routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Top-k routed SwiGLU experts of which this chip holds a share.
+
+    ``g = softmax(W_r z)`` over all ``num_experts`` in float32; the
+    ``top_k`` largest are chosen and their weights renormalised over the
+    chosen (held here or not); the output is
+    ``sum over chosen AND held e of w_e * down_e(silu(gate_e z) * up_e
+    z)``.  ``held = (first, count)`` names the expert ids this chip holds
+    (``None``: all of them); the stacked expert matrices have ``count``
+    leading entries.  What the absent experts would add is left out.
+
+    Dropless: the (token, expert) assignments are sorted by held expert
+    and go through ``jax.lax.ragged_dot`` in chunks of ``chunk_rows``
+    sorted rows.  The first chunk always runs; a later one runs only if
+    the sort reached it (``_routed_sum``: a loop over the chunks in use,
+    a later chunk recomputed in the backward pass, so an idle one costs
+    neither time nor memory).  The default chunk is twice the expected
+    load of the held share, so balanced routing is one chunk and a router
+    that sends everything to one expert still drops nothing.
+
+    Returns ``(y, stats)``: ``aux_loss`` (Switch / Hugging Face form:
+    ``E * sum_e (n_e / (k T)) * mean_T g_e``, no gradient through the
+    counts), and the counters ``assigned`` (assignments to held experts),
+    ``load_max_over_mean`` (largest held expert's load over the mean held
+    load), ``dropped`` (assignments to held experts no chunk computed: 0 by
+    construction, counted from the chunks' own group sizes) and
+    ``expert_index`` (T, k), the chosen ids.
+    """
+
+    num_experts: int
+    top_k: int
+    d_model: int
+    d_ff: int
+    held: Optional[Tuple[int, int]] = None
+    chunk_rows: Optional[int] = None
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        n_exp, k, d = self.num_experts, self.top_k, self.d_model
+        first, n_held = self.held if self.held is not None else (0, n_exp)
+        if not (0 <= first and n_held >= 1 and first + n_held <= n_exp
+                and 1 <= k <= n_exp):
+            raise ValueError(
+                f"held experts ({first}, {n_held}) and top_k {k} do not fit "
+                f"{n_exp} experts")
+        z = x.reshape(-1, d)
+        rows = z.shape[0]
+        slots = rows * k
+
+        with jax.named_scope("router"):
+            logits = nn.Dense(
+                n_exp, use_bias=False, dtype=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST, name="router",
+            )(z.astype(jnp.float32))
+            gates = jax.nn.softmax(logits, axis=-1)
+            _, index = jax.lax.top_k(logits, k)
+            chosen = jnp.take_along_axis(gates, index, axis=-1)
+            chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+            counts = jnp.bincount(index.reshape(-1), length=n_exp)
+            share = jax.lax.stop_gradient(counts / slots)
+            aux_loss = n_exp * jnp.sum(share * jnp.mean(gates, axis=0))
+            # sort the assignments by held expert; the others go last
+            local = index.reshape(-1) - first
+            key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+            order = jnp.argsort(key, stable=True)
+            sizes = jax.lax.dynamic_slice_in_dim(counts, first, n_held)
+            sizes = sizes.astype(jnp.int32)
+            assigned = jnp.sum(sizes)
+            ends = jnp.cumsum(sizes)
+            starts = ends - sizes
+
+        chunk = self.chunk_rows
+        if chunk is None:
+            chunk = 2 * math.ceil(slots * n_held / n_exp)
+        chunk = min(_round_up(max(chunk, 1), 8), _round_up(slots, 8))
+        n_chunks = -(-slots // chunk)
+        order = jnp.pad(order, (0, n_chunks * chunk - slots))
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (n_held, d, self.d_ff))
+        w_up = self.param("w_up", init, (n_held, d, self.d_ff))
+        w_down = self.param("w_down", init, (n_held, self.d_ff, d))
+
+        with jax.named_scope("experts"):
+            mats = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
+            sort = (order, starts, ends, assigned)
+            out = _routed_sum(z.astype(self.dtype), mats, chosen.reshape(-1),
+                              sort, k, chunk)
+            y = out.astype(self.dtype).reshape(x.shape)
+        # what the chunks in use computed, from their own group sizes
+        active = _active_chunks(sort, chunk)
+        computed = sum(
+            jnp.where(c < active,
+                      jnp.sum(_chunk_sizes(c * chunk, starts, ends, chunk)), 0)
+            for c in range(n_chunks))
+
+        mean_load = jnp.maximum(assigned, 1) / n_held
+        stats = {
+            "aux_loss": aux_loss,
+            "assigned": assigned,
+            "load_max_over_mean": jnp.max(sizes) / mean_load,
+            "dropped": assigned - computed,
+            "expert_index": index,
+        }
+        return y, stats

@@ -28,7 +28,8 @@ and the profiler see ONE set of span names.
 
 Inside the compiled step no host span can record, so the program names
 its device work instead: :data:`DEVICE_SCOPES` (``jax.named_scope``
-phases of every step builder in ``training.py``) and
+phases of every step builder in ``training.py``), :data:`DEVICE_SUBSCOPES`
+(parts of the forward: the routed layer's ``router`` and ``experts``) and
 :data:`DEVICE_KERNELS` (one ``name=`` a Pallas kernel).  Both are
 metadata: the operations are the same with tracing on or off.
 :mod:`.device` reduces a profiler capture by them.
@@ -51,7 +52,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
-    "DEVICE_KERNELS", "DEVICE_SCOPES", "SITES", "add_span", "configure",
+    "DEVICE_KERNELS", "DEVICE_SCOPES", "DEVICE_SUBSCOPES", "SITES", "add_span", "configure",
     "enabled", "event", "install_from_env", "new_trace_id", "now",
     "snapshot", "span",
 ]
@@ -104,6 +105,17 @@ DEVICE_SCOPES = (
     "optimizer",  # optimizer.update + apply_updates
 )
 
+#: Parts of a phase — ``jax.named_scope`` literals INSIDE the forward scope
+#: (so also inside its transpose, the backward), held by the same pass and
+#: the same docs table.  They name no phase: ``trace/device.py`` reports
+#: their time beside the phases' (``subscopes``), and the benchmark's
+#: ``router_ms`` / ``expert_ffn_ms`` read them by ``op_name`` pattern.
+DEVICE_SUBSCOPES = (
+    "router",   # parallel/moe.py RoutedExperts: router product, softmax,
+                # top-k, the sort and index building
+    "experts",  # RoutedExperts: gather, grouped products, scatter-add
+)
+
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
 #: ops/flash_attention.py, one name a kernel; the HLO instruction (and
 #: the profiler's event) is ``%<name>.<n>``.  All start with
@@ -112,6 +124,8 @@ DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
     "flash_attention_bwd_dq",   # _backward_folded: dQ
     "flash_attention_bwd_dkv",  # _backward_folded: dK/dV per kv head
+    "flash_attention_bwd_dkv_bd",  # _backward_folded: dK/dV under the
+                                   # block-diffusion mask, a query head a program
     "flash_attention_chunk",    # flash_chunk_attention (prefill, decode)
 )
 

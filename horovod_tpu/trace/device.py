@@ -64,11 +64,11 @@ from typing import (
     Dict, Iterable, Iterator, List, NamedTuple, Optional, Tuple,
 )
 
-from . import DEVICE_SCOPES
+from . import DEVICE_SCOPES, DEVICE_SUBSCOPES
 
 __all__ = ["PHASES", "classify", "device_events", "embedded_hlo",
            "find_xplane", "format_phases", "phase_ms", "phase_table",
-           "reduce_phases"]
+           "reduce_phases", "subscope_table"]
 
 PHASES = ("forward", "backward", "exchange", "optimizer", "unattributed")
 
@@ -279,14 +279,47 @@ def phase_table(compiled) -> Dict[str, Tuple[str, bool, Tuple[str, ...]]]:
     return table
 
 
+def subscope_table(compiled) -> Dict[str, str]:
+    """Instruction name -> the innermost :data:`~horovod_tpu.trace.
+    DEVICE_SUBSCOPES` component of its ``op_name`` (a part of the forward
+    scope or of its transpose: the routed layer's ``router``, ``experts``),
+    for the instructions that have one.  An instruction without an
+    ``op_name`` takes that of the root of the computation it calls, else
+    that of its last operand that has one (XLA's own ``ragged-dot`` custom
+    calls carry only their bare name; their data operands carry the scope)."""
+    instrs = list(_from_text(compiled) if isinstance(compiled, str)
+                  else _from_proto(compiled))
+    roots = {i.computation: i.op_name for i in instrs if i.root}
+    paths: Dict[str, str] = {}
+    table = {}
+    for ins in instrs:   # definitions come before uses
+        own = ins.op_name if "/" in ins.op_name else ""   # a bare name is no path
+        path = (own or roots.get(ins.callee, "")
+                or next((paths[o] for o in reversed(ins.operands)
+                         if paths.get(o)), ""))
+        paths[ins.name] = path
+        found = [p for p in path.split("/") if p in DEVICE_SUBSCOPES]
+        if found:
+            table[ins.name] = found[-1]
+    return table
+
+
 def embedded_hlo(xplane_path: str, module: str = "step") -> Optional[bytes]:
     """The serialized ``HloModuleProto`` of the step program, which the
     profiler stores in the capture itself (plane ``/host:metadata``, an
-    event metadata named after the program with an ``Hlo Proto`` stat),
-    or None.  Field numbers: tsl/profiler/protobuf/xplane.proto."""
+    event metadata named after the program, ``jit__step(<id>)``, with an
+    ``Hlo Proto`` stat), or None.  That plane holds EVERY program the
+    process has loaded, run during the capture or not, in no order: of
+    several whose name matches ``module`` the one that ran on a device
+    during the capture is taken (its name is on the ``XLA Modules``
+    line), else the one loaded last (the largest ``<id>``; an earlier
+    ``jit_step`` of another test or tool must not stand in for the step).
+    Field numbers: tsl/profiler/protobuf/xplane.proto."""
     rx = re.compile(module)
-    with open(find_xplane(xplane_path), "rb") as f:
+    path = find_xplane(xplane_path)
+    with open(path, "rb") as f:
         space = f.read()
+    found: List[Tuple[str, bytes]] = []
     for number, plane in _fields(space):
         if number != 1:                      # XSpace.planes
             continue
@@ -311,10 +344,36 @@ def embedded_hlo(xplane_path: str, module: str = "step") -> Optional[bytes]:
                 for stat in stats:
                     for m, v in _fields(stat):
                         if m == 6:           # XStat.bytes_value: an HloProto
-                            return next(
+                            hlo = next(
                                 (bytes(x) for h, x in _fields(v) if h == 1),
                                 None)        # HloProto.hlo_module
-    return None
+                            if hlo is not None:
+                                found.append((name, hlo))
+    if len(found) > 1:
+        ran = _modules_run(path)
+        found = [f for f in found if f[0] in ran] or found
+        found = [max(found, key=lambda f: _program_id(f[0]))]
+    return found[0][1] if found else None
+
+
+def _program_id(name: str) -> int:
+    """The ``<id>`` of ``jit__step(<id>)``; -1 without one."""
+    m = re.search(r"\((\d+)\)$", name)
+    return int(m.group(1)) if m else -1
+
+
+def _modules_run(xplane_file: str) -> set:
+    """Names of the programs that ran on a device during the capture
+    (the ``XLA Modules`` line of every TPU plane; none on the CPU)."""
+    from jax.profiler import ProfileData
+
+    ran = set()
+    for plane in ProfileData.from_file(xplane_file).planes:
+        if _DEVICE_PLANE.match(plane.name):
+            for line in plane.lines:
+                if line.name == _MODULES_LINE:
+                    ran.update(e.name for e in line.events)
+    return ran
 
 
 def find_xplane(path: str) -> str:
@@ -439,7 +498,8 @@ def device_events(xplane_path: str, module: str = "step") -> Dict[str, dict]:
     return devices
 
 
-def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dict:
+def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None,
+                  subscopes: Optional[dict] = None) -> dict:
     """Milliseconds a step by phase from :func:`device_events`' shape,
     means over the devices.  An operation's phase is its instruction's
     in ``table`` (:func:`phase_table`); one the table does not name, or
@@ -454,7 +514,11 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
     part of a phase spent in fusions that also hold another phase's
     instructions.  ``exchange_in_flight_ms`` is the time with a
     reduction in flight and ``exchange_hidden_ms`` the part of it during
-    which an operation of another phase ran (module docstring)."""
+    which an operation of another phase ran (module docstring).
+    ``subscopes`` (:func:`subscope_table`) adds ``"subscopes": {name:
+    ms}``: the time of the operations under each part of the forward
+    scope, forward and backward together (a union: a ``conditional`` and
+    its body count once); they lie inside the phases, not beside them."""
     nothing = ("unattributed", False, ())
     n_dev = len(devices)
     phases = dict.fromkeys(PHASES, 0.0)
@@ -465,7 +529,12 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
 
     shared: Dict[str, float] = {}
     unattributed: Dict[str, float] = {}
+    parts = dict.fromkeys(DEVICE_SUBSCOPES, 0.0) if subscopes else {}
     for dev in devices.values():
+        for part in parts:
+            parts[part] += _union_ns(
+                [(start, dur) for name, start, dur in dev["ops"]
+                 if subscopes.get(name) == part]) * 1e-6 / dev["steps"] / n_dev
         keyed = [(start, dur, (table or {}).get(name, nothing) + (name,))
                  for name, start, dur in dev["ops"]]
         scale = 1e-6 / dev["steps"] / n_dev
@@ -495,6 +564,7 @@ def reduce_phases(devices: Dict[str, dict], table: Optional[dict] = None) -> dic
         "exchange_in_flight_ms": in_flight_ms,
         "exchange_hidden_ms": hidden_ms,
         "unattributed_top": [[name, ms] for name, ms in top],
+        **({"subscopes": parts} if subscopes else {}),
     }
 
 
@@ -504,10 +574,12 @@ def phase_ms(xplane_path: str, table: Optional[dict] = None,
     file or a directory holding one): :func:`reduce_phases` over
     :func:`device_events`, with the :func:`phase_table` of the step
     program the capture itself carries unless ``table`` is given."""
+    subscopes = None
     if table is None:
         hlo = embedded_hlo(xplane_path, module)
         table = phase_table(hlo) if hlo else None
-    return reduce_phases(device_events(xplane_path, module), table)
+        subscopes = (subscope_table(hlo) or None) if hlo else None
+    return reduce_phases(device_events(xplane_path, module), table, subscopes)
 
 
 def format_phases(result: dict) -> str:
@@ -530,6 +602,9 @@ def format_phases(result: dict) -> str:
                 f"  {'exchange hidden':<16}{hidden:7.3f} ms  "
                 f"{100 * hidden / flying:5.1f} % of the {flying:.3f} ms "
                 "with a reduction in flight")
+    for part, ms in result.get("subscopes", {}).items():
+        rows.append(f"  of which {part:<10}{ms:7.3f} ms  {100 * ms / busy:5.1f} %"
+                    "  (inside forward and backward)")
     rows.append(f"  {'busy':<13}{result['busy_ms']:10.3f} ms  (phases sum "
                 f"{result['sum_ms']:.3f}; recompute, inside backward, "
                 f"{result['recompute_ms']:.3f})")

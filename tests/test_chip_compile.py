@@ -103,6 +103,51 @@ def test_flash_attention_compiles(one_chip, case, backward):
     assert _has_kernel(_compile(fn, q, kv, kv))
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward):
+    """sdar-30b-a3b-bd4-s4096-1chip: one row of [noisy || clean] = 8,192
+    positions, 32 query heads over 4 key/value heads of 128, blocks of 4.
+    The causal dK/dV kernel cannot hold a group of 8 query heads of 8,192
+    rows in VMEM; the block-diffusion one holds one query head a program."""
+    half = 4096
+    q = _sds((1, 2 * half, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 2 * half, 4, 128), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, block_diffusion=(half, 4),
+                               interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    text = _compile(fn, q, kv, kv).as_text()
+    assert "flash_attention_fwd" in text
+    if backward:
+        assert "flash_attention_bwd_dq" in text
+        assert "flash_attention_bwd_dkv_bd" in text
+
+
+def test_grouped_products_are_a_kernel_on_the_chip(one_chip):
+    """``jax.lax.ragged_dot`` of the routed layer at the cell's chunk (16,384
+    sorted rows, 16 held experts of 2048 x 768): the v5e compiler lowers it to
+    its own ``tpu_custom_call``, forward and both gradients, and counts one
+    expert's product a row, not the stack's (no dense expansion)."""
+    rows, d, f, held = 16384, 2048, 768, 16
+    x = _sds((rows, d), jnp.bfloat16, one_chip)
+    w = _sds((held, d, f), jnp.bfloat16, one_chip)
+    sizes = _sds((held,), jnp.int32, one_chip)
+
+    def loss(x, w, sizes):
+        return jnp.sum(jax.lax.ragged_dot(x, w, sizes).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), x, w, sizes)
+    assert "ragged-dot" in compiled.as_text() and _has_kernel(compiled)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] < 3 * 2 * rows * d * f
+
+
 # -- serving kernels: decode and chunked prefill ------------------------------
 
 

@@ -1,0 +1,353 @@
+"""The routed feed-forward, the block-diffusion mask kind and the
+masked-diffusion loss (PR 28): the program against the benchmark's plain
+float32 reference, at small sizes on the CPU."""
+
+import functools
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import flops_sdar, harness  # noqa: E402
+from benchmark.reference import chain, sdar_moe as reference  # noqa: E402
+from horovod_tpu.models import transformer  # noqa: E402
+from horovod_tpu.models.transformer import (  # noqa: E402
+    Transformer, TransformerConfig, block_diffusion_mask,
+)
+from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from horovod_tpu.parallel.moe import RoutedExperts  # noqa: E402
+
+
+def _tiny_config(dtype="float32"):
+    """The cell's configuration with every size made tiny (widths too: a
+    test's sizes, never a cell's)."""
+    config = harness.load_json(ROOT, "benchmark", "configs", "sdar-30b-a3b.json")
+    config.update(
+        hidden_size=32, head_dim=8, num_attention_heads=8, num_key_value_heads=2,
+        moe_intermediate_size=24, num_hidden_layers=2, vocab_size=64,
+        mask_token_id=63, router_experts=16, num_experts=4, held_experts_first=4,
+        num_experts_per_tok=4, max_position_embeddings=64, compute_dtype=dtype)
+    return config
+
+
+_TRAFFIC = {"samples_per_chip": 1, "seq_len": 40, "block_length": 4, "t_min": 0.001,
+            "layout": "dp", "step_options": {}, "span_steps": 2, "trace_steps": 3}
+
+
+# -- the family through the harness: loss and every leaf's gradient -----------
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_family_through_run_cell_matches_the_reference_at_float32(chips):
+    config = _tiny_config()
+    config["check"] = dict(config["check"], limits={
+        "loss_gap": 2e-6, "grad_norm_gap": 5e-5, "delta_norm_gap": 5e-5,
+        "grad_diff_gap": 5e-5})
+    cell = harness.Cell(
+        name=f"tiny-sdar-{chips}", config_name="tiny", config=config,
+        traffic_name="tiny", traffic=_TRAFFIC, chips=chips,
+        end_to_end=["setup_s", "train_tokens_per_s", "step_ms_p90", "mfu"], per_layer=[])
+    harness.check_names(cell)
+    result = harness.run_cell(cell, seed=2 ** 31 + 28, seconds=0.3, trace=False,
+                              devices=jax.devices()[:chips])
+    assert result["correct"], json.dumps(result["checks"])
+    assert result["checks"]["grad_diff_gap"]["value"] < 5e-5
+    assert set(reference.REFERENCE_ROUTING) == {0, 1}
+
+
+def test_the_control_precision_fails_the_same_comparison():
+    """The reference at bfloat16 operands is not the float32 program."""
+    config = _tiny_config()
+    cell = harness.Cell(
+        name="tiny-sdar-control", config_name="tiny", config=config, traffic_name="tiny",
+        traffic=_TRAFFIC, chips=1, end_to_end=[], per_layer=[])
+    sound = harness.run_reference(cell, 7, jax.devices()[0], keep_first_gradient=True)
+    control = harness.run_reference(cell, 7, jax.devices()[0], precision="bfloat16",
+                                    other_first_gradient=sound["first_gradient"])
+    share, _ = harness.worst_leaf_diff(control["grad_diff_norms"], sound["grad_norms"])
+    assert share > 1e-3
+
+
+def test_batch_is_block_diffusion_input():
+    from benchmark.families_sdar import SdarMoe
+
+    config = _tiny_config()
+    inputs, (targets, weights) = SdarMoe.batch(
+        jax.random.PRNGKey(3), config, dict(_TRAFFIC, seq_len=38), 5)
+    length = 38
+    assert inputs.shape == (5, 2 * length) and targets.shape == weights.shape == (5, length)
+    xt, x0 = np.asarray(inputs[:, :length]), np.asarray(inputs[:, length:])
+    w = np.asarray(weights)
+    assert (x0 == np.asarray(targets)).all() and x0.max() < config["mask_token_id"]
+    masked = xt == config["mask_token_id"]
+    assert (xt[~masked] == x0[~masked]).all() and masked.any() and (~masked).any()
+    assert (w[~masked] == 0).all() and (w[masked] >= 1.0).all()
+    # one t a block: the weights of a block's masked positions agree
+    for row in range(5):
+        for start in range(0, length, 4):
+            values = w[row, start:start + 4][masked[row, start:start + 4]]
+            assert np.allclose(values, values[:1])
+    assert len({tuple(r) for r in x0}) == 5
+
+
+# -- the flash mask kind against the dense mask -------------------------------
+
+
+def _dense_attention(q, k, v, mask):
+    group = q.shape[2] // k.shape[2]
+    kk, vv = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(mask[None, None], logits, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+
+
+@pytest.mark.parametrize("half,block,heads,kv,tile", [
+    (200, 4, 4, 2, 128),    # L no multiple of the tile; a tile straddles L
+    (160, 32, 8, 1, 128),   # B 32, eight query heads a key/value head
+    (72, 4, 2, 2, 256),     # one tile holds both halves
+    (130, 7, 2, 1, 128),    # B divides neither L nor the tile
+])
+def test_flash_block_diffusion_matches_the_dense_mask(half, block, heads, kv, tile):
+    keys = jax.random.split(jax.random.PRNGKey(half), 4)
+    q = jax.random.normal(keys[0], (1, 2 * half, heads, 16))
+    k = jax.random.normal(keys[1], (1, 2 * half, kv, 16))
+    v = jax.random.normal(keys[2], (1, 2 * half, kv, 16))
+    w = jax.random.normal(keys[3], q.shape)
+    mask = block_diffusion_mask(half, block)
+    assert mask.sum() == flops_sdar.allowed_pairs(half, block)
+    assert mask.diagonal().all()   # every row sees at least itself
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=tile, block_k=tile,
+                               block_diffusion=(half, block))
+
+    np.testing.assert_allclose(flash(q, k, v), _dense_attention(q, k, v, mask), atol=5e-6)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_dense_attention(*a, mask) * w), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_flash_block_diffusion_refuses_what_it_cannot_mask():
+    q = jnp.zeros((1, 16, 2, 8))
+    with pytest.raises(ValueError, match="2 L"):
+        flash_attention(q, q, q, block_diffusion=(7, 4))
+    with pytest.raises(ValueError, match="no window"):
+        flash_attention(q, q, q, window=4, block_diffusion=(8, 4))
+    with pytest.raises(ValueError, match="block_diffusion"):
+        TransformerConfig(block_diffusion=4, attention_impl="ring")
+
+
+@pytest.mark.parametrize("length,block", [(8, 4), (40, 4), (4096, 4), (37, 5), (96, 32), (5, 8)])
+def test_allowed_pairs_is_the_brute_force_count(length, block):
+    pairs = flops_sdar.allowed_pairs(length, block)
+    if length <= 128:
+        assert pairs == int(block_diffusion_mask(length, block).sum())
+        assert pairs == int(reference.allowed(length, block).sum())
+    if length % block == 0:
+        assert pairs == length * length + length * block
+
+
+def test_required_flops_are_the_issue_s_count():
+    cell = harness.load_cell("sdar-30b-a3b-bd4-s4096-1chip")
+    per_token = flops_sdar.train_flops_per_token(cell.config, cell.traffic)
+    assert abs(per_token - 3.16e9) < 0.01e9
+    attention = flops_sdar.bd_attention_train_flops_per_step(cell.config, cell.traffic, 1)
+    assert abs(attention / 4096 / per_token - 0.38) < 0.01
+    experts = flops_sdar.expert_ffn_train_flops_per_step(cell.config, cell.traffic, 1)
+    assert experts == 6 * 6.0 * 3 * 2048 * 768 * 8192
+
+
+# -- the routed layer ----------------------------------------------------------
+
+
+def _layer(held, chunk_rows=None, experts=16, top_k=4, width=32, ff=24):
+    return RoutedExperts(experts, top_k, width, ff, held=held, chunk_rows=chunk_rows,
+                         dtype=jnp.float32)
+
+
+def _reference_experts(params, x, top_k, first):
+    """The reference's layer on one chip's rows: (y, aux)."""
+    z = x.reshape(-1, x.shape[-1])
+    y, aux = reference._experts(chain.Ops("float32"), params, z, top_k, first)
+    return y.reshape(x.shape), aux
+
+
+@pytest.mark.parametrize("held,chunk_rows", [((4, 4), None), ((0, 16), None),
+                                             ((4, 4), 16), ((8, 2), 8)])
+def test_routed_experts_match_the_reference_layer(held, chunk_rows):
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32))
+    layer = _layer(held, chunk_rows)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    y, stats = layer.apply({"params": params}, x)
+    want, aux = _reference_experts(params, x, 4, held[0])
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    np.testing.assert_allclose(stats["aux_loss"], aux, rtol=1e-6)
+    assert int(stats["dropped"]) == 0
+    chosen = np.asarray(stats["expert_index"])
+    inside = (chosen >= held[0]) & (chosen < held[0] + held[1])
+    assert int(stats["assigned"]) == inside.sum()
+
+    def loss(fn):
+        return lambda p, x: jnp.sum(fn(p, x)[0] ** 2) + fn(p, x)[1]
+
+    got = jax.grad(loss(lambda p, x: (lambda o: (o[0], o[1]["aux_loss"]))(
+        layer.apply({"params": p}, x))), (0, 1))(params, x)
+    want = jax.grad(loss(lambda p, x: _reference_experts(p, x, 4, held[0])), (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_identical_rows_route_as_one_and_later_chunks_carry_their_gradient():
+    """Rows that are one token (every masked position of block-diffusion
+    training) choose one set of experts: the router is the model's own, nothing
+    spreads them.  The held expert they chose then takes every row, the sort
+    runs past the first chunk, and the later chunks' recomputed backward pass
+    gives the reference's gradient."""
+    x = jnp.broadcast_to(jax.random.normal(jax.random.PRNGKey(4), (1, 1, 32)), (1, 64, 32))
+    layer = _layer((0, 16), chunk_rows=48)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    y, stats = layer.apply({"params": params}, x)
+    assert len({tuple(sorted(r)) for r in np.asarray(stats["expert_index"])}) == 1
+    assert int(stats["assigned"]) == 64 * 4 and int(stats["dropped"]) == 0   # six chunks of 48
+    assert float(stats["load_max_over_mean"]) == 4.0            # 4 of the 16 held take all
+    want, aux = _reference_experts(params, x, 4, 0)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    np.testing.assert_allclose(stats["aux_loss"], aux, rtol=1e-6)
+    w = jax.random.normal(jax.random.PRNGKey(6), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(w * layer.apply({"params": p}, x)[0]), (0, 1))(params, x)
+    ref = jax.grad(lambda p, x: jnp.sum(w * _reference_experts(p, x, 4, 0)[0]), (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_eight_shares_sum_to_the_uncut_layer():
+    """128 experts, 8 a token: the eight shares of 16 experts each, each
+    computed by the program's layer told which experts it holds, add up to the
+    reference's layer that holds all 128."""
+    experts, top_k, width, ff = 128, 8, 16, 8
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 24, width))
+    whole = _layer(None, experts=experts, top_k=top_k, width=width, ff=ff)
+    params = whole.init(jax.random.PRNGKey(5), x)["params"]
+    params = dict(params, router={"kernel": 4.0 * params["router"]["kernel"]})
+    want, _ = _reference_experts(params, x, top_k, 0)
+    total, assigned = 0.0, 0
+    for share in range(8):
+        first = 16 * share
+        own = dict(params, **{k: params[k][first:first + 16]
+                              for k in ("w_gate", "w_up", "w_down")})
+        layer = _layer((first, 16), experts=experts, top_k=top_k, width=width, ff=ff)
+        y, stats = layer.apply({"params": own}, x)
+        total, assigned = total + y, assigned + int(stats["assigned"])
+        assert int(stats["dropped"]) == 0
+    assert assigned == 24 * top_k
+    np.testing.assert_allclose(total, want, atol=2e-6)
+    assert float(jnp.max(jnp.abs(want))) > 1e-3
+
+
+def test_dropless_under_a_router_forced_onto_one_expert():
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32)))
+    layer = _layer((4, 4), chunk_rows=32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    kernel = (0.01 * params["router"]["kernel"]).at[:, 5].add(1.0)
+    params = dict(params, router={"kernel": kernel})
+    y, stats = layer.apply({"params": params}, x)
+    chosen = np.asarray(stats["expert_index"])
+    assert (chosen == 5).any(axis=-1).all()          # every row chose expert 5
+    # chunks of 32 sorted rows: the first three are full, so later chunks
+    # ran, and nothing was dropped
+    assert int(stats["assigned"]) > 3 * 32
+    assert int(stats["dropped"]) == 0 and float(stats["load_max_over_mean"]) > 2.0
+    want, _ = _reference_experts(params, x, 4, 4)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+
+
+# -- the model's keys -----------------------------------------------------------
+
+
+def _lowered(cfg):
+    model = Transformer(cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(lambda k: model.init(k, tokens), jax.random.PRNGKey(0))
+
+    def loss(p):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(p, tokens), tokens).mean()
+
+    return jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+
+
+def test_new_keys_default_to_the_model_that_was():
+    """A configuration that states none of the new keys (InternLM2's) builds
+    the program it built: stating each default changes nothing."""
+    base = dict(vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8,
+                max_seq_len=32, attention_impl="flash")
+    stated = dict(base, hidden_size=32, rope_theta=10000.0, rms_norm_eps=1e-5,
+                  tie_word_embeddings=True, qk_norm=False, num_experts=None,
+                  block_diffusion=None)
+    assert _lowered(TransformerConfig(**base)) == _lowered(TransformerConfig(**stated))
+    cfg = TransformerConfig(**base)
+    assert cfg.d_model == 32 and cfg.rope_theta == 10000.0 and cfg.rms_norm_eps == 1e-5
+    for key, value in (("rope_theta", 1e6), ("rms_norm_eps", 1e-6), ("qk_norm", True),
+                       ("tie_word_embeddings", False)):
+        assert _lowered(TransformerConfig(**dict(base, **{key: value}))) != _lowered(cfg)
+
+
+def test_hidden_size_is_its_own_key_and_the_head_is_untied():
+    cfg = TransformerConfig(vocab_size=50, num_layers=1, num_heads=4, num_kv_heads=2,
+                            head_dim=8, hidden_size=16, dtype=jnp.float32,
+                            tie_word_embeddings=False, qk_norm=True)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    params = Transformer(cfg).init(jax.random.PRNGKey(0), tokens)["params"]
+    shapes = jax.tree_util.tree_map(lambda x: x.shape, params)
+    assert shapes["embed"]["embedding"] == (50, 16) and shapes["head"]["kernel"] == (16, 50)
+    attn = shapes["layer_0"]["attn"]
+    assert attn["q"]["kernel"] == (16, 4, 8) and attn["o"]["kernel"] == (4, 8, 16)
+    assert attn["q_norm"]["scale"] == attn["k_norm"]["scale"] == (8,)
+    logits = Transformer(cfg).apply({"params": params}, tokens)
+    assert logits.shape == (1, 8, 50) and logits.dtype == jnp.float32
+
+
+def test_block_diffusion_model_trains_through_the_normal_path():
+    """create_train_state -> replicate_state -> data_parallel_train_step, a
+    loss_fn through the step's own argument, labels a tuple."""
+    import horovod_tpu as hvd
+    from horovod_tpu import training
+
+    hvd.init()
+    losses = {}
+    for impl in ("dot", "flash"):
+        cfg = TransformerConfig(
+            vocab_size=50, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8,
+            hidden_size=16, max_seq_len=64, dtype=jnp.float32, attention_impl=impl,
+            rope_theta=1e6, rms_norm_eps=1e-6, tie_word_embeddings=False, qk_norm=True,
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=12,
+            held_experts=(2, 4), block_diffusion=4)
+        model = Transformer(cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(0), (8, 48), 0, 50)
+        labels = (tokens[:, 24:], jax.random.uniform(jax.random.PRNGKey(1), (8, 24)))
+        logits, aux = model.apply(
+            model.init(jax.random.PRNGKey(2), tokens[:1]), tokens[:1])
+        assert logits.shape == (1, 24, 50) and int(aux["dropped_assignments"]) == 0
+        assert aux["expert_index"].shape == (2, 48, 2)
+        state = training.create_train_state(
+            model, optax.adamw(1e-2), jax.random.PRNGKey(2), np.asarray(tokens[:1]))
+        state = training.replicate_state(state, hvd.world_mesh())
+        step = training.data_parallel_train_step(
+            model, optax.adamw(1e-2), loss_fn=functools.partial(
+                transformer.block_diffusion_loss, aux_coef=0.001))
+        losses[impl] = []
+        for _ in range(4):
+            state, loss = step(state, tokens, labels)
+            losses[impl].append(float(loss))
+        assert losses[impl][-1] < losses[impl][0]
+    np.testing.assert_allclose(losses["dot"], losses["flash"], rtol=2e-5)

@@ -917,3 +917,113 @@ def test_device_names_catalogue_matches_the_code():
     assert all(k.startswith("flash_attention_") for k in trace.DEVICE_KERNELS)
     assert not os.path.exists(
         os.path.join(repo, "horovod_tpu", "utils", "profiler.py"))
+
+
+# -- parts of the forward scope; the routed layer's names (PR 28) -------------
+
+
+def test_subscope_catalogue_matches_the_code_and_names_no_phase():
+    from horovod_tpu.analysis import trace_sites
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert tuple(trace_sites.catalogue(repo, "DEVICE_SUBSCOPES")) == \
+        trace.DEVICE_SUBSCOPES == ("router", "experts")
+    assert "flash_attention_bwd_dkv_bd" in trace.DEVICE_KERNELS
+    assert not set(trace.DEVICE_SUBSCOPES) & set(trace.DEVICE_SCOPES)
+    path = "jit(_step)/shard_map/transpose(jvp(forward))/T/layer_0/moe/experts/x"
+    assert trace_device.classify(path) == ("backward", False)
+    assert trace_sites.run(repo) == []
+
+
+@pytest.mark.parametrize("rows,missing", [
+    ([("forward", "scope"), ("router", "subscope")], None),
+    ([("forward", "scope")], ("router", "__init__.py")),          # no docs row
+    ([("forward", "scope"), ("router", "subscope"),
+      ("ghost", "subscope")], ("ghost", "TRACING.md")),           # stale docs row
+])
+def test_trace_pass_holds_subscopes_like_scopes(tmp_path, rows, missing):
+    from horovod_tpu.analysis import trace_sites
+
+    root = _device_tree(
+        tmp_path, ["forward"], ["flash_attention_fwd"],
+        'with jax.named_scope("forward"):\n    pass\n'
+        'with jax.named_scope("router"):\n    pass\n',
+        _FLASH_OK, rows + [("flash_attention_fwd", "kernel")])
+    init = tmp_path / "horovod_tpu" / "trace" / "__init__.py"
+    init.write_text(init.read_text() + 'DEVICE_SUBSCOPES = (\n    "router",\n)\n')
+    keys = {(f.key, f.file.split("/")[-1]) for f in trace_sites.run(root)}
+    assert keys == (set() if missing is None else {missing})
+
+
+def _routed_step_text():
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu import training
+    from horovod_tpu.models.transformer import (
+        Transformer, TransformerConfig, block_diffusion_loss,
+    )
+
+    hvd.init()
+    cfg = TransformerConfig(
+        vocab_size=32, num_layers=1, num_heads=2, head_dim=8, max_seq_len=32,
+        dtype=jnp.float32, num_experts=4, num_experts_per_tok=2,
+        moe_intermediate_size=8, held_experts=(0, 2), block_diffusion=2)
+    model, optimizer = Transformer(cfg), optax.adamw(1e-3)
+    tokens = jnp.zeros((hvd.size(), 16), jnp.int32)
+    labels = (tokens[:, :8], jnp.ones((hvd.size(), 8), jnp.float32))
+    state = training.replicate_state(training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), tokens[:1]))
+    step = training.data_parallel_train_step(
+        model, optimizer, loss_fn=block_diffusion_loss)
+    return step.lower(state, tokens, labels).compile().as_text()
+
+
+def test_subscope_table_finds_the_routed_layer_in_a_compiled_step():
+    text = _routed_step_text()
+    parts = trace_device.subscope_table(text)
+    assert set(parts.values()) == set(trace.DEVICE_SUBSCOPES)
+    phases = trace_device.phase_table(text)
+    # a part lies inside the forward scope or its transpose (or is an
+    # operation the compiler left unnamed, which takes its operand's part
+    # and no phase), never in another phase
+    assert {phases[name][0] for name in parts} <= {
+        "forward", "backward", "unattributed"}
+    assert {"forward", "backward"} <= {phases[name][0] for name in parts}
+    events = {"0": {"steps": 1, "ops": [
+        (name, 10.0 * i, 5.0) for i, name in enumerate(sorted(parts))]}}
+    result = trace_device.reduce_phases(events, phases, parts)
+    assert abs(sum(result["subscopes"].values()) - result["busy_ms"]) < 1e-12
+    assert all(v > 0 for v in result["subscopes"].values())
+    assert "of which router" in trace_device.format_phases(result)
+    assert "subscopes" not in trace_device.reduce_phases(events, phases)
+
+
+def test_embedded_hlo_is_the_step_loaded_last_not_another_step(tmp_path):
+    """The capture's metadata plane holds every program of the process, in
+    no order: an earlier ``jit_step`` must not stand in for the step."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def step(x):
+        return x * 2 + 1
+
+    @jax.jit
+    def my_step_fn(x):
+        return x * 3
+
+    step(jnp.ones(4)).block_until_ready()
+    my_step_fn(jnp.ones(4)).block_until_ready()
+    state, train_step, x, y = _tiny_step()
+    state, _ = train_step(state, x, y)
+    jax.profiler.start_trace(str(tmp_path))
+    jax.block_until_ready(train_step(state, x, y))
+    jax.profiler.stop_trace()
+    phases = {v[0] for v in trace_device.phase_table(
+        trace_device.embedded_hlo(str(tmp_path))).values()}
+    assert phases == set(trace_device.PHASES)
+    assert trace_device._program_id("jit__step(55)") == 55
+    assert trace_device._program_id("jit__step") == -1
