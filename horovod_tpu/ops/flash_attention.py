@@ -14,8 +14,31 @@ Kernel shape (the standard TPU flash forward, per pallas_guide.md):
 grid = (batch*heads, Sq/block_q); each program holds one Q block in VMEM,
 K/V for the whole (padded) sequence stream through VMEM block-by-block
 inside a ``fori_loop`` with running (max, sum, accumulator) statistics in
-float32; causal programs stop the loop at the diagonal block.  Matmuls
-run on the MXU with ``preferred_element_type=float32``.
+float32; causal programs stop the loop at the diagonal block.
+
+Operand dtypes: the kernel's inputs (``q``, ``k``, ``v``, ``dO``) are
+widened to float32 in front of every product and the products run on the MXU
+with ``preferred_element_type=float32``.  On the v5e such a product is ONE
+bfloat16 pass: the MXU rounds its float32 operands to bfloat16 itself, to
+nearest even, and sums in float32 (probed bit for bit, PERF.md PR 29), so
+the widening costs no pass and an explicit cast to bfloat16 in front of the
+product buys nothing (it was slower: the cast is vector work, the MXU's
+rounding is not).  float32 inputs (the tests) go the same way.
+
+Several tiles a loop iteration: within one tile the second product waits
+for the tile's own softmax and the softmax for the first product, and one
+loop iteration is one scheduling region, so a loop of one tile an iteration
+leaves the MXU idle for most of it.  Every kernel therefore walks its tiles
+four an iteration, then the rest two and one (``_run_tiles``): the
+tiles of an iteration are independent but for the running sums, so the
+scheduler fills one tile's waits with the next one's products.  Tiles are
+visited in the same order: the result is the same bit for bit.
+``tile_counts`` gives a head's tile visits and loop iterations by kernel
+(136 visits in 44 iterations under the causal mask at 4,096 in 256-tiles;
+a window of one tile visits one tile an iteration, and nothing is won), and
+tracing a kernel records them as a ``flash.tiles`` event
+(``horovod_tpu.trace``).  Every mask kind's loop bounds come from
+``_tile_ranges``; tiles are masked element by element as before.
 
 Grouped-query attention (GQA — Ainslie et al., 2023) is KERNEL-NATIVE:
 ``k``/``v`` may carry ``num_kv_heads < num_heads`` heads and are folded
@@ -43,14 +66,22 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as _pltpu
+
+from .. import trace as _trace
 
 # scalar params belong in SMEM on TPU; interpret mode accepts it too
 _SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
 
 _NEG_INF = -1e30
+
+# tiles a loop iteration: a range runs four at a time, then what is left two
+# and one at a time (_run_tiles).  Starting at 8 was 2 % of the kernels' time
+# faster again at twice their compile time (PERF.md PR 29)
+_TILES_AN_ITERATION = (4, 2, 1)
 
 
 def _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off=0):
@@ -70,30 +101,32 @@ def _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off=0):
     return mask
 
 
-def _kb_range(q_off, block_q, block_k, padded_kb, causal, window, kv_off=0):
+def _kb_range(q_off, block_q, block_k, padded_kb, causal, window, kv_off=0,
+              xp=jnp):
     """K-block loop bounds for one Q block: skip blocks entirely outside
     the causal diagonal / sliding window (this skip is where the windowed
     kernel's compute drops from O(S²) to O(S·W)).  ``kv_off`` is the
     global K−Q offset (see _tile_mask); bounds may be traced and may
     satisfy lo >= hi (an empty, fully-masked range — fori_loop runs zero
-    iterations and the caller's l==0 guard takes over)."""
+    iterations and the caller's l==0 guard takes over).  ``xp=numpy``
+    computes the same bounds on the host (``tile_counts``)."""
     hi = padded_kb
     if causal:
         # last K block holding any k <= q for the block's last row
-        hi = jnp.minimum(
-            hi, jnp.floor_divide(q_off + block_q - 1 - kv_off, block_k) + 1)
+        hi = xp.minimum(
+            hi, xp.floor_divide(q_off + block_q - 1 - kv_off, block_k) + 1)
     elif window is not None:
         # bidirectional: the forward reach k < q + window also bounds hi
-        hi = jnp.minimum(
+        hi = xp.minimum(
             hi,
-            jnp.floor_divide(
+            xp.floor_divide(
                 q_off + block_q - 1 + window - 1 - kv_off, block_k) + 1)
     if window is None:
         lo = 0
     else:  # first K block any row of this Q block can reach back to
-        lo = jnp.maximum(
-            0, jnp.floor_divide(q_off - (window - 1) - kv_off, block_k))
-    hi = jnp.maximum(hi, 0)
+        lo = xp.maximum(
+            0, xp.floor_divide(q_off - (window - 1) - kv_off, block_k))
+    hi = xp.maximum(hi, 0)
     return lo, hi
 
 
@@ -128,7 +161,8 @@ def _bd_tile_mask(row_off, col_off, rows, cols, seq_len, bd, cols_are_keys):
     return jnp.logical_or(k_as_clean <= bound, k_as_noisy == own)
 
 
-def _bd_ranges(off, rows, other_block, n_other, seq_len, bd, rows_are_queries):
+def _bd_ranges(off, rows, other_block, n_other, seq_len, bd, rows_are_queries,
+               xp=jnp):
     """Loop bounds, in tiles of ``other_block`` positions, over the other
     side of the block-diffusion mask for the tile of ``rows`` positions at
     ``off``: two half-open tile ranges ``((lo1, hi1), (lo2, hi2))``, the
@@ -139,47 +173,139 @@ def _bd_ranges(off, rows, other_block, n_other, seq_len, bd, rows_are_queries):
     outside these ranges are never computed.  Either range may be empty."""
     half, blk = bd
     first = off
-    last = jnp.minimum(off + rows, seq_len) - 1   # last real position
+    last = xp.minimum(off + rows, seq_len) - 1   # last real position
     has_noisy = first < half
     has_clean = last >= half
     n0 = first                                    # noisy positions [n0, n1]
-    n1 = jnp.minimum(last, half - 1)
-    c0 = jnp.maximum(first, half) - half          # clean positions [c0, c1]
+    n1 = xp.minimum(last, half - 1)
+    c0 = xp.maximum(first, half) - half          # clean positions [c0, c1]
     c1 = last - half
-    own_lo = jnp.floor_divide(n0, blk) * blk      # own blocks of the noisy rows
-    own_hi = jnp.minimum(half, jnp.floor_divide(n1, blk) * blk + blk)
+    own_lo = xp.floor_divide(n0, blk) * blk      # own blocks of the noisy rows
+    own_hi = xp.minimum(half, xp.floor_divide(n1, blk) * blk + blk)
     if rows_are_queries:
         # noisy keys: the noisy queries' own blocks
-        a_lo, a_hi = own_lo, jnp.where(has_noisy, own_hi, own_lo)
+        a_lo, a_hi = own_lo, xp.where(has_noisy, own_hi, own_lo)
         # clean keys: before the last noisy query's block, up to and with
         # the last clean query's block
-        upto = jnp.maximum(
-            jnp.where(has_noisy, jnp.floor_divide(n1, blk) * blk, 0),
-            jnp.where(has_clean, jnp.floor_divide(c1, blk) * blk + blk, 0))
-        b_lo, b_hi = half, half + jnp.minimum(upto, half)
+        upto = xp.maximum(
+            xp.where(has_noisy, xp.floor_divide(n1, blk) * blk, 0),
+            xp.where(has_clean, xp.floor_divide(c1, blk) * blk + blk, 0))
+        b_lo, b_hi = half, half + xp.minimum(upto, half)
     else:
         # noisy queries: the noisy keys' own blocks, and for clean keys
         # every block after the first clean key's
-        after = jnp.minimum(half, jnp.floor_divide(c0, blk) * blk + blk)
-        a_lo = jnp.where(
+        after = xp.minimum(half, xp.floor_divide(c0, blk) * blk + blk)
+        a_lo = xp.where(
             has_noisy,
-            jnp.where(has_clean, jnp.minimum(own_lo, after), own_lo), after)
-        a_hi = jnp.where(has_clean, half, own_hi)
+            xp.where(has_clean, xp.minimum(own_lo, after), own_lo), after)
+        a_hi = xp.where(has_clean, half, own_hi)
         # clean queries: from the first clean key's block on
-        b_lo = half + jnp.floor_divide(c0, blk) * blk
-        b_hi = jnp.where(has_clean, seq_len, b_lo)
+        b_lo = half + xp.floor_divide(c0, blk) * blk
+        b_hi = xp.where(has_clean, seq_len, b_lo)
 
     def tiles(lo, hi):
-        t_lo = jnp.floor_divide(lo, other_block)
-        t_hi = jnp.minimum(
-            jnp.floor_divide(hi + other_block - 1, other_block), n_other)
+        t_lo = xp.floor_divide(lo, other_block)
+        t_hi = xp.minimum(
+            xp.floor_divide(hi + other_block - 1, other_block), n_other)
         some = hi > lo      # an empty range is (0, 0)
-        return jnp.where(some, t_lo, 0), jnp.where(some, t_hi, 0)
+        return xp.where(some, t_lo, 0), xp.where(some, t_hi, 0)
 
     lo1, hi1 = tiles(a_lo, a_hi)
     lo2, hi2 = tiles(b_lo, b_hi)
-    lo2 = jnp.maximum(lo2, hi1)
-    return (lo1, hi1), (lo2, jnp.maximum(hi2, lo2))
+    lo2 = xp.maximum(lo2, hi1)
+    return (lo1, hi1), (lo2, xp.maximum(hi2, lo2))
+
+
+def _tile_ranges(off, rows, other_block, n_other, seq_len, *, causal, window,
+                 kv_off, bd, rows_are_queries, xp=jnp):
+    """One program's loop bounds, whatever the mask kind and the side: the
+    tile of ``rows`` positions at ``off`` (queries in the forward and dQ
+    kernels, keys in the dK/dV kernels) against the other side's tiles of
+    ``other_block`` positions, as half-open tile ranges visited in rising
+    order."""
+    if bd is not None:
+        return _bd_ranges(off, rows, other_block, n_other, seq_len, bd,
+                          rows_are_queries, xp)
+    if rows_are_queries:
+        return (_kb_range(off, rows, other_block, n_other, causal, window,
+                          kv_off, xp),)
+    # Which Q blocks can see this K block = _kb_range with the q/k roles
+    # transposed (the offset flips sign, the window reach is symmetric).
+    # Causality is NOT symmetric: it becomes a LOWER bound here (the
+    # first Q block at or after the shifted diagonal), joined by max.
+    lo, hi = _kb_range(off, rows, other_block, n_other, False, window,
+                       -kv_off, xp)
+    if causal:
+        lo = xp.maximum(lo, xp.maximum(
+            0, xp.floor_divide(off + kv_off, other_block)))
+    return ((lo, hi),)
+
+
+def _run_tiles(ranges, body, carry):
+    """``carry = body(t, carry)`` for every tile ``t`` of every range, in
+    order, ``_TILES_AN_ITERATION`` tiles a loop iteration: one iteration is
+    one region for the scheduler, which overlaps its tiles (module
+    docstring).  Bounds may be traced, and ``lo >= hi`` runs nothing."""
+    for lo, hi in ranges:
+        for n in _TILES_AN_ITERATION:
+            steps = jnp.maximum(hi - lo, 0) // n
+
+            def several(i, carry, lo=lo, n=n):
+                for j in range(n):
+                    carry = body(lo + n * i + j, carry)
+                return carry
+
+            carry = jax.lax.fori_loop(0, steps, several, carry)
+            lo = lo + steps * n
+    return carry
+
+
+def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
+                window=None, kv_off=0, bd=None):
+    """One query head's tile visits and the loop iterations they take, by
+    kernel: ``{"fwd": (visited, iterations), "bwd_dq": ..., "bwd_dkv":
+    ...}`` for padded lengths ``s_q``, ``s_k`` in tiles of ``block_q`` x
+    ``block_k``.  Host arithmetic on the kernels' own bounds
+    (``_tile_ranges`` on numpy) and ``_run_tiles``' steps: where ``visited
+    / iterations`` is near 1 (a window of a tile, a sequence of two tiles)
+    walking several tiles an iteration wins nothing."""
+    kw = dict(causal=causal, window=window, kv_off=kv_off, bd=bd, xp=np)
+    by_queries = _tile_ranges(
+        np.arange(s_q // block_q) * block_q, block_q, block_k,
+        s_k // block_k, seq_len, rows_are_queries=True, **kw)
+    by_keys = _tile_ranges(
+        np.arange(s_k // block_k) * block_k, block_k, block_q,
+        s_q // block_q, seq_len, rows_are_queries=False, **kw)
+
+    def count(ranges, programs):
+        visited = iterations = 0
+        for lo, hi in ranges:
+            rest = np.broadcast_to(np.maximum(hi - lo, 0), (programs,))
+            visited += int(rest.sum())
+            for n in _TILES_AN_ITERATION:
+                iterations += int((rest // n).sum())
+                rest = rest % n
+        return visited, iterations
+
+    fwd = count(by_queries, s_q // block_q)
+    return {"fwd": fwd, "bwd_dq": fwd,
+            "bwd_dkv": count(by_keys, s_k // block_k)}
+
+
+def _note_tiles(kernels, kv_offset, **shape):
+    """One ``flash.tiles`` instant a kernel as it is traced: its name and a
+    head's ``visited`` tiles and loop ``iterations``.  ``kernels`` maps a
+    kernel's name to its key in ``tile_counts``.  Host bookkeeping at trace
+    time; a traced ``kv_offset`` (a ring step) has no count to give."""
+    if kv_offset is None:
+        kv_offset = 0
+    if not _trace.enabled() or not isinstance(kv_offset, int):
+        return
+    counts = tile_counts(kv_off=kv_offset, **shape)
+    for name, key in kernels.items():
+        visited, iterations = counts[key]
+        _trace.event("flash.tiles", kernel=name, visited=visited,
+                     iterations=iterations)
 
 
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
@@ -233,18 +359,14 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
         )
         return acc, l, new_m
 
-    acc = jnp.zeros((block_q, head_dim), jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    m = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    padded_len = k_ref.shape[1]
-    if bd is None:
-        ranges = (_kb_range(q_off, block_q, block_k,
-                            padded_len // block_k, causal, window, kv_off),)
-    else:
-        ranges = _bd_ranges(q_off, block_q, block_k, padded_len // block_k,
-                            seq_len, bd, True)
-    for lo_kb, n_kb in ranges:
-        acc, l, m = jax.lax.fori_loop(lo_kb, n_kb, body, (acc, l, m))
+    acc, l, m = _run_tiles(
+        _tile_ranges(q_off, block_q, block_k, k_ref.shape[1] // block_k,
+                     seq_len, causal=causal, window=window, kv_off=kv_off,
+                     bd=bd, rows_are_queries=True),
+        body,
+        (jnp.zeros((block_q, head_dim), jnp.float32),
+         jnp.zeros((block_q,), jnp.float32),
+         jnp.full((block_q,), _NEG_INF, jnp.float32)))
     # rows past the true sequence (or wholly out of window) are
     # all-masked (l == 0): emit zeros
     safe_l = jnp.where(l > 0, l, 1.0)
@@ -325,6 +447,9 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
         window=window,
         bd=bd,
     )
+    _note_tiles({"flash_attention_fwd": "fwd"}, kv_offset, s_q=s_q, s_k=s_k,
+                block_q=block_q, block_k=block_k, seq_len=orig_s,
+                causal=causal, window=window, bd=bd)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
@@ -361,9 +486,10 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
 def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
                  seq_len, block_q, block_k, window=None, kv_off=0, bd=None):
     """Exact softmax probabilities of one (block_q, block_k) tile from
-    the saved logsumexp — shared by both backward kernels.  Masked
-    entries are zeroed EXPLICITLY (not via the lse sentinel), so padded
-    rows and wholly-out-of-window rows stay inert whatever their lse."""
+    the saved logsumexp (the dQ kernel's; the dK/dV kernels compute the
+    same tile transposed).  Masked entries are zeroed EXPLICITLY (not via
+    the lse sentinel), so padded rows and wholly-out-of-window rows stay
+    inert whatever their lse."""
     s = jax.lax.dot_general(
         q_blk.astype(jnp.float32) * sm_scale, k_blk.astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
@@ -418,16 +544,11 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if bd is None:
-        ranges = (_kb_range(q_off, block_q, block_k,
-                            k_ref.shape[1] // block_k, causal, window,
-                            kv_off),)
-    else:
-        ranges = _bd_ranges(q_off, block_q, block_k,
-                            k_ref.shape[1] // block_k, seq_len, bd, True)
-    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    for lo_kb, n_kb in ranges:
-        dq = jax.lax.fori_loop(lo_kb, n_kb, body, dq)
+    dq = _run_tiles(
+        _tile_ranges(q_off, block_q, block_k, k_ref.shape[1] // block_k,
+                     seq_len, causal=causal, window=window, kv_off=kv_off,
+                     bd=bd, rows_are_queries=True),
+        body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
@@ -438,64 +559,71 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     the whole query-head group concatenated on the row axis
     ((1, group*s_q, d) blocks), and the group's contributions accumulate
     into the same (block_k, d) dK/dV — this is the GQA dK/dV reduction
-    done in VMEM, with K/V loaded once per kv head."""
+    done in VMEM, with K/V loaded once per kv head.  Tiles are computed
+    transposed, keys on the rows (``k q^T``, as the block-diffusion kernel
+    below): ``p^T`` and ``dS^T`` are what the two sums take, and computing
+    ``p`` first meant transposing two (block_q, block_k) float32 tiles a
+    tile visit.  The per-query ``lse`` and ``delta`` arrive as row vectors
+    ((1, 1, group*s_q) blocks, not lane-padded columns)."""
     ki = pl.program_id(1)
     kv_off = kvoff_ref[0]
     k_off = ki * block_k
-    k_blk = k_ref[0]
-    v_blk = v_ref[0]
+    k_blk = k_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0].astype(jnp.float32)
     d = k_blk.shape[-1]
     s_q = q_ref.shape[1] // group  # per-query-head padded length
-    n_qb = s_q // block_q
+    ranges = _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
+                          causal=causal, window=window, kv_off=kv_off,
+                          bd=None, rows_are_queries=False)
 
-    # Which Q blocks can see this K block = _kb_range with the q/k roles
-    # transposed (the offset flips sign, the window reach is symmetric).
-    # Causality is NOT symmetric: it becomes a LOWER bound here (the
-    # first Q block at or after the shifted diagonal), joined by max.
-    qb_start, qb_stop = _kb_range(k_off, block_k, block_q, n_qb,
-                                  False, window, -kv_off)
-    if causal:
-        qb_start = jnp.maximum(
-            qb_start,
-            jnp.maximum(0, jnp.floor_divide(k_off + kv_off, block_q)))
-
-    dk = jnp.zeros((block_k, d), jnp.float32)
-    dv = jnp.zeros((block_k, d), jnp.float32)
+    carry = (jnp.zeros((block_k, d), jnp.float32),
+             jnp.zeros((block_k, d), jnp.float32))
     for g in range(group):  # static unroll over the query-head group
         base = g * s_q
 
         def body(qb, carry, base=base):
             dk, dv = carry
             q_off = qb * block_q
-            q_blk = q_ref[0, pl.ds(base + q_off, block_q), :]
+            q_blk = q_ref[0, pl.ds(base + q_off, block_q), :].astype(
+                jnp.float32)
             do_blk = do_ref[0, pl.ds(base + q_off, block_q), :].astype(
                 jnp.float32)
-            lse_blk = lse_ref[0, pl.ds(base + q_off, block_q), 0]
-            delta_blk = delta_ref[0, pl.ds(base + q_off, block_q), 0]
-            p = _recompute_p(
-                q_blk, k_blk, lse_blk, q_off, k_off, sm_scale=sm_scale,
-                causal=causal, seq_len=seq_len, block_q=block_q,
-                block_k=block_k, window=window, kv_off=kv_off,
-            )
-            dv = dv + jax.lax.dot_general(
-                p, do_blk,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dp = jax.lax.dot_general(
-                do_blk, v_blk.astype(jnp.float32),
+            lse_blk = lse_ref[0, :, pl.ds(base + q_off, block_q)]  # (1, block_q)
+            delta_blk = delta_ref[0, :, pl.ds(base + q_off, block_q)]
+            st = jax.lax.dot_general(               # (block_k, block_q)
+                k_blk, q_blk * sm_scale,
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            ds = p * (dp - delta_blk[:, None])
+            k_pos = k_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            mask = jnp.logical_and(
+                _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
+                q_pos < seq_len,
+            )
+            pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
+            dv = dv + jax.lax.dot_general(
+                pt, do_blk,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dpt = jax.lax.dot_general(
+                v_blk, do_blk,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dst = pt * (dpt - delta_blk)
             dk = dk + jax.lax.dot_general(
-                ds, q_blk.astype(jnp.float32),
-                dimension_numbers=(((0,), (0,)), ((), ())),
+                dst, q_blk,
+                dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             return dk, dv
 
-        dk, dv = jax.lax.fori_loop(qb_start, qb_stop, body, (dk, dv))
+        carry = _run_tiles(ranges, body, carry)
+    dk, dv = carry
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -553,12 +681,11 @@ def _bwd_dkv_bd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
         return dk, dv
 
-    carry = (dk_acc[...], dv_acc[...])
-    for lo_qb, n_qb in _bd_ranges(k_off, block_k, block_q,
-                                  q_ref.shape[1] // block_q, seq_len, bd,
-                                  False):
-        carry = jax.lax.fori_loop(lo_qb, n_qb, body, carry)
-    dk_acc[...], dv_acc[...] = carry
+    dk_acc[...], dv_acc[...] = _run_tiles(
+        _tile_ranges(k_off, block_k, block_q, q_ref.shape[1] // block_q,
+                     seq_len, causal=False, window=None, kv_off=0, bd=bd,
+                     rows_are_queries=False),
+        body, (dk_acc[...], dv_acc[...]))
 
     @pl.when(g == group - 1)
     def _():
@@ -587,6 +714,11 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     off = _off_arr(kv_offset)
     kw = dict(sm_scale=1.0 / (d ** 0.5), causal=causal, block_q=block_q,
               block_k=block_k, seq_len=orig_s, window=window)
+    _note_tiles({"flash_attention_bwd_dq": "bwd_dq",
+                 "flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
+                 "bwd_dkv"},
+                kv_offset, s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
+                seq_len=orig_s, causal=causal, window=window, bd=bd)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bd=bd, **kw),
         name="flash_attention_bwd_dq",
@@ -643,8 +775,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     # reshape of the head-major fold (B, H_kv, G, s_q, d contiguity)
     qg = qf.reshape(bh_kv, group * s_q, d)
     gg = gf.reshape(bh_kv, group * s_q, d)
-    lse_g = lse_f.reshape(bh_kv, group * s_q, 1)
-    delta_g = delta_f.reshape(bh_kv, group * s_q, 1)
+    lse_g = lse_f.reshape(bh_kv, 1, group * s_q)
+    delta_g = delta_f.reshape(bh_kv, 1, group * s_q)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, group=group, **kw),
         name="flash_attention_bwd_dkv",
@@ -655,8 +787,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, group * s_q, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, group * s_q, 1), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, group * s_q, 1), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),

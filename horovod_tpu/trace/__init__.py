@@ -91,6 +91,7 @@ SITES = (
     "guard.exchange",      # cross-rank digest/vote exchange (cadence)
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
+    "flash.tiles",         # a flash kernel traced: tile visits, iterations
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the

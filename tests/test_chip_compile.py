@@ -82,6 +82,10 @@ FLASH_CASES = {
                            window=None),
     "mha_d64_noncausal": dict(b=4, s=2048, h=12, kv=12, d=64, causal=False,
                               window=None),
+    # internlm2-1.8b-s4096-1chip: four tiles a loop iteration in every
+    # kernel, the group of two query heads a dK/dV program
+    "internlm2_cell": dict(b=1, s=4096, h=16, kv=8, d=128, causal=True,
+                           window=None),
 }
 
 

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models.transformer import causal_dot_attention
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.flash_attention import flash_attention, tile_counts
 
 
 def _qkv(b, s, h, d, dtype, seed=0):
@@ -19,8 +19,9 @@ def _qkv(b, s, h, d, dtype, seed=0):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("s", [256, 384])
+@pytest.mark.parametrize("s", [256, 384, 1024])
 def test_flash_matches_dense_causal(dtype, s):
+    # 1024: loops of one to eight 128-tiles, in iterations of four, two, one
     q, k, v = _qkv(2, s, 2, 64, dtype)
     ref = causal_dot_attention(q, k, v)
     out = flash_attention(q, k, v, block_q=128, block_k=128)
@@ -60,16 +61,22 @@ def test_transformer_flash_impl_matches_dot():
     )
 
 
+@pytest.mark.parametrize("s,block", [(256, 256), (1024, 128)],
+                         ids=["one_tile", "eight_tiles"])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-3),
                                        (jnp.bfloat16, 1e-1)])
-def test_flash_gradients_match_dense(dtype, tol):
+def test_flash_gradients_match_dense(dtype, tol, s, block):
     """Training through the kernel: custom_vjp gradients must match the
     dense path's (backward recomputes with the kernel's upcast numerics;
-    bf16 compares loosely against the model's dense reference)."""
-    q, k, v = _qkv(1, 256, 2, 32, dtype, seed=3)
+    bf16 compares loosely against the model's dense reference).  Eight
+    tiles a side: dQ's and dK/dV's loops run one to eight tiles, in
+    iterations of four, two and one (``tile_counts``: 36 visits in 14)."""
+    assert tile_counts(1024, 1024, 128, 128, 1024)["bwd_dkv"] == (36, 14)
+    q, k, v = _qkv(1, s, 2, 32, dtype, seed=3)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v).astype(jnp.float32) ** 2).sum()
+        return (flash_attention(q, k, v, block_q=block, block_k=block
+                                ).astype(jnp.float32) ** 2).sum()
 
     def loss_dense(q, k, v):
         return (
@@ -176,3 +183,25 @@ def test_flash_non_causal_gradients():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3
         )
+
+
+def test_flash_tiles_event_names_each_kernel_traced():
+    """Tracing a kernel leaves one ``flash.tiles`` instant with its name and
+    a head's tile visits and loop iterations (``tile_counts``); the
+    block-diffusion kind names its own dK/dV kernel."""
+    from horovod_tpu import trace
+
+    def traced(**kw):
+        t0 = trace.now()
+        q = jnp.ones((1, 512, 2, 16), jnp.float32)
+        jax.make_jaxpr(jax.grad(lambda a: flash_attention(
+            a, a, a, block_q=128, block_k=128, **kw).sum()))(q)
+        return {r[3]["kernel"]: (r[3]["visited"], r[3]["iterations"])
+                for r in trace.snapshot(t0) if r[0] == "flash.tiles"}
+
+    assert traced(window=300) == {
+        "flash_attention_fwd": (10, 5), "flash_attention_bwd_dq": (10, 5),
+        "flash_attention_bwd_dkv": (10, 5)}
+    assert traced(block_diffusion=(256, 4)) == {
+        "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
+        "flash_attention_bwd_dkv_bd": (8, 6)}

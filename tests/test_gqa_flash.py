@@ -9,7 +9,8 @@ oracle sum each kv head's group automatically (autodiff of repeat is
 the grouped sum), which pins the kernels' in-VMEM dK/dV accumulation.
 
 Also here: the `_kb_range` block-skip property test (the bounds the
-windowed kernels AND the bench's modeled columns both rely on) and the
+windowed kernels AND, through `tile_counts`, the bench's modeled columns
+both rely on) and the
 modeled-attention-bytes pin for the ~num_heads/num_kv_heads K/V
 traffic reduction (ISSUE 5 acceptance).
 """
@@ -24,7 +25,8 @@ import pytest
 
 from horovod_tpu.models.transformer import causal_dot_attention
 from horovod_tpu.ops.flash_attention import (
-    _kb_range, flash_attention,
+    _TILES_AN_ITERATION, _bd_tile_mask, _kb_range, _tile_mask, _tile_ranges,
+    flash_attention, tile_counts,
 )
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -179,9 +181,7 @@ def _bounds_int(fn, *args):
 def test_kb_range_bounds_property():
     """kv_off=0 (self/diagonal attention): [lo, hi) covers EXACTLY the
     causal/window-unmasked K blocks — no block skipped that has work,
-    no empty block visited at either edge.  The bench's pure-python
-    mirror (tools/flash_bench.kb_bounds) must agree bit-for-bit."""
-    fb = _load_flash_bench()
+    no empty block visited at either edge."""
     for block_q, block_k in ((64, 64), (128, 64), (64, 128)):
         for padded_kb in (2, 3):
             s_k = padded_kb * block_k
@@ -199,16 +199,12 @@ def test_kb_range_bounds_property():
                             f"q_off={q_off} causal={causal} "
                             f"window={window}: {sorted(got)} != "
                             f"{sorted(want)}")
-                        assert (lo, hi) == fb.kb_bounds(
-                            q_off, block_q, block_k, padded_kb, causal,
-                            window, 0)
 
 
 def test_kb_range_bounds_with_offset():
     """kv_off != 0 (ring off-diagonal blocks): the bounds must CONTAIN
     every unmasked block (correctness — a skipped block with work would
-    silently drop attention mass), and the bench mirror agrees."""
-    fb = _load_flash_bench()
+    silently drop attention mass)."""
     rng = np.random.RandomState(0)
     for _ in range(200):
         block_q = int(rng.choice([32, 64]))
@@ -226,8 +222,171 @@ def test_kb_range_bounds_with_offset():
             f"bq={block_q} bk={block_k} kb={padded_kb} q_off={q_off} "
             f"causal={causal} window={window} kv_off={kv_off}: "
             f"{sorted(want)} not within [{lo}, {hi})")
-        assert (lo, hi) == fb.kb_bounds(q_off, block_q, block_k,
-                                        padded_kb, causal, window, kv_off)
+
+
+# -- every kernel's loop bounds against the mask, tile by tile ----------------
+
+
+def _check_ranges(ranges, mask_of, n_other):
+    """One program's loop ranges against the mask of each tile of the other
+    side: a tile that holds an allowed pair is visited, exactly once and in
+    rising order; a tile no range visits holds none."""
+    visited = []
+    for lo, hi in ranges:
+        visited += range(int(lo), int(hi))   # lo >= hi: an empty range
+    assert visited == sorted(set(visited)), ranges
+    assert not visited or 0 <= visited[0] and visited[-1] < n_other, ranges
+    for t in set(range(n_other)) - set(visited):
+        assert not mask_of(t).any(), (t, ranges)
+
+
+@pytest.mark.parametrize("seq_len", [96, 83], ids=["aligned", "padded"])
+@pytest.mark.parametrize("window", [None, 5, 40],
+                         ids=["nowin", "win<tile", "win>tile"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "bidir"])
+def test_tile_ranges_visit_every_tile_the_mask_allows(causal, window,
+                                                      seq_len):
+    """``_tile_ranges`` from both sides (queries on the rows: forward and
+    dQ; keys on the rows: dK/dV) against ``_tile_mask`` itself, over tile
+    shapes and positive and negative ``kv_off``: what the loops skip has no
+    allowed pair."""
+    s_pad = 96
+    for block_q, block_k in ((16, 16), (32, 16), (16, 32)):
+        for kv_off in (-48, -7, 0, 16, 64):
+            q_pos = np.arange(s_pad)[:, None]
+            k_pos = np.arange(s_pad)[None, :]
+            mask = np.broadcast_to(np.asarray(_tile_mask(
+                q_pos, k_pos, causal, window, seq_len, kv_off)),
+                (s_pad, s_pad)) & (q_pos < seq_len)
+            kw = dict(causal=causal, window=window, kv_off=kv_off, bd=None,
+                      xp=np)
+            for q_off in range(0, s_pad, block_q):
+                _check_ranges(
+                    _tile_ranges(q_off, block_q, block_k, s_pad // block_k,
+                                 seq_len, rows_are_queries=True, **kw),
+                    lambda t: mask[q_off:q_off + block_q,
+                                   t * block_k:(t + 1) * block_k],
+                    s_pad // block_k)
+            for k_off in range(0, s_pad, block_k):
+                _check_ranges(
+                    _tile_ranges(k_off, block_k, block_q, s_pad // block_q,
+                                 seq_len, rows_are_queries=False, **kw),
+                    lambda t: mask[t * block_q:(t + 1) * block_q,
+                                   k_off:k_off + block_k],
+                    s_pad // block_q)
+
+
+@pytest.mark.parametrize("half,blk,block_q,block_k", [
+    (128, 4, 32, 32),    # the cell's kind: blocks of 4, L a multiple of the tile
+    (128, 32, 32, 32),   # blocks of 32, as long as a tile
+    (100, 4, 32, 32),    # a tile straddles L, the last one is padded
+    (96, 32, 64, 32),    # rectangular tiles
+    (100, 7, 32, 16),    # B divides neither L nor a tile
+])
+def test_block_diffusion_tile_ranges_visit_what_the_mask_allows(
+        half, blk, block_q, block_k):
+    """The same for the block-diffusion kind, both orientations of
+    ``_bd_tile_mask`` (which agree)."""
+    seq_len = 2 * half
+    s_pad = -(-seq_len // max(block_q, block_k)) * max(block_q, block_k)
+    mask = np.asarray(_bd_tile_mask(0, 0, s_pad, s_pad, seq_len,
+                                    (half, blk), True))
+    assert (mask == np.asarray(_bd_tile_mask(
+        0, 0, s_pad, s_pad, seq_len, (half, blk), False)).T).all()
+    kw = dict(causal=False, window=None, kv_off=0, bd=(half, blk), xp=np)
+    for q_off in range(0, s_pad, block_q):
+        _check_ranges(
+            _tile_ranges(q_off, block_q, block_k, s_pad // block_k, seq_len,
+                         rows_are_queries=True, **kw),
+            lambda t: mask[q_off:q_off + block_q,
+                           t * block_k:(t + 1) * block_k],
+            s_pad // block_k)
+    for k_off in range(0, s_pad, block_k):
+        _check_ranges(
+            _tile_ranges(k_off, block_k, block_q, s_pad // block_q, seq_len,
+                         rows_are_queries=False, **kw),
+            lambda t: mask[t * block_q:(t + 1) * block_q,
+                           k_off:k_off + block_k],
+            s_pad // block_q)
+
+
+@pytest.mark.parametrize("cell,kw,want", [
+    ("internlm2-1.8b-s4096-1chip",
+     dict(s_q=4096, s_k=4096, seq_len=4096, causal=True), (136, 44)),
+    ("sdar-30b-a3b-bd4-s4096-1chip",
+     dict(s_q=8192, s_k=8192, seq_len=8192, causal=False, bd=(4096, 4)),
+     (288, 104)),
+])
+def test_tile_counts_at_the_cells_shapes(cell, kw, want):
+    """A head's tile visits and the loop iterations they take in 256-tiles:
+    3.1 and 2.8 tiles an iteration for the scheduler to overlap."""
+    counts = tile_counts(block_q=256, block_k=256, **kw)
+    assert counts == {"fwd": want, "bwd_dq": want, "bwd_dkv": want}
+
+
+@pytest.mark.parametrize("causal,window,seq_len", [
+    (True, None, 1024), (True, 300, 1000), (False, 300, 1000),
+    (False, None, 1000), (True, 100, 1024)])
+def test_tile_counts_follow_the_kernels_loop_bounds(causal, window, seq_len):
+    """``visited`` is the sum of the kernels' own ``_kb_range`` ranges (the
+    bench's ``_kv_tiles``), the same pairs from both sides; ``iterations``
+    is what ``_run_tiles`` takes for each range: four tiles at a time,
+    then two, then one."""
+    fb = _load_flash_bench()
+    s_pad, blk = 1024, 128
+    counts = tile_counts(s_pad, s_pad, blk, blk, seq_len, causal=causal,
+                         window=window)
+    lengths = [max(0, hi - lo) for lo, hi in (
+        _bounds_int(_kb_range, q_off, blk, blk, s_pad // blk, causal,
+                    window, 0) for q_off in range(0, s_pad, blk))]
+    assert sum(lengths) == fb._kv_tiles(seq_len, causal, window, blk, blk)
+    assert {v for v, _ in counts.values()} == {sum(lengths)}
+
+    def iterations(n):
+        steps = 0
+        for size in _TILES_AN_ITERATION:
+            steps, n = steps + n // size, n % size
+        return steps
+
+    assert counts["fwd"][1] == sum(iterations(n) for n in lengths)
+    assert iterations(7) == 3 and iterations(1) == 1 and iterations(0) == 0
+    if window == 100:   # a tile or two a program: little to overlap
+        assert counts["fwd"][0] < 2 * counts["fwd"][1]
+    elif window is None and not causal:
+        assert counts["fwd"] == (64, 16)
+
+
+@pytest.mark.parametrize("dtype,tol,gtol", [(jnp.float32, 2e-5, 1e-3),
+                                            (jnp.bfloat16, 2e-2, 1e-1)])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 300),
+                                           (False, None)])
+def test_flash_gqa_several_tiles_an_iteration_match_oracle(causal, window,
+                                                           dtype, tol, gtol):
+    """Forward and gradients where the programs' loops take iterations of
+    four, two and one tile (S = 700 in 128-tiles, padded to 768: ranges of
+    one to six tiles; two query heads a kv head, the dK/dV tile computed
+    transposed), float32 and bfloat16 inputs, at the existing tolerances."""
+    s = 700
+    counts = tile_counts(768, 768, 128, 128, s, causal=causal, window=window)
+    assert all(iterations < visited for visited, iterations in counts.values())
+    q, k, v = _qkv(1, s, 4, 2, 32, dtype=dtype, seed=13)
+
+    def loss(fn):
+        return lambda a, b, c: (fn(a, b, c).astype(jnp.float32) ** 2).sum()
+
+    flash = lambda a, b, c: flash_attention(
+        a, b, c, causal=causal, window=window, block_q=128, block_k=128)
+    oracle = lambda a, b, c: repeat_oracle(a, b, c, causal=causal,
+                                           window=window)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(oracle(q, k, v), np.float32), rtol=tol, atol=tol)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=gtol, atol=gtol)
 
 
 # -- modeled K/V traffic (ISSUE 5 acceptance pin) ---------------------------

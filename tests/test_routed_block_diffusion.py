@@ -115,6 +115,9 @@ def _dense_attention(q, k, v, mask):
     (160, 32, 8, 1, 128),   # B 32, eight query heads a key/value head
     (72, 4, 2, 2, 256),     # one tile holds both halves
     (130, 7, 2, 1, 128),    # B divides neither L nor the tile
+    (384, 4, 4, 2, 128),    # the cell's kind, three tiles a half: loop
+                            # iterations of four, two and one tile
+    (320, 32, 2, 1, 128),   # the same where a tile straddles L
 ])
 def test_flash_block_diffusion_matches_the_dense_mask(half, block, heads, kv, tile):
     keys = jax.random.split(jax.random.PRNGKey(half), 4)

@@ -9,9 +9,9 @@ kernel's BlockSpecs imply (K/V fetched once per KV head, Q/out once per
 query head) — so the ``num_heads/num_kv_heads`` K/V-read reduction is
 pinned even on a CPU box where wall-clock runs in interpret mode; the
 chip legs have not been re-run on today's code.  The window legs carry the
-modeled-FLOPs column from the same block-skip bounds the kernels use
-(``kb_bounds`` mirrors ``ops.flash_attention._kb_range`` and is
-property-tested against it in tests/test_gqa_flash.py).
+modeled-FLOPs column from the block-skip bounds the kernels use
+(``ops.flash_attention.tile_counts``: ``_kb_range`` itself, on numpy;
+property-tested in tests/test_gqa_flash.py).
 
 Timing uses chained iterations with a scalar fetch as the sync.
 ``HVD_TPU_BENCH_ITERS`` / ``HVD_TPU_BENCH_WARMUP``
@@ -21,6 +21,8 @@ Usage:
   flash_bench.py                 # chip kernel legs (dense vs flash)
   flash_bench.py --gqa           # GQA ratio sweep (1/2/4/8)
   flash_bench.py --window        # window sweep at fixed S
+  flash_bench.py --cells         # forward, dQ and dK/dV apart at the
+                                 #  benchmark cells' shapes (PERF.md §5)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -39,7 +41,8 @@ import jax.numpy as jnp  # noqa: E402
 from horovod_tpu.common.retry import env_int  # noqa: E402
 from horovod_tpu.models.transformer import causal_dot_attention  # noqa: E402
 from horovod_tpu.ops.flash_attention import (  # noqa: E402
-    _clamp_blocks, flash_attention,
+    _backward_impl, _clamp_blocks, _forward_impl, flash_attention,
+    tile_counts,
 )
 
 
@@ -53,33 +56,12 @@ def _pad(s, m):
     return s + (-s) % m
 
 
-def kb_bounds(q_off, block_q, block_k, padded_kb, causal, window, kv_off=0):
-    """Pure-python mirror of ``ops.flash_attention._kb_range``: [lo, hi)
-    K-block loop bounds for one Q block (the windowed/causal block skip).
-    Property-tested against the kernel's version, so the modeled columns
-    below track exactly what the kernels execute."""
-    hi = padded_kb
-    if causal:
-        hi = min(hi, (q_off + block_q - 1 - kv_off) // block_k + 1)
-    elif window is not None:
-        hi = min(
-            hi, (q_off + block_q - 1 + window - 1 - kv_off) // block_k + 1)
-    if window is None:
-        lo = 0
-    else:
-        lo = max(0, (q_off - (window - 1) - kv_off) // block_k)
-    return lo, max(hi, 0)
-
-
 def _kv_tiles(s, causal, window, block_q, block_k):
-    """Total (Q block, K block) tile pairs the forward kernel visits."""
+    """Total (Q block, K block) tile pairs the forward kernel visits: the
+    kernels' own loop bounds (``_kb_range``), summed on the host."""
     bq, bk = _clamp_blocks(s, block_q, block_k)
-    sq, sk = _pad(s, bq), _pad(s, bk)
-    tiles = 0
-    for qi in range(sq // bq):
-        lo, hi = kb_bounds(qi * bq, bq, bk, sk // bk, causal, window)
-        tiles += max(0, hi - lo)
-    return tiles
+    return tile_counts(_pad(s, bq), _pad(s, bk), bq, bk, s, causal=causal,
+                       window=window)["fwd"][0]
 
 
 def modeled_attention_bytes(b, s, h, h_kv, d,
@@ -249,11 +231,68 @@ def leg_window(b, s, h, d, windows, iters, warmup, interpret,
         )
 
 
+# (b, s, h, h_kv, d, causal, block_diffusion) of the benchmark's two
+# transformer cells (benchmark/configs/): what a layer's attention sees
+CELL_SHAPES = {
+    "internlm2-1.8b-s4096-1chip": (1, 4096, 16, 8, 128, True, None),
+    "sdar-30b-a3b-bd4-s4096-1chip": (1, 8192, 32, 4, 128, False, (4096, 4)),
+}
+
+
+def leg_cells(shapes, iters, warmup, interpret, block=256):
+    """The three training kernels apart, one layer's call each: the dQ and
+    the dK/dV program are the two halves of ``_backward_impl`` (a jit
+    that returns one of them drops the other kernel).  Beside each time, a
+    head's tile visits and the loop iterations they take (``tile_counts``)
+    and the time a tile visit, which PERF.md §5 holds against the 0.085 us
+    a 256 x 256 x 128 product needs on the v5e's MXU."""
+    for cell, (b, s, h, h_kv, d, causal, bd) in shapes.items():
+        q, k, v = _qkv(b, s, h, h_kv, d)
+        g = _qkv(b, s, h, h_kv, d, seed=1)[0]
+        def fwd(q, k, v):
+            return _forward_impl(q, k, v, causal, block, block, interpret,
+                                 with_lse=True, bd=bd)
+
+        def bwd(q, k, v, out, lse, g):
+            return _backward_impl(q, k, v, out, lse, g, causal, block, block,
+                                  interpret, bd=bd)
+
+        def timed(fn, *args):
+            fn = jax.jit(fn)
+            for _ in range(warmup):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                out = fn(*args)
+            jax.block_until_ready(out)  # one device queue: calls run in turn
+            return (time.perf_counter() - t0) / iters * 1e3
+
+        out, lse = jax.jit(fwd)(q, k, v)
+        ms = {"fwd": timed(fwd, q, k, v),
+              "bwd_dq": timed(lambda *a: bwd(*a)[0], q, k, v, out, lse, g),
+              "bwd_dkv": timed(lambda *a: bwd(*a)[1:], q, k, v, out, lse, g)}
+        bq, bk = _clamp_blocks(s, block, block)
+        tiles = tile_counts(_pad(s, bq), _pad(s, bk), bq, bk, s,
+                            causal=causal, bd=bd)
+        rec = {"bench": "flash_cells", "cell": cell, "b": b, "s": s, "h": h,
+               "h_kv": h_kv, "d": d, "block": [bq, bk]}
+        for name, t in ms.items():
+            visited, iterations = tiles[name]
+            rec[name + "_ms"] = round(t, 4)
+            rec[name + "_tiles"] = [visited, iterations]
+            rec[name + "_us_per_tile"] = round(t * 1e3 / (b * h * visited), 4)
+        _emit(rec, f"{cell}: " + "  ".join(
+            f"{n} {t:7.3f} ms" for n, t in ms.items())
+            + f"  tiles a head {tiles['fwd'][0]} in {tiles['fwd'][1]} iterations")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--gqa", action="store_true")
     ap.add_argument("--window", action="store_true")
     ap.add_argument("--kernel", action="store_true")
+    ap.add_argument("--cells", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny interpret-mode pass of every leg (CI)")
     args = ap.parse_args(argv)
@@ -272,9 +311,12 @@ def main(argv=None):
         leg_gqa(1, 256, 4, 64, (1, 2, 4), 2, 1, True)
         leg_window(1, 384, 2, 64, (None, 128), 2, 1, True,
                    block_q=128, block_k=128)
+        leg_cells({"causal-gqa": (1, 512, 4, 2, 32, True, None),
+                   "block-diffusion": (1, 512, 4, 1, 32, False, (256, 4))},
+                  2, 1, True, block=128)
         return 0
 
-    run_all = not (args.gqa or args.window or args.kernel)
+    run_all = not (args.gqa or args.window or args.kernel or args.cells)
     if args.kernel or run_all:
         leg_kernel([(4, 1024, 8, 128), (4, 2048, 8, 128),
                     (2, 4096, 8, 128)], iters, warmup, None)
@@ -283,6 +325,8 @@ def main(argv=None):
     if args.window or run_all:
         leg_window(2, 4096, 8, 128,
                    (None, 2048, 1024, 512, 256), iters, warmup, None)
+    if args.cells or run_all:
+        leg_cells(CELL_SHAPES, iters, warmup, None)
     return 0
 
 
