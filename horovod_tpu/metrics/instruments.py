@@ -69,39 +69,16 @@ OP_LATENCY = histogram(
     ["op"],
 )
 
-# -- backward/collective overlap (ops/overlap.py) ----------------------------
+# -- bucketed submission (torch/optimizer.py, ops/fusion.BucketSchedule) ------
 
-#: Stream-byte share of gradient collectives that trail ALL backward
-#: compute in the compiled step — the static exposed-comm fraction the
-#: bucket schedule exists to shrink (1.0 = unoverlapped jax.grad step;
-#: ~ last-bucket share when the schedule interleaves).  Set from the
-#: lowered program by ``ops.overlap.record_overlap_metrics``.
-OVERLAP_EXPOSED_FRACTION = gauge(
-    "hvd_tpu_overlap_exposed_comm_fraction",
-    "Stream-byte fraction of gradient collectives trailing all backward "
-    "compute in the compiled step (static schedule view)",
-)
-
-#: How early each bucket's collective launches: matmul-class compute ops
-#: still scheduled after the launch point (0 = the bucket trails; the
-#: torch bridge observes parameters still awaiting gradients instead).
+#: How early each bucket's collective launches: parameters still
+#: awaiting gradients when the torch bridge submits the bucket (0 = the
+#: bucket trails the whole backward).
 OVERLAP_LAUNCH_LEAD = histogram(
     "hvd_tpu_overlap_bucket_launch_lead",
-    "Backward compute remaining when a bucket's collective launches "
-    "(compute ops after launch; torch: params still pending)",
+    "Backward work remaining when a bucket's collective launches "
+    "(torch bridge: parameters still awaiting gradients)",
     buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128),
-)
-
-#: Bucket-size/tier trials the BucketAutotuner has scored.
-OVERLAP_AUTOTUNE_TRIALS = counter(
-    "hvd_tpu_overlap_autotune_trials_total",
-    "Bucket-schedule candidates scored by the overlap autotuner",
-)
-
-#: The pinned (converged) bucket size; 0 until convergence.
-OVERLAP_AUTOTUNE_PINNED_BYTES = gauge(
-    "hvd_tpu_overlap_autotune_pinned_bucket_bytes",
-    "Bucket bytes of the overlap autotuner's pinned winning plan",
 )
 
 # -- sharded optimizer (optim.py ZeRO wrappers) ------------------------------

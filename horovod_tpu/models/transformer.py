@@ -624,85 +624,6 @@ def block_diffusion_loss(outputs, labels, aux_coef: float = 0.0):
     return loss
 
 
-def overlap_segments(model: "Transformer", tokens, targets,
-                     loss_fn=None):
-    """Segment-chain view of :class:`Transformer` for the
-    backward/collective overlap scheduler (``ops/overlap.py``,
-    docs/tensor-fusion.md): one :class:`~horovod_tpu.ops.overlap.Segment`
-    per decoder block plus the embed and head links, each applying the
-    SAME flax submodules ``__call__`` composes (``Block``/``nn.Embed``/
-    ``nn.RMSNorm`` applied standalone against their param subtrees), so
-    the chain's math is identical op-for-op — only the backward gains
-    bucket boundaries.  The tied embedding is read by both the first and
-    last segment; its gradient therefore completes at the embed segment
-    and rides the final bucket.
-
-    Per-block remat policies compose: a non-``none`` policy wraps that
-    block's segment in ``jax.checkpoint`` with the same policy the
-    in-module ``nn.remat`` lift would use.
-
-    The sequence-sharded ring impls position tokens off the mesh axis —
-    segment them via the multi-axis chain
-    (``parallel.sharded.overlap_segments``) instead.
-    """
-    from ..ops.overlap import Segment
-
-    cfg = model.cfg
-    if cfg.num_experts is not None or cfg.block_diffusion is not None \
-            or not cfg.tie_word_embeddings:
-        raise ValueError(
-            "overlap_segments has no chain for a routed feed-forward, "
-            "block diffusion or an untied head; use the plain step")
-    if cfg.attention_impl in ("ring", "ring_flash"):
-        raise ValueError(
-            "overlap_segments does not support the sequence-sharded ring "
-            "impls; use parallel.sharded.overlap_segments' chain or the "
-            "plain (unoverlapped) step"
-        )
-    if loss_fn is None:
-        import optax
-
-        def loss_fn(logits, labels):
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits, labels
-            ).mean()
-
-    positions = jnp.broadcast_to(
-        jnp.arange(tokens.shape[1]), tokens.shape
-    )
-    embed_mod = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype)
-
-    def seg_embed(params, toks):
-        return embed_mod.apply({"params": params["embed"]}, toks)
-
-    def make_block(i, policy):
-        def seg(params, x):
-            return Block(cfg).apply(
-                {"params": params[f"layer_{i}"]}, x, positions
-            )
-
-        if policy != "none":
-            seg = jax.checkpoint(seg, policy=_checkpoint_policy(policy))
-        return Segment(seg, keys=(f"layer_{i}",))
-
-    def seg_head(params, x):
-        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps).apply(
-            {"params": params["ln_f"]}, x
-        )
-        logits = embed_mod.apply(
-            {"params": params["embed"]}, x.astype(jnp.float32),
-            method=nn.Embed.attend,
-        )
-        return loss_fn(logits, targets)
-
-    policies = cfg.block_remat_policies()
-    return (
-        [Segment(seg_embed, keys=("embed",))]
-        + [make_block(i, policies[i]) for i in range(cfg.num_layers)]
-        + [Segment(seg_head, keys=("ln_f", "embed"))]
-    )
-
-
 def modeled_activation_bytes(cfg: TransformerConfig, batch: int,
                              seq: Optional[int] = None) -> dict:
     """Modeled forward-to-backward activation bytes under the config's

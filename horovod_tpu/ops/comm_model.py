@@ -225,10 +225,9 @@ def _collective_records(
     lowered_text: str, default_group: int
 ) -> List[Dict[str, object]]:
     """Inventory every collective instruction of a lowered (StableHLO)
-    module: kind, line index, payload bytes, replica groups, and the
-    ring-stream per-chip link bytes.  The shared parser behind
-    :func:`measured_tier_bytes` (tier attribution) and
-    :func:`overlap_inventory` (program-order interleave check)."""
+    module: kind, payload bytes, replica groups, and the ring-stream
+    per-chip link bytes.  The parser behind
+    :func:`measured_tier_bytes` (tier attribution)."""
     lines = lowered_text.splitlines()
     records: List[Dict[str, object]] = []
     for i, line in enumerate(lines):
@@ -265,7 +264,7 @@ def _collective_records(
             g = max(len(groups[0]), 1) if groups else 1
             stream = int(_COLLECTIVE_FACTOR[kind] * (g - 1) * payload // g)
         records.append({
-            "op": kind, "line": i, "end_line": j, "groups": groups,
+            "op": kind, "groups": groups,
             "payload_bytes": payload, "group_size": g,
             "stream_bytes": stream,
         })
@@ -441,81 +440,7 @@ def serve_gather_read_bytes(lowered_text: str, min_rank: int = 5) -> dict:
     return {"gather_bytes": int(total), "ops": ops}
 
 
-# -- backward/collective overlap: program-order and timing models ------------
-
-#: compute markers of the interleave check: MXU-bound ops a backward
-#: segment is made of.  Elementwise chains don't count — a collective is
-#: "overlapped" only when real (matmul-class) compute is scheduled after
-#: its launch point.
-_COMPUTE_RE = re.compile(
-    r"stablehlo\.(dot_general|dot\b|convolution)"
-)
-
-
-def overlap_inventory(
-    lowered_text: str, min_payload_bytes: int = 0
-) -> Dict[str, object]:
-    """Program-order interleave check of a compiled step
-    (docs/tensor-fusion.md): for each collective, how much matmul-class
-    compute the lowered module schedules before and after it.
-
-    A ``jax.grad``-then-allreduce step shows every collective TRAILING
-    (``compute_after == 0`` for all of them — the whole comm time is
-    exposed); the overlapped step of ``ops/overlap.py`` pins each
-    bucket's collective between segment computations, so all but the
-    last bucket carry ``compute_after > 0``.  ``exposed_fraction`` is
-    the stream-byte share of trailing collectives — the static
-    (schedule-structure) view of the exposed-comm fraction whose
-    wall-clock twin the chip bench measures.
-
-    ``min_payload_bytes`` filters scalar control collectives (the loss
-    pmean) out of a full train step's inventory.  Returns
-    ``{"collectives": [...], "total_stream_bytes",
-    "trailing_stream_bytes", "exposed_fraction", "interleaved"}``
-    (``interleaved``: at least one collective launches with compute
-    still after it AND the trailing share is below 1 — a trailing-only
-    program is False.  A single-collective bucket trails only when it
-    is the last bucket; a multi-collective bucket — the two-level
-    hierarchical reduction is three ops — legitimately trails with its
-    whole final group, which is why the flag is not "every non-final op
-    has compute after it"; the per-op records let tests pin stricter
-    shapes).
-    """
-    compute_lines = [
-        i for i, line in enumerate(lowered_text.splitlines())
-        if _COMPUTE_RE.search(line)
-    ]
-    records = [
-        r for r in _collective_records(lowered_text, 1)
-        if r["payload_bytes"] >= min_payload_bytes
-    ]
-    total = trailing = 0
-    out = []
-    for rec in records:
-        before = sum(1 for c in compute_lines if c < rec["line"])
-        after = sum(1 for c in compute_lines if c > rec["end_line"])
-        total += rec["stream_bytes"]
-        if after == 0:
-            trailing += rec["stream_bytes"]
-        out.append({
-            "op": rec["op"], "line": rec["line"],
-            "payload_bytes": rec["payload_bytes"],
-            "stream_bytes": rec["stream_bytes"],
-            "compute_before": before, "compute_after": after,
-        })
-    interleaved = (
-        bool(out)
-        and any(op["compute_after"] > 0 for op in out)
-        and trailing < total
-    )
-    return {
-        "collectives": out,
-        "total_stream_bytes": int(total),
-        "trailing_stream_bytes": int(trailing),
-        "exposed_fraction": (trailing / total) if total else 0.0,
-        "interleaved": interleaved,
-    }
-
+# -- what the backend scheduled: the compiled program's all-reduces -----------
 
 _HLO_COMPUTATION_RE = re.compile(
     r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$"
@@ -526,9 +451,8 @@ _HLO_FUSION_CALLS_RE = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
 def compiled_collective_counts(compiled_text: str) -> Dict[str, int]:
     """How the BACKEND scheduled a step's all-reduces, counted in the
     compiled text (``step.lower(...).compile().as_text()``: scheduled HLO,
-    after the compiler's passes) — the counterpart of
-    :func:`overlap_inventory`, which reads the lowered program and so
-    cannot see what the TPU scheduler does with it.
+    after the compiler's passes): the lowered program cannot say what
+    the TPU scheduler does with it.
 
     ``async_pairs``: start/done pairs of asynchronous collectives — the
     TPU's ``AsyncCollectiveStart``/``AsyncCollectiveDone`` custom calls
@@ -553,51 +477,3 @@ def compiled_collective_counts(compiled_text: str) -> Dict[str, int]:
         elif " all-reduce(" in line and computation not in fused:
             sync += 1
     return {"async_pairs": min(starts, dones), "sync_all_reduces": sync}
-
-
-def modeled_overlap_exposed(
-    bucket_bytes: Sequence[int],
-    t_compute_s: float,
-    link_bytes_per_s: float,
-    world: int,
-    dtype_ratio: float = 1.0,
-) -> Dict[str, float]:
-    """Timing model of the bucketed backward/collective overlap
-    (docs/tensor-fusion.md derives it; the r4 scaling-model row of
-    tools/collective_bench.py evaluates it at PERF.md's measured point).
-
-    Buckets (launch order, wire bytes each) are produced by a backward
-    pass of duration ``t_compute_s`` at a rate proportional to bytes:
-    bucket ``i`` is ready at ``t_compute_s * cum_bytes_i / total``.  Its
-    ring allreduce costs ``2*(w-1)/w * bytes * dtype_ratio /
-    link_bytes_per_s`` and the link is serial, so transfers queue:
-    ``start_i = max(ready_i, end_{i-1})``.  Exposed communication is
-    whatever finishes after the compute does; the unoverlapped baseline
-    exposes everything (``exposed_fraction == 1``).
-
-    Returns ``{"t_comm_s", "t_exposed_s", "exposed_fraction",
-    "t_step_s", "n_buckets"}``.
-    """
-    sizes = [int(b) for b in bucket_bytes if int(b) > 0]
-    total = sum(sizes)
-    if not sizes or world <= 1 or link_bytes_per_s <= 0:
-        return {
-            "t_comm_s": 0.0, "t_exposed_s": 0.0, "exposed_fraction": 0.0,
-            "t_step_s": float(t_compute_s), "n_buckets": len(sizes),
-        }
-    ring = 2.0 * (world - 1) / world * dtype_ratio / link_bytes_per_s
-    t_comm = sum(s * ring for s in sizes)
-    cum = 0
-    end = 0.0
-    for s in sizes:
-        cum += s
-        ready = t_compute_s * cum / total
-        end = max(ready, end) + s * ring
-    exposed = max(0.0, end - t_compute_s)
-    return {
-        "t_comm_s": t_comm,
-        "t_exposed_s": exposed,
-        "exposed_fraction": exposed / t_comm if t_comm else 0.0,
-        "t_step_s": t_compute_s + exposed,
-        "n_buckets": len(sizes),
-    }

@@ -19,8 +19,8 @@ What still matters on TPU and is kept:
 :class:`BucketSchedule` extends the plan with a *launch order*: buckets
 sorted by backward production order so each bucket's collective can start
 while earlier layers' gradients are still computing — the PyTorch-DDP
-bucketing insight (Li et al., VLDB '20) applied to the staged backward of
-``ops/overlap.py`` (docs/tensor-fusion.md).
+bucketing insight (Li et al., VLDB '20), used by the torch bridge's
+bucketed submission (``torch/optimizer.py``, docs/tensor-fusion.md).
 """
 
 from __future__ import annotations
@@ -140,9 +140,9 @@ class BucketSchedule(FusionPlan):
         HOROVOD_FUSION_THRESHOLD=0 contract);
       * buckets order by ``ready_at`` — the production position of their
         LAST member, the earliest moment their collective can launch.
-        ``ops/overlap.py`` launches bucket ``b``'s reduction as soon as
-        the backward segment producing ``ready_at[b]`` retires, while
-        earlier segments are still computing.
+        The torch bridge submits bucket ``b`` as soon as the gradient at
+        ``ready_at[b]`` has arrived, while earlier layers' are still
+        computing.
     """
 
     def __init__(

@@ -627,7 +627,6 @@ def ZeroSpmdOptimizer(
     ici_axis: str = ICI_AXIS,
     dcn_axis: str = DCN_AXIS,
     dcn_compression=None,
-    pre_reduced: bool = False,
 ) -> optax.GradientTransformation:
     """The SPMD twin of :func:`ZeroDistributedOptimizer` — call ``init``
     and ``update`` INSIDE a ``shard_map`` over ``axis`` (the per-chip
@@ -657,18 +656,6 @@ def ZeroSpmdOptimizer(
     the matching ``PartitionSpec`` tree for host-side init/donation
     (``training.zero_train_setup`` wires both for the world mesh).
 
-    ``pre_reduced=True`` is the backward/collective-overlap pairing
-    (``ops/overlap.py``, ``training.zero_train_setup(overlap=True)``):
-    the gradients arriving at ``update`` are ALREADY fully reduced (the
-    bucket collectives ran them interleaved with the backward), so the
-    reduce-scatter degenerates to a zero-communication local slice of
-    this chip's chunk — same elementwise arithmetic: gradients (and
-    elementwise-exact inner updates) bit-equal to the unoverlapped
-    wrapper; fma-bearing inners may contract ≤2 ulp differently across
-    the two program shapes (tests/test_overlap.py, docs/OPTIM.md).
-    Error-feedback compression cannot ride that slice (no wire hop);
-    the update-shard allgather is unchanged.
-
     Integrity-guard composition (``horovod_tpu.guard``,
     docs/FAULT_TOLERANCE.md; ``training.zero_train_setup(guard=True)``
     wires it): the guard's agreement object is the POST-allgather
@@ -689,11 +676,6 @@ def ZeroSpmdOptimizer(
             "the DCN hop, which only exists on the two-level exchange)")
     feedback = hierarchical and dcn_compression is not None and \
         dcn_compression.error_feedback
-    if pre_reduced and feedback:
-        raise ValueError(
-            "pre_reduced grads never cross the reduce-scatter wire — "
-            "error_feedback compression does not compose with the "
-            "overlapped exchange")
 
     def _world():
         if hierarchical:
@@ -743,14 +725,7 @@ def ZeroSpmdOptimizer(
         g_bufs = plan.flatten(g_leaves)
 
         new_residual = state.residual
-        if pre_reduced:
-            # overlap pairing: the bucket collectives already summed (and
-            # averaged) these gradients across the axis — this chip's
-            # shard is a local slice, no collective (flat and
-            # hierarchical alike: _slice_shards' flat chunk me IS mesh
-            # position (d, i)'s chunk d*n_ici+i)
-            g_shards = _slice_shards(plan, g_bufs, me)
-        elif hierarchical:
+        if hierarchical:
             residuals = (
                 state.residual if state.residual is not None
                 else [None] * len(g_bufs)

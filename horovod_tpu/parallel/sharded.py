@@ -33,10 +33,7 @@ DP_AXIS, SP_AXIS, TP_AXIS = "dp", "sp", "tp"
 
 def _make_attn_fn(attention_impl: str, causal: bool,
                   window: Optional[int]) -> Callable:
-    """The per-block attention closure of :class:`MultiAxisTransformer`
-    — factored out of ``__call__`` so the overlap segment chain
-    (:func:`overlap_segments`) composes the exact same attention the
-    monolithic forward uses."""
+    """The per-block attention closure of :class:`MultiAxisTransformer`."""
 
     def attn_fn(q, k, v):
         # SP_AXIS always exists on the (dp, sp, tp) mesh (size 1 when
@@ -308,120 +305,26 @@ def init_opt_sharded(optimizer: optax.GradientTransformation, params: Any,
     return opt_state, ospecs
 
 
-def overlap_segments(model: MultiAxisTransformer, tokens, targets):
-    """Segment-chain view of :class:`MultiAxisTransformer` for the
-    backward/collective overlap scheduler (``ops/overlap.py``): embed →
-    one :class:`~horovod_tpu.ops.overlap.Segment` per ``block_{i}`` →
-    tied head+loss, applying the same ``_MultiAxisBlock`` modules the
-    monolithic ``__call__`` builds (identical math; the backward gains
-    bucket boundaries).  Call inside the (dp, sp, tp) shard_map — the
-    segments use the same mesh axes the model does.  The chain's params
-    tree is the step's WRAPPED ``{"params": ...}`` variables dict (the
-    ``make_sharded_train_step`` convention).  The tied embedding is read
-    by the first AND last segment, so its gradient rides the final
-    bucket.  Per-block remat policies wrap the block segment in
-    ``jax.checkpoint`` with the matching policy."""
-    from ..models.transformer import _checkpoint_policy
-    from ..ops.overlap import Segment
-
-    sp = _axis_size_or_1(SP_AXIS)
-    sp_idx = jax.lax.axis_index(SP_AXIS) if sp > 1 else 0
-    s_local = tokens.shape[1]
-    head_dim = model.d_model // model.num_heads
-    attn_fn = _make_attn_fn(model.attention_impl, model.causal,
-                            model.window)
-
-    def seg_embed(variables, toks):
-        params = variables["params"]
-        x = params["embed"][toks].astype(model.dtype)
-        offset = sp_idx * s_local
-        return x + jax.lax.dynamic_slice_in_dim(
-            params["pos_embed"], offset, s_local, axis=0
-        ).astype(model.dtype)[None]
-
-    def make_block(i, policy):
-        def seg(variables, x):
-            return _MultiAxisBlock(
-                d_model=model.d_model, num_heads=model.num_heads,
-                head_dim=head_dim, dtype=model.dtype, attn_fn=attn_fn,
-            ).apply({"params": variables["params"][f"block_{i}"]}, x)
-
-        if policy != "none":
-            seg = jax.checkpoint(seg, policy=_checkpoint_policy(policy))
-        return Segment(seg, keys=(f"params/block_{i}",))
-
-    def seg_head(variables, x):
-        params = variables["params"]
-        x = nn.LayerNorm(dtype=model.dtype).apply(
-            {"params": params["ln_f"]}, x
-        )
-        logits = jnp.dot(x, params["embed"].T.astype(model.dtype))
-        losses = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), targets
-        )
-        return losses.mean()
-
-    policies = resolve_remat_policies(
-        model.remat_policy, model.num_layers
-    )
-    return (
-        [Segment(seg_embed, keys=("params/embed", "params/pos_embed"))]
-        + [make_block(i, policies[i]) for i in range(model.num_layers)]
-        + [Segment(seg_head, keys=("params/ln_f", "params/embed"))]
-    )
-
-
 def make_sharded_train_step(model: MultiAxisTransformer,
                             optimizer: optax.GradientTransformation,
                             mesh: Mesh, param_spec_tree: Any,
-                            opt_spec_tree: Any,
-                            overlap: bool = False,
-                            bucket_bytes: Optional[int] = None):
+                            opt_spec_tree: Any):
     """One compiled program: forward (TP × SP), backward, grad pmean over
     (dp, sp), optimizer update — the multi-axis analog of
-    training.data_parallel_train_step.
-
-    ``overlap=True`` swaps the monolithic ``jax.value_and_grad`` +
-    trailing pmean for the bucket-boundary staged backward of
-    ``ops/overlap.py``: the backward runs block-by-block (the
-    :func:`overlap_segments` chain) and each
-    :class:`~horovod_tpu.ops.fusion.BucketSchedule` bucket's (dp, sp)
-    reduction launches at its bucket boundary, interleaved between block
-    backwards instead of trailing them.  Gradients — and the optimizer
-    update — are bit-equal to the unoverlapped step at fp32
-    (tests/test_overlap.py); ``bucket_bytes`` overrides
-    ``HVD_TPU_OVERLAP_BUCKET_BYTES``.
-    """
-    n_rep = int(mesh.shape[DP_AXIS] * mesh.shape[SP_AXIS])
+    training.data_parallel_train_step."""
 
     def step(params, opt_state, tokens, targets):
-        if overlap:
-            from ..ops.overlap import overlapped_value_and_grad
-
-            def bucket_reduce(buf):
-                # == jax.lax.pmean(buf, (dp, sp)): psum then divide —
-                # same arithmetic per element as the monolithic step's
-                # trailing pmean, so the A/B stays bit-equal
-                return jax.lax.psum(buf, (DP_AXIS, SP_AXIS)) / jnp.asarray(
-                    n_rep, buf.dtype
-                )
-
-            loss, grads, _ = overlapped_value_and_grad(
-                overlap_segments(model, tokens, targets), params, tokens,
-                bucket_reduce=bucket_reduce, bucket_bytes=bucket_bytes,
+        def loss_fn(p):
+            logits = model.apply(p, tokens)
+            losses = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), targets
             )
-        else:
-            def loss_fn(p):
-                logits = model.apply(p, tokens)
-                losses = optax.softmax_cross_entropy_with_integer_labels(
-                    logits.astype(jnp.float32), targets
-                )
-                return losses.mean()
+            return losses.mean()
 
-            loss, grads = jax.value_and_grad(loss_fn)(params)
-            # replicated across dp and sp -> average gradients over both;
-            # tp-sharded leaves hold distinct shards and are NOT tp-reduced
-            grads = jax.lax.pmean(grads, (DP_AXIS, SP_AXIS))
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # replicated across dp and sp -> average gradients over both;
+        # tp-sharded leaves hold distinct shards and are NOT tp-reduced
+        grads = jax.lax.pmean(grads, (DP_AXIS, SP_AXIS))
         loss = jax.lax.pmean(loss, (DP_AXIS, SP_AXIS))
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)

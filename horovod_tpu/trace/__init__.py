@@ -73,7 +73,6 @@ SITES = (
     "collective.enqueue",  # negotiated-collective submission (controller)
     "collective.exec",     # fused collective dispatch->data-ready
     "overlap.bucket",      # torch bridge: one bucket's drained submission
-    "overlap.autotune",    # overlap autotuner: one trial scored
     "serve.queued",        # request arrival -> admission (per request)
     "serve.prefill_chunk", # one prefill chunk computed (per request)
     "serve.step",          # one mixed/decode engine step (batch-wide)

@@ -110,57 +110,6 @@ def _create_train_state(model, optimizer, rng, sample_input) -> TrainState:
     )
 
 
-def _forward_scoped(segments):
-    """The overlap chain with every segment under the ``forward`` device
-    scope, so the staged backward reads ``transpose(jvp(forward))`` like
-    the plain step's."""
-    from .ops.overlap import Segment
-
-    def scoped(fn):
-        def seg(params, x):
-            with jax.named_scope("forward"):
-                return fn(params, x)
-
-        return seg
-
-    return [
-        s._replace(fn=scoped(s.fn)) if isinstance(s, Segment)
-        else Segment(scoped(s))
-        for s in segments
-    ]
-
-
-def _resolve_segmenter(model, segmenter):
-    """The overlap segment-chain builder for ``model``:
-    ``segmenter(model, inputs, labels, loss_fn) -> [Segment]``.  The two
-    flagship transformers ship theirs; any other model must pass one
-    explicitly (docs/tensor-fusion.md describes the chain contract)."""
-    if segmenter is not None:
-        return segmenter
-    from .models.transformer import Transformer, overlap_segments
-
-    if isinstance(model, Transformer):
-        return overlap_segments
-    raise ValueError(
-        f"overlap=True needs a segment chain for {type(model).__name__}; "
-        "pass segmenter=(model, inputs, labels, loss_fn) -> [Segment] "
-        "(models.transformer / parallel.sharded ship theirs)"
-    )
-
-
-def _overlap_bucket_reduce(axis, op, world):
-    """Per-bucket reduction of the overlapped data-parallel backward —
-    the SAME arithmetic as ``spmd_ops.allreduce`` applied leaf-wise
-    (psum, then divide for Average), so overlapped and unoverlapped
-    steps stay bit-equal."""
-
-    @jax.named_scope("exchange")
-    def bucket_reduce(buf):
-        return spmd_ops.allreduce(buf, op=op, axis=axis)
-
-    return bucket_reduce
-
-
 def data_parallel_train_step(
     model,
     optimizer: optax.GradientTransformation,
@@ -168,9 +117,6 @@ def data_parallel_train_step(
     axis: str = WORLD_AXIS,
     loss_fn: Callable = softmax_cross_entropy,
     op: ReduceOp = Average,
-    overlap: bool = False,
-    segmenter: Optional[Callable] = None,
-    bucket_bytes: Optional[int] = None,
     guard: Optional[bool] = None,
 ) -> Callable:
     """Build the compiled data-parallel train step.
@@ -191,18 +137,6 @@ def data_parallel_train_step(
     the CPU and on any other backend: no option, the step as it always
     was).  The arithmetic is the same either way.
 
-    ``overlap=True`` stages the backward at bucket boundaries
-    (``ops/overlap.py``): in the LOWERED program each :class:`~horovod_tpu.
-    ops.fusion.BucketSchedule` bucket's allreduce sits between the
-    segments' backward computations instead of trailing them; what the
-    backend schedules beside what is decided by the compile options
-    above, not by that order.  Gradients and updates stay bit-equal to the
-    unoverlapped step at fp32.  Requires a segment-chain model
-    (:func:`models.transformer.overlap_segments` is used for the
-    flagship ``Transformer``; pass ``segmenter`` otherwise) and no
-    ``batch_stats``; ``bucket_bytes`` overrides
-    ``HVD_TPU_OVERLAP_BUCKET_BYTES``.
-
     ``guard=True`` (``None`` = the ``HVD_TPU_GUARD`` env flag) makes
     the step ALSO return the silent-corruption diagnostics
     (:func:`horovod_tpu.guard.step_diag` over the POST-allreduce
@@ -215,49 +149,8 @@ def data_parallel_train_step(
     guard = _resolve_guard(guard)
     if mesh is None:
         mesh = basics._require_init().process_set_registry.get(0).mesh
-    if overlap:
-        segmenter = _resolve_segmenter(model, segmenter)
-        if op not in (ReduceOp.AVERAGE, ReduceOp.SUM):
-            raise ValueError(
-                f"overlap supports Sum/Average gradient reduction, got "
-                f"{op!r}"
-            )
-        world = int(mesh.shape[axis])
 
     def _step(state: TrainState, images, labels):
-        if overlap:
-            from .ops.overlap import overlapped_value_and_grad
-
-            if state.batch_stats is not None:
-                raise ValueError(
-                    "overlap=True does not support batch_stats models"
-                )
-            loss, grads, _ = overlapped_value_and_grad(
-                _forward_scoped(segmenter(model, images, labels, loss_fn)),
-                state.params, images,
-                bucket_reduce=_overlap_bucket_reduce(axis, op, world),
-                bucket_bytes=bucket_bytes,
-            )
-            new_stats = None
-            with jax.named_scope("exchange"):
-                loss = spmd_ops.allreduce(loss, axis=axis)
-            with jax.named_scope("optimizer"):
-                updates, new_opt_state = optimizer.update(
-                    grads, state.opt_state, state.params
-                )
-                new_params = optax.apply_updates(state.params, updates)
-            new_state = TrainState(
-                step=state.step + 1,
-                params=new_params,
-                opt_state=new_opt_state,
-                batch_stats=new_stats,
-            )
-            if guard:
-                from .guard import step_diag
-
-                return new_state, loss, step_diag(loss, grads)
-            return new_state, loss
-
         # forward / exchange / optimizer: device scopes
         # (trace.DEVICE_SCOPES).  Metadata only: the program is the same
         # operations with or without them; value_and_grad's transpose of
@@ -332,9 +225,6 @@ def zero_train_setup(
     op: ReduceOp = Average,
     hierarchical: bool = False,
     dcn_compression=None,
-    overlap: bool = False,
-    segmenter: Optional[Callable] = None,
-    bucket_bytes: Optional[int] = None,
     guard: Optional[bool] = None,
 ):
     """Build a ZeRO-sharded data-parallel trainer over the world mesh.
@@ -361,20 +251,6 @@ def zero_train_setup(
     Pass the INNER optax optimizer; do not wrap it in a Zero/Distributed
     wrapper yourself.
 
-    ``overlap=True`` composes the bucket-boundary backward
-    (``ops/overlap.py``) with ZeRO: the gradient exchange IS the
-    collective the buckets launch, so each bucket's reduction rides an
-    earlier segment's backward and the wrapper slices its pre-reduced
-    shard locally (``ZeroSpmdOptimizer(pre_reduced=True)``).  Exactness
-    vs the unoverlapped ZeRO step at fp32: gradients bit-equal; updates
-    bit-equal for elementwise-exact inners (sgd); fma-bearing inners
-    (adam's ``g²`` moment) may drift ≤2 ulp/step from XLA contracting
-    the fma differently across the two program shapes —
-    tests/test_overlap.py pins both, docs/OPTIM.md documents the
-    caveat.  Error-feedback DCN compression needs the reduce-scatter
-    hop the overlapped exchange folds into the buckets, so it does not
-    compose (stateless wire compression does).
-
     ``guard=True`` (``None`` = ``HVD_TPU_GUARD``) adds the silent-
     corruption diagnostics as a third step output, composing with
     every mode above.  Both detectors read only REPLICATED values —
@@ -391,17 +267,6 @@ def zero_train_setup(
 
     guard = _resolve_guard(guard)
 
-    if overlap and dcn_compression is not None and getattr(
-        dcn_compression, "error_feedback", False
-    ):
-        raise ValueError(
-            "overlap=True folds the gradient reduce-scatter into the "
-            "bucket collectives — error_feedback compression (which "
-            "rides that hop's residual) does not compose; use stateless "
-            "DcnCompression or overlap=False"
-        )
-    if overlap:
-        segmenter = _resolve_segmenter(model, segmenter)
     if hierarchical:
         if mesh is None:
             mesh = basics._require_init().topology.hierarchical_mesh()
@@ -411,14 +276,12 @@ def zero_train_setup(
             inner_optimizer, op=op, hierarchical=True,
             ici_axis=ICI_AXIS, dcn_axis=DCN_AXIS,
             dcn_compression=dcn_compression,
-            pre_reduced=overlap,
         )
     else:
         if mesh is None:
             mesh = basics._require_init().process_set_registry.get(0).mesh
         world = int(mesh.shape[axis])
-        zopt = ZeroSpmdOptimizer(inner_optimizer, axis=axis, op=op,
-                                 pre_reduced=overlap)
+        zopt = ZeroSpmdOptimizer(inner_optimizer, axis=axis, op=op)
 
     variables = model.init(rng, sample_input)
     params = variables["params"]
@@ -455,47 +318,6 @@ def zero_train_setup(
             )
         return spmd_ops.allreduce(x, axis=axis)
 
-    @jax.named_scope("exchange")
-    def _overlap_zero_reduce(buf):
-        """Full (pre-ZeRO) reduction of one bucket, run as the SAME
-        reduce-scatter (+ allgather) primitives the wrapper's own
-        exchange uses — ZeRO's reduce-scatter IS the bucket collective,
-        just launched at the bucket boundary.  Using psum here instead
-        was measured to drift 1 ulp against the unoverlapped step (XLA
-        lowers all-reduce and reduce-scatter with different reduction
-        association); the scatter/gather pair keeps every element's
-        reduction order identical, so GRADIENTS are bit-equal
-        (tests/test_overlap.py pins it; see the overlap docstring above
-        for the fma-inner update caveat)."""
-        pad = (-buf.size) % world
-        padded = (
-            jnp.concatenate([buf, jnp.zeros((pad,), buf.dtype)])
-            if pad else buf
-        )
-        if hierarchical:
-            shard, _ = spmd_ops._two_level_reduce_scatter_flat(
-                padded, ICI_AXIS, DCN_AXIS, dcn_compression, None
-            )
-        else:
-            shard = spmd_ops.reducescatter(padded, axis=axis)
-        if op == ReduceOp.AVERAGE:
-            shard = shard / jnp.asarray(world, shard.dtype)
-        if hierarchical:
-            # gather the reduced GRADIENTS at full precision: this
-            # gather only exists because of the overlap composition (the
-            # unoverlapped path feeds the reduce-scatter output straight
-            # to the update), so compressing it would quantize the
-            # gradients the optimizer sees — a divergence the
-            # unoverlapped step never has.  Wire compression stays where
-            # it always was: the reduce-scatter's DCN hop above and the
-            # update-delta allgather inside ZeroSpmdOptimizer.
-            red = spmd_ops._two_level_all_gather_flat(
-                shard, ICI_AXIS, DCN_AXIS, None
-            )
-        else:
-            red = spmd_ops.allgather(shard, axis=axis)
-        return red[: buf.size] if pad else red
-
     def _zero_diag(loss, updates):
         """Guard diagnostics for the ZeRO step, from REPLICATED values
         only: digest + finite sentinel over the POST-exchange update
@@ -512,37 +334,6 @@ def zero_train_setup(
                 "digest": device_digest(updates)}
 
     def _step(state: TrainState, images, labels):
-        if overlap:
-            from .ops.overlap import overlapped_value_and_grad
-
-            if state.batch_stats is not None:
-                raise ValueError(
-                    "overlap=True does not support batch_stats models"
-                )
-            loss, grads, _ = overlapped_value_and_grad(
-                _forward_scoped(segmenter(model, images, labels, loss_fn)),
-                state.params, images,
-                bucket_reduce=_overlap_zero_reduce,
-                bucket_bytes=bucket_bytes,
-            )
-            new_stats = None
-            with jax.named_scope("exchange"):
-                loss = _mean(loss)
-            with jax.named_scope("optimizer"):
-                updates, new_opt_state = zopt.update(
-                    grads, state.opt_state, state.params
-                )
-                new_params = optax.apply_updates(state.params, updates)
-            new_state = TrainState(
-                step=state.step + 1,
-                params=new_params,
-                opt_state=new_opt_state,
-                batch_stats=new_stats,
-            )
-            if guard:
-                return new_state, loss, _zero_diag(loss, updates)
-            return new_state, loss
-
         def compute_loss(params):
             with jax.named_scope("forward"):
                 variables = {"params": params}

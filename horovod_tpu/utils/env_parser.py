@@ -30,10 +30,10 @@ def _get_int(name: str, default: int) -> int:
         return default
 
 
-def _get_int_validated(name: str, default: int, minimum: int = 0) -> int:
-    """Strict integer knob: a set-but-garbage or out-of-range value is a
+def _get_int_validated(name: str, default: int) -> int:
+    """Strict integer knob: a set-but-garbage or negative value is a
     configuration ERROR, not a silent default.  Used for the fusion/
-    overlap byte thresholds, where a typo'd ``64MB`` or a negative value
+    bucket byte thresholds, where a typo'd ``64MB`` or a negative value
     would otherwise silently fall through to the one-bucket-per-tensor
     path and tank collective efficiency without any signal."""
     v = _get(name)
@@ -54,12 +54,10 @@ def _get_int_validated(name: str, default: int, minimum: int = 0) -> int:
             f"{var} must be an integer (bytes/count), got "
             f"{v!r} — unset it or pass a plain integer"
         ) from None
-    if value < minimum:
+    if value < 0:
         raise ValueError(
-            f"{var} must be >= {minimum}, got {value} "
+            f"{var} must be >= 0, got {value} "
             f"(0 disables fusion: one bucket per tensor)"
-            if minimum == 0 else
-            f"{var} must be >= {minimum}, got {value}"
         )
     return value
 
@@ -101,14 +99,10 @@ class Config:
     # Autotune (horovod/common/parameter_manager.cc):
     autotune: bool = False  # HOROVOD_AUTOTUNE
     autotune_log: str = ""  # HOROVOD_AUTOTUNE_LOG
-    # Backward/collective overlap scheduler (ops/overlap.py,
-    # docs/tensor-fusion.md): bucket size of the BucketSchedule (0 = one
-    # bucket per tensor), and the metrics-driven BucketAutotuner sweeping
-    # bucket sizes against live step time (docs/autotune.md).
+    # The torch bridge's bucketed submission (torch/optimizer.py,
+    # docs/tensor-fusion.md): bucket size of its BucketSchedule (0 = one
+    # bucket per tensor).
     overlap_bucket_bytes: int = 4 * 1024 * 1024  # HVD_TPU_OVERLAP_BUCKET_BYTES
-    overlap_autotune: bool = False  # HVD_TPU_OVERLAP_AUTOTUNE
-    overlap_autotune_trials: int = 8  # HVD_TPU_OVERLAP_AUTOTUNE_TRIALS
-    overlap_autotune_steps: int = 3  # HVD_TPU_OVERLAP_AUTOTUNE_STEPS
     # Hierarchical allreduce (nccl_operations.cc NCCLHierarchicalAllreduce):
     hierarchical_allreduce: bool = False  # HOROVOD_HIERARCHICAL_ALLREDUCE
     # DCN-hop wire format for routed hierarchical allreduces
@@ -138,11 +132,6 @@ class Config:
             autotune_log=_get("AUTOTUNE_LOG", "") or "",
             overlap_bucket_bytes=_get_int_validated(
                 "OVERLAP_BUCKET_BYTES", 4 * 1024 * 1024),
-            overlap_autotune=_get_bool("OVERLAP_AUTOTUNE", False),
-            overlap_autotune_trials=_get_int_validated(
-                "OVERLAP_AUTOTUNE_TRIALS", 8, minimum=1),
-            overlap_autotune_steps=_get_int_validated(
-                "OVERLAP_AUTOTUNE_STEPS", 3, minimum=1),
             hierarchical_allreduce=_get_bool("HIERARCHICAL_ALLREDUCE", False),
             dcn_wire_dtype=(_get("DCN_WIRE_DTYPE", "") or "").lower(),
             elastic=_get_bool("ELASTIC", False),

@@ -8,7 +8,7 @@ lowered programs — the same code path ``tools/verify_programs.py``
 tier-1 stays fast:
 
 * training leg — guard/trace byte-identity, zero added collectives
-  (plain + ZeRO), overlap interleave;
+  (plain + ZeRO);
 * hierarchical leg — modeled == measured per-tier bytes of the
   two-level allreduce over the 8-device virtual world;
 * serving leg — DCN-exclusion + modeled == measured psum stream per

@@ -493,7 +493,7 @@ def test_structured_log_context_and_json_formatter():
 # -- the entry path's sites (ISSUE 24) ---------------------------------------
 
 
-def _tiny_step(overlap=False):
+def _tiny_step():
     """A compiled two-layer MLP step over the 8-device CPU mesh: state,
     the jitted step, a batch."""
     import flax.linen as nn

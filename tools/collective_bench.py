@@ -84,10 +84,6 @@ LEGS = {
     "hier_fp16": (True, "float16"),
 }
 
-#: overlap legs (ops/overlap.py): handled by run_overlap_legs /
-#: run_overlap_autotune_leg rather than the allreduce sweep above
-OVERLAP_LEGS = ("overlap", "overlap_autotune")
-
 
 _leg_t0 = time.time()
 
@@ -187,210 +183,10 @@ def run_leg(leg, x, hmesh, wmesh, slice_ids, n_ici):
     }
 
 
-def _overlap_chain(world, n_seg, d, batch):
-    """A segment-chain training program (relu MLP) sized so the
-    BucketSchedule splits it into several buckets — the overlap leg's
-    workload.  Returns (segments, params, x, schedule bucket bytes)."""
-    from horovod_tpu.ops.overlap import Segment
-
-    rs = np.random.RandomState(1)
-    params = {
-        f"w{k}": jnp.asarray(
-            np.round(rs.randn(d, d) * 8) / 8, jnp.float32
-        )
-        for k in range(n_seg)
-    }
-
-    def make(k):
-        def seg(p, x):
-            return jax.nn.relu(x @ p[f"w{k}"])
-
-        return Segment(seg, keys=(f"w{k}",))
-
-    def head(p, x):
-        return jnp.mean((x @ p[f"w{n_seg - 1}"]) ** 2)
-
-    segments = [make(k) for k in range(n_seg - 1)] + [
-        Segment(head, keys=(f"w{n_seg - 1}",))
-    ]
-    x = jnp.asarray(
-        np.round(rs.randn(batch, d) * 8) / 8, jnp.float32
-    )
-    return segments, params, x
-
-
-def _overlap_step_fn(segments, wmesh, world, bucket_bytes, overlap):
-    from horovod_tpu.ops.overlap import overlapped_value_and_grad
-
-    def f(p, x):
-        loss, grads, _ = overlapped_value_and_grad(
-            segments, p, x,
-            bucket_reduce=lambda b: jax.lax.psum(b, WORLD_AXIS)
-            / jnp.asarray(world, b.dtype),
-            bucket_bytes=bucket_bytes, overlap=overlap,
-        )
-        return loss, grads
-
-    return jax.jit(jax.shard_map(
-        f, mesh=wmesh, in_specs=(P(), P(WORLD_AXIS)),
-        out_specs=(P(), P()), check_vma=False,
-    ))
-
-
-def run_overlap_legs(wmesh, world, smoke):
-    """The backward/collective overlap leg: overlapped vs unoverlapped
-    step time, static (program-inventory) exposed-comm fraction on both,
-    bucket count/size columns, grads-bit-equal oracle — plus the r4
-    scaling-model row (modeled exposed fraction + efficiency at the
-    PERF.md round-4 measured point, cross-checked against
-    tools/scaling_model.py's inline twin)."""
-    from horovod_tpu.ops.fusion import BucketSchedule
-    from horovod_tpu.ops.overlap import record_overlap_metrics
-    from horovod_tpu.ops.comm_model import (
-        modeled_overlap_exposed, overlap_inventory,
-    )
-
-    n_seg, d = (4, 32) if smoke else (8, 256)
-    batch = world * (2 if smoke else 8)
-    segments, params, x = _overlap_chain(world, n_seg, d, batch)
-    leaf_bytes = d * d * 4
-    bucket_bytes = 2 * leaf_bytes  # 2 layers per bucket -> n_seg/2 buckets
-    f_ov = _overlap_step_fn(segments, wmesh, world, bucket_bytes, True)
-    f_un = _overlap_step_fn(segments, wmesh, world, bucket_bytes, False)
-    (l1, g1), t_ov = _timed(f_ov, params, x)
-    (l2, g2), t_un = _timed(f_un, params, x)
-    bit_equal = bool(np.asarray(l1) == np.asarray(l2)) and all(
-        (np.asarray(a) == np.asarray(b)).all()
-        for a, b in zip(
-            jax.tree_util.tree_leaves(g1), jax.tree_util.tree_leaves(g2)
-        )
-    )
-    inv_ov = record_overlap_metrics(f_ov.lower(params, x).as_text())
-    inv_un = overlap_inventory(f_un.lower(params, x).as_text())
-    sched = BucketSchedule(
-        jax.tree_util.tree_leaves(params), bucket_bytes
-    )
-    # r4 scaling-model point (tools/scaling_model.py constants): the
-    # acceptance bar is a >=2x modeled exposed-comm drop there
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "scaling_model",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "scaling_model.py"),
-    )
-    sm = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sm)
-    n_chips = 256
-    n_buckets_r4 = -(-int(sm.WIRE_BYTES) // sm.BUCKET_BYTES)
-    r4 = modeled_overlap_exposed(
-        [sm.BUCKET_BYTES] * (n_buckets_r4 - 1)
-        + [int(sm.WIRE_BYTES) - sm.BUCKET_BYTES * (n_buckets_r4 - 1)],
-        sm.T_STEP_S, sm.B_ICI, n_chips,
-    )
-    exp_sm, frac_sm, eff_sm = sm.overlap_model(n_chips)
-    if abs(frac_sm - r4["exposed_fraction"]) > 1e-9:
-        raise AssertionError(
-            "scaling_model.overlap_model drifted from "
-            f"comm_model.modeled_overlap_exposed: {frac_sm} vs "
-            f"{r4['exposed_fraction']}"
-        )
-    recs = [
-        {
-            "bench": "collective",
-            "leg": "overlap",
-            "world": world,
-            "segments": n_seg,
-            "n_buckets": sched.num_buckets,
-            "bucket_bytes": bucket_bytes,
-            "bucket_nbytes": list(sched.bucket_nbytes),
-            "time_ms": round(t_ov * 1e3, 3),
-            "time_ms_unoverlapped": round(t_un * 1e3, 3),
-            "exposed_fraction_static": round(
-                inv_ov["exposed_fraction"], 4),
-            "exposed_fraction_static_unoverlapped": round(
-                inv_un["exposed_fraction"], 4),
-            "interleaved": inv_ov["interleaved"],
-            "interleaved_unoverlapped": inv_un["interleaved"],
-            "collectives": len(inv_ov["collectives"]),
-            "bit_exact": bit_equal,
-        },
-        {
-            "bench": "collective",
-            "leg": "overlap_r4_model",
-            "chips": n_chips,
-            "bucket_bytes": int(sm.BUCKET_BYTES),
-            "n_buckets": r4["n_buckets"],
-            "t_comm_ms": round(r4["t_comm_s"] * 1e3, 4),
-            "t_exposed_ms": round(r4["t_exposed_s"] * 1e3, 4),
-            "exposed_fraction": round(r4["exposed_fraction"], 4),
-            "exposed_fraction_unoverlapped": 1.0,
-            "exposed_drop_x": round(
-                1.0 / max(r4["exposed_fraction"], 1e-9), 2),
-            "efficiency_bucketed_overlap": round(eff_sm, 4),
-        },
-    ]
-    return recs
-
-
-def run_overlap_autotune_leg(wmesh, world, smoke):
-    """BucketAutotuner leg: sweep bucket sizes over the overlap chain,
-    pin the winner, report per-candidate step times — the bench
-    acceptance is structural (the default is trial 0 and the pin is the
-    argmin, so the pinned plan can never regress against it)."""
-    import time as _time
-
-    from horovod_tpu.ops.overlap import BucketAutotuner, Candidate
-
-    n_seg, d = (4, 32) if smoke else (8, 256)
-    batch = world * (2 if smoke else 8)
-    segments, params, x = _overlap_chain(world, n_seg, d, batch)
-    leaf_bytes = d * d * 4
-    default = Candidate(2 * leaf_bytes)
-    candidates = [Candidate(leaf_bytes), Candidate(4 * leaf_bytes)]
-    tuner = BucketAutotuner(
-        candidates=candidates, default=default,
-        trial_budget=len(candidates) + 1,
-        steps_per_trial=2 if smoke else max(3, WARMUP + 1),
-    )
-
-    def build(cand):
-        step = _overlap_step_fn(
-            segments, wmesh, world, cand.bucket_bytes, True
-        )
-        return lambda: step(params, x)
-
-    def timed(thunk):
-        t0 = _time.perf_counter()
-        jax.block_until_ready(thunk())
-        return _time.perf_counter() - t0
-
-    pinned = tuner.run(build, timed)
-    scores = {c.bucket_bytes: t for c, t in tuner.scores}
-    return {
-        "bench": "collective",
-        "leg": "overlap_autotune",
-        "world": world,
-        "candidates": sorted(scores),
-        "step_ms_by_bucket": {
-            str(k): round(v * 1e3, 3) for k, v in sorted(scores.items())
-        },
-        "pinned_bucket_bytes": pinned.bucket_bytes,
-        "trials": len(tuner.scores),
-        "trial_budget": tuner.trial_budget,
-        "pinned_step_ms": round(scores[pinned.bucket_bytes] * 1e3, 3),
-        "default_step_ms": round(scores[default.bucket_bytes] * 1e3, 3),
-        "regressed_vs_default": bool(
-            scores[pinned.bucket_bytes] > scores[default.bucket_bytes]
-        ),
-    }
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    all_legs = tuple(LEGS) + OVERLAP_LEGS
-    ap.add_argument("--legs", default=",".join(all_legs),
-                    help=f"comma list of {'/'.join(all_legs)}")
+    ap.add_argument("--legs", default=",".join(LEGS),
+                    help=f"comma list of {'/'.join(LEGS)}")
     ap.add_argument("--numel", type=int, default=1 << 20,
                     help="payload elements per contribution")
     ap.add_argument("--slice-size", type=int, default=0,
@@ -425,38 +221,10 @@ def main(argv=None):
     failed = False
     for leg in args.legs.split(","):
         leg = leg.strip()
-        if leg not in LEGS and leg not in OVERLAP_LEGS:
+        if leg not in LEGS:
             ap.error(f"unknown leg {leg!r}")
         begin_leg()
         try:
-            if leg == "overlap":
-                for rec in run_overlap_legs(wmesh, world, args.smoke):
-                    if rec["leg"] == "overlap":
-                        emit(rec, (
-                            f"[collective_bench]    overlap: "
-                            f"{rec['n_buckets']} buckets, static exposed "
-                            f"{rec['exposed_fraction_static']} (unoverlapped "
-                            f"{rec['exposed_fraction_static_unoverlapped']}), "
-                            f"bit_exact {rec['bit_exact']}, "
-                            f"{rec['time_ms']}ms vs "
-                            f"{rec['time_ms_unoverlapped']}ms"
-                        ))
-                    else:
-                        emit(rec, (
-                            f"[collective_bench] overlap_r4: modeled exposed "
-                            f"{rec['exposed_fraction']} at {rec['chips']} "
-                            f"chips ({rec['exposed_drop_x']}x drop)"
-                        ))
-                continue
-            if leg == "overlap_autotune":
-                rec = run_overlap_autotune_leg(wmesh, world, args.smoke)
-                emit(rec, (
-                    f"[collective_bench]   autotune: pinned "
-                    f"{rec['pinned_bucket_bytes']}B after {rec['trials']} "
-                    f"trials, {rec['pinned_step_ms']}ms (default "
-                    f"{rec['default_step_ms']}ms)"
-                ))
-                continue
             rec = run_leg(leg, x, hmesh, wmesh, slice_ids, n_ici)
         except Exception as e:  # noqa: BLE001 - isolate legs, report at exit
             print(f"[collective_bench] leg {leg} FAILED: {e}",

@@ -31,11 +31,6 @@ T_STEP_S = 0.0476          # measured, v5e batch 128 (PERF.md round 4)
 PARAMS = 25.6e6
 WIRE_BYTES = PARAMS * 2    # bf16 gradient compression on the wire
 B_ICI = 200e9              # ~1600 Gbit/s per v5e chip (approx. public spec)
-#: default BucketSchedule bucket (HVD_TPU_OVERLAP_BUCKET_BYTES) for the
-#: bucketed-overlap row; ops/comm_model.modeled_overlap_exposed is the
-#: canonical simulation — tools/collective_bench.py cross-checks this
-#: file's inline twin against it on the overlap leg.
-BUCKET_BYTES = 4 * 1024 * 1024
 
 
 def model(n: int):
@@ -45,50 +40,18 @@ def model(n: int):
     return t_comm, worst, best
 
 
-def overlap_model(n: int, bucket_bytes: int = BUCKET_BYTES):
-    """Bucketed backward/overlap row (ops/overlap.py schedule): buckets
-    are produced across the backward at a byte-proportional rate, each
-    bucket's ring allreduce queues on the serial link, and only what
-    outlives the compute is exposed.  Inline twin of
-    ``ops.comm_model.modeled_overlap_exposed`` (kept dependency-free so
-    this tool stays stdlib-only); returns (t_exposed_s,
-    exposed_fraction, efficiency)."""
-    if n <= 1:
-        return 0.0, 0.0, 1.0
-    sizes = [bucket_bytes] * int(WIRE_BYTES // bucket_bytes)
-    rem = WIRE_BYTES - bucket_bytes * len(sizes)
-    if rem:
-        sizes.append(rem)
-    ring = 2 * (n - 1) / n / B_ICI
-    t_comm = sum(s * ring for s in sizes)
-    cum, end = 0.0, 0.0
-    for s in sizes:
-        cum += s
-        ready = T_STEP_S * cum / WIRE_BYTES
-        end = max(ready, end) + s * ring
-    exposed = max(0.0, end - T_STEP_S)
-    frac = exposed / t_comm if t_comm else 0.0
-    return exposed, frac, T_STEP_S / (T_STEP_S + exposed)
-
-
 def main():
     rows = []
     for n in (1, 8, 32, 64, 256):
         t_comm, worst, best = model(n)
-        exposed, frac, eff_overlap = overlap_model(n)
         rows.append({
             "chips": n,
             "t_comm_ms": round(t_comm * 1e3, 3),
             "efficiency_no_overlap": round(worst, 4),
             "efficiency_full_overlap": round(best, 4),
-            "bucketed_exposed_ms": round(exposed * 1e3, 4),
-            "bucketed_exposed_fraction": round(frac, 4),
-            "efficiency_bucketed_overlap": round(eff_overlap, 4),
         })
         print(f"n={n:4d}: allreduce {t_comm*1e3:6.3f} ms  "
-              f"efficiency {worst:.1%} (no overlap) .. {best:.1%} (full); "
-              f"bucketed schedule exposes {frac:.1%} of comm "
-              f"-> {eff_overlap:.1%}")
+              f"efficiency {worst:.1%} (no overlap) .. {best:.1%} (full)")
     print()
     worst_comm_ms = max(r["t_comm_ms"] for r in rows)
     print("Even with ZERO compute/comm overlap the model stays above "

@@ -2,7 +2,7 @@
 """Program-contract verifier launcher (the ``programs`` analysis pass).
 
 Lowers the canonical program menu — serving decode/mixed/speculative
-tiers at shard counts 1 and 2, guarded + overlapped + ZeRO train
+tiers at shard counts 1 and 2, guarded + ZeRO train
 steps, the hierarchical allreduce — and machine-checks the invariants
 docs promise in prose (see ``horovod_tpu/analysis/programs.py``):
 
