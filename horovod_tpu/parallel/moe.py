@@ -34,19 +34,34 @@ Two layers live here, and they are different mechanisms:
     yet: on one chip it runs on the tokens it is given and what the
     absent experts would add is left out; the ``all_to_all`` that sends
     every chip's assignments to their owners is a later change.
+
+How ``RoutedExperts`` moves rows (PR 31; the chip's timings are in PERF.md
+§6).  Between token order and expert order a row moves by GATHERS alone, in
+the layer's dtype: into expert order by the sorted row's token
+(``_dispatch``), back by each token slot's rank in the sort, ``top_k``
+gathers of all the rows, weighted and summed in float32 (``_combine``).
+Each is the other's transpose, written so under ``jax.custom_vjp``:
+autodiff's own transpose of a gather is a scatter-add, which the chip runs
+as a sort, a gather and a serial pass, 1.5 ms for 16,384 rows where the
+gathers of four times the rows take 0.9.  Single numbers move by sorts
+(the weights ride the sort by expert, their cotangent a sort back; a
+slot's rank is the sort of the order) or not at all (the chosen gates and
+the counts are one dense comparison): a gather or a scatter of 65,536
+scalars takes 0.57 ms, a sort of them 0.05.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 
+from .. import trace as _trace
 from ._mesh_utils import axis_size_or_1 as _axis_size
 
 
@@ -156,24 +171,143 @@ def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
-def _expert_chunk(z, w_gate, w_up, w_down, token, weight, valid, sizes):
+@jax.custom_vjp
+def _sorted_with(key, value):
+    """The stable sort of the slots by ``key`` with ``value`` carried along:
+    ``(order, value[order])``, one sort of three operands.  On the chip a
+    sort moves single numbers ten times faster than a gather or a scatter
+    does, so the transpose is a sort too: back by ``order``."""
+    _, order, value = jax.lax.sort(
+        (key, jnp.arange(key.shape[0]), value), num_keys=1, is_stable=True)
+    return order, value
+
+
+def _sorted_with_fwd(key, value):
+    order, value = _sorted_with(key, value)
+    return (order, value), order
+
+
+def _sorted_with_bwd(order, cotangents):
+    return None, jax.lax.sort((order, cotangents[1]), num_keys=1)[1]
+
+
+_sorted_with.defvjp(_sorted_with_fwd, _sorted_with_bwd)
+
+
+def _chosen_and_counts(gates, index):
+    """Each row's gates at its ``index`` (T, k) and how many slots chose
+    each expert (E,), from one (rows, k, experts) comparison: dense forward
+    and backward.  ``take_along_axis``'s transpose and ``bincount`` are
+    scatters of single numbers, which the chip does one at a time."""
+    onehot = index[..., None] == jnp.arange(gates.shape[-1])
+    chosen = jnp.sum(jnp.where(onehot, gates[:, None, :], 0), axis=-1)
+    return chosen, jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+
+
+class _Sort(NamedTuple):
+    """The layer's index work, once a layer: the (row, slot) assignments
+    sorted by held expert, the others last."""
+
+    order: jax.Array     # (slots, padded to whole chunks) the slot of each sorted row
+    rank: jax.Array      # (T, k) each slot's place in the sort: the inverse
+    chosen: jax.Array    # (T, k) the combine weights, as the tokens hold them
+    starts: jax.Array    # (held,) each held expert's run [start, end) of the sort
+    ends: jax.Array
+    assigned: jax.Array  # () how many assignments are held at all
+
+
+class _Route(NamedTuple):
+    """How one chunk's rows move between token order and expert order."""
+
+    token: jax.Array   # (C,) the row of ``z`` each sorted row reads
+    at: jax.Array      # (T, k) the chunk's row each token's slot owns, or 0
+    held: jax.Array    # (T, k) whether the slot owns a row of the chunk
+    weight: jax.Array  # (T, k) the combine weights, as the tokens hold them
+
+
+@jax.jit
+def _to_tokens(src, route, scale=None):
+    """Expert order to token order, float32 ``(rows, D)``: each token's sum
+    over its ``k`` slots of the chunk's row that the slot's assignment has,
+    times ``scale`` (rows, k).  ``k`` gathers of ``rows`` rows in ``src``'s
+    own dtype and no scatter: a slot finds its row by its rank in the sort,
+    a slot with no row in the chunk reads row 0 and adds nothing.  Under a
+    ``jit`` of its own for ``model.init``'s sake, which runs the layer
+    operation by operation: one program there, not one a slice of the loop
+    (18 compiles of 118, 2.7 s of ``init_s`` on the chip)."""
+    out = jnp.zeros((route.at.shape[0], src.shape[1]), jnp.float32)
+    for j in range(route.at.shape[1]):
+        rows = src[route.at[:, j]].astype(jnp.float32)
+        if scale is not None:
+            rows = rows * scale[:, j, None]
+        out = out + jnp.where(route.held[:, j, None], rows, 0)
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(z, route):
+    """Token order to expert order: the row of ``z`` each of the chunk's
+    assignments reads.  Its transpose is the combine without weights, so
+    autodiff never writes the scatter-add of a gather."""
+    return z[route.token]
+
+
+def _dispatch_fwd(z, route):
+    return _dispatch(z, route), route
+
+
+def _dispatch_bwd(route, dx):
+    return _to_tokens(dx, route).astype(dx.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weight, route):
+    """Expert order to token order: each token's sum of its assignments'
+    rows of ``y`` times their weights, float32.  ``weight`` (C,) is in
+    expert order, as the backward pass wants it; the forward pass reads the
+    same numbers where the tokens hold them (``route``'s copy), so neither
+    moves a weight.  Its transpose is the dispatch, of the cotangent in
+    ``y``'s dtype, times the weight."""
+    return _to_tokens(y, route, route.weight)
+
+
+def _combine_fwd(y, weight, route):
+    return _combine(y, weight, route), (y, weight, route)
+
+
+def _combine_bwd(residuals, g):
+    y, weight, route = residuals
+    # the cast on the (rows, D) side, once, then one gather; exact where g is
+    # the cotangent of a cast to that dtype, which is all the layer makes
+    g = g.astype(y.dtype)[route.token].astype(jnp.float32)
+    return ((g * weight[:, None]).astype(y.dtype),
+            jnp.sum(g * y.astype(jnp.float32), axis=-1), None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route):
     """One chunk of the sorted assignments through the held experts.
 
-    ``token`` (C,) the row of ``z`` each assignment reads and adds to,
-    ``weight`` (C,) its combine weight, ``valid`` (C,) whether the slot
-    holds an assignment at all, ``sizes`` (held,) how many of the chunk's
-    rows each held expert owns, in order.  Rows that hold no assignment
-    are zeroed on the way in, between the products and on the way out:
-    the grouped product leaves what lies beyond its groups undefined.
-    Returns the chunk's part of the output, (T, D) float32."""
+    ``weight`` (C,) each assignment's combine weight, ``valid`` (C,) whether
+    the slot holds an assignment at all, ``sizes`` (held,) how many of the
+    chunk's rows each held expert owns, in order, ``route`` how the chunk's
+    rows move between token order and expert order (``_chunk_out``).  Rows
+    that hold no assignment are zeroed on the way in, between the products
+    and on the way out: the grouped product leaves what lies beyond its
+    groups undefined.  Returns the chunk's part of the output, (T, D)
+    float32."""
     keep = valid[:, None]
-    x = jnp.where(keep, z[token], 0)
+    x = jnp.where(keep, _dispatch(z, route), 0)
     gate = jax.lax.ragged_dot(x, w_gate, sizes)
     up = jax.lax.ragged_dot(x, w_up, sizes)
     h = jnp.where(keep, nn.silu(gate) * up, 0)
     y = jnp.where(keep, jax.lax.ragged_dot(h, w_down, sizes), 0)
-    y = y.astype(jnp.float32) * weight[:, None]
-    return jnp.zeros(z.shape, jnp.float32).at[token].add(y)
+    return _combine(y, weight, route)
 
 
 def _chunk_sizes(lo, starts, ends, chunk):
@@ -184,18 +318,19 @@ def _chunk_sizes(lo, starts, ends, chunk):
 
 def _chunk_out(lo, z, mats, weight, index, k, chunk):
     """The part of the output that the sorted rows ``[lo, lo + chunk)``
-    give.  ``index = (order, starts, ends, assigned)``: the sort of the
-    (row, slot) assignments by held expert, each held expert's run in it,
-    and how many assignments are held at all."""
-    order, starts, ends, assigned = index
-    sel = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
-    valid = lo + jnp.arange(chunk) < assigned
-    return _expert_chunk(z, *mats, sel // k, weight[sel], valid,
-                         _chunk_sizes(lo, starts, ends, chunk))
+    give.  ``weight``: the combine weights in the order of the sort;
+    ``index``: the layer's ``_Sort``."""
+    sel = jax.lax.dynamic_slice_in_dim(index.order, lo, chunk)
+    valid = lo + jnp.arange(chunk) < index.assigned
+    at = index.rank - lo
+    held = (at >= 0) & (at < chunk) & (index.rank < index.assigned)
+    route = _Route(sel // k, jnp.where(held, at, 0), held, index.chosen)
+    return _expert_chunk(z, *mats, jax.lax.dynamic_slice_in_dim(weight, lo, chunk),
+                         valid, _chunk_sizes(lo, index.starts, index.ends, chunk), route)
 
 
 def _active_chunks(index, chunk):
-    return (index[3] + chunk - 1) // chunk
+    return (index.assigned + chunk - 1) // chunk
 
 
 def _later_chunks(out, z, mats, weight, index, k, chunk):
@@ -239,7 +374,7 @@ def _routed_sum_bwd(k, chunk, residuals, g):
 
     grads = jax.lax.fori_loop(1, _active_chunks(index, chunk), later,
                               first_vjp(g))
-    return (*grads, None)   # the index arrays are integers
+    return (*grads, None)   # the index: integers, and a copy of the weights
 
 
 _routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
@@ -258,7 +393,10 @@ class RoutedExperts(nn.Module):
 
     Dropless: the (token, expert) assignments are sorted by held expert
     and go through ``jax.lax.ragged_dot`` in chunks of ``chunk_rows``
-    sorted rows.  The first chunk always runs; a later one runs only if
+    sorted rows.  A chunk's rows come by one gather of the tokens' rows and
+    go back by ``top_k`` gathers of the chunk's, one a slot, found by the
+    slot's rank in the sort (no scatter, forward or backward: the module's
+    text).  The first chunk always runs; a later one runs only if
     the sort reached it (``_routed_sum``: a loop over the chunks in use,
     a later chunk recomputed in the backward pass, so an idle one costs
     neither time nor memory).  The default chunk is twice the expected
@@ -272,6 +410,10 @@ class RoutedExperts(nn.Module):
     load), ``dropped`` (assignments to held experts no chunk computed: 0 by
     construction, counted from the chunks' own group sizes) and
     ``expert_index`` (T, k), the chosen ids.
+
+    Traced into a program (never in a step) it leaves one ``moe.rows``
+    event: the rows, slots and chunk rows, the held assignments balanced
+    routing gives, and the row gathers a chunk makes, forward and backward.
     """
 
     num_experts: int
@@ -302,15 +444,16 @@ class RoutedExperts(nn.Module):
             )(z.astype(jnp.float32))
             gates = jax.nn.softmax(logits, axis=-1)
             _, index = jax.lax.top_k(logits, k)
-            chosen = jnp.take_along_axis(gates, index, axis=-1)
+            chosen, counts = _chosen_and_counts(gates, index)
             chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-            counts = jnp.bincount(index.reshape(-1), length=n_exp)
             share = jax.lax.stop_gradient(counts / slots)
             aux_loss = n_exp * jnp.sum(share * jnp.mean(gates, axis=0))
             # sort the assignments by held expert; the others go last
             local = index.reshape(-1) - first
             key = jnp.where((local >= 0) & (local < n_held), local, n_held)
-            order = jnp.argsort(key, stable=True)
+            order, weight = _sorted_with(key, chosen.reshape(-1))
+            # each slot's place in the sort: the inverse permutation, a sort
+            rank = jax.lax.sort((order, jnp.arange(slots)), num_keys=1)[1]
             sizes = jax.lax.dynamic_slice_in_dim(counts, first, n_held)
             sizes = sizes.astype(jnp.int32)
             assigned = jnp.sum(sizes)
@@ -322,7 +465,15 @@ class RoutedExperts(nn.Module):
             chunk = 2 * math.ceil(slots * n_held / n_exp)
         chunk = min(_round_up(max(chunk, 1), 8), _round_up(slots, 8))
         n_chunks = -(-slots // chunk)
+        if _trace.enabled():
+            # shape arithmetic: a chunk gathers its own rows twice (x; g in
+            # the backward) and every token's slots twice (y; dx)
+            _trace.event(
+                "moe.rows", rows=rows, slots=slots, chunk=chunk,
+                expected=slots * n_held / n_exp, dtype=jnp.dtype(self.dtype).name,
+                gathered=2 * chunk + 2 * slots)
         order = jnp.pad(order, (0, n_chunks * chunk - slots))
+        weight = jnp.pad(weight, (0, n_chunks * chunk - slots))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         w_gate = self.param("w_gate", init, (n_held, d, self.d_ff))
@@ -331,9 +482,9 @@ class RoutedExperts(nn.Module):
 
         with jax.named_scope("experts"):
             mats = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
-            sort = (order, starts, ends, assigned)
-            out = _routed_sum(z.astype(self.dtype), mats, chosen.reshape(-1),
-                              sort, k, chunk)
+            sort = _Sort(order, rank.reshape(rows, k), jax.lax.stop_gradient(chosen),
+                         starts, ends, assigned)
+            out = _routed_sum(z.astype(self.dtype), mats, weight, sort, k, chunk)
             y = out.astype(self.dtype).reshape(x.shape)
         # what the chunks in use computed, from their own group sizes
         active = _active_chunks(sort, chunk)

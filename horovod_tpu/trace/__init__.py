@@ -91,6 +91,7 @@ SITES = (
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
     "flash.tiles",         # a flash kernel traced: tile visits, iterations
+    "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -112,8 +113,10 @@ DEVICE_SCOPES = (
 #: ``router_ms`` / ``expert_ffn_ms`` read them by ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
-                # top-k, the sort and index building
-    "experts",  # RoutedExperts: gather, grouped products, scatter-add
+                # top-k, counts, the sort by held expert and its inverse
+    "experts",  # RoutedExperts: the rows gathered into expert order,
+                # grouped products, the rows gathered back by rank and
+                # summed with their weights (no scatter since PR 31)
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of

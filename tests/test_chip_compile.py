@@ -152,6 +152,47 @@ def test_grouped_products_are_a_kernel_on_the_chip(one_chip):
     assert cost["flops"] < 3 * 2 * rows * d * f
 
 
+def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip):
+    """``RoutedExperts`` forward and backward at the SDAR cell's shapes (8,192
+    rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) as the
+    v5e compiler leaves it: no scatter at all (before PR 31: two scatter-adds
+    of 16,384 rows, each a sort of its indices, a gather of its updates and a
+    sorted scatter, and three scatters of single numbers, into
+    ``s32[num_experts]`` among them), no float32 gather of rows, and no sort
+    but the four the layer asks for (top-k, the assignments by held expert,
+    its inverse, the weights' cotangent back)."""
+    import re
+
+    from horovod_tpu.parallel.moe import RoutedExperts
+
+    rows, d, ff, experts, top_k, held = 8192, 2048, 768, 128, 8, 16
+    chunk = 2 * rows * top_k * held // experts
+    layer = RoutedExperts(experts, top_k, d, ff, held=(0, held), dtype=jnp.bfloat16)
+    x = _sds((1, rows, d), jnp.bfloat16, one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, rows, d), jnp.bfloat16)))
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), params["params"])
+
+    def loss(p, x):
+        y, stats = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + stats["aux_loss"]
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    ops = re.findall(r"= (\(.*?\)|\S+) (gather|scatter|sort)\(", text)
+    shapes = lambda kind: [re.sub(r"\{[^}]*\}", "", s) for s, k in ops if k == kind]
+    assert shapes("scatter") == []
+    gathers = shapes("gather")
+    assert not [s for s in gathers if s.startswith("f32") and f",{d}]" in s], gathers
+    # the first chunk, and the loop over the later ones (recomputed backward)
+    assert gathers.count(f"bf16[{chunk},{d}]") == 2 + 3
+    assert gathers.count(f"bf16[{rows},{d}]") == 4 * top_k
+    sorts = shapes("sort")
+    assert not [s for s in sorts if f"[{chunk}]" in s], sorts
+    assert len(sorts) == 4 and sum(f"[{rows * top_k}]" in s for s in sorts) == 3
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
 # -- serving kernels: decode and chunked prefill ------------------------------
 
 

@@ -274,6 +274,208 @@ def test_dropless_under_a_router_forced_onto_one_expert():
     np.testing.assert_allclose(y, want, atol=2e-6)
 
 
+# -- how the rows move (PR 31): gathers by rank against the plain definition -------
+
+
+def _plain_layer(params, x, top_k, held, experts=16):
+    """The routed layer as it was before its rows moved by gathers alone,
+    kept plain: the chosen gates by ``take_along_axis``, the counts by
+    ``bincount``, every sorted assignment in ONE chunk, rows into expert
+    order by ``z[token]`` and back by ``zeros.at[token].add(...)``, ordinary
+    autodiff throughout.  Returns ``(y, stats)`` as the layer does."""
+    first, n_held = held
+    z = x.reshape(-1, x.shape[-1])
+    slots = z.shape[0] * top_k
+    logits = jnp.dot(z, params["router"]["kernel"], precision=jax.lax.Precision.HIGHEST)
+    gates = jax.nn.softmax(logits, axis=-1)
+    _, index = jax.lax.top_k(logits, top_k)
+    chosen = jnp.take_along_axis(gates, index, axis=-1)
+    chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    counts = jnp.bincount(index.reshape(-1), length=experts)
+    aux = experts * jnp.sum(jax.lax.stop_gradient(counts / slots) * jnp.mean(gates, axis=0))
+    local = index.reshape(-1) - first
+    order = jnp.argsort(jnp.where((local >= 0) & (local < n_held), local, n_held), stable=True)
+    sizes = counts[first:first + n_held].astype(jnp.int32)
+    assigned = jnp.sum(sizes)
+    token = order // top_k
+    keep = (jnp.arange(slots) < assigned)[:, None]
+    rows = jnp.where(keep, z[token], 0)
+    hidden = jnp.where(keep, jax.nn.silu(jax.lax.ragged_dot(rows, params["w_gate"], sizes))
+                       * jax.lax.ragged_dot(rows, params["w_up"], sizes), 0)
+    y = jnp.where(keep, jax.lax.ragged_dot(hidden, params["w_down"], sizes), 0)
+    y = y * chosen.reshape(-1)[order][:, None]
+    out = jnp.zeros(z.shape, jnp.float32).at[token].add(y)
+    stats = {"aux_loss": aux, "assigned": assigned, "expert_index": index,
+             "load_max_over_mean": jnp.max(sizes) / (jnp.maximum(assigned, 1) / n_held)}
+    return out.reshape(x.shape), stats
+
+
+def _routed_by_hand(choices, seed=0):
+    """``(x, router kernel)`` under which row ``t`` of ``x`` (1, T, 32) chooses
+    exactly ``choices[t]`` of 16 experts, in that order: row ``t`` is four
+    times the ``t``-th unit vector plus a little noise, and the router's
+    ``t``-th row holds the logits wanted of it."""
+    rng = np.random.default_rng(seed)
+    rows, top_k = choices.shape
+    wanted = 0.05 * rng.standard_normal((32, 16))
+    for t in range(rows):
+        wanted[t, choices[t]] = 4.5 - 0.5 * np.arange(top_k)
+    x = 4.0 * np.eye(rows, 32) + 0.02 * rng.standard_normal((rows, 32))
+    return jnp.asarray(x[None], jnp.float32), jnp.asarray(wanted, jnp.float32)
+
+
+def _choices(rows, top_k, rng, always=()):
+    """Distinct experts a row, ``always`` among them."""
+    rest = [e for e in range(16) if e not in always]
+    return np.array([list(always) + list(rng.permutation(rest)[:top_k - len(always)])
+                     for _ in range(rows)])
+
+
+def _routing(case):
+    """``(choices (T, 4), held, chunk_rows, chunks in use)`` of a named case."""
+    rng = np.random.default_rng(31)
+    if case == "balanced":                      # the default chunk, one in use
+        return _choices(24, 4, rng), (4, 4), None, 1
+    if case == "one_expert_many_chunks":        # every row onto held expert 5
+        return _choices(24, 4, rng, always=(5,)), (4, 4), 8, None
+    if case == "all_held_and_none_held":
+        choices = _choices(24, 4, rng)
+        choices[0], choices[1] = [7, 4, 6, 5], [0, 9, 15, 3]
+        return choices, (4, 4), 8, None
+    if case == "held_range_from_8":
+        return _choices(24, 4, rng), (8, 2), None, 1
+    if case == "chunk_not_a_multiple_of_the_load":
+        return _choices(24, 4, rng, always=(6,)), (4, 4), 16, None
+    raise KeyError(case)
+
+
+_ROUTING_CASES = ["balanced", "one_expert_many_chunks", "all_held_and_none_held",
+                  "held_range_from_8", "chunk_not_a_multiple_of_the_load"]
+
+
+def _close(got, want, what):
+    scale = max(1.0, float(np.max(np.abs(want))))
+    np.testing.assert_allclose(got, want, atol=1e-6 * scale, rtol=0, err_msg=what)
+
+
+@pytest.mark.parametrize("case", _ROUTING_CASES)
+def test_rows_moved_by_gathers_give_the_plain_definition(case):
+    """The layer (rows into expert order by one gather, back by ``top_k``
+    gathers by rank, every transpose written by hand) against
+    ``_plain_layer`` (``z[token]``, ``zeros.at[token].add``, autodiff): the
+    output, the statistics, and the gradient with respect to the rows, the
+    three expert matrices and, through the router, the weights."""
+    choices, held, chunk_rows, in_use = _routing(case)
+    x, kernel = _routed_by_hand(choices)
+    layer = _layer(held, chunk_rows)
+    params = dict(layer.init(jax.random.PRNGKey(0), x)["params"], router={"kernel": kernel})
+    y, stats = layer.apply({"params": params}, x)
+    want, plain = _plain_layer(params, x, 4, held)
+    np.testing.assert_array_equal(stats["expert_index"], choices)      # the case is what it says
+    np.testing.assert_array_equal(stats["expert_index"], plain["expert_index"])
+    inside = (choices >= held[0]) & (choices < held[0] + held[1])
+    assert int(stats["assigned"]) == int(plain["assigned"]) == inside.sum()
+    assert int(stats["dropped"]) == 0
+    assert float(stats["load_max_over_mean"]) == float(plain["load_max_over_mean"])
+    np.testing.assert_allclose(stats["aux_loss"], plain["aux_loss"], rtol=1e-6)
+    chunk = chunk_rows or 2 * 96 * held[1] // 16
+    if in_use is None:
+        assert inside.sum() > chunk                        # a later chunk runs
+    else:
+        assert -(-int(inside.sum()) // chunk) == in_use
+    if case == "all_held_and_none_held":
+        assert inside[0].all() and not inside[1].any()
+        assert float(jnp.max(jnp.abs(y[0, 1]))) == 0.0
+    if case == "chunk_not_a_multiple_of_the_load":
+        assert inside.sum() % chunk
+    _close(y, want, "output")
+    assert float(jnp.max(jnp.abs(want))) > 1e-2
+
+    w = jax.random.normal(jax.random.PRNGKey(6), x.shape)
+
+    def loss(fn):
+        def of(p, x):
+            y, stats = fn(p, x)
+            return jnp.sum(w * y) + stats["aux_loss"]
+        return of
+
+    got = jax.grad(loss(lambda p, x: layer.apply({"params": p}, x)), (0, 1))(params, x)
+    ref = jax.grad(loss(lambda p, x: _plain_layer(p, x, 4, held)), (0, 1))(params, x)
+    flat = dict(jax.tree_util.tree_leaves_with_path(ref))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(got):
+        _close(leaf, flat[path], jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(ref[0]["router"]["kernel"]))) > 1e-3
+
+
+@pytest.mark.parametrize("case", _ROUTING_CASES)
+def test_chosen_gates_and_counts_are_the_scatter_forms_exactly(case):
+    """One dense comparison against ``take_along_axis`` and ``bincount``:
+    the same numbers to the last bit, and the same gradient."""
+    from horovod_tpu.parallel import moe
+
+    choices = jnp.asarray(_routing(case)[0])
+    gates = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(3), (24, 16)), axis=-1)
+    chosen, counts = moe._chosen_and_counts(gates, choices)
+    np.testing.assert_array_equal(chosen, jnp.take_along_axis(gates, choices, axis=-1))
+    np.testing.assert_array_equal(counts, jnp.bincount(choices.reshape(-1), length=16))
+    assert counts.dtype == jnp.int32 and int(counts.sum()) == choices.size
+    w = jax.random.normal(jax.random.PRNGKey(4), choices.shape)
+    np.testing.assert_array_equal(
+        jax.grad(lambda g: jnp.sum(w * moe._chosen_and_counts(g, choices)[0]))(gates),
+        jax.grad(lambda g: jnp.sum(w * jnp.take_along_axis(g, choices, axis=-1)))(gates))
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("dtype,chunk_rows", [("float32", None), ("bfloat16", None),
+                                              ("bfloat16", 8)])
+def test_no_scatter_forward_or_backward_and_rows_gathered_in_the_layer_s_dtype(dtype, chunk_rows):
+    """What autodiff would write for a gather is a scatter-add: the traced
+    layer, forward and backward, holds none (a row moves by gathers, a single
+    number by sorts), and every gather of rows is in the layer's dtype: the
+    cotangent is cast on its (rows, D) side before it is gathered."""
+    layer = RoutedExperts(16, 4, 32, 24, held=(4, 4), chunk_rows=chunk_rows,
+                          dtype=jnp.dtype(dtype))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 32), jnp.dtype(dtype))
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+
+    def loss(p, x):
+        y, stats = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + stats["aux_loss"]
+
+    eqns = list(_primitives(jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x).jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert not {n for n in names if n.startswith("scatter")}, names
+    assert {"gather", "sort", "ragged_dot_general"} <= names or {"gather", "sort", "ragged_dot"} <= names
+    wide = [e for e in eqns if e.primitive.name == "gather" and e.invars[0].aval.ndim == 2
+            and e.invars[0].aval.shape[1] == 32]
+    assert len(wide) >= 2 * (1 + 4)             # x and g; y and dx for each of 4 slots
+    assert {str(e.invars[0].aval.dtype) for e in wide} == {dtype}
+
+
+def test_moe_rows_event_says_what_a_chunk_moves():
+    """Tracing the layer leaves one ``moe.rows`` instant: shape arithmetic."""
+    from horovod_tpu import trace
+
+    layer = RoutedExperts(16, 4, 32, 24, held=(4, 4), dtype=jnp.bfloat16)
+    x = jnp.zeros((2, 12, 32), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x))["params"]
+    t0 = trace.now()
+    jax.make_jaxpr(lambda p: layer.apply({"params": p}, x)[0])(params)
+    events = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"]
+    assert events == [{"rows": 24, "slots": 96, "chunk": 48, "expected": 24.0,
+                       "dtype": "bfloat16", "gathered": 2 * 48 + 2 * 96}]
+
+
 # -- the model's keys -----------------------------------------------------------
 
 
