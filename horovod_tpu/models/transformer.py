@@ -185,6 +185,41 @@ class TransformerConfig:
     # ``causal``/``window``; the head runs on the noisy half only, so the
     # logits are (B, L, V).  'dot' and 'flash' attention only.
     block_diffusion: Optional[int] = None
+    # Latent attention (MLA; DeepSeek-V2, arXiv:2405.04434), named as the
+    # published configs name it.  All four set: ``Attention`` builds ``q``
+    # (heads of ``qk_nope_head_dim + qk_rope_head_dim``), ``kv_a`` (the
+    # stream down to ``kv_lora_rank`` + ONE rotary key of ``qk_rope_head_dim``
+    # for all heads), ``kv_a_norm`` (RMSNorm over the latent), ``kv_b`` (the
+    # latent up to a head's ``qk_nope_head_dim`` key and ``v_head_dim``
+    # value) and ``o``; RoPE on the rotary parts only; scores scaled by
+    # ``(qk_nope_head_dim + qk_rope_head_dim) ** -0.5``.  Needs
+    # ``hidden_size``; ``head_dim`` and ``num_kv_heads`` play no part.
+    # 'dot' and 'flash' only, training only: no window, no block diffusion,
+    # no ring, no ``shard_axis`` > 1, no paged serving (no latent cache yet).
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # The dense feed-forward's width when it is not d_model * mlp_ratio.
+    intermediate_size: Optional[int] = None
+    # With a routed feed-forward: the first ``first_dense_layers`` layers
+    # keep the dense MlpBlock; ``num_shared_experts`` > 0 adds, in every
+    # routed layer, one SwiGLU of width num_shared_experts x
+    # moe_intermediate_size beside the routed sum, whole on every chip.
+    first_dense_layers: int = 0
+    num_shared_experts: int = 0
+    # The router (parallel/moe.py RoutedExperts): ``router_scoring``
+    # 'softmax' | 'sigmoid'; the chosen weights, renormalised, times
+    # ``routed_scaling_factor``; ``router_selection_bias``: a bias a
+    # expert added to the scores for the SELECTION only (DeepSeek-V3's
+    # ``e_score_correction_bias``: no gradient, not the optimizer's: it
+    # lives in the ``batch_stats`` collection, which the train steps carry
+    # beside the parameters); ``router_seq_aux``: the auxiliary loss in
+    # DeepSeek's sequence-wise form instead of Switch's.
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    router_selection_bias: bool = False
+    router_seq_aux: bool = False
 
     def __post_init__(self):
         kv = self.num_kv_heads
@@ -218,6 +253,39 @@ class TransformerConfig:
                     f"not {self.attention_impl!r}")
             if self.window is not None:
                 raise ValueError("block_diffusion takes no window")
+        latent = (self.kv_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if any(v is not None for v in latent):
+            if not all(latent) or self.hidden_size is None:
+                raise ValueError(
+                    "latent attention needs kv_lora_rank, qk_nope_head_dim, "
+                    f"qk_rope_head_dim, v_head_dim (got {latent}) and "
+                    "hidden_size")
+            if self.attention_impl not in ("dot", "flash"):
+                raise ValueError(
+                    "latent attention supports attention_impl 'dot'/'flash', "
+                    f"not {self.attention_impl!r} (the ring rotates keys and "
+                    "values of one width)")
+            if (self.window is not None or self.block_diffusion is not None
+                    or self.qk_norm or kv not in (None, self.num_heads)):
+                raise ValueError(
+                    "latent attention takes no window, no block_diffusion, "
+                    "no qk_norm and no grouped key/value heads")
+        if self.num_experts is None and (
+                self.first_dense_layers or self.num_shared_experts):
+            raise ValueError(
+                "first_dense_layers and num_shared_experts need num_experts")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_scoring is 'softmax' or 'sigmoid', got "
+                f"{self.router_scoring!r}")
+
+    @property
+    def mlp_hidden(self) -> int:
+        """Width of the dense feed-forward."""
+        if self.intermediate_size is not None:
+            return self.intermediate_size
+        return self.d_model * self.mlp_ratio
 
     def block_remat_policies(self):
         """Per-block policy names (``remat_policy`` resolved, with the
@@ -284,7 +352,9 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
                          window=None, mask=None):
     """Standard attention; offsets support sequence-sharded blocks.
 
-    q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv | H — under GQA
+    q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv | H (``v`` may be of
+    another width than ``q`` and ``k``, latent attention's 192 / 128: the
+    scale is that of ``q``'s width and the output as wide as ``v``) — under GQA
     (H_kv < H) the einsums GROUP the contraction (query head
     ``hk*g + j`` reads kv head ``hk``) instead of repeating K/V to full
     heads, so no inflated K/V tensor is ever materialized.  Softmax in
@@ -324,7 +394,7 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
         return jnp.einsum(
             "bhgqk,bkhd->bqhgd",
             probs.reshape(b, h_kv, h // h_kv, s_q, s_k), v,
-        ).reshape(b, s_q, h, d)
+        ).reshape(b, s_q, h, v.shape[-1])
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
@@ -338,7 +408,11 @@ def _shard_size(cfg: TransformerConfig) -> int:
     tp = axis_size_or_1(cfg.shard_axis)
     if tp > 1:
         kv = cfg.num_kv_heads or cfg.num_heads
-        hidden = cfg.d_model * cfg.mlp_ratio
+        hidden = cfg.mlp_hidden
+        if cfg.kv_lora_rank is not None:
+            raise ValueError(
+                f"shard_axis {cfg.shard_axis!r} of size {tp} takes no latent "
+                "attention (the latent products are not sharded yet)")
         if cfg.num_heads % tp or kv % tp or hidden % tp:
             raise ValueError(
                 f"shard_axis {cfg.shard_axis!r} of size {tp} must divide "
@@ -349,6 +423,32 @@ def _shard_size(cfg: TransformerConfig) -> int:
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+
+    def _latent_qkv(self, x, positions, dense, heads):
+        """Latent attention's q and k (``qk_nope_head_dim +
+        qk_rope_head_dim`` wide) and v (``v_head_dim``) as the kernels take
+        them: keys and values come up from one ``kv_lora_rank``-wide latent a
+        position, and the key's rotary part is ONE head, shared by all (it
+        is broadcast to the heads here: the layout the chip ran faster,
+        docs/ATTENTION.md).  Only the rotary parts are rotated."""
+        cfg = self.cfg
+        rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        rot, vd = cfg.qk_rope_head_dim, cfg.v_head_dim
+        q = dense(features=(heads, nope + rot), name="q")(x)
+        with jax.named_scope("mla"):
+            kv_a = dense(features=rank + rot, name="kv_a")(x)
+            latent = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                                name="kv_a_norm")(kv_a[..., :rank])
+            kv_b = dense(features=(heads, nope + vd), name="kv_b")(latent)
+            k_rot = rope(kv_a[..., None, rank:], positions, cfg.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], positions,
+                                     cfg.rope_theta)], axis=-1)
+            k = jnp.concatenate(
+                [kv_b[..., :nope],
+                 jnp.broadcast_to(k_rot, (*kv_b.shape[:-1], rot))], axis=-1)
+            v = kv_b[..., nope:]
+        return q, k, v
 
     @nn.compact
     def __call__(self, x, positions, paged=None, layer: int = 0):
@@ -368,16 +468,23 @@ class Attention(nn.Module):
         tp = _shard_size(cfg)
         heads = cfg.num_heads // tp
         kv_heads = kv_heads // tp
-        q = dense(features=(heads, cfg.head_dim), name="q")(x)
-        k = dense(features=(kv_heads, cfg.head_dim), name="k")(x)
-        v = dense(features=(kv_heads, cfg.head_dim), name="v")(x)
-        if cfg.qk_norm:
-            q = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                           name="q_norm")(q)
-            k = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                           name="k_norm")(k)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.kv_lora_rank is not None:
+            if paged is not None:
+                raise ValueError(
+                    "paged serving takes no latent attention (the cache "
+                    "holds keys and values of one width: no latent cache yet)")
+            q, k, v = self._latent_qkv(x, positions, dense, heads)
+        else:
+            q = dense(features=(heads, cfg.head_dim), name="q")(x)
+            k = dense(features=(kv_heads, cfg.head_dim), name="k")(x)
+            v = dense(features=(kv_heads, cfg.head_dim), name="v")(x)
+            if cfg.qk_norm:
+                q = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                               name="q_norm")(q)
+                k = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                               name="k_norm")(k)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         if paged is not None and cfg.block_diffusion is not None:
             raise ValueError("paged serving takes no block_diffusion model")
         if paged is not None:
@@ -467,6 +574,9 @@ class Attention(nn.Module):
 
 class MlpBlock(nn.Module):
     cfg: TransformerConfig
+    # the SwiGLU's width where it is not the config's dense one (the shared
+    # experts beside a routed sum)
+    hidden: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -478,7 +588,7 @@ class MlpBlock(nn.Module):
         # Megatron's two collectives per block.  tp == 1 is the
         # unsharded program verbatim.
         tp = _shard_size(cfg)
-        hidden = cfg.d_model * cfg.mlp_ratio // tp
+        hidden = (self.hidden or cfg.mlp_hidden) // tp
         gate = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="gate")(x)
         up = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="up")(x)
         out = nn.Dense(
@@ -491,6 +601,9 @@ class MlpBlock(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    # whether THIS layer's feed-forward is the routed one: the model's
+    # layers after its ``first_dense_layers``, where it has ``num_experts``
+    routed: bool = False
 
     @nn.compact
     def __call__(self, x, positions, paged=None, layer: int = 0):
@@ -500,23 +613,38 @@ class Block(nn.Module):
         )
         x = x + Attention(cfg, name="attn")(
             norm(name="ln1")(x), positions, paged=paged, layer=layer)
-        if cfg.num_experts is None:
+        if not self.routed:
             x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
             return x
         # routed feed-forward: (x, the layer's routing statistics)
         from ..parallel.moe import RoutedExperts
 
+        z = norm(name="ln2")(x)
         y, stats = RoutedExperts(
             num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
             d_model=cfg.d_model, d_ff=cfg.moe_intermediate_size,
-            held=cfg.held_experts, dtype=cfg.dtype, name="moe",
-        )(norm(name="ln2")(x))
+            held=cfg.held_experts, dtype=cfg.dtype,
+            scoring=cfg.router_scoring,
+            scaling_factor=cfg.routed_scaling_factor,
+            selection_bias=cfg.router_selection_bias,
+            seq_aux=cfg.router_seq_aux, name="moe",
+        )(z)
+        if cfg.num_shared_experts:
+            # an ordinary SwiGLU beside the routed sum, every chip the whole
+            # of it; a scope of its own, not the routed layer's ``experts``
+            with jax.named_scope("shared_experts"):
+                y = y + MlpBlock(
+                    cfg, hidden=(cfg.num_shared_experts
+                                 * cfg.moe_intermediate_size),
+                    name="shared_experts")(z)
         return x + y, stats
 
 
 class Transformer(nn.Module):
     """Decoder-only LM.  ``__call__(tokens, positions=None) -> logits``;
-    ``(logits, aux)`` with a routed feed-forward (``cfg.num_experts``); with
+    ``(logits, aux)`` with a routed feed-forward (``cfg.num_experts``; the
+    statistics are over the routed layers, ``aux_loss`` in the form
+    ``cfg.router_seq_aux`` names); with
     ``cfg.block_diffusion`` the tokens are ``[noisy || clean]`` (B, 2L) and
     the logits those of the noisy half, (B, L, V)."""
 
@@ -564,15 +692,17 @@ class Transformer(nn.Module):
                     Block, policy=_checkpoint_policy(pol)
                 )
                 block_cls_for[pol] = block_cls
+            # a routed model's leading dense layers keep the dense MlpBlock
+            routed_here = routed and i >= cfg.first_dense_layers
+            block = block_cls(cfg, routed=routed_here, name=f"layer_{i}")
             if paged is not None:
                 # serving (inference-only) path: the paged-cache state
                 # threads through every block, each addressing its own
                 # pool layer; never composes with remat (train=False)
-                x = block_cls(cfg, name=f"layer_{i}")(
-                    x, positions, paged, i)
+                x = block(x, positions, paged, i)
             else:
-                x = block_cls(cfg, name=f"layer_{i}")(x, positions)
-            if routed:
+                x = block(x, positions)
+            if routed_here:
                 x, stats = x
                 layer_stats.append(stats)
         if cfg.block_diffusion is not None:
@@ -604,6 +734,23 @@ class Transformer(nn.Module):
         return logits
 
 
+def _cross_entropy_plus_aux(outputs, targets, weights, aux_coef):
+    """Mean over the positions of (``weights`` x) the float32 cross-entropy of
+    a model's ``(logits, aux)`` (or of the logits alone) against ``targets``,
+    plus ``aux_coef`` x the router's auxiliary loss."""
+    import optax
+
+    logits, aux = outputs if isinstance(outputs, tuple) else (outputs, None)
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), targets)
+    if weights is not None:
+        ce = weights.astype(jnp.float32) * ce
+    loss = jnp.mean(ce)
+    if aux is not None and aux_coef:
+        loss = loss + aux_coef * aux["aux_loss"]
+    return loss
+
+
 def block_diffusion_loss(outputs, labels, aux_coef: float = 0.0):
     """The masked-diffusion loss of block-diffusion training, a ``loss_fn``
     for the train step: ``outputs`` is the model's ``(logits, aux)`` (or the
@@ -612,16 +759,16 @@ def block_diffusion_loss(outputs, labels, aux_coef: float = 0.0):
     position ``masked / t`` of its block (0 where the position was not
     masked).  Loss = mean over the L positions of weight x cross-entropy,
     in float32, plus ``aux_coef`` x the router's auxiliary loss."""
-    import optax
-
-    logits, aux = outputs if isinstance(outputs, tuple) else (outputs, None)
     targets, weights = labels
-    ce = optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), targets)
-    loss = jnp.mean(weights.astype(jnp.float32) * ce)
-    if aux is not None and aux_coef:
-        loss = loss + aux_coef * aux["aux_loss"]
-    return loss
+    return _cross_entropy_plus_aux(outputs, targets, weights, aux_coef)
+
+
+def next_token_loss(outputs, labels, aux_coef: float = 0.0):
+    """Mean softmax cross-entropy in float32 of a routed model's ``(logits,
+    aux)`` (or of the logits alone) against integer ``labels``, plus
+    ``aux_coef`` x the router's auxiliary loss: a ``loss_fn`` for the train
+    step."""
+    return _cross_entropy_plus_aux(outputs, labels, None, aux_coef)
 
 
 def modeled_activation_bytes(cfg: TransformerConfig, batch: int,
@@ -648,6 +795,16 @@ def modeled_activation_bytes(cfg: TransformerConfig, batch: int,
     "policies"}``; ``total_bytes`` sums the per-block figure over the
     resolved per-block policies.
     """
+    uncounted = [name for name, is_set in (
+        ("latent attention (kv_lora_rank)", cfg.kv_lora_rank is not None),
+        ("intermediate_size", cfg.intermediate_size is not None),
+        ("a routed feed-forward (num_experts)", cfg.num_experts is not None),
+    ) if is_set]
+    if uncounted:
+        raise ValueError(
+            "modeled_activation_bytes counts one head width and a dense "
+            f"feed-forward of d_model * mlp_ratio; it cannot count "
+            f"{', '.join(uncounted)}")
     s = int(seq if seq is not None else cfg.max_seq_len)
     act = jnp.dtype(cfg.dtype).itemsize
     kv_heads = cfg.num_kv_heads or cfg.num_heads

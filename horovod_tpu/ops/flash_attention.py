@@ -292,11 +292,13 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
             "bwd_dkv": count(by_keys, s_k // block_k)}
 
 
-def _note_tiles(kernels, kv_offset, **shape):
-    """One ``flash.tiles`` instant a kernel as it is traced: its name and a
-    head's ``visited`` tiles and loop ``iterations``.  ``kernels`` maps a
-    kernel's name to its key in ``tile_counts``.  Host bookkeeping at trace
-    time; a traced ``kv_offset`` (a ring step) has no count to give."""
+def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
+    """One ``flash.tiles`` instant a kernel as it is traced: its name, a
+    head's ``visited`` tiles and loop ``iterations``, and the widths of a
+    tile's products (``d_qk`` of queries and keys, ``d_v`` of values).
+    ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
+    bookkeeping at trace time; a traced ``kv_offset`` (a ring step) has no
+    count to give."""
     if kv_offset is None:
         kv_offset = 0
     if not _trace.enabled() or not isinstance(kv_offset, int):
@@ -305,7 +307,7 @@ def _note_tiles(kernels, kv_offset, **shape):
     for name, key in kernels.items():
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
-                     iterations=iterations)
+                     iterations=iterations, d_qk=d_qk, d_v=d_v)
 
 
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
@@ -320,7 +322,7 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
         kv_off = kvoff_ref[0]
     else:
         kv_off = kvoff_ref[pl.program_id(0) // off_div]
-    head_dim = q_ref.shape[-1]
+    head_dim = v_ref.shape[-1]  # the accumulator is as wide as the values
     q = q_ref[0].astype(jnp.float32) * sm_scale  # (block_q, D)
     q_off = qi * block_q
 
@@ -424,6 +426,7 @@ def _off_arr(kv_offset):
 def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
                   with_lse=False, window=None, kv_offset=None, bd=None):
     b, s, h, d = q.shape
+    dv = v.shape[-1]  # the values' own width (latent attention: 192 / 128)
     group = _group_of(q, k)
     h_kv = h // group
     orig_s = s
@@ -434,7 +437,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
     s_q, s_k = qp.shape[1], kp.shape[1]
     qf = _fold(qp, b, h, d)
     kf = _fold(kp, b, h_kv, d)
-    vf = _fold(vp, b, h_kv, d)
+    vf = _fold(vp, b, h_kv, dv)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     kernel = functools.partial(
@@ -449,7 +452,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
     )
     _note_tiles({"flash_attention_fwd": "fwd"}, kv_offset, s_q=s_q, s_k=s_k,
                 block_q=block_q, block_k=block_k, seq_len=orig_s,
-                causal=causal, window=window, bd=bd)
+                causal=causal, window=window, bd=bd, d_qk=d, d_v=dv)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
@@ -462,22 +465,22 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
             # HBM once per kv head, not once per query head
             pl.BlockSpec((1, s_k, d),
                          lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, s_k, d),
+            pl.BlockSpec((1, s_k, dv),
                          lambda bh, qi: (bh // group, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0)),
             # trailing singleton: TPU block tiling requires the last two
             # block dims divisible by (8, 128) or equal to the array's
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s_q, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(_off_arr(kv_offset), qf, kf, vf)
-    out = _unfold(out, b, h, s_q, d)[:, :orig_s]
+    out = _unfold(out, b, h, s_q, dv)[:, :orig_s]
     if with_lse:
         return out, lse  # lse stays folded+padded: (B*H, S_q_padded)
     return out
@@ -570,14 +573,14 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     k_off = ki * block_k
     k_blk = k_ref[0].astype(jnp.float32)
     v_blk = v_ref[0].astype(jnp.float32)
-    d = k_blk.shape[-1]
+    d, dv = k_blk.shape[-1], v_blk.shape[-1]
     s_q = q_ref.shape[1] // group  # per-query-head padded length
     ranges = _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
                           causal=causal, window=window, kv_off=kv_off,
                           bd=None, rows_are_queries=False)
 
     carry = (jnp.zeros((block_k, d), jnp.float32),
-             jnp.zeros((block_k, d), jnp.float32))
+             jnp.zeros((block_k, dv), jnp.float32))
     for g in range(group):  # static unroll over the query-head group
         base = g * s_q
 
@@ -701,8 +704,11 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     delta happens once, not once per ring step.  Shapes: qf/gf
     (B*H, s_q, d), kf/vf (B*H_kv, s_k, d) with H_kv | H (GQA),
     lse_f/delta_f (B*H, s_q, 1).  Returns folded (dq, dk, dv) with
-    dk/dv per KV head."""
+    dk/dv per KV head.  ``vf`` and ``gf`` may be of another width than
+    ``qf`` and ``kf`` (latent attention), under the causal mask only; the
+    scale is that of the query-key width."""
     bh, s_q, d = qf.shape
+    dv_w = vf.shape[-1]   # the values' width: that of gf and of dv too
     bh_kv = kf.shape[0]
     if bh_kv <= 0 or bh % bh_kv:
         raise ValueError(f"folded q heads ({bh}) must be a multiple of "
@@ -718,7 +724,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
                  "flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
                  "bwd_dkv"},
                 kv_offset, s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
-                seq_len=orig_s, causal=causal, window=window, bd=bd)
+                seq_len=orig_s, causal=causal, window=window, bd=bd, d_qk=d,
+                d_v=dv_w)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bd=bd, **kw),
         name="flash_attention_bwd_dq",
@@ -727,8 +734,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             _SCALAR_SPEC,
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, s_k, d), lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, s_k, d), lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, s_k, dv_w), lambda bh, qi: (bh // group, 0, 0)),
+            pl.BlockSpec((1, block_q, dv_w), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
@@ -774,7 +781,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     # program sees its whole query-head group on the row axis — a free
     # reshape of the head-major fold (B, H_kv, G, s_q, d contiguity)
     qg = qf.reshape(bh_kv, group * s_q, d)
-    gg = gf.reshape(bh_kv, group * s_q, d)
+    gg = gf.reshape(bh_kv, group * s_q, dv_w)
     lse_g = lse_f.reshape(bh_kv, 1, group * s_q)
     delta_g = delta_f.reshape(bh_kv, 1, group * s_q)
     dk, dv = pl.pallas_call(
@@ -785,18 +792,18 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             _SCALAR_SPEC,
             pl.BlockSpec((1, group * s_q, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, group * s_q, d), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, block_k, dv_w), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, group * s_q, dv_w), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, dv_w), lambda bh, ki: (bh, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh_kv, s_k, d), vf.dtype),
+            jax.ShapeDtypeStruct((bh_kv, s_k, dv_w), vf.dtype),
         ],
         interpret=interpret,
     )(off, qg, kf, vf, gg, lse_g, delta_g)
@@ -813,7 +820,7 @@ def _fold_bwd_invariants(q, out, lse, g, block_q):
     )  # (B, S, H)
     delta = delta.transpose(0, 2, 1).reshape(b * h, s, 1)
     qf = _fold(_pad_to(q, block_q, axis=1), b, h, d)
-    gf = _fold(_pad_to(g, block_q, axis=1), b, h, d)
+    gf = _fold(_pad_to(g, block_q, axis=1), b, h, g.shape[-1])
     delta_f = _pad_to(delta, block_q, axis=1)
     lse_f = _pad_to(lse, block_q, axis=1)
     return qf, gf, lse_f, delta_f
@@ -822,7 +829,7 @@ def _fold_bwd_invariants(q, out, lse, g, block_q):
 def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
                    interpret, window=None, bd=None):
     b, s, h, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, dv_w = k.shape[2], v.shape[-1]
     orig_s = s
     block_q, block_k = _clamp_blocks(s, block_q, block_k)
     # lse arrives from the forward already folded and padded to the same
@@ -830,7 +837,7 @@ def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
     # invariants' pad is then a no-op on it
     qf, gf, lse_f, delta_f = _fold_bwd_invariants(q, out, lse, g, block_q)
     kf = _fold(_pad_to(k, block_k, axis=1), b, h_kv, d)
-    vf = _fold(_pad_to(v, block_k, axis=1), b, h_kv, d)
+    vf = _fold(_pad_to(v, block_k, axis=1), b, h_kv, dv_w)
     s_q, s_k = qf.shape[1], kf.shape[1]
     dq, dk, dv = _backward_folded(
         qf, kf, vf, gf, lse_f, delta_f, orig_s=orig_s, causal=causal,
@@ -839,7 +846,7 @@ def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
     )
     dq = _unfold(dq, b, h, s_q, d)[:, :orig_s]
     dk = _unfold(dk, b, h_kv, s_k, d)[:, :orig_s]
-    dv = _unfold(dv, b, h_kv, s_k, d)[:, :orig_s]
+    dv = _unfold(dv, b, h_kv, s_k, dv_w)[:, :orig_s]
     return dq, dk, dv
 
 
@@ -929,7 +936,9 @@ def flash_chunk_attention(q, k, v, q_starts, *, window=None, kv_start=None,
     """
     b, c, h, d = q.shape
     if k.shape != v.shape:
-        raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
+        raise ValueError(
+            f"k/v shapes differ: {k.shape} vs {v.shape} (the serving kernels "
+            "take keys and values of one width: no latent cache yet)")
     group = _group_of(q, k)
     h_kv = h // group
     s_k = k.shape[1]
@@ -1093,8 +1102,16 @@ def flash_attention(
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
+    if k.shape[:-1] != v.shape[:-1] or q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"q/k/v shapes do not fit: {q.shape}, {k.shape}, {v.shape} "
+            "(k and v differ in their last axis at most, q and k not there)")
+    if k.shape[-1] != v.shape[-1] and (
+            window is not None or block_diffusion is not None):
+        raise ValueError(
+            f"keys {k.shape[-1]} wide and values {v.shape[-1]} wide (latent "
+            "attention) take no window and no block_diffusion: those "
+            "kernels are not widened")
     _group_of(q, k)  # validate the GQA head split early
     if block_diffusion is None:
         return _flash(q, k, v, causal, block_q, block_k, interpret, window)

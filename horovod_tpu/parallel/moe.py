@@ -194,14 +194,19 @@ def _sorted_with_bwd(order, cotangents):
 _sorted_with.defvjp(_sorted_with_fwd, _sorted_with_bwd)
 
 
-def _chosen_and_counts(gates, index):
+def _chosen_and_counts(gates, index, sequences=None):
     """Each row's gates at its ``index`` (T, k) and how many slots chose
     each expert (E,), from one (rows, k, experts) comparison: dense forward
     and backward.  ``take_along_axis``'s transpose and ``bincount`` are
-    scatters of single numbers, which the chip does one at a time."""
+    scatters of single numbers, which the chip does one at a time.  With
+    ``sequences`` the counts are by sequence, (sequences, E): the rows are
+    that many sequences of equal length, one after the other."""
     onehot = index[..., None] == jnp.arange(gates.shape[-1])
     chosen = jnp.sum(jnp.where(onehot, gates[:, None, :], 0), axis=-1)
-    return chosen, jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+    if sequences is None:
+        return chosen, jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+    by_sequence = onehot.reshape(sequences, -1, *onehot.shape[1:])
+    return chosen, jnp.sum(by_sequence, axis=(1, 2), dtype=jnp.int32)
 
 
 class _Sort(NamedTuple):
@@ -391,6 +396,21 @@ class RoutedExperts(nn.Module):
     (``None``: all of them); the stacked expert matrices have ``count``
     leading entries.  What the absent experts would add is left out.
 
+    The router's other forms (DeepSeek-V3's; all inside the ``router``
+    scope, everything after the chosen ids and weights is the same code):
+    ``scoring="sigmoid"``: ``g = sigmoid(W_r z)``, the weights ``g_e / (sum
+    over the chosen of g + 1e-20)``; ``selection_bias``: the ``top_k`` are
+    taken of ``g + b`` and weighed by ``g`` without it.  ``b``
+    (``e_score_correction_bias``, one number an expert, zeros at init) is
+    no parameter: it lives in the ``batch_stats`` collection, which the
+    train steps carry beside the parameters, takes no gradient and is not
+    the optimizer's to move (its update rule between steps is not here
+    yet); ``scaling_factor``: the renormalised weights times it;
+    ``seq_aux``: the auxiliary loss a SEQUENCE (the input's leading axis),
+    ``sum_e f_e P_e`` with ``f_e = E / (k S) x n_e`` of that sequence's
+    ``S`` rows (no gradient through the counts) and ``P_e`` the sequence's
+    mean of ``g_e / sum g``; mean over the sequences.
+
     Dropless: the (token, expert) assignments are sorted by held expert
     and go through ``jax.lax.ragged_dot`` in chunks of ``chunk_rows``
     sorted rows.  A chunk's rows come by one gather of the tokens' rows and
@@ -405,7 +425,7 @@ class RoutedExperts(nn.Module):
 
     Returns ``(y, stats)``: ``aux_loss`` (Switch / Hugging Face form:
     ``E * sum_e (n_e / (k T)) * mean_T g_e``, no gradient through the
-    counts), and the counters ``assigned`` (assignments to held experts),
+    counts; or the sequence-wise form above), and the counters ``assigned`` (assignments to held experts),
     ``load_max_over_mean`` (largest held expert's load over the mean held
     load), ``dropped`` (assignments to held experts no chunk computed: 0 by
     construction, counted from the chunks' own group sizes) and
@@ -423,10 +443,17 @@ class RoutedExperts(nn.Module):
     held: Optional[Tuple[int, int]] = None
     chunk_rows: Optional[int] = None
     dtype: jnp.dtype = jnp.bfloat16
+    scoring: str = "softmax"
+    scaling_factor: float = 1.0
+    selection_bias: bool = False
+    seq_aux: bool = False
 
     @nn.compact
     def __call__(self, x):
         n_exp, k, d = self.num_experts, self.top_k, self.d_model
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"scoring is 'softmax' or 'sigmoid', got {self.scoring!r}")
         first, n_held = self.held if self.held is not None else (0, n_exp)
         if not (0 <= first and n_held >= 1 and first + n_held <= n_exp
                 and 1 <= k <= n_exp):
@@ -442,12 +469,39 @@ class RoutedExperts(nn.Module):
                 n_exp, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name="router",
             )(z.astype(jnp.float32))
-            gates = jax.nn.softmax(logits, axis=-1)
-            _, index = jax.lax.top_k(logits, k)
-            chosen, counts = _chosen_and_counts(gates, index)
-            chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-            share = jax.lax.stop_gradient(counts / slots)
-            aux_loss = n_exp * jnp.sum(share * jnp.mean(gates, axis=0))
+            if self.scoring == "softmax":
+                gates = jax.nn.softmax(logits, axis=-1)
+                select_by = logits
+            else:
+                gates = select_by = jax.nn.sigmoid(logits)
+            if self.selection_bias:
+                bias = self.variable(
+                    "batch_stats", "e_score_correction_bias",
+                    lambda: jnp.zeros((n_exp,), jnp.float32))
+                select_by = gates + jax.lax.stop_gradient(bias.value)
+            _, index = jax.lax.top_k(select_by, k)
+            chosen, counts = _chosen_and_counts(
+                gates, index, x.shape[0] if self.seq_aux else None)
+            if self.scoring == "softmax":
+                chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+            else:
+                chosen = chosen / (
+                    jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+            if self.scaling_factor != 1.0:
+                chosen = chosen * self.scaling_factor
+            if self.seq_aux:
+                # a sequence's own counts and its own mean of the
+                # normalised scores; the layer's counts are their sum
+                share = jax.lax.stop_gradient(
+                    counts * (n_exp / (slots // x.shape[0])))
+                mean_gate = jnp.mean(
+                    (gates / jnp.sum(gates, axis=-1, keepdims=True)).reshape(
+                        x.shape[0], -1, n_exp), axis=1)
+                aux_loss = jnp.mean(jnp.sum(share * mean_gate, axis=-1))
+                counts = jnp.sum(counts, axis=0)
+            else:
+                share = jax.lax.stop_gradient(counts / slots)
+                aux_loss = n_exp * jnp.sum(share * jnp.mean(gates, axis=0))
             # sort the assignments by held expert; the others go last
             local = index.reshape(-1) - first
             key = jnp.where((local >= 0) & (local < n_held), local, n_held)
@@ -471,7 +525,7 @@ class RoutedExperts(nn.Module):
             _trace.event(
                 "moe.rows", rows=rows, slots=slots, chunk=chunk,
                 expected=slots * n_held / n_exp, dtype=jnp.dtype(self.dtype).name,
-                gathered=2 * chunk + 2 * slots)
+                gathered=2 * chunk + 2 * slots, scoring=self.scoring)
         order = jnp.pad(order, (0, n_chunks * chunk - slots))
         weight = jnp.pad(weight, (0, n_chunks * chunk - slots))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,

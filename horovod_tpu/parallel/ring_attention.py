@@ -154,6 +154,10 @@ def ring_attention(
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
+    if k.shape != v.shape:
+        raise ValueError(
+            f"k/v shapes differ: {k.shape} vs {v.shape} (ring attention "
+            "rotates keys and values of one width: no latent attention)")
     if impl == "flash":
         return ring_flash_attention(q, k, v, axis_name, causal=causal,
                                     window=window)

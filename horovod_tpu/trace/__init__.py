@@ -90,8 +90,8 @@ SITES = (
     "guard.exchange",      # cross-rank digest/vote exchange (cadence)
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
-    "flash.tiles",         # a flash kernel traced: tile visits, iterations
-    "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers
+    "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths
+    "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -110,13 +110,20 @@ DEVICE_SCOPES = (
 #: (so also inside its transpose, the backward), held by the same pass and
 #: the same docs table.  They name no phase: ``trace/device.py`` reports
 #: their time beside the phases' (``subscopes``), and the benchmark's
-#: ``router_ms`` / ``expert_ffn_ms`` read them by ``op_name`` pattern.
+#: ``router_ms`` / ``expert_ffn_ms`` / ``mla_proj_ms`` / ``shared_expert_ms``
+#: read them by ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
     "experts",  # RoutedExperts: the rows gathered into expert order,
                 # grouped products, the rows gathered back by rank and
                 # summed with their weights (no scatter since PR 31)
+    "mla",      # models/transformer.py Attention, latent attention: the
+                # stream down to the latent and the rotary key, the latent's
+                # norm, the latent up to keys and values, RoPE on the rotary
+                # parts, q and k assembled for the kernels (not the kernels)
+    "shared_experts",  # models/transformer.py Block: the SwiGLU beside the
+                       # routed sum, whole on every chip
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of

@@ -603,8 +603,10 @@ def format_phases(result: dict) -> str:
                 f"{100 * hidden / flying:5.1f} % of the {flying:.3f} ms "
                 "with a reduction in flight")
     for part, ms in result.get("subscopes", {}).items():
-        rows.append(f"  of which {part:<10}{ms:7.3f} ms  {100 * ms / busy:5.1f} %"
-                    "  (inside forward and backward)")
+        if ms > 0:   # a model without the part (no latent attention) has no row
+            rows.append(
+                f"  of which {part:<15}{ms:7.3f} ms  {100 * ms / busy:5.1f} %"
+                "  (inside forward and backward)")
     rows.append(f"  {'busy':<13}{result['busy_ms']:10.3f} ms  (phases sum "
                 f"{result['sum_ms']:.3f}; recompute, inside backward, "
                 f"{result['recompute_ms']:.3f})")

@@ -54,6 +54,22 @@ def softmax_cross_entropy(logits, labels):
     ).mean()
 
 
+def _mean_written_stats(new_stats, old_stats, mean: Callable):
+    """``mean`` over the leaves of ``batch_stats`` that the forward pass
+    wrote, as one call over all of them: replicas see different batches, so
+    running statistics are averaged (sync-BN semantics; reference:
+    torch/sync_batch_norm.py).  A leaf the forward pass did not write (a
+    router's selection bias: state that no step moves) is the same on every
+    replica and is carried as it is, bit for bit: a sum of equal floats over
+    a number of devices that is no power of two, or summed one by one, rounds."""
+    leaves, treedef = jax.tree_util.tree_flatten(new_stats)
+    written = [i for i, (new, old) in enumerate(
+        zip(leaves, jax.tree_util.tree_leaves(old_stats))) if new is not old]
+    for i, averaged in zip(written, mean([leaves[i] for i in written])):
+        leaves[i] = averaged
+    return treedef.unflatten(leaves)
+
+
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # backend compiles of this process, [count, seconds]: fed by ONE
 # jax.monitoring listener, registered the first time a traced
@@ -177,10 +193,9 @@ def data_parallel_train_step(
             grads = spmd_ops.allreduce(grads, op=op, axis=axis)
             loss = spmd_ops.allreduce(loss, axis=axis)
             if new_stats is not None:
-                # replicas see different batches -> average the running
-                # stats (sync-BN semantics; reference:
-                # torch/sync_batch_norm.py)
-                new_stats = spmd_ops.allreduce(new_stats, axis=axis)
+                new_stats = _mean_written_stats(
+                    new_stats, state.batch_stats,
+                    lambda stats: spmd_ops.allreduce(stats, axis=axis))
         with jax.named_scope("optimizer"):
             updates, new_opt_state = optimizer.update(
                 grads, state.opt_state, state.params
@@ -356,7 +371,8 @@ def zero_train_setup(
         with jax.named_scope("exchange"):
             loss = _mean(loss)
             if new_stats is not None:
-                new_stats = _mean(new_stats)
+                new_stats = _mean_written_stats(
+                    new_stats, state.batch_stats, _mean)
         with jax.named_scope("optimizer"):
             updates, new_opt_state = zopt.update(
                 grads, state.opt_state, state.params

@@ -108,6 +108,30 @@ def test_flash_attention_compiles(one_chip, case, backward):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_latent_attention_compiles_at_the_cell_s_shapes(one_chip, backward):
+    """kimi-vl-a3b-s8192-1chip: one sequence of 8,192 positions, 16 heads,
+    queries and keys 192 wide (128 + 64 rotary), values 128: the three
+    kernels hold a head's whole keys, values and (dK/dV) queries in VMEM at
+    these widths, and give dq, dk 192 wide and dv 128."""
+    q = _sds((1, 8192, 16, 192), jnp.bfloat16, one_chip)
+    v = _sds((1, 8192, 16, 128), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    compiled = _compile(fn, q, q, v)
+    assert _has_kernel(compiled)
+    if backward:
+        shapes = [tuple(o.shape) for o in jax.tree_util.tree_leaves(
+            jax.eval_shape(fn, q, q, v))]
+        assert shapes == [(1, 8192, 16, 192), (1, 8192, 16, 192), (1, 8192, 16, 128)]
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward):
     """sdar-30b-a3b-bd4-s4096-1chip: one row of [noisy || clean] = 8,192
     positions, 32 query heads over 4 key/value heads of 128, blocks of 4.

@@ -236,7 +236,7 @@ def test_maybe_training_autoscaler_from_env(monkeypatch):
                                      max_size=4) is None
 
 
-def test_endpoint_signal_source_and_http_targets():
+def test_endpoint_signal_source_and_http_targets(monkeypatch):
     """The scrape loop + HTTP-settable targets, against a REAL PR-1
     exposition server: gauges/histograms in, policy signals out, and a
     GET /control/fleet/targets?set=... retunes the live policy."""
@@ -246,6 +246,13 @@ def test_endpoint_signal_source_and_http_targets():
     from horovod_tpu.metrics import exposition as expo
     from horovod_tpu.metrics import instruments as instr
 
+    # the histogram is the process's: every engine an earlier test of this
+    # worker drove observed its first-token latencies into it, and a loaded
+    # box puts enough of them over 0.5 s to move the first scrape's p99
+    first = instr.SERVE_TOKEN_LATENCY.labels("first")
+    monkeypatch.setattr(first, "_counts", [0] * len(first._counts))
+    monkeypatch.setattr(first, "_sum", 0.0)
+    monkeypatch.setattr(first, "_count", 0)
     instr.SERVE_QUEUE_DEPTH.set(7.0)
     instr.SERVE_TOKEN_LATENCY.labels("first").observe(0.3)
     instr.SERVE_STEPS.labels("decode").inc(5)

@@ -473,7 +473,8 @@ def test_moe_rows_event_says_what_a_chunk_moves():
     jax.make_jaxpr(lambda p: layer.apply({"params": p}, x)[0])(params)
     events = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"]
     assert events == [{"rows": 24, "slots": 96, "chunk": 48, "expected": 24.0,
-                       "dtype": "bfloat16", "gathered": 2 * 48 + 2 * 96}]
+                       "dtype": "bfloat16", "gathered": 2 * 48 + 2 * 96,
+                       "scoring": "softmax"}]
 
 
 # -- the model's keys -----------------------------------------------------------

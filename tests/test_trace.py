@@ -927,7 +927,7 @@ def test_subscope_catalogue_matches_the_code_and_names_no_phase():
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert tuple(trace_sites.catalogue(repo, "DEVICE_SUBSCOPES")) == \
-        trace.DEVICE_SUBSCOPES == ("router", "experts")
+        trace.DEVICE_SUBSCOPES == ("router", "experts", "mla", "shared_experts")
     assert "flash_attention_bwd_dkv_bd" in trace.DEVICE_KERNELS
     assert not set(trace.DEVICE_SUBSCOPES) & set(trace.DEVICE_SCOPES)
     path = "jit(_step)/shard_map/transpose(jvp(forward))/T/layer_0/moe/experts/x"
@@ -955,7 +955,7 @@ def test_trace_pass_holds_subscopes_like_scopes(tmp_path, rows, missing):
     assert keys == (set() if missing is None else {missing})
 
 
-def _routed_step_text():
+def _routed_step_text(latent=False):
     import jax
     import jax.numpy as jnp
     import optax
@@ -963,28 +963,38 @@ def _routed_step_text():
     import horovod_tpu as hvd
     from horovod_tpu import training
     from horovod_tpu.models.transformer import (
-        Transformer, TransformerConfig, block_diffusion_loss,
+        Transformer, TransformerConfig, block_diffusion_loss, next_token_loss,
     )
 
     hvd.init()
-    cfg = TransformerConfig(
-        vocab_size=32, num_layers=1, num_heads=2, head_dim=8, max_seq_len=32,
-        dtype=jnp.float32, num_experts=4, num_experts_per_tok=2,
-        moe_intermediate_size=8, held_experts=(0, 2), block_diffusion=2)
-    model, optimizer = Transformer(cfg), optax.adamw(1e-3)
+    routed = dict(vocab_size=32, num_layers=1, num_heads=2, max_seq_len=32,
+                  dtype=jnp.float32, num_experts=4, num_experts_per_tok=2,
+                  moe_intermediate_size=8, held_experts=(0, 2))
     tokens = jnp.zeros((hvd.size(), 16), jnp.int32)
-    labels = (tokens[:, :8], jnp.ones((hvd.size(), 8), jnp.float32))
+    if latent:   # latent attention and shared experts beside the routed sum
+        cfg = TransformerConfig(
+            hidden_size=16, kv_lora_rank=8, qk_nope_head_dim=8,
+            qk_rope_head_dim=4, v_head_dim=8, num_shared_experts=1,
+            router_scoring="sigmoid", router_selection_bias=True, **routed)
+        labels, loss_fn = tokens, next_token_loss
+    else:
+        cfg = TransformerConfig(head_dim=8, block_diffusion=2, **routed)
+        labels = (tokens[:, :8], jnp.ones((hvd.size(), 8), jnp.float32))
+        loss_fn = block_diffusion_loss
+    model, optimizer = Transformer(cfg), optax.adamw(1e-3)
     state = training.replicate_state(training.create_train_state(
         model, optimizer, jax.random.PRNGKey(0), tokens[:1]))
     step = training.data_parallel_train_step(
-        model, optimizer, loss_fn=block_diffusion_loss)
+        model, optimizer, loss_fn=loss_fn)
     return step.lower(state, tokens, labels).compile().as_text()
 
 
-def test_subscope_table_finds_the_routed_layer_in_a_compiled_step():
-    text = _routed_step_text()
+@pytest.mark.parametrize("latent", [False, True], ids=["routed", "latent"])
+def test_subscope_table_finds_the_routed_layer_in_a_compiled_step(latent):
+    text = _routed_step_text(latent)
     parts = trace_device.subscope_table(text)
-    assert set(parts.values()) == set(trace.DEVICE_SUBSCOPES)
+    assert set(parts.values()) == set(
+        trace.DEVICE_SUBSCOPES if latent else trace.DEVICE_SUBSCOPES[:2])
     phases = trace_device.phase_table(text)
     # a part lies inside the forward scope or its transpose (or is an
     # operation the compiler left unnamed, which takes its operand's part
@@ -996,7 +1006,7 @@ def test_subscope_table_finds_the_routed_layer_in_a_compiled_step():
         (name, 10.0 * i, 5.0) for i, name in enumerate(sorted(parts))]}}
     result = trace_device.reduce_phases(events, phases, parts)
     assert abs(sum(result["subscopes"].values()) - result["busy_ms"]) < 1e-12
-    assert all(v > 0 for v in result["subscopes"].values())
+    assert all(result["subscopes"][part] > 0 for part in set(parts.values()))
     assert "of which router" in trace_device.format_phases(result)
     assert "subscopes" not in trace_device.reduce_phases(events, phases)
 

@@ -129,10 +129,12 @@ def bench(fn, q, k, v, iters, warmup):
 
 
 def _qkv(b, s, h, h_kv, d, dtype=jnp.bfloat16, seed=0):
+    """``d``: one width, or ``(d_qk, d_v)`` (latent attention's 192 / 128)."""
+    d_qk, d_v = d if isinstance(d, tuple) else (d, d)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    mk = lambda kk, heads: jax.random.normal(
-        kk, (b, s, heads, d), jnp.float32).astype(dtype)
-    return mk(ks[0], h), mk(ks[1], h_kv), mk(ks[2], h_kv)
+    mk = lambda kk, heads, width: jax.random.normal(
+        kk, (b, s, heads, width), jnp.float32).astype(dtype)
+    return mk(ks[0], h, d_qk), mk(ks[1], h_kv, d_qk), mk(ks[2], h_kv, d_v)
 
 
 def _emit(rec, human):
@@ -231,11 +233,14 @@ def leg_window(b, s, h, d, windows, iters, warmup, interpret,
         )
 
 
-# (b, s, h, h_kv, d, causal, block_diffusion) of the benchmark's two
-# transformer cells (benchmark/configs/): what a layer's attention sees
+# (b, s, h, h_kv, d, causal, block_diffusion) of the benchmark's three
+# transformer cells (benchmark/configs/): what a layer's attention sees.
+# ``d`` = (d_qk, d_v) where keys and values differ in width (latent
+# attention: 128 + 64 rotary against 128)
 CELL_SHAPES = {
     "internlm2-1.8b-s4096-1chip": (1, 4096, 16, 8, 128, True, None),
     "sdar-30b-a3b-bd4-s4096-1chip": (1, 8192, 32, 4, 128, False, (4096, 4)),
+    "kimi-vl-a3b-s8192-1chip": (1, 8192, 16, 16, (192, 128), True, None),
 }
 
 
@@ -248,7 +253,7 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
     a 256 x 256 x 128 product needs on the v5e's MXU."""
     for cell, (b, s, h, h_kv, d, causal, bd) in shapes.items():
         q, k, v = _qkv(b, s, h, h_kv, d)
-        g = _qkv(b, s, h, h_kv, d, seed=1)[0]
+        g = _qkv(b, s, h, h, d, seed=1)[2]  # dO: every query head, as wide as v
         def fwd(q, k, v):
             return _forward_impl(q, k, v, causal, block, block, interpret,
                                  with_lse=True, bd=bd)
@@ -312,7 +317,8 @@ def main(argv=None):
         leg_window(1, 384, 2, 64, (None, 128), 2, 1, True,
                    block_q=128, block_k=128)
         leg_cells({"causal-gqa": (1, 512, 4, 2, 32, True, None),
-                   "block-diffusion": (1, 512, 4, 1, 32, False, (256, 4))},
+                   "block-diffusion": (1, 512, 4, 1, 32, False, (256, 4)),
+                   "latent": (1, 512, 4, 4, (48, 32), True, None)},
                   2, 1, True, block=128)
         return 0
 
