@@ -28,9 +28,10 @@ Two layers live here, and they are different mechanisms:
     chosen weights renormalised, SwiGLU experts, of which this chip holds
     a share (a range of expert ids) and computes only that share's part
     of the sum; DROPLESS (assignments sorted by expert, grouped matrix
-    products through ``jax.lax.ragged_dot``, no capacity), the router in
-    float32.  This is the feed-forward ``models/transformer.py`` builds
-    when ``TransformerConfig.num_experts`` is set.  It has no exchange
+    products by the layer's own Mosaic kernels, ``ops/grouped_matmul.py``,
+    no capacity), the router in float32.  This is the feed-forward
+    ``models/transformer.py`` builds when
+    ``TransformerConfig.num_experts`` is set.  It has no exchange
     yet: on one chip it runs on the tokens it is given and what the
     absent experts would add is left out; the ``all_to_all`` that sends
     every chip's assignments to their owners is a later change.
@@ -48,6 +49,15 @@ gathers of four times the rows take 0.9.  Single numbers move by sorts
 slot's rank is the sort of the order) or not at all (the chosen gates and
 the counts are one dense comparison): a gather or a scatter of 65,536
 scalars takes 0.57 ms, a sort of them 0.05.
+
+The grouped products (PR 33).  A chunk's rows times their experts'
+matrices, three products forward and six backward, are
+``ops.grouped_matmul.grouped_matmul``: Mosaic kernels whose grid walks only
+the row tiles the groups cover, so the half of a chunk that holds no
+assignment costs nothing, and a tile that two experts share is computed
+once an expert under a row mask.  XLA's own kernels for the ragged product,
+which the layer called before, ran at a quarter of the MXU on groups of
+512-768 rows (PERF.md §6).
 """
 
 from __future__ import annotations
@@ -62,6 +72,7 @@ import jax.numpy as jnp
 
 
 from .. import trace as _trace
+from ..ops.grouped_matmul import grouped_matmul, tiles as _tiles, visit_counts
 from ._mesh_utils import axis_size_or_1 as _axis_size
 
 
@@ -308,10 +319,10 @@ def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route):
     float32."""
     keep = valid[:, None]
     x = jnp.where(keep, _dispatch(z, route), 0)
-    gate = jax.lax.ragged_dot(x, w_gate, sizes)
-    up = jax.lax.ragged_dot(x, w_up, sizes)
+    gate = grouped_matmul(x, w_gate, sizes)
+    up = grouped_matmul(x, w_up, sizes)
     h = jnp.where(keep, nn.silu(gate) * up, 0)
-    y = jnp.where(keep, jax.lax.ragged_dot(h, w_down, sizes), 0)
+    y = jnp.where(keep, grouped_matmul(h, w_down, sizes), 0)
     return _combine(y, weight, route)
 
 
@@ -412,11 +423,12 @@ class RoutedExperts(nn.Module):
     mean of ``g_e / sum g``; mean over the sequences.
 
     Dropless: the (token, expert) assignments are sorted by held expert
-    and go through ``jax.lax.ragged_dot`` in chunks of ``chunk_rows``
-    sorted rows.  A chunk's rows come by one gather of the tokens' rows and
-    go back by ``top_k`` gathers of the chunk's, one a slot, found by the
-    slot's rank in the sort (no scatter, forward or backward: the module's
-    text).  The first chunk always runs; a later one runs only if
+    and go through the grouped products' kernels
+    (``ops.grouped_matmul``) in chunks of ``chunk_rows`` sorted rows.  A
+    chunk's rows come by one gather of the tokens' rows and go back by
+    ``top_k`` gathers of the chunk's, one a slot, found by the slot's rank
+    in the sort (no scatter, forward or backward: the module's text).  The
+    first chunk always runs; a later one runs only if
     the sort reached it (``_routed_sum``: a loop over the chunks in use,
     a later chunk recomputed in the backward pass, so an idle one costs
     neither time nor memory).  The default chunk is twice the expected
@@ -433,7 +445,9 @@ class RoutedExperts(nn.Module):
 
     Traced into a program (never in a step) it leaves one ``moe.rows``
     event: the rows, slots and chunk rows, the held assignments balanced
-    routing gives, and the row gathers a chunk makes, forward and backward.
+    routing gives, the row gathers a chunk makes, forward and backward, and
+    the grouped products' tile sizes with the row-tile visits one product
+    makes at balanced sizes against the row tiles of a whole chunk.
     """
 
     num_experts: int
@@ -521,11 +535,18 @@ class RoutedExperts(nn.Module):
         n_chunks = -(-slots // chunk)
         if _trace.enabled():
             # shape arithmetic: a chunk gathers its own rows twice (x; g in
-            # the backward) and every token's slots twice (y; dx)
+            # the backward) and every token's slots twice (y; dx); a grouped
+            # product visits the row tiles that balanced groups cover, not
+            # the chunk's
+            expected = slots * n_held / n_exp
+            tile = _tiles(chunk, d, self.d_ff, n_held, self.dtype)
+            visits, chunk_tiles = visit_counts(
+                chunk, n_held, tile.m, int(min(expected, chunk)) // n_held)
             _trace.event(
                 "moe.rows", rows=rows, slots=slots, chunk=chunk,
-                expected=slots * n_held / n_exp, dtype=jnp.dtype(self.dtype).name,
-                gathered=2 * chunk + 2 * slots, scoring=self.scoring)
+                expected=expected, dtype=jnp.dtype(self.dtype).name,
+                gathered=2 * chunk + 2 * slots, scoring=self.scoring,
+                tiles=list(tile), visits=visits, chunk_tiles=chunk_tiles)
         order = jnp.pad(order, (0, n_chunks * chunk - slots))
         weight = jnp.pad(weight, (0, n_chunks * chunk - slots))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,

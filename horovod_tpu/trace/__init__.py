@@ -91,7 +91,8 @@ SITES = (
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
     "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths
-    "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring
+    "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring,
+                           # the grouped products' tiles and row-tile visits
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -115,9 +116,10 @@ DEVICE_SCOPES = (
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
-    "experts",  # RoutedExperts: the rows gathered into expert order,
-                # grouped products, the rows gathered back by rank and
-                # summed with their weights (no scatter since PR 31)
+    "experts",  # RoutedExperts: the rows gathered into expert order, the
+                # grouped products (the grouped_matmul kernels since PR 33),
+                # the rows gathered back by rank and summed with their
+                # weights (no scatter since PR 31)
     "mla",      # models/transformer.py Attention, latent attention: the
                 # stream down to the latent and the rotary key, the latent's
                 # norm, the latent up to keys and values, RoPE on the rotary
@@ -127,9 +129,11 @@ DEVICE_SUBSCOPES = (
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
-#: ops/flash_attention.py, one name a kernel; the HLO instruction (and
-#: the profiler's event) is ``%<name>.<n>``.  All start with
-#: ``flash_attention`` so one pattern still reads them together.
+#: ops/flash_attention.py and ops/grouped_matmul.py, one name a kernel; the
+#: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
+#: attention kernels all start with ``flash_attention`` so one pattern still
+#: reads them together; the routed experts' grouped products do not, and are
+#: read by the ``experts`` scope they run in.
 DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
     "flash_attention_bwd_dq",   # _backward_folded: dQ
@@ -137,6 +141,9 @@ DEVICE_KERNELS = (
     "flash_attention_bwd_dkv_bd",  # _backward_folded: dK/dV under the
                                    # block-diffusion mask, a query head a program
     "flash_attention_chunk",    # flash_chunk_attention (prefill, decode)
+    "grouped_matmul",    # ops/grouped_matmul.py: rows x their group's matrix,
+                         # forward and (the matrix read transposed) dx
+    "grouped_matmul_t",  # the matrices' gradient: x[g]^T @ dy[g] a group
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"

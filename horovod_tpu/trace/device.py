@@ -285,8 +285,10 @@ def subscope_table(compiled) -> Dict[str, str]:
     scope or of its transpose: the routed layer's ``router``, ``experts``),
     for the instructions that have one.  An instruction without an
     ``op_name`` takes that of the root of the computation it calls, else
-    that of its last operand that has one (XLA's own ``ragged-dot`` custom
-    calls carry only their bare name; their data operands carry the scope)."""
+    that of its last operand that has one (a custom call of XLA's own, such
+    as the ragged product the routed layer called before PR 33, carries only
+    its bare name; its data operands carry the scope.  The package's own
+    kernels carry their path themselves)."""
     instrs = list(_from_text(compiled) if isinstance(compiled, str)
                   else _from_proto(compiled))
     roots = {i.computation: i.op_name for i in instrs if i.root}

@@ -13,6 +13,7 @@ described chip cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -156,27 +157,49 @@ def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward)
         assert "flash_attention_bwd_dkv_bd" in text
 
 
-def test_grouped_products_are_a_kernel_on_the_chip(one_chip):
-    """``jax.lax.ragged_dot`` of the routed layer at the cell's chunk (16,384
-    sorted rows, 16 held experts of 2048 x 768): the v5e compiler lowers it to
-    its own ``tpu_custom_call``, forward and both gradients, and counts one
-    expert's product a row, not the stack's (no dense expansion)."""
-    rows, d, f, held = 16384, 2048, 768, 16
-    x = _sds((rows, d), jnp.bfloat16, one_chip)
-    w = _sds((held, d, f), jnp.bfloat16, one_chip)
+# the routed cells' grouped products: (rows of a chunk, k, n, held experts)
+GROUPED_CASES = {
+    "sdar_gate_up": (16384, 2048, 768, 16),
+    "sdar_down": (16384, 768, 2048, 16),
+    "kimi_gate_up": (12288, 2048, 1408, 8),
+    "kimi_down": (12288, 1408, 2048, 8),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_products_are_a_kernel_on_the_chip(one_chip, case, backward):
+    """``ops.grouped_matmul`` at both routed cells' chunks (SDAR: 16,384 sorted
+    rows, 16 held experts of 2,048 x 768 and back; Kimi: 12,288 rows, 8 of
+    2,048 x 1,408 and back) as the v5e compiler takes it: the package's own
+    Mosaic kernels, forward and both gradients, by their names; no
+    ``ragged-dot``; an expert's whole matrix in a tile fits the fast memory
+    the kernels ask for (the compile refuses what does not)."""
+    from horovod_tpu.ops.grouped_matmul import grouped_matmul, tiles
+
+    rows, k, n, held = GROUPED_CASES[case]
+    assert tuple(tiles(rows, k, n, held, jnp.bfloat16)) == (256, k, n)
+    x = _sds((rows, k), jnp.bfloat16, one_chip)
+    w = _sds((held, k, n), jnp.bfloat16, one_chip)
     sizes = _sds((held,), jnp.int32, one_chip)
 
+    def fwd(x, w, sizes):
+        return grouped_matmul(x, w, sizes, interpret=False)
+
     def loss(x, w, sizes):
-        return jnp.sum(jax.lax.ragged_dot(x, w, sizes).astype(jnp.float32))
+        return jnp.sum(fwd(x, w, sizes).astype(jnp.float32) ** 2)
 
-    compiled = _compile(jax.grad(loss, argnums=(0, 1)), x, w, sizes)
-    assert "ragged-dot" in compiled.as_text() and _has_kernel(compiled)
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-    assert cost["flops"] < 3 * 2 * rows * d * f
+    fn = jax.grad(loss, argnums=(0, 1)) if backward else fwd
+    text = _compile(fn, x, w, sizes).as_text()
+    kernels = re.findall(r"%(grouped_matmul\w*?)\.\d+ = [^\n]*tpu_custom_call", text)
+    # forward; the same kernel on the matrices read transposed; the other shape
+    want = ["grouped_matmul", "grouped_matmul", "grouped_matmul_t"] if backward \
+        else ["grouped_matmul"]
+    assert sorted(kernels) == want
+    assert "ragged-dot" not in text
 
 
-def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip):
+def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch):
     """``RoutedExperts`` forward and backward at the SDAR cell's shapes (8,192
     rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) as the
     v5e compiler leaves it: no scatter at all (before PR 31: two scatter-adds
@@ -185,10 +208,11 @@ def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip):
     ``s32[num_experts]`` among them), no float32 gather of rows, and no sort
     but the four the layer asks for (top-k, the assignments by held expert,
     its inverse, the weights' cotangent back)."""
-    import re
 
     from horovod_tpu.parallel.moe import RoutedExperts
 
+    # the layer asks the backend whether its kernels are interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows, d, ff, experts, top_k, held = 8192, 2048, 768, 128, 8, 16
     chunk = 2 * rows * top_k * held // experts
     layer = RoutedExperts(experts, top_k, d, ff, held=(0, held), dtype=jnp.bfloat16)
@@ -214,7 +238,16 @@ def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip):
     sorts = shapes("sort")
     assert not [s for s in sorts if f"[{chunk}]" in s], sorts
     assert len(sorts) == 4 and sum(f"[{rows * top_k}]" in s for s in sorts) == 3
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    # the grouped products: three forward, six backward, and the three the
+    # later chunks' loop holds forward and nine backward (recomputed)
+    assert "ragged-dot" not in text
+    kernels = re.findall(r"%(grouped_matmul\w*?)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert kernels.count("grouped_matmul") == 3 + 3 + 3 + 6
+    assert kernels.count("grouped_matmul_t") == 3 + 3
+    # forward and backward alike run inside the ``experts`` scope
+    lines = [l for l in text.splitlines() if re.search(r"%grouped_matmul\w*\.\d+ = ", l)]
+    assert lines and all("/experts/" in re.search(r'op_name="([^"]*)"', l).group(1)
+                         for l in lines)
 
 
 # -- serving kernels: decode and chunked prefill ------------------------------

@@ -455,7 +455,8 @@ def test_no_scatter_forward_or_backward_and_rows_gathered_in_the_layer_s_dtype(d
     eqns = list(_primitives(jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x).jaxpr))
     names = {e.primitive.name for e in eqns}
     assert not {n for n in names if n.startswith("scatter")}, names
-    assert {"gather", "sort", "ragged_dot_general"} <= names or {"gather", "sort", "ragged_dot"} <= names
+    assert {"gather", "sort", "pallas_call"} <= names   # the grouped products' kernels
+    assert not {n for n in names if n.startswith("ragged_dot")}, names
     wide = [e for e in eqns if e.primitive.name == "gather" and e.invars[0].aval.ndim == 2
             and e.invars[0].aval.shape[1] == 32]
     assert len(wide) >= 2 * (1 + 4)             # x and g; y and dx for each of 4 slots
@@ -472,9 +473,12 @@ def test_moe_rows_event_says_what_a_chunk_moves():
     t0 = trace.now()
     jax.make_jaxpr(lambda p: layer.apply({"params": p}, x)[0])(params)
     events = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"]
+    # the grouped products: one 48-row tile holds the chunk, and each of the
+    # four balanced groups of 6 rows visits it
     assert events == [{"rows": 24, "slots": 96, "chunk": 48, "expected": 24.0,
                        "dtype": "bfloat16", "gathered": 2 * 48 + 2 * 96,
-                       "scoring": "softmax"}]
+                       "scoring": "softmax", "tiles": [48, 32, 24], "visits": 4,
+                       "chunk_tiles": 1}]
 
 
 # -- the model's keys -----------------------------------------------------------

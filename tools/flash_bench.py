@@ -23,6 +23,9 @@ Usage:
   flash_bench.py --window        # window sweep at fixed S
   flash_bench.py --cells         # forward, dQ and dK/dV apart at the
                                  #  benchmark cells' shapes (PERF.md §5)
+  flash_bench.py --grouped       # the routed experts' grouped product alone,
+                                 #  kernel against jax.lax.ragged_dot, DEVICE
+                                 #  time from a capture (PERF.md §6, PR 33)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -40,6 +43,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from horovod_tpu.common.retry import env_int  # noqa: E402
 from horovod_tpu.models.transformer import causal_dot_attention  # noqa: E402
+from horovod_tpu.ops import grouped_matmul as gm  # noqa: E402
 from horovod_tpu.ops.flash_attention import (  # noqa: E402
     _backward_impl, _clamp_blocks, _forward_impl, flash_attention,
     tile_counts,
@@ -292,12 +296,157 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
             + f"  tiles a head {tiles['fwd'][0]} in {tiles['fwd'][1]} iterations")
 
 
+# (rows of a chunk, k, n, groups): the routed cells' products, a chunk twice
+# the expected load (parallel/moe.py), and the same at 8 x the rows a group,
+# which is what an expert of a deployment sees (PERF.md §4)
+GROUPED_SHAPES = {
+    "kimi-vl-a3b gate/up": (12288, 2048, 1408, 8),
+    "kimi-vl-a3b down": (12288, 1408, 2048, 8),
+    "sdar-30b-a3b gate/up": (16384, 2048, 768, 16),
+    "sdar-30b-a3b down": (16384, 768, 2048, 16),
+    "kimi-vl-a3b gate/up x8 rows": (98304, 2048, 1408, 8),
+    "sdar-30b-a3b gate/up x8 rows": (131072, 2048, 768, 16),
+}
+
+
+def routed_sizes(rows, groups, seed=0):
+    """Group sizes as a first step's routing leaves them: half the chunk's
+    rows held, unequal (the largest group about 1.15 x the mean: PERF.md §6,
+    PR 32) and no multiple of anything."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    share = np.maximum(1.0 + 0.1 * rng.standard_normal(groups), 0.5)
+    return rng.multinomial(rows // 2, share / share.sum()).astype(np.int32)
+
+
+def module_ms(capture_dir):
+    """Mean DEVICE time of each program that ran during a capture, by the
+    name of its ``jit`` (the ``XLA Modules`` line of the TPU plane); empty
+    off the chip, where a capture has no such line."""
+    import re
+
+    from jax.profiler import ProfileData
+
+    from horovod_tpu.trace import device as _device
+
+    runs = {}
+    data = ProfileData.from_file(_device.find_xplane(capture_dir))
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Modules":
+                continue
+            for e in line.events:
+                name = re.sub(r"\(\d+\)$", "", e.name)
+                runs.setdefault(name, []).append(e.duration_ns / 1e6)
+    return {name: sum(ms) / len(ms) for name, ms in runs.items()}
+
+
+def grouped_variants(interpret):
+    """``{variant: {product: function of (x, w, dy, sizes)}}``: the three
+    products of one expert matrix (forward, the gradient with respect to the
+    rows, and to the matrices; what a program does not use the compiler
+    drops) as ``jax.lax.ragged_dot`` and autodiff make them, and as the
+    layer's kernels do at the tiles the shapes give."""
+    def three(product):
+        return {
+            "fwd": lambda x, w, dy, sizes: product(x, w, sizes),
+            "dx": lambda x, w, dy, sizes: jax.vjp(
+                lambda x: product(x, w, sizes), x)[1](dy)[0],
+            "dw": lambda x, w, dy, sizes: jax.vjp(
+                lambda w: product(x, w, sizes), w)[1](dy)[0],
+        }
+
+    return {"ragged_dot": three(jax.lax.ragged_dot),
+            "kernel": three(lambda x, w, sizes: gm.grouped_matmul(
+                x, w, sizes, interpret=interpret))}
+
+
+def leg_grouped(shapes, iters, warmup, interpret):
+    """The routed experts' grouped product alone: each of its three products
+    as its own ``jit``, all inside ONE capture, DEVICE time a call read from
+    the capture's ``XLA Modules`` events (the host clock reads 17-22 % over
+    it: PERF.md §6, PR 32).  Beside each time, the share of the bf16 MXU
+    peak the held rows' FLOPs make of it.  Off the chip there is no device
+    time (``null``); the kernels' results are held against ``ragged_dot``'s
+    on the held rows either way."""
+    import shutil
+    import tempfile
+
+    import numpy as np
+
+    capture_dir = tempfile.mkdtemp(prefix="grouped_capture_")
+    programs, records = {}, []
+    for shape_name, (rows, k, n, groups) in shapes.items():
+        sizes = routed_sizes(rows, groups)
+        held = int(sizes.sum())
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        x = jax.random.normal(keys[0], (rows, k), jnp.float32).astype(jnp.bfloat16)
+        w = (jax.random.normal(keys[1], (groups, k, n), jnp.float32)
+             / k ** 0.5).astype(jnp.bfloat16)
+        dy = jax.random.normal(keys[2], (rows, n), jnp.float32).astype(jnp.bfloat16)
+        args = (x, w, dy, jnp.asarray(sizes))
+        rec = {"bench": "grouped_matmul", "shape": shape_name, "rows": rows,
+               "k": k, "n": n, "groups": groups, "held_rows": held,
+               "sizes_min_max": [int(sizes.min()), int(sizes.max())],
+               "tiles": list(gm.tiles(rows, k, n, groups, x.dtype)),
+               "flops": 2 * held * k * n, "variants": {}}
+        expected = {}
+        for variant, products in grouped_variants(interpret).items():
+            for product, fn in products.items():
+                fn.__name__ = "g%d_%s_%s" % (len(records), variant, product)
+                fn = jax.jit(fn)
+                out = fn(*args)
+                # the rows beyond the groups are undefined: compare the held
+                valid = out if product == "dw" else out[:held]
+                valid = np.asarray(valid.astype(jnp.float32))
+                if variant == "ragged_dot":
+                    expected[product] = valid
+                else:
+                    scale = np.abs(expected[product]).max()
+                    gap = float(np.abs(valid - expected[product]).max() / scale)
+                    rec["variants"].setdefault(variant, {})[product + "_gap"] = gap
+                programs[(len(records), variant, product)] = (fn, args)
+        records.append(rec)
+    for fn, args in programs.values():
+        for _ in range(warmup):
+            jax.block_until_ready(fn(*args))
+    jax.profiler.start_trace(capture_dir)
+    try:
+        for fn, args in programs.values():
+            for _ in range(iters):
+                out = fn(*args)
+            jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    ms = module_ms(capture_dir)
+    shutil.rmtree(capture_dir, ignore_errors=True)
+    peak = 197e12  # one v5e chip, bf16 (benchmark/peaks.json)
+    for i, rec in enumerate(records):
+        for (j, variant, product), (fn, _) in programs.items():
+            if j != i:
+                continue
+            t = ms.get("jit_" + fn.__name__)
+            out = rec["variants"].setdefault(variant, {})
+            out[product + "_device_ms"] = round(t, 4) if t else None
+            out[product + "_mxu_share"] = (
+                round(rec["flops"] / (t * 1e-3) / peak, 4) if t else None)
+        times = {v: sum(p.get(q + "_device_ms") or 0 for q in ("fwd", "dx", "dw"))
+                 for v, p in rec["variants"].items()}
+        _emit(rec, f"{rec['shape']}: " + "  ".join(
+            f"{v} {t:6.3f} ms" for v, t in times.items())
+            + "  (fwd + dx + dw, device time)")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--gqa", action="store_true")
     ap.add_argument("--window", action="store_true")
     ap.add_argument("--kernel", action="store_true")
     ap.add_argument("--cells", action="store_true")
+    ap.add_argument("--grouped", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny interpret-mode pass of every leg (CI)")
     args = ap.parse_args(argv)
@@ -320,9 +469,11 @@ def main(argv=None):
                    "block-diffusion": (1, 512, 4, 1, 32, False, (256, 4)),
                    "latent": (1, 512, 4, 4, (48, 32), True, None)},
                   2, 1, True, block=128)
+        leg_grouped({"skewed": (192, 256, 384, 4)}, 1, 1, True)
         return 0
 
-    run_all = not (args.gqa or args.window or args.kernel or args.cells)
+    run_all = not (args.gqa or args.window or args.kernel or args.cells
+                   or args.grouped)
     if args.kernel or run_all:
         leg_kernel([(4, 1024, 8, 128), (4, 2048, 8, 128),
                     (2, 4096, 8, 128)], iters, warmup, None)
@@ -333,6 +484,8 @@ def main(argv=None):
                    (None, 2048, 1024, 512, 256), iters, warmup, None)
     if args.cells or run_all:
         leg_cells(CELL_SHAPES, iters, warmup, None)
+    if args.grouped:
+        leg_grouped(GROUPED_SHAPES, iters, warmup, None)
     return 0
 
 
