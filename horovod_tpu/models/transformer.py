@@ -220,6 +220,33 @@ class TransformerConfig:
     routed_scaling_factor: float = 1.0
     router_selection_bias: bool = False
     router_seq_aux: bool = False
+    # Layers of two mixers in one model (the ``qwen3_next`` configs' keys, each
+    # under its own name).  ``layer_types``: a tuple a layer of
+    # ``"full_attention"`` (``Attention``) or ``"linear_attention"``
+    # (``GatedDeltaNet``); None = all full.  The five ``linear_*`` sizes are
+    # Gated DeltaNet's: key heads of ``linear_key_head_dim``, value heads (a
+    # multiple of the key heads) of ``linear_value_head_dim``, and the taps of
+    # its causal depthwise convolution.  A linear layer trains on 'dot' and
+    # 'flash' models only: no paged serving (no recurrent-state cache yet), no
+    # bound ``shard_axis``, no ring, no block diffusion.
+    layer_types: Optional[Any] = None
+    linear_num_key_heads: Optional[int] = None
+    linear_key_head_dim: Optional[int] = None
+    linear_num_value_heads: Optional[int] = None
+    linear_value_head_dim: Optional[int] = None
+    linear_conv_kernel_dim: int = 4
+    # ``Attention``: RoPE on the first ``partial_rotary_factor`` of each head
+    # (the rest passes); ``attn_output_gate``: ``q`` projects to a query and a
+    # gate a head, and the attention's output is multiplied by the gate's
+    # sigmoid before ``o``.
+    partial_rotary_factor: float = 1.0
+    attn_output_gate: bool = False
+    # Every RMSNorm of the residual stream and the q / k norms in the
+    # zero-centred form ``x / rms(x) * (1 + w)``, ``w`` initialised 0.
+    norm_zero_centered: bool = False
+    # ``shared_expert_gate``: the shared experts' output times
+    # ``sigmoid(x @ w)``, ``w`` (d_model, 1).
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         kv = self.num_kv_heads
@@ -271,6 +298,10 @@ class TransformerConfig:
                 raise ValueError(
                     "latent attention takes no window, no block_diffusion, "
                     "no qk_norm and no grouped key/value heads")
+            if self.attn_output_gate or self.partial_rotary_factor != 1.0:
+                raise ValueError(
+                    "latent attention takes no attn_output_gate and no "
+                    "partial_rotary_factor (its rotary part is its own key)")
         if self.num_experts is None and (
                 self.first_dense_layers or self.num_shared_experts):
             raise ValueError(
@@ -279,6 +310,50 @@ class TransformerConfig:
             raise ValueError(
                 f"router_scoring is 'softmax' or 'sigmoid', got "
                 f"{self.router_scoring!r}")
+        if self.shared_expert_gate and not self.num_shared_experts:
+            raise ValueError("shared_expert_gate needs num_shared_experts")
+        if self.partial_rotary_factor != 1.0 and (
+                not 0.0 < self.partial_rotary_factor < 1.0
+                or int(self.head_dim * self.partial_rotary_factor) % 2):
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of "
+                f"head_dim {self.head_dim} is no even number of columns")
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            kinds = ("full_attention", "linear_attention")
+            if (len(self.layer_types) != self.num_layers
+                    or any(t not in kinds for t in self.layer_types)):
+                raise ValueError(
+                    f"layer_types names one of {kinds} for each of the "
+                    f"{self.num_layers} layers, got {self.layer_types}")
+        if self.has_linear_attention:
+            sizes = (self.linear_num_key_heads, self.linear_key_head_dim,
+                     self.linear_num_value_heads, self.linear_value_head_dim)
+            if not all(sizes) or sizes[2] % sizes[0] or (
+                    self.linear_conv_kernel_dim < 1):
+                raise ValueError(
+                    "layer_types with a 'linear_attention' layer needs "
+                    "linear_num_key_heads, linear_key_head_dim, "
+                    "linear_num_value_heads (a multiple of the key heads), "
+                    f"linear_value_head_dim (got {sizes}) and "
+                    "linear_conv_kernel_dim >= 1")
+            if self.attention_impl not in ("dot", "flash"):
+                raise ValueError(
+                    "layer_types with a 'linear_attention' layer supports "
+                    "attention_impl 'dot'/'flash', not "
+                    f"{self.attention_impl!r} (the ring shards the sequence "
+                    "and the recurrent state is not handed from shard to "
+                    "shard yet)")
+            if self.block_diffusion is not None:
+                raise ValueError(
+                    "layer_types with a 'linear_attention' layer takes no "
+                    "block_diffusion (a recurrence has no block-diffusion "
+                    "mask)")
+
+    @property
+    def has_linear_attention(self) -> bool:
+        return (self.layer_types is not None
+                and "linear_attention" in self.layer_types)
 
     @property
     def mlp_hidden(self) -> int:
@@ -398,6 +473,48 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+class ZeroCenteredRMSNorm(nn.Module):
+    """``x / rms(x) * (1 + scale)``, ``scale`` initialised 0, statistics in
+    float32 (the ``qwen3_next`` norm of the residual stream and of q / k)."""
+
+    epsilon: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (y * (1.0 + scale)).astype(self.dtype)
+
+
+def _rms_norm(cfg: TransformerConfig):
+    """The model's RMSNorm, in the form ``cfg.norm_zero_centered`` names."""
+    return functools.partial(
+        ZeroCenteredRMSNorm if cfg.norm_zero_centered else nn.RMSNorm,
+        dtype=cfg.dtype, epsilon=cfg.rms_norm_eps)
+
+
+def _rotary(cfg: TransformerConfig, x, positions):
+    """RoPE on the first ``partial_rotary_factor`` of each head's columns."""
+    rot = int(cfg.head_dim * cfg.partial_rotary_factor)
+    if rot == cfg.head_dim:
+        return rope(x, positions, cfg.rope_theta)
+    return jnp.concatenate(
+        [rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]], axis=-1)
+
+
+def causal_depthwise_conv(u, w):
+    """``silu(sum_j w[j] * u[t - (K - 1) + j])`` a channel, zeros before the
+    sequence: ``u`` (B, T, C), ``w`` (K, C); the sum in float32."""
+    taps, t = w.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    acc = sum(padded[:, j:j + t].astype(jnp.float32) * w[j] for j in range(taps))
+    return nn.silu(acc).astype(u.dtype)
+
+
 def _shard_size(cfg: TransformerConfig) -> int:
     """Bound size of ``cfg.shard_axis`` (1 when unset/unbound), with the
     divisibility contract checked at trace: every per-chip slice —
@@ -468,6 +585,7 @@ class Attention(nn.Module):
         tp = _shard_size(cfg)
         heads = cfg.num_heads // tp
         kv_heads = kv_heads // tp
+        gate = None
         if cfg.kv_lora_rank is not None:
             if paged is not None:
                 raise ValueError(
@@ -475,16 +593,19 @@ class Attention(nn.Module):
                     "holds keys and values of one width: no latent cache yet)")
             q, k, v = self._latent_qkv(x, positions, dense, heads)
         else:
-            q = dense(features=(heads, cfg.head_dim), name="q")(x)
+            if cfg.attn_output_gate:
+                # a head's columns are [query | gate]
+                q = dense(features=(heads, 2 * cfg.head_dim), name="q")(x)
+                q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
+            else:
+                q = dense(features=(heads, cfg.head_dim), name="q")(x)
             k = dense(features=(kv_heads, cfg.head_dim), name="k")(x)
             v = dense(features=(kv_heads, cfg.head_dim), name="v")(x)
             if cfg.qk_norm:
-                q = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                               name="q_norm")(q)
-                k = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                               name="k_norm")(k)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+                q = _rms_norm(cfg)(name="q_norm")(q)
+                k = _rms_norm(cfg)(name="k_norm")(k)
+            q = _rotary(cfg, q, positions)
+            k = _rotary(cfg, k, positions)
         if paged is not None and cfg.block_diffusion is not None:
             raise ValueError("paged serving takes no block_diffusion model")
         if paged is not None:
@@ -559,6 +680,9 @@ class Attention(nn.Module):
         else:
             out = causal_dot_attention(q, k, v, causal=cfg.causal,
                                        window=cfg.window)
+        if gate is not None:
+            out = (out.astype(jnp.float32)
+                   * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
             use_bias=False, name="o",
@@ -570,6 +694,111 @@ class Attention(nn.Module):
             # the first of Megatron's two collectives per block
             out = spmd_ops.allreduce(out, op=Sum, axis=cfg.shard_axis)
         return out
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log(A)``, ``A`` uniform over (0, 16): the published initialisation."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
+def _conv_init(key, shape, dtype=jnp.float32):
+    """Uniform within ``1 / sqrt(taps)``: a depthwise convolution's fan in is
+    its taps."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class GatedDeltaNet(nn.Module):
+    """The linear-attention mixer of the ``qwen3_next`` family (Gated DeltaNet,
+    arXiv:2412.06464): ``x`` (B, T, d_model) ->
+
+      1. ``in_proj_qkvz``: q, k (``linear_num_key_heads`` of
+         ``linear_key_head_dim``), v, z (``linear_num_value_heads`` of
+         ``linear_value_head_dim``), in that column order (the published
+         checkpoint interleaves them a key head: a loader's business);
+         ``in_proj_ba``: b, a, one number a value head, float32;
+      2. ``[q, k, v]`` through a causal depthwise convolution of
+         ``linear_conv_kernel_dim`` taps (``conv_kernel`` (taps, channels), no
+         bias), then SiLU;
+      3. q, k L2-normalised a head (eps 1e-6), q scaled by ``key_head_dim **
+         -0.5``, both repeated to the value heads;
+      4. ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``;
+      5. the gated delta rule (``ops/gated_delta.py``), a state of ``key_head_dim
+         x value_head_dim`` a value head, in chunks of 64 with the carry a
+         Mosaic kernel ('flash' models) or a scan ('dot' models);
+      6. ``norm(o) * silu(z)`` (RMSNorm over a head in the plain form, one
+         weight vector for all heads), then ``out_proj``.
+
+    Steps 1-4 and 6 trace under ``jax.named_scope("gdn")``, step 5 under its
+    sibling ``"gated_delta"``.  Training only: no recurrent-state cache, no
+    bound ``shard_axis`` (``TransformerConfig.layer_types``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.gated_delta import gated_delta_rule
+        from ..parallel._mesh_utils import axis_size_or_1
+
+        cfg = self.cfg
+        if axis_size_or_1(cfg.shard_axis) > 1:
+            raise ValueError(
+                f"shard_axis {cfg.shard_axis!r} takes no 'linear_attention' "
+                "layer (layer_types): the gated delta rule's heads are not "
+                "sharded yet")
+        hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+        hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+        key_dim, value_dim = hk * dk, hv * dv
+        b, t, _ = x.shape
+        f32 = jnp.float32
+        with jax.named_scope("gdn"):
+            qkvz = nn.Dense(2 * key_dim + 2 * value_dim, use_bias=False,
+                            dtype=cfg.dtype, name="in_proj_qkvz")(x)
+            ba = nn.Dense(
+                2 * hv, use_bias=False, dtype=cfg.dtype, name="in_proj_ba",
+                dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=f32))(x)
+            conv_w = self.param("conv_kernel", _conv_init,
+                                (cfg.linear_conv_kernel_dim,
+                                 2 * key_dim + value_dim), f32)
+            a_log = self.param("A_log", _a_log_init, (hv,), f32)
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
+            z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, hv, dv)
+
+        def unit(y, scale=1.0):
+            y32 = y.astype(f32)
+            inv = jax.lax.rsqrt(
+                jnp.sum(jnp.square(y32), axis=-1, keepdims=True) + 1e-6)
+            return jnp.repeat((y32 * (inv * scale)).astype(cfg.dtype),
+                              hv // hk, axis=2)
+
+        # steps 2-5 keep their inputs alone for the backward, which makes
+        # the convolution's, the norms' and the rule's own tensors again: at
+        # 8,192 tokens the benchmark's step is 13.6 GB so, 15.4 with the
+        # rule alone made again and 16.0 with nothing (PERF.md, PR 35)
+        @jax.checkpoint
+        def mix(qkv, ba, conv_w, a_log, dt_bias):
+            with jax.named_scope("gdn"):
+                mixed = causal_depthwise_conv(qkv, conv_w)
+                q = mixed[..., :key_dim].reshape(b, t, hk, dk)
+                k = mixed[..., key_dim:2 * key_dim].reshape(b, t, hk, dk)
+                v = mixed[..., 2 * key_dim:].reshape(b, t, hv, dv)
+                q, k = unit(q, dk ** -0.5), unit(k)
+                beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+                g = -jnp.exp(a_log) * jax.nn.softplus(
+                    ba[..., hv:].astype(f32) + dt_bias)
+            with jax.named_scope("gated_delta"):
+                return gated_delta_rule(
+                    q, k, v, g, beta,
+                    impl="kernel" if cfg.attention_impl == "flash" else "jnp")
+
+        o = mix(qkvz[..., :2 * key_dim + value_dim], ba, conv_w, a_log, dt_bias)
+        with jax.named_scope("gdn"):
+            o = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                           name="norm")(o)
+            o = (o.astype(f32) * nn.silu(z.astype(f32))).astype(cfg.dtype)
+            return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                            name="out_proj")(o.reshape(b, t, value_dim))
 
 
 class MlpBlock(nn.Module):
@@ -604,15 +833,24 @@ class Block(nn.Module):
     # whether THIS layer's feed-forward is the routed one: the model's
     # layers after its ``first_dense_layers``, where it has ``num_experts``
     routed: bool = False
+    # whether THIS layer's mixer is the linear one (``GatedDeltaNet``):
+    # ``cfg.layer_types`` of the layer
+    linear: bool = False
 
     @nn.compact
     def __call__(self, x, positions, paged=None, layer: int = 0):
         cfg = self.cfg
-        norm = functools.partial(
-            nn.RMSNorm, dtype=cfg.dtype, epsilon=cfg.rms_norm_eps
-        )
-        x = x + Attention(cfg, name="attn")(
-            norm(name="ln1")(x), positions, paged=paged, layer=layer)
+        norm = _rms_norm(cfg)
+        if self.linear:
+            if paged is not None:
+                raise ValueError(
+                    "paged serving takes no 'linear_attention' layer "
+                    "(layer_types): the cache holds keys and values, no "
+                    "recurrent state yet")
+            x = x + GatedDeltaNet(cfg, name="linear_attn")(norm(name="ln1")(x))
+        else:
+            x = x + Attention(cfg, name="attn")(
+                norm(name="ln1")(x), positions, paged=paged, layer=layer)
         if not self.routed:
             x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
             return x
@@ -633,10 +871,15 @@ class Block(nn.Module):
             # an ordinary SwiGLU beside the routed sum, every chip the whole
             # of it; a scope of its own, not the routed layer's ``experts``
             with jax.named_scope("shared_experts"):
-                y = y + MlpBlock(
+                shared = MlpBlock(
                     cfg, hidden=(cfg.num_shared_experts
                                  * cfg.moe_intermediate_size),
                     name="shared_experts")(z)
+                if cfg.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=cfg.dtype,
+                        name="shared_expert_gate")(z))
+                y = y + shared
         return x + y, stats
 
 
@@ -694,7 +937,11 @@ class Transformer(nn.Module):
                 block_cls_for[pol] = block_cls
             # a routed model's leading dense layers keep the dense MlpBlock
             routed_here = routed and i >= cfg.first_dense_layers
-            block = block_cls(cfg, routed=routed_here, name=f"layer_{i}")
+            kinds = {}
+            if cfg.layer_types is not None:
+                kinds["linear"] = cfg.layer_types[i] == "linear_attention"
+            block = block_cls(cfg, routed=routed_here, name=f"layer_{i}",
+                              **kinds)
             if paged is not None:
                 # serving (inference-only) path: the paged-cache state
                 # threads through every block, each addressing its own
@@ -707,8 +954,7 @@ class Transformer(nn.Module):
                 layer_stats.append(stats)
         if cfg.block_diffusion is not None:
             x = x[:, : x.shape[1] // 2]  # the head on the noisy half only
-        x = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                       name="ln_f")(x)
+        x = _rms_norm(cfg)(name="ln_f")(x)
         if cfg.tie_word_embeddings:
             logits = emb.attend(x.astype(jnp.float32))
         else:
@@ -799,6 +1045,7 @@ def modeled_activation_bytes(cfg: TransformerConfig, batch: int,
         ("latent attention (kv_lora_rank)", cfg.kv_lora_rank is not None),
         ("intermediate_size", cfg.intermediate_size is not None),
         ("a routed feed-forward (num_experts)", cfg.num_experts is not None),
+        ("layers of two mixers (layer_types)", cfg.layer_types is not None),
     ) if is_set]
     if uncounted:
         raise ValueError(

@@ -78,6 +78,28 @@ _SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
 
 _NEG_INF = -1e30
 
+# the causal dK/dV kernel holds a whole query-head group's q and dO in VMEM
+# (of the chip's 128 MiB) while they are at most this, twice buffered; beyond
+# it, one query head a program (_bwd_dkv_head_kernel).  The accepted cells'
+# are 8 MiB (2 x 4,096 x 128 + 128) and 10 MiB (8,192 x 192 + 128)
+_DKV_GROUP_BYTES = 48 * 1024 * 1024
+# what a kernel that states its VMEM asks for beside its resident operands
+_VMEM_HEADROOM = 16 * 1024 * 1024
+# the forward and dQ kernels hold a head's whole keys and values, twice
+# buffered; beyond this they state what they need (the compiler's own scoped
+# limit is 16 MiB; the accepted cells' are at most 10 MiB: 8,192 x 192 + 128)
+_KV_RESIDENT_BYTES = 12 * 1024 * 1024
+
+
+def _kv_params(s_k, d, dv, dtype):
+    """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
+    values in VMEM: none while they fit the compiler's own limit."""
+    resident = 2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize
+    if resident <= _KV_RESIDENT_BYTES:
+        return {}
+    return {"compiler_params": _pltpu.CompilerParams(
+        vmem_limit_bytes=resident + _VMEM_HEADROOM)}
+
 # tiles a loop iteration: a range runs four at a time, then what is left two
 # and one at a time (_run_tiles).  Starting at 8 was 2 % of the kernels' time
 # faster again at twice their compile time (PERF.md PR 29)
@@ -479,6 +501,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        **_kv_params(s_k, d, dv, k.dtype),
     )(_off_arr(kv_offset), qf, kf, vf)
     out = _unfold(out, b, h, s_q, dv)[:, :orig_s]
     if with_lse:
@@ -555,6 +578,56 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
+def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
+              base, *, sm_scale, causal, block_q, block_k, seq_len, window):
+    """One tile visit of the causal / windowed dK/dV kernels, as a body for
+    ``_run_tiles``: the query head's rows start at ``base`` of the q-side
+    blocks; ``(dk, dv)`` is the carry."""
+
+    def body(qb, carry):
+        dk, dv = carry
+        q_off = qb * block_q
+        q_blk = q_ref[0, pl.ds(base + q_off, block_q), :].astype(
+            jnp.float32)
+        do_blk = do_ref[0, pl.ds(base + q_off, block_q), :].astype(
+            jnp.float32)
+        lse_blk = lse_ref[0, :, pl.ds(base + q_off, block_q)]  # (1, block_q)
+        delta_blk = delta_ref[0, :, pl.ds(base + q_off, block_q)]
+        st = jax.lax.dot_general(               # (block_k, block_q)
+            k_blk, q_blk * sm_scale,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        k_pos = k_off + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 0)
+        q_pos = q_off + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+        mask = jnp.logical_and(
+            _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
+            q_pos < seq_len,
+        )
+        pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
+        dv = dv + jax.lax.dot_general(
+            pt, do_blk,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            v_blk, do_blk,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dst = pt * (dpt - delta_blk)
+        dk = dk + jax.lax.dot_general(
+            dst, q_blk,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dk, dv
+
+    return body
+
+
 def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *, sm_scale, causal,
                     block_q, block_k, seq_len, window=None, group=1):
@@ -581,54 +654,52 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     carry = (jnp.zeros((block_k, d), jnp.float32),
              jnp.zeros((block_k, dv), jnp.float32))
+    tile = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                block_k=block_k, seq_len=seq_len, window=window)
     for g in range(group):  # static unroll over the query-head group
-        base = g * s_q
-
-        def body(qb, carry, base=base):
-            dk, dv = carry
-            q_off = qb * block_q
-            q_blk = q_ref[0, pl.ds(base + q_off, block_q), :].astype(
-                jnp.float32)
-            do_blk = do_ref[0, pl.ds(base + q_off, block_q), :].astype(
-                jnp.float32)
-            lse_blk = lse_ref[0, :, pl.ds(base + q_off, block_q)]  # (1, block_q)
-            delta_blk = delta_ref[0, :, pl.ds(base + q_off, block_q)]
-            st = jax.lax.dot_general(               # (block_k, block_q)
-                k_blk, q_blk * sm_scale,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            k_pos = k_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = q_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            mask = jnp.logical_and(
-                _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
-                q_pos < seq_len,
-            )
-            pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
-            dv = dv + jax.lax.dot_general(
-                pt, do_blk,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dpt = jax.lax.dot_general(
-                v_blk, do_blk,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dst = pt * (dpt - delta_blk)
-            dk = dk + jax.lax.dot_general(
-                dst, q_blk,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return dk, dv
-
+        body = _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk,
+                         k_off, kv_off, g * s_q, **tile)
         carry = _run_tiles(ranges, body, carry)
     dk, dv = carry
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                         delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                         sm_scale, causal, block_q, block_k, seq_len,
+                         window=None, group=1):
+    """``_bwd_dkv_kernel`` with ONE query head of the group a program: the
+    grid's last axis walks the group and the (block_k, d) sums live in VMEM
+    scratch across it, as under the block-diffusion mask below.  Taken where
+    the whole group's queries and output gradients do not fit VMEM
+    (``_DKV_GROUP_BYTES``: 8 query heads of 256 a key/value head at 8,192
+    rows are 128 MiB twice buffered).  The tile's body is the grouped
+    kernel's (``_dkv_tile``)."""
+    ki, g = pl.program_id(1), pl.program_id(2)
+    kv_off = kvoff_ref[0]
+    k_off = ki * block_k
+
+    @pl.when(g == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    body = _dkv_tile(
+        q_ref, do_ref, lse_ref, delta_ref, k_ref[0].astype(jnp.float32),
+        v_ref[0].astype(jnp.float32), k_off, kv_off, 0, sm_scale=sm_scale,
+        causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
+        window=window)
+    dk_acc[...], dv_acc[...] = _run_tiles(
+        _tile_ranges(k_off, block_k, block_q, q_ref.shape[1] // block_q,
+                     seq_len, causal=causal, window=window, kv_off=kv_off,
+                     bd=None, rows_are_queries=False),
+        body, (dk_acc[...], dv_acc[...]))
+
+    @pl.when(g == group - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dkv_bd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -742,6 +813,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qf.dtype),
         interpret=interpret,
+        **_kv_params(s_k, d, dv_w, kf.dtype),
     )(off, qf, kf, vf, gf, lse_f, delta_f)
     if bd is not None:
         # one query head a program, the group on the grid's last axis
@@ -776,6 +848,43 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
                             _pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
         )(qf, kf, vf, gf, row(lse_f), row(delta_f))
+        return dq, dk, dv
+    head_bytes = 2 * s_q * (d + dv_w) * qf.dtype.itemsize   # twice buffered
+    if group > 1 and group * head_bytes > _DKV_GROUP_BYTES:
+        # the whole group's q and dO do not fit VMEM: one query head a
+        # program, the group on the grid's last axis
+        row = lambda x: x.reshape(bh, 1, s_q)
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_head_kernel, group=group, **kw),
+            name="flash_attention_bwd_dkv",
+            grid=(bh_kv, s_k // block_k, group),
+            in_specs=[
+                _SCALAR_SPEC,
+                pl.BlockSpec((1, s_q, d),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, dv_w), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, s_q, dv_w),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, 1, s_q),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+                pl.BlockSpec((1, 1, s_q),
+                             lambda b, ki, g: (b * group + g, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, dv_w), lambda b, ki, g: (b, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
+                jax.ShapeDtypeStruct((bh_kv, s_k, dv_w), vf.dtype),
+            ],
+            scratch_shapes=[_pltpu.VMEM((block_k, d), jnp.float32),
+                            _pltpu.VMEM((block_k, dv_w), jnp.float32)],
+            compiler_params=_pltpu.CompilerParams(
+                vmem_limit_bytes=head_bytes + _VMEM_HEADROOM),
+            interpret=interpret,
+        )(off, qf, kf, vf, gf, row(lse_f), row(delta_f))
         return dq, dk, dv
     # dK/dV per KV head: regroup the q-side operands so each kv-head
     # program sees its whole query-head group on the row axis — a free

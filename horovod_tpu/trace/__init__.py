@@ -93,6 +93,8 @@ SITES = (
     "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths
     "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring,
                            # the grouped products' tiles and row-tile visits
+    "gdn.chunks",          # the gated delta rule traced: rows, value heads, chunk,
+                           # chunks a sequence, both head widths, the kernel's programs
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -112,7 +114,7 @@ DEVICE_SCOPES = (
 #: the same docs table.  They name no phase: ``trace/device.py`` reports
 #: their time beside the phases' (``subscopes``), and the benchmark's
 #: ``router_ms`` / ``expert_ffn_ms`` / ``mla_proj_ms`` / ``shared_expert_ms``
-#: read them by ``op_name`` pattern.
+#: / ``gdn_proj_ms`` / ``gated_delta_ms`` read them by ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
@@ -125,15 +127,26 @@ DEVICE_SUBSCOPES = (
                 # norm, the latent up to keys and values, RoPE on the rotary
                 # parts, q and k assembled for the kernels (not the kernels)
     "shared_experts",  # models/transformer.py Block: the SwiGLU beside the
-                       # routed sum, whole on every chip
+                       # routed sum, whole on every chip (with its gate, where
+                       # ``shared_expert_gate``)
+    "gdn",      # models/transformer.py GatedDeltaNet, all but the rule: the
+                # two input projections, the causal depthwise convolution,
+                # the L2 norms and the repeat to the value heads, the gates
+                # beta and g, the gated RMSNorm and the output projection
+    "gated_delta",  # GatedDeltaNet, the rule itself (ops/gated_delta.py): the
+                    # chunk-local products, the unit-triangular inverse, the
+                    # decays, and the carry's kernels; a SIBLING of ``gdn``,
+                    # not nested in it, so that one pattern reads each
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
-#: ops/flash_attention.py and ops/grouped_matmul.py, one name a kernel; the
+#: ops/flash_attention.py, ops/grouped_matmul.py and ops/gated_delta.py, one
+#: name a kernel; the
 #: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
 #: attention kernels all start with ``flash_attention`` so one pattern still
 #: reads them together; the routed experts' grouped products do not, and are
-#: read by the ``experts`` scope they run in.
+#: read by the ``experts`` scope they run in, as the gated delta rule's two
+#: (``gated_delta*``) are by the ``gated_delta`` scope.
 DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
     "flash_attention_bwd_dq",   # _backward_folded: dQ
@@ -144,6 +157,11 @@ DEVICE_KERNELS = (
     "grouped_matmul",    # ops/grouped_matmul.py: rows x their group's matrix,
                          # forward and (the matrix read transposed) dx
     "grouped_matmul_t",  # the matrices' gradient: x[g]^T @ dy[g] a group
+    "gated_delta_fwd",   # ops/gated_delta.py: the gated delta rule's carry over
+                         # the chunks, the state in VMEM; a program a
+                         # (sequence, value head)
+    "gated_delta_bwd",   # the same walk last to first, the state's cotangent
+                         # in VMEM
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"

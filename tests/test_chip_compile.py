@@ -157,12 +157,64 @@ def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward)
         assert "flash_attention_bwd_dkv_bd" in text
 
 
-# the routed cells' grouped products: (rows of a chunk, k, n, held experts)
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_256_wide_heads_compile_at_the_cell_s_shapes(one_chip, backward):
+    """qwen3-next-80b-a3b-s8192-1chip: one sequence of 8,192 positions, 16
+    query heads over 2 key/value heads of 256.  The forward and dQ kernels hold
+    a head's 8,192 x 256 keys and values twice buffered (16 MiB: they state
+    their VMEM, ``_kv_params``); a whole group's q and dO would be 128 MiB, so
+    dK/dV runs one query head a program (``_bwd_dkv_head_kernel``) under the
+    name the metrics read."""
+    q = _sds((1, 8192, 16, 256), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, 2, 256), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    text = _compile(fn, q, kv, kv).as_text()
+    kernels = re.findall(r"%(flash_attention\w*)\.\d+ = [^\n]*tpu_custom_call", text)
+    want = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"] \
+        if backward else ["flash_attention_fwd"]
+    assert sorted(kernels) == want
+    assert "bf16[16,8192,256]" in text and "bf16[2,8192,256]" in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward):
+    """``ops.gated_delta.gated_delta_rule`` at the cell's shape (8,192 tokens,
+    32 value heads, a 128 x 128 state) as the v5e compiler takes it: the carry
+    over the chunks is the Mosaic pair, forward and backward, by their names."""
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+
+    qkv = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    gate = _sds((1, 8192, 32), jnp.float32, one_chip)
+
+    def fwd(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta, interpret=False)
+
+    def loss(*a):
+        return jnp.sum(fwd(*a).astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else fwd
+    text = _compile(fn, qkv, qkv, qkv, gate, gate).as_text()
+    kernels = re.findall(r"%(gated_delta\w*)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(kernels) == (["gated_delta_bwd", "gated_delta_fwd"] if backward
+                               else ["gated_delta_fwd"])
+    assert "triangular-solve" not in text and "while(" not in text.replace(" ", "")
+
+
+# the routed cells' grouped products: (rows of a chunk, k, n, held experts, row tile)
 GROUPED_CASES = {
-    "sdar_gate_up": (16384, 2048, 768, 16),
-    "sdar_down": (16384, 768, 2048, 16),
-    "kimi_gate_up": (12288, 2048, 1408, 8),
-    "kimi_down": (12288, 1408, 2048, 8),
+    "sdar_gate_up": (16384, 2048, 768, 16, 256),
+    "sdar_down": (16384, 768, 2048, 16, 256),
+    "kimi_gate_up": (12288, 2048, 1408, 8, 256),
+    "kimi_down": (12288, 1408, 2048, 8, 256),
+    "qwen3next_gate_up": (10240, 2048, 512, 32, 128),
+    "qwen3next_down": (10240, 512, 2048, 32, 128),
 }
 
 
@@ -171,14 +223,15 @@ GROUPED_CASES = {
 def test_grouped_products_are_a_kernel_on_the_chip(one_chip, case, backward):
     """``ops.grouped_matmul`` at both routed cells' chunks (SDAR: 16,384 sorted
     rows, 16 held experts of 2,048 x 768 and back; Kimi: 12,288 rows, 8 of
-    2,048 x 1,408 and back) as the v5e compiler takes it: the package's own
+    2,048 x 1,408 and back; Qwen3-Next: 10,240 rows, 32 of 2,048 x 512 and
+    back, 160-row groups on 128-row tiles) as the v5e compiler takes it: the package's own
     Mosaic kernels, forward and both gradients, by their names; no
     ``ragged-dot``; an expert's whole matrix in a tile fits the fast memory
     the kernels ask for (the compile refuses what does not)."""
     from horovod_tpu.ops.grouped_matmul import grouped_matmul, tiles
 
-    rows, k, n, held = GROUPED_CASES[case]
-    assert tuple(tiles(rows, k, n, held, jnp.bfloat16)) == (256, k, n)
+    rows, k, n, held, tm = GROUPED_CASES[case]
+    assert tuple(tiles(rows, k, n, held, jnp.bfloat16)) == (tm, k, n)
     x = _sds((rows, k), jnp.bfloat16, one_chip)
     w = _sds((held, k, n), jnp.bfloat16, one_chip)
     sizes = _sds((held,), jnp.int32, one_chip)
@@ -199,9 +252,13 @@ def test_grouped_products_are_a_kernel_on_the_chip(one_chip, case, backward):
     assert "ragged-dot" not in text
 
 
-def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch):
+@pytest.mark.parametrize("ff,experts,top_k,held", [(768, 128, 8, 16), (512, 512, 10, 32)],
+                         ids=["sdar", "qwen3next"])
+def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch, ff, experts,
+                                                        top_k, held):
     """``RoutedExperts`` forward and backward at the SDAR cell's shapes (8,192
-    rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) as the
+    rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) and at
+    Qwen3-Next's (top-10 of 512, 32 held, a chunk of 10,240) as the
     v5e compiler leaves it: no scatter at all (before PR 31: two scatter-adds
     of 16,384 rows, each a sort of its indices, a gather of its updates and a
     sorted scatter, and three scatters of single numbers, into
@@ -213,7 +270,7 @@ def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch):
 
     # the layer asks the backend whether its kernels are interpreted
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rows, d, ff, experts, top_k, held = 8192, 2048, 768, 128, 8, 16
+    rows, d = 8192, 2048
     chunk = 2 * rows * top_k * held // experts
     layer = RoutedExperts(experts, top_k, d, ff, held=(0, held), dtype=jnp.bfloat16)
     x = _sds((1, rows, d), jnp.bfloat16, one_chip)
@@ -476,3 +533,68 @@ def test_one_chip_step_gets_no_option(topo, monkeypatch):
     assert compiled_collective_counts(texts[0]) == {
         "async_pairs": 0, "sync_all_reduces": 0}
     assert texts[0] == texts[1]
+
+
+# -- layers of two mixers in one compiled step (PR 35) ------------------------
+
+
+def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
+    """One Gated DeltaNet layer and one gated-attention layer at the widths of
+    qwen3-next-80b-a3b-s8192-1chip (8,192 tokens; 32 value heads of 128; 16
+    query heads over 2 key/value heads of 256; top-10 of 512 with 32 held, a
+    gated shared expert) through the train step: the gated delta rule's carry
+    and the 256-wide attention are Mosaic calls by their names, each under the
+    scope its metric reads (``gated_delta``, never ``gdn``; forward, the
+    backward's own copy and the backward itself), the projections under
+    ``gdn``, and the routed layer still holds no scatter."""
+    import functools
+
+    from horovod_tpu.models.transformer import next_token_loss
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=2, num_heads=16, num_kv_heads=2, head_dim=256,
+        hidden_size=2048, max_seq_len=8192, dtype=jnp.bfloat16, attention_impl="flash",
+        rms_norm_eps=1e-6, rope_theta=1e7, tie_word_embeddings=False, qk_norm=True,
+        norm_zero_centered=True, attn_output_gate=True, partial_rotary_factor=0.25,
+        layer_types=("linear_attention", "full_attention"), linear_num_key_heads=16,
+        linear_key_head_dim=128, linear_num_value_heads=32, linear_value_head_dim=128,
+        num_experts=512, num_experts_per_tok=10, moe_intermediate_size=512,
+        held_experts=(0, 32), num_shared_experts=1, shared_expert_gate=True)
+    model, optimizer = Transformer(cfg), optax.adamw(1e-7)
+    replicated = NamedSharding(mesh, P())
+    batch = NamedSharding(mesh, P(WORLD_AXIS))
+    state = jax.eval_shape(lambda: training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)))
+    state = jax.tree_util.tree_map(lambda s: _sds(s.shape, s.dtype, replicated), state)
+    step = training.data_parallel_train_step(
+        model, optimizer, mesh=mesh,
+        loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
+    tokens = _sds((1, 8192), jnp.int32, batch)
+    compiled = step.lower(state, tokens, tokens).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    calls = {}
+    for line in text.splitlines():
+        m = re.search(r"%(\w+?)\.\d+ = [^\n]*tpu_custom_call", line)
+        if m:
+            calls.setdefault(m.group(1), []).append(
+                re.search(r'op_name="([^"]*)"', line).group(1))
+    # the rule's forward twice (the mixer is made again in the backward) and its
+    # backward once; the three attention kernels once each
+    assert len(calls["gated_delta_fwd"]) == 2 and len(calls["gated_delta_bwd"]) == 1
+    assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                                    "flash_attention_bwd_dkv")] == [1, 1, 1]
+    for name in ("gated_delta_fwd", "gated_delta_bwd"):
+        assert all("/linear_attn/" in o and "/gated_delta/" in o and "/gdn/" not in o
+                   for o in calls[name]), calls[name]
+    assert all("/layer_1/attn/" in o for k in calls if k.startswith("flash") for o in calls[k])
+    assert all("/experts/" in o for k in calls if k.startswith("grouped") for o in calls[k])
+    op_names = set(re.findall(r'op_name="([^"]+)"', text))
+    assert any("/gdn/" in o and "in_proj_qkvz" in o for o in op_names)
+    assert any("/gdn/" in o and "transpose(jvp(forward))" in o for o in op_names)
+    assert "bf16[16,8192,256]" in text            # the 256-wide heads reach the kernels
+    # the step's only scatters are the embedding's and the loss's gradients
+    scatters = [l for l in text.splitlines() if re.search(r"= (\(.*?\)|\S+) scatter\(", l)]
+    assert not [l for l in scatters if "/moe/" in l or "/linear_attn/" in l], scatters

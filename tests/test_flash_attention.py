@@ -205,3 +205,56 @@ def test_flash_tiles_event_names_each_kernel_traced():
     assert traced(block_diffusion=(256, 4)) == {
         "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
         "flash_attention_bwd_dkv_bd": (8, 6)}
+
+
+# -- 256-wide heads, eight query heads a key/value head (PR 35) ----------------
+
+
+@pytest.mark.parametrize("one_head", [False, True],
+                         ids=["dkv_whole_group", "dkv_one_head_a_program"])
+@pytest.mark.parametrize("dtype,s,tol", [(jnp.float32, 300, 2e-4),
+                                         (jnp.float32, 1024, 2e-4),
+                                         (jnp.bfloat16, 512, 6e-2)])
+def test_flash_256_wide_heads_match_dense(monkeypatch, dtype, s, tol, one_head):
+    """Forward, dQ and dK/dV at ``d_qk = d_v = 256`` against
+    ``causal_dot_attention``, 8 query heads over 1 key/value head (the gated
+    attention of the ``qwen3_next`` family), in 128-tiles so that the loops run
+    several tiles; dK/dV by both kernels: the whole group a program, and one
+    query head a program with the sums in VMEM scratch (what 8 x 8,192 rows of
+    256 take: ``_DKV_GROUP_BYTES``)."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    if one_head:
+        monkeypatch.setattr(fa, "_DKV_GROUP_BYTES", 0)
+    ks = jax.random.split(jax.random.PRNGKey(s), 4)
+    q = jax.random.normal(ks[0], (1, s, 8, 256), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (1, s, 1, 256), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (1, s, 1, 256), jnp.float32).astype(dtype)
+    co = jax.random.normal(ks[3], (1, s, 8, 256), jnp.float32)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * co),
+            argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=128))
+    want = run(causal_dot_attention)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=tol * max(1.0, np.abs(b).max()))
+
+
+def test_the_one_head_dkv_kernel_is_chosen_by_the_group_s_bytes():
+    """Twice-buffered q and dO of a whole query-head group: the accepted cells'
+    8 and 10 MiB keep the grouped kernel, the 256-wide cell's 128 MiB do not."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    group_bytes = lambda group, s, d, dv: group * 2 * s * (d + dv) * 2
+    assert group_bytes(2, 4096, 128, 128) == 8 * 2 ** 20 < fa._DKV_GROUP_BYTES
+    assert group_bytes(1, 8192, 192, 128) == 10 * 2 ** 20 < fa._DKV_GROUP_BYTES
+    assert group_bytes(8, 8192, 256, 256) == 128 * 2 ** 20 > fa._DKV_GROUP_BYTES
+    # and the forward / dQ kernels state their VMEM only beyond the compiler's own
+    assert fa._kv_params(8192, 192, 128, jnp.bfloat16) == {}
+    assert fa._kv_params(8192, 128, 128, jnp.bfloat16) == {}
+    stated = fa._kv_params(8192, 256, 256, jnp.bfloat16)["compiler_params"]
+    assert stated.vmem_limit_bytes == 16 * 2 ** 20 + fa._VMEM_HEADROOM

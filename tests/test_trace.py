@@ -917,7 +917,7 @@ def test_device_names_catalogue_matches_the_code():
     assert tuple(trace_sites.catalogue(repo, "DEVICE_SCOPES")) == \
         trace.DEVICE_SCOPES
     assert [k for k in trace.DEVICE_KERNELS if not k.startswith("flash_attention_")] \
-        == ["grouped_matmul", "grouped_matmul_t"]
+        == ["grouped_matmul", "grouped_matmul_t", "gated_delta_fwd", "gated_delta_bwd"]
     assert not os.path.exists(
         os.path.join(repo, "horovod_tpu", "utils", "profiler.py"))
 
@@ -930,7 +930,8 @@ def test_subscope_catalogue_matches_the_code_and_names_no_phase():
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert tuple(trace_sites.catalogue(repo, "DEVICE_SUBSCOPES")) == \
-        trace.DEVICE_SUBSCOPES == ("router", "experts", "mla", "shared_experts")
+        trace.DEVICE_SUBSCOPES == ("router", "experts", "mla", "shared_experts",
+                                   "gdn", "gated_delta")
     assert "flash_attention_bwd_dkv_bd" in trace.DEVICE_KERNELS
     assert not set(trace.DEVICE_SUBSCOPES) & set(trace.DEVICE_SCOPES)
     path = "jit(_step)/shard_map/transpose(jvp(forward))/T/layer_0/moe/experts/x"
@@ -997,7 +998,7 @@ def test_subscope_table_finds_the_routed_layer_in_a_compiled_step(latent):
     text = _routed_step_text(latent)
     parts = trace_device.subscope_table(text)
     assert set(parts.values()) == set(
-        trace.DEVICE_SUBSCOPES if latent else trace.DEVICE_SUBSCOPES[:2])
+        trace.DEVICE_SUBSCOPES[:4] if latent else trace.DEVICE_SUBSCOPES[:2])
     phases = trace_device.phase_table(text)
     # a part lies inside the forward scope or its transpose (or is an
     # operation the compiler left unnamed, which takes its operand's part

@@ -26,6 +26,9 @@ Usage:
   flash_bench.py --grouped       # the routed experts' grouped product alone,
                                  #  kernel against jax.lax.ragged_dot, DEVICE
                                  #  time from a capture (PERF.md §6, PR 33)
+  flash_bench.py --gated-delta   # the gated delta rule alone, the carry as
+                                 #  the Mosaic kernel and as a jax.numpy scan,
+                                 #  DEVICE time from a capture (PERF.md §6, PR 35)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -245,6 +248,7 @@ CELL_SHAPES = {
     "internlm2-1.8b-s4096-1chip": (1, 4096, 16, 8, 128, True, None),
     "sdar-30b-a3b-bd4-s4096-1chip": (1, 8192, 32, 4, 128, False, (4096, 4)),
     "kimi-vl-a3b-s8192-1chip": (1, 8192, 16, 16, (192, 128), True, None),
+    "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 16, 2, 256, True, None),
 }
 
 
@@ -306,7 +310,84 @@ GROUPED_SHAPES = {
     "sdar-30b-a3b down": (16384, 768, 2048, 16),
     "kimi-vl-a3b gate/up x8 rows": (98304, 2048, 1408, 8),
     "sdar-30b-a3b gate/up x8 rows": (131072, 2048, 768, 16),
+    "qwen3-next-80b-a3b gate/up": (10240, 2048, 512, 32),
+    "qwen3-next-80b-a3b down": (10240, 512, 2048, 32),
 }
+
+# (b, t, value heads, dk, dv): a linear layer's gated delta rule in the
+# benchmark's cell (benchmark/configs/qwen3-next-80b-a3b.json)
+GATED_DELTA_SHAPES = {
+    "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 32, 128, 128),
+}
+
+
+def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
+    """The gated delta rule alone (``ops/gated_delta.py``), forward and
+    forward + backward, the carry as the Mosaic kernel and as the
+    ``jax.numpy`` scan, each its own ``jit`` inside ONE capture: DEVICE time a
+    call from the capture's ``XLA Modules`` events (``null`` off the chip), and
+    the largest gap between the two carries' outputs."""
+    import shutil
+    import tempfile
+
+    from horovod_tpu.ops.gated_delta import gated_delta_rule
+
+    capture_dir = tempfile.mkdtemp(prefix="gated_delta_capture_")
+    programs, records = {}, []
+    for name, (b, t, h, dk, dv) in shapes.items():
+        keys = jax.random.split(jax.random.PRNGKey(0), 6)
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        q = (unit(jax.random.normal(keys[0], (b, t, h, dk))) * dk ** -0.5
+             ).astype(jnp.bfloat16)
+        k = unit(jax.random.normal(keys[1], (b, t, h, dk))).astype(jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, t, h, dv)).astype(jnp.bfloat16)
+        beta = jax.nn.sigmoid(jax.random.normal(keys[3], (b, t, h)))
+        g = -0.03 * jnp.exp(jax.random.normal(keys[4], (b, t, h)))
+        do = jax.random.normal(keys[5], (b, t, h, dv)).astype(jnp.bfloat16)
+        args = (q, k, v, g, beta)
+        rec = {"bench": "gated_delta", "shape": name, "b": b, "t": t, "heads": h,
+               "d_k": dk, "d_v": dv, "chunk": chunk, "variants": {}}
+        outs = {}
+        for impl in ("kernel", "jnp"):
+            def fwd(*a, impl=impl):
+                return gated_delta_rule(*a, chunk=chunk, impl=impl,
+                                        interpret=interpret)
+
+            def fwd_bwd(*a, fwd=fwd):
+                return jax.grad(lambda *x: jnp.sum(
+                    fwd(*x).astype(jnp.float32) * do.astype(jnp.float32)),
+                    argnums=(0, 1, 2, 3, 4))(*a)
+
+            for what, fn in (("fwd", fwd), ("fwd_bwd", fwd_bwd)):
+                fn.__name__ = "gd%d_%s_%s" % (len(records), impl, what)
+                programs[(len(records), impl, what)] = jax.jit(fn)
+            outs[impl] = programs[(len(records), impl, "fwd")](*args).astype(
+                jnp.float32)
+        scale = float(jnp.max(jnp.abs(outs["jnp"])))
+        rec["kernel_against_jnp_gap"] = float(
+            jnp.max(jnp.abs(outs["kernel"] - outs["jnp"]))) / scale
+        records.append((rec, args))
+    for (i, _, _), fn in programs.items():
+        for _ in range(warmup):
+            jax.block_until_ready(fn(*records[i][1]))
+    jax.profiler.start_trace(capture_dir)
+    try:
+        for (i, _, _), fn in programs.items():
+            for _ in range(iters):
+                out = fn(*records[i][1])
+            jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    ms = module_ms(capture_dir)
+    shutil.rmtree(capture_dir, ignore_errors=True)
+    for i, (rec, _) in enumerate(records):
+        for (j, impl, what), fn in programs.items():
+            if j == i:
+                t = ms.get("jit_" + fn.__name__)
+                rec["variants"].setdefault(impl, {})[what + "_device_ms"] = (
+                    round(t, 4) if t else None)
+        _emit(rec, f"{rec['shape']}: " + "  ".join(
+            f"{impl} {p}" for impl, p in rec["variants"].items()))
 
 
 def routed_sizes(rows, groups, seed=0):
@@ -447,6 +528,7 @@ def main(argv=None):
     ap.add_argument("--kernel", action="store_true")
     ap.add_argument("--cells", action="store_true")
     ap.add_argument("--grouped", action="store_true")
+    ap.add_argument("--gated-delta", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny interpret-mode pass of every leg (CI)")
     args = ap.parse_args(argv)
@@ -470,10 +552,11 @@ def main(argv=None):
                    "latent": (1, 512, 4, 4, (48, 32), True, None)},
                   2, 1, True, block=128)
         leg_grouped({"skewed": (192, 256, 384, 4)}, 1, 1, True)
+        leg_gated_delta({"tiny": (1, 80, 2, 16, 16)}, 1, 1, True, chunk=16)
         return 0
 
     run_all = not (args.gqa or args.window or args.kernel or args.cells
-                   or args.grouped)
+                   or args.grouped or args.gated_delta)
     if args.kernel or run_all:
         leg_kernel([(4, 1024, 8, 128), (4, 2048, 8, 128),
                     (2, 4096, 8, 128)], iters, warmup, None)
@@ -486,6 +569,8 @@ def main(argv=None):
         leg_cells(CELL_SHAPES, iters, warmup, None)
     if args.grouped:
         leg_grouped(GROUPED_SHAPES, iters, warmup, None)
+    if args.gated_delta:
+        leg_gated_delta(GATED_DELTA_SHAPES, iters, warmup, None)
     return 0
 
 
