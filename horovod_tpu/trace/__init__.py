@@ -133,10 +133,11 @@ DEVICE_SUBSCOPES = (
                 # two input projections, the causal depthwise convolution,
                 # the L2 norms and the repeat to the value heads, the gates
                 # beta and g, the gated RMSNorm and the output projection
-    "gated_delta",  # GatedDeltaNet, the rule itself (ops/gated_delta.py): the
-                    # chunk-local products, the unit-triangular inverse, the
-                    # decays, and the carry's kernels; a SIBLING of ``gdn``,
-                    # not nested in it, so that one pattern reads each
+    "gated_delta",  # GatedDeltaNet, the rule itself (ops/gated_delta.py): its
+                    # three kernels (since PR 36 they make the chunk-local
+                    # tensors themselves), XLA's unit-triangular inverse and
+                    # the layouts XLA hands them; a SIBLING of ``gdn``, not
+                    # nested in it, so that one pattern reads each
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
@@ -145,7 +146,7 @@ DEVICE_SUBSCOPES = (
 #: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
 #: attention kernels all start with ``flash_attention`` so one pattern still
 #: reads them together; the routed experts' grouped products do not, and are
-#: read by the ``experts`` scope they run in, as the gated delta rule's two
+#: read by the ``experts`` scope they run in, as the gated delta rule's three
 #: (``gated_delta*``) are by the ``gated_delta`` scope.
 DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
@@ -157,11 +158,16 @@ DEVICE_KERNELS = (
     "grouped_matmul",    # ops/grouped_matmul.py: rows x their group's matrix,
                          # forward and (the matrix read transposed) dx
     "grouped_matmul_t",  # the matrices' gradient: x[g]^T @ dy[g] a group
-    "gated_delta_fwd",   # ops/gated_delta.py: the gated delta rule's carry over
-                         # the chunks, the state in VMEM; a program a
-                         # (sequence, value head)
+    "gated_delta_kkt",   # ops/gated_delta.py: L = strictly lower(beta (k k^T)
+                         # decay) a chunk and head from k, g, beta, float32:
+                         # what XLA's unit-triangular inverse takes
+    "gated_delta_fwd",   # the chunk-local tensors made in VMEM from q, k, v,
+                         # g, beta, T, and the rule's carry over the chunks,
+                         # the state in VMEM; a program a (sequence, four
+                         # value heads)
     "gated_delta_bwd",   # the same walk last to first, the state's cotangent
-                         # in VMEM
+                         # in VMEM: the chunk-local tensors made again, their
+                         # backward written out, dq, dk, dv, dg, dbeta
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"

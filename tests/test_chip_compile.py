@@ -183,11 +183,20 @@ def test_flash_256_wide_heads_compile_at_the_cell_s_shapes(one_chip, backward):
     assert "bf16[16,8192,256]" in text and "bf16[2,8192,256]" in text
 
 
+def _xla_products(text, inside):
+    """The compiled text's ``dot`` and ``convolution`` instructions (XLA's own
+    products, in a fusion or not) whose ``op_name`` holds ``inside``."""
+    return [line.strip() for line in text.splitlines()
+            if re.search(r"= \S+ (dot|convolution)\(", line) and inside in line]
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward):
     """``ops.gated_delta.gated_delta_rule`` at the cell's shape (8,192 tokens,
-    32 value heads, a 128 x 128 state) as the v5e compiler takes it: the carry
-    over the chunks is the Mosaic pair, forward and backward, by their names."""
+    32 value heads, a 128 x 128 state) as the v5e compiler takes it: ``L``, the
+    chunk-local tensors with the carry over the chunks, and their backward are
+    three Mosaic kernels by their names, and XLA is left no product of the rule
+    but the unit-triangular inverse's (PR 36)."""
     from horovod_tpu.ops.gated_delta import gated_delta_rule
 
     qkv = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
@@ -202,9 +211,13 @@ def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward):
     fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else fwd
     text = _compile(fn, qkv, qkv, qkv, gate, gate).as_text()
     kernels = re.findall(r"%(gated_delta\w*)\.\d+ = [^\n]*tpu_custom_call", text)
-    assert sorted(kernels) == (["gated_delta_bwd", "gated_delta_fwd"] if backward
-                               else ["gated_delta_fwd"])
+    assert sorted(kernels) == (["gated_delta_bwd"] if backward else []) + [
+        "gated_delta_fwd", "gated_delta_kkt"]
     assert "triangular-solve" not in text and "while(" not in text.replace(" ", "")
+    products = _xla_products(text, "jit(_rule)")
+    assert products and all("jit(_block_inverse)" in line for line in products)
+    # the kernels' operands as they cross HBM: q, k, v token-major, T float32
+    assert "bf16[1,8192,4096]" in text and "f32[1,32,8192,64]" in text
 
 
 # the routed cells' grouped products: (rows of a chunk, k, n, held experts, row tile)
@@ -581,12 +594,15 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
         if m:
             calls.setdefault(m.group(1), []).append(
                 re.search(r'op_name="([^"]*)"', line).group(1))
-    # the rule's forward twice (the mixer is made again in the backward) and its
-    # backward once; the three attention kernels once each
-    assert len(calls["gated_delta_fwd"]) == 2 and len(calls["gated_delta_bwd"]) == 1
+    # the rule's forward (``L``'s kernel, then the chunks') twice (the mixer is
+    # made again in the backward) and its backward once; the three attention
+    # kernels once each; of the rule's products XLA keeps the inverse's alone
+    assert [len(calls[k]) for k in ("gated_delta_kkt", "gated_delta_fwd",
+                                    "gated_delta_bwd")] == [2, 2, 1]
+    assert all("jit(_block_inverse)" in line for line in _xla_products(text, "/gated_delta/"))
     assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
                                     "flash_attention_bwd_dkv")] == [1, 1, 1]
-    for name in ("gated_delta_fwd", "gated_delta_bwd"):
+    for name in ("gated_delta_kkt", "gated_delta_fwd", "gated_delta_bwd"):
         assert all("/linear_attn/" in o and "/gated_delta/" in o and "/gdn/" not in o
                    for o in calls[name]), calls[name]
     assert all("/layer_1/attn/" in o for k in calls if k.startswith("flash") for o in calls[k])

@@ -26,9 +26,11 @@ Usage:
   flash_bench.py --grouped       # the routed experts' grouped product alone,
                                  #  kernel against jax.lax.ragged_dot, DEVICE
                                  #  time from a capture (PERF.md §6, PR 33)
-  flash_bench.py --gated-delta   # the gated delta rule alone, the carry as
-                                 #  the Mosaic kernel and as a jax.numpy scan,
-                                 #  DEVICE time from a capture (PERF.md §6, PR 35)
+  flash_bench.py --gated-delta   # the gated delta rule alone, as the Mosaic
+                                 #  kernels and as XLA's products with a
+                                 #  jax.numpy scan, DEVICE time from a capture,
+                                 #  split into the kernels by name and what is
+                                 #  left in XLA (PERF.md §6, PR 35, PR 36)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -323,10 +325,12 @@ GATED_DELTA_SHAPES = {
 
 def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
     """The gated delta rule alone (``ops/gated_delta.py``), forward and
-    forward + backward, the carry as the Mosaic kernel and as the
-    ``jax.numpy`` scan, each its own ``jit`` inside ONE capture: DEVICE time a
-    call from the capture's ``XLA Modules`` events (``null`` off the chip), and
-    the largest gap between the two carries' outputs."""
+    forward + backward, as the Mosaic kernels and as XLA's chunk-local products
+    with the ``jax.numpy`` scan, each its own ``jit`` inside ONE capture: DEVICE time a
+    call from the capture's ``XLA Modules`` events (``null`` off the chip), the
+    largest gap between the two carries' outputs and, inside each program, the
+    split of its operations' time into the rule's kernels by name and what is
+    left in XLA (the unit-triangular inverse, and the layouts in and out)."""
     import shutil
     import tempfile
 
@@ -379,13 +383,17 @@ def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
     finally:
         jax.profiler.stop_trace()
     ms = module_ms(capture_dir)
-    shutil.rmtree(capture_dir, ignore_errors=True)
     for i, (rec, _) in enumerate(records):
         for (j, impl, what), fn in programs.items():
             if j == i:
                 t = ms.get("jit_" + fn.__name__)
-                rec["variants"].setdefault(impl, {})[what + "_device_ms"] = (
-                    round(t, 4) if t else None)
+                variant = rec["variants"].setdefault(impl, {})
+                variant[what + "_device_ms"] = round(t, 4) if t else None
+                if t:
+                    variant[what + "_split_ms"] = kernel_split_ms(
+                        capture_dir, "jit_" + fn.__name__, "gated_delta_")
+    shutil.rmtree(capture_dir, ignore_errors=True)
+    for rec, _ in records:
         _emit(rec, f"{rec['shape']}: " + "  ".join(
             f"{impl} {p}" for impl, p in rec["variants"].items()))
 
@@ -423,6 +431,25 @@ def module_ms(capture_dir):
                 name = re.sub(r"\(\d+\)$", "", e.name)
                 runs.setdefault(name, []).append(e.duration_ns / 1e6)
     return {name: sum(ms) / len(ms) for name, ms in runs.items()}
+
+
+def kernel_split_ms(capture_dir, module, prefix):
+    """``{kernel name: ms, ..., "xla": ms}`` a run of the program ``module``:
+    the DEVICE time of its operations (the capture's ``XLA Ops`` events inside
+    the program's runs), those whose instruction starts with ``prefix`` by
+    their kernel's name and every other one under ``xla``."""
+    import re
+
+    from horovod_tpu.trace import device as _device
+
+    split = {}
+    events = _device.device_events(
+        capture_dir, module=r"^%s(\(\d+\))?$" % re.escape(module))
+    for dev in events.values():
+        for name, _, ns in dev["ops"]:
+            key = name.rsplit(".", 1)[0] if name.startswith(prefix) else "xla"
+            split[key] = split.get(key, 0.0) + ns / 1e6 / dev["steps"] / len(events)
+    return {k: round(v, 4) for k, v in sorted(split.items())}
 
 
 def grouped_variants(interpret):
