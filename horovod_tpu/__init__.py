@@ -21,6 +21,18 @@ Typical JAX use::
 
 from __future__ import annotations
 
+import sys as _sys
+import time as _time
+
+# the package's import on its own ring (site ``hvd.import``, recorded on
+# the last line): trace first, it is import-light
+_import_t0 = _time.perf_counter()
+_jax_loaded = "jax" in _sys.modules
+from . import trace
+import jax as _jax  # noqa: F401  the compile recorder needs jax loaded
+
+_import_compiles = trace.compile_totals()
+
 from .common import basics as _basics
 from .common.basics import (
     init,
@@ -89,7 +101,6 @@ from .functions import (
     broadcast_parameters,
 )
 from . import callbacks, chaos, checkpoint, data, elastic, guard, metrics
-from . import trace
 from .compression import Compression
 from .sync_batch_norm import SyncBatchNorm
 from .optim import (
@@ -143,3 +154,7 @@ def hierarchical_mesh(num_groups=None):
     """2-D (dcn, ici) mesh for two-level reductions (reference analog:
     local/cross communicators of NCCLHierarchicalAllreduce)."""
     return _basics._require_init().topology.hierarchical_mesh(num_groups)
+
+
+trace.add_span("hvd.import", _import_t0, trace.now(), jax_loaded=_jax_loaded,
+               **trace.compile_delta(_import_compiles))

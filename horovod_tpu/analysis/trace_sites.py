@@ -3,11 +3,11 @@
 The span recorder names its instrumentation points twice — the
 ``SITES`` catalogue in ``trace/__init__.py`` and the site table in
 ``docs/TRACING.md`` — and the package's ``trace.span("...")`` /
-``trace.event("...")`` / ``trace.add_span("...")`` literals must agree
-with both.  A span site present in one layer but not the others is
-either a timeline name no dashboard can look up, or a documented
-signal that never records — the same silent-drift class the chaos and
-metrics passes exist for.
+``trace.event("...")`` / ``trace.add_span("...")`` /
+``trace.compile_span("...")`` literals must agree with both.  A span site
+present in one layer but not the others is either a timeline name no
+dashboard can look up, or a documented signal that never records — the
+same silent-drift class the chaos and metrics passes exist for.
 
 Checked equivalences:
 
@@ -48,9 +48,11 @@ FLASH_PY = "horovod_tpu/ops/flash_attention.py"
 _STR_RE = re.compile(r"\"([a-z0-9_.]+)\"")
 # matches trace.span("x") / _trace.event("x") / trace.add_span("x") —
 # any alias ending in `trace.`; the method set keeps collective_ops'
-# unrelated _span(name, ...) helper out
+# unrelated _span(name, ...) helper out; a bare add_span("x") is the
+# recorder's own record, in trace/__init__.py
 _CALL_RE = re.compile(
-    r"\w*trace\.(?:span|event|add_span)\(\s*[\"']([a-z0-9_.]+)[\"']")
+    r"(?:\w*trace\.|(?<![\w.]))(?:span|event|add_span|compile_span)"
+    r"\(\s*[\"']([a-z0-9_.]+)[\"']")
 _DOC_ROW_RE = re.compile(
     r"^\|\s*`([a-z0-9_]+(?:\.[a-z0-9_]+)+)`\s*\|", re.MULTILINE)
 # device names: (catalogue tuple, kind in the docs table, call-site regex)
@@ -108,9 +110,10 @@ def run(root: str) -> List[Finding]:
     # -- call sites ----------------------------------------------------------
     used: Set[str] = set()
     sources: Dict[str, str] = {}
-    for rel in iter_py_files(root,
-                             exclude_dirs=("analysis", "trace",
-                                           "__pycache__")):
+    for rel in [*iter_py_files(root,
+                               exclude_dirs=("analysis", "trace",
+                                             "__pycache__")),
+                TRACE_INIT_PY]:
         text = read_text(os.path.join(root, rel))
         if text is None:
             continue

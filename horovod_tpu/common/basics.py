@@ -166,72 +166,84 @@ def init(devices: Optional[Sequence] = None) -> None:
       devices: optional explicit device list (defaults to ``jax.devices()``);
         mainly for tests that carve up a virtual CPU mesh.
     """
+    from .. import trace as _trace
+
     with _state.lock:
         if _state.initialized:
             return
-        _maybe_init_distributed()
-        _state.config = Config.from_env()
-        _state.topology = _topology.discover(devices)
-        _state.process_set_registry.attach_world(_state.topology)
+        # start-up on the ring (docs/TRACING.md): the whole of init with
+        # the compiles it paid, its two slow parts as child spans
+        with _trace.compile_span("hvd.init"):
+            # where PJRT's client comes up: the coordination service
+            # joined, then the first jax.devices()
+            with _trace.compile_span("hvd.init.topology"):
+                _maybe_init_distributed()
+                _state.config = Config.from_env()
+                _state.topology = _topology.discover(devices)
+            _state.process_set_registry.attach_world(_state.topology)
 
-        # fault injection: install the HVD_TPU_CHAOS plan for THIS rank
-        # before the controller loads (the ctypes controller exports the
-        # transport.* rules into the native core).  No spec = one module
-        # bool per injection point.
-        from .. import chaos as _chaos
+            # fault injection: install the HVD_TPU_CHAOS plan for THIS rank
+            # before the controller loads (the ctypes controller exports the
+            # transport.* rules into the native core).  No spec = one module
+            # bool per injection point.
+            from .. import chaos as _chaos
 
-        _chaos.install_from_env(rank=_state.topology.process_index)
+            _chaos.install_from_env(rank=_state.topology.process_index)
 
-        from ..ops.engine import CollectiveEngine  # deferred: avoids cycle
+            from ..ops.engine import CollectiveEngine  # deferred: avoids cycle
 
-        _state.engine = CollectiveEngine(_state.topology, _state.config)
+            _state.engine = CollectiveEngine(_state.topology, _state.config)
 
-        from ..native import load_controller  # deferred: optional native core
+            from .. import native as _native  # deferred: optional native core
 
-        _state.controller = load_controller(_state.topology, _state.config)
-        if _state.controller.is_native:
-            _state.controller.set_engine(_state.engine)
-        elif _state.config.timeline_filename:
-            # python fallback timeline; the native core owns the file when
-            # loaded (its C++ writer thread, reference-style)
-            from ..utils.timeline import Timeline
+            with _trace.compile_span("hvd.init.controller") as sp:
+                _state.controller = _native.load_controller(
+                    _state.topology, _state.config)
+                if sp is not None:
+                    sp.set(native=bool(_state.controller.is_native),
+                           **_native.build_args())
+            if _state.controller.is_native:
+                _state.controller.set_engine(_state.engine)
+            elif _state.config.timeline_filename:
+                # python fallback timeline; the native core owns the file when
+                # loaded (its C++ writer thread, reference-style)
+                from ..utils.timeline import Timeline
 
-            _state.timeline = Timeline(
-                _state.config.timeline_filename, rank=_state.topology.rank
+                _state.timeline = Timeline(
+                    _state.config.timeline_filename, rank=_state.topology.rank
+                )
+
+            # telemetry: identity gauge + the per-worker /metrics + /healthz
+            # endpoint (HVD_TPU_METRICS_PORT opts in; collection itself is
+            # always on and costs nothing until scraped)
+            from ..metrics import exposition as _metrics_exposition
+            from ..metrics import instruments as _instruments
+
+            _instruments.PROCESS_INFO.labels(
+                str(_state.topology.rank), str(local_rank()),
+                str(_state.topology.size),
+                str(_state.topology.num_processes),
+            ).set(1)
+            _metrics_exposition.maybe_start_from_env(local_rank=local_rank())
+
+            # span recorder + flight recorder (docs/TRACING.md): stamp this
+            # rank on exports/bundles, mount /trace on the endpoint above,
+            # and baseline the metric-delta snapshot.  Recording itself is
+            # on by default (HVD_TPU_TRACE=0 disables) and device-free.
+            from ..utils.logging import set_log_context
+
+            _trace.install_from_env(rank=_state.topology.rank)
+            set_log_context(rank=_state.topology.rank)
+
+            _state.initialized = True
+            get_logger().info(
+                "initialized: size=%d local_size=%d rank=%d processes=%d backend=%s",
+                _state.topology.size,
+                _state.topology.local_size,
+                _state.topology.rank,
+                _state.topology.num_processes,
+                jax.default_backend(),
             )
-
-        # telemetry: identity gauge + the per-worker /metrics + /healthz
-        # endpoint (HVD_TPU_METRICS_PORT opts in; collection itself is
-        # always on and costs nothing until scraped)
-        from ..metrics import exposition as _metrics_exposition
-        from ..metrics import instruments as _instruments
-
-        _instruments.PROCESS_INFO.labels(
-            str(_state.topology.rank), str(local_rank()),
-            str(_state.topology.size),
-            str(_state.topology.num_processes),
-        ).set(1)
-        _metrics_exposition.maybe_start_from_env(local_rank=local_rank())
-
-        # span recorder + flight recorder (docs/TRACING.md): stamp this
-        # rank on exports/bundles, mount /trace on the endpoint above,
-        # and baseline the metric-delta snapshot.  Recording itself is
-        # on by default (HVD_TPU_TRACE=0 disables) and device-free.
-        from .. import trace as _trace
-        from ..utils.logging import set_log_context
-
-        _trace.install_from_env(rank=_state.topology.rank)
-        set_log_context(rank=_state.topology.rank)
-
-        _state.initialized = True
-        get_logger().info(
-            "initialized: size=%d local_size=%d rank=%d processes=%d backend=%s",
-            _state.topology.size,
-            _state.topology.local_size,
-            _state.topology.rank,
-            _state.topology.num_processes,
-            jax.default_backend(),
-        )
 
 
 def shutdown() -> None:

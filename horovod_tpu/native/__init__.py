@@ -50,6 +50,17 @@ def _lib_path() -> str:
 
 
 _build_attempted = False
+# seconds `make` took in this process when it made the library anew (its
+# mtime moved); None when the library was found fresh, or nothing was built
+_built_s = None
+
+
+def build_args() -> dict:
+    """What the ``hvd.init.controller`` span says of the build: ``built``,
+    and ``build_s`` when the core was compiled in this process."""
+    if _built_s is None:
+        return {"built": False}
+    return {"built": True, "build_s": _built_s}
 
 
 def _maybe_build() -> None:
@@ -62,23 +73,33 @@ def _maybe_build() -> None:
     turns on a lock over the Makefile, so one builds and the rest find the
     library fresh.  Without a toolchain an existing library is used as it
     is; a failed build, or no library and no toolchain, raises."""
-    global _build_attempted
+    global _build_attempted, _built_s
     if _build_attempted:
         return
     import fcntl
     import shutil
     import subprocess
+    import time
+
+    def mtime():
+        try:
+            return os.stat(_lib_path()).st_mtime_ns
+        except OSError:
+            return None
 
     src = os.path.join(os.path.dirname(__file__), "src")
     if shutil.which("make") and shutil.which("g++"):
         with open(os.path.join(src, "Makefile")) as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
+            before, t0 = mtime(), time.perf_counter()
             try:
                 subprocess.run(["make"], cwd=src, check=True,
                                capture_output=True, text=True, timeout=300)
             except subprocess.CalledProcessError as e:
                 raise RuntimeError(
                     f"native core build failed:\n{e.stderr[-4000:]}") from e
+            if mtime() != before:
+                _built_s = time.perf_counter() - t0
     elif not os.path.exists(_lib_path()):
         raise RuntimeError(
             f"native core: {_lib_path()} is not built and make/g++ are not "

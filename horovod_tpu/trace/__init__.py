@@ -20,6 +20,14 @@ recorder).  Three properties are load-bearing:
   :func:`event` into a single module-bool check returning a shared
   null context (the chaos ``point()`` discipline).
 
+Start-up is on the ring too: ``hvd.import``, ``hvd.init`` and its two
+children, ``train.create_state`` and its two, and one ``jax.compile``
+record a backend compile or persistent-cache load, written by the
+process's ONE ``jax.monitoring`` recorder (:func:`compile_totals`;
+nothing is registered under ``HVD_TPU_TRACE=0``).  A child lies inside
+its parent's extents on the same thread: that is the whole nesting, no
+span stack and no ids (:func:`~horovod_tpu.trace.export.enclosing`).
+
 Sites are catalogued in :data:`SITES` (the analysis ``trace`` pass
 holds code ≡ catalogue ≡ docs/TRACING.md in both directions).  Spans
 bridge into any active ``jax.profiler`` XPlane capture through the same
@@ -49,21 +57,30 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = [
-    "DEVICE_KERNELS", "DEVICE_SCOPES", "DEVICE_SUBSCOPES", "SITES", "add_span", "configure",
+    "CompileTotals", "DEVICE_KERNELS", "DEVICE_SCOPES", "DEVICE_SUBSCOPES", "SITES",
+    "add_span", "compile_delta", "compile_span", "compile_totals", "configure",
     "enabled", "event", "install_from_env", "new_trace_id", "now",
-    "snapshot", "span",
+    "snapshot", "span", "wrapped",
 ]
 
-#: Span/event site catalogue — every ``trace.span("...")`` /
-#: ``trace.event("...")`` / ``trace.add_span("...")`` literal in the
+#: Span/event site catalogue — every ``trace.span(<site>)`` /
+#: ``trace.event(<site>)`` / ``trace.add_span(<site>)`` literal in the
 #: package must name an entry here, every entry must have a live call
 #: site, and docs/TRACING.md's table mirrors this tuple exactly (the
 #: analysis ``trace`` pass checks all directions).
 SITES = (
+    "hvd.import",          # the package's import, first line to last
+    "hvd.init",            # basics.init, the whole of it
+    "hvd.init.topology",   # jax.distributed + topology.discover: PJRT's client
+    "hvd.init.controller", # load_controller: the native core built or loaded
+    "jax.compile",         # one backend compile or persistent-cache load (the
+                           # process's one jax.monitoring recorder, below)
     "train.create_state",  # create_train_state: model.init + optimizer.init
+    "train.model_init",    # model.init alone (op by op: start-up's compiles)
+    "train.optimizer_init",  # optimizer.init and the step counter
     "train.replicate",     # replicate_state: the state placed over the mesh
     "train.step",          # fit_epoch loop body: dispatch + host work
     "data.wait",           # consumer wait on the prefetch queue
@@ -381,6 +398,111 @@ def snapshot(since: float = 0.0) -> List[tuple]:
     return out
 
 
+def wrapped() -> bool:
+    """Whether any live ring has overwritten a record: a reader that sums
+    a site over the whole process (the benchmark's ``program_span``) has
+    the whole of it only while this is False."""
+    with _rings_lock:
+        return any(r.idx > r.cap for r in _rings)
+
+
+# -- the process's one compile recorder ---------------------------------------
+#
+# jax reports every backend compile through jax.monitoring, on the thread
+# that asked for it.  ONE duration listener turns each into a
+# ``jax.compile`` ring record with explicit extents and keeps the process
+# totals.  Which span caused a compile is read off the ring afterwards, by
+# time and thread (``export.enclosing``): span()'s hot path knows nothing
+# of it.
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class CompileTotals(NamedTuple):
+    """The process's compile totals since the recorder was installed."""
+
+    compiles: int = 0        # backend_compile_duration events: compiles AND cache loads
+    compile_s: float = 0.0   # their seconds (a load reports its load time)
+    cache_hits: int = 0      # of them, loads from the persistent cache
+
+
+_totals = CompileTotals()
+_totals_lock = threading.Lock()
+_recorder_installed = False
+
+
+def _on_duration(name: str, seconds: float, **kw) -> None:
+    global _totals
+    if not _enabled:
+        return
+    if name == _COMPILE_EVENT:
+        # a persistent-cache hit reports its retrieval time on this thread
+        # just before the compile event that wraps it (jax/_src/compiler.py
+        # compile_or_get_cached): that is how a load is told from a compile
+        cached = getattr(_local, "cache_load", False)
+        _local.cache_load = False
+        with _totals_lock:
+            _totals = CompileTotals(_totals.compiles + 1,
+                                    _totals.compile_s + seconds,
+                                    _totals.cache_hits + cached)
+        end = time.perf_counter()
+        add_span("jax.compile", end - seconds, end,
+                 fun=str(kw.get("fun_name", "")), cached=cached)
+    elif name == _CACHE_LOAD_EVENT:
+        _local.cache_load = True
+
+
+def _install_compile_recorder() -> None:
+    """Register the listener, once a process, when tracing is enabled and
+    jax is ALREADY loaded (never imported from here)."""
+    global _recorder_installed
+    if _recorder_installed or not _enabled or "jax" not in sys.modules:
+        return
+    with _totals_lock:
+        if not _recorder_installed:
+            from jax import monitoring
+
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _recorder_installed = True
+
+
+def compile_totals() -> CompileTotals:
+    """The process's compile totals (zeros under ``HVD_TPU_TRACE=0``, and
+    until jax is loaded).  A span that wants the compiles it paid takes
+    the totals before and stamps :func:`compile_delta` on exit
+    (:func:`compile_span` does both)."""
+    _install_compile_recorder()
+    return _totals
+
+
+def compile_delta(before: CompileTotals) -> Dict[str, Any]:
+    """``compiles``, ``compile_s`` and ``cache_hits`` since ``before``: the
+    args a start-up span carries."""
+    after = compile_totals()
+    return {"compiles": after.compiles - before.compiles,
+            "compile_s": after.compile_s - before.compile_s,
+            "cache_hits": after.cache_hits - before.cache_hits}
+
+
+@contextlib.contextmanager
+def compile_span(site: str, /, **args):
+    """:func:`span` whose record also carries the compile totals'
+    difference over its extents (``compiles``, ``compile_s``,
+    ``cache_hits``).  For start-up's spans, entered once a process: a
+    generator's cost is nothing there.  Yields the span (``.set(...)``
+    adds args), or None when tracing is off."""
+    if not _enabled:
+        yield None
+        return
+    before = compile_totals()
+    with span(site, **args) as sp:
+        try:
+            yield sp
+        finally:
+            sp.set(**compile_delta(before))
+
+
 def epoch_us(t: float) -> float:
     """Map a ``now()``-clock time to epoch microseconds (export axis)."""
     return (_WALL0 + (t - _PERF0)) * 1e6
@@ -417,9 +539,11 @@ def configure(enabled: Optional[bool] = None,
 
 def install_from_env(rank: int = 0, host: Optional[str] = None) -> bool:
     """Init-time hook (``hvd.init()``): resolve the env switches, stamp
-    the rank/host the export and flight bundles carry, mount the
-    ``/trace`` control endpoint, and baseline the flight recorder's
-    metric snapshot.  Returns whether recording is enabled."""
+    the rank/host the export and flight bundles carry, switch the compile
+    recorder on (once a process, however often this runs; never under
+    ``HVD_TPU_TRACE=0``), mount the ``/trace`` control endpoint, and
+    baseline the flight recorder's metric snapshot.  Returns whether
+    recording is enabled."""
     global _enabled, _ring_cap, _rank, _host
     _enabled = os.environ.get(ENV_TRACE, "1") != "0"
     _ring_cap = max(256, _env_int(ENV_RING, 16384))
@@ -429,6 +553,7 @@ def install_from_env(rank: int = 0, host: Optional[str] = None) -> bool:
 
         host = socket.gethostname()
     _host = host
+    _install_compile_recorder()
     from . import export as _export
     from . import flight as _flight
 

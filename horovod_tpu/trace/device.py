@@ -26,9 +26,13 @@ The rules, stated and not hidden:
   ZeRO's reduce-scatter / all-gather under ``optimizer/exchange`` is the
   exchange;
 * the backward has no scope of its own: it is the transpose of the
-  forward scope, ``transpose(jvp(forward))``; a ``rematted_computation``
-  or ``checkpoint`` component beneath it is backward time and is also
-  reported as ``recompute``;
+  forward scope, ``transpose(jvp(forward))``, and everything beneath it:
+  under ``jax.checkpoint`` jax writes a second ``jvp(forward)`` BENEATH
+  the transpose (``transpose(jvp(forward))/jvp(forward)/checkpoint/...``:
+  the block linearised again in the backward pass), which is backward
+  time; of it, what lies under ``rematted_computation`` is the forward
+  made again and is also reported as ``recompute`` (the rest of
+  ``checkpoint/...`` is the block's own backward);
 * a fusion takes the phase of the one ``op_name`` XLA leaves on it (its
   root's): a fusion that merged work of two phases counts under one;
 * nested events (a ``while`` and the operations of its body) are not
@@ -82,7 +86,7 @@ _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
 # operands: %names not handed over as an attribute (calls=%c, to_apply=%c)
 _OPERAND_RE = re.compile(r"(?<![=\w])%([\w.\-]+)")
-_RECOMPUTE = ("rematted_computation", "checkpoint")
+_RECOMPUTE = "rematted_computation"
 _FORWARD = DEVICE_SCOPES[0]
 # the forward scope inside transforms: jvp(forward), transpose(jvp(forward))
 _WRAPPED_FORWARD = re.compile(rf"^((?:[\w.]+\()+){_FORWARD}\)+$")
@@ -97,9 +101,12 @@ def classify(op_name: str) -> Tuple[str, bool]:
         if part in DEVICE_SCOPES:
             phase, recompute = part, False
         elif wrapped:
-            # jvp(forward), transpose(jvp(forward)), ...
+            # jvp(forward), transpose(jvp(forward)), ...; the outermost
+            # transform names the pass: a jvp(forward) beneath the
+            # transpose is a checkpointed block linearised again, backward
             transposed = "transpose(" in wrapped.group(1)
-            phase, recompute = "backward" if transposed else _FORWARD, False
+            if transposed or phase != "backward":
+                phase, recompute = "backward" if transposed else _FORWARD, False
         elif phase == "backward" and part.startswith(_RECOMPUTE):
             recompute = True
     return phase, recompute
@@ -232,11 +239,13 @@ def phase_table(compiled) -> Dict[str, Tuple[str, bool, Tuple[str, ...]]]:
     without one, or with one of its own making outside every scope
     (``jit(_step)/shard_map/convert.126``); such an instruction takes,
     in this order, the phase of the computation it calls (a fusion: its
-    root's ``op_name``, else the phase most of its instructions name) or
+    root's ``op_name``, else the phase most of its instructions name),
     the one phase its attributed operands agree on (a relayout between
-    two backward operations is backward).  What is left — parameters,
-    constants, the prefetch copies of the weights — stays
-    ``unattributed``.  ``also`` lists the OTHER phases whose
+    two backward operations is backward) or, last, the one phase its
+    users agree on (the loss's backward scatter, which the compiler
+    rewrites without metadata, feeds backward products alone).  What is
+    left — parameters, constants, copies of weights that the forward and
+    the backward both read — stays ``unattributed``.  ``also`` lists the OTHER phases whose
     instructions a fusion holds: XLA fuses AdamW's update into the
     weight-gradient matmul that feeds it, and that fusion's time cannot
     be split."""
@@ -276,6 +285,21 @@ def phase_table(compiled) -> Dict[str, Tuple[str, bool, Tuple[str, ...]]]:
                 (got,) = agreed
         also = tuple(sorted({phase for phase, _ in votes} - {got[0]}))
         table[ins.name] = got + (also,)
+    # last, uses before definitions: what is still unnamed takes the one
+    # phase its users agree on (the compiler rewrites the loss's scatter
+    # and the cast of its result with no metadata at all; both feed the
+    # head's backward products alone)
+    users: Dict[str, List[str]] = {}
+    for ins in instrs:
+        for operand in ins.operands:
+            users.setdefault(operand, []).append(ins.name)
+    for ins in reversed(instrs):
+        if table[ins.name][:2] != nothing:
+            continue
+        agreed = {table[u][:2] for u in users.get(ins.name, ())
+                  if table[u][:2] != nothing}
+        if len(agreed) == 1:
+            table[ins.name] = agreed.pop() + (table[ins.name][2],)
     return table
 
 

@@ -4,7 +4,10 @@ One record format (docs/TRACING.md): the ring's ``(site, t0, dur,
 args, tid)`` tuples render as Chrome trace-event JSON — ``ph="X"``
 complete spans, ``ph="i"`` instants — with ``pid`` = the rank and
 ``tid`` = the recording thread, timestamps in epoch microseconds.  The
-result loads directly in ui.perfetto.dev / ``chrome://tracing``.
+result loads directly in ui.perfetto.dev / ``chrome://tracing``.  Spans
+nest by their extents alone (``hvd.init`` over its children, a
+``jax.compile`` record under the span it fell in): no ids; events are
+written parent first.
 
 ``GET /trace`` serves the live export from the PR-1 exposition
 endpoint.  Like every mutating-or-verbose control surface (the PR-13
@@ -28,9 +31,29 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from . import epoch_us, host, rank, snapshot
 
 __all__ = [
-    "chrome_trace", "merge_ranks", "register_trace_endpoint",
+    "chrome_trace", "enclosing", "merge_ranks", "register_trace_endpoint",
     "request_decomposition", "write_dump",
 ]
+
+
+def enclosing(records: Sequence[tuple], rec: tuple,
+              site: Optional[str] = None) -> Optional[tuple]:
+    """The innermost span of ``records`` (:func:`~horovod_tpu.trace.
+    snapshot` tuples) that holds ``rec``: same thread, extents around
+    ``rec``'s; of ``site`` alone where given.  This is how a record is
+    attributed to the span that caused it (a ``jax.compile`` to its
+    ``train.step``: which step recompiled): by time and thread, read off
+    the ring afterwards; the recorder keeps no span stack."""
+    t0, t1 = rec[1], rec[1] + (rec[2] or 0.0)
+    best = None
+    for other in records:
+        if (other == rec or other[2] is None or other[4] != rec[4]
+                or (site is not None and other[0] != site)):
+            continue
+        if other[1] <= t0 and t1 <= other[1] + other[2] and (
+                best is None or other[2] < best[2]):
+            best = other
+    return best
 
 
 def chrome_trace(since: float = 0.0,
@@ -40,6 +63,9 @@ def chrome_trace(since: float = 0.0,
     dict.  ``pid`` defaults to the installed rank."""
     pid = rank() if pid is None else int(pid)
     recs = snapshot(since) if records is None else list(records)
+    # a parent before the children that start with it: viewers nest
+    # complete events of one thread by their extents, in this order
+    recs.sort(key=lambda r: (r[1], -(r[2] or 0.0)))
     tids: Dict[str, int] = {}
     events: List[dict] = [{
         "name": "process_name", "ph": "M", "pid": pid, "tid": 0,

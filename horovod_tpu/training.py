@@ -70,60 +70,30 @@ def _mean_written_stats(new_stats, old_stats, mean: Callable):
     return treedef.unflatten(leaves)
 
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# backend compiles of this process, [count, seconds]: fed by ONE
-# jax.monitoring listener, registered the first time a traced
-# create_train_state asks (a persistent-cache hit reports its load time
-# under the same event)
-_compiles = [0, 0.0]
-_compile_listener_on = False
-
-
-def _compile_totals():
-    global _compile_listener_on
-    if not _compile_listener_on:
-        _compile_listener_on = True
-
-        def on_duration(name, seconds, **_):
-            if name == _COMPILE_EVENT:
-                _compiles[0] += 1
-                _compiles[1] += seconds
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-    return _compiles[0], _compiles[1]
-
-
 def create_train_state(
     model, optimizer: optax.GradientTransformation, rng, sample_input
 ) -> TrainState:
     """Initialize the model and the optimizer state.  Recorded at the
     ``train.create_state`` site with the parameter count and the backend
-    compiles the call paid (``model.init`` runs op by op: this is where
-    a job's start-up time goes)."""
-    if not trace.enabled():
-        return _create_train_state(model, optimizer, rng, sample_input)
-    c0, s0 = _compile_totals()
-    with trace.span("train.create_state") as sp:
-        state = _create_train_state(model, optimizer, rng, sample_input)
-        c1, s1 = _compile_totals()
-        sp.set(
-            params=sum(int(x.size) for x in
-                       jax.tree_util.tree_leaves(state.params)),
-            compiles=c1 - c0, compile_s=s1 - s0,
-        )
+    compiles the call paid (``trace.compile_totals``: the process's one
+    recorder), and inside it ``train.model_init`` (``model.init`` runs op
+    by op: this is where a job's start-up time goes) and
+    ``train.optimizer_init``, each with its own compiles."""
+    with trace.compile_span("train.create_state") as sp:
+        with trace.compile_span("train.model_init"):
+            variables = model.init(rng, sample_input)
+        params = variables["params"]
+        with trace.compile_span("train.optimizer_init"):
+            state = TrainState(
+                step=jnp.zeros((), jnp.int32),
+                params=params,
+                opt_state=optimizer.init(params),
+                batch_stats=variables.get("batch_stats"),
+            )
+        if sp is not None:
+            sp.set(params=sum(int(x.size) for x in
+                              jax.tree_util.tree_leaves(params)))
     return state
-
-
-def _create_train_state(model, optimizer, rng, sample_input) -> TrainState:
-    variables = model.init(rng, sample_input)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats")
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        opt_state=optimizer.init(params),
-        batch_stats=batch_stats,
-    )
 
 
 def data_parallel_train_step(
@@ -298,23 +268,26 @@ def zero_train_setup(
         world = int(mesh.shape[axis])
         zopt = ZeroSpmdOptimizer(inner_optimizer, axis=axis, op=op)
 
-    variables = model.init(rng, sample_input)
+    # the split create_train_state records (docs/TRACING.md)
+    with trace.compile_span("train.model_init"):
+        variables = model.init(rng, sample_input)
     params = variables["params"]
     batch_stats = variables.get("batch_stats")
-    ospecs = zero_opt_state_specs(
-        inner_optimizer, params, world, axis,
-        dcn_compression=dcn_compression if hierarchical else None,
-    )
-    opt_state = jax.jit(jax.shard_map(
-        zopt.init, mesh=mesh, in_specs=(P(),), out_specs=ospecs,
-        check_vma=False,
-    ))(params)
-    state = TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        opt_state=opt_state,
-        batch_stats=batch_stats,
-    )
+    with trace.compile_span("train.optimizer_init"):
+        ospecs = zero_opt_state_specs(
+            inner_optimizer, params, world, axis,
+            dcn_compression=dcn_compression if hierarchical else None,
+        )
+        opt_state = jax.jit(jax.shard_map(
+            zopt.init, mesh=mesh, in_specs=(P(),), out_specs=ospecs,
+            check_vma=False,
+        ))(params)
+        state = TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            opt_state=opt_state,
+            batch_stats=batch_stats,
+        )
     state_specs = TrainState(
         step=P(),
         params=P(),

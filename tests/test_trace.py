@@ -551,6 +551,310 @@ def test_create_state_untraced_records_nothing_and_returns_the_same():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# -- start-up on the ring; the process's one compile recorder (ISSUE 37) ------
+
+
+def _ours(listeners):
+    return [f for f in listeners
+            if getattr(f, "__module__", "") == "horovod_tpu.trace"]
+
+
+def test_a_compile_inside_a_span_is_a_record_inside_its_extents():
+    import jax
+    import jax.numpy as jnp
+
+    def never_compiled_before_issue37(x):
+        return x * 37.0 + 1.0
+
+    t0 = trace.now()
+    before = trace.compile_totals()
+    with trace.span("train.step", step=370):
+        jax.jit(never_compiled_before_issue37)(jnp.ones((3, 7))).block_until_ready()
+    recs = trace.snapshot(since=t0 - 1.0)
+    (span,) = [r for r in recs if r[0] == "train.step"
+               and (r[3] or {}).get("step") == 370]
+    (mine,) = [r for r in recs if r[0] == "jax.compile"
+               and r[3]["fun"] == "jit(never_compiled_before_issue37)"]
+    assert mine[4] == span[4]                       # the thread that asked
+    assert span[1] <= mine[1] and mine[1] + mine[2] <= span[1] + span[2]
+    assert mine[2] > 0 and mine[3]["cached"] in (False, True)
+    assert trace_export.enclosing(recs, mine, "train.step") == span
+    # the totals moved by what the ring holds of this stretch
+    delta = trace.compile_delta(before)
+    inside = [r for r in recs if r[0] == "jax.compile" and r[1] >= span[1]]
+    assert delta["compiles"] == len(inside) >= 1
+    assert delta["compile_s"] == pytest.approx(sum(r[2] for r in inside))
+    assert delta["cache_hits"] == sum(r[3]["cached"] for r in inside)
+
+
+def test_compile_span_stamps_the_totals_difference_and_is_null_when_off():
+    import jax
+    import jax.numpy as jnp
+
+    t0 = trace.now()
+    with trace.compile_span("train.optimizer_init", kind="issue37") as sp:
+        jax.jit(lambda x: x - 37.5)(jnp.ones((5,))).block_until_ready()
+        sp.set(extra=1)
+    (rec,) = [r for r in trace.snapshot(since=t0)
+              if r[0] == "train.optimizer_init"]
+    inside = [r for r in trace.snapshot(since=t0) if r[0] == "jax.compile"]
+    assert rec[3]["kind"] == "issue37" and rec[3]["extra"] == 1
+    assert rec[3]["compiles"] == len(inside) >= 1
+    assert rec[3]["compile_s"] == pytest.approx(sum(r[2] for r in inside))
+    trace.configure(enabled=False)
+    t1 = trace.now()
+    before = trace.compile_totals()
+    with trace.compile_span("train.optimizer_init") as sp:
+        assert sp is None
+        jax.jit(lambda x: x - 38.5)(jnp.ones((5,))).block_until_ready()
+    assert trace.compile_totals() == before        # the listener returns at once
+    trace.configure(enabled=True)
+    assert trace.snapshot(since=t1) == []
+
+
+def test_a_compile_forced_inside_fit_epoch_gives_its_step_number():
+    import jax.numpy as jnp
+
+    from horovod_tpu import training
+
+    state, step, x, y = _tiny_step()
+    base = int(state.step)
+    wide = (jnp.concatenate([x, x]), jnp.concatenate([y, y]))  # another shape
+    t0 = trace.now()
+    state, _ = training.fit_epoch(step, state, [(x, y), (x, y), wide, wide])
+    recs = trace.snapshot(since=t0)
+    steps = [trace_export.enclosing(recs, r, "train.step")[3]["step"]
+             for r in recs if r[0] == "jax.compile"
+             and r[3]["fun"] == "jit(_step)"]
+    # the first step compiles, the third compiles again; no other does
+    assert steps == [base + 1, base + 3]
+
+
+def test_model_and_optimizer_init_lie_inside_create_state_and_hold_its_compiles():
+    t0 = trace.now()
+    _tiny_step()
+    recs = trace.snapshot(since=t0)
+    by = {r[0]: r for r in recs if r[0] in (
+        "train.create_state", "train.model_init", "train.optimizer_init")}
+    parent, model, optim = (by["train.create_state"], by["train.model_init"],
+                            by["train.optimizer_init"])
+    for child in (model, optim):
+        assert child[4] == parent[4]
+        assert parent[1] <= child[1]
+        assert child[1] + child[2] <= parent[1] + parent[2]
+        assert trace_export.enclosing(recs, child) == parent
+    assert model[1] + model[2] <= optim[1]
+    for key in ("compiles", "cache_hits"):
+        assert model[3][key] + optim[3][key] == parent[3][key]
+    assert model[3]["compile_s"] + optim[3]["compile_s"] == pytest.approx(
+        parent[3]["compile_s"])
+    # and the ring says the same: a jax.compile record each
+    inside = [r for r in recs if r[0] == "jax.compile"
+              and trace_export.enclosing(recs, r, "train.create_state")]
+    assert len(inside) == parent[3]["compiles"]
+
+
+def test_zero_train_setup_records_the_same_split():
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu import training
+
+    t0 = trace.now()
+    training.zero_train_setup(
+        nn.Dense(4), optax.adam(1e-3), jax.random.PRNGKey(0),
+        jnp.ones((1, 8), jnp.float32))
+    sites = [r[0] for r in trace.snapshot(since=t0)
+             if r[0].startswith("train.")]
+    assert sites == ["train.model_init", "train.optimizer_init"]
+
+
+def test_the_export_nests_a_compile_under_the_span_that_holds_it():
+    import jax
+    import jax.numpy as jnp
+
+    t0 = trace.now()
+    with trace.span("train.step", step=371):
+        jax.jit(lambda x: x / 37.1)(jnp.ones((2,))).block_until_ready()
+    events = [e for e in trace_export.chrome_trace(since=t0)["traceEvents"]
+              if e.get("ph") == "X"]
+    (outer,) = [e for e in events if e["name"] == "train.step"]
+    inner = [e for e in events if e["name"] == "jax.compile"]
+    assert inner and all(e["tid"] == outer["tid"] for e in inner)
+    for e in inner:     # inside the parent's extents, written after it
+        assert outer["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+        assert events.index(outer) < events.index(e)
+        assert e["cat"] == "jax" and "fun" in e["args"]
+    # equal starts: the longer span first, as viewers nest by extents
+    recs = [("hvd.init.topology", 5.0, 1.0, None, "t"),
+            ("hvd.init", 5.0, 2.0, None, "t")]
+    names = [e["name"] for e in trace_export.chrome_trace(records=recs)
+             ["traceEvents"] if e.get("ph") == "X"]
+    assert names == ["hvd.init", "hvd.init.topology"]
+
+
+def test_the_tool_prints_the_set_up_table_from_the_ring():
+    """``tools/profile_capture.py``'s set-up table (the formatter lives in
+    the tool, its only caller): a row a start-up span, nested by depth,
+    the compile records summed, a recompile named with its step."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "profile_capture", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "profile_capture.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    paid = {"compiles": 2, "compile_s": 1.5, "cache_hits": 1}
+    recs = [
+        ("hvd.init", 0.0, 3.0, dict(paid, compiles=0), "t"),
+        ("hvd.init.controller", 1.0, 2.0,
+         dict(paid, native=True, built=True, build_s=1.9), "t"),
+        ("train.model_init", 4.0, 2.0, paid, "t"),
+        ("jax.compile", 4.1, 1.25, {"fun": "jit(init)", "cached": False}, "t"),
+        ("jax.compile", 5.5, 0.25, {"fun": "jit(init)", "cached": True}, "t"),
+        ("train.step", 7.0, 1.0, {"step": 12}, "t"),
+        ("jax.compile", 7.1, 0.5, {"fun": "jit(_step)", "cached": False}, "t"),
+    ]
+    rows = tool.format_startup(recs).splitlines()
+    assert [r.split()[0] for r in rows[1:5]] == [
+        "hvd.init", "hvd.init.controller", "train.model_init", "jax.compile"]
+    assert rows[2].startswith("    hvd.init.controller") and "build_s 1.9" in rows[2]
+    assert "2 compiles 1.500 s, 1 from the cache" in rows[3]
+    assert "3 records: 2 compiled, 1 loaded" in rows[4] and "in 0.250 s" in rows[4]
+    (step,) = [r for r in rows if "jit(_step)" in r]
+    assert step.endswith("compiled inside train.step 12")
+
+
+def test_install_from_env_registers_one_recorder_however_often():
+    from jax._src import monitoring     # the public module lists nothing
+
+    for _ in range(3):
+        trace.install_from_env(rank=0)
+        trace.compile_totals()
+    assert len(_ours(monitoring.get_event_duration_listeners())) == 1
+    assert _ours(monitoring.get_event_listeners()) == []
+    # and nothing else of the package listens for compile events
+    other = [f for f in monitoring.get_event_duration_listeners()
+             if getattr(f, "__module__", "").startswith("horovod_tpu")
+             and f not in _ours([f])]
+    assert other == []
+    from horovod_tpu import training
+
+    assert not hasattr(training, "_compile_totals")
+
+
+_STARTUP_SCRIPT = r"""
+import json, os, sys, tempfile
+import horovod_tpu as hvd
+from horovod_tpu import trace
+import jax, jax.numpy as jnp
+from jax._src import monitoring
+jax.config.update("jax_compilation_cache_dir", tempfile.mkdtemp())
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+hvd.init(); hvd.init()
+trace.install_from_env(rank=0)
+def first(x): return x * 2.0 + 37.0
+def second(x): return x * 2.0 + 37.0
+first.__name__ = second.__name__ = "same_program"
+with trace.span("train.step", step=1):
+    jax.jit(first)(jnp.ones(4)).block_until_ready()    # compiled, written
+    jax.jit(second)(jnp.ones(4)).block_until_ready()   # the same bytes: loaded
+ours = lambda fs: sum(getattr(f, "__module__", "") == "horovod_tpu.trace" for f in fs)
+print("RESULT " + json.dumps({
+    "records": trace.snapshot(),
+    "totals": trace.compile_totals()._asdict(),
+    "duration_listeners": ours(monitoring.get_event_duration_listeners()),
+    "event_listeners": ours(monitoring.get_event_listeners()),
+    "wrapped": trace.wrapped()}))
+"""
+
+
+@pytest.fixture(scope="module", params=["1", "0"])
+def startup_run(request):
+    """A fresh process: import, ``hvd.init()`` twice, a program compiled
+    and then loaded from a persistent cache of its own; under
+    ``HVD_TPU_TRACE`` on and off."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, HVD_TPU_TRACE=request.param, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run([sys.executable, "-c", _STARTUP_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    (line,) = [ln for ln in out.stdout.splitlines()
+               if ln.startswith("RESULT ")]
+    return request.param, json.loads(line[len("RESULT "):])
+
+
+def test_start_up_is_on_the_ring_and_nested(startup_run):
+    flag, got = startup_run
+    recs = [tuple(r) for r in got["records"]]
+    if flag == "0":
+        assert recs == [] and not got["wrapped"]
+        return
+    by = {}
+    for r in recs:
+        by.setdefault(r[0], []).append(r)
+    for site in ("hvd.import", "hvd.init", "hvd.init.topology",
+                 "hvd.init.controller"):
+        assert len(by[site]) == 1, site          # the second init() is a no-op
+    imp, init = by["hvd.import"][0], by["hvd.init"][0]
+    topo, ctrl = by["hvd.init.topology"][0], by["hvd.init.controller"][0]
+    assert imp[3]["jax_loaded"] is False and imp[2] > 0.1
+    assert imp[1] + imp[2] <= init[1]
+    for child in (topo, ctrl):
+        assert trace_export.enclosing(recs, child) == init
+        assert {"compiles", "compile_s", "cache_hits"} <= set(child[3])
+    assert topo[1] + topo[2] <= ctrl[1]
+    assert ctrl[3]["native"] in (True, False) and "built" in ctrl[3]
+    assert ("build_s" in ctrl[3]) == ctrl[3]["built"]
+
+
+def test_one_recorder_a_process_and_none_when_tracing_is_off(startup_run):
+    flag, got = startup_run
+    want = 1 if flag == "1" else 0
+    assert got["duration_listeners"] == want
+    assert got["event_listeners"] == 0          # the one listener is the whole of it
+    if flag == "0":
+        assert got["totals"] == trace.CompileTotals()._asdict()
+
+
+def test_a_load_from_the_persistent_cache_is_told_from_a_compile(startup_run):
+    flag, got = startup_run
+    if flag == "0":
+        assert got["records"] == []
+        return
+    mine = [r for r in got["records"] if r[0] == "jax.compile"
+            and r[3]["fun"] == "jit(same_program)"]
+    assert [r[3]["cached"] for r in mine] == [False, True]
+    totals = got["totals"]
+    assert set(totals) == {"compiles", "compile_s", "cache_hits"}
+    assert totals["cache_hits"] >= 1
+    assert totals["compiles"] == sum(r[0] == "jax.compile"
+                                     for r in got["records"])
+    assert totals["cache_hits"] == sum(
+        r[0] == "jax.compile" and r[3]["cached"] for r in got["records"])
+
+
+def test_wrapped_says_when_a_ring_has_overwritten(monkeypatch):
+    monkeypatch.setattr(trace, "_rings", [])
+    monkeypatch.setattr(trace, "_local", threading.local())
+    monkeypatch.setattr(trace, "_ring_cap", 256)
+    for i in range(256):
+        trace.event("chaos.inject", n=i)
+    assert not trace.wrapped()
+    trace.event("chaos.inject", n=256)
+    assert trace.wrapped()
+
+
 # -- device names and their reducer (ISSUE 24) -------------------------------
 
 
@@ -565,6 +869,16 @@ def test_create_state_untraced_records_nothing_and_returns_the_same():
      "rematted_computation/dot_general", ("backward", True)),
     ("jit(_step)/jvp(forward)/Block/checkpoint/dot_general",
      ("forward", False)),           # recompute only beneath the backward
+    # jax 0.9 under jax.checkpoint: a second jvp(forward) BENEATH the
+    # transpose is the block linearised again: backward, and of it only
+    # rematted_computation is the forward made again
+    ("jit(_step)/shard_map/transpose(jvp(forward))/jvp(forward)/Transformer/"
+     "layer_0/linear_attn/checkpoint/gdn/dot_general", ("backward", False)),
+    ("jit(_step)/shard_map/transpose(jvp(forward))/jvp(forward)/Transformer/"
+     "layer_0/linear_attn/checkpoint/rematted_computation/gdn/tanh",
+     ("backward", True)),
+    ("jit(_step)/transpose(jvp(forward))/jvp(forward)/remat2",
+     ("backward", False)),
     ("jit(_step)/shard_map/exchange/psum", ("exchange", False)),
     ("jit(_step)/shard_map/optimizer/mul", ("optimizer", False)),
     ("jit(_step)/shard_map/optimizer/exchange/reduce_scatter",
@@ -574,6 +888,40 @@ def test_create_state_untraced_records_nothing_and_returns_the_same():
 ])
 def test_classify_op_name(op_name, want):
     assert trace_device.classify(op_name) == want
+
+
+def test_a_checkpointed_block_in_a_compiled_step_is_backward_beneath_the_transpose():
+    """What jax 0.9 really writes (not a made-up path): the reducer read a
+    rematerialised mixer's backward as forward until ISSUE 37's first phase
+    row of Qwen3-Next showed it."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    def loss(w, x):
+        with jax.named_scope("forward"):
+            @jax.checkpoint
+            def block(x):
+                with jax.named_scope("gdn"):
+                    return jnp.tanh(x @ w) @ w
+            return jnp.sum(block(x) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        jnp.ones((8, 8)), jnp.ones((4, 8))).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("(jit\(loss\)/[^"]*)"', text))
+    beneath = [n for n in names if "/transpose(jvp(forward))/" in n]
+    assert any("/jvp(forward)/checkpoint/" in n for n in beneath)
+    assert all(trace_device.classify(n)[0] == "backward" for n in beneath)
+    remade = [n for n in beneath if "/rematted_computation/" in n]
+    assert remade and all(
+        trace_device.classify(n) == ("backward", True) for n in remade)
+    own = [n for n in beneath if "/checkpoint/gdn/" in n]
+    assert own and all(
+        trace_device.classify(n) == ("backward", False) for n in own)
+    above = [n for n in names if n not in beneath and "forward" in n]
+    assert above and all(
+        trace_device.classify(n) == ("forward", False) for n in above)
 
 
 def test_phase_table_classifies_a_compiled_cpu_step():
@@ -636,6 +984,37 @@ ENTRY %main (x: f32[4]) -> f32[4] {
     assert table["copy.2"] == ("unattributed", False, ())
     assert table["mul.1"] == ("optimizer", False, ())
     assert table["add.2"] == ("unattributed", False, ())  # operands disagree
+
+
+def test_phase_table_gives_an_unnamed_instruction_the_phase_its_users_agree_on():
+    """The compiler rewrites the loss's backward scatter, and the cast of
+    its result, with no metadata at all (Kimi's ``fusion.119`` ->
+    ``reshape.500``, 2.2 ms a step; my chip run, PR 37): both feed the
+    head's backward products alone.  A copy of a weight that the forward and
+    the backward both read stays unattributed."""
+    text = """HloModule m
+%fused_computation.119 (p0: f32[8], p1: s32[2]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  %p1 = s32[2]{0} parameter(1)
+  ROOT %scatter.5 = f32[8]{0} scatter(%p0, %p1), to_apply=%region
+}
+ENTRY %main (w: f32[8], labels: s32[2]) -> f32[8] {
+  %w = f32[8]{0} parameter(0)
+  %labels = s32[2]{0} parameter(1)
+  %zeros = f32[8]{0} broadcast(%w)
+  %copy.7 = f32[8]{0} copy(%w)
+  %fwd.1 = f32[8]{0} add(%copy.7, %copy.7), metadata={op_name="jit(_step)/jvp(forward)/add"}
+  %fusion.119 = f32[8]{0} fusion(%zeros, %labels), kind=kCustom, calls=%fused_computation.119
+  %reshape.500 = f32[8]{0} reshape(%fusion.119)
+  %dx.1 = f32[8]{0} multiply(%reshape.500, %copy.7), metadata={op_name="jit(_step)/transpose(jvp(forward))/mul"}
+  ROOT %dw.1 = f32[8]{0} multiply(%reshape.500, %fwd.1), metadata={op_name="jit(_step)/transpose(jvp(forward))/mul"}
+}
+"""
+    table = trace_device.phase_table(text)
+    assert table["reshape.500"] == ("backward", False, ())
+    assert table["fusion.119"] == ("backward", False, ())   # through its user
+    assert table["copy.7"] == ("unattributed", False, ())   # users disagree
+    assert table["fwd.1"][0] == "forward" and table["dw.1"][0] == "backward"
 
 
 def test_reduce_phases_sums_to_the_busy_time():

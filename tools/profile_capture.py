@@ -16,7 +16,10 @@ collectives (``hvd_tpu::<name>::ENQUEUE`` / ``hvd_tpu::<op>::XLA_COMM``
 spans next to XLA's own op activity; SURVEY.md §5.1) and a few steps of a
 small compiled train step through ``fit_epoch`` (``hvd_tpu::train.step``
 as a step annotation, the phase scopes inside the program) — then print
-the same table.  The phases need a TPU's device plane: on the virtual CPU
+the set-up table (start-up by the program's own spans: ``hvd.import``,
+``hvd.init``, ``train.create_state`` and their children, the
+``jax.compile`` records; from ``trace.snapshot()``, so only in the process
+that made the capture) and the same phase table.  The phases need a TPU's device plane: on the virtual CPU
 mesh (the default without ``JAX_PLATFORMS``) only the capture is written.
 
     tensorboard --logdir <dir>           # Profile plugin, or load
@@ -80,6 +83,50 @@ def capture(logdir: str) -> None:
     trace_export.write_dump(chrome, since=since)
     print(f"trace written under {logdir}/plugins/profile/")
     print(f"framework spans (Chrome trace-event JSON): {chrome}")
+    # start-up by the program's own spans, beside the phase table below:
+    # the same two tables the benchmark's readers_program.py reads
+    print(format_startup(trace.snapshot()))
+
+
+#: the start-up sites, in the order a job passes them (depth = nesting)
+_STARTUP = (
+    ("hvd.import", 0), ("hvd.init", 0), ("hvd.init.topology", 1),
+    ("hvd.init.controller", 1), ("train.create_state", 0),
+    ("train.model_init", 1), ("train.optimizer_init", 1),
+    ("train.replicate", 0),
+)
+
+
+def format_startup(records) -> str:
+    """The set-up table: a row a start-up span of ``records``
+    (``trace.snapshot()`` tuples; seconds, and the compiles it paid), then every ``jax.compile`` record summed (compiles against cache
+    loads) and the longest of them by ``fun``."""
+    from horovod_tpu.trace.export import enclosing
+
+    rows = ["start-up, by the program's own spans (s):"]
+    for site, depth in _STARTUP:
+        for rec in (r for r in records if r[0] == site):
+            args = dict(rec[3] or {})
+            paid = (f"  {args.pop('compiles')} compiles {args.pop('compile_s'):.3f} s, "
+                    f"{args.pop('cache_hits')} from the cache"
+                    if "compiles" in args else "")
+            rest = ", ".join(f"{k} {v}" for k, v in sorted(args.items()))
+            rows.append(f"  {'  ' * depth}{site:<{24 - 2 * depth}}{rec[2]:9.3f}"
+                        f"{paid}{'  (' + rest + ')' if rest else ''}")
+    compiles = [r for r in records if r[0] == "jax.compile" and r[3]]
+    loads = [r for r in compiles if r[3].get("cached")]
+    rows.append(
+        f"  {'jax.compile':<24}{sum(r[2] for r in compiles):9.3f}  "
+        f"{len(compiles)} records: {len(compiles) - len(loads)} compiled, "
+        f"{len(loads)} loaded from the persistent cache in "
+        f"{sum(r[2] for r in loads):.3f} s")
+    for rec in sorted(compiles, key=lambda r: -r[2])[:5]:
+        step = enclosing(records, rec, "train.step")
+        rows.append(
+            f"    {rec[3].get('fun', ''):<22}{rec[2]:9.3f}  "
+            f"{'loaded' if rec[3].get('cached') else 'compiled'}"
+            + (f" inside train.step {step[3].get('step')}" if step else ""))
+    return "\n".join(rows)
 
 
 def main(argv=None) -> int:
