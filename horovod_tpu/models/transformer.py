@@ -515,6 +515,14 @@ def causal_depthwise_conv(u, w):
     return nn.silu(acc).astype(u.dtype)
 
 
+def l2_unit(y, scale=1.0, eps=1e-6):
+    """``y / sqrt(sum(y^2) + eps) * scale`` over the last axis (a head), in
+    float32, rounded to ``y``'s dtype."""
+    y32 = y.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.sum(jnp.square(y32), axis=-1, keepdims=True) + eps)
+    return (y32 * (inv * scale)).astype(y.dtype)
+
+
 def _shard_size(cfg: TransformerConfig) -> int:
     """Bound size of ``cfg.shard_axis`` (1 when unset/unbound), with the
     divisibility contract checked at trace: every per-chip slice —
@@ -708,6 +716,15 @@ def _conv_init(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
+class _NormScale(nn.Module):
+    """``nn.RMSNorm``'s one parameter, under the name and with the
+    initialisation it has there, for a kernel that applies it itself."""
+
+    @nn.compact
+    def __call__(self, features):
+        return self.param("scale", nn.initializers.ones, (features,), jnp.float32)
+
+
 class GatedDeltaNet(nn.Module):
     """The linear-attention mixer of the ``qwen3_next`` family (Gated DeltaNet,
     arXiv:2412.06464): ``x`` (B, T, d_model) ->
@@ -721,13 +738,21 @@ class GatedDeltaNet(nn.Module):
          ``linear_conv_kernel_dim`` taps (``conv_kernel`` (taps, channels), no
          bias), then SiLU;
       3. q, k L2-normalised a head (eps 1e-6), q scaled by ``key_head_dim **
-         -0.5``, both repeated to the value heads;
+         -0.5``;
       4. ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``;
       5. the gated delta rule (``ops/gated_delta.py``), a state of ``key_head_dim
-         x value_head_dim`` a value head, in chunks of 64 with the carry a
-         Mosaic kernel ('flash' models) or a scan ('dot' models);
+         x value_head_dim`` a value head, each reading the key head it shares,
+         in chunks of 64 with the carry a Mosaic kernel ('flash' models) or a
+         scan ('dot' models);
       6. ``norm(o) * silu(z)`` (RMSNorm over a head in the plain form, one
          weight vector for all heads), then ``out_proj``.
+
+    'flash' models run steps 2-3 and the norm of step 6 as one Mosaic kernel
+    pair each (``ops/gdn_kernels.py``) on token-major rows: q, k, v reach the
+    rule's kernels, and ``o`` leaves them, as ``(B, T, heads x width)`` rows,
+    ``z`` is read out of ``in_proj_qkvz``'s rows in place, and XLA is left the
+    projections and the gates.  'dot' models keep the ``jnp`` functions
+    (``causal_depthwise_conv``, ``l2_unit``, ``nn.RMSNorm``).
 
     Steps 1-4 and 6 trace under ``jax.named_scope("gdn")``, step 5 under its
     sibling ``"gated_delta"``.  Training only: no recurrent-state cache, no
@@ -738,6 +763,7 @@ class GatedDeltaNet(nn.Module):
     @nn.compact
     def __call__(self, x):
         from ..ops.gated_delta import gated_delta_rule
+        from ..ops.gdn_kernels import gdn_conv_norm, gdn_gated_norm
         from ..parallel._mesh_utils import axis_size_or_1
 
         cfg = self.cfg
@@ -763,40 +789,55 @@ class GatedDeltaNet(nn.Module):
                                  2 * key_dim + value_dim), f32)
             a_log = self.param("A_log", _a_log_init, (hv,), f32)
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
-            z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, hv, dv)
 
-        def unit(y, scale=1.0):
-            y32 = y.astype(f32)
-            inv = jax.lax.rsqrt(
-                jnp.sum(jnp.square(y32), axis=-1, keepdims=True) + 1e-6)
-            return jnp.repeat((y32 * (inv * scale)).astype(cfg.dtype),
-                              hv // hk, axis=2)
+        kernels = cfg.attention_impl == "flash"
 
         # steps 2-5 keep their inputs alone for the backward, which makes
         # the convolution's, the norms' and the rule's own tensors again: at
-        # 8,192 tokens the benchmark's step is 13.6 GB so, 15.4 with the
-        # rule alone made again and 16.0 with nothing (PERF.md, PR 35)
+        # 8,192 tokens the benchmark's step is 12.69 GB so and 14.06 with
+        # nothing made again (the compiled step's ``memory_analysis()`` for a
+        # v5e, PR 38; 13.6 and 16.0 while these passes were XLA's, PR 35)
         @jax.checkpoint
         def mix(qkv, ba, conv_w, a_log, dt_bias):
             with jax.named_scope("gdn"):
-                mixed = causal_depthwise_conv(qkv, conv_w)
-                q = mixed[..., :key_dim].reshape(b, t, hk, dk)
-                k = mixed[..., key_dim:2 * key_dim].reshape(b, t, hk, dk)
-                v = mixed[..., 2 * key_dim:].reshape(b, t, hv, dv)
-                q, k = unit(q, dk ** -0.5), unit(k)
+                if kernels:
+                    # one pass, token-major rows out (ops/gdn_kernels.py)
+                    q, k, v = gdn_conv_norm(
+                        qkv, conv_w, key_heads=hk, key_head_dim=dk,
+                        value_heads=hv, value_head_dim=dv)
+                else:
+                    mixed = causal_depthwise_conv(qkv, conv_w)
+                    keys = lambda x: x.reshape(b, t, hk, dk)
+                    q = l2_unit(keys(mixed[..., :key_dim]), dk ** -0.5)
+                    k = l2_unit(keys(mixed[..., key_dim:2 * key_dim]))
+                    v = mixed[..., 2 * key_dim:]
                 beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
                 g = -jnp.exp(a_log) * jax.nn.softplus(
                     ba[..., hv:].astype(f32) + dt_bias)
             with jax.named_scope("gated_delta"):
-                return gated_delta_rule(
-                    q, k, v, g, beta,
-                    impl="kernel" if cfg.attention_impl == "flash" else "jnp")
+                # q, k at the key heads: the rule reads a value head's key
+                # head itself.  Out as rows where the next pass reads rows: the
+                # checkpoint's boundary holds what crosses it in the layout it
+                # has, and a 4-D ``o`` there costs a relayout each way
+                o = gated_delta_rule(
+                    q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk),
+                    v.reshape(b, t, hv, dv), g, beta,
+                    impl="kernel" if kernels else "jnp")
+                return o.reshape(b, t, value_dim) if kernels else o
 
-        o = mix(qkvz[..., :2 * key_dim + value_dim], ba, conv_w, a_log, dt_bias)
+        # the kernel reads [q | k | v] out of the projection's rows in place
+        o = mix(qkvz if kernels else qkvz[..., :2 * key_dim + value_dim], ba, conv_w,
+                a_log, dt_bias)
         with jax.named_scope("gdn"):
-            o = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
-                           name="norm")(o)
-            o = (o.astype(f32) * nn.silu(z.astype(f32))).astype(cfg.dtype)
+            if kernels:
+                # one pass on the rule's rows, z read out of the projection's
+                o = gdn_gated_norm(o, qkvz, _NormScale(name="norm")(dv), heads=hv,
+                                   eps=cfg.rms_norm_eps)
+            else:
+                z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, hv, dv)
+                o = nn.RMSNorm(dtype=cfg.dtype, epsilon=cfg.rms_norm_eps,
+                               name="norm")(o)
+                o = (o.astype(f32) * nn.silu(z.astype(f32))).astype(cfg.dtype)
             return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                             name="out_proj")(o.reshape(b, t, value_dim))
 

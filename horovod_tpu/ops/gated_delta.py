@@ -36,7 +36,17 @@ dtype once, where ``_prepare`` rounds it.
 and the layouts in and out): q, k, v go in token-major ``(B, T, H d)``, ``g`` and ``beta`` as rows a
 chunk ``(B, H, N, C)``, and a program is a (sequence, group of ``_BLOCK_HEADS``
 value heads) with the grid's last axis walking the sequence ``block`` chunks a
-step.
+step.  q and k may come at fewer heads than v (``Hk`` key heads, a divisor of
+the ``H`` value heads: Gated DeltaNet's 16 and 32): value head ``j`` reads key
+head ``j // (H / Hk)``.  Since PR 38 that repeat is the kernels' index map: a
+program's q and k blocks are its value heads' ``_BLOCK_HEADS / (H / Hk)`` key
+heads (``_key_heads_a_program``: whole key heads, a multiple of 128 lanes or
+all of them), the backward sums the value heads' dq, dk a key head in VMEM in
+float32 and writes them at ``Hk`` heads; any other shape, and ``impl="jnp"``,
+repeats q and k first (``jnp.repeat``, XLA's).  Equal head counts are the
+program they were.  (``models.transformer.GatedDeltaNet`` hands q, k, v over as
+the token-major rows ``ops/gdn_kernels.py`` writes: the reshapes to ``(B, T, H,
+d)`` and back fold away, and no layout of XLA's is left between them.)
 
   ``gated_delta_kkt``      k, g, beta -> ``L`` (float32), one product a chunk and
       head; ``T = (I + L)^-1`` is then XLA's (``_block_inverse``: 16-wide power
@@ -341,18 +351,18 @@ def _strictly_lower(kb, k, decay):
     return jnp.where(row > col, _dot(kb, k, _NT) * decay, 0.0)
 
 
-def _kkt_kernel(k_ref, g_ref, beta_ref, l_ref, *, chunk, block, heads, dk):
+def _kkt_kernel(k_ref, g_ref, beta_ref, l_ref, *, chunk, block, heads, ratio, dk):
     for j, gammas in enumerate(_gammas(g_ref, heads, chunk)):
         for c in range(block):
             rows = slice(c * chunk, (c + 1) * chunk)
-            k = k_ref[0, rows, j * dk:(j + 1) * dk]
+            k = k_ref[0, rows, _key_columns(j, ratio, dk)]
             beta, decay, *_ = _gates(gammas[c:c + 1], beta_ref[0, j, c:c + 1, :], chunk)
             kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
             l_ref[0, j, rows, :] = _strictly_lower(kb, k, decay)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, o_ref, d_ref, s_ref,
-                state, *, chunk, block, heads, dk, dv):
+                state, *, chunk, block, heads, ratio, dk, dv):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
@@ -362,7 +372,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, o_ref, d_ref, s_ref
     for c in range(block):
         rows = slice(c * chunk, (c + 1) * chunk)
         for j in range(heads):
-            keys, values = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+            keys, values = _key_columns(j, ratio, dk), slice(j * dv, (j + 1) * dv)
             t = t_ref[0, j, rows, :].astype(dtype)
             gates = _gates(gammas[j][c:c + 1], beta_ref[0, j, c:c + 1, :], chunk)
             x = _chunk_local(q_ref[0, rows, keys], k_ref[0, rows, keys],
@@ -381,7 +391,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, o_ref, d_ref, s_ref
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, d_ref, s_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate,
-                *, chunk, block, heads, dk, dv):
+                *, chunk, block, heads, ratio, dk, dv):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
@@ -392,11 +402,23 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, d_ref, s_ref, do_re
     # a column's numbers along a row, exactly
     as_row = lambda x: jnp.sum(jnp.where(row == col, x, 0.0), axis=0, keepdims=True)
     by_row = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    sums = {}
+
+    def shared(ref, name, value, j, rows, keys):
+        """dq or dk of value head ``j``: the value heads of one key head summed
+        in float32 and written once, at the key head."""
+        if j % ratio:
+            value = sums[name] + value
+        if (j + 1) % ratio:
+            sums[name] = value
+        else:
+            ref[0, rows, keys] = cast(value)
+
     gammas = _gammas(g_ref, heads, chunk)
     for c in reversed(range(block)):
         rows = slice(c * chunk, (c + 1) * chunk)
         for j in range(heads):
-            keys, values = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+            keys, values = _key_columns(j, ratio, dk), slice(j * dv, (j + 1) * dv)
             q, k, v = q_ref[0, rows, keys], k_ref[0, rows, keys], v_ref[0, rows, values]
             t32 = t_ref[0, j, rows, :]
             t = cast(t32)
@@ -425,9 +447,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, d_ref, s_ref, do_re
                 row > col, -_dot_f32(_dot_f32(t32, dt, _TN), t32, _NT), 0.0)
             dkk, dqk = cast(dlower * decay), cast(daqk * decay)
             dkb = _dot(dkk, k) + dkbr * rise
-            dq_ref[0, rows, keys] = cast(_dot(dqk, k) + dqg * rise)
-            dk_ref[0, rows, keys] = cast(_dot(dkk, x["kb"], _TN) + _dot(dqk, q, _TN)
-                                         + dkd * fall + dkb * beta)
+            shared(dq_ref, "dq", _dot(dqk, k) + dqg * rise, j, rows, keys)
+            shared(dk_ref, "dk", _dot(dkk, x["kb"], _TN) + _dot(dqk, q, _TN)
+                   + dkd * fall + dkb * beta, j, rows, keys)
             dv_ref[0, rows, values] = cast(dvb * beta)
             dbeta_ref[0, j, c:c + 1, :] = as_row(
                 by_row(dkb * x["k32"]) + by_row(dvb * v.astype(f32)))
@@ -446,24 +468,43 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, d_ref, s_ref, do_re
         dg_ref[0, j] = _running_sums(dg_ref[0, j], lower)
 
 
-def _specs(chunk, block, heads, dk, dv, steps, reverse=False):
+def _key_columns(j, ratio, dk):
+    """A program's value head ``j`` reads (and its dq, dk are written at) the
+    key head ``j // ratio`` of the program's key block."""
+    return slice(j // ratio * dk, (j // ratio + 1) * dk)
+
+
+def _specs(chunk, block, heads, ratio, dk, dv, steps, reverse=False):
     """The blocks of a grid step ``(b, h, i)``, ``h`` a group of ``heads`` value
     heads: a step's rows of the token-major (B, T, H d) tensors by a head's
-    width, of the gates' rows (B, H, N, C), of ``L`` and ``T`` (B, H, T, C) and
-    of the states (B, H, N dk, dv).  ``reverse``: the backward walks the steps
-    last to first."""
+    width (q and k by the group's ``heads // ratio`` KEY heads: the repeat to
+    the value heads is this index map), of the gates' rows (B, H, N, C), of
+    ``L`` and ``T`` (B, H, T, C) and of the states (B, H, N dk, dv).
+    ``reverse``: the backward walks the steps last to first."""
     at = (lambda i: steps - 1 - i) if reverse else (lambda i: i)
     tokens = lambda width: pl.BlockSpec(
-        (1, block * chunk, heads * width), lambda b, h, i: (b, at(i), h))
+        (1, block * chunk, width), lambda b, h, i: (b, at(i), h))
     by_head = lambda size, width: pl.BlockSpec(
         (1, heads, size, width), lambda b, h, i: (b, h, at(i), 0))
-    return {"k": tokens(dk), "v": tokens(dv), "gate": by_head(block, chunk),
-            "t": by_head(block * chunk, chunk), "s": by_head(block * dk, dv)}
+    return {"k": tokens(heads // ratio * dk), "v": tokens(heads * dv),
+            "gate": by_head(block, chunk), "t": by_head(block * chunk, chunk),
+            "s": by_head(block * dk, dv)}
 
 
 def _head_group(heads: int) -> int:
     """Value heads a program: ``_BLOCK_HEADS`` where that divides them."""
     return _BLOCK_HEADS if heads % _BLOCK_HEADS == 0 else 1
+
+
+def _key_heads_a_program(group: int, key_heads: int, ratio: int, dk: int) -> int:
+    """Key heads a program of ``group`` value heads reads where q and k come at
+    ``key_heads`` = value heads / ``ratio``: the group's own, when they are
+    whole heads and a block Mosaic takes (a multiple of 128 lanes, or all the
+    key heads); else 0, and the rule repeats q and k to the value heads."""
+    if group % ratio:
+        return 0
+    held = group // ratio
+    return held if held == key_heads or held * dk % 128 == 0 else 0
 
 
 def _params(carried: bool):
@@ -473,17 +514,19 @@ def _params(carried: bool):
         vmem_limit_bytes=_VMEM_BYTES)
 
 
-_STATIC = ("chunk", "block", "group", "interpret")
+_STATIC = ("chunk", "block", "group", "ratio", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _kkt_call(k, g, beta, chunk, block, group, interpret):
-    """``L`` (B, H, T, C) float32 from k (B, T, H dk) and the gates' rows."""
+def _kkt_call(k, g, beta, chunk, block, group, ratio, interpret):
+    """``L`` (B, H, T, C) float32 from k (B, T, H / ratio dk) and the gates'
+    rows."""
     b, heads, n, _ = g.shape
-    dk = k.shape[-1] // heads
-    sp = _specs(chunk, block, group, dk, dk, n // block)
+    dk = k.shape[-1] * ratio // heads
+    sp = _specs(chunk, block, group, ratio, dk, dk, n // block)
     return pl.pallas_call(
-        functools.partial(_kkt_kernel, chunk=chunk, block=block, heads=group, dk=dk),
+        functools.partial(_kkt_kernel, chunk=chunk, block=block, heads=group,
+                          ratio=ratio, dk=dk),
         name="gated_delta_kkt",
         grid=(b, heads // group, n // block),
         in_specs=[sp["k"], sp["gate"], sp["gate"]],
@@ -495,13 +538,13 @@ def _kkt_call(k, g, beta, chunk, block, group, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd_call(q, k, v, g, beta, t_inv, chunk, block, group, interpret):
+def _fwd_call(q, k, v, g, beta, t_inv, chunk, block, group, ratio, interpret):
     b, heads, n, _ = g.shape
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    sp = _specs(chunk, block, group, dk, dv, n // block)
+    dk, dv = q.shape[-1] * ratio // heads, v.shape[-1] // heads
+    sp = _specs(chunk, block, group, ratio, dk, dv, n // block)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, block=block, heads=group,
-                          dk=dk, dv=dv),
+                          ratio=ratio, dk=dk, dv=dv),
         name="gated_delta_fwd",
         grid=(b, heads // group, n // block),
         in_specs=[sp["k"], sp["k"], sp["v"], sp["gate"], sp["gate"], sp["t"]],
@@ -516,14 +559,15 @@ def _fwd_call(q, k, v, g, beta, t_inv, chunk, block, group, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _bwd_call(q, k, v, g, beta, t_inv, d, states, do, chunk, block, group, interpret):
+def _bwd_call(q, k, v, g, beta, t_inv, d, states, do, chunk, block, group, ratio,
+              interpret):
     b, heads, n, _ = g.shape
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    sp = _specs(chunk, block, group, dk, dv, n // block, reverse=True)
+    dk, dv = q.shape[-1] * ratio // heads, v.shape[-1] // heads
+    sp = _specs(chunk, block, group, ratio, dk, dv, n // block, reverse=True)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, block=block, heads=group,
-                          dk=dk, dv=dv),
+                          ratio=ratio, dk=dk, dv=dv),
         name="gated_delta_bwd",
         grid=(b, heads // group, n // block),
         in_specs=[sp["k"], sp["k"], sp["v"], sp["gate"], sp["gate"], sp["t"],
@@ -543,9 +587,9 @@ _inverse = jax.jit(_block_inverse)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _fused(q, k, v, g, beta, static):
-    """The rule on token-major q, k (B, T, H dk), v (B, T, H dv) and the gates
-    as rows (B, H, N, C) float32 -> o (B, T, H dv).  ``static``: (chunk, block,
-    heads a program, interpret)."""
+    """The rule on token-major q, k (B, T, H / ratio dk), v (B, T, H dv) and the
+    gates as rows (B, H, N, C) float32 -> o (B, T, H dv).  ``static``: (chunk,
+    block, value heads a program, value heads a key head, interpret)."""
     return _fused_fwd(q, k, v, g, beta, static)[0]
 
 
@@ -572,14 +616,14 @@ def _rule(q, k, v, g, beta, chunk, block, group, impl, interpret):
     """Whole kernel steps of padded tensors -> ``o`` (B, T, H, dv).  A ``jit`` of
     its own for ``model.init``'s sake, which runs a layer operation by
     operation: one program there, not the hundred the inverse is."""
-    b, t, h, _ = q.shape
+    b, t, h, _ = v.shape
     if impl == "jnp":
         return jnp.moveaxis(_carry_scan(*_prepare(q, k, v, g, beta, chunk), chunk), 1, 2)
     tokens = lambda x: x.reshape(b, t, -1)
     rows = lambda x: jnp.moveaxis(x.astype(jnp.float32), 2, 1).reshape(
         b, h, t // chunk, chunk)
     o = _fused(tokens(q), tokens(k), tokens(v), rows(g), rows(beta),
-               (chunk, block, group, interpret))
+               (chunk, block, group, h // q.shape[2], interpret))
     return o.reshape(b, t, h, -1)
 
 
@@ -587,36 +631,43 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, *, impl: str = "kernel",
                      interpret: Optional[bool] = None):
     """The gated delta rule in chunks of ``chunk`` tokens (the module's text).
 
-    ``q``, ``k`` (B, T, H, dk) and ``v`` (B, T, H, dv) in one dtype (``q``
-    already scaled, ``q`` and ``k`` already normalised and repeated to the value
-    heads), ``g`` (B, T, H) the log of the forget gate (<= 0), ``beta`` (B, T,
-    H).  Returns ``o`` (B, T, H, dv) in ``q``'s dtype; differentiable in all
-    five.  ``impl``: ``"kernel"`` (the rule's Mosaic kernels, forward and
-    backward) or ``"jnp"`` (XLA's chunk-local products and a ``lax.scan``).  What
-    a backward pass keeps under ``"kernel"``: the operands, ``T`` (float32),
-    ``D`` and a state a chunk, 0.47 GB a layer of 8,192 tokens x 32 heads in
-    bf16; a caller short of memory wraps the call in ``jax.checkpoint``
+    ``q``, ``k`` (B, T, Hk, dk) and ``v`` (B, T, H, dv) in one dtype (``q``
+    already scaled, ``q`` and ``k`` already normalised), ``g`` (B, T, H) the log
+    of the forget gate (<= 0), ``beta`` (B, T, H).  ``Hk`` divides ``H``: value
+    head ``j`` reads key head ``j // (H / Hk)``, as if q and k were repeated to
+    the value heads, and ``dq``, ``dk`` come back at the key heads.  Returns
+    ``o`` (B, T, H, dv) in ``q``'s dtype; differentiable in all five.
+    ``impl``: ``"kernel"`` (the rule's Mosaic kernels, forward and backward) or
+    ``"jnp"`` (XLA's chunk-local products and a ``lax.scan``).  What a backward
+    pass keeps under ``"kernel"``: the operands, ``T`` (float32), ``D`` and a
+    state a chunk, 0.47 GB a layer of 8,192 tokens x 32 heads in bf16; a caller
+    short of memory wraps the call in ``jax.checkpoint``
     (``models.transformer.GatedDeltaNet`` does)."""
     if impl not in ("kernel", "jnp"):
         raise ValueError(f"impl is 'kernel' or 'jnp', got {impl!r}")
-    if (q.ndim != 4 or q.shape != k.shape or v.shape[:3] != q.shape[:3]
-            or g.shape != q.shape[:3] or beta.shape != q.shape[:3]):
+    if (q.ndim != 4 or v.ndim != 4 or q.shape != k.shape or v.shape[:2] != q.shape[:2]
+            or v.shape[2] % q.shape[2] or g.shape != v.shape[:3]
+            or beta.shape != v.shape[:3]):
         raise ValueError(
-            "gated_delta_rule takes q, k (B, T, H, dk), v (B, T, H, dv) and g, "
-            f"beta (B, T, H), got {q.shape}, {k.shape}, {v.shape}, {g.shape}, "
-            f"{beta.shape}")
+            "gated_delta_rule takes q, k (B, T, Hk, dk), v (B, T, H, dv), Hk a "
+            f"divisor of H, and g, beta (B, T, H), got {q.shape}, {k.shape}, "
+            f"{v.shape}, {g.shape}, {beta.shape}")
     if not (q.dtype == k.dtype == v.dtype):
         raise ValueError(f"q, k, v are {q.dtype}, {k.dtype}, {v.dtype}: one dtype")
     if chunk < 1:
         raise ValueError(f"chunk is a number of tokens >= 1, got {chunk}")
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    b, t, hk, dk = q.shape
+    h, dv = v.shape[2:]
     n = -(-t // chunk)
     # whole kernel steps: a step's chunks are 8 (the tiling of ``a``'s block) or
     # all there are
     block = _BLOCK_CHUNKS if n >= _BLOCK_CHUNKS else n
     n = -(-n // block) * block
     group = _head_group(h)
+    if hk != h and (impl == "jnp" or not _key_heads_a_program(group, hk, h // hk, dk)):
+        # no block of whole key heads for a program's value heads: repeat
+        q, k = (jnp.repeat(x, h // hk, axis=2) for x in (q, k))
+        hk = h
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if impl == "kernel" and not interpret and any(
@@ -625,14 +676,16 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, *, impl: str = "kernel",
             f"on the chip a program's rows are {group} heads wide, a multiple of "
             f"128 lanes or all {h} heads: got dk {dk}, dv {dv}")
     if _trace.enabled():
-        fields = dict(rows=b * t, value_heads=h, chunk=chunk, chunks=n, d_k=dk, d_v=dv,
-                      impl=impl, programs=b * (h // group) * (n // block), block=block,
+        fields = dict(rows=b * t, value_heads=h, key_heads=hk, chunk=chunk, chunks=n,
+                      d_k=dk, d_v=dv, impl=impl,
+                      programs=b * (h // group) * (n // block), block=block,
                       heads_a_program=group)
         if impl == "kernel":
-            # what XLA hands the forward through HBM a layer and pass: q, k, v,
-            # g, beta and T (float32)
-            fields["hbm_operand_bytes"] = b * n * chunk * h * (
-                jnp.dtype(q.dtype).itemsize * (2 * dk + dv) + 4 * 2 + 4 * chunk)
+            # what XLA hands the forward through HBM a layer and pass: q, k (at
+            # the heads they come at), v, g, beta and T (float32)
+            fields["hbm_operand_bytes"] = b * n * chunk * (
+                jnp.dtype(q.dtype).itemsize * (2 * hk * dk + h * dv)
+                + h * (4 * 2 + 4 * chunk))
         _trace.event("gdn.chunks", **fields)
     pad = n * chunk - t
     if pad:

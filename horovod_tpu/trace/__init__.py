@@ -110,8 +110,13 @@ SITES = (
     "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths
     "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring,
                            # the grouped products' tiles and row-tile visits
-    "gdn.chunks",          # the gated delta rule traced: rows, value heads, chunk,
-                           # chunks a sequence, both head widths, the kernel's programs
+    "gdn.chunks",          # the gated delta rule traced: rows, value and key heads,
+                           # chunk, chunks a sequence, both head widths, the kernel's
+                           # programs, the bytes XLA hands the forward through HBM
+    "gdn.conv_norm",       # Gated DeltaNet's input pass traced (convolution, SiLU, L2
+                           # norms): rows, channels, taps, heads, row tile, programs, bytes
+    "gdn.gated_norm",      # its output pass traced (norm(o) * silu(z)): rows, channels,
+                           # value heads, row tile, programs, bytes
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -147,24 +152,28 @@ DEVICE_SUBSCOPES = (
                        # routed sum, whole on every chip (with its gate, where
                        # ``shared_expert_gate``)
     "gdn",      # models/transformer.py GatedDeltaNet, all but the rule: the
-                # two input projections, the causal depthwise convolution,
-                # the L2 norms and the repeat to the value heads, the gates
-                # beta and g, the gated RMSNorm and the output projection
+                # two input projections, the input pass (the causal depthwise
+                # convolution, SiLU, the L2 norms: since PR 38 the kernel pair
+                # gdn_conv_norm_*), the gates beta and g, the output pass
+                # (the gated RMSNorm: gdn_gated_norm_*) and the output
+                # projection
     "gated_delta",  # GatedDeltaNet, the rule itself (ops/gated_delta.py): its
                     # three kernels (since PR 36 they make the chunk-local
-                    # tensors themselves), XLA's unit-triangular inverse and
-                    # the layouts XLA hands them; a SIBLING of ``gdn``, not
-                    # nested in it, so that one pattern reads each
+                    # tensors themselves, since PR 38 they read q and k at the
+                    # key heads), XLA's unit-triangular inverse and the gates'
+                    # rows a chunk; a SIBLING of ``gdn``, not nested in it, so
+                    # that one pattern reads each
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
-#: ops/flash_attention.py, ops/grouped_matmul.py and ops/gated_delta.py, one
-#: name a kernel; the
+#: ops/flash_attention.py, ops/grouped_matmul.py, ops/gated_delta.py and
+#: ops/gdn_kernels.py, one name a kernel; the
 #: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
 #: attention kernels all start with ``flash_attention`` so one pattern still
 #: reads them together; the routed experts' grouped products do not, and are
 #: read by the ``experts`` scope they run in, as the gated delta rule's three
-#: (``gated_delta*``) are by the ``gated_delta`` scope.
+#: (``gated_delta*``) are by the ``gated_delta`` scope and Gated DeltaNet's
+#: two passes (``gdn_*``) by the ``gdn`` scope.
 DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
     "flash_attention_bwd_dq",   # _backward_folded: dQ
@@ -184,7 +193,16 @@ DEVICE_KERNELS = (
                          # value heads)
     "gated_delta_bwd",   # the same walk last to first, the state's cotangent
                          # in VMEM: the chunk-local tensors made again, their
-                         # backward written out, dq, dk, dv, dg, dbeta
+                         # backward written out, dq, dk (summed a key head), dv,
+                         # dg, dbeta
+    "gdn_conv_norm_fwd",   # ops/gdn_kernels.py: q, k (key heads), v as token-major
+                           # rows from the projection's rows: the four-tap causal
+                           # convolution, SiLU, the L2 norm a head, q's scale
+    "gdn_conv_norm_bwd",   # the same made again in VMEM, dqkv and the taps'
+                           # gradient (summed over the row tiles in float32)
+    "gdn_gated_norm_fwd",  # norm(o) * silu(z) on the rule's rows, z read out of
+                           # the projection's rows in place
+    "gdn_gated_norm_bwd",  # do, dz and the norm's scale's gradient
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"

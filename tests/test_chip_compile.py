@@ -183,6 +183,13 @@ def test_flash_256_wide_heads_compile_at_the_cell_s_shapes(one_chip, backward):
     assert "bf16[16,8192,256]" in text and "bf16[2,8192,256]" in text
 
 
+def _entry_instructions(text):
+    """The lines of the compiled text's ENTRY computation: what runs as an
+    operation of its own (a fusion's body is elsewhere)."""
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.M | re.S).group(1)
+    return body.splitlines()
+
+
 def _xla_products(text, inside):
     """The compiled text's ``dot`` and ``convolution`` instructions (XLA's own
     products, in a fusion or not) whose ``op_name`` holds ``inside``."""
@@ -190,16 +197,20 @@ def _xla_products(text, inside):
             if re.search(r"= \S+ (dot|convolution)\(", line) and inside in line]
 
 
-@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward):
+@pytest.mark.parametrize("backward,key_heads", [(False, 32), (True, 32), (True, 16)],
+                         ids=["fwd", "fwd_bwd", "fwd_bwd_key_heads"])
+def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward, key_heads):
     """``ops.gated_delta.gated_delta_rule`` at the cell's shape (8,192 tokens,
     32 value heads, a 128 x 128 state) as the v5e compiler takes it: ``L``, the
     chunk-local tensors with the carry over the chunks, and their backward are
     three Mosaic kernels by their names, and XLA is left no product of the rule
-    but the unit-triangular inverse's (PR 36)."""
+    but the unit-triangular inverse's (PR 36).  With q and k at the layer's 16
+    key heads (PR 38) the kernels' q, k operands and dq, dk results are 2,048
+    wide: nothing is repeated, nothing summed over pairs of heads outside."""
     from horovod_tpu.ops.gated_delta import gated_delta_rule
 
-    qkv = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    qk = _sds((1, 8192, key_heads, 128), jnp.bfloat16, one_chip)
+    v = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
     gate = _sds((1, 8192, 32), jnp.float32, one_chip)
 
     def fwd(q, k, v, g, beta):
@@ -209,15 +220,47 @@ def test_gated_delta_rule_is_a_kernel_on_the_chip(one_chip, backward):
         return jnp.sum(fwd(*a).astype(jnp.float32) ** 2)
 
     fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else fwd
-    text = _compile(fn, qkv, qkv, qkv, gate, gate).as_text()
-    kernels = re.findall(r"%(gated_delta\w*)\.\d+ = [^\n]*tpu_custom_call", text)
-    assert sorted(kernels) == (["gated_delta_bwd"] if backward else []) + [
+    text = _compile(fn, qk, qk, v, gate, gate).as_text()
+    calls = {m.group(1): m.group(0) for m in re.finditer(
+        r"%(gated_delta\w*)\.\d+ = [^\n]*tpu_custom_call[^\n]*", text)}
+    assert sorted(calls) == (["gated_delta_bwd"] if backward else []) + [
         "gated_delta_fwd", "gated_delta_kkt"]
     assert "triangular-solve" not in text and "while(" not in text.replace(" ", "")
     products = _xla_products(text, "jit(_rule)")
     assert products and all("jit(_block_inverse)" in line for line in products)
     # the kernels' operands as they cross HBM: q, k, v token-major, T float32
     assert "bf16[1,8192,4096]" in text and "f32[1,32,8192,64]" in text
+    keys = f"bf16[1,8192,{key_heads * 128}]"
+    layouts = lambda name: re.search(
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", calls[name]).group(1)
+    assert layouts("gated_delta_kkt").startswith(keys)           # k
+    assert layouts("gated_delta_fwd").count(keys) == 2 + (key_heads == 32)     # q, k (, v)
+    if backward:                                                   # dq, dk at the key heads
+        assert calls["gated_delta_bwd"].split(" custom-call(")[0].count(keys) == 2 + (
+            key_heads == 32)
+
+
+def test_gdn_passes_are_kernels_on_the_chip(one_chip):
+    """``ops.gdn_kernels`` at the cell's shape (PR 38): the input pass reads [q
+    | k | v] and the output pass ``z`` out of the projection's 12,288-wide rows
+    in place (no slice of XLA's), forward and backward a kernel each."""
+    from horovod_tpu.ops.gdn_kernels import gdn_conv_norm, gdn_gated_norm
+
+    qkvz = _sds((1, 8192, 12288), jnp.bfloat16, one_chip)
+    taps = _sds((4, 8192), jnp.float32, one_chip)
+    scale = _sds((128,), jnp.float32, one_chip)
+
+    def loss(qkvz, taps, scale):
+        q, k, v = gdn_conv_norm(qkvz, taps, key_heads=16, key_head_dim=128, value_heads=32,
+                                value_head_dim=128, interpret=False)
+        o = gdn_gated_norm(v, qkvz, scale, heads=32, interpret=False)
+        return sum(jnp.sum(x.astype(jnp.float32) ** 2) for x in (q, k, o))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), qkvz, taps, scale).as_text()
+    kernels = re.findall(r"%(gdn_\w*?)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(kernels) == ["gdn_conv_norm_bwd", "gdn_conv_norm_fwd",
+                               "gdn_gated_norm_bwd", "gdn_gated_norm_fwd"]
+    assert not re.search(r"= bf16\[1,8192,(8192|4096)\]\S* slice\(", text)
 
 
 # the routed cells' grouped products: (rows of a chunk, k, n, held experts, row tile)
@@ -559,7 +602,11 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
     and the 256-wide attention are Mosaic calls by their names, each under the
     scope its metric reads (``gated_delta``, never ``gdn``; forward, the
     backward's own copy and the backward itself), the projections under
-    ``gdn``, and the routed layer still holds no scatter."""
+    ``gdn``, and the routed layer still holds no scatter.  Since PR 38 the
+    linear layer is kernels and projections: ``gdn_conv_norm_*`` and
+    ``gdn_gated_norm_*`` under ``gdn``, q and k 16 heads wide into the rule,
+    and no copy, reshape, pad, slice or broadcast of XLA's of an activation
+    between the projections."""
     import functools
 
     from horovod_tpu.models.transformer import next_token_loss
@@ -586,7 +633,8 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
         loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
     tokens = _sds((1, 8192), jnp.int32, batch)
     compiled = step.lower(state, tokens, tokens).compile()
-    assert _device_bytes(compiled) < HBM_BYTES
+    # 6.630 GB at PR 37, whose elementwise passes kept float32 copies; 6.072 now
+    assert _device_bytes(compiled) <= 6_100_000_000
     text = compiled.as_text()
     calls = {}
     for line in text.splitlines():
@@ -605,6 +653,27 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
     for name in ("gated_delta_kkt", "gated_delta_fwd", "gated_delta_bwd"):
         assert all("/linear_attn/" in o and "/gated_delta/" in o and "/gdn/" not in o
                    for o in calls[name]), calls[name]
+    # the input pass twice forward (the mixer is made again) and once backward,
+    # the output pass (outside the checkpoint) once each way, all under ``gdn``
+    assert [len(calls[k]) for k in ("gdn_conv_norm_fwd", "gdn_conv_norm_bwd",
+                                    "gdn_gated_norm_fwd", "gdn_gated_norm_bwd")] == [2, 1, 1, 1]
+    for name in (k for k in calls if k.startswith("gdn_")):
+        assert all("/linear_attn/" in o and "/gdn/" in o and "/gated_delta/" not in o
+                   for o in calls[name]), calls[name]
+    # q and k reach the rule's kernels 16 heads wide
+    for line in text.splitlines():
+        if re.search(r"%gated_delta_(fwd|bwd)\.\d+ = ", line):
+            operands = re.search(
+                r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", line).group(1)
+            assert operands.startswith("bf16[1,8192,2048]{2,1,0}, bf16[1,8192,2048]{2,1,0}, "
+                                       "bf16[1,8192,4096]{2,1,0}"), operands
+    # between the projections XLA moves no activation (bf16 here): what is
+    # left under /linear_attn/ of these operations is the gates' float32 (B,
+    # T, 32) rows a chunk and the inverse's own blocks
+    moved = [l for l in _entry_instructions(text)
+             if "/linear_attn/" in l and re.search(
+                 r"= bf16\S+ (copy|reshape|pad|slice|broadcast|transpose|concatenate)\(", l)]
+    assert not moved, moved
     assert all("/layer_1/attn/" in o for k in calls if k.startswith("flash") for o in calls[k])
     assert all("/experts/" in o for k in calls if k.startswith("grouped") for o in calls[k])
     op_names = set(re.findall(r'op_name="([^"]+)"', text))

@@ -1,8 +1,12 @@
 """The gated delta rule (``ops/gated_delta.py``, PR 35): the chunked form, as
 the Mosaic kernels (interpreted here; since PR 36 they make the chunk-local
-tensors themselves, forward and backward) and as XLA's chunk-local products
-with a ``jax.numpy`` scan, against the token-by-token recurrence, values and all
-five gradients."""
+tensors themselves, forward and backward; since PR 38 they read q and k at the
+key heads) and as XLA's chunk-local products with a ``jax.numpy`` scan, against
+the token-by-token recurrence, values and all five gradients.  And the pass
+that hands the rule its q, k, v and the one that takes its ``o``
+(``ops/gdn_kernels.py``, PR 38): the convolution, SiLU and L2 norms, and the
+gated RMSNorm, each a Mosaic kernel pair, against the ``jnp`` functions 'dot'
+models run."""
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +14,10 @@ import numpy as np
 import pytest
 
 from horovod_tpu import trace
+from horovod_tpu.models.transformer import causal_depthwise_conv, l2_unit
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import gated_delta_recurrent, gated_delta_rule
+from horovod_tpu.ops.gdn_kernels import gdn_conv_norm, gdn_gated_norm
 
 
 def _inputs(seed, b, t, h, dk, dv, slow, dtype=jnp.float32, alike=0.0):
@@ -197,6 +203,64 @@ def test_kernel_and_scan_carry_agree_under_jit_and_vmap_free_batches():
     np.testing.assert_allclose(kernel, scan, atol=2e-6)
 
 
+@pytest.mark.parametrize("impl", ["kernel", "jnp"])
+@pytest.mark.parametrize("heads,key_heads,dk,held", [
+    (4, 2, 16, 2),      # two value heads a key head: the program's four read both key heads
+    (4, 1, 16, 1),      # four a key head: all of them read the one
+    (8, 4, 64, 2),      # a program's block of two key heads, 128 lanes of the four's 256
+    (8, 4, 16, 0),      # ... 32 lanes: no block Mosaic takes, the rule repeats
+    (6, 3, 16, 0),      # one value head a program: none of a key head's pair, the same
+], ids=["ratio_2", "ratio_4", "ratio_2_lane_aligned_block", "ratio_2_falls_back",
+        "one_head_a_program_falls_back"])
+def test_q_and_k_at_the_key_heads_are_the_repeated_call(heads, key_heads, dk, held, impl):
+    """q, k at fewer heads than v (PR 38): the kernels read a value head's key
+    head through their index map and sum the value heads' dq, dk a key head in
+    VMEM; values and all five gradients equal the call on q, k repeated to the
+    value heads, whichever way the shapes send it."""
+    ratio = heads // key_heads
+    assert gated_delta._key_heads_a_program(
+        gated_delta._head_group(heads), key_heads, ratio, dk) == held
+    q, k, v, g, beta = _inputs(3, 2, 40, heads, dk, 24, True)
+    args = (q[:, :, ::ratio], k[:, :, ::ratio], v, g, beta)
+    rule = lambda *a: gated_delta_rule(*a, chunk=16, impl=impl)
+    repeat = lambda x: jnp.repeat(x, ratio, axis=2)
+    t0 = trace.now()
+    got, grads = _value_and_grads(rule, args)
+    (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "gdn.chunks"]
+    assert event["key_heads"] == (key_heads if held and impl == "kernel" else heads)
+    want, want_grads = _value_and_grads(lambda q, k, *a: rule(repeat(q), repeat(k), *a), args)
+    assert abs(float(got - want)) <= 1e-6 * abs(float(want))
+    for name, a, b in zip("q k v g beta".split(), grads, want_grads):
+        assert a.shape == b.shape, name
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * float(jnp.max(jnp.abs(b))), name
+
+
+def test_key_heads_in_bfloat16_sum_their_gradients_in_float32():
+    """bf16: the key heads' dq, dk are each value head's summed in float32 and
+    rounded once; the repeated call rounds each and sums in bf16.  A bf16
+    place apart, and the values the same to the bit."""
+    q, k, v, g, beta = _inputs(6, 1, 128, 4, 32, 32, True, jnp.bfloat16)
+    args = (q[:, :, ::2], k[:, :, ::2], v, g, beta)
+    f32 = lambda x: x.astype(jnp.float32)
+    co = jax.random.normal(jax.random.PRNGKey(9), v.shape)
+    repeat = lambda x: jnp.repeat(x, 2, axis=2)
+
+    def run(fn):
+        def loss(*a):
+            o = f32(fn(*a))
+            return jnp.sum(o * co), o
+        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        return o, grads
+
+    (o, grads) = run(lambda *a: gated_delta_rule(*a))
+    (want, want_grads) = run(lambda q, k, *a: gated_delta_rule(repeat(q), repeat(k), *a))
+    np.testing.assert_array_equal(o, want)
+    for name, a, b in zip("q k v g beta".split(), grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        gap = float(jnp.max(jnp.abs(f32(a) - f32(b))))
+        assert gap <= 0.01 * float(jnp.max(jnp.abs(f32(b)))), (name, gap)
+
+
 @pytest.mark.parametrize("bad,message", [
     (dict(impl="pallas"), "impl is 'kernel' or 'jnp'"),
     (dict(chunk=0), "chunk is a number of tokens"),
@@ -211,10 +275,14 @@ def test_arguments_are_refused_by_name(bad, message):
 
 def test_shapes_and_dtypes_are_refused():
     q, k, v, g, beta = _inputs(8, 1, 32, 2, 8, 8, True)
-    with pytest.raises(ValueError, match=r"takes q, k \(B, T, H, dk\)"):
+    with pytest.raises(ValueError, match=r"takes q, k \(B, T, Hk, dk\)"):
         gated_delta_rule(q, k[:, :16], v, g, beta)
-    with pytest.raises(ValueError, match=r"takes q, k \(B, T, H, dk\)"):
+    with pytest.raises(ValueError, match=r"takes q, k \(B, T, Hk, dk\)"):
         gated_delta_rule(q, k, v, g[..., :1], beta)
+    three = jnp.concatenate([v, v[:, :, :1]], axis=2)      # 2 key heads, 3 value heads
+    with pytest.raises(ValueError, match="Hk a divisor of H"):
+        gated_delta_rule(q, k, three, jnp.pad(g, ((0, 0), (0, 0), (0, 1))),
+                         jnp.pad(beta, ((0, 0), (0, 0), (0, 1))))
     with pytest.raises(ValueError, match="one dtype"):
         gated_delta_rule(q.astype(jnp.bfloat16), k, v, g, beta)
 
@@ -226,8 +294,8 @@ def test_gdn_chunks_event_carries_the_shape_arithmetic():
     t0 = trace.now()
     jax.eval_shape(lambda *a: gated_delta_rule(*a), q, k, v, g, beta)
     (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "gdn.chunks"]
-    assert event == dict(rows=2200, value_heads=3, chunk=64, chunks=24, d_k=16, d_v=24,
-                         impl="kernel", programs=2 * 3 * 3, block=8, heads_a_program=1,
+    assert event == dict(rows=2200, value_heads=3, key_heads=3, chunk=64, chunks=24, d_k=16,
+                         d_v=24, impl="kernel", programs=2 * 3 * 3, block=8, heads_a_program=1,
                          hbm_operand_bytes=2 * 24 * 64 * 3 * (4 * (16 + 16 + 24) + 8 + 4 * 64))
     t0 = trace.now()      # the benchmark's cell: 8,192 tokens, 32 value heads of 128
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
@@ -239,3 +307,208 @@ def test_gdn_chunks_event_carries_the_shape_arithmetic():
     # four value heads a program: 1 sequence x 8 head groups x 16 steps of eight chunks
     assert (event["chunks"], event["programs"], event["heads_a_program"], event["d_k"],
             event["d_v"]) == (128, 128, 4, 128, 128)
+    assert event["key_heads"] == 32 and event["hbm_operand_bytes"] == 8192 * 32 * (
+        2 * 3 * 128 + 8 + 4 * 64)
+    t0 = trace.now()      # q, k as the cell's layer hands them since PR 38: 16 key heads
+    jax.eval_shape(lambda *a: gated_delta_rule(*a), shape(1, 8192, 16, 128),
+                   shape(1, 8192, 16, 128), shape(1, 8192, 32, 128),
+                   jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32),
+                   jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32))
+    (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "gdn.chunks"]
+    # a program's four value heads read two key heads: q and k cross HBM once a
+    # key head, 33.5 MB less a layer and pass than repeated
+    assert (event["key_heads"], event["value_heads"], event["programs"]) == (16, 32, 128)
+    assert event["hbm_operand_bytes"] == 8192 * (
+        2 * (2 * 16 * 128 + 32 * 128) + 32 * (8 + 4 * 64)) == 203_423_744
+
+
+# -- the pass that makes q, k, v: ops/gdn_kernels.py (PR 38) ------------------
+
+
+def _conv_norm_oracle(qkv, w, hk, dk, hv, dv):
+    """What 'dot' models run: ``causal_depthwise_conv``, the slices, ``l2_unit``
+    a key head, q scaled."""
+    b, t, _ = qkv.shape
+    key = hk * dk
+    mixed = causal_depthwise_conv(qkv[..., :2 * key + hv * dv], w)
+    heads = lambda x: x.reshape(b, t, hk, dk)
+    return (l2_unit(heads(mixed[..., :key]), dk ** -0.5).reshape(b, t, key),
+            l2_unit(heads(mixed[..., key:2 * key])).reshape(b, t, key), mixed[..., 2 * key:])
+
+
+def _conv_norm_both(dtype, t, rows, extra=0, seed=0, heads=(2, 16, 4, 24)):
+    """Outputs and gradients (of a random cotangent a output) of the kernel pair
+    and of the oracle: ``((q, k, v), (dqkv, dw))`` each, float32 numpy."""
+    hk, dk, hv, dv = heads
+    width = 2 * hk * dk + hv * dv
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    qkv = jax.random.normal(ks[0], (2, t, width + extra)).astype(dtype)
+    w = jax.random.uniform(ks[1], (4, width), jnp.float32, -0.5, 0.5)
+    cos = [jax.random.normal(key, (2, t, n)) for key, n in
+           zip(ks[2:], (hk * dk, hk * dk, hv * dv))]
+
+    def run(fn):
+        def loss(qkv, w):
+            outs = fn(qkv, w)
+            return sum(jnp.sum(o.astype(jnp.float32) * c) for o, c in zip(outs, cos)), outs
+        (_, outs), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(qkv, w)
+        assert [o.dtype for o in outs] == [dtype] * 3
+        assert (grads[0].dtype, grads[1].dtype) == (dtype, jnp.float32)
+        assert grads[0].shape == qkv.shape and grads[1].shape == w.shape
+        return tuple(np.asarray(x, np.float32) for x in outs), tuple(
+            np.asarray(x, np.float32) for x in grads)
+
+    kernel = run(lambda qkv, w: gdn_conv_norm(
+        qkv, w, key_heads=hk, key_head_dim=dk, value_heads=hv, value_head_dim=dv,
+        row_tile=rows))
+    return kernel, run(lambda qkv, w: _conv_norm_oracle(qkv, w, hk, dk, hv, dv)), width
+
+
+@pytest.mark.parametrize("t,rows,extra", [
+    (32, 32, 0),      # one tile: the rows before the sequence are zeros
+    (64, 16, 0),      # four tiles: every boundary hands three rows on, and back
+    (50, 16, 0),      # no multiple of the tile: padded with zero rows
+    (50, 32, 24),     # the projection's further columns (z) ride along unread
+], ids=["one_tile", "tile_boundaries", "padded_tail", "columns_past_the_taps"])
+def test_conv_norm_pass_is_the_jnp_functions_in_float32(t, rows, extra):
+    """``gdn_conv_norm`` against ``causal_depthwise_conv`` + ``l2_unit``: q, k,
+    v, ``dqkv`` and ``d conv_kernel`` to 1e-5 in float32."""
+    (outs, grads), (want, want_grads), width = _conv_norm_both(jnp.float32, t, rows, extra)
+    for name, a, b in zip("q k v dqkv dw".split(), outs + grads, want + want_grads):
+        np.testing.assert_allclose(a, b, atol=1e-5 * np.abs(b).max(), err_msg=name)
+    # the first rows see zeros before the sequence, not the tile's own last rows
+    assert np.abs(outs[2][:, :3]).max() > 0 and np.isfinite(grads[0]).all()
+    if extra:
+        assert not grads[0][..., width:].any()      # what is never read has no gradient
+
+
+@pytest.mark.parametrize("t,rows", [(48, 16), (50, 32)], ids=["tile_boundaries", "padded_tail"])
+def test_conv_norm_pass_rounds_where_the_jnp_functions_round(t, rows):
+    """bf16: the float32 sum of the taps and SiLU rounded once, the norm in
+    float32 rounded again: q, k, v are the ``jnp`` functions' to the bit, but
+    for a rare last place (XLA:CPU contracts the oracle's multiply-adds its own
+    way; a moved or missing rounding point moves every tenth number).  The
+    gradients, which the kernel keeps in float32 where autodiff rounds the
+    cotangent at each of the two points, to a bf16 place."""
+    (outs, grads), (want, want_grads), _ = _conv_norm_both(jnp.bfloat16, t, rows, seed=1)
+    for name, a, b in zip("q k v".split(), outs, want):
+        assert np.mean(a != b) <= 1e-3, name
+        assert np.all(np.abs(a - b) <= 2.0 ** -7 * np.abs(b)), name
+    for name, a, b in zip("dqkv dw".split(), grads, want_grads):
+        assert np.abs(a - b).max() <= 0.02 * np.abs(b).max(), name
+
+
+def test_conv_norm_pass_crosses_a_tile_boundary_as_it_crosses_any_row():
+    """The three-row halo: the same sequence in tiles of 16 and in one tile of
+    64 gives the same rows, values and gradients, on both sides of every
+    boundary (a boundary that dropped or doubled a row's tap would be off by
+    the tap: tenths)."""
+    (a, ga), _, _ = _conv_norm_both(jnp.float32, 64, 16, seed=2)
+    (b, gb), _, _ = _conv_norm_both(jnp.float32, 64, 64, seed=2)
+    for name, x, y in zip("q k v dqkv dw".split(), a + ga, b + gb):
+        np.testing.assert_allclose(x, y, atol=2e-6 * np.abs(y).max(), err_msg=name)
+
+
+def test_conv_norm_pass_leaves_its_event_and_refuses_by_name():
+    """``gdn.conv_norm``: rows, channels, taps, heads, the row tile, its programs
+    and the bytes a pass moves; shapes and tiles refused by name."""
+    qkv = jax.ShapeDtypeStruct((1, 8192, 12288), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, 8192), jnp.float32)
+    call = lambda qkv, w, **kw: gdn_conv_norm(
+        qkv, w, key_heads=16, key_head_dim=128, value_heads=32, value_head_dim=128, **kw)
+    t0 = trace.now()
+    q, k, v = jax.eval_shape(call, qkv, w)
+    (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "gdn.conv_norm"]
+    assert (q.shape, k.shape, v.shape) == ((1, 8192, 2048), (1, 8192, 2048), (1, 8192, 4096))
+    assert event == dict(rows=8192, channels=8192, taps=4, key_heads=16, value_heads=32,
+                         row_tile=64, programs=128, hbm_bytes=128 * (2 * 64 + 16) * 8192 * 2)
+    with pytest.raises(ValueError, match=r"takes qkv \(B, T, >= 8192\)"):
+        call(jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16), w)
+    with pytest.raises(ValueError, match="row_tile is a multiple of 16"):
+        call(qkv, w, row_tile=24)
+    with pytest.raises(ValueError, match="a multiple of 128 lanes"):
+        gdn_conv_norm(jnp.zeros((1, 32, 160)), jnp.zeros((4, 160)), key_heads=2,
+                      key_head_dim=16, value_heads=4, value_head_dim=24, interpret=False)
+
+
+# -- the pass that takes o: norm(o) * silu(z) (PR 38) ---------------------------
+
+
+def _gated_norm_oracle(o, gate, scale, heads, eps):
+    """What 'dot' models run: flax's ``nn.RMSNorm`` a head, times ``silu(z)``
+    in float32, ``z`` the gate's last columns."""
+    import flax.linen as nn
+
+    b, t, width = o.shape
+    by_head = lambda x: x.reshape(b, t, heads, width // heads)
+    n = nn.RMSNorm(dtype=o.dtype, epsilon=eps).apply(
+        {"params": {"scale": scale}}, by_head(o))
+    gated = n.astype(jnp.float32) * nn.silu(by_head(gate[..., -width:]).astype(jnp.float32))
+    return gated.astype(o.dtype).reshape(b, t, width)
+
+
+def _gated_norm_both(dtype, t, rows, gate_width, dv=24, heads=4, seed=0):
+    width = heads * dv
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    o = jax.random.normal(ks[0], (2, t, width)).astype(dtype)
+    gate = jax.random.normal(ks[1], (2, t, gate_width)).astype(dtype)
+    scale = 1.0 + 0.3 * jax.random.normal(ks[2], (dv,))
+    co = jax.random.normal(ks[3], (2, t, width))
+
+    def run(fn):
+        def loss(o, gate, scale):
+            out = fn(o, gate, scale)
+            return jnp.sum(out.astype(jnp.float32) * co), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            o, gate, scale)
+        assert out.dtype == dtype and [g.dtype for g in grads] == [dtype, dtype, jnp.float32]
+        assert [g.shape for g in grads] == [o.shape, gate.shape, scale.shape]
+        return tuple(np.asarray(x, np.float32) for x in (out,) + grads)
+
+    return (run(lambda o, g, s: gdn_gated_norm(o, g, s, heads=heads, eps=1e-6, row_tile=rows)),
+            run(lambda o, g, s: _gated_norm_oracle(o, g, s, heads, 1e-6)), width)
+
+
+@pytest.mark.parametrize("t,rows,gate_width", [
+    (32, 32, 96),      # the gate alone
+    (50, 16, 288),     # three times as wide: z read in place by column block; a padded tail
+    (48, 16, 136),     # no multiple of the gate's width: sliced first
+], ids=["gate_alone", "gate_in_the_projection_s_rows", "gate_sliced_first"])
+def test_gated_norm_pass_is_the_jnp_functions_in_float32(t, rows, gate_width):
+    """``gdn_gated_norm`` against ``nn.RMSNorm`` x ``silu``: the product, ``do``,
+    ``dz`` and ``d scale`` to 1e-5 in float32; what is not the gate has no
+    gradient."""
+    got, want, width = _gated_norm_both(jnp.float32, t, rows, gate_width)
+    for name, a, b in zip("out do dgate dscale".split(), got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5 * np.abs(b).max(), err_msg=name)
+    assert not got[2][..., :gate_width - width].any()
+
+
+def test_gated_norm_pass_rounds_where_the_jnp_functions_round():
+    """bf16: the norm in float32 rounded once, the gate's product in float32
+    rounded again: the ``jnp`` functions' to the bit but for a rare last place;
+    the gradients, float32 throughout in the kernel, to a bf16 place."""
+    got, want, _ = _gated_norm_both(jnp.bfloat16, 50, 32, 256, dv=32, seed=1)
+    assert np.mean(got[0] != want[0]) <= 1e-3
+    assert np.all(np.abs(got[0] - want[0]) <= 2.0 ** -7 * np.abs(want[0]))
+    for name, a, b in zip("do dgate dscale".split(), got[1:], want[1:]):
+        assert np.abs(a - b).max() <= 0.02 * np.abs(b).max(), name
+
+
+def test_gated_norm_pass_leaves_its_event_and_refuses_by_name():
+    o = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16)
+    qkvz = jax.ShapeDtypeStruct((1, 8192, 12288), jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32)
+    t0 = trace.now()
+    out = jax.eval_shape(lambda *a: gdn_gated_norm(*a, heads=32), o, qkvz, scale)
+    (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "gdn.gated_norm"]
+    assert (out.shape, out.dtype) == ((1, 8192, 4096), jnp.bfloat16)
+    assert event == dict(rows=8192, channels=4096, value_heads=32, row_tile=64,
+                         programs=128, hbm_bytes=3 * 8192 * 4096 * 2)
+    with pytest.raises(ValueError, match=r"takes o \(B, T, H dv\)"):
+        gdn_gated_norm(jnp.zeros((1, 32, 96)), jnp.zeros((1, 32, 64)), jnp.ones((24,)), heads=4)
+    with pytest.raises(ValueError, match=r"takes o \(B, T, H dv\)"):
+        gdn_gated_norm(jnp.zeros((1, 32, 96)), jnp.zeros((1, 32, 96)), jnp.ones((96,)), heads=4)
+    with pytest.raises(ValueError, match="a multiple of 128 lanes"):
+        gdn_gated_norm(jnp.zeros((1, 32, 96)), jnp.zeros((1, 32, 96)), jnp.ones((24,)),
+                       heads=4, interpret=False)

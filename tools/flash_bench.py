@@ -26,11 +26,11 @@ Usage:
   flash_bench.py --grouped       # the routed experts' grouped product alone,
                                  #  kernel against jax.lax.ragged_dot, DEVICE
                                  #  time from a capture (PERF.md §6, PR 33)
-  flash_bench.py --gated-delta   # the gated delta rule alone, as the Mosaic
-                                 #  kernels and as XLA's products with a
-                                 #  jax.numpy scan, DEVICE time from a capture,
+  flash_bench.py --gated-delta   # a Gated DeltaNet layer's input side and
+                                 #  rule alone, as the Mosaic kernels and as the
+                                 #  jnp functions, DEVICE time from a capture,
                                  #  split into the kernels by name and what is
-                                 #  left in XLA (PERF.md §6, PR 35, PR 36)
+                                 #  left in XLA (PERF.md §6, PR 35, 36, 38)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -316,51 +316,67 @@ GROUPED_SHAPES = {
     "qwen3-next-80b-a3b down": (10240, 512, 2048, 32),
 }
 
-# (b, t, value heads, dk, dv): a linear layer's gated delta rule in the
-# benchmark's cell (benchmark/configs/qwen3-next-80b-a3b.json)
+# (b, t, key heads, value heads, dk, dv): a linear layer's input side and gated
+# delta rule in the benchmark's cell (benchmark/configs/qwen3-next-80b-a3b.json)
 GATED_DELTA_SHAPES = {
-    "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 32, 128, 128),
+    "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 16, 32, 128, 128),
 }
 
 
 def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
-    """The gated delta rule alone (``ops/gated_delta.py``), forward and
-    forward + backward, as the Mosaic kernels and as XLA's chunk-local products
-    with the ``jax.numpy`` scan, each its own ``jit`` inside ONE capture: DEVICE time a
-    call from the capture's ``XLA Modules`` events (``null`` off the chip), the
-    largest gap between the two carries' outputs and, inside each program, the
-    split of its operations' time into the rule's kernels by name and what is
-    left in XLA (the unit-triangular inverse, and the layouts in and out)."""
+    """A Gated DeltaNet layer from the projection's rows to the rule's output:
+    the convolution, SiLU, L2 norms and the gated delta rule, forward and
+    forward + backward, as the Mosaic kernels (``ops/gdn_kernels.py``, then
+    ``ops/gated_delta.py`` with q and k at the key heads) and as the ``jnp``
+    functions 'dot' models run (XLA's passes, the rule's chunk-local products
+    and a ``jax.numpy`` scan), each its own ``jit`` inside ONE capture: DEVICE
+    time a call from the capture's ``XLA Modules`` events (``null`` off the
+    chip), the largest gap between the two outputs and, inside each program,
+    the split of its operations' time into the kernels by name
+    (``gdn_conv_norm_*``, ``gated_delta_*``) and what is left in XLA (the
+    unit-triangular inverse, the layouts, and for ``jnp`` everything)."""
     import shutil
     import tempfile
 
+    from horovod_tpu.models.transformer import causal_depthwise_conv, l2_unit
     from horovod_tpu.ops.gated_delta import gated_delta_rule
+    from horovod_tpu.ops.gdn_kernels import gdn_conv_norm
 
     capture_dir = tempfile.mkdtemp(prefix="gated_delta_capture_")
     programs, records = {}, []
-    for name, (b, t, h, dk, dv) in shapes.items():
-        keys = jax.random.split(jax.random.PRNGKey(0), 6)
-        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-        q = (unit(jax.random.normal(keys[0], (b, t, h, dk))) * dk ** -0.5
-             ).astype(jnp.bfloat16)
-        k = unit(jax.random.normal(keys[1], (b, t, h, dk))).astype(jnp.bfloat16)
-        v = jax.random.normal(keys[2], (b, t, h, dv)).astype(jnp.bfloat16)
-        beta = jax.nn.sigmoid(jax.random.normal(keys[3], (b, t, h)))
-        g = -0.03 * jnp.exp(jax.random.normal(keys[4], (b, t, h)))
-        do = jax.random.normal(keys[5], (b, t, h, dv)).astype(jnp.bfloat16)
-        args = (q, k, v, g, beta)
-        rec = {"bench": "gated_delta", "shape": name, "b": b, "t": t, "heads": h,
-               "d_k": dk, "d_v": dv, "chunk": chunk, "variants": {}}
+    for name, (b, t, hk, h, dk, dv) in shapes.items():
+        keys = jax.random.split(jax.random.PRNGKey(0), 5)
+        key_dim = hk * dk
+        qkv = jax.random.normal(keys[0], (b, t, 2 * key_dim + h * dv)).astype(
+            jnp.bfloat16)
+        taps = jax.random.uniform(keys[1], (4, qkv.shape[-1]), jnp.float32, -0.5, 0.5)
+        beta = jax.nn.sigmoid(jax.random.normal(keys[2], (b, t, h)))
+        g = -0.03 * jnp.exp(jax.random.normal(keys[3], (b, t, h)))
+        do = jax.random.normal(keys[4], (b, t, h, dv)).astype(jnp.bfloat16)
+        args = (qkv, taps, g, beta)
+        rec = {"bench": "gated_delta", "shape": name, "b": b, "t": t, "key_heads": hk,
+               "heads": h, "d_k": dk, "d_v": dv, "chunk": chunk, "variants": {}}
         outs = {}
         for impl in ("kernel", "jnp"):
-            def fwd(*a, impl=impl):
-                return gated_delta_rule(*a, chunk=chunk, impl=impl,
-                                        interpret=interpret)
+            def fwd(qkv, taps, g, beta, impl=impl):
+                if impl == "kernel":
+                    q, k, v = gdn_conv_norm(
+                        qkv, taps, key_heads=hk, key_head_dim=dk, value_heads=h,
+                        value_head_dim=dv, interpret=interpret)
+                else:
+                    mixed = causal_depthwise_conv(qkv, taps)
+                    q, k, v = (mixed[..., :key_dim], mixed[..., key_dim:2 * key_dim],
+                               mixed[..., 2 * key_dim:])
+                q, k = q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk)
+                if impl == "jnp":
+                    q, k = l2_unit(q, dk ** -0.5), l2_unit(k)
+                return gated_delta_rule(q, k, v.reshape(b, t, h, dv), g, beta,
+                                        chunk=chunk, impl=impl, interpret=interpret)
 
             def fwd_bwd(*a, fwd=fwd):
                 return jax.grad(lambda *x: jnp.sum(
                     fwd(*x).astype(jnp.float32) * do.astype(jnp.float32)),
-                    argnums=(0, 1, 2, 3, 4))(*a)
+                    argnums=(0, 1, 2, 3))(*a)
 
             for what, fn in (("fwd", fwd), ("fwd_bwd", fwd_bwd)):
                 fn.__name__ = "gd%d_%s_%s" % (len(records), impl, what)
@@ -391,7 +407,8 @@ def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
                 variant[what + "_device_ms"] = round(t, 4) if t else None
                 if t:
                     variant[what + "_split_ms"] = kernel_split_ms(
-                        capture_dir, "jit_" + fn.__name__, "gated_delta_")
+                        capture_dir, "jit_" + fn.__name__,
+                        ("gated_delta_", "gdn_conv_norm_"))
     shutil.rmtree(capture_dir, ignore_errors=True)
     for rec, _ in records:
         _emit(rec, f"{rec['shape']}: " + "  ".join(
@@ -436,8 +453,8 @@ def module_ms(capture_dir):
 def kernel_split_ms(capture_dir, module, prefix):
     """``{kernel name: ms, ..., "xla": ms}`` a run of the program ``module``:
     the DEVICE time of its operations (the capture's ``XLA Ops`` events inside
-    the program's runs), those whose instruction starts with ``prefix`` by
-    their kernel's name and every other one under ``xla``."""
+    the program's runs), those whose instruction starts with ``prefix`` (one, or
+    a tuple of them) by their kernel's name and every other one under ``xla``."""
     import re
 
     from horovod_tpu.trace import device as _device
@@ -579,7 +596,7 @@ def main(argv=None):
                    "latent": (1, 512, 4, 4, (48, 32), True, None)},
                   2, 1, True, block=128)
         leg_grouped({"skewed": (192, 256, 384, 4)}, 1, 1, True)
-        leg_gated_delta({"tiny": (1, 80, 2, 16, 16)}, 1, 1, True, chunk=16)
+        leg_gated_delta({"tiny": (1, 80, 1, 2, 16, 16)}, 1, 1, True, chunk=16)
         return 0
 
     run_all = not (args.gqa or args.window or args.kernel or args.cells
