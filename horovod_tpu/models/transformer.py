@@ -22,8 +22,10 @@ bfloat16 activations, float32 params; RoPE positions; pre-norm blocks.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -31,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace as _trace
 from ..ops import spmd_ops
 from ..ops.reduce_ops import Sum
 
@@ -88,6 +91,37 @@ def resolve_remat_policies(policy, num_layers: int,
                 f"{sorted(REMAT_POLICIES)}"
             )
     return policies
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParameters:
+    """RoPE of one kind of layer (``TransformerConfig.rope_parameters``):
+    ``theta``, the share of each head that is rotated, and YaRN's numbers
+    (arXiv:2309.00071; the keys of the published configs), all five or none:
+    ``yarn_inv_freq`` blends the frequencies and cos and sin are multiplied
+    by ``attention_factor``."""
+
+    theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    factor: Optional[float] = None
+    original_max_position_embeddings: Optional[int] = None
+    beta_fast: Optional[float] = None
+    beta_slow: Optional[float] = None
+    attention_factor: Optional[float] = None
+
+    @property
+    def yarn(self) -> Optional[tuple]:
+        """YaRN's five numbers, or None for plain RoPE."""
+        five = (self.factor, self.original_max_position_embeddings,
+                self.beta_fast, self.beta_slow, self.attention_factor)
+        return None if all(v is None for v in five) else five
+
+    @property
+    def rope_type(self) -> str:
+        return "default" if self.yarn is None else "yarn"
+
+
+ATTENTION_KINDS = ("full_attention", "sliding_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,6 +281,26 @@ class TransformerConfig:
     # ``shared_expert_gate``: the shared experts' output times
     # ``sigmoid(x @ w)``, ``w`` (d_model, 1).
     shared_expert_gate: bool = False
+    # Attention that differs by layer (the ``laguna`` configs' keys).
+    # ``layer_types`` also takes ``"sliding_attention"``: such a layer sees the
+    # last ``sliding_window`` positions, itself included (``sliding_mask``),
+    # and a ``"full_attention"`` layer all of them; ``window`` stays the form
+    # that windows every layer, and the two together are refused.
+    # ``num_heads_per_layer``: a layer's query heads where they differ (each
+    # a multiple of ``num_kv_heads``; ``q``, ``o`` and a gate a head are built
+    # at the layer's count, ``d_model`` needs ``hidden_size``).
+    # ``rope_parameters``: ``{layer type: RopeParameters (or its fields as a
+    # dict)}`` for the ``ATTENTION_KINDS``; a type it does not name takes
+    # ``rope_theta`` and ``partial_rotary_factor``.  ``attn_head_gate``: the
+    # attention's output times ``sigmoid(x @ w)``, ``w`` (d_model, heads): one
+    # number a head and token (``attn_output_gate`` is the form with a gate a
+    # column, inside ``q``).  'dot' and 'flash' only: no ring, no
+    # ``shard_axis``, no latent attention, no block diffusion, no paged
+    # serving (one cache allocator for window and full layers is not there).
+    sliding_window: Optional[int] = None
+    num_heads_per_layer: Optional[Any] = None
+    rope_parameters: Optional[Any] = None
+    attn_head_gate: bool = False
 
     def __post_init__(self):
         kv = self.num_kv_heads
@@ -320,7 +374,7 @@ class TransformerConfig:
                 f"head_dim {self.head_dim} is no even number of columns")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            kinds = ("full_attention", "linear_attention")
+            kinds = ATTENTION_KINDS + ("linear_attention",)
             if (len(self.layer_types) != self.num_layers
                     or any(t not in kinds for t in self.layer_types)):
                 raise ValueError(
@@ -349,11 +403,127 @@ class TransformerConfig:
                     "layer_types with a 'linear_attention' layer takes no "
                     "block_diffusion (a recurrence has no block-diffusion "
                     "mask)")
+        self._check_per_layer_attention()
+
+    def _check_per_layer_attention(self):
+        """The fields of attention that differs by layer, each refusal with
+        its reason; tuples stored hashable."""
+        if self.num_heads_per_layer is not None:
+            heads = tuple(int(h) for h in self.num_heads_per_layer)
+            object.__setattr__(self, "num_heads_per_layer", heads)
+            kv = self.num_kv_heads or self.num_heads
+            if len(heads) != self.num_layers or any(
+                    h < 1 or h % kv for h in heads):
+                raise ValueError(
+                    f"num_heads_per_layer names for each of the "
+                    f"{self.num_layers} layers a multiple of the {kv} "
+                    f"key/value heads, got {heads}")
+            if self.hidden_size is None:
+                raise ValueError(
+                    "num_heads_per_layer needs hidden_size (no one head "
+                    "count gives the stream's width)")
+        if self.rope_parameters is not None:
+            given = dict(self.rope_parameters)
+            unknown = sorted(set(given) - set(ATTENTION_KINDS))
+            if unknown:
+                raise ValueError(
+                    f"rope_parameters names {ATTENTION_KINDS}, got {unknown}")
+            rope_of = {
+                kind: p if isinstance(p, RopeParameters) else RopeParameters(**p)
+                for kind, p in given.items()}
+            object.__setattr__(
+                self, "rope_parameters", tuple(sorted(rope_of.items())))
+            for kind, p in rope_of.items():
+                if p.yarn is not None and None in p.yarn:
+                    raise ValueError(
+                        f"rope_parameters[{kind!r}]: YaRN takes factor, "
+                        "original_max_position_embeddings, beta_fast, "
+                        f"beta_slow and attention_factor, all or none, got "
+                        f"{p.yarn}")
+                if (not 0.0 < p.partial_rotary_factor <= 1.0
+                        or int(self.head_dim * p.partial_rotary_factor) % 2):
+                    raise ValueError(
+                        f"rope_parameters[{kind!r}]: partial_rotary_factor "
+                        f"{p.partial_rotary_factor} of head_dim "
+                        f"{self.head_dim} is no even number of columns")
+        sliding = self.has_sliding_attention
+        if sliding != (self.sliding_window is not None):
+            raise ValueError(
+                "sliding_window is the window of the 'sliding_attention' "
+                f"layers of layer_types: got sliding_window "
+                f"{self.sliding_window} and layer_types {self.layer_types}")
+        if sliding and self.sliding_window < 1:
+            raise ValueError(
+                f"sliding_window must be >= 1, got {self.sliding_window}")
+        if sliding and self.window is not None:
+            raise ValueError(
+                "window windows every layer and sliding_window the "
+                "'sliding_attention' layers: give one of the two")
+        if self.attn_head_gate and self.attn_output_gate:
+            raise ValueError(
+                "attn_head_gate (a gate a head) and attn_output_gate (a gate "
+                "a column) are two forms of one gate: give one")
+        by_layer = [name for name, is_set in (
+            ("a 'sliding_attention' layer", sliding),
+            ("num_heads_per_layer", self.num_heads_per_layer is not None),
+            ("rope_parameters", self.rope_parameters is not None),
+            ("attn_head_gate", self.attn_head_gate)) if is_set]
+        if not by_layer:
+            return
+        for refused, why in (
+                (self.attention_impl not in ("dot", "flash"),
+                 f"attention_impl {self.attention_impl!r}: the ring rotates "
+                 "keys and values under one window and one head count"),
+                (self.shard_axis is not None,
+                 "shard_axis: the head slices of layers that differ are not "
+                 "sharded yet"),
+                (self.kv_lora_rank is not None,
+                 "latent attention: it has no window and its own rotary key"),
+                (self.block_diffusion is not None,
+                 "block_diffusion: its mask takes the place of causal and "
+                 "window")):
+            if refused:
+                raise ValueError(
+                    f"{', '.join(by_layer)} (attention that differs by "
+                    f"layer) takes no {why}")
 
     @property
     def has_linear_attention(self) -> bool:
         return (self.layer_types is not None
                 and "linear_attention" in self.layer_types)
+
+    @property
+    def has_sliding_attention(self) -> bool:
+        return (self.layer_types is not None
+                and "sliding_attention" in self.layer_types)
+
+    def layer_rope(self, kind: str) -> RopeParameters:
+        """``rope_parameters`` of a layer type; the model's ``rope_theta`` and
+        ``partial_rotary_factor`` for a type they do not name."""
+        return dict(self.rope_parameters or ()).get(kind) or RopeParameters(
+            self.rope_theta, self.partial_rotary_factor)
+
+    def attention_layers(self) -> list:
+        """For each layer what its attention is built from (the ``attn.layers``
+        event's fields): ``kind``, query ``heads``, ``kv_heads``, ``window``
+        (None: every position), ``rotary_columns`` of a head and ``rope_type``;
+        a 'linear_attention' layer has its kind alone."""
+        layers = []
+        kinds = self.layer_types or ("full_attention",) * self.num_layers
+        counts = self.num_heads_per_layer or (self.num_heads,) * self.num_layers
+        for kind, heads in zip(kinds, counts):
+            if kind not in ATTENTION_KINDS:
+                layers.append({"kind": kind})
+                continue
+            own = self.layer_rope(kind)
+            layers.append({
+                "kind": kind, "heads": heads,
+                "kv_heads": self.num_kv_heads or self.num_heads,
+                "window": (self.sliding_window if kind == "sliding_attention"
+                           else self.window),
+                "rotary_columns": int(self.head_dim * own.partial_rotary_factor),
+                "rope_type": own.rope_type})
+        return layers
 
     @property
     def mlp_hidden(self) -> int:
@@ -377,14 +547,43 @@ class TransformerConfig:
         return self.num_heads * self.head_dim
 
 
-def rope(x: jax.Array, positions: jax.Array,
-         theta: float = 10000.0) -> jax.Array:
-    """Rotary position embedding; x: (B, S, H, D), positions: (B, S)."""
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position_embeddings: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's ``dim // 2`` frequencies (arXiv:2309.00071, Hugging Face's
+    ``_compute_yarn_parameters``), float32, host arithmetic at trace time:
+    pair ``i`` turns at ``theta^(-2i/dim)`` (extrapolation) below the ramp and
+    at ``1 / factor`` of it (interpolation) above, blended linearly between
+    ``low`` and ``high``, the pairs that make ``beta_fast`` and ``beta_slow``
+    turns over the original context."""
+    extra = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    inter = extra / factor
+
+    def pair_of(turns):
+        return (dim * math.log(original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         inv_freq=None, scale: Optional[float] = None) -> jax.Array:
+    """Rotary position embedding; x: (B, S, H, D), positions: (B, S).
+    ``inv_freq``: the D/2 frequencies where they are not ``theta``'s
+    (``yarn_inv_freq``); ``scale``: YaRN's ``attention_factor`` on cos and sin."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (np.arange(0, d, 2) / d))
+    freqs = (1.0 / (theta ** (np.arange(0, d, 2) / d)) if inv_freq is None
+             else inv_freq)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
@@ -497,13 +696,18 @@ def _rms_norm(cfg: TransformerConfig):
         dtype=cfg.dtype, epsilon=cfg.rms_norm_eps)
 
 
-def _rotary(cfg: TransformerConfig, x, positions):
-    """RoPE on the first ``partial_rotary_factor`` of each head's columns."""
-    rot = int(cfg.head_dim * cfg.partial_rotary_factor)
+def _rotary(cfg: TransformerConfig, x, positions, own: RopeParameters):
+    """RoPE by ``own`` (``cfg.layer_rope`` of the layer's type) on the first
+    ``partial_rotary_factor`` of each head's columns."""
+    rot = int(cfg.head_dim * own.partial_rotary_factor)
+    turn = functools.partial(rope, positions=positions, theta=own.theta)
+    if own.yarn is not None:
+        turn = functools.partial(
+            turn, inv_freq=yarn_inv_freq(rot, own.theta, *own.yarn[:4]),
+            scale=own.attention_factor)
     if rot == cfg.head_dim:
-        return rope(x, positions, cfg.rope_theta)
-    return jnp.concatenate(
-        [rope(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]], axis=-1)
+        return turn(x)
+    return jnp.concatenate([turn(x[..., :rot]), x[..., rot:]], axis=-1)
 
 
 def causal_depthwise_conv(u, w):
@@ -546,8 +750,21 @@ def _shard_size(cfg: TransformerConfig) -> int:
     return tp
 
 
+def _own_scope(cfg: TransformerConfig, scope):
+    """``scope`` (a ``jax.named_scope``) in a model with a 'sliding_attention'
+    layer, so that a trace tells a window layer's kernels, rotary step and
+    gate from a full layer's; no scope, and so the ``op_name``s that were, in
+    every other model."""
+    return scope if cfg.has_sliding_attention else contextlib.nullcontext()
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # THIS layer where attention differs by layer: whether it is a
+    # 'sliding_attention' one (``cfg.layer_types``), and its query heads
+    # (``cfg.num_heads_per_layer``; None: ``cfg.num_heads``)
+    sliding: bool = False
+    heads: Optional[int] = None
 
     def _latent_qkv(self, x, positions, dense, heads):
         """Latent attention's q and k (``qk_nope_head_dim +
@@ -591,8 +808,19 @@ class Attention(nn.Module):
         # shard-invariant), and the output projection below reassembles
         # with one psum (row-parallel).
         tp = _shard_size(cfg)
-        heads = cfg.num_heads // tp
+        heads = (self.heads or cfg.num_heads) // tp
         kv_heads = kv_heads // tp
+        kind = ATTENTION_KINDS[self.sliding]
+        window = cfg.sliding_window if self.sliding else cfg.window
+        # the attention core alone: the kernel call or the dot path
+        core = (jax.named_scope("attn_window") if self.sliding
+                else jax.named_scope("attn_full"))
+        if paged is not None and (
+                cfg.has_sliding_attention or self.heads is not None):
+            raise ValueError(
+                "paged serving takes no 'sliding_attention' layer and no "
+                "num_heads_per_layer: the cache allocator keeps one window "
+                "and one head count for all layers")
         gate = None
         if cfg.kv_lora_rank is not None:
             if paged is not None:
@@ -612,8 +840,10 @@ class Attention(nn.Module):
             if cfg.qk_norm:
                 q = _rms_norm(cfg)(name="q_norm")(q)
                 k = _rms_norm(cfg)(name="k_norm")(k)
-            q = _rotary(cfg, q, positions)
-            k = _rotary(cfg, k, positions)
+            with _own_scope(cfg, jax.named_scope("attn_rope")):
+                own = cfg.layer_rope(kind)
+                q = _rotary(cfg, q, positions, own)
+                k = _rotary(cfg, k, positions, own)
         if paged is not None and cfg.block_diffusion is not None:
             raise ValueError("paged serving takes no block_diffusion model")
         if paged is not None:
@@ -683,14 +913,22 @@ class Attention(nn.Module):
         elif cfg.attention_impl == "flash":
             from ..ops.flash_attention import flash_attention
 
-            out = flash_attention(q, k, v, causal=cfg.causal,
-                                  window=cfg.window)
+            with _own_scope(cfg, core):
+                out = flash_attention(q, k, v, causal=cfg.causal,
+                                      window=window)
         else:
-            out = causal_dot_attention(q, k, v, causal=cfg.causal,
-                                       window=cfg.window)
+            with _own_scope(cfg, core):
+                out = causal_dot_attention(q, k, v, causal=cfg.causal,
+                                           window=window)
         if gate is not None:
             out = (out.astype(jnp.float32)
                    * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
+        if cfg.attn_head_gate:
+            # one number a head and token, from the layer's (normed) input
+            with _own_scope(cfg, jax.named_scope("attn_gate")):
+                head_gate = dense(features=heads, name="gate")(x)
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    head_gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
             use_bias=False, name="o",
@@ -877,6 +1115,9 @@ class Block(nn.Module):
     # whether THIS layer's mixer is the linear one (``GatedDeltaNet``):
     # ``cfg.layer_types`` of the layer
     linear: bool = False
+    # THIS layer's attention where it differs by layer (``Attention``'s)
+    sliding: bool = False
+    heads: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, positions, paged=None, layer: int = 0):
@@ -890,7 +1131,8 @@ class Block(nn.Module):
                     "recurrent state yet")
             x = x + GatedDeltaNet(cfg, name="linear_attn")(norm(name="ln1")(x))
         else:
-            x = x + Attention(cfg, name="attn")(
+            x = x + Attention(cfg, sliding=self.sliding, heads=self.heads,
+                              name="attn")(
                 norm(name="ln1")(x), positions, paged=paged, layer=layer)
         if not self.routed:
             x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
@@ -957,6 +1199,10 @@ class Transformer(nn.Module):
                     + local
                 )
             positions = jnp.broadcast_to(local, tokens.shape)
+        if _trace.enabled() and (
+                cfg.has_sliding_attention or cfg.rope_parameters is not None
+                or cfg.num_heads_per_layer is not None):
+            _trace.event("attn.layers", layers=cfg.attention_layers())
         emb = nn.Embed(
             cfg.vocab_size, cfg.d_model,
             dtype=cfg.dtype, name="embed",
@@ -981,6 +1227,9 @@ class Transformer(nn.Module):
             kinds = {}
             if cfg.layer_types is not None:
                 kinds["linear"] = cfg.layer_types[i] == "linear_attention"
+                kinds["sliding"] = cfg.layer_types[i] == "sliding_attention"
+            if cfg.num_heads_per_layer is not None:
+                kinds["heads"] = cfg.num_heads_per_layer[i]
             block = block_cls(cfg, routed=routed_here, name=f"layer_{i}",
                               **kinds)
             if paged is not None:

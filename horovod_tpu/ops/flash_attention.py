@@ -78,11 +78,15 @@ _SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
 
 _NEG_INF = -1e30
 
-# the causal dK/dV kernel holds a whole query-head group's q and dO in VMEM
-# (of the chip's 128 MiB) while they are at most this, twice buffered; beyond
-# it, one query head a program (_bwd_dkv_head_kernel).  The accepted cells'
-# are 8 MiB (2 x 4,096 x 128 + 128) and 10 MiB (8,192 x 192 + 128)
-_DKV_GROUP_BYTES = 48 * 1024 * 1024
+# the causal / windowed dK/dV kernel holds a whole query-head group's q and dO
+# in VMEM (of the chip's 128 MiB) while they are at most this, twice buffered,
+# and states what it needs past the compiler's own limit; beyond it, one query
+# head a program (_bwd_dkv_head_kernel), which fetches that head's q and dO
+# anew for every key tile.  The accepted cells' are 8 MiB (2 x 4,096 x 128 +
+# 128) and 10 MiB (8,192 x 192 + 128); 8,192 rows of 128 + 128 are 48 MiB at a
+# group of 6 and 64 MiB at a group of 8, where the group form ran the causal
+# mask 1.10 x and the 512 window 2.27 x as fast as the head form (PERF.md PR 39)
+_DKV_GROUP_BYTES = 64 * 1024 * 1024
 # what a kernel that states its VMEM asks for beside its resident operands
 _VMEM_HEADROOM = 16 * 1024 * 1024
 # the forward and dQ kernels hold a head's whole keys and values, twice
@@ -91,14 +95,20 @@ _VMEM_HEADROOM = 16 * 1024 * 1024
 _KV_RESIDENT_BYTES = 12 * 1024 * 1024
 
 
-def _kv_params(s_k, d, dv, dtype):
-    """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
-    values in VMEM: none while they fit the compiler's own limit."""
-    resident = 2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize
+def _resident_params(resident):
+    """``compiler_params`` for a kernel that holds ``resident`` bytes of whole
+    operands (twice buffered) in VMEM: none while they fit the compiler's own
+    limit, else what it needs, stated."""
     if resident <= _KV_RESIDENT_BYTES:
         return {}
     return {"compiler_params": _pltpu.CompilerParams(
         vmem_limit_bytes=resident + _VMEM_HEADROOM)}
+
+
+def _kv_params(s_k, d, dv, dtype):
+    """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
+    values in VMEM."""
+    return _resident_params(2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize)
 
 # tiles a loop iteration: a range runs four at a time, then what is left two
 # and one at a time (_run_tiles).  Starting at 8 was 2 % of the kernels' time
@@ -316,8 +326,9 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
 
 def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
     """One ``flash.tiles`` instant a kernel as it is traced: its name, a
-    head's ``visited`` tiles and loop ``iterations``, and the widths of a
-    tile's products (``d_qk`` of queries and keys, ``d_v`` of values).
+    head's ``visited`` tiles and loop ``iterations``, the ``window`` of its
+    mask (None: none) and the widths of a tile's products (``d_qk`` of
+    queries and keys, ``d_v`` of values).
     ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
     bookkeeping at trace time; a traced ``kv_offset`` (a ring step) has no
     count to give."""
@@ -329,7 +340,8 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
     for name, key in kernels.items():
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
-                     iterations=iterations, d_qk=d_qk, d_v=d_v)
+                     iterations=iterations, d_qk=d_qk, d_v=d_v,
+                     window=shape.get("window"))
 
 
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
@@ -915,6 +927,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             jax.ShapeDtypeStruct((bh_kv, s_k, dv_w), vf.dtype),
         ],
         interpret=interpret,
+        **_resident_params(group * head_bytes),
     )(off, qg, kf, vf, gg, lse_g, delta_g)
     return dq, dk, dv
 

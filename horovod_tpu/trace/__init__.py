@@ -117,6 +117,9 @@ SITES = (
                            # norms): rows, channels, taps, heads, row tile, programs, bytes
     "gdn.gated_norm",      # its output pass traced (norm(o) * silu(z)): rows, channels,
                            # value heads, row tile, programs, bytes
+    "attn.layers",         # a model whose attention differs by layer traced: each
+                           # layer's kind, heads, key/value heads, window, rotary
+                           # columns and RoPE type
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -136,7 +139,9 @@ DEVICE_SCOPES = (
 #: the same docs table.  They name no phase: ``trace/device.py`` reports
 #: their time beside the phases' (``subscopes``), and the benchmark's
 #: ``router_ms`` / ``expert_ffn_ms`` / ``mla_proj_ms`` / ``shared_expert_ms``
-#: / ``gdn_proj_ms`` / ``gated_delta_ms`` read them by ``op_name`` pattern.
+#: / ``gdn_proj_ms`` / ``gated_delta_ms`` / ``window_attention_ms`` /
+#: ``full_attention_ms`` / ``attn_rope_ms`` / ``attn_gate_ms`` read them by
+#: ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
@@ -163,6 +168,19 @@ DEVICE_SUBSCOPES = (
                     # key heads), XLA's unit-triangular inverse and the gates'
                     # rows a chunk; a SIBLING of ``gdn``, not nested in it, so
                     # that one pattern reads each
+    # models/transformer.py Attention, only in a model with a
+    # 'sliding_attention' layer (``TransformerConfig.layer_types``): other
+    # models' ``op_name``s are what they were
+    "attn_rope",    # the rotary step of q and k: the layer type's frequencies
+                    # (YaRN's blend where it has one), cos, sin, the partial
+                    # rotation
+    "attn_window",  # the attention CORE of a sliding layer: the flash kernels
+                    # under ``window=sliding_window`` (or the dot path), their
+                    # folds and pads; not the projections
+    "attn_full",    # the same of a full layer: the kernels that share their
+                    # names with a sliding layer's are told apart by this
+    "attn_gate",    # the gate a head: the stream times (d_model, heads),
+                    # sigmoid, times the attention's output
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of

@@ -683,3 +683,137 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
     # the step's only scatters are the embedding's and the loss's gradients
     scatters = [l for l in text.splitlines() if re.search(r"= (\(.*?\)|\S+) scatter\(", l)]
     assert not [l for l in scatters if "/moe/" in l or "/linear_attn/" in l], scatters
+
+
+# -- attention that differs by layer in one compiled step (PR 39) --------------
+
+_BY_LAYER_SCOPES = ("attn_window", "attn_full", "attn_rope", "attn_gate")
+
+
+def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(topo, monkeypatch):
+    """A full-attention layer over a dense feed-forward and a sliding-window layer
+    over routed experts at the widths of laguna-xs.2-s8192-1chip (8,192 tokens; 48
+    and 64 query heads over 8 key/value heads of 128; window 512; YaRN on half of
+    each head of the full layer; a gate a head) through the train step: each of
+    the three flash kernels is called once unwindowed at 48 heads under
+    ``attn_full`` and once at 64 heads under ``attn_window``; the dK/dV kernel
+    holds a whole group's q and dO and states its VMEM (48 + 16 MiB at the group
+    of 6, 64 + 16 MiB at the group of 8: the compiler's own limit is 16 MiB); the
+    rotary step and the gate lie under their scopes, forward and backward."""
+    import functools
+
+    from horovod_tpu.models.transformer import next_token_loss
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=2, num_heads=48, num_kv_heads=8, head_dim=128,
+        hidden_size=2048, max_seq_len=8192, dtype=jnp.bfloat16, attention_impl="flash",
+        rms_norm_eps=1e-6, tie_word_embeddings=False,
+        layer_types=("full_attention", "sliding_attention"), sliding_window=512,
+        num_heads_per_layer=(48, 64), attn_head_gate=True,
+        rope_parameters={
+            "full_attention": dict(
+                theta=5e5, partial_rotary_factor=0.5, factor=64.0,
+                original_max_position_embeddings=4096, beta_fast=64.0, beta_slow=1.0,
+                attention_factor=1.4158883083359672),
+            "sliding_attention": dict(theta=1e4)},
+        intermediate_size=8192, first_dense_layers=1, num_experts=256, num_experts_per_tok=8,
+        moe_intermediate_size=512, held_experts=(0, 16), num_shared_experts=1,
+        router_scoring="sigmoid", routed_scaling_factor=2.5)
+    model, optimizer = Transformer(cfg), optax.adamw(1e-7)
+    replicated = NamedSharding(mesh, P())
+    batch = NamedSharding(mesh, P(WORLD_AXIS))
+    state = jax.eval_shape(lambda: training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)))
+    state = jax.tree_util.tree_map(lambda s: _sds(s.shape, s.dtype, replicated), state)
+    step = training.data_parallel_train_step(
+        model, optimizer, mesh=mesh,
+        loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
+    tokens = _sds((1, 8192), jnp.int32, batch)
+    compiled = step.lower(state, tokens, tokens).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    calls = {}
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"%(flash_attention\w*?)\.\d+ = [^\n]*tpu_custom_call", line)
+        if m:
+            calls.setdefault(m.group(1), []).append((
+                re.search(r'op_name="([^"]*)"', line).group(1),
+                re.search(r"operand_layout_constraints=\{(.*?)\}, frontend", line).group(1),
+                re.search(r'"scoped_memory_configs":\[(.*?)\]', line).group(1)))
+    assert sorted(calls) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                             "flash_attention_fwd"]
+    for name, found in calls.items():
+        by_scope = {next(p for p in op.split("/") if p in _BY_LAYER_SCOPES): (op, operands, vmem)
+                    for op, operands, vmem in found}
+        assert sorted(by_scope) == ["attn_full", "attn_window"], (name, found)
+        assert "/layer_0/attn/attn_full/" in by_scope["attn_full"][0]
+        assert "/layer_1/attn/attn_window/" in by_scope["attn_window"][0]
+        # q (and in dK/dV the whole group's rows) at the layer's own head count
+        rows = {"attn_full": "bf16[8,49152,128]", "attn_window": "bf16[8,65536,128]"} \
+            if name == "flash_attention_bwd_dkv" else \
+            {"attn_full": "bf16[48,8192,128]", "attn_window": "bf16[64,8192,128]"}
+        for scope, want in rows.items():
+            assert want in by_scope[scope][1], (name, scope, by_scope[scope][1])
+        if name == "flash_attention_bwd_dkv":
+            assert '"size":"67108864"' in by_scope["attn_full"][2]
+            assert '"size":"83886080"' in by_scope["attn_window"][2]
+    op_names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+    for scope in ("attn_rope", "attn_gate"):
+        for phase in ("jvp(forward)", "transpose(jvp(forward))"):
+            assert any(f"/{scope}/" in o and phase in o.split("/") for o in op_names), (scope, phase)
+
+
+def _lowered_names(cfg, tokens, labels=None, **step_kw):
+    """The locations of a model's lowered step: where a ``jax.named_scope`` shows
+    before any compiler has seen the program."""
+    model, optimizer = Transformer(cfg), optax.adamw(1e-3)
+    state = jax.eval_shape(lambda: training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), tokens[:1]))
+    step = training.data_parallel_train_step(
+        model, optimizer, mesh=Mesh(np.array(jax.devices()[:1]), (WORLD_AXIS,)), **step_kw)
+    return step.lower(state, tokens, tokens if labels is None else labels).as_text(
+        debug_info=True)
+
+
+@pytest.mark.parametrize("name", ["internlm2", "sdar", "kimi", "qwen3next", "by_layer"])
+def test_accepted_models_steps_hold_none_of_the_by_layer_scopes(name):
+    """The four accepted language models' steps (their cells' kinds of layer at
+    small sizes) name no ``attn_window`` / ``attn_full`` / ``attn_rope`` /
+    ``attn_gate``: their ``op_name``s, and so their fixtures and what their
+    metrics read, are what they were.  A model with a sliding layer names all
+    four (the same lowering, so the pattern would have found them)."""
+    import functools
+
+    from horovod_tpu.models import transformer
+
+    tokens, labels, kw = jnp.zeros((1, 64), jnp.int32), None, {}
+    base = dict(vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+                max_seq_len=64, dtype=jnp.float32, attention_impl="dot")
+    routed = dict(num_experts=8, num_experts_per_tok=2, moe_intermediate_size=8,
+                  held_experts=(0, 4), hidden_size=64)
+    if name == "sdar":
+        base.update(routed, qk_norm=True, block_diffusion=4, tie_word_embeddings=False)
+        labels = (jnp.zeros((1, 32), jnp.int32), jnp.ones((1, 32), jnp.float32))
+        kw["loss_fn"] = transformer.block_diffusion_loss
+    elif name == "kimi":
+        base.update(routed, num_kv_heads=None, kv_lora_rank=16, qk_nope_head_dim=8,
+                    qk_rope_head_dim=4, v_head_dim=8, first_dense_layers=1,
+                    num_shared_experts=2, router_scoring="sigmoid", routed_scaling_factor=2.4,
+                    router_selection_bias=True, router_seq_aux=True)
+    elif name == "qwen3next":
+        base.update(routed, qk_norm=True, norm_zero_centered=True, attn_output_gate=True,
+                    partial_rotary_factor=0.25, num_shared_experts=1, shared_expert_gate=True,
+                    layer_types=("linear_attention", "full_attention"),
+                    linear_num_key_heads=2, linear_key_head_dim=8, linear_num_value_heads=4,
+                    linear_value_head_dim=8)
+    elif name == "by_layer":
+        base.update(routed, layer_types=("full_attention", "sliding_attention"),
+                    sliding_window=16, num_heads_per_layer=(4, 6), attn_head_gate=True,
+                    rope_parameters={"sliding_attention": dict(theta=1e4)})
+    if "num_experts" in base:
+        kw.setdefault("loss_fn", functools.partial(transformer.next_token_loss, aux_coef=0.001))
+    text = _lowered_names(TransformerConfig(**base), tokens, labels, **kw)
+    assert "forward" in text            # the phase scopes are in these locations
+    found = {s for s in _BY_LAYER_SCOPES if re.search(rf"\b{s}\b", text)}
+    assert found == (set(_BY_LAYER_SCOPES) if name == "by_layer" else set()), found

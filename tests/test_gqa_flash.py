@@ -92,12 +92,21 @@ def test_flash_gqa_matches_oracle(ratio, causal, window):
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (6, 1), (8, 1)],
+                         ids=["group2", "group6", "group8"])
 @pytest.mark.parametrize("window", [None, 150])
-def test_flash_gqa_gradients_match_oracle(window):
+def test_flash_gqa_gradients_match_oracle(window, heads, kv_heads):
     """GQA backward: dq per query head, dk/dv per KV head (the in-VMEM
     group accumulation) vs autodiff through the repeat oracle — whose
-    repeat-transpose IS the grouped sum."""
-    q, k, v = _qkv(1, 320, 4, 2, 32, seed=5)
+    repeat-transpose IS the grouped sum.  Groups of 6 and of 8 are the
+    window-and-full-attention cell's (48 and 64 query heads over 8), the
+    causal mask and a window that crosses the 128-tiles."""
+    q, k, v = _qkv(1, 320, heads, kv_heads, 32, seed=5)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, window=window, block_q=128,
+                                   block_k=128)),
+        np.asarray(repeat_oracle(q, k, v, window=window)),
+        rtol=2e-5, atol=2e-5)
 
     gf = jax.grad(
         lambda a, b, c: (flash_attention(
@@ -316,10 +325,16 @@ def test_block_diffusion_tile_ranges_visit_what_the_mask_allows(
     ("sdar-30b-a3b-bd4-s4096-1chip",
      dict(s_q=8192, s_k=8192, seq_len=8192, causal=False, bd=(4096, 4)),
      (288, 104)),
+    ("laguna-xs.2-s8192-1chip/full",
+     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True), (528, 152)),
+    ("laguna-xs.2-s8192-1chip/sliding",
+     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True, window=512), (93, 62)),
 ])
 def test_tile_counts_at_the_cells_shapes(cell, kw, want):
     """A head's tile visits and the loop iterations they take in 256-tiles:
-    3.1 and 2.8 tiles an iteration for the scheduler to overlap."""
+    3.1 and 2.8 tiles an iteration for the scheduler to overlap (3.5 at
+    8,192 under the causal mask); a window of 512 visits three tiles a
+    query tile, the diagonal one and two before it, in two iterations."""
     counts = tile_counts(block_q=256, block_k=256, **kw)
     assert counts == {"fwd": want, "bwd_dq": want, "bwd_dkv": want}
 

@@ -242,7 +242,7 @@ def leg_window(b, s, h, d, windows, iters, warmup, interpret,
         )
 
 
-# (b, s, h, h_kv, d, causal, block_diffusion) of the benchmark's three
+# (b, s, h, h_kv, d, causal, block_diffusion[, window]) of the benchmark's
 # transformer cells (benchmark/configs/): what a layer's attention sees.
 # ``d`` = (d_qk, d_v) where keys and values differ in width (latent
 # attention: 128 + 64 rotary against 128)
@@ -251,6 +251,8 @@ CELL_SHAPES = {
     "sdar-30b-a3b-bd4-s4096-1chip": (1, 8192, 32, 4, 128, False, (4096, 4)),
     "kimi-vl-a3b-s8192-1chip": (1, 8192, 16, 16, (192, 128), True, None),
     "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 16, 2, 256, True, None),
+    "laguna-xs.2-s8192-1chip/sliding": (1, 8192, 64, 8, 128, True, None, 512),
+    "laguna-xs.2-s8192-1chip/full": (1, 8192, 48, 8, 128, True, None),
 }
 
 
@@ -261,16 +263,17 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
     head's tile visits and the loop iterations they take (``tile_counts``)
     and the time a tile visit, which PERF.md §5 holds against the 0.085 us
     a 256 x 256 x 128 product needs on the v5e's MXU."""
-    for cell, (b, s, h, h_kv, d, causal, bd) in shapes.items():
+    for cell, (b, s, h, h_kv, d, causal, bd, *window) in shapes.items():
+        window = window[0] if window else None
         q, k, v = _qkv(b, s, h, h_kv, d)
         g = _qkv(b, s, h, h, d, seed=1)[2]  # dO: every query head, as wide as v
         def fwd(q, k, v):
             return _forward_impl(q, k, v, causal, block, block, interpret,
-                                 with_lse=True, bd=bd)
+                                 with_lse=True, window=window, bd=bd)
 
         def bwd(q, k, v, out, lse, g):
             return _backward_impl(q, k, v, out, lse, g, causal, block, block,
-                                  interpret, bd=bd)
+                                  interpret, window=window, bd=bd)
 
         def timed(fn, *args):
             fn = jax.jit(fn)
@@ -289,9 +292,9 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
               "bwd_dkv": timed(lambda *a: bwd(*a)[1:], q, k, v, out, lse, g)}
         bq, bk = _clamp_blocks(s, block, block)
         tiles = tile_counts(_pad(s, bq), _pad(s, bk), bq, bk, s,
-                            causal=causal, bd=bd)
+                            causal=causal, window=window, bd=bd)
         rec = {"bench": "flash_cells", "cell": cell, "b": b, "s": s, "h": h,
-               "h_kv": h_kv, "d": d, "block": [bq, bk]}
+               "h_kv": h_kv, "d": d, "window": window, "block": [bq, bk]}
         for name, t in ms.items():
             visited, iterations = tiles[name]
             rec[name + "_ms"] = round(t, 4)
