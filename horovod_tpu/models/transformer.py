@@ -571,14 +571,17 @@ def yarn_inv_freq(dim: int, theta: float, factor: float,
     return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
+def rope_frequencies(d: int, theta: float) -> np.ndarray:
+    """Plain RoPE's ``d // 2`` frequencies: pair ``i`` turns at ``theta^(-2i/d)``."""
+    return 1.0 / (theta ** (np.arange(0, d, 2) / d))
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
          inv_freq=None, scale: Optional[float] = None) -> jax.Array:
     """Rotary position embedding; x: (B, S, H, D), positions: (B, S).
     ``inv_freq``: the D/2 frequencies where they are not ``theta``'s
     (``yarn_inv_freq``); ``scale``: YaRN's ``attention_factor`` on cos and sin."""
-    d = x.shape[-1]
-    freqs = (1.0 / (theta ** (np.arange(0, d, 2) / d)) if inv_freq is None
-             else inv_freq)
+    freqs = rope_frequencies(x.shape[-1], theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -696,18 +699,46 @@ def _rms_norm(cfg: TransformerConfig):
         dtype=cfg.dtype, epsilon=cfg.rms_norm_eps)
 
 
+def _rotary_terms(cfg: TransformerConfig, own: RopeParameters):
+    """The rotated columns a head, their ``rot / 2`` frequencies and the factor
+    on cos and sin (None: 1) by ``own``: YaRN's where it has one."""
+    rot = int(cfg.head_dim * own.partial_rotary_factor)
+    if own.yarn is None:
+        return rot, rope_frequencies(rot, own.theta), None
+    return rot, yarn_inv_freq(rot, own.theta, *own.yarn[:4]), own.attention_factor
+
+
 def _rotary(cfg: TransformerConfig, x, positions, own: RopeParameters):
     """RoPE by ``own`` (``cfg.layer_rope`` of the layer's type) on the first
     ``partial_rotary_factor`` of each head's columns."""
-    rot = int(cfg.head_dim * own.partial_rotary_factor)
-    turn = functools.partial(rope, positions=positions, theta=own.theta)
-    if own.yarn is not None:
-        turn = functools.partial(
-            turn, inv_freq=yarn_inv_freq(rot, own.theta, *own.yarn[:4]),
-            scale=own.attention_factor)
+    rot, freqs, scale = _rotary_terms(cfg, own)
+    turn = functools.partial(rope, positions=positions, inv_freq=freqs, scale=scale)
     if rot == cfg.head_dim:
         return turn(x)
     return jnp.concatenate([turn(x[..., :rot]), x[..., rot:]], axis=-1)
+
+
+def _rotary_qk(cfg: TransformerConfig, q, k, positions, own: RopeParameters):
+    """``_rotary`` of q and k.  A 'flash' model whose shapes the rotary kernels
+    take (``ops/rope_kernel.py`` ``engages``: 128-wide heads, rows that tile)
+    rotates whole heads in one pass each and hands the flash kernels their own
+    head-major layout; every other shape and model keeps ``rope``."""
+    from ..ops import rope_kernel
+
+    rot, freqs, scale = _rotary_terms(cfg, own)
+    kernel = cfg.attention_impl == "flash" and all(
+        rope_kernel.engages(x.shape, rot) for x in (q, k))
+    if _trace.enabled():
+        sizes = (rope_kernel.counts(q.shape, k.shape[2], rot, q.dtype.itemsize) if kernel
+                 else dict(row_tile=0, programs=0, hbm_bytes=0))
+        _trace.event(
+            "rope.rotate", rows=q.shape[0] * q.shape[1], heads=q.shape[2],
+            kv_heads=k.shape[2], head_dim=q.shape[3], rot=rot, rope_type=own.rope_type,
+            kernel=kernel, **sizes)
+    if not kernel:
+        return _rotary(cfg, q, positions, own), _rotary(cfg, k, positions, own)
+    c, s = rope_kernel.tables(positions, freqs, rot, scale)
+    return rope_kernel.rotate(q, c, s, rot), rope_kernel.rotate(k, c, s, rot)
 
 
 def causal_depthwise_conv(u, w):
@@ -842,8 +873,7 @@ class Attention(nn.Module):
                 k = _rms_norm(cfg)(name="k_norm")(k)
             with _own_scope(cfg, jax.named_scope("attn_rope")):
                 own = cfg.layer_rope(kind)
-                q = _rotary(cfg, q, positions, own)
-                k = _rotary(cfg, k, positions, own)
+                q, k = _rotary_qk(cfg, q, k, positions, own)
         if paged is not None and cfg.block_diffusion is not None:
             raise ValueError("paged serving takes no block_diffusion model")
         if paged is not None:

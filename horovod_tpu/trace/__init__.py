@@ -120,6 +120,10 @@ SITES = (
     "attn.layers",         # a model whose attention differs by layer traced: each
                            # layer's kind, heads, key/value heads, window, rotary
                            # columns and RoPE type
+    "rope.rotate",         # a layer's rotary step of q and k traced: rows, heads,
+                           # key/value heads, head width, rotated columns, RoPE type,
+                           # whether the kernel pair engaged, its row tile, programs
+                           # and the bytes a pass moves
 )
 
 #: Device phase scopes — every ``jax.named_scope("...")`` literal in the
@@ -172,8 +176,8 @@ DEVICE_SUBSCOPES = (
     # 'sliding_attention' layer (``TransformerConfig.layer_types``): other
     # models' ``op_name``s are what they were
     "attn_rope",    # the rotary step of q and k: the layer type's frequencies
-                    # (YaRN's blend where it has one), cos, sin, the partial
-                    # rotation
+                    # (YaRN's blend where it has one), the cos and sin tables,
+                    # the (partial) rotation: the rope_* kernels since PR 40
     "attn_window",  # the attention CORE of a sliding layer: the flash kernels
                     # under ``window=sliding_window`` (or the dot path), their
                     # folds and pads; not the projections
@@ -184,14 +188,15 @@ DEVICE_SUBSCOPES = (
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
-#: ops/flash_attention.py, ops/grouped_matmul.py, ops/gated_delta.py and
-#: ops/gdn_kernels.py, one name a kernel; the
+#: ops/flash_attention.py, ops/grouped_matmul.py, ops/gated_delta.py,
+#: ops/gdn_kernels.py and ops/rope_kernel.py, one name a kernel; the
 #: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
 #: attention kernels all start with ``flash_attention`` so one pattern still
 #: reads them together; the routed experts' grouped products do not, and are
 #: read by the ``experts`` scope they run in, as the gated delta rule's three
 #: (``gated_delta*``) are by the ``gated_delta`` scope and Gated DeltaNet's
-#: two passes (``gdn_*``) by the ``gdn`` scope.
+#: two passes (``gdn_*``) by the ``gdn`` scope and, in a model with a sliding
+#: layer, the rotary step's two (``rope_*``) by the ``attn_rope`` scope.
 DEVICE_KERNELS = (
     "flash_attention_fwd",      # _forward_impl
     "flash_attention_bwd_dq",   # _backward_folded: dQ
@@ -221,6 +226,9 @@ DEVICE_KERNELS = (
     "gdn_gated_norm_fwd",  # norm(o) * silu(z) on the rule's rows, z read out of
                            # the projection's rows in place
     "gdn_gated_norm_bwd",  # do, dz and the norm's scale's gradient
+    "rope_fwd",  # ops/rope_kernel.py: q or k rotated on whole heads, head-major in
+                 # and out, one pass (models whose heads are 128 lanes wide)
+    "rope_bwd",  # the cotangent's pass: the same rotation with sin negated
 )
 
 ENV_TRACE = "HVD_TPU_TRACE"

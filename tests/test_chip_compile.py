@@ -427,18 +427,22 @@ def test_fused_norm_compiles(one_chip, shape, backward):
 # -- whole train steps, as chip_smoke.py runs them ----------------------------
 
 
-def _step_compiled(model, optimizer, mesh, sample, inputs, labels):
+def _step_compiled(model, optimizer, mesh, sample, inputs, labels, **step_kw):
     """training.data_parallel_train_step over ``mesh`` (described devices),
-    lowered from shapes: replicated state, batch sharded over the axis."""
+    lowered from shapes: replicated state, batch sharded over the axis.
+    ``labels``: one (shape, dtype) or a tuple of them."""
     replicated = NamedSharding(mesh, P())
     batch = NamedSharding(mesh, P(WORLD_AXIS))
     state = jax.eval_shape(lambda: training.create_train_state(
         model, optimizer, jax.random.PRNGKey(0), sample))
     state = jax.tree_util.tree_map(
         lambda s: _sds(s.shape, s.dtype, replicated), state)
-    step = training.data_parallel_train_step(model, optimizer, mesh=mesh)
-    return step.lower(state, _sds(*inputs, batch),
-                      _sds(*labels, batch)).compile()
+    step = training.data_parallel_train_step(model, optimizer, mesh=mesh, **step_kw)
+    several = isinstance(labels[0][0], tuple)
+    return step.lower(
+        state, _sds(*inputs, batch),
+        tuple(_sds(*one, batch) for one in labels) if several else _sds(*labels, batch)
+    ).compile()
 
 
 def _resnet_step(topo, n_devices, batch, dtype, bn_axis_name=None):
@@ -690,21 +694,17 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
 _BY_LAYER_SCOPES = ("attn_window", "attn_full", "attn_rope", "attn_gate")
 
 
-def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def by_layer_step(topo):
     """A full-attention layer over a dense feed-forward and a sliding-window layer
     over routed experts at the widths of laguna-xs.2-s8192-1chip (8,192 tokens; 48
     and 64 query heads over 8 key/value heads of 128; window 512; YaRN on half of
-    each head of the full layer; a gate a head) through the train step: each of
-    the three flash kernels is called once unwindowed at 48 heads under
-    ``attn_full`` and once at 64 heads under ``attn_window``; the dK/dV kernel
-    holds a whole group's q and dO and states its VMEM (48 + 16 MiB at the group
-    of 6, 64 + 16 MiB at the group of 8: the compiler's own limit is 16 MiB); the
-    rotary step and the gate lie under their scopes, forward and backward."""
+    each head of the full layer; a gate a head), the train step compiled once for
+    the tests below."""
     import functools
 
     from horovod_tpu.models.transformer import next_token_loss
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
     cfg = TransformerConfig(
         vocab_size=1024, num_layers=2, num_heads=48, num_kv_heads=8, head_dim=128,
@@ -721,17 +721,22 @@ def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(topo, mon
         intermediate_size=8192, first_dense_layers=1, num_experts=256, num_experts_per_tok=8,
         moe_intermediate_size=512, held_experts=(0, 16), num_shared_experts=1,
         router_scoring="sigmoid", routed_scaling_factor=2.5)
-    model, optimizer = Transformer(cfg), optax.adamw(1e-7)
-    replicated = NamedSharding(mesh, P())
-    batch = NamedSharding(mesh, P(WORLD_AXIS))
-    state = jax.eval_shape(lambda: training.create_train_state(
-        model, optimizer, jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)))
-    state = jax.tree_util.tree_map(lambda s: _sds(s.shape, s.dtype, replicated), state)
-    step = training.data_parallel_train_step(
-        model, optimizer, mesh=mesh,
-        loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
-    tokens = _sds((1, 8192), jnp.int32, batch)
-    compiled = step.lower(state, tokens, tokens).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return _step_compiled(
+            Transformer(cfg), optax.adamw(1e-7), mesh, jnp.zeros((1, 8192), jnp.int32),
+            ((1, 8192), jnp.int32), ((1, 8192), jnp.int32),
+            loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
+
+
+def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(by_layer_step):
+    """Laguna's two kinds of layer through the train step: each of
+    the three flash kernels is called once unwindowed at 48 heads under
+    ``attn_full`` and once at 64 heads under ``attn_window``; the dK/dV kernel
+    holds a whole group's q and dO and states its VMEM (48 + 16 MiB at the group
+    of 6, 64 + 16 MiB at the group of 8: the compiler's own limit is 16 MiB); the
+    rotary step and the gate lie under their scopes, forward and backward."""
+    compiled = by_layer_step
     assert _device_bytes(compiled) < HBM_BYTES
     calls = {}
     for line in compiled.as_text().splitlines():
@@ -762,6 +767,122 @@ def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(topo, mon
     for scope in ("attn_rope", "attn_gate"):
         for phase in ("jvp(forward)", "transpose(jvp(forward))"):
             assert any(f"/{scope}/" in o and phase in o.split("/") for o in op_names), (scope, phase)
+
+
+def _instructions(text):
+    """``{name: (shape, opcode, operand names, op_name)}`` of the ENTRY computation."""
+    found = {}
+    for line in _entry_instructions(text):
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*?)\)(?:, |$)", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            found[m.group(1)] = (m.group(2), m.group(3), re.findall(r"%([\w.-]+)", m.group(4)),
+                                 op.group(1) if op else "")
+    return found
+
+
+def _made_by(instructions, name):
+    """The instruction that made ``name``'s bytes: through bitcasts and tuple reads."""
+    while instructions[name][1] in ("bitcast", "get-tuple-element"):
+        name = instructions[name][2][0]
+    return name
+
+
+def _rotary_step_is_one_pass_a_direction(text, layers, rows, heads, kv_heads, d, under=""):
+    """What PR 40 holds the rotary step to in a compiled step: ``rope_fwd`` and
+    ``rope_bwd`` once each for q and for k a layer; under the step's ``op_name``s
+    (``under``; the attention module's where the model names no scope) no float32 tensor of
+    q's or k's size and none of theirs whose last dimension is half a head; and
+    the layouts handed over as they are: each flash forward reads what a
+    ``rope_fwd`` wrote, each ``rope_bwd`` what a flash backward kernel wrote, and
+    no ``copy`` / ``transpose`` stands before or after a ``rope_*`` call."""
+    found = _instructions(text)
+    calls = {kind: [n for n in found if n.startswith(kind + ".")]
+             for kind in ("rope_fwd", "rope_bwd", "flash_attention_fwd")}
+    assert len(calls["rope_fwd"]) == len(calls["rope_bwd"]) == 2 * layers, calls
+    sizes = {rows * n * d for n in set(heads) | {kv_heads}}
+    for name, (shape, opcode, _, op_name) in found.items():
+        if under in op_name and opcode != "custom-call":
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
+                dims = [int(x) for x in dims.split(",")]
+                size = int(np.prod(dims))
+                assert not (dtype == "f32" and size in sizes), (name, shape, op_name)
+                assert not (dims[-1] == d // 2 and 2 * size in sizes), (name, shape, op_name)
+    for name in calls["flash_attention_fwd"]:
+        q, k = (_made_by(found, x) for x in found[name][2][1:3])
+        assert q.startswith("rope_fwd.") and k.startswith("rope_fwd."), (name, q, k)
+    for name in calls["rope_bwd"]:
+        source = _made_by(found, found[name][2][0])
+        assert source.startswith(("flash_attention_bwd_dq", "flash_attention_bwd_dkv")), \
+            (name, source)
+    rotary = set(calls["rope_fwd"] + calls["rope_bwd"])
+    for name, (shape, opcode, operands, _) in found.items():
+        if name in rotary:
+            assert found[_made_by(found, operands[0])][1] not in ("copy", "transpose"), name
+        elif opcode in ("copy", "transpose"):
+            assert not rotary & {_made_by(found, x) for x in operands}, (name, shape)
+
+
+def test_rotary_step_of_window_and_full_layers_is_one_pass_a_direction(by_layer_step):
+    """Laguna's two kinds of layer: whole head at 64 heads, YaRN on 64 of 128
+    columns at 48, both over 8 key/value heads (PR 40; before, XLA rotated float32
+    copies of q in 64-wide halves: 0.9 GB through HBM a layer and pass)."""
+    _rotary_step_is_one_pass_a_direction(
+        by_layer_step.as_text(), layers=2, rows=8192, heads=(48, 64), kv_heads=8, d=128,
+        under="/attn_rope/")
+
+
+def test_rotary_step_under_q_k_norms_and_block_diffusion_is_one_pass_a_direction(
+        topo, monkeypatch):
+    """SDAR's kind of layer at its cell's widths (32 query heads over 4 of 128, q /
+    k norms before the rotation, positions ``[0..L) || [0..L)``, the block-diffusion
+    kernels) over a dense feed-forward: the model names no scope for the step, so
+    it is the attention module that holds no float32 q and no half-head tensor."""
+    from horovod_tpu.models.transformer import block_diffusion_loss
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    cfg = TransformerConfig(
+        vocab_size=1024, num_layers=1, num_heads=32, num_kv_heads=4, head_dim=128,
+        hidden_size=2048, mlp_ratio=1, max_seq_len=8192, dtype=jnp.bfloat16,
+        attention_impl="flash", rms_norm_eps=1e-6, tie_word_embeddings=False, qk_norm=True,
+        block_diffusion=4, rope_theta=1e6)
+    compiled = _step_compiled(
+        Transformer(cfg), optax.adamw(1e-7), mesh, jnp.zeros((1, 8192), jnp.int32),
+        ((1, 8192), jnp.int32), (((1, 4096), jnp.int32), ((1, 4096), jnp.float32)),
+        loss_fn=block_diffusion_loss)
+    assert "flash_attention_bwd_dkv_bd" in compiled.as_text()
+    _rotary_step_is_one_pass_a_direction(
+        compiled.as_text(), layers=1, rows=8192, heads=(32,), kv_heads=4, d=128,
+        under="/attn/")
+
+
+# sha256 of the step below lowered at the parent of PR 40 (commit 6fbca40), this
+# test's own lines run in that tree
+_LATENT_STEP_AT_THE_PARENT = "8e42198242e0c1ff27a999e06d1b52e67ded0354063ab32a61483922a48e245f"
+
+
+def test_latent_attention_s_step_is_the_parent_s_to_the_byte():
+    """Kimi's kind of layer at its head widths (128 + 64 rotary columns a query
+    head, ONE 64-wide rotary key for all heads, 'flash'): a 64-wide rotary slice
+    of a 192-wide head is no shape the rotary kernels take, so the lowered step
+    holds none and is the one the parent lowered."""
+    import hashlib
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=1, num_heads=4, head_dim=192, hidden_size=128, mlp_ratio=1,
+        max_seq_len=256, dtype=jnp.bfloat16, attention_impl="flash", kv_lora_rank=64,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, rope_theta=8e5,
+        tie_word_embeddings=False)
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    model, optimizer = Transformer(cfg), optax.adamw(1e-3)
+    state = jax.eval_shape(lambda: training.create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), tokens))
+    step = training.data_parallel_train_step(
+        model, optimizer, mesh=Mesh(np.array(jax.devices()[:1]), (WORLD_AXIS,)))
+    text = step.lower(state, tokens, tokens).as_text()
+    assert "rope_fwd" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _LATENT_STEP_AT_THE_PARENT
 
 
 def _lowered_names(cfg, tokens, labels=None, **step_kw):

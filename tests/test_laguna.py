@@ -381,6 +381,36 @@ def test_trains_through_the_normal_path():
     assert losses[-1] < 0.6 * losses[0], losses
 
 
+def test_rotary_kernels_engaged_give_the_dot_model_s_loss_and_gradients():
+    """128-wide heads and rows that tile: the 'flash' model rotates q and k by
+    the kernel pair of ``ops/rope_kernel.py`` (interpreted; plain RoPE on the
+    whole head in the sliding layer, YaRN on half of it in the full one), the
+    'dot' model by ``rope``: one loss and one gradient on every leaf."""
+    import functools
+
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 49), 0, 64)
+    loss_fn = functools.partial(transformer.next_token_loss, aux_coef=0.001)
+    found = {}
+    for impl in ("dot", "flash"):
+        cfg = _config(attention_impl=impl, head_dim=128, num_layers=2, layer_types=KINDS[:2],
+                      num_heads_per_layer=HEADS[:2], sliding_window=20)
+        model = Transformer(cfg)
+        t0 = trace.now()
+        params = model.init(jax.random.PRNGKey(1), tokens[:, :-1])["params"]
+        found[impl] = jax.value_and_grad(lambda p: loss_fn(
+            model.apply({"params": p}, tokens[:, :-1]), tokens[:, 1:]))(params)
+        events = [r[3] for r in trace.snapshot(t0) if r[0] == "rope.rotate"]
+        assert events and all(e["kernel"] == (impl == "flash") for e in events)
+        assert {(e["heads"], e["rot"], e["rope_type"]) for e in events} == {
+            (6, 64, "yarn"), (8, 128, "default")}
+    (loss, grads), (want_loss, want_grads) = found["flash"], found["dot"]
+    np.testing.assert_allclose(loss, want_loss, rtol=2e-6)
+    flat, want_flat = (jax.tree_util.tree_leaves_with_path(g) for g in (grads, want_grads))
+    for (path, got), (_, want) in zip(flat, want_flat):
+        np.testing.assert_allclose(got, want, atol=3e-6 * float(jnp.max(jnp.abs(want))) + 1e-9,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 # -- refusals ---------------------------------------------------------------------------------
 
 

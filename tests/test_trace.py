@@ -1298,7 +1298,8 @@ def test_device_names_catalogue_matches_the_code():
     assert [k for k in trace.DEVICE_KERNELS if not k.startswith("flash_attention_")] \
         == ["grouped_matmul", "grouped_matmul_t", "gated_delta_kkt",
             "gated_delta_fwd", "gated_delta_bwd", "gdn_conv_norm_fwd",
-            "gdn_conv_norm_bwd", "gdn_gated_norm_fwd", "gdn_gated_norm_bwd"]
+            "gdn_conv_norm_bwd", "gdn_gated_norm_fwd", "gdn_gated_norm_bwd",
+            "rope_fwd", "rope_bwd"]
     assert not os.path.exists(
         os.path.join(repo, "horovod_tpu", "utils", "profiler.py"))
 

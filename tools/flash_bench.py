@@ -31,6 +31,10 @@ Usage:
                                  #  jnp functions, DEVICE time from a capture,
                                  #  split into the kernels by name and what is
                                  #  left in XLA (PERF.md §6, PR 35, 36, 38)
+  flash_bench.py --rope          # the rotary step of q and k alone at the
+                                 #  cells' shapes, as the Mosaic kernel pair and
+                                 #  as the jnp function, DEVICE time of the
+                                 #  kernels' own events (PERF.md §6, PR 40)
   flash_bench.py --smoke         # tiny interpret-mode pass of all legs
                                  #  (CI: runs on the CPU workflow)
 """
@@ -338,14 +342,10 @@ def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
     the split of its operations' time into the kernels by name
     (``gdn_conv_norm_*``, ``gated_delta_*``) and what is left in XLA (the
     unit-triangular inverse, the layouts, and for ``jnp`` everything)."""
-    import shutil
-    import tempfile
-
     from horovod_tpu.models.transformer import causal_depthwise_conv, l2_unit
     from horovod_tpu.ops.gated_delta import gated_delta_rule
     from horovod_tpu.ops.gdn_kernels import gdn_conv_norm
 
-    capture_dir = tempfile.mkdtemp(prefix="gated_delta_capture_")
     programs, records = {}, []
     for name, (b, t, hk, h, dk, dv) in shapes.items():
         keys = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -383,39 +383,71 @@ def leg_gated_delta(shapes, iters, warmup, interpret, chunk=64):
 
             for what, fn in (("fwd", fwd), ("fwd_bwd", fwd_bwd)):
                 fn.__name__ = "gd%d_%s_%s" % (len(records), impl, what)
-                programs[(len(records), impl, what)] = jax.jit(fn)
-            outs[impl] = programs[(len(records), impl, "fwd")](*args).astype(
+                programs[(len(records), impl, what)] = (jax.jit(fn), args)
+            outs[impl] = programs[(len(records), impl, "fwd")][0](*args).astype(
                 jnp.float32)
         scale = float(jnp.max(jnp.abs(outs["jnp"])))
         rec["kernel_against_jnp_gap"] = float(
             jnp.max(jnp.abs(outs["kernel"] - outs["jnp"]))) / scale
-        records.append((rec, args))
-    for (i, _, _), fn in programs.items():
-        for _ in range(warmup):
-            jax.block_until_ready(fn(*records[i][1]))
-    jax.profiler.start_trace(capture_dir)
-    try:
-        for (i, _, _), fn in programs.items():
-            for _ in range(iters):
-                out = fn(*records[i][1])
-            jax.block_until_ready(out)
-    finally:
-        jax.profiler.stop_trace()
-    ms = module_ms(capture_dir)
-    for i, (rec, _) in enumerate(records):
-        for (j, impl, what), fn in programs.items():
-            if j == i:
-                t = ms.get("jit_" + fn.__name__)
-                variant = rec["variants"].setdefault(impl, {})
-                variant[what + "_device_ms"] = round(t, 4) if t else None
-                if t:
-                    variant[what + "_split_ms"] = kernel_split_ms(
-                        capture_dir, "jit_" + fn.__name__,
-                        ("gated_delta_", "gdn_conv_norm_"))
-    shutil.rmtree(capture_dir, ignore_errors=True)
-    for rec, _ in records:
+        records.append(rec)
+    _fill_variants(records, captured_ms(programs, iters, warmup,
+                                        ("gated_delta_", "gdn_conv_norm_")))
+    for rec in records:
         _emit(rec, f"{rec['shape']}: " + "  ".join(
             f"{impl} {p}" for impl, p in rec["variants"].items()))
+
+
+# heads, key/value heads, head width, rotated columns at 1 x rows
+ROPE_SHAPES = {
+    "laguna-xs.2-s8192-1chip sliding": (8192, 64, 8, 128, 128),
+    "laguna-xs.2-s8192-1chip full": (8192, 48, 8, 128, 64),
+    "sdar-30b-a3b-bd4-s4096-1chip": (8192, 32, 4, 128, 128),
+    "internlm2-1.8b-s4096-1chip": (4096, 16, 8, 128, 128),
+    "qwen3-next-80b-a3b-s8192-1chip": (8192, 16, 2, 256, 64),
+}
+
+
+def leg_rope(shapes, iters, warmup, interpret, row_tiles=(None,)):
+    """The rotary step of one layer's q and k, forward and forward + backward,
+    as the kernel pair (``ops/rope_kernel.py``, at each of ``row_tiles``; None:
+    the tile the shapes give) and as ``rope``: DEVICE time a program and its
+    split into ``rope_fwd`` / ``rope_bwd`` by their own events and what is left
+    in XLA (the tables; alone, also the copies into and out of the head-major
+    layout, which a model's neighbours make unnecessary)."""
+    from horovod_tpu.models.transformer import rope, rope_frequencies
+    from horovod_tpu.ops import rope_kernel
+
+    programs, records = {}, []
+    for name, (s, h, h_kv, d, rot) in shapes.items():
+        keys = jax.random.split(jax.random.PRNGKey(0), 2)
+        q = jax.random.normal(keys[0], (1, s, h, d)).astype(jnp.bfloat16)
+        k = jax.random.normal(keys[1], (1, s, h_kv, d)).astype(jnp.bfloat16)
+        positions, freqs = jnp.arange(s)[None], rope_frequencies(rot, 1e4)
+        rec = {"bench": "rope", "shape": name, "rows": s, "heads": h, "kv_heads": h_kv,
+               "head_dim": d, "rot": rot, "variants": {},
+               "floor_ms": round(4 * s * (h + h_kv) * d / 819e9 * 1e3, 4)}
+        for tile in row_tiles + ("jnp",):
+            def fwd(q, k, tile=tile, rot=rot, positions=positions, freqs=freqs):
+                if tile == "jnp":
+                    turn = lambda x: jnp.concatenate(
+                        [rope(x[..., :rot], positions, inv_freq=freqs), x[..., rot:]], -1)
+                    return turn(q), turn(k)
+                c, sn = rope_kernel.tables(positions, freqs, rot)
+                return tuple(rope_kernel.rotate(x, c, sn, rot, row_tile=tile,
+                                                interpret=interpret) for x in (q, k))
+
+            def fwd_bwd(q, k, fwd=fwd):
+                return jax.grad(lambda *x: sum(
+                    jnp.sum(o.astype(jnp.float32)) for o in fwd(*x)), argnums=(0, 1))(q, k)
+
+            for what, fn in (("fwd", fwd), ("fwd_bwd", fwd_bwd)):
+                fn.__name__ = "rope%d_%s_%s" % (len(records), tile, what)
+                programs[(len(records), str(tile), what)] = (jax.jit(fn), (q, k))
+        records.append(rec)
+    _fill_variants(records, captured_ms(programs, iters, warmup, "rope_"))
+    for rec in records:
+        _emit(rec, f"{rec['shape']}: " + "  ".join(
+            f"{tile} {p}" for tile, p in rec["variants"].items()))
 
 
 def routed_sizes(rows, groups, seed=0):
@@ -472,6 +504,48 @@ def kernel_split_ms(capture_dir, module, prefix):
     return {k: round(v, 4) for k, v in sorted(split.items())}
 
 
+def captured_ms(programs, iters, warmup, kernels=None):
+    """``programs`` ``{key: (jitted function, its arguments)}``, each warmed and
+    then run ``iters`` times inside ONE capture: ``{key: (mean DEVICE ms a call,
+    split)}`` from the capture's ``XLA Modules`` events (``None`` off the chip,
+    where a capture has no such line); ``split``: ``kernel_split_ms`` of the
+    program's operations by the kernel-name prefix ``kernels`` (``None`` where
+    not asked for, or with no device time)."""
+    import shutil
+    import tempfile
+
+    capture_dir = tempfile.mkdtemp(prefix="flash_bench_capture_")
+    for fn, args in programs.values():
+        for _ in range(warmup):
+            jax.block_until_ready(fn(*args))
+    jax.profiler.start_trace(capture_dir)
+    try:
+        for fn, args in programs.values():
+            for _ in range(iters):
+                out = fn(*args)
+            jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    ms, timed = module_ms(capture_dir), {}
+    for key, (fn, _) in programs.items():
+        t = ms.get("jit_" + fn.__name__)
+        timed[key] = (round(t, 4) if t else None,
+                      kernel_split_ms(capture_dir, "jit_" + fn.__name__, kernels)
+                      if t and kernels else None)
+    shutil.rmtree(capture_dir, ignore_errors=True)
+    return timed
+
+
+def _fill_variants(records, timed):
+    """``captured_ms``'s ``{(record, variant, program): (ms, split)}`` into each
+    record's ``variants``."""
+    for (i, name, what), (t, split) in timed.items():
+        variant = records[i]["variants"].setdefault(name, {})
+        variant[what + "_device_ms"] = t
+        if split:
+            variant[what + "_split_ms"] = split
+
+
 def grouped_variants(interpret):
     """``{variant: {product: function of (x, w, dy, sizes)}}``: the three
     products of one expert matrix (forward, the gradient with respect to the
@@ -500,12 +574,8 @@ def leg_grouped(shapes, iters, warmup, interpret):
     peak the held rows' FLOPs make of it.  Off the chip there is no device
     time (``null``); the kernels' results are held against ``ragged_dot``'s
     on the held rows either way."""
-    import shutil
-    import tempfile
-
     import numpy as np
 
-    capture_dir = tempfile.mkdtemp(prefix="grouped_capture_")
     programs, records = {}, []
     for shape_name, (rows, k, n, groups) in shapes.items():
         sizes = routed_sizes(rows, groups)
@@ -538,27 +608,14 @@ def leg_grouped(shapes, iters, warmup, interpret):
                     rec["variants"].setdefault(variant, {})[product + "_gap"] = gap
                 programs[(len(records), variant, product)] = (fn, args)
         records.append(rec)
-    for fn, args in programs.values():
-        for _ in range(warmup):
-            jax.block_until_ready(fn(*args))
-    jax.profiler.start_trace(capture_dir)
-    try:
-        for fn, args in programs.values():
-            for _ in range(iters):
-                out = fn(*args)
-            jax.block_until_ready(out)
-    finally:
-        jax.profiler.stop_trace()
-    ms = module_ms(capture_dir)
-    shutil.rmtree(capture_dir, ignore_errors=True)
+    timed = captured_ms(programs, iters, warmup)
     peak = 197e12  # one v5e chip, bf16 (benchmark/peaks.json)
     for i, rec in enumerate(records):
-        for (j, variant, product), (fn, _) in programs.items():
+        for (j, variant, product), (t, _) in timed.items():
             if j != i:
                 continue
-            t = ms.get("jit_" + fn.__name__)
             out = rec["variants"].setdefault(variant, {})
-            out[product + "_device_ms"] = round(t, 4) if t else None
+            out[product + "_device_ms"] = t
             out[product + "_mxu_share"] = (
                 round(rec["flops"] / (t * 1e-3) / peak, 4) if t else None)
         times = {v: sum(p.get(q + "_device_ms") or 0 for q in ("fwd", "dx", "dw"))
@@ -576,6 +633,7 @@ def main(argv=None):
     ap.add_argument("--cells", action="store_true")
     ap.add_argument("--grouped", action="store_true")
     ap.add_argument("--gated-delta", action="store_true")
+    ap.add_argument("--rope", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny interpret-mode pass of every leg (CI)")
     args = ap.parse_args(argv)
@@ -600,10 +658,11 @@ def main(argv=None):
                   2, 1, True, block=128)
         leg_grouped({"skewed": (192, 256, 384, 4)}, 1, 1, True)
         leg_gated_delta({"tiny": (1, 80, 1, 2, 16, 16)}, 1, 1, True, chunk=16)
+        leg_rope({"tiny": (32, 4, 2, 128, 64)}, 1, 1, True)
         return 0
 
     run_all = not (args.gqa or args.window or args.kernel or args.cells
-                   or args.grouped or args.gated_delta)
+                   or args.grouped or args.gated_delta or args.rope)
     if args.kernel or run_all:
         leg_kernel([(4, 1024, 8, 128), (4, 2048, 8, 128),
                     (2, 4096, 8, 128)], iters, warmup, None)
@@ -618,6 +677,8 @@ def main(argv=None):
         leg_grouped(GROUPED_SHAPES, iters, warmup, None)
     if args.gated_delta:
         leg_gated_delta(GATED_DELTA_SHAPES, iters, warmup, None)
+    if args.rope:
+        leg_rope(ROPE_SHAPES, iters, warmup, None)
     return 0
 
 
