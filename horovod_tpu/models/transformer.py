@@ -993,6 +993,69 @@ class _NormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (features,), jnp.float32)
 
 
+def _delta_mix(cfg, qkvz, ba, conv_w, a_log, dt_bias):
+    """Steps 2-5 of ``GatedDeltaNet``: ``in_proj_qkvz``'s rows (B, T, [q | k |
+    v | z]), ``in_proj_ba``'s (B, T, [b | a]) and the layer's three small
+    parameters -> the rule's ``o``, (B, T, value heads x width) rows in a
+    'flash' model, (B, T, value heads, width) in a 'dot' model.
+
+    Nothing of it is made again in the backward (PR 41; until then a bare
+    ``jax.checkpoint`` kept its inputs alone): the backward reads what the
+    forward made -- the rule's residuals (its q, k, v rows, ``g``,
+    ``beta``, the inverse ``T``, each chunk's ``D`` and the state it was
+    handed: ``ops/gated_delta.py`` ``_fused_fwd``; 0.40 GB a layer at 8,192
+    tokens, 0.46 with ``o``) and the input pass's operands -- so the inverse,
+    ``gated_delta_kkt`` and ``gated_delta_fwd`` run once a layer and not
+    twice.  q, k, v are kept too: making them again from the projection's
+    rows (``jax.checkpoint`` with ``save_only_these_names`` over ``T``, ``D``
+    and the states) ran 1.0 % slower on the chip, 46,367 against 46,828
+    tokens/s, for 0.41 GB, and the benchmark's step fits as it is (14.06 GB
+    where the old checkpoint's was 12.69; PERF.md section 6, PR 41).  A
+    block under ``remat_policy`` makes this again with the rest of its
+    layer."""
+    from ..ops.gated_delta import gated_delta_rule
+    from ..ops.gdn_kernels import gdn_conv_norm
+
+    hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    key_dim, value_dim = hk * dk, hv * dv
+    b, t, _ = qkvz.shape
+    f32 = jnp.float32
+    kernels = cfg.attention_impl == "flash"
+    with jax.named_scope("gdn"):
+        if kernels:
+            # one pass, token-major rows out, [q | k | v] read out of the
+            # projection's rows in place (ops/gdn_kernels.py)
+            q, k, v = gdn_conv_norm(
+                qkvz, conv_w, key_heads=hk, key_head_dim=dk,
+                value_heads=hv, value_head_dim=dv)
+        else:
+            mixed = causal_depthwise_conv(
+                qkvz[..., :2 * key_dim + value_dim], conv_w)
+            keys = lambda x: x.reshape(b, t, hk, dk)
+            q = l2_unit(keys(mixed[..., :key_dim]), dk ** -0.5)
+            k = l2_unit(keys(mixed[..., key_dim:2 * key_dim]))
+            v = mixed[..., 2 * key_dim:]
+        beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            ba[..., hv:].astype(f32) + dt_bias)
+    with jax.named_scope("gated_delta"):
+        # q, k at the key heads: the rule reads a value head's key head
+        # itself.  Rows in and rows out ('flash'): the reshapes fold away
+        o = gated_delta_rule(
+            q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk),
+            v.reshape(b, t, hv, dv), g, beta,
+            impl="kernel" if kernels else "jnp")
+        return o.reshape(b, t, value_dim) if kernels else o
+
+
+# ``_delta_mix`` as ONE jaxpr a layer for ``jax.grad`` to work on, every
+# residual kept: nothing is made again.  Traced bare, the same compiled step
+# cost the benchmark's set-up 20 s more (PERF.md section 6, PR 41)
+_mix = jax.checkpoint(_delta_mix, static_argnums=(0,),
+                      policy=jax.checkpoint_policies.everything_saveable)
+
+
 class GatedDeltaNet(nn.Module):
     """The linear-attention mixer of the ``qwen3_next`` family (Gated DeltaNet,
     arXiv:2412.06464): ``x`` (B, T, d_model) ->
@@ -1030,8 +1093,7 @@ class GatedDeltaNet(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.gated_delta import gated_delta_rule
-        from ..ops.gdn_kernels import gdn_conv_norm, gdn_gated_norm
+        from ..ops.gdn_kernels import gdn_gated_norm
         from ..parallel._mesh_utils import axis_size_or_1
 
         cfg = self.cfg
@@ -1059,43 +1121,7 @@ class GatedDeltaNet(nn.Module):
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
 
         kernels = cfg.attention_impl == "flash"
-
-        # steps 2-5 keep their inputs alone for the backward, which makes
-        # the convolution's, the norms' and the rule's own tensors again: at
-        # 8,192 tokens the benchmark's step is 12.69 GB so and 14.06 with
-        # nothing made again (the compiled step's ``memory_analysis()`` for a
-        # v5e, PR 38; 13.6 and 16.0 while these passes were XLA's, PR 35)
-        @jax.checkpoint
-        def mix(qkv, ba, conv_w, a_log, dt_bias):
-            with jax.named_scope("gdn"):
-                if kernels:
-                    # one pass, token-major rows out (ops/gdn_kernels.py)
-                    q, k, v = gdn_conv_norm(
-                        qkv, conv_w, key_heads=hk, key_head_dim=dk,
-                        value_heads=hv, value_head_dim=dv)
-                else:
-                    mixed = causal_depthwise_conv(qkv, conv_w)
-                    keys = lambda x: x.reshape(b, t, hk, dk)
-                    q = l2_unit(keys(mixed[..., :key_dim]), dk ** -0.5)
-                    k = l2_unit(keys(mixed[..., key_dim:2 * key_dim]))
-                    v = mixed[..., 2 * key_dim:]
-                beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
-                g = -jnp.exp(a_log) * jax.nn.softplus(
-                    ba[..., hv:].astype(f32) + dt_bias)
-            with jax.named_scope("gated_delta"):
-                # q, k at the key heads: the rule reads a value head's key
-                # head itself.  Out as rows where the next pass reads rows: the
-                # checkpoint's boundary holds what crosses it in the layout it
-                # has, and a 4-D ``o`` there costs a relayout each way
-                o = gated_delta_rule(
-                    q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk),
-                    v.reshape(b, t, hv, dv), g, beta,
-                    impl="kernel" if kernels else "jnp")
-                return o.reshape(b, t, value_dim) if kernels else o
-
-        # the kernel reads [q | k | v] out of the projection's rows in place
-        o = mix(qkvz if kernels else qkvz[..., :2 * key_dim + value_dim], ba, conv_w,
-                a_log, dt_bias)
+        o = _mix(cfg, qkvz, ba, conv_w, a_log, dt_bias)
         with jax.named_scope("gdn"):
             if kernels:
                 # one pass on the rule's rows, z read out of the projection's

@@ -640,9 +640,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, *, impl: str = "kernel",
     ``impl``: ``"kernel"`` (the rule's Mosaic kernels, forward and backward) or
     ``"jnp"`` (XLA's chunk-local products and a ``lax.scan``).  What a backward
     pass keeps under ``"kernel"``: the operands, ``T`` (float32), ``D`` and a
-    state a chunk, 0.47 GB a layer of 8,192 tokens x 32 heads in bf16; a caller
-    short of memory wraps the call in ``jax.checkpoint``
-    (``models.transformer.GatedDeltaNet`` does)."""
+    state a chunk, 0.47 GB a layer of 8,192 tokens x 32 heads in bf16
+    (``models.transformer.GatedDeltaNet`` keeps them since PR 41: made again,
+    the inverse alone was 4 ms a layer); a caller short of memory wraps the
+    call, or its block (``remat_policy``), in ``jax.checkpoint``."""
     if impl not in ("kernel", "jnp"):
         raise ValueError(f"impl is 'kernel' or 'jnp', got {impl!r}")
     if (q.ndim != 4 or v.ndim != 4 or q.shape != k.shape or v.shape[:2] != q.shape[:2]

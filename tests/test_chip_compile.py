@@ -604,13 +604,13 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
     query heads over 2 key/value heads of 256; top-10 of 512 with 32 held, a
     gated shared expert) through the train step: the gated delta rule's carry
     and the 256-wide attention are Mosaic calls by their names, each under the
-    scope its metric reads (``gated_delta``, never ``gdn``; forward, the
-    backward's own copy and the backward itself), the projections under
-    ``gdn``, and the routed layer still holds no scatter.  Since PR 38 the
-    linear layer is kernels and projections: ``gdn_conv_norm_*`` and
-    ``gdn_gated_norm_*`` under ``gdn``, q and k 16 heads wide into the rule,
-    and no copy, reshape, pad, slice or broadcast of XLA's of an activation
-    between the projections."""
+    scope its metric reads (``gated_delta``, never ``gdn``; forward and
+    backward, each once: since PR 41 nothing of the mixer is made again), the
+    projections under ``gdn``, and the routed layer still holds no scatter.
+    Since PR 38 the linear layer is kernels and projections:
+    ``gdn_conv_norm_*`` and ``gdn_gated_norm_*`` under ``gdn``, q and k 16
+    heads wide into the rule, and no copy, reshape, pad, slice or broadcast of
+    XLA's of an activation between the projections."""
     import functools
 
     from horovod_tpu.models.transformer import next_token_loss
@@ -637,8 +637,11 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
         loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
     tokens = _sds((1, 8192), jnp.int32, batch)
     compiled = step.lower(state, tokens, tokens).compile()
-    # 6.630 GB at PR 37, whose elementwise passes kept float32 copies; 6.072 now
-    assert _device_bytes(compiled) <= 6_100_000_000
+    # 6.630 GB at PR 37, whose elementwise passes kept float32 copies; 6.072
+    # at PR 38-40 with the mixer made again; since PR 41 the rule's residuals
+    # (0.47 GB a linear layer) are kept and nothing of the mixer is made
+    # again: 6.545 GB (6.511 with the mixer traced under no checkpoint at all)
+    assert _device_bytes(compiled) <= 6_560_000_000
     text = compiled.as_text()
     calls = {}
     for line in text.splitlines():
@@ -646,21 +649,22 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
         if m:
             calls.setdefault(m.group(1), []).append(
                 re.search(r'op_name="([^"]*)"', line).group(1))
-    # the rule's forward (``L``'s kernel, then the chunks') twice (the mixer is
-    # made again in the backward) and its backward once; the three attention
-    # kernels once each; of the rule's products XLA keeps the inverse's alone
+    # the rule's forward (``L``'s kernel, then the chunks') ONCE (until PR 41
+    # twice: the mixer was made again in the backward) and its backward once;
+    # the three attention kernels once each; of the rule's products XLA keeps
+    # the inverse's alone, and runs it once
     assert [len(calls[k]) for k in ("gated_delta_kkt", "gated_delta_fwd",
-                                    "gated_delta_bwd")] == [2, 2, 1]
+                                    "gated_delta_bwd")] == [1, 1, 1]
+    assert "rematted_computation" not in text
     assert all("jit(_block_inverse)" in line for line in _xla_products(text, "/gated_delta/"))
     assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
                                     "flash_attention_bwd_dkv")] == [1, 1, 1]
     for name in ("gated_delta_kkt", "gated_delta_fwd", "gated_delta_bwd"):
         assert all("/linear_attn/" in o and "/gated_delta/" in o and "/gdn/" not in o
                    for o in calls[name]), calls[name]
-    # the input pass twice forward (the mixer is made again) and once backward,
-    # the output pass (outside the checkpoint) once each way, all under ``gdn``
+    # the input pass and the output pass once each way, all under ``gdn``
     assert [len(calls[k]) for k in ("gdn_conv_norm_fwd", "gdn_conv_norm_bwd",
-                                    "gdn_gated_norm_fwd", "gdn_gated_norm_bwd")] == [2, 1, 1, 1]
+                                    "gdn_gated_norm_fwd", "gdn_gated_norm_bwd")] == [1, 1, 1, 1]
     for name in (k for k in calls if k.startswith("gdn_")):
         assert all("/linear_attn/" in o and "/gdn/" in o and "/gated_delta/" not in o
                    for o in calls[name]), calls[name]

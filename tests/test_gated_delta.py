@@ -322,6 +322,32 @@ def test_gdn_chunks_event_carries_the_shape_arithmetic():
         2 * (2 * 16 * 128 + 32 * 128) + 32 * (8 + 4 * 64)) == 203_423_744
 
 
+@pytest.mark.parametrize("key_heads,total", [(16, 404_750_336), (32, 471_859_200)],
+                         ids=["the_cell_s_16_key_heads", "equal_head_counts"])
+def test_the_backward_keeps_the_kernels_operands_the_inverse_and_a_state_a_chunk(key_heads, total):
+    """What ``jax.vjp`` holds of the rule under ``"kernel"`` at the cell's
+    shapes (8,192 tokens, 32 value heads of 128, bf16): q, k, v as rows, the
+    gates' rows, ``T`` in float32, each chunk's ``D`` and the state it was
+    handed, and nothing else: 0.40 GB a layer at Gated DeltaNet's 16 key heads,
+    the docstring's 0.47 at equal head counts.  Since PR 41 the layer keeps
+    exactly these (no ``jax.checkpoint`` around the call)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    gate = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32)
+    kept = saved_residuals(lambda *a: gated_delta_rule(*a, interpret=True),
+                           shape(1, 8192, key_heads, 128), shape(1, 8192, key_heads, 128),
+                           shape(1, 8192, 32, 128), gate, gate)
+    assert [(a.shape, a.dtype.name) for a, _ in kept] == [
+        ((1, 8192, key_heads * 128), "bfloat16"), ((1, 8192, key_heads * 128), "bfloat16"),
+        ((1, 8192, 4096), "bfloat16"),                                    # q, k, v
+        ((1, 32, 128, 64), "float32"), ((1, 32, 128, 64), "float32"),     # g, beta
+        ((1, 32, 8192, 64), "float32"),                                   # T
+        ((1, 8192, 4096), "bfloat16"),                                    # D
+        ((1, 32, 128 * 128, 128), "bfloat16")]                            # the states
+    assert sum(a.size * a.dtype.itemsize for a, _ in kept) == total
+
+
 # -- the pass that makes q, k, v: ops/gdn_kernels.py (PR 38) ------------------
 
 

@@ -426,6 +426,98 @@ def test_trains_through_the_normal_path():
     np.testing.assert_allclose(losses["dot"], losses["flash"], rtol=2e-2)
 
 
+# -- the backward reads what the forward made (PR 41) -----------------------------------
+
+_RULE_PROGRAMS = ("gated_delta_kkt", "gated_delta_fwd", "_block_inverse",
+                  "gdn_conv_norm_fwd", "_rule")
+
+
+def _checkpointed_mix(monkeypatch):
+    """The mixer as it was until PR 41: steps 2-5 (the input pass, the gates,
+    the rule) under a BARE ``jax.checkpoint``, which kept their inputs alone
+    and made the rest again in the backward (the package's ``_mix`` keeps
+    every residual).  The old form lives here, not in the package."""
+    monkeypatch.setattr(transformer, "_mix", jax.checkpoint(
+        transformer._delta_mix, static_argnums=(0,)))
+
+
+def _two_mixer_loss(impl, **kw):
+    """Two linear layers and a full one over routed experts, 150 tokens (three
+    of the rule's chunks, the last partial): parameters, loss(params)."""
+    cfg = _config(attention_impl=impl, num_layers=3,
+                  layer_types=("linear_attention",) * 2 + ("full_attention",), **kw)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 150), 0, 64)
+    model = Transformer(cfg)
+    # one program, not a layer operation by operation (under a block's remat too)
+    params = _random_scales(jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"])
+    return params, lambda p: transformer.next_token_loss(
+        model.apply({"params": p}, tokens), tokens, 0.001)
+
+
+@pytest.mark.parametrize("impl", ["dot", "flash"])
+def test_loss_and_gradients_are_the_checkpointed_mixer_s(impl, monkeypatch):
+    """Nothing of the arithmetic changed with the checkpoint: the backward
+    reads the very tensors it used to make again.  Loss bit for bit, every
+    leaf's gradient to float32's last places."""
+    params, loss = _two_mixer_loss(impl)
+    got, grads = jax.jit(jax.value_and_grad(loss))(params)
+    _checkpointed_mix(monkeypatch)
+    want, want_grads = jax.jit(jax.value_and_grad(loss))(params)
+    assert float(got) == float(want)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) > 30 and all(bool(jnp.any(g != 0)) for _, g in flat)
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-6, atol=2e-7 * float(jnp.max(jnp.abs(b))),
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def _programs_in_the_gradient(loss, params):
+    """Call sites of each of ``_RULE_PROGRAMS`` (a kernel's or a ``jit``'s
+    name) in ``jax.grad(loss)``'s program, and whether any ``jax.checkpoint``
+    in it makes something again."""
+    from test_routed_block_diffusion import _primitives
+
+    eqns = list(_primitives(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+    names = [eqn.params.get("name") for eqn in eqns]
+    # a checkpoint with no policy keeps its inputs alone (``_mix``'s keeps all)
+    bare = any(e.primitive.name.startswith("remat") and e.params.get("policy") is None
+               for e in eqns)
+    return [names.count(name) for name in _RULE_PROGRAMS], bare
+
+
+@pytest.mark.parametrize("impl", ["dot", "flash"])
+def test_the_differentiated_program_runs_the_rule_s_forward_once_a_layer(impl, monkeypatch):
+    """Two linear layers: the inverse's own program (``_inverse``, the ``jit`` of
+    ``_block_inverse``), ``gated_delta_kkt``, ``gated_delta_fwd`` and the
+    input pass's forward kernel twice each in ``jax.grad``'s program, once a
+    layer, and no checkpoint that makes anything again (``_mix``'s policy
+    keeps every residual).  Under the old bare checkpoint each forward is
+    there a second time: what fails if a later change makes the rule again.
+    (The rule's ``jit`` is split three ways a layer under either.)"""
+    params, loss = _two_mixer_loss(impl)
+    once = [2, 2, 2, 2] if impl == "flash" else [0, 0, 0, 0]
+    assert _programs_in_the_gradient(loss, params) == (once + [6], False)
+    _checkpointed_mix(monkeypatch)
+    assert _programs_in_the_gradient(loss, params) == ([2 * n for n in once] + [6], True)
+
+
+def test_a_block_under_remat_policy_makes_its_mixer_again_and_agrees_with_the_reference():
+    """The per-block policy composes: under ``remat_policy="full"`` the family
+    trains through ``run_cell`` (three checked steps and a window) within the
+    float32 limits of the reference, and the block's backward holds the rule's
+    forward a second time (the block's own choice, as for any other layer)."""
+    config = _tiny_cell_config()
+    config["model"] = dict(config["model"], kwargs={"remat_policy": "full"})
+    config["check"] = dict(config["check"], diff_leaves="", limits=_TIGHT)
+    assert Qwen3Next.model(config).cfg.block_remat_policies() == ("full",) * 4
+    result = harness.run_cell(_tiny_cell(config), seed=41, seconds=0.3, trace=False,
+                              devices=jax.devices()[:1])
+    assert result["correct"], json.dumps(result["checks"])
+    assert result["checks"]["grad_diff_gap"]["value"] < 5e-5     # over every leaf
+    params, loss = _two_mixer_loss("flash", remat_policy="full")
+    assert _programs_in_the_gradient(loss, params) == ([4, 4, 4, 4, 8], True)
+
+
 # -- what is refused, by the key's name ---------------------------------------------------
 
 
