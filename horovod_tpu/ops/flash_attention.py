@@ -28,17 +28,33 @@ rounding is not).  float32 inputs (the tests) go the same way.
 Several tiles a loop iteration: within one tile the second product waits
 for the tile's own softmax and the softmax for the first product, and one
 loop iteration is one scheduling region, so a loop of one tile an iteration
-leaves the MXU idle for most of it.  Every kernel therefore walks its tiles
-four an iteration, then the rest two and one (``_run_tiles``): the
-tiles of an iteration are independent but for the running sums, so the
-scheduler fills one tile's waits with the next one's products.  Tiles are
-visited in the same order: the result is the same bit for bit.
-``tile_counts`` gives a head's tile visits and loop iterations by kernel
-(136 visits in 44 iterations under the causal mask at 4,096 in 256-tiles;
-a window of one tile visits one tile an iteration, and nothing is won), and
-tracing a kernel records them as a ``flash.tiles`` event
+leaves the MXU idle for most of it.  The forward and dQ kernels therefore
+walk their tiles four an iteration, then the rest two and one
+(``_run_tiles``): the tiles of an iteration are independent but for the
+running sums, so the scheduler fills one tile's waits with the next one's
+products.  Tiles are visited in the same order: the result is the same bit
+for bit.  ``tile_counts`` gives the tile visits and loop iterations by kernel
+(136 visits in 44 iterations a head under the causal mask at 4,096 in
+256-tiles; a window of one tile visits one tile an iteration, and nothing is
+won), and tracing a kernel records them as a ``flash.tiles`` event
 (``horovod_tpu.trace``).  Every mask kind's loop bounds come from
 ``_tile_ranges``; tiles are masked element by element as before.
+
+Two dK/dV kernels, chosen by bytes and by nothing else (``_backward_folded``):
+a program owns one key tile of one kv head and sums over the query heads that
+read it.  While the group's q and dO fit VMEM twice buffered
+(``_DKV_GROUP_BYTES``) the WHOLE GROUP is one program's operand, at a block
+index that does not move along the key tiles, so it is fetched once a kv head
+(``_bwd_dkv_kernel``); beyond that, one query head a program with the sums in
+VMEM scratch across the grid's last axis (``_bwd_dkv_head_kernel``), which
+fetches a head's q and dO for every key tile.  Both take every mask kind
+through one tile body (``_dkv_tile``); under the block-diffusion mask the call
+is named ``flash_attention_bwd_dkv_bd`` in either form.  Both walk the tiles
+of the heads a program holds as ONE sequence, eight a loop iteration, then
+four, two and one (``_run_group_tiles``: head after head in ``_run_tiles``'
+order, so the sums are the same bit for bit): a Mosaic program pays for every
+loop it holds, run or not, and a loop nest a head was 48 loops a program at a
+group of 8 under two ranges.
 
 Grouped-query attention (GQA — Ainslie et al., 2023) is KERNEL-NATIVE:
 ``k``/``v`` may carry ``num_kv_heads < num_heads`` heads and are folded
@@ -78,16 +94,20 @@ _SCALAR_SPEC = pl.BlockSpec(memory_space=_pltpu.SMEM)
 
 _NEG_INF = -1e30
 
-# the causal / windowed dK/dV kernel holds a whole query-head group's q and dO
-# in VMEM (of the chip's 128 MiB) while they are at most this, twice buffered,
-# and states what it needs past the compiler's own limit; beyond it, one query
-# head a program (_bwd_dkv_head_kernel), which fetches that head's q and dO
-# anew for every key tile.  The accepted cells' are 8 MiB (2 x 4,096 x 128 +
-# 128) and 10 MiB (8,192 x 192 + 128); 8,192 rows of 128 + 128 are 48 MiB at a
-# group of 6 and 64 MiB at a group of 8, where the group form ran the causal
-# mask 1.10 x and the 512 window 2.27 x as fast as the head form (PERF.md PR 39)
+# the dK/dV kernel, under every mask kind, holds a whole query-head group's q
+# and dO in VMEM (of the chip's 128 MiB) while they are at most this, twice
+# buffered, and states what it needs (_VMEM_HEADROOM beside them); beyond it,
+# one query head a program (_bwd_dkv_head_kernel), which fetches that head's q
+# and dO anew for every key tile.  The accepted cells' are 8 MiB (2 x 4,096 x
+# 128 + 128) and 10 MiB (8,192 x 192 + 128); 8,192 rows of 128 + 128 are 48 MiB
+# at a group of 6 and 64 MiB at a group of 8, where the group form ran the
+# causal mask 1.10 x and the 512 window 2.27 x as fast as the head form
+# (PERF.md PR 39), and the block-diffusion mask 1.07 x (7.60 -> 7.08 ms a layer
+# in the step, PERF.md PR 42: the fetch was the smaller cost there, the loops
+# the larger: _run_group_tiles)
 _DKV_GROUP_BYTES = 64 * 1024 * 1024
 # what a kernel that states its VMEM asks for beside its resident operands
+# (a dK/dV iteration of eight 256-tiles is 8-10 MiB of it)
 _VMEM_HEADROOM = 16 * 1024 * 1024
 # the forward and dQ kernels hold a head's whole keys and values, twice
 # buffered; beyond this they state what they need (the compiler's own scoped
@@ -95,25 +115,26 @@ _VMEM_HEADROOM = 16 * 1024 * 1024
 _KV_RESIDENT_BYTES = 12 * 1024 * 1024
 
 
-def _resident_params(resident):
-    """``compiler_params`` for a kernel that holds ``resident`` bytes of whole
-    operands (twice buffered) in VMEM: none while they fit the compiler's own
+def _kv_params(s_k, d, dv, dtype):
+    """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
+    values in VMEM, twice buffered: none while they fit the compiler's own
     limit, else what it needs, stated."""
+    resident = 2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize
     if resident <= _KV_RESIDENT_BYTES:
         return {}
     return {"compiler_params": _pltpu.CompilerParams(
         vmem_limit_bytes=resident + _VMEM_HEADROOM)}
 
 
-def _kv_params(s_k, d, dv, dtype):
-    """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
-    values in VMEM."""
-    return _resident_params(2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize)
-
 # tiles a loop iteration: a range runs four at a time, then what is left two
 # and one at a time (_run_tiles).  Starting at 8 was 2 % of the kernels' time
 # faster again at twice their compile time (PERF.md PR 29)
 _TILES_AN_ITERATION = (4, 2, 1)
+# the same for the dK/dV kernels, which walk a program's heads as one
+# (_run_group_tiles): one set of loops a program whatever the group, so
+# starting at 8 costs little to compile (Mosaic 2.6 s against 1.4), and it was
+# 9 % of the kernel's time (4.54 against 4.97 ms alone, PERF.md PR 42)
+_TILES_A_WALK_ITERATION = (8, 4, 2, 1)
 
 
 def _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off=0):
@@ -292,15 +313,58 @@ def _run_tiles(ranges, body, carry):
     return carry
 
 
+def _run_group_tiles(ranges, group, body, carry):
+    """``carry = body(g, t, carry)`` for every query head ``g`` of a group and
+    every tile ``t`` of every range: head after head and, within a head, range
+    after range in rising order (``_run_tiles``' order a head, so the sums
+    come out the same bit for bit), but as ONE walk of ``group x tiles``
+    visits, ``_TILES_A_WALK_ITERATION`` a loop iteration.  The head and the
+    place in its walk are carried beside the sums, so an iteration's tiles
+    may be of two heads: a head's walk of one tile (a noisy key tile under
+    the block-diffusion mask) or of three (a window) still fills whole
+    iterations, and a program holds one set of loops whatever the group.  The
+    dK/dV kernels' driver; a group of 1 is ``_run_tiles`` at this tuple."""
+    shifts, n = [], 0       # place p of a head's walk is tile p + shift
+    for lo, hi in ranges:   # while p < end, range after range
+        shifts.append((lo - n, n + jnp.maximum(hi - lo, 0)))
+        n = shifts[-1][1]
+
+    def tile_at(p):
+        shift = shifts[-1][0]
+        for earlier, end in shifts[-2::-1]:
+            shift = jnp.where(p < end, earlier, shift)
+        return p + shift
+
+    state = (jnp.int32(0), jnp.int32(0), carry)
+    left = group * n
+    for m in _TILES_A_WALK_ITERATION:
+        steps = left // m
+
+        def several(_, state, m=m):
+            g, p, carry = state
+            for _ in range(m):
+                carry = body(g, tile_at(p), carry)
+                last = p + 1 == n       # the head's walk is done
+                g, p = jnp.where(last, g + 1, g), jnp.where(last, 0, p + 1)
+            return g, p, carry
+
+        state = jax.lax.fori_loop(0, steps, several, state)
+        left = left - steps * m
+    return state[2]
+
+
 def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
-                window=None, kv_off=0, bd=None):
-    """One query head's tile visits and the loop iterations they take, by
-    kernel: ``{"fwd": (visited, iterations), "bwd_dq": ..., "bwd_dkv":
-    ...}`` for padded lengths ``s_q``, ``s_k`` in tiles of ``block_q`` x
-    ``block_k``.  Host arithmetic on the kernels' own bounds
-    (``_tile_ranges`` on numpy) and ``_run_tiles``' steps: where ``visited
-    / iterations`` is near 1 (a window of a tile, a sequence of two tiles)
-    walking several tiles an iteration wins nothing."""
+                window=None, kv_off=0, bd=None, heads_a_program=1):
+    """Tile visits and the loop iterations they take, by kernel: ``{"fwd":
+    (visited, iterations), "bwd_dq": ..., "bwd_dkv": ...}`` for padded
+    lengths ``s_q``, ``s_k`` in tiles of ``block_q`` x ``block_k``: one query
+    head's in the forward and dQ kernels, and in the dK/dV kernel those of the
+    ``heads_a_program`` query heads that one of its programs holds
+    (``_dkv_heads_a_program``) and walks as one (``_run_group_tiles``).  Host
+    arithmetic on the kernels' own bounds (``_tile_ranges`` on numpy) and the
+    loops' steps: where ``visited / iterations`` is near 1 (a window of a
+    tile, a sequence of two tiles) walking several tiles an iteration wins
+    nothing."""
     kw = dict(causal=causal, window=window, kv_off=kv_off, bd=bd, xp=np)
     by_queries = _tile_ranges(
         np.arange(s_q // block_q) * block_q, block_q, block_k,
@@ -309,26 +373,34 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
         np.arange(s_k // block_k) * block_k, block_k, block_q,
         s_q // block_q, seq_len, rows_are_queries=False, **kw)
 
-    def count(ranges, programs):
+    def count(lengths, at_a_time=_TILES_AN_ITERATION):
         visited = iterations = 0
-        for lo, hi in ranges:
-            rest = np.broadcast_to(np.maximum(hi - lo, 0), (programs,))
+        for rest in lengths:
             visited += int(rest.sum())
-            for n in _TILES_AN_ITERATION:
+            for n in at_a_time:
                 iterations += int((rest // n).sum())
                 rest = rest % n
         return visited, iterations
 
-    fwd = count(by_queries, s_q // block_q)
-    return {"fwd": fwd, "bwd_dq": fwd,
-            "bwd_dkv": count(by_keys, s_k // block_k)}
+    def lengths(ranges, programs):
+        return [np.broadcast_to(np.maximum(hi - lo, 0), (programs,))
+                for lo, hi in ranges]
+
+    fwd = count(lengths(by_queries, s_q // block_q))
+    # a dK/dV program's ranges and heads are one walk
+    dkv = count([heads_a_program * sum(lengths(by_keys, s_k // block_k))],
+                _TILES_A_WALK_ITERATION)
+    return {"fwd": fwd, "bwd_dq": fwd, "bwd_dkv": dkv}
 
 
-def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
-    """One ``flash.tiles`` instant a kernel as it is traced: its name, a
-    head's ``visited`` tiles and loop ``iterations``, the ``window`` of its
-    mask (None: none) and the widths of a tile's products (``d_qk`` of
-    queries and keys, ``d_v`` of values).
+def _note_tiles(kernels, kv_offset, d_qk, d_v, heads_a_program=None,
+                **shape):
+    """One ``flash.tiles`` instant a kernel as it is traced: its name, the
+    ``visited`` tiles and loop ``iterations`` (``tile_counts``: a query
+    head's, and for a dK/dV kernel those of the ``heads_a_program`` query
+    heads a program holds, the whole group or 1, given beside them), the
+    ``window`` of its mask (None: none) and the widths of a tile's products
+    (``d_qk`` of queries and keys, ``d_v`` of values).
     ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
     bookkeeping at trace time; a traced ``kv_offset`` (a ring step) has no
     count to give."""
@@ -336,12 +408,14 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
         kv_offset = 0
     if not _trace.enabled() or not isinstance(kv_offset, int):
         return
-    counts = tile_counts(kv_off=kv_offset, **shape)
+    more = {} if heads_a_program is None else {
+        "heads_a_program": heads_a_program}
+    counts = tile_counts(kv_off=kv_offset, **more, **shape)
     for name, key in kernels.items():
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
                      iterations=iterations, d_qk=d_qk, d_v=d_v,
-                     window=shape.get("window"))
+                     window=shape.get("window"), **more)
 
 
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
@@ -591,13 +665,20 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
-              base, *, sm_scale, causal, block_q, block_k, seq_len, window):
-    """One tile visit of the causal / windowed dK/dV kernels, as a body for
-    ``_run_tiles``: the query head's rows start at ``base`` of the q-side
-    blocks; ``(dk, dv)`` is the carry."""
+              s_q, *, sm_scale, causal, block_q, block_k, seq_len, window,
+              bd=None):
+    """One tile visit of the dK/dV kernels, as a body for
+    ``_run_group_tiles``: query head ``g`` of those the program holds has its
+    ``s_q`` rows at ``g * s_q`` of the q-side blocks; ``(dk, dv)`` is the
+    carry.  The tile is computed transposed, keys on the rows (``k q^T``):
+    ``p^T`` and ``dS^T`` are what the two sums take, and computing ``p``
+    first meant transposing two (block_q, block_k) float32 tiles a visit.
+    ``bd`` chooses the mask (``_bd_tile_mask`` on the transposed tile; else
+    ``_tile_mask``)."""
 
-    def body(qb, carry):
+    def body(g, qb, carry):
         dk, dv = carry
+        base = g * s_q
         q_off = qb * block_q
         q_blk = q_ref[0, pl.ds(base + q_off, block_q), :].astype(
             jnp.float32)
@@ -610,14 +691,18 @@ def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        k_pos = k_off + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 0)
-        q_pos = q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 1)
-        mask = jnp.logical_and(
-            _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
-            q_pos < seq_len,
-        )
+        if bd is None:
+            k_pos = k_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_off + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            mask = jnp.logical_and(
+                _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
+                q_pos < seq_len,
+            )
+        else:
+            mask = _bd_tile_mask(k_off, q_off, block_k, block_q, seq_len, bd,
+                                 False)
         pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
         dv = dv + jax.lax.dot_general(
             pt, do_blk,
@@ -642,17 +727,15 @@ def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
 
 def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                    block_q, block_k, seq_len, window=None, group=1):
+                    block_q, block_k, seq_len, window=None, bd=None, group=1):
     """dK/dV for ONE kv head's K block: the q-side operands arrive with
     the whole query-head group concatenated on the row axis
-    ((1, group*s_q, d) blocks), and the group's contributions accumulate
+    ((1, group*s_q, d) blocks, constant along the key-tile axis and so
+    fetched once a kv head), and the group's contributions accumulate
     into the same (block_k, d) dK/dV — this is the GQA dK/dV reduction
-    done in VMEM, with K/V loaded once per kv head.  Tiles are computed
-    transposed, keys on the rows (``k q^T``, as the block-diffusion kernel
-    below): ``p^T`` and ``dS^T`` are what the two sums take, and computing
-    ``p`` first meant transposing two (block_q, block_k) float32 tiles a
-    tile visit.  The per-query ``lse`` and ``delta`` arrive as row vectors
-    ((1, 1, group*s_q) blocks, not lane-padded columns)."""
+    done in VMEM, with K/V loaded once per kv head.  The per-query ``lse``
+    and ``delta`` arrive as row vectors ((1, 1, group*s_q) blocks, not
+    lane-padded columns).  Every mask kind's (``_dkv_tile``)."""
     ki = pl.program_id(1)
     kv_off = kvoff_ref[0]
     k_off = ki * block_k
@@ -662,17 +745,15 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     s_q = q_ref.shape[1] // group  # per-query-head padded length
     ranges = _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
                           causal=causal, window=window, kv_off=kv_off,
-                          bd=None, rows_are_queries=False)
+                          bd=bd, rows_are_queries=False)
 
-    carry = (jnp.zeros((block_k, d), jnp.float32),
-             jnp.zeros((block_k, dv), jnp.float32))
-    tile = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                block_k=block_k, seq_len=seq_len, window=window)
-    for g in range(group):  # static unroll over the query-head group
-        body = _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk,
-                         k_off, kv_off, g * s_q, **tile)
-        carry = _run_tiles(ranges, body, carry)
-    dk, dv = carry
+    body = _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off,
+                     kv_off, s_q, sm_scale=sm_scale, causal=causal,
+                     block_q=block_q, block_k=block_k, seq_len=seq_len,
+                     window=window, bd=bd)
+    dk, dv = _run_group_tiles(
+        ranges, group, body, (jnp.zeros((block_k, d), jnp.float32),
+                              jnp.zeros((block_k, dv), jnp.float32)))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -680,14 +761,14 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                          sm_scale, causal, block_q, block_k, seq_len,
-                         window=None, group=1):
+                         window=None, bd=None, group=1):
     """``_bwd_dkv_kernel`` with ONE query head of the group a program: the
     grid's last axis walks the group and the (block_k, d) sums live in VMEM
-    scratch across it, as under the block-diffusion mask below.  Taken where
-    the whole group's queries and output gradients do not fit VMEM
-    (``_DKV_GROUP_BYTES``: 8 query heads of 256 a key/value head at 8,192
-    rows are 128 MiB twice buffered).  The tile's body is the grouped
-    kernel's (``_dkv_tile``)."""
+    scratch across it, so a program fetches that head's whole q and dO anew
+    for every key tile.  Taken where the whole group's queries and output
+    gradients do not fit VMEM (``_DKV_GROUP_BYTES``: 8 query heads of 256 a
+    key/value head at 8,192 rows are 128 MiB twice buffered).  The tile's
+    body is the grouped kernel's (``_dkv_tile``)."""
     ki, g = pl.program_id(1), pl.program_id(2)
     kv_off = kvoff_ref[0]
     k_off = ki * block_k
@@ -697,86 +778,30 @@ def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    s_q = q_ref.shape[1]
     body = _dkv_tile(
         q_ref, do_ref, lse_ref, delta_ref, k_ref[0].astype(jnp.float32),
-        v_ref[0].astype(jnp.float32), k_off, kv_off, 0, sm_scale=sm_scale,
+        v_ref[0].astype(jnp.float32), k_off, kv_off, s_q, sm_scale=sm_scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
-        window=window)
-    dk_acc[...], dv_acc[...] = _run_tiles(
-        _tile_ranges(k_off, block_k, block_q, q_ref.shape[1] // block_q,
-                     seq_len, causal=causal, window=window, kv_off=kv_off,
-                     bd=None, rows_are_queries=False),
-        body, (dk_acc[...], dv_acc[...]))
-
-    @pl.when(g == group - 1)
-    def _():
-        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _bwd_dkv_bd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block_q,
-                       block_k, seq_len, bd, group):
-    """dK/dV under the block-diffusion mask, for ONE kv head's K block and
-    ONE query head of its group a program: the grid's last axis walks the
-    group and the (block_k, d) sums live in VMEM scratch across it, so only
-    one query head's rows are resident at a time (the causal kernel above
-    holds the whole group: at 8 query heads a kv head and 8,192 rows that is
-    beyond the chip's VMEM).  Tiles are computed transposed, keys on the
-    rows, so the per-query ``lse`` and ``delta`` arrive as row vectors
-    ((1, 1, s_q) blocks, not lane-padded columns)."""
-    ki, g = pl.program_id(1), pl.program_id(2)
-    k_off = ki * block_k
-
-    @pl.when(g == 0)
-    def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    k_blk = k_ref[0]
-    v_blk = v_ref[0]
-
-    def body(qb, carry):
-        dk, dv = carry
-        q_off = qb * block_q
-        q_blk = q_ref[0, pl.ds(q_off, block_q), :]
-        do_blk = do_ref[0, pl.ds(q_off, block_q), :]
-        lse_blk = lse_ref[0, :, pl.ds(q_off, block_q)]      # (1, block_q)
-        delta_blk = delta_ref[0, :, pl.ds(q_off, block_q)]
-        st = jax.lax.dot_general(                           # (block_k, block_q)
-            k_blk, q_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        mask = _bd_tile_mask(k_off, q_off, block_k, block_q, seq_len, bd,
-                             False)
-        pt = jnp.where(mask, jnp.exp(st - lse_blk), 0.0)
-        dv = dv + jax.lax.dot_general(
-            pt, do_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dpt = jax.lax.dot_general(
-            v_blk, do_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dst = pt * (dpt - delta_blk)
-        dk = dk + jax.lax.dot_general(
-            dst, q_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
-
-    dk_acc[...], dv_acc[...] = _run_tiles(
-        _tile_ranges(k_off, block_k, block_q, q_ref.shape[1] // block_q,
-                     seq_len, causal=False, window=None, kv_off=0, bd=bd,
+        window=window, bd=bd)
+    dk_acc[...], dv_acc[...] = _run_group_tiles(
+        _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
+                     causal=causal, window=window, kv_off=kv_off, bd=bd,
                      rows_are_queries=False),
-        body, (dk_acc[...], dv_acc[...]))
+        1, body, (dk_acc[...], dv_acc[...]))
 
     @pl.when(g == group - 1)
     def _():
         dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _dkv_heads_a_program(group, s_q, d, dv, itemsize):
+    """The query heads one dK/dV program holds, and a head's bytes of q and
+    dO twice buffered: the whole ``group`` while it fits ``_DKV_GROUP_BYTES``,
+    else one.  The one place that decides the form, from bytes alone."""
+    head_bytes = 2 * s_q * (d + dv) * itemsize
+    return (group if group * head_bytes <= _DKV_GROUP_BYTES else 1), head_bytes
 
 
 def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
@@ -803,12 +828,10 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     off = _off_arr(kv_offset)
     kw = dict(sm_scale=1.0 / (d ** 0.5), causal=causal, block_q=block_q,
               block_k=block_k, seq_len=orig_s, window=window)
-    _note_tiles({"flash_attention_bwd_dq": "bwd_dq",
-                 "flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
-                 "bwd_dkv"},
-                kv_offset, s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
-                seq_len=orig_s, causal=causal, window=window, bd=bd, d_qk=d,
-                d_v=dv_w)
+    shape = dict(s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
+                 seq_len=orig_s, causal=causal, window=window, bd=bd, d_qk=d,
+                 d_v=dv_w)
+    _note_tiles({"flash_attention_bwd_dq": "bwd_dq"}, kv_offset, **shape)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bd=bd, **kw),
         name="flash_attention_bwd_dq",
@@ -827,108 +850,67 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
         interpret=interpret,
         **_kv_params(s_k, d, dv_w, kf.dtype),
     )(off, qf, kf, vf, gf, lse_f, delta_f)
-    if bd is not None:
-        # one query head a program, the group on the grid's last axis
-        row = lambda x: x.reshape(bh, 1, s_q)
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_bd_kernel, sm_scale=kw["sm_scale"], block_q=block_q,
-                block_k=block_k, seq_len=orig_s, bd=bd, group=group),
-            name="flash_attention_bwd_dkv_bd",
-            grid=(bh_kv, s_k // block_k, group),
-            in_specs=[
-                pl.BlockSpec((1, s_q, d),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, s_q, d),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, 1, s_q),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, 1, s_q),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
-                jax.ShapeDtypeStruct((bh_kv, s_k, d), vf.dtype),
-            ],
-            scratch_shapes=[_pltpu.VMEM((block_k, d), jnp.float32),
-                            _pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret,
-        )(qf, kf, vf, gf, row(lse_f), row(delta_f))
-        return dq, dk, dv
-    head_bytes = 2 * s_q * (d + dv_w) * qf.dtype.itemsize   # twice buffered
-    if group > 1 and group * head_bytes > _DKV_GROUP_BYTES:
-        # the whole group's q and dO do not fit VMEM: one query head a
-        # program, the group on the grid's last axis
-        row = lambda x: x.reshape(bh, 1, s_q)
-        dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_head_kernel, group=group, **kw),
-            name="flash_attention_bwd_dkv",
-            grid=(bh_kv, s_k // block_k, group),
-            in_specs=[
-                _SCALAR_SPEC,
-                pl.BlockSpec((1, s_q, d),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, dv_w), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, s_q, dv_w),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, 1, s_q),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-                pl.BlockSpec((1, 1, s_q),
-                             lambda b, ki, g: (b * group + g, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, ki, g: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, dv_w), lambda b, ki, g: (b, ki, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
-                jax.ShapeDtypeStruct((bh_kv, s_k, dv_w), vf.dtype),
-            ],
-            scratch_shapes=[_pltpu.VMEM((block_k, d), jnp.float32),
-                            _pltpu.VMEM((block_k, dv_w), jnp.float32)],
-            compiler_params=_pltpu.CompilerParams(
-                vmem_limit_bytes=head_bytes + _VMEM_HEADROOM),
-            interpret=interpret,
-        )(off, qf, kf, vf, gf, row(lse_f), row(delta_f))
-        return dq, dk, dv
-    # dK/dV per KV head: regroup the q-side operands so each kv-head
-    # program sees its whole query-head group on the row axis — a free
-    # reshape of the head-major fold (B, H_kv, G, s_q, d contiguity)
-    qg = qf.reshape(bh_kv, group * s_q, d)
-    gg = gf.reshape(bh_kv, group * s_q, dv_w)
-    lse_g = lse_f.reshape(bh_kv, 1, group * s_q)
-    delta_g = delta_f.reshape(bh_kv, 1, group * s_q)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, group=group, **kw),
-        name="flash_attention_bwd_dkv",
-        grid=(bh_kv, s_k // block_k),
+    heads, head_bytes = _dkv_heads_a_program(
+        group, s_q, d, dv_w, qf.dtype.itemsize)
+    if heads == group:
+        # the whole group a program: each kv-head program sees its query
+        # heads on the row axis — a free reshape of the head-major fold
+        # (B, H_kv, G, s_q, d contiguity) — at a block index that does not
+        # move along the key tiles
+        kernel, scratch = _bwd_dkv_kernel, []
+        grid = (bh_kv, s_k // block_k)
+        q_at = lambda b, ki: (b, 0, 0)
+        kv_at = lambda b, ki: (b, ki, 0)
+    else:
+        # the group's q and dO do not fit VMEM: one query head a program,
+        # the group on the grid's last axis, the sums in scratch across it
+        kernel = _bwd_dkv_head_kernel
+        scratch = [_pltpu.VMEM((block_k, d), jnp.float32),
+                   _pltpu.VMEM((block_k, dv_w), jnp.float32)]
+        grid = (bh_kv, s_k // block_k, group)
+        q_at = lambda b, ki, g: (b * group + g, 0, 0)
+        kv_at = lambda b, ki, g: (b, ki, 0)
+    rows = heads * s_q
+    _note_tiles({"flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
+                 "bwd_dkv"}, kv_offset, heads_a_program=heads, **shape)
+    call = dict(
+        grid=grid,
         in_specs=[
             _SCALAR_SPEC,
-            pl.BlockSpec((1, group * s_q, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dv_w), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, group * s_q, dv_w), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, group * s_q), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, rows, d), q_at),
+            pl.BlockSpec((1, block_k, d), kv_at),
+            pl.BlockSpec((1, block_k, dv_w), kv_at),
+            pl.BlockSpec((1, rows, dv_w), q_at),
+            pl.BlockSpec((1, 1, rows), q_at),
+            pl.BlockSpec((1, 1, rows), q_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dv_w), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_at),
+            pl.BlockSpec((1, block_k, dv_w), kv_at),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, s_k, d), kf.dtype),
             jax.ShapeDtypeStruct((bh_kv, s_k, dv_w), vf.dtype),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
-        **_resident_params(group * head_bytes),
-    )(off, qg, kf, vf, gg, lse_g, delta_g)
+        # stated always: beside its resident q and dO a program holds an
+        # iteration's eight tiles in float32, 8-10 MiB at 256-tiles, which
+        # at 10 MiB resident (192 / 128 at 8,192 rows) is past the
+        # compiler's own 16 MiB
+        compiler_params=_pltpu.CompilerParams(
+            vmem_limit_bytes=heads * head_bytes + _VMEM_HEADROOM),
+    )
+    kernel = functools.partial(kernel, group=group, bd=bd, **kw)
+    # one name a mask kind, whichever form (the traces read it)
+    if bd is None:
+        dkv = pl.pallas_call(kernel, name="flash_attention_bwd_dkv", **call)
+    else:
+        dkv = pl.pallas_call(kernel, name="flash_attention_bwd_dkv_bd", **call)
+    dk, dv = dkv(off, qf.reshape(bh // heads, rows, d), kf, vf,
+                 gf.reshape(bh // heads, rows, dv_w),
+                 lse_f.reshape(bh // heads, 1, rows),
+                 delta_f.reshape(bh // heads, 1, rows))
     return dq, dk, dv
 
 

@@ -132,12 +132,40 @@ def test_flash_latent_attention_compiles_at_the_cell_s_shapes(one_chip, backward
         assert shapes == [(1, 8192, 16, 192), (1, 8192, 16, 192), (1, 8192, 16, 128)]
 
 
+def test_flash_latent_dkv_alone_compiles_with_its_vmem_stated(one_chip):
+    """The dK/dV half alone at Kimi's shape (``tools/flash_bench.py --cells``'
+    jit): 10 MiB of q and dO resident and an iteration's eight float32 tiles
+    were 17.7 MiB, past the compiler's own 16 (refused on the chip and here,
+    PR 42), so the call states its VMEM whatever its resident bytes."""
+    from horovod_tpu.ops.flash_attention import _backward_impl
+
+    q = _sds((1, 8192, 16, 192), jnp.bfloat16, one_chip)
+    v = _sds((1, 8192, 16, 128), jnp.bfloat16, one_chip)
+    lse = _sds((16, 8192, 1), jnp.float32, one_chip)
+    compiled = _compile(
+        lambda q, k, v, out, lse, g: _backward_impl(
+            q, k, v, out, lse, g, True, 256, 256, False)[1:], q, q, v, v, lse, v)
+    text = compiled.as_text()
+    assert "flash_attention_bwd_dkv" in text and "flash_attention_bwd_dq" not in text
+
+
+def _dkv_bd_operands(text):
+    """The operand shapes the compiled ``flash_attention_bwd_dkv_bd`` call
+    constrains: its q is the second (the key offset comes first)."""
+    (line,) = [l for l in text.splitlines()
+               if re.search(r"%flash_attention_bwd_dkv_bd\.\d+ = ", l)]
+    constraints = re.search(r"operand_layout_constraints=\{(.*?\})\}", line).group(1)
+    return re.findall(r"\w+\[[\d,]*\]", constraints)
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward):
     """sdar-30b-a3b-bd4-s4096-1chip: one row of [noisy || clean] = 8,192
     positions, 32 query heads over 4 key/value heads of 128, blocks of 4.
-    The causal dK/dV kernel cannot hold a group of 8 query heads of 8,192
-    rows in VMEM; the block-diffusion one holds one query head a program."""
+    A group of 8 query heads' q and dO at 8,192 rows are 64 MiB twice
+    buffered: the dK/dV kernel holds the whole group a program as under the
+    other masks (``_DKV_GROUP_BYTES``; Laguna's sliding layers are the same
+    shape), stating 80 MiB of VMEM, and its q operand is the group's rows."""
     half = 4096
     q = _sds((1, 2 * half, 32, 128), jnp.bfloat16, one_chip)
     kv = _sds((1, 2 * half, 4, 128), jnp.bfloat16, one_chip)
@@ -154,7 +182,28 @@ def test_flash_block_diffusion_compiles_at_the_cell_s_shapes(one_chip, backward)
     assert "flash_attention_fwd" in text
     if backward:
         assert "flash_attention_bwd_dq" in text
-        assert "flash_attention_bwd_dkv_bd" in text
+        operands = _dkv_bd_operands(text)
+        assert operands[1] == operands[4] == "bf16[4,65536,128]", operands
+        assert "bf16[32,8192,128]" not in operands
+        assert operands[5] == operands[6] == "f32[4,1,65536]"
+
+
+def test_flash_block_diffusion_beyond_the_group_s_bytes_is_one_head_a_program(one_chip):
+    """256-wide heads under the mask, 16 query heads over 2 key/value heads at
+    8,192 rows: the group's q and dO would be 128 MiB twice buffered, so
+    dK/dV runs one query head a program (16 MiB, stated) under the same name."""
+    half = 4096
+    q = _sds((1, 2 * half, 16, 256), jnp.bfloat16, one_chip)
+    kv = _sds((1, 2 * half, 2, 256), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, block_diffusion=(half, 4),
+                                       interpret=False).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).as_text()
+    operands = _dkv_bd_operands(text)
+    assert operands[1] == operands[4] == "bf16[16,8192,256]", operands
+    assert operands[5] == operands[6] == "f32[16,1,8192]"
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
@@ -862,15 +911,16 @@ def test_rotary_step_under_q_k_norms_and_block_diffusion_is_one_pass_a_direction
 
 
 # sha256 of the step below lowered at the parent of PR 40 (commit 6fbca40), this
-# test's own lines run in that tree
-_LATENT_STEP_AT_THE_PARENT = "8e42198242e0c1ff27a999e06d1b52e67ded0354063ab32a61483922a48e245f"
+# test's own lines run in that tree; since PR 42 with its dK/dV kernel walking
+# a program's heads as one, another program by design (before: 8e421982...245f)
+_LATENT_STEP_AT_THE_PARENT = "55dd310a470a5d17d2fdff89c8fcff9a80a7d9c346b44acb4f611ac423a80715"
 
 
 def test_latent_attention_s_step_is_the_parent_s_to_the_byte():
     """Kimi's kind of layer at its head widths (128 + 64 rotary columns a query
     head, ONE 64-wide rotary key for all heads, 'flash'): a 64-wide rotary slice
     of a 192-wide head is no shape the rotary kernels take, so the lowered step
-    holds none and is the one the parent lowered."""
+    holds none and is the one the parent lowered (PR 42's dK/dV walk apart)."""
     import hashlib
 
     cfg = TransformerConfig(

@@ -69,9 +69,11 @@ def test_flash_gradients_match_dense(dtype, tol, s, block):
     """Training through the kernel: custom_vjp gradients must match the
     dense path's (backward recomputes with the kernel's upcast numerics;
     bf16 compares loosely against the model's dense reference).  Eight
-    tiles a side: dQ's and dK/dV's loops run one to eight tiles, in
-    iterations of four, two and one (``tile_counts``: 36 visits in 14)."""
-    assert tile_counts(1024, 1024, 128, 128, 1024)["bwd_dkv"] == (36, 14)
+    tiles a side: dQ's and dK/dV's loops run one to eight tiles, dQ's in
+    iterations of four, two and one and dK/dV's of eight first
+    (``tile_counts``: 36 visits in 14, and in 13)."""
+    counts = tile_counts(1024, 1024, 128, 128, 1024)
+    assert (counts["bwd_dq"], counts["bwd_dkv"]) == ((36, 14), (36, 13))
     q, k, v = _qkv(1, s, 2, 32, dtype, seed=3)
 
     def loss_flash(q, k, v):
@@ -187,24 +189,34 @@ def test_flash_non_causal_gradients():
 
 def test_flash_tiles_event_names_each_kernel_traced():
     """Tracing a kernel leaves one ``flash.tiles`` instant with its name and
-    a head's tile visits and loop iterations (``tile_counts``); the
-    block-diffusion kind names its own dK/dV kernel."""
+    the tile visits and loop iterations (``tile_counts``): a head's, and for a
+    dK/dV kernel those of the query heads a program holds and walks as one,
+    ``heads_a_program`` beside them; the block-diffusion kind names its own
+    dK/dV kernel."""
     from horovod_tpu import trace
 
-    def traced(**kw):
+    def traced(kv_heads=2, **kw):
         t0 = trace.now()
         q = jnp.ones((1, 512, 2, 16), jnp.float32)
         jax.make_jaxpr(jax.grad(lambda a: flash_attention(
-            a, a, a, block_q=128, block_k=128, **kw).sum()))(q)
+            a, a[:, :, :kv_heads], a[:, :, :kv_heads], block_q=128, block_k=128,
+            **kw).sum()))(q)
         return {r[3]["kernel"]: (r[3]["visited"], r[3]["iterations"])
+                + ((r[3]["heads_a_program"],) if "heads_a_program" in r[3] else ())
                 for r in trace.snapshot(t0) if r[0] == "flash.tiles"}
 
+    # the dK/dV kernels alone say how many query heads a program holds
     assert traced(window=300) == {
         "flash_attention_fwd": (10, 5), "flash_attention_bwd_dq": (10, 5),
-        "flash_attention_bwd_dkv": (10, 5)}
+        "flash_attention_bwd_dkv": (10, 5, 1)}
+    # two heads a program: twice the visits in as many iterations
+    assert traced(window=300, kv_heads=1)["flash_attention_bwd_dkv"] == (20, 5, 2)
     assert traced(block_diffusion=(256, 4)) == {
         "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
-        "flash_attention_bwd_dkv_bd": (8, 6)}
+        "flash_attention_bwd_dkv_bd": (8, 4, 1)}
+    assert traced(block_diffusion=(256, 4), kv_heads=1) == {
+        "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
+        "flash_attention_bwd_dkv_bd": (16, 4, 2)}
 
 
 # -- 256-wide heads, eight query heads a key/value head (PR 35) ----------------
@@ -253,6 +265,31 @@ def test_the_one_head_dkv_kernel_is_chosen_by_the_group_s_bytes():
     assert group_bytes(2, 4096, 128, 128) == 8 * 2 ** 20 < fa._DKV_GROUP_BYTES
     assert group_bytes(1, 8192, 192, 128) == 10 * 2 ** 20 < fa._DKV_GROUP_BYTES
     assert group_bytes(8, 8192, 256, 256) == 128 * 2 ** 20 > fa._DKV_GROUP_BYTES
+    # the block-diffusion mask goes by the same bytes: the SDAR cell's group of
+    # 8 x 8,192 rows of 128 + 128 is AT the threshold and held whole (as
+    # Laguna's sliding layers' is), 256-wide heads under the mask are not
+    assert group_bytes(8, 8192, 128, 128) == 64 * 2 ** 20 == fa._DKV_GROUP_BYTES
+    # the one rule that decides (the kernel's and tools/flash_bench.py's): the
+    # heads a program holds, and a head's bytes
+    assert fa._dkv_heads_a_program(8, 8192, 128, 128, 2) == (8, 8 * 2 ** 20)
+    assert fa._dkv_heads_a_program(8, 8192, 256, 256, 2) == (1, 16 * 2 ** 20)
+    assert fa._dkv_heads_a_program(1, 8192, 192, 128, 2) == (1, 10 * 2 ** 20)
+    from horovod_tpu import trace
+
+    def heads_a_program(heads, kv_heads, width):
+        """What the dK/dV kernel traced at 8,192 rows under the mask says."""
+        t0 = trace.now()
+        q = jax.ShapeDtypeStruct((1, 8192, heads, width), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 8192, kv_heads, width), jnp.bfloat16)
+        jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, block_diffusion=(4096, 4), interpret=True).astype(jnp.float32))),
+            q, kv, kv)
+        (event,) = [r[3] for r in trace.snapshot(t0) if r[0] == "flash.tiles"
+                    and r[3]["kernel"] == "flash_attention_bwd_dkv_bd"]
+        return event["heads_a_program"]
+
+    assert heads_a_program(32, 4, 128) == 8
+    assert heads_a_program(16, 2, 256) == 1
     # and the forward / dQ kernels state their VMEM only beyond the compiler's own
     assert fa._kv_params(8192, 192, 128, jnp.bfloat16) == {}
     assert fa._kv_params(8192, 128, 128, jnp.bfloat16) == {}

@@ -319,24 +319,61 @@ def test_block_diffusion_tile_ranges_visit_what_the_mask_allows(
             s_pad // block_q)
 
 
-@pytest.mark.parametrize("cell,kw,want", [
+@pytest.mark.parametrize("cell,kw,want,heads,dkv,dkv_a_head", [
     ("internlm2-1.8b-s4096-1chip",
-     dict(s_q=4096, s_k=4096, seq_len=4096, causal=True), (136, 44)),
+     dict(s_q=4096, s_k=4096, seq_len=4096, causal=True), (136, 44),
+     2, (272, 44), (136, 34)),
     ("sdar-30b-a3b-bd4-s4096-1chip",
      dict(s_q=8192, s_k=8192, seq_len=8192, causal=False, bd=(4096, 4)),
-     (288, 104)),
+     (288, 104), 8, (2304, 288), (288, 60)),
     ("laguna-xs.2-s8192-1chip/full",
-     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True), (528, 152)),
+     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True), (528, 152),
+     6, (3168, 416), (528, 100)),
     ("laguna-xs.2-s8192-1chip/sliding",
-     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True, window=512), (93, 62)),
+     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True, window=512), (93, 62),
+     8, (744, 93), (93, 62)),
 ])
-def test_tile_counts_at_the_cells_shapes(cell, kw, want):
+def test_tile_counts_at_the_cells_shapes(cell, kw, want, heads, dkv, dkv_a_head):
     """A head's tile visits and the loop iterations they take in 256-tiles:
     3.1 and 2.8 tiles an iteration for the scheduler to overlap (3.5 at
     8,192 under the causal mask); a window of 512 visits three tiles a
-    query tile, the diagonal one and two before it, in two iterations."""
-    counts = tile_counts(block_q=256, block_k=256, **kw)
-    assert counts == {"fwd": want, "bwd_dq": want, "bwd_dkv": want}
+    query tile, the diagonal one and two before it, in two iterations.  The
+    dK/dV kernel walks the query heads a program holds as one (PR 42), eight
+    tiles an iteration first: at a group of 8 every iteration holds eight,
+    the window's three tiles a head and the 512 lone visits of a
+    block-diffusion layer's noisy key tiles among them; one head a program
+    (``heads_a_program=1``: the same shape beyond ``_DKV_GROUP_BYTES``) has
+    its own walks' remainders."""
+    counts = tile_counts(block_q=256, block_k=256, heads_a_program=heads, **kw)
+    assert counts == {"fwd": want, "bwd_dq": want, "bwd_dkv": dkv}
+    assert tile_counts(block_q=256, block_k=256, **kw)["bwd_dkv"] == dkv_a_head
+
+
+@pytest.mark.parametrize("group", [1, 2, 8])
+@pytest.mark.parametrize("ranges", [((2, 5),), ((3, 4), (6, 11)), ((0, 0), (1, 3)),
+                                     ((4, 9), (9, 9)), ((0, 0), (0, 0))])
+def test_the_group_s_walk_visits_what_a_walk_a_head_visits_in_its_order(ranges, group):
+    """``_run_group_tiles`` against ``_run_tiles`` head after head: the same
+    (head, tile) visits in the same order, bounds traced, whatever the ranges'
+    lengths (an empty one, both empty) and the group."""
+    from horovod_tpu.ops.flash_attention import _run_group_tiles, _run_tiles
+
+    def visits(walk):
+        def run(bounds):
+            note = lambda g, t, carry: (carry[0].at[carry[1]].set(100 * g + t), carry[1] + 1)
+            return walk(tuple((lo, hi) for lo, hi in bounds), note,
+                        (jnp.full((64,), -1, jnp.int32), jnp.int32(0)))
+        seen, n = jax.jit(run)(jnp.asarray(ranges, jnp.int32))
+        return list(np.asarray(seen)[:int(n)])
+
+    def a_head(ranges, note, carry):
+        for g in range(group):
+            carry = _run_tiles(ranges, lambda t, carry, g=g: note(g, t, carry), carry)
+        return carry
+
+    want = [100 * g + t for g in range(group) for lo, hi in ranges for t in range(lo, hi)]
+    assert visits(a_head) == want
+    assert visits(lambda r, note, carry: _run_group_tiles(r, group, note, carry)) == want
 
 
 @pytest.mark.parametrize("causal,window,seq_len", [

@@ -509,8 +509,12 @@ _DEFAULTS = dict(kv_lora_rank=None, qk_nope_head_dim=None, qk_rope_head_dim=None
 # test's own helper run in that tree on the suite's eight CPU devices.  SDAR's is
 # PR 33's: its routed layer's grouped products became the package's own kernels
 # (ops/grouped_matmul.py), another program by design (before: b704eb86...7fe89);
-# interpreted on the CPU their bodies are part of the text, so it moves with them
-_PARENT_TEXT = {"lm": "d1d9a043e1529fd37a0d6037ab591dc0afc2ac2d8e00c6263bd10d12a7515463", "sdar": "f916682c06aa3cb41fc0a05374ecc6d16421cab67100103ad085807cfa99a577"}
+# interpreted on the CPU their bodies are part of the text, so it moves with them;
+# and PR 42's: dK/dV under the block-diffusion mask by the whole-group kernel.
+# Both are PR 42's since the dK/dV kernels walk a program's heads as one under
+# every mask, another program by design (before: the LM's d1d9a043...5463, PR
+# 32's parent's; SDAR's f916682c...a577)
+_PARENT_TEXT = {"lm": "036f93384494f559c68b17f273bbd99f3d5840ea14fa17c3c4a1f1ca6c74f5dc", "sdar": "ecd6688a49f3bad081ed76e7ed7e3f564db374d179b5891e5e079aa2a2300e1b"}
 
 
 @pytest.mark.parametrize("name", ["lm", "sdar"])

@@ -140,6 +140,83 @@ def test_flash_block_diffusion_matches_the_dense_mask(half, block, heads, kv, ti
         np.testing.assert_allclose(a, b, atol=2e-5)
 
 
+def _bd_grads(half, block, heads, kv, dtype, fn=None):
+    """dq, dk, dv of ``fn`` (default: the flash kernels in 128-tiles) under
+    the block-diffusion mask, of one weighted sum of its output."""
+    keys = jax.random.split(jax.random.PRNGKey(half + heads), 4)
+    q, k, v = (jax.random.normal(key, (1, 2 * half, n, 16)).astype(dtype)
+               for key, n in zip(keys, (heads, kv, kv)))
+    w = jax.random.normal(keys[3], q.shape)
+    if fn is None:
+        fn = lambda q, k, v: flash_attention(
+            q, k, v, block_q=128, block_k=128, block_diffusion=(half, block))
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), (0, 1, 2))(q, k, v)
+
+
+@pytest.fixture
+def dkv_form(monkeypatch):
+    """``dkv_form(one_head)`` chooses the dK/dV form by the module's own
+    threshold, and ``dkv_form.traced()`` says what the kernels traced since
+    held: ``heads_a_program`` of each ``flash_attention_bwd_dkv_bd`` event.
+    The jitted entry caches its trace by arguments and not by the module's
+    globals, so the cache is emptied around the change."""
+    from horovod_tpu import trace
+    from horovod_tpu.ops import flash_attention as fa
+
+    def choose(one_head):
+        fa.flash_attention.clear_cache()
+        if one_head:
+            monkeypatch.setattr(fa, "_DKV_GROUP_BYTES", 0)
+        choose.t0 = trace.now()
+
+    choose.traced = lambda: [
+        r[3]["heads_a_program"] for r in trace.snapshot(choose.t0)
+        if r[0] == "flash.tiles" and r[3]["kernel"] == "flash_attention_bwd_dkv_bd"]
+    yield choose
+    fa.flash_attention.clear_cache()
+
+
+@pytest.mark.parametrize("one_head", [False, True],
+                         ids=["dkv_whole_group", "dkv_one_head_a_program"])
+@pytest.mark.parametrize("heads,kv", [(8, 1), (2, 2)], ids=["group_8", "group_1"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 6e-2)],
+                         ids=["float32", "bfloat16"])
+def test_flash_block_diffusion_dkv_forms_match_the_dense_mask(
+        dkv_form, dtype, tol, heads, kv, one_head):
+    """dq, dk, dv under the mask against the dense mask, dK/dV by both forms
+    of its kernel: the whole query-head group a program (what the group's
+    bytes allow: the cell's 64 MiB), and one query head a program with the
+    sums in VMEM scratch (forced by a threshold of 0, as for the causal mask
+    in ``test_flash_256_wide_heads_match_dense``).  400 rows in 128-tiles: a
+    length that is no multiple of the tile, a tile that straddles L.  A group
+    of one has one form."""
+    half, block = 200, 4
+    dkv_form(one_head)
+    got = _bd_grads(half, block, heads, kv, dtype)
+    assert dkv_form.traced() == [1 if one_head else heads // kv]
+    mask = block_diffusion_mask(half, block)
+    want = _bd_grads(half, block, heads, kv, dtype, lambda q, k, v: _dense_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), mask))
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=tol * max(1.0, np.abs(b).max()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_flash_block_diffusion_dkv_forms_agree_bit_for_bit(dkv_form, dtype):
+    """The same tiles in the same order a head, the heads summed in the same
+    order, in float32: the two forms of the dK/dV kernel give the same dk and
+    dv to the last bit (the scratch the head form carries between its
+    programs is the carry of the group form's loop)."""
+    dkv_form(False)
+    group = _bd_grads(200, 4, 8, 1, dtype)
+    dkv_form(True)
+    head = _bd_grads(200, 4, 8, 1, dtype)
+    assert dkv_form.traced() == [1]
+    for a, b in zip(group, head):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
 def test_flash_block_diffusion_refuses_what_it_cannot_mask():
     q = jnp.zeros((1, 16, 2, 8))
     with pytest.raises(ValueError, match="2 L"):

@@ -54,7 +54,8 @@ from horovod_tpu.common.retry import env_int  # noqa: E402
 from horovod_tpu.models.transformer import causal_dot_attention  # noqa: E402
 from horovod_tpu.ops import grouped_matmul as gm  # noqa: E402
 from horovod_tpu.ops.flash_attention import (  # noqa: E402
-    _backward_impl, _clamp_blocks, _forward_impl, flash_attention,
+    _backward_impl, _clamp_blocks, _dkv_heads_a_program, _forward_impl,
+    flash_attention,
     tile_counts,
 )
 
@@ -263,9 +264,9 @@ CELL_SHAPES = {
 def leg_cells(shapes, iters, warmup, interpret, block=256):
     """The three training kernels apart, one layer's call each: the dQ and
     the dK/dV program are the two halves of ``_backward_impl`` (a jit
-    that returns one of them drops the other kernel).  Beside each time, a
-    head's tile visits and the loop iterations they take (``tile_counts``)
-    and the time a tile visit, which PERF.md §5 holds against the 0.085 us
+    that returns one of them drops the other kernel).  Beside each time, the
+    tile visits and the loop iterations they take (``tile_counts``: a head's,
+    and for dK/dV those of the heads a program holds) and the time a tile visit, which PERF.md §5 holds against the 0.085 us
     a 256 x 256 x 128 product needs on the v5e's MXU."""
     for cell, (b, s, h, h_kv, d, causal, bd, *window) in shapes.items():
         window = window[0] if window else None
@@ -295,15 +296,23 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
               "bwd_dq": timed(lambda *a: bwd(*a)[0], q, k, v, out, lse, g),
               "bwd_dkv": timed(lambda *a: bwd(*a)[1:], q, k, v, out, lse, g)}
         bq, bk = _clamp_blocks(s, block, block)
+        # a dK/dV program walks the query heads it holds as one: its counts
+        # are theirs, and the kernel's own rule says how many they are
+        heads = _dkv_heads_a_program(
+            h // h_kv, _pad(s, bq), q.shape[-1], v.shape[-1],
+            q.dtype.itemsize)[0]
         tiles = tile_counts(_pad(s, bq), _pad(s, bk), bq, bk, s,
-                            causal=causal, window=window, bd=bd)
+                            causal=causal, window=window, bd=bd,
+                            heads_a_program=heads)
+        walks = {"fwd": b * h, "bwd_dq": b * h, "bwd_dkv": b * h // heads}
         rec = {"bench": "flash_cells", "cell": cell, "b": b, "s": s, "h": h,
                "h_kv": h_kv, "d": d, "window": window, "block": [bq, bk]}
         for name, t in ms.items():
             visited, iterations = tiles[name]
             rec[name + "_ms"] = round(t, 4)
             rec[name + "_tiles"] = [visited, iterations]
-            rec[name + "_us_per_tile"] = round(t * 1e3 / (b * h * visited), 4)
+            rec[name + "_us_per_tile"] = round(
+                t * 1e3 / (walks[name] * visited), 4)
         _emit(rec, f"{cell}: " + "  ".join(
             f"{n} {t:7.3f} ms" for n, t in ms.items())
             + f"  tiles a head {tiles['fwd'][0]} in {tiles['fwd'][1]} iterations")
