@@ -11,10 +11,11 @@ the local block of ring attention; numerically validated against
 tests/test_gqa_flash.py).
 
 Kernel shape (the standard TPU flash forward, per pallas_guide.md):
-grid = (batch*heads, Sq/block_q); each program holds one Q block in VMEM,
-K/V for the whole (padded) sequence stream through VMEM block-by-block
-inside a ``fori_loop`` with running (max, sum, accumulator) statistics in
-float32; causal programs stop the loop at the diagonal block.
+grid = (batch*heads, Sq/(Q*block_q)); each program holds Q query blocks of one
+head in VMEM (``_query_tiles_a_program``), K/V for the whole (padded) sequence
+stream through VMEM block-by-block inside ``fori_loop``s with running (max,
+sum, accumulator) statistics in float32; causal programs stop at the diagonal
+block.
 
 Operand dtypes: the kernel's inputs (``q``, ``k``, ``v``, ``dO``) are
 widened to float32 in front of every product and the products run on the MXU
@@ -28,15 +29,25 @@ rounding is not).  float32 inputs (the tests) go the same way.
 Several tiles a loop iteration: within one tile the second product waits
 for the tile's own softmax and the softmax for the first product, and one
 loop iteration is one scheduling region, so a loop of one tile an iteration
-leaves the MXU idle for most of it.  The forward and dQ kernels therefore
-walk their tiles four an iteration, then the rest two and one
-(``_run_tiles``): the tiles of an iteration are independent but for the
-running sums, so the scheduler fills one tile's waits with the next one's
-products.  Tiles are visited in the same order: the result is the same bit
-for bit.  ``tile_counts`` gives the tile visits and loop iterations by kernel
-(136 visits in 44 iterations a head under the causal mask at 4,096 in
-256-tiles; a window of one tile visits one tile an iteration, and nothing is
-won), and tracing a kernel records them as a ``flash.tiles`` event
+leaves the MXU idle for most of it.  Every kernel therefore walks its tiles
+eight an iteration, then the rest four, two and one (``_walk``): the tiles
+of an iteration are independent but for the running sums, so the scheduler
+fills one tile's waits with the next one's products.  Tiles are visited in
+the same order: the result is the same bit for bit.
+
+One walk a program: a Mosaic program pays for every loop it holds, run or
+not, and for its own start.  A forward or dQ program therefore holds several
+consecutive query tiles of one head where their ranges are short
+(``_query_tiles_a_program``: four under a window of two tiles, one where a
+query tile has eight visits or more) and walks all their visits, range after
+range and query tile after query tile, as ONE sequence in one set of loops
+(``_run_query_tiles``).  Each query tile has its own running sums, in VMEM
+scratch at the tile's number, the forward's row sums ``l`` and ``m`` as wide
+as a vector register (a row's number on all 128 lanes: reading and writing
+them moves whole registers, where a 1-D carry cost a lane broadcast a visit).
+``tile_counts`` gives the tile visits and loop iterations by kernel (136
+visits in 34 iterations a head under the causal mask at 4,096 in 256-tiles),
+and tracing a kernel records them as a ``flash.tiles`` event
 (``horovod_tpu.trace``).  Every mask kind's loop bounds come from
 ``_tile_ranges``; tiles are masked element by element as before.
 
@@ -50,11 +61,9 @@ VMEM scratch across the grid's last axis (``_bwd_dkv_head_kernel``), which
 fetches a head's q and dO for every key tile.  Both take every mask kind
 through one tile body (``_dkv_tile``); under the block-diffusion mask the call
 is named ``flash_attention_bwd_dkv_bd`` in either form.  Both walk the tiles
-of the heads a program holds as ONE sequence, eight a loop iteration, then
-four, two and one (``_run_group_tiles``: head after head in ``_run_tiles``'
-order, so the sums are the same bit for bit): a Mosaic program pays for every
-loop it holds, run or not, and a loop nest a head was 48 loops a program at a
-group of 8 under two ranges.
+of the heads a program holds as ONE sequence (``_run_group_tiles``: head
+after head, range after range, so the sums are the same bit for bit): a loop
+nest a head was 48 loops a program at a group of 8 under two ranges.
 
 Grouped-query attention (GQA — Ainslie et al., 2023) is KERNEL-NATIVE:
 ``k``/``v`` may carry ``num_kv_heads < num_heads`` heads and are folded
@@ -115,26 +124,33 @@ _VMEM_HEADROOM = 16 * 1024 * 1024
 _KV_RESIDENT_BYTES = 12 * 1024 * 1024
 
 
-def _kv_params(s_k, d, dv, dtype):
+def _kv_params(s_k, d, dv, dtype, q_side=0):
     """``compiler_params`` for a kernel that holds (s_k, d) keys and (s_k, dv)
-    values in VMEM, twice buffered: none while they fit the compiler's own
-    limit, else what it needs, stated."""
-    resident = 2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize
+    values in VMEM, twice buffered, beside ``q_side`` bytes of query-side
+    blocks and scratch (``_query_side_bytes``): none while they fit the
+    compiler's own limit, else what it needs, stated."""
+    resident = 2 * s_k * (d + dv) * jnp.dtype(dtype).itemsize + q_side
     if resident <= _KV_RESIDENT_BYTES:
         return {}
     return {"compiler_params": _pltpu.CompilerParams(
         vmem_limit_bytes=resident + _VMEM_HEADROOM)}
 
 
-# tiles a loop iteration: a range runs four at a time, then what is left two
-# and one at a time (_run_tiles).  Starting at 8 was 2 % of the kernels' time
-# faster again at twice their compile time (PERF.md PR 29)
-_TILES_AN_ITERATION = (4, 2, 1)
-# the same for the dK/dV kernels, which walk a program's heads as one
-# (_run_group_tiles): one set of loops a program whatever the group, so
-# starting at 8 costs little to compile (Mosaic 2.6 s against 1.4), and it was
-# 9 % of the kernel's time (4.54 against 4.97 ms alone, PERF.md PR 42)
-_TILES_A_WALK_ITERATION = (8, 4, 2, 1)
+# tiles a loop iteration, every kernel's: a program's walk runs eight at a
+# time, then what is left four, two and one at a time (_walk).  One set of
+# loops a program whatever it walks, so starting at 8 costs little to compile
+# (Mosaic 2.6 s against 1.4); it was 9 % of the dK/dV kernel's time (PERF.md
+# PR 42) and 3-10 % of the forward's and dQ's (PR 43)
+_TILES_AN_ITERATION = (8, 4, 2, 1)
+# a forward or dQ program walks consecutive query tiles of a head as one until
+# it has this many tile visits, at most so many tiles (_query_tiles_a_program):
+# a window of two tiles gets four query tiles a program (3.50 against 4.46 ms
+# a layer at one, 3.42 at eight), and a mask of nine visits a query tile one:
+# beyond a tile a program the tile's number is a traced index and the mask's
+# query-side columns are made at every visit, which cost the block-diffusion
+# mask 15 % and gave the causal one 4-7 % (PERF.md PR 43)
+_VISITS_A_PROGRAM = 8
+_QUERY_TILES_MOST = 8
 
 
 def _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off=0):
@@ -294,36 +310,77 @@ def _tile_ranges(off, rows, other_block, n_other, seq_len, *, causal, window,
     return ((lo, hi),)
 
 
-def _run_tiles(ranges, body, carry):
-    """``carry = body(t, carry)`` for every tile ``t`` of every range, in
-    order, ``_TILES_AN_ITERATION`` tiles a loop iteration: one iteration is
-    one region for the scheduler, which overlaps its tiles (module
-    docstring).  Bounds may be traced, and ``lo >= hi`` runs nothing."""
-    for lo, hi in ranges:
-        for n in _TILES_AN_ITERATION:
-            steps = jnp.maximum(hi - lo, 0) // n
+def _walk(total, visit, state):
+    """``state = visit(state)`` ``total`` times, ``_TILES_AN_ITERATION`` visits
+    a loop iteration (its first entry, then what is left by the next ones):
+    one iteration is one region for the scheduler, which overlaps its tiles
+    (module docstring), and a program holds one loop an entry whatever it
+    walks.  ``total`` may be traced, and 0 runs nothing."""
+    left = total
+    for m in _TILES_AN_ITERATION:
+        steps = left // m
 
-            def several(i, carry, lo=lo, n=n):
-                for j in range(n):
-                    carry = body(lo + n * i + j, carry)
-                return carry
+        def several(_, state, m=m):
+            for _ in range(m):
+                state = visit(state)
+            return state
 
-            carry = jax.lax.fori_loop(0, steps, several, carry)
-            lo = lo + steps * n
-    return carry
+        state = jax.lax.fori_loop(0, steps, several, state)
+        left = left - steps * m
+    return state
+
+
+def _query_tiles_ranges(first, tiles, block_q, block_k, n_k, seq_len, **mask):
+    """``_run_query_tiles``' ranges for the ``tiles`` consecutive query tiles
+    of ``block_q`` rows from row ``first`` on: each tile's own
+    (``_tile_ranges`` at its offset)."""
+    return [_tile_ranges(first + j * block_q, block_q, block_k, n_k, seq_len,
+                         rows_are_queries=True, **mask) for j in range(tiles)]
+
+
+def _run_query_tiles(ranges, body, carry):
+    """``carry = body(j, t, carry)`` for every query tile ``j`` of those a
+    program holds (``ranges[j]``: its half-open tile ranges) and every tile
+    ``t`` of every range: query tile after query tile and, within one, range
+    after range in rising order, as ONE walk of all their visits,
+    ``_TILES_AN_ITERATION`` a loop iteration.  The ranges differ from query
+    tile to query tile (they follow its offset), so the walk keeps its place
+    ``P`` alone and reads the query tile and the tile off it: place ``P`` is
+    tile ``P + shift`` of query tile ``j`` while ``P`` is before the range's
+    end, range after range, so an empty range (``lo >= hi``, bounds may be
+    traced) is passed over and an iteration's tiles may be of two query
+    tiles.  The forward and dQ kernels' driver (the chunk and decode kernels',
+    the ring's); one query tile a program is a walk of one."""
+    shifts, ends, n = [], [], 0
+    for tile_ranges in ranges:
+        for lo, hi in tile_ranges:
+            shifts.append((lo - n, n + jnp.maximum(hi - lo, 0)))
+            n = shifts[-1][1]
+        ends.append(n)  # where the walk leaves this query tile
+
+    def visit(state):
+        p, carry = state
+        j, shift = len(ranges) - 1, shifts[-1][0]
+        for earlier, end in shifts[-2::-1]:
+            shift = jnp.where(p < end, earlier, shift)
+        for earlier, end in reversed(list(enumerate(ends[:-1]))):
+            j = jnp.where(p < end, earlier, j)
+        return p + 1, body(j, p + shift, carry)
+
+    return _walk(n, visit, (jnp.int32(0), carry))[1]
 
 
 def _run_group_tiles(ranges, group, body, carry):
     """``carry = body(g, t, carry)`` for every query head ``g`` of a group and
     every tile ``t`` of every range: head after head and, within a head, range
-    after range in rising order (``_run_tiles``' order a head, so the sums
-    come out the same bit for bit), but as ONE walk of ``group x tiles``
-    visits, ``_TILES_A_WALK_ITERATION`` a loop iteration.  The head and the
-    place in its walk are carried beside the sums, so an iteration's tiles
-    may be of two heads: a head's walk of one tile (a noisy key tile under
-    the block-diffusion mask) or of three (a window) still fills whole
-    iterations, and a program holds one set of loops whatever the group.  The
-    dK/dV kernels' driver; a group of 1 is ``_run_tiles`` at this tuple."""
+    after range in rising order (so the sums come out the same bit for bit
+    as a loop nest a head would give them), but as ONE walk of ``group x
+    tiles`` visits, ``_TILES_AN_ITERATION`` a loop iteration.  The head
+    and the place in its walk are carried beside the sums (the heads' ranges
+    are equal), so an iteration's tiles may be of two heads: a head's walk of
+    one tile (a noisy key tile under the block-diffusion mask) or of three (a
+    window) still fills whole iterations, and a program holds one set of
+    loops whatever the group.  The dK/dV kernels' driver."""
     shifts, n = [], 0       # place p of a head's walk is tile p + shift
     for lo, hi in ranges:   # while p < end, range after range
         shifts.append((lo - n, n + jnp.maximum(hi - lo, 0)))
@@ -335,70 +392,106 @@ def _run_group_tiles(ranges, group, body, carry):
             shift = jnp.where(p < end, earlier, shift)
         return p + shift
 
-    state = (jnp.int32(0), jnp.int32(0), carry)
-    left = group * n
-    for m in _TILES_A_WALK_ITERATION:
-        steps = left // m
+    def visit(state):
+        g, p, carry = state
+        carry = body(g, tile_at(p), carry)
+        last = p + 1 == n       # the head's walk is done
+        return jnp.where(last, g + 1, g), jnp.where(last, 0, p + 1), carry
 
-        def several(_, state, m=m):
-            g, p, carry = state
-            for _ in range(m):
-                carry = body(g, tile_at(p), carry)
-                last = p + 1 == n       # the head's walk is done
-                g, p = jnp.where(last, g + 1, g), jnp.where(last, 0, p + 1)
-            return g, p, carry
+    return _walk(group * n, visit, (jnp.int32(0), jnp.int32(0), carry))[2]
 
-        state = jax.lax.fori_loop(0, steps, several, state)
-        left = left - steps * m
-    return state[2]
+
+def _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal=True,
+                       window=None, kv_off=0, bd=None):
+    """The tile visits of each query tile of one head, an array of
+    ``s_q // block_q``: the kernels' own bounds (``_tile_ranges`` on numpy)."""
+    tiles = s_q // block_q
+    ranges = _tile_ranges(
+        np.arange(tiles) * block_q, block_q, block_k, s_k // block_k, seq_len,
+        causal=causal, window=window, kv_off=kv_off, bd=bd,
+        rows_are_queries=True, xp=np)
+    return sum(np.broadcast_to(np.maximum(hi - lo, 0), (tiles,))
+               for lo, hi in ranges)
+
+
+def _query_side_bytes(tiles, block_q, d, dv, itemsize):
+    """What a forward or dQ program holds in VMEM for ``tiles`` query tiles:
+    its query-side blocks twice buffered and its scratch, the larger of the
+    two kernels' (the forward: q, o, the ``lse`` column, the scaled q and the
+    running sums; dQ: q, dO, dq, the ``lse`` and ``delta`` columns and the
+    sum).  A column of float32 is lane-padded: 128 KiB a 256-row tile."""
+    column = 128 * 4
+    fwd = 2 * ((d + dv) * itemsize + column) + (d + dv) * 4 + 2 * column
+    dq = 2 * ((2 * d + dv) * itemsize + 2 * column) + d * 4
+    return tiles * block_q * max(fwd, dq)
+
+
+def _query_tiles_a_program(s_q, s_k, block_q, block_k, seq_len, causal=True,
+                           window=None, kv_off=0, bd=None):
+    """The consecutive query tiles of a head that one forward or dQ program
+    holds and walks as one (``_run_query_tiles``): the smallest power of two
+    that gives a program ``_VISITS_A_PROGRAM`` tile visits at the mask's mean
+    visits a query tile, at most ``_QUERY_TILES_MOST``, and a divisor of the
+    head's query tiles (no operand is padded for it).  The one place that
+    decides it, from the call's shapes and mask alone; a traced ``kv_off`` (a
+    ring step) counts as 0."""
+    if not isinstance(kv_off, int):
+        kv_off = 0
+    tiles = s_q // block_q
+    visits = _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal,
+                                window, kv_off, bd).mean()
+    held = 1
+    while (held < _QUERY_TILES_MOST and tiles % (2 * held) == 0
+           and held * visits < _VISITS_A_PROGRAM):
+        held *= 2
+    return held
 
 
 def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
-                window=None, kv_off=0, bd=None, heads_a_program=1):
+                window=None, kv_off=0, bd=None, heads_a_program=1,
+                query_tiles_a_program=1):
     """Tile visits and the loop iterations they take, by kernel: ``{"fwd":
     (visited, iterations), "bwd_dq": ..., "bwd_dkv": ...}`` for padded
     lengths ``s_q``, ``s_k`` in tiles of ``block_q`` x ``block_k``: one query
-    head's in the forward and dQ kernels, and in the dK/dV kernel those of the
-    ``heads_a_program`` query heads that one of its programs holds
-    (``_dkv_heads_a_program``) and walks as one (``_run_group_tiles``).  Host
-    arithmetic on the kernels' own bounds (``_tile_ranges`` on numpy) and the
-    loops' steps: where ``visited / iterations`` is near 1 (a window of a
-    tile, a sequence of two tiles) walking several tiles an iteration wins
-    nothing."""
-    kw = dict(causal=causal, window=window, kv_off=kv_off, bd=bd, xp=np)
-    by_queries = _tile_ranges(
-        np.arange(s_q // block_q) * block_q, block_q, block_k,
-        s_k // block_k, seq_len, rows_are_queries=True, **kw)
+    head's in the forward and dQ kernels, whose programs each walk
+    ``query_tiles_a_program`` consecutive query tiles as one
+    (``_query_tiles_a_program``, ``_run_query_tiles``), and in the dK/dV
+    kernel those of the ``heads_a_program`` query heads that one of its
+    programs holds (``_dkv_heads_a_program``) and walks as one
+    (``_run_group_tiles``).  Host arithmetic on the kernels' own bounds
+    (``_tile_ranges`` on numpy) and the loops' steps: where ``visited /
+    iterations`` is near 1 (a sequence of two tiles) walking several tiles an
+    iteration wins nothing."""
     by_keys = _tile_ranges(
         np.arange(s_k // block_k) * block_k, block_k, block_q,
-        s_q // block_q, seq_len, rows_are_queries=False, **kw)
+        s_q // block_q, seq_len, causal=causal, window=window, kv_off=kv_off,
+        bd=bd, rows_are_queries=False, xp=np)
 
-    def count(lengths, at_a_time=_TILES_AN_ITERATION):
-        visited = iterations = 0
-        for rest in lengths:
-            visited += int(rest.sum())
-            for n in at_a_time:
-                iterations += int((rest // n).sum())
-                rest = rest % n
+    def count(walks):
+        """``walks``: the visits of each program's one walk."""
+        visited, iterations = int(walks.sum()), 0
+        for n in _TILES_AN_ITERATION:
+            iterations += int((walks // n).sum())
+            walks = walks % n
         return visited, iterations
 
-    def lengths(ranges, programs):
-        return [np.broadcast_to(np.maximum(hi - lo, 0), (programs,))
-                for lo, hi in ranges]
-
-    fwd = count(lengths(by_queries, s_q // block_q))
-    # a dK/dV program's ranges and heads are one walk
-    dkv = count([heads_a_program * sum(lengths(by_keys, s_k // block_k))],
-                _TILES_A_WALK_ITERATION)
+    fwd = count(
+        _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal,
+                           window, kv_off, bd).reshape(
+                               -1, query_tiles_a_program).sum(axis=1))
+    dkv = count(
+        heads_a_program * sum(
+            np.broadcast_to(np.maximum(hi - lo, 0), (s_k // block_k,))
+            for lo, hi in by_keys))
     return {"fwd": fwd, "bwd_dq": fwd, "bwd_dkv": dkv}
 
 
-def _note_tiles(kernels, kv_offset, d_qk, d_v, heads_a_program=None,
-                **shape):
+def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
     """One ``flash.tiles`` instant a kernel as it is traced: its name, the
     ``visited`` tiles and loop ``iterations`` (``tile_counts``: a query
-    head's, and for a dK/dV kernel those of the ``heads_a_program`` query
-    heads a program holds, the whole group or 1, given beside them), the
+    head's at the ``query_tiles_a_program`` a forward or dQ program walks as
+    one, and for a dK/dV kernel those of the ``heads_a_program`` query heads a
+    program holds, the whole group or 1; either is given beside them), the
     ``window`` of its mask (None: none) and the widths of a tile's products
     (``d_qk`` of queries and keys, ``d_v`` of values).
     ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
@@ -408,20 +501,41 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, heads_a_program=None,
         kv_offset = 0
     if not _trace.enabled() or not isinstance(kv_offset, int):
         return
-    more = {} if heads_a_program is None else {
-        "heads_a_program": heads_a_program}
-    counts = tile_counts(kv_off=kv_offset, **more, **shape)
+    held = {k: shape[k] for k in ("heads_a_program", "query_tiles_a_program")
+            if k in shape}
+    counts = tile_counts(kv_off=kv_offset, **shape)
     for name, key in kernels.items():
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
                      iterations=iterations, d_qk=d_qk, d_v=d_v,
-                     window=shape.get("window"), **more)
+                     window=shape.get("window"), **held)
 
 
-def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
-                causal, block_q, block_k, seq_len, window=None,
-                off_div=None, bd=None):
-    qi = pl.program_id(1)
+_LANES = 128
+
+
+def _lanes(x, width):
+    """``x`` (rows, 128), every lane of a row alike (a running sum a row, kept
+    as wide as a vector register so that reading and writing it moves whole
+    registers and no lane), as (rows, width)."""
+    if width % _LANES == 0:
+        return jnp.concatenate([x] * (width // _LANES), axis=-1)
+    if width < _LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s,
+                l_s, m_s, *, sm_scale, causal, block_q, block_k, seq_len,
+                window=None, off_div=None, bd=None):
+    """The forward of the ``acc_s.shape[0]`` consecutive query tiles of one
+    head that a program holds (``_query_tiles_a_program``), walked as one
+    sequence of tile visits (``_run_query_tiles``).  Each query tile has its
+    own running ``(acc, l, m)``, in VMEM scratch at the tile's number (``l``
+    and ``m`` a row's number on all 128 lanes: ``_lanes``): a visit reads and
+    writes its tile's, and every output is written once, after the walk."""
+    tiles = acc_s.shape[0]
+    first = pl.program_id(1) * (tiles * block_q)
     # off_div=None: one kv_offset for the whole grid (self/ring blocks).
     # off_div=H: kvoff_ref holds one offset PER BATCH ROW and grid row bh
     # reads entry bh // H — the paged-decode path, where every sequence
@@ -430,17 +544,22 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
         kv_off = kvoff_ref[0]
     else:
         kv_off = kvoff_ref[pl.program_id(0) // off_div]
-    head_dim = v_ref.shape[-1]  # the accumulator is as wide as the values
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # (block_q, D)
-    q_off = qi * block_q
+    dv = acc_s.shape[-1]  # the accumulator is as wide as the values
+    for j in range(tiles):
+        q_s[j] = q_ref[0, j * block_q:(j + 1) * block_q, :].astype(
+            jnp.float32) * sm_scale  # (block_q, D)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    l_s[...] = jnp.zeros_like(l_s)
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
 
-    def body(kb, carry):
-        acc, l, m = carry
+    def body(j, kb, carry):
+        acc, l, m = acc_s[j], l_s[j], m_s[j]
+        q_off = first + j * block_q
         k_off = kb * block_k
         k = k_ref[0, pl.ds(k_off, block_k), :]  # (block_k, D)
         v = v_ref[0, pl.ds(k_off, block_k), :]
         s = jax.lax.dot_general(
-            q, k.astype(jnp.float32),
+            q_s[j], k.astype(jnp.float32),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
@@ -456,36 +575,47 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
             mask = _bd_tile_mask(q_off, k_off, block_q, block_k, seq_len,
                                  bd, True)
         s = jnp.where(mask, s, _NEG_INF)
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1))
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # explicit zeroing: a fully-masked row keeps new_m at the -inf
         # sentinel, where exp(s - new_m) would be exp(0) = 1
-        p = jnp.where(mask, jnp.exp(s - new_m[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - _lanes(new_m, block_k)), 0.0)
         corr = jnp.exp(m - new_m)
-        l = l * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
+        l_s[j] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[j] = acc * _lanes(corr, dv) + jax.lax.dot_general(
             p, v.astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return acc, l, new_m
+        m_s[j] = new_m
+        return carry
 
-    acc, l, m = _run_tiles(
-        _tile_ranges(q_off, block_q, block_k, k_ref.shape[1] // block_k,
-                     seq_len, causal=causal, window=window, kv_off=kv_off,
-                     bd=bd, rows_are_queries=True),
-        body,
-        (jnp.zeros((block_q, head_dim), jnp.float32),
-         jnp.zeros((block_q,), jnp.float32),
-         jnp.full((block_q,), _NEG_INF, jnp.float32)))
-    # rows past the true sequence (or wholly out of window) are
-    # all-masked (l == 0): emit zeros
-    safe_l = jnp.where(l > 0, l, 1.0)
-    o_ref[0] = (acc / safe_l[:, None]).astype(o_ref.dtype)
-    # per-row logsumexp of the SCALED logits, for the backward's exact
-    # softmax recomputation and the ring merge; all-masked rows get the
-    # -inf sentinel so a logaddexp merge leaves them inert (the backward
-    # is protected by _recompute_p's explicit mask, not the sentinel)
-    lse_ref[0, :, 0] = jnp.where(l > 0, m + jnp.log(safe_l), _NEG_INF)
+    _run_query_tiles(
+        _query_tiles_ranges(first, tiles, block_q, block_k,
+                            k_ref.shape[1] // block_k, seq_len, causal=causal,
+                            window=window, kv_off=kv_off, bd=bd), body, ())
+    for j in range(tiles):
+        rows = slice(j * block_q, (j + 1) * block_q)
+        l, m = l_s[j], m_s[j]
+        # rows past the true sequence (or wholly out of window) are
+        # all-masked (l == 0): emit zeros
+        safe_l = jnp.where(l > 0, l, 1.0)
+        o_ref[0, rows, :] = (acc_s[j] / _lanes(safe_l, dv)).astype(o_ref.dtype)
+        # per-row logsumexp of the SCALED logits, for the backward's exact
+        # softmax recomputation and the ring merge; all-masked rows get the
+        # -inf sentinel so a logaddexp merge leaves them inert (the backward
+        # is protected by _recompute_p's explicit mask, not the sentinel)
+        lse_ref[0, rows, :] = jnp.where(
+            l > 0, m + jnp.log(safe_l), _NEG_INF)[:, :1]
+
+
+def _fwd_scratch(tiles, block_q, d, dv):
+    """The forward kernel's VMEM scratch at ``tiles`` query tiles a program:
+    the scaled queries, and each tile's running sums (``l`` and ``m`` on all
+    lanes of a row: 128 KiB a 256-row tile each)."""
+    return [_pltpu.VMEM((tiles, block_q, d), jnp.float32),
+            _pltpu.VMEM((tiles, block_q, dv), jnp.float32),
+            _pltpu.VMEM((tiles, block_q, _LANES), jnp.float32),
+            _pltpu.VMEM((tiles, block_q, _LANES), jnp.float32)]
 
 
 def _pad_to(x, multiple, axis):
@@ -558,16 +688,19 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
         window=window,
         bd=bd,
     )
-    _note_tiles({"flash_attention_fwd": "fwd"}, kv_offset, s_q=s_q, s_k=s_k,
-                block_q=block_q, block_k=block_k, seq_len=orig_s,
-                causal=causal, window=window, bd=bd, d_qk=d, d_v=dv)
+    shape = dict(s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
+                 seq_len=orig_s, causal=causal, window=window, bd=bd)
+    tiles = _query_tiles_a_program(kv_off=kv_offset, **shape)
+    _note_tiles({"flash_attention_fwd": "fwd"}, kv_offset, d_qk=d, d_v=dv,
+                query_tiles_a_program=tiles, **shape)
+    rows = tiles * block_q  # a program's: its query tiles, walked as one
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
-        grid=(b * h, s_q // block_q),
+        grid=(b * h, s_q // rows),
         in_specs=[
             _SCALAR_SPEC,
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, rows, d), lambda bh, qi: (bh, qi, 0)),
             # GQA: the whole query-head group reads ONE kv head's K/V —
             # consecutive programs share the block, so it is fetched from
             # HBM once per kv head, not once per query head
@@ -577,17 +710,19 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
                          lambda bh, qi: (bh // group, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, rows, dv), lambda bh, qi: (bh, qi, 0)),
             # trailing singleton: TPU block tiling requires the last two
             # block dims divisible by (8, 128) or equal to the array's
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, rows, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_q, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
         ],
+        scratch_shapes=_fwd_scratch(tiles, block_q, d, dv),
         interpret=interpret,
-        **_kv_params(s_k, d, dv, k.dtype),
+        **_kv_params(s_k, d, dv, k.dtype, _query_side_bytes(
+            tiles, block_q, d, dv, q.dtype.itemsize)),
     )(_off_arr(kv_offset), qf, kf, vf)
     out = _unfold(out, b, h, s_q, dv)[:, :orig_s]
     if with_lse:
@@ -625,22 +760,25 @@ def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
 
 
 def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, *, sm_scale, causal, block_q,
+                   delta_ref, dq_ref, dq_s, *, sm_scale, causal, block_q,
                    block_k, seq_len, window=None, bd=None):
-    qi = pl.program_id(1)
+    """dQ of the ``dq_s.shape[0]`` consecutive query tiles of one head that a
+    program holds, walked as one sequence of tile visits as the forward's
+    (``_run_query_tiles``); each tile's sum in VMEM scratch at its number."""
+    tiles = dq_s.shape[0]
+    first = pl.program_id(1) * (tiles * block_q)
     kv_off = kvoff_ref[0]
-    q_off = qi * block_q
-    q = q_ref[0]
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
+    dq_s[...] = jnp.zeros_like(dq_s)
 
-    def body(kb, dq):
+    def body(j, kb, carry):
+        rows = pl.ds(j * block_q, block_q)
+        do = do_ref[0, rows, :].astype(jnp.float32)
         k_off = kb * block_k
         k_blk = k_ref[0, pl.ds(k_off, block_k), :]
         v_blk = v_ref[0, pl.ds(k_off, block_k), :]
         p = _recompute_p(
-            q, k_blk, lse, q_off, k_off, sm_scale=sm_scale, causal=causal,
+            q_ref[0, rows, :], k_blk, lse_ref[0, rows, 0],
+            first + j * block_q, k_off, sm_scale=sm_scale, causal=causal,
             seq_len=seq_len, block_q=block_q, block_k=block_k,
             window=window, kv_off=kv_off, bd=bd,
         )
@@ -649,19 +787,21 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
+        ds = p * (dp - delta_ref[0, rows, 0][:, None])
+        dq_s[j] = dq_s[j] + jax.lax.dot_general(
             ds, k_blk.astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    dq = _run_tiles(
-        _tile_ranges(q_off, block_q, block_k, k_ref.shape[1] // block_k,
-                     seq_len, causal=causal, window=window, kv_off=kv_off,
-                     bd=bd, rows_are_queries=True),
-        body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+    _run_query_tiles(
+        _query_tiles_ranges(first, tiles, block_q, block_k,
+                            k_ref.shape[1] // block_k, seq_len, causal=causal,
+                            window=window, kv_off=kv_off, bd=bd), body, ())
+    for j in range(tiles):
+        dq_ref[0, j * block_q:(j + 1) * block_q, :] = (
+            dq_s[j] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
@@ -828,27 +968,32 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     off = _off_arr(kv_offset)
     kw = dict(sm_scale=1.0 / (d ** 0.5), causal=causal, block_q=block_q,
               block_k=block_k, seq_len=orig_s, window=window)
-    shape = dict(s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
-                 seq_len=orig_s, causal=causal, window=window, bd=bd, d_qk=d,
-                 d_v=dv_w)
-    _note_tiles({"flash_attention_bwd_dq": "bwd_dq"}, kv_offset, **shape)
+    mask = dict(s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
+                seq_len=orig_s, causal=causal, window=window, bd=bd)
+    shape = dict(d_qk=d, d_v=dv_w, **mask)
+    tiles = _query_tiles_a_program(kv_off=kv_offset, **mask)
+    _note_tiles({"flash_attention_bwd_dq": "bwd_dq"}, kv_offset,
+                query_tiles_a_program=tiles, **shape)
+    q_rows = tiles * block_q  # a program's: its query tiles, walked as one
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bd=bd, **kw),
         name="flash_attention_bwd_dq",
-        grid=(bh, s_q // block_q),
+        grid=(bh, s_q // q_rows),
         in_specs=[
             _SCALAR_SPEC,
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, q_rows, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, s_k, d), lambda bh, qi: (bh // group, 0, 0)),
             pl.BlockSpec((1, s_k, dv_w), lambda bh, qi: (bh // group, 0, 0)),
-            pl.BlockSpec((1, block_q, dv_w), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, q_rows, dv_w), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, q_rows, 1), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, q_rows, 1), lambda bh, qi: (bh, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, q_rows, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qf.dtype),
+        scratch_shapes=[_pltpu.VMEM((tiles, block_q, d), jnp.float32)],
         interpret=interpret,
-        **_kv_params(s_k, d, dv_w, kf.dtype),
+        **_kv_params(s_k, d, dv_w, kf.dtype, _query_side_bytes(
+            tiles, block_q, d, dv_w, qf.dtype.itemsize)),
     )(off, qf, kf, vf, gf, lse_f, delta_f)
     heads, head_bytes = _dkv_heads_a_program(
         group, s_q, d, dv_w, qf.dtype.itemsize)
@@ -1099,6 +1244,8 @@ def flash_chunk_attention(q, k, v, q_starts, *, window=None, kv_start=None,
             jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s_q_pad, 1), jnp.float32),
         ],
+        # each row at its own offset: a walk of one query tile a program
+        scratch_shapes=_fwd_scratch(1, block_q, d, d),
         interpret=interpret,
     )(offs, qf, kf, vf)
     return _unfold(out, b, h, s_q_pad, d)[:, :c]

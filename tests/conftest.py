@@ -51,3 +51,25 @@ def _hvd_init():
         f"(backend={jax.default_backend()})"
     )
     yield
+
+
+# A compiled CPU program is loaded as one object a kernel, three memory
+# mappings each (code, constants, data), and jax's caches keep every program
+# of a worker's earlier modules: a worker that has run a few heavy files
+# holds 60,000 mappings, and at the kernel's limit (vm.max_map_count,
+# 65,530) the next load segfaults inside ``deserialize_executable``.  So a
+# module that ends with the process past half the limit drops jax's caches
+# (the programs come back from the persistent cache above when wanted).
+_MAPPINGS_TO_DROP_AT = 30_000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _bounded_memory_mappings():
+    yield
+    try:
+        with open("/proc/self/maps") as maps:
+            mappings = sum(1 for _ in maps)
+    except OSError:  # no procfs: nothing to bound
+        return
+    if mappings > _MAPPINGS_TO_DROP_AT:
+        jax.clear_caches()

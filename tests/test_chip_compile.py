@@ -149,6 +149,51 @@ def test_flash_latent_dkv_alone_compiles_with_its_vmem_stated(one_chip):
     assert "flash_attention_bwd_dkv" in text and "flash_attention_bwd_dq" not in text
 
 
+# the forward's and dQ's call at the cells' shapes: heads over key/value heads,
+# (d_qk, d_v), the mask, and the consecutive query tiles of a head that a program
+# walks as one (the rule's: 8,192 rows are 32 query tiles of 256)
+_QUERY_WALK_CASES = {
+    "laguna_sliding": (64, 8, (128, 128), dict(causal=True, window=512), 4),
+    "laguna_full": (48, 8, (128, 128), dict(causal=True), 1),
+    "sdar": (32, 4, (128, 128), dict(causal=False, bd=(4096, 4)), 1),
+    "kimi": (16, 16, (192, 128), dict(causal=True), 1),
+    "qwen3next": (16, 2, (256, 256), dict(causal=True), 1),
+}
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention_fwd", "flash_attention_bwd_dq"])
+@pytest.mark.parametrize("case", _QUERY_WALK_CASES)
+def test_flash_forward_and_dq_alone_compile_at_the_query_tiles_the_rule_picks(
+        one_chip, case, kernel):
+    """The forward and the dQ call, each ALONE (``tools/flash_bench.py --cells``'
+    jits; a whole backward compiling says nothing of one half's VMEM: PR 42), at
+    the five cells' attention shapes with the query tiles a program that
+    ``_query_tiles_a_program`` picks from the shapes: one call of that name in
+    the compiled text, its grid ``(heads, 32 / query tiles)``."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    h, h_kv, (d, dv), mask, tiles = _QUERY_WALK_CASES[case]
+    q = _sds((1, 8192, h, d), jnp.bfloat16, one_chip)
+    k = _sds((1, 8192, h_kv, d), jnp.bfloat16, one_chip)
+    v = _sds((1, 8192, h_kv, dv), jnp.bfloat16, one_chip)
+    out = _sds((1, 8192, h, dv), jnp.bfloat16, one_chip)
+    lse = _sds((h, 8192, 1), jnp.float32, one_chip)
+    kw = dict(window=mask.get("window"), bd=mask.get("bd"))
+    if kernel == "flash_attention_fwd":
+        fn, args = (lambda q, k, v: fa._forward_impl(
+            q, k, v, mask["causal"], 256, 256, False, with_lse=True, **kw)), (q, k, v)
+    else:
+        fn, args = (lambda q, k, v, out, lse, g: fa._backward_impl(
+            q, k, v, out, lse, g, mask["causal"], 256, 256, False, **kw)[0]), (
+                q, k, v, out, lse, out)
+    assert fa._query_tiles_a_program(8192, 8192, 256, 256, 8192, **mask) == tiles
+    text = _compile(fn, *args).as_text()
+    assert re.findall(r"%(flash_attention\w*)\.\d+ = [^\n]*tpu_custom_call", text) == [kernel]
+    grids = [e.params["grid_mapping"].grid for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert grids[0] == (h, 32 // tiles), grids
+
+
 def _dkv_bd_operands(text):
     """The operand shapes the compiled ``flash_attention_bwd_dkv_bd`` call
     constrains: its q is the second (the key offset comes first)."""
@@ -912,15 +957,17 @@ def test_rotary_step_under_q_k_norms_and_block_diffusion_is_one_pass_a_direction
 
 # sha256 of the step below lowered at the parent of PR 40 (commit 6fbca40), this
 # test's own lines run in that tree; since PR 42 with its dK/dV kernel walking
-# a program's heads as one, another program by design (before: 8e421982...245f)
-_LATENT_STEP_AT_THE_PARENT = "55dd310a470a5d17d2fdff89c8fcff9a80a7d9c346b44acb4f611ac423a80715"
+# a program's heads as one, another program by design (before: 8e421982...245f);
+# since PR 43 with its forward and dQ kernels walking a program's query tiles as
+# one, their sums in VMEM scratch (before: 55dd310a...0715)
+_LATENT_STEP_AT_THE_PARENT = "cdba4e1ac5008fa1407a4168b87d537b98e69cbfda892c9ae40796bea49f3c30"
 
 
 def test_latent_attention_s_step_is_the_parent_s_to_the_byte():
     """Kimi's kind of layer at its head widths (128 + 64 rotary columns a query
     head, ONE 64-wide rotary key for all heads, 'flash'): a 64-wide rotary slice
     of a 192-wide head is no shape the rotary kernels take, so the lowered step
-    holds none and is the one the parent lowered (PR 42's dK/dV walk apart)."""
+    holds none and is the one the parent lowered (PR 42's and PR 43's walks apart)."""
     import hashlib
 
     cfg = TransformerConfig(

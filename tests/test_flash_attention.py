@@ -21,7 +21,7 @@ def _qkv(b, s, h, d, dtype, seed=0):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("s", [256, 384, 1024])
 def test_flash_matches_dense_causal(dtype, s):
-    # 1024: loops of one to eight 128-tiles, in iterations of four, two, one
+    # 1024: walks of one to eight 128-tiles, in iterations of eight, four, two, one
     q, k, v = _qkv(2, s, 2, 64, dtype)
     ref = causal_dot_attention(q, k, v)
     out = flash_attention(q, k, v, block_q=128, block_k=128)
@@ -69,11 +69,10 @@ def test_flash_gradients_match_dense(dtype, tol, s, block):
     """Training through the kernel: custom_vjp gradients must match the
     dense path's (backward recomputes with the kernel's upcast numerics;
     bf16 compares loosely against the model's dense reference).  Eight
-    tiles a side: dQ's and dK/dV's loops run one to eight tiles, dQ's in
-    iterations of four, two and one and dK/dV's of eight first
-    (``tile_counts``: 36 visits in 14, and in 13)."""
+    tiles a side: dQ's and dK/dV's loops run one to eight tiles, in iterations
+    of eight first (``tile_counts``: 36 visits in 13)."""
     counts = tile_counts(1024, 1024, 128, 128, 1024)
-    assert (counts["bwd_dq"], counts["bwd_dkv"]) == ((36, 14), (36, 13))
+    assert (counts["bwd_dq"], counts["bwd_dkv"]) == ((36, 13), (36, 13))
     q, k, v = _qkv(1, s, 2, 32, dtype, seed=3)
 
     def loss_flash(q, k, v):
@@ -189,10 +188,11 @@ def test_flash_non_causal_gradients():
 
 def test_flash_tiles_event_names_each_kernel_traced():
     """Tracing a kernel leaves one ``flash.tiles`` instant with its name and
-    the tile visits and loop iterations (``tile_counts``): a head's, and for a
-    dK/dV kernel those of the query heads a program holds and walks as one,
-    ``heads_a_program`` beside them; the block-diffusion kind names its own
-    dK/dV kernel."""
+    the tile visits and loop iterations (``tile_counts``): a head's, at the
+    ``query_tiles_a_program`` consecutive query tiles a forward or dQ program
+    walks as one, and for a dK/dV kernel those of the query heads a program
+    holds and walks as one, ``heads_a_program`` beside them; the
+    block-diffusion kind names its own dK/dV kernel."""
     from horovod_tpu import trace
 
     def traced(kv_heads=2, **kw):
@@ -201,22 +201,27 @@ def test_flash_tiles_event_names_each_kernel_traced():
         jax.make_jaxpr(jax.grad(lambda a: flash_attention(
             a, a[:, :, :kv_heads], a[:, :, :kv_heads], block_q=128, block_k=128,
             **kw).sum()))(q)
-        return {r[3]["kernel"]: (r[3]["visited"], r[3]["iterations"])
-                + ((r[3]["heads_a_program"],) if "heads_a_program" in r[3] else ())
+        held = lambda r: {k: r[k] for k in ("heads_a_program", "query_tiles_a_program")
+                          if k in r}
+        return {r[3]["kernel"]: (r[3]["visited"], r[3]["iterations"], held(r[3]))
                 for r in trace.snapshot(t0) if r[0] == "flash.tiles"}
 
-    # the dK/dV kernels alone say how many query heads a program holds
+    # each kernel says what a program of its holds: the head's four query tiles
+    # (10 visits under the window: one walk of 8 + 2), or its query heads
+    a_head = (10, 2, {"query_tiles_a_program": 4})
     assert traced(window=300) == {
-        "flash_attention_fwd": (10, 5), "flash_attention_bwd_dq": (10, 5),
-        "flash_attention_bwd_dkv": (10, 5, 1)}
+        "flash_attention_fwd": a_head, "flash_attention_bwd_dq": a_head,
+        "flash_attention_bwd_dkv": (10, 5, {"heads_a_program": 1})}
     # two heads a program: twice the visits in as many iterations
-    assert traced(window=300, kv_heads=1)["flash_attention_bwd_dkv"] == (20, 5, 2)
+    assert traced(window=300, kv_heads=1)["flash_attention_bwd_dkv"] == (
+        20, 5, {"heads_a_program": 2})
+    a_head = (8, 1, {"query_tiles_a_program": 4})
     assert traced(block_diffusion=(256, 4)) == {
-        "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
-        "flash_attention_bwd_dkv_bd": (8, 4, 1)}
+        "flash_attention_fwd": a_head, "flash_attention_bwd_dq": a_head,
+        "flash_attention_bwd_dkv_bd": (8, 4, {"heads_a_program": 1})}
     assert traced(block_diffusion=(256, 4), kv_heads=1) == {
-        "flash_attention_fwd": (8, 6), "flash_attention_bwd_dq": (8, 6),
-        "flash_attention_bwd_dkv_bd": (16, 4, 2)}
+        "flash_attention_fwd": a_head, "flash_attention_bwd_dq": a_head,
+        "flash_attention_bwd_dkv_bd": (16, 4, {"heads_a_program": 2})}
 
 
 # -- 256-wide heads, eight query heads a key/value head (PR 35) ----------------

@@ -319,61 +319,154 @@ def test_block_diffusion_tile_ranges_visit_what_the_mask_allows(
             s_pad // block_q)
 
 
-@pytest.mark.parametrize("cell,kw,want,heads,dkv,dkv_a_head", [
+_CAUSAL_8192 = dict(s_q=8192, s_k=8192, seq_len=8192, causal=True)
+
+
+@pytest.mark.parametrize("cell,kw,a_tile,tiles,want,heads,dkv,dkv_a_head", [
     ("internlm2-1.8b-s4096-1chip",
-     dict(s_q=4096, s_k=4096, seq_len=4096, causal=True), (136, 44),
+     dict(s_q=4096, s_k=4096, seq_len=4096, causal=True), (136, 34), 1, (136, 34),
      2, (272, 44), (136, 34)),
     ("sdar-30b-a3b-bd4-s4096-1chip",
      dict(s_q=8192, s_k=8192, seq_len=8192, causal=False, bd=(4096, 4)),
-     (288, 104), 8, (2304, 288), (288, 60)),
-    ("laguna-xs.2-s8192-1chip/full",
-     dict(s_q=8192, s_k=8192, seq_len=8192, causal=True), (528, 152),
+     (288, 70), 1, (288, 70), 8, (2304, 288), (288, 60)),
+    ("laguna-xs.2-s8192-1chip/full", _CAUSAL_8192, (528, 100), 1, (528, 100),
      6, (3168, 416), (528, 100)),
     ("laguna-xs.2-s8192-1chip/sliding",
      dict(s_q=8192, s_k=8192, seq_len=8192, causal=True, window=512), (93, 62),
-     8, (744, 93), (93, 62)),
+     4, (93, 16), 8, (744, 93), (93, 62)),
+    ("kimi-vl-a3b-s8192-1chip", _CAUSAL_8192, (528, 100), 1, (528, 100),
+     1, (528, 100), (528, 100)),
+    ("qwen3-next-80b-a3b-s8192-1chip", _CAUSAL_8192, (528, 100), 1, (528, 100),
+     1, (528, 100), (528, 100)),
 ])
-def test_tile_counts_at_the_cells_shapes(cell, kw, want, heads, dkv, dkv_a_head):
-    """A head's tile visits and the loop iterations they take in 256-tiles:
-    3.1 and 2.8 tiles an iteration for the scheduler to overlap (3.5 at
-    8,192 under the causal mask); a window of 512 visits three tiles a
-    query tile, the diagonal one and two before it, in two iterations.  The
-    dK/dV kernel walks the query heads a program holds as one (PR 42), eight
-    tiles an iteration first: at a group of 8 every iteration holds eight,
-    the window's three tiles a head and the 512 lone visits of a
-    block-diffusion layer's noisy key tiles among them; one head a program
-    (``heads_a_program=1``: the same shape beyond ``_DKV_GROUP_BYTES``) has
-    its own walks' remainders."""
-    counts = tile_counts(block_q=256, block_k=256, heads_a_program=heads, **kw)
+def test_tile_counts_at_the_cells_shapes(cell, kw, a_tile, tiles, want, heads, dkv,
+                                         dkv_a_head):
+    """A head's tile visits and the loop iterations they take in 256-tiles, eight
+    tiles an iteration first in every kernel.  A forward or dQ program walks
+    ``tiles`` consecutive query tiles of a head as one (PR 43;
+    ``_query_tiles_a_program``: the fewest that give it 8 visits, at most 8): a
+    window of 512 visits three tiles a query tile, the diagonal one and two
+    before it, and four query tiles are 12 visits in two iterations where one a
+    program took two for its three (eight would be 24 in three: 13 a head); a
+    query tile under the causal or the block-diffusion mask has eight visits and
+    more, and its program walks it alone, the block-diffusion mask's two ranges
+    as one.  The dK/dV kernel walks the query heads a program holds as one
+    (PR 42): at a group of 8 every iteration holds eight, the window's three
+    tiles a head and the 512 lone visits of a block-diffusion layer's noisy key
+    tiles among them; one head a program (``heads_a_program=1``: the same shape
+    beyond ``_DKV_GROUP_BYTES``) has its own walks' remainders."""
+    from horovod_tpu.ops.flash_attention import _query_tiles_a_program
+
+    assert _query_tiles_a_program(block_q=256, block_k=256, **kw) == tiles
+    counts = tile_counts(block_q=256, block_k=256, heads_a_program=heads,
+                         query_tiles_a_program=tiles, **kw)
     assert counts == {"fwd": want, "bwd_dq": want, "bwd_dkv": dkv}
-    assert tile_counts(block_q=256, block_k=256, **kw)["bwd_dkv"] == dkv_a_head
+    one = tile_counts(block_q=256, block_k=256, **kw)
+    assert (one["fwd"], one["bwd_dkv"]) == (a_tile, dkv_a_head)
+    if "window" in kw:
+        assert tile_counts(block_q=256, block_k=256, query_tiles_a_program=8,
+                           **kw)["fwd"] == (93, 13)
+
+
+def _walked(walk, bounds):
+    """The ``100 * owner + tile`` a walk visits, in its order, its bounds traced."""
+    def run(bounds):
+        note = lambda g, t, carry: (carry[0].at[carry[1]].set(100 * g + t), carry[1] + 1)
+        return walk(bounds, note, (jnp.full((64,), -1, jnp.int32), jnp.int32(0)))
+    seen, n = jax.jit(run)(jnp.asarray(bounds, jnp.int32))
+    return list(np.asarray(seen)[:int(n)])
 
 
 @pytest.mark.parametrize("group", [1, 2, 8])
 @pytest.mark.parametrize("ranges", [((2, 5),), ((3, 4), (6, 11)), ((0, 0), (1, 3)),
                                      ((4, 9), (9, 9)), ((0, 0), (0, 0))])
 def test_the_group_s_walk_visits_what_a_walk_a_head_visits_in_its_order(ranges, group):
-    """``_run_group_tiles`` against ``_run_tiles`` head after head: the same
-    (head, tile) visits in the same order, bounds traced, whatever the ranges'
-    lengths (an empty one, both empty) and the group."""
-    from horovod_tpu.ops.flash_attention import _run_group_tiles, _run_tiles
-
-    def visits(walk):
-        def run(bounds):
-            note = lambda g, t, carry: (carry[0].at[carry[1]].set(100 * g + t), carry[1] + 1)
-            return walk(tuple((lo, hi) for lo, hi in bounds), note,
-                        (jnp.full((64,), -1, jnp.int32), jnp.int32(0)))
-        seen, n = jax.jit(run)(jnp.asarray(ranges, jnp.int32))
-        return list(np.asarray(seen)[:int(n)])
-
-    def a_head(ranges, note, carry):
-        for g in range(group):
-            carry = _run_tiles(ranges, lambda t, carry, g=g: note(g, t, carry), carry)
-        return carry
+    """``_run_group_tiles`` against a loop nest: the same (head, tile) visits in
+    the same order, head after head and range after range, bounds traced,
+    whatever the ranges' lengths (an empty one, both empty) and the group; and
+    ``_run_query_tiles`` over as many query tiles of those same ranges visits
+    them alike (a walk of one query tile at a group of 1)."""
+    from horovod_tpu.ops.flash_attention import _run_group_tiles, _run_query_tiles
 
     want = [100 * g + t for g in range(group) for lo, hi in ranges for t in range(lo, hi)]
-    assert visits(a_head) == want
-    assert visits(lambda r, note, carry: _run_group_tiles(r, group, note, carry)) == want
+    assert _walked(lambda r, note, carry: _run_group_tiles(
+        tuple((lo, hi) for lo, hi in r), group, note, carry), ranges) == want
+    assert _walked(lambda r, note, carry: _run_query_tiles(
+        [tuple((lo, hi) for lo, hi in r)] * group, note, carry), ranges) == want
+
+
+@pytest.mark.parametrize("kind,kw,kv_off", [
+    ("causal", dict(causal=True, window=None, bd=None), 0),
+    ("window", dict(causal=True, window=200, bd=None), 0),
+    ("two-sided-window", dict(causal=False, window=130, bd=None), 0),
+    ("block-diffusion", dict(causal=False, window=None, bd=(320, 4)), 0),
+    ("ring-step-behind", dict(causal=True, window=300, bd=None), -256),
+    ("ring-step-ahead", dict(causal=True, window=None, bd=None), 384),
+])
+@pytest.mark.parametrize("tiles", [1, 2, 5])
+def test_the_query_tiles_walk_visits_each_tile_s_ranges_in_order(kind, kw, kv_off, tiles):
+    """``_run_query_tiles`` over ``tiles`` consecutive query tiles a program
+    visits, for every mask kind, exactly the tiles of each query tile's own
+    ranges (``_tile_ranges`` at its offset), query tile after query tile and
+    range after range in rising order: the order of a loop nest a query tile,
+    so the sums come out the same.  The offset is traced (a ring step: the
+    bounds are computed in the program; behind the queries some tiles' ranges
+    are cut, ahead of them some are empty and the walk passes over them)."""
+    from horovod_tpu.ops.flash_attention import _run_query_tiles
+
+    block, s = 128, 640
+    ranges_at = lambda off, kv_off, xp: _tile_ranges(
+        off, block, block, s // block, s, kv_off=kv_off, rows_are_queries=True, xp=xp, **kw)
+    for first in range(0, s // block, tiles):
+        held = range(first, min(first + tiles, s // block))
+        want = [100 * j + t for j in held
+                for lo, hi in ranges_at(j * block, kv_off, np) for t in range(lo, hi)]
+        got = _walked(lambda off, note, carry: _run_query_tiles(
+            [ranges_at(j * block, off[0], jnp) for j in held],
+            lambda j, t, carry: note(held[0] + j, t, carry), carry), [kv_off])
+        assert got == want, (kind, first)
+    assert kind != "ring-step-ahead" or not ranges_at(0, kv_off, np)[0][1]  # an empty one
+
+
+@pytest.mark.parametrize("kind,s,heads,d,kw,tiles", [
+    ("causal", 700, (2, 1), (32, 32), dict(causal=True), 2),
+    ("window", 1000, (2, 1), (32, 32), dict(causal=True, window=200), 4),
+    ("block-diffusion", 700, (2, 2), (32, 32), dict(causal=False, bd=(350, 4)), 2),
+    ("latent-192-128", 700, (2, 2), (192, 128), dict(causal=True), 2),
+])
+def test_query_tiles_walked_as_one_give_the_sums_of_one_a_program(
+        monkeypatch, kind, s, heads, d, kw, tiles):
+    """Forward output, ``lse`` and dq of the programs the rule makes (several
+    consecutive query tiles a program, walked as one: ``tiles``) against one
+    query tile a program, BIT FOR BIT, in interpret mode: the walk visits each
+    query tile's tiles in the order of its own loops, and each query tile has
+    its own running sums.  128-row tiles at lengths that are no multiple of a
+    program's rows (the last tile is padded), keys and values 192 / 128 wide
+    among them."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    (h, h_kv), (d_qk, d_v) = heads, d
+    ks = jax.random.split(jax.random.PRNGKey(43), 4)
+    mk = lambda key, n, width: jax.random.normal(
+        key, (1, s, n, width), jnp.float32).astype(jnp.bfloat16)
+    q, k, v, g = mk(ks[0], h, d_qk), mk(ks[1], h_kv, d_qk), mk(ks[2], h_kv, d_v), mk(ks[3], h, d_v)
+
+    def run():
+        def both(q, k, v, g):
+            out, lse = fa._forward_impl(q, k, v, kw["causal"], 128, 128, True, with_lse=True,
+                                        window=kw.get("window"), bd=kw.get("bd"))
+            return out, lse, fa._backward_impl(
+                q, k, v, out, lse, g, kw["causal"], 128, 128, True,
+                window=kw.get("window"), bd=kw.get("bd"))[0]
+        return jax.jit(both)(q, k, v, g)
+
+    s_pad = s + (-s) % 128
+    assert fa._query_tiles_a_program(s_pad, s_pad, 128, 128, s, **kw) == tiles
+    walked = run()
+    monkeypatch.setattr(fa, "_QUERY_TILES_MOST", 1)
+    for name, a, b in zip(("out", "lse", "dq"), walked, run()):
+        assert jnp.array_equal(a, b), (kind, name)
+    assert np.isfinite(np.asarray(walked[0], np.float32)).all()
 
 
 @pytest.mark.parametrize("causal,window,seq_len", [
@@ -382,8 +475,8 @@ def test_the_group_s_walk_visits_what_a_walk_a_head_visits_in_its_order(ranges, 
 def test_tile_counts_follow_the_kernels_loop_bounds(causal, window, seq_len):
     """``visited`` is the sum of the kernels' own ``_kb_range`` ranges (the
     bench's ``_kv_tiles``), the same pairs from both sides; ``iterations``
-    is what ``_run_tiles`` takes for each range: four tiles at a time,
-    then two, then one."""
+    is what a walk of one query tile a program takes (``_walk``): eight tiles
+    at a time, then four, two, one."""
     fb = _load_flash_bench()
     s_pad, blk = 1024, 128
     counts = tile_counts(s_pad, s_pad, blk, blk, seq_len, causal=causal,
@@ -405,7 +498,7 @@ def test_tile_counts_follow_the_kernels_loop_bounds(causal, window, seq_len):
     if window == 100:   # a tile or two a program: little to overlap
         assert counts["fwd"][0] < 2 * counts["fwd"][1]
     elif window is None and not causal:
-        assert counts["fwd"] == (64, 16)
+        assert counts["fwd"] == (64, 8)
 
 
 @pytest.mark.parametrize("dtype,tol,gtol", [(jnp.float32, 2e-5, 1e-3),
@@ -415,8 +508,9 @@ def test_tile_counts_follow_the_kernels_loop_bounds(causal, window, seq_len):
 def test_flash_gqa_several_tiles_an_iteration_match_oracle(causal, window,
                                                            dtype, tol, gtol):
     """Forward and gradients where the programs' loops take iterations of
-    four, two and one tile (S = 700 in 128-tiles, padded to 768: ranges of
-    one to six tiles; two query heads a kv head, the dK/dV tile computed
+    eight, four, two and one tile (S = 700 in 128-tiles, padded to 768: ranges
+    of one to six tiles, two query tiles a forward or dQ program under the
+    masks that cut them; two query heads a kv head, the dK/dV tile computed
     transposed), float32 and bfloat16 inputs, at the existing tolerances."""
     s = 700
     counts = tile_counts(768, 768, 128, 128, s, causal=causal, window=window)

@@ -513,8 +513,11 @@ _DEFAULTS = dict(kv_lora_rank=None, qk_nope_head_dim=None, qk_rope_head_dim=None
 # and PR 42's: dK/dV under the block-diffusion mask by the whole-group kernel.
 # Both are PR 42's since the dK/dV kernels walk a program's heads as one under
 # every mask, another program by design (before: the LM's d1d9a043...5463, PR
-# 32's parent's; SDAR's f916682c...a577)
-_PARENT_TEXT = {"lm": "036f93384494f559c68b17f273bbd99f3d5840ea14fa17c3c4a1f1ca6c74f5dc", "sdar": "ecd6688a49f3bad081ed76e7ed7e3f564db374d179b5891e5e079aa2a2300e1b"}
+# 32's parent's; SDAR's f916682c...a577), and PR 43's since the forward and dQ
+# kernels walk a program's query tiles as one with their sums in VMEM scratch,
+# eight tiles an iteration first (before: the LM's 036f9338...f5dc, SDAR's
+# ecd6688a...0e1b)
+_PARENT_TEXT = {"lm": "cc9b24840038ca14b23ce0dd5bed4aed58c54a3e1700c939a71bddbd833b7d0b", "sdar": "8451fea377b69d0437bf23c9f4f9007e6dd1417395eb5753a0ce87653f2a9bc5"}
 
 
 @pytest.mark.parametrize("name", ["lm", "sdar"])

@@ -55,8 +55,7 @@ from horovod_tpu.models.transformer import causal_dot_attention  # noqa: E402
 from horovod_tpu.ops import grouped_matmul as gm  # noqa: E402
 from horovod_tpu.ops.flash_attention import (  # noqa: E402
     _backward_impl, _clamp_blocks, _dkv_heads_a_program, _forward_impl,
-    flash_attention,
-    tile_counts,
+    _query_tiles_a_program, flash_attention, tile_counts,
 )
 
 
@@ -266,8 +265,12 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
     the dK/dV program are the two halves of ``_backward_impl`` (a jit
     that returns one of them drops the other kernel).  Beside each time, the
     tile visits and the loop iterations they take (``tile_counts``: a head's,
-    and for dK/dV those of the heads a program holds) and the time a tile visit, which PERF.md §5 holds against the 0.085 us
-    a 256 x 256 x 128 product needs on the v5e's MXU."""
+    and for dK/dV those of the heads a program holds), what a program holds
+    and walks as one (``query_tiles_a_program`` consecutive query tiles of a
+    head in the forward and dQ kernels, ``heads_a_program`` query heads in
+    dK/dV: the kernels' own rules) with a program's mean visits, and the time a
+    tile visit, which PERF.md §5 holds against the 0.085 us a 256 x 256 x 128
+    product needs on the v5e's MXU."""
     for cell, (b, s, h, h_kv, d, causal, bd, *window) in shapes.items():
         window = window[0] if window else None
         q, k, v = _qkv(b, s, h, h_kv, d)
@@ -296,26 +299,37 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
               "bwd_dq": timed(lambda *a: bwd(*a)[0], q, k, v, out, lse, g),
               "bwd_dkv": timed(lambda *a: bwd(*a)[1:], q, k, v, out, lse, g)}
         bq, bk = _clamp_blocks(s, block, block)
-        # a dK/dV program walks the query heads it holds as one: its counts
-        # are theirs, and the kernel's own rule says how many they are
+        mask = dict(s_q=_pad(s, bq), s_k=_pad(s, bk), block_q=bq, block_k=bk,
+                    seq_len=s, causal=causal, window=window, bd=bd)
+        # what a program walks as one, by the kernels' own rules: its counts
+        # are those of the query tiles, or of the query heads, that it holds
+        query_tiles = _query_tiles_a_program(**mask)
         heads = _dkv_heads_a_program(
-            h // h_kv, _pad(s, bq), q.shape[-1], v.shape[-1],
+            h // h_kv, mask["s_q"], q.shape[-1], v.shape[-1],
             q.dtype.itemsize)[0]
-        tiles = tile_counts(_pad(s, bq), _pad(s, bk), bq, bk, s,
-                            causal=causal, window=window, bd=bd,
-                            heads_a_program=heads)
+        tiles = tile_counts(heads_a_program=heads,
+                            query_tiles_a_program=query_tiles, **mask)
         walks = {"fwd": b * h, "bwd_dq": b * h, "bwd_dkv": b * h // heads}
+        q_programs = mask["s_q"] // (query_tiles * bq)
+        programs = {"fwd": q_programs, "bwd_dq": q_programs,
+                    "bwd_dkv": mask["s_k"] // bk}
         rec = {"bench": "flash_cells", "cell": cell, "b": b, "s": s, "h": h,
-               "h_kv": h_kv, "d": d, "window": window, "block": [bq, bk]}
+               "h_kv": h_kv, "d": d, "window": window, "block": [bq, bk],
+               "query_tiles_a_program": query_tiles, "heads_a_program": heads}
         for name, t in ms.items():
             visited, iterations = tiles[name]
             rec[name + "_ms"] = round(t, 4)
             rec[name + "_tiles"] = [visited, iterations]
+            rec[name + "_a_program"] = [
+                round(visited / programs[name], 2),
+                round(iterations / programs[name], 2)]
             rec[name + "_us_per_tile"] = round(
                 t * 1e3 / (walks[name] * visited), 4)
         _emit(rec, f"{cell}: " + "  ".join(
             f"{n} {t:7.3f} ms" for n, t in ms.items())
-            + f"  tiles a head {tiles['fwd'][0]} in {tiles['fwd'][1]} iterations")
+            + f"  tiles a head {tiles['fwd'][0]} in {tiles['fwd'][1]} iterations,"
+            f" {query_tiles} query tiles a program: "
+            f"{rec['fwd_a_program'][0]} visits in {rec['fwd_a_program'][1]}")
 
 
 # (rows of a chunk, k, n, groups): the routed cells' products, a chunk twice
