@@ -216,7 +216,7 @@ class ContinuousBatchingScheduler:
         device-staged-but-undrained.  THE number behind the
         ``hvd_tpu_serve_queue_depth`` gauge and the fleet router's
         least-queue-depth fallback — both must see the same sum, so
-        both read it here (pinned by tests/test_serving.py)."""
+        both read it here (pinned by tests/test_serving_sharded.py)."""
         return len(self.pending) + self.staged_depth()
 
     def submit(self, seq: Sequence) -> None:

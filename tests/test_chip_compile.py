@@ -10,6 +10,12 @@ module-scoped fixture (never at import, never in conftest.py), everything
 compiles in this test's own process, and all cases live in this one file.
 The persistent compile cache is off around them: an entry written for a
 described chip cannot be read back without one.
+
+One compile a program, one contract a case: a whole step or layer is compiled
+once, in a module-scoped fixture (``two_mixer_step``, ``by_layer_step``, ...),
+and every fact held of it is a test of its own on that fixture, named for the
+fact.  A new fact about a program that is compiled here already is a new case
+on its fixture, not a new compile.
 """
 
 import os
@@ -31,7 +37,6 @@ from horovod_tpu.models.transformer import (
 from horovod_tpu.ops.flash_attention import (
     flash_attention, flash_chunk_attention, flash_decode_attention,
 )
-from horovod_tpu.ops.fused_norm import fused_batch_norm_act
 
 HBM_BYTES = 16e9  # one v5e chip
 
@@ -402,25 +407,22 @@ def test_grouped_products_are_a_kernel_on_the_chip(one_chip, case, backward):
     assert "ragged-dot" not in text
 
 
-@pytest.mark.parametrize("ff,experts,top_k,held", [(768, 128, 8, 16), (512, 512, 10, 32)],
-                         ids=["sdar", "qwen3next"])
-def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch, ff, experts,
-                                                        top_k, held):
+_ROUTED_ROWS, _ROUTED_WIDTH = 8192, 2048
+# the routed layers' (ff, experts, top_k, held)
+_ROUTED_CASES = {"sdar": (768, 128, 8, 16), "qwen3next": (512, 512, 10, 32)}
+
+
+@pytest.fixture(scope="module", params=list(_ROUTED_CASES))
+def routed_layer(request, one_chip):
     """``RoutedExperts`` forward and backward at the SDAR cell's shapes (8,192
     rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) and at
-    Qwen3-Next's (top-10 of 512, 32 held, a chunk of 10,240) as the
-    v5e compiler leaves it: no scatter at all (before PR 31: two scatter-adds
-    of 16,384 rows, each a sort of its indices, a gather of its updates and a
-    sorted scatter, and three scatters of single numbers, into
-    ``s32[num_experts]`` among them), no float32 gather of rows, and no sort
-    but the four the layer asks for (top-k, the assignments by held expert,
-    its inverse, the weights' cotangent back)."""
-
+    Qwen3-Next's (top-10 of 512, 32 held, a chunk of 10,240) as the v5e compiler
+    leaves it, compiled once a shape for the tests below: ``(compiled text,
+    chunk, top_k)``."""
     from horovod_tpu.parallel.moe import RoutedExperts
 
-    # the layer asks the backend whether its kernels are interpreted
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rows, d = 8192, 2048
+    ff, experts, top_k, held = _ROUTED_CASES[request.param]
+    rows, d = _ROUTED_ROWS, _ROUTED_WIDTH
     chunk = 2 * rows * top_k * held // experts
     layer = RoutedExperts(experts, top_k, d, ff, held=(0, held), dtype=jnp.bfloat16)
     x = _sds((1, rows, d), jnp.bfloat16, one_chip)
@@ -433,20 +435,54 @@ def test_routed_layer_moves_rows_by_gathers_on_the_chip(one_chip, monkeypatch, f
         y, stats = layer.apply({"params": p}, x)
         return jnp.sum(y.astype(jnp.float32) ** 2) + stats["aux_loss"]
 
-    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        # the layer asks the backend whether its kernels are interpreted
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    return text, chunk, top_k
+
+
+def _moved_shapes(text, kind):
+    """The result shapes (layouts dropped) of the compiled text's ``gather``,
+    ``scatter`` or ``sort`` instructions."""
     ops = re.findall(r"= (\(.*?\)|\S+) (gather|scatter|sort)\(", text)
-    shapes = lambda kind: [re.sub(r"\{[^}]*\}", "", s) for s, k in ops if k == kind]
-    assert shapes("scatter") == []
-    gathers = shapes("gather")
+    return [re.sub(r"\{[^}]*\}", "", s) for s, k in ops if k == kind]
+
+
+def test_routed_layer_holds_no_scatter_on_the_chip(routed_layer):
+    """No scatter at all (before PR 31: two scatter-adds of 16,384 rows, each a
+    sort of its indices, a gather of its updates and a sorted scatter, and
+    three scatters of single numbers, into ``s32[num_experts]`` among them)."""
+    text, _, _ = routed_layer
+    assert _moved_shapes(text, "scatter") == []
+
+
+def test_routed_layer_moves_rows_by_gathers_on_the_chip(routed_layer):
+    """The rows move by bf16 gathers and no float32 gather of rows."""
+    text, chunk, top_k = routed_layer
+    rows, d = _ROUTED_ROWS, _ROUTED_WIDTH
+    gathers = _moved_shapes(text, "gather")
     assert not [s for s in gathers if s.startswith("f32") and f",{d}]" in s], gathers
     # the first chunk, and the loop over the later ones (recomputed backward)
     assert gathers.count(f"bf16[{chunk},{d}]") == 2 + 3
     assert gathers.count(f"bf16[{rows},{d}]") == 4 * top_k
-    sorts = shapes("sort")
+
+
+def test_routed_layer_sorts_only_what_it_asks_for_on_the_chip(routed_layer):
+    """No sort but the four the layer asks for (top-k, the assignments by held
+    expert, its inverse, the weights' cotangent back)."""
+    text, chunk, top_k = routed_layer
+    rows = _ROUTED_ROWS
+    sorts = _moved_shapes(text, "sort")
     assert not [s for s in sorts if f"[{chunk}]" in s], sorts
     assert len(sorts) == 4 and sum(f"[{rows * top_k}]" in s for s in sorts) == 3
-    # the grouped products: three forward, six backward, and the three the
-    # later chunks' loop holds forward and nine backward (recomputed)
+
+
+def test_routed_layer_s_grouped_products_are_its_kernels_under_experts_on_the_chip(
+        routed_layer):
+    """The grouped products: three forward, six backward, and the three the
+    later chunks' loop holds forward and nine backward (recomputed)."""
+    text, _, _ = routed_layer
     assert "ragged-dot" not in text
     kernels = re.findall(r"%(grouped_matmul\w*?)\.\d+ = [^\n]*tpu_custom_call", text)
     assert kernels.count("grouped_matmul") == 3 + 3 + 3 + 6
@@ -495,27 +531,6 @@ def test_flash_chunk_compiles(one_chip, chunk, s_kv):
                                                   interpret=False),
         q, kv, kv, starts)
     assert _has_kernel(compiled)
-
-
-# -- fused norm at the three ResNet-50 shapes ---------------------------------
-
-
-@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize(
-    "shape", [(128, 56, 56, 256), (128, 7, 7, 2048), (128, 112, 112, 64)],
-    ids=["56x56x256", "7x7x2048", "112x112x64"])
-def test_fused_norm_compiles(one_chip, shape, backward):
-    x = _sds(shape, jnp.bfloat16, one_chip)
-    g = _sds(shape[-1:], jnp.float32, one_chip)
-
-    def fwd(x, gamma, beta):
-        return fused_batch_norm_act(x, gamma, beta, impl="pallas")[0]
-
-    def loss(x, gamma, beta):
-        return jnp.sum(fwd(x, gamma, beta).astype(jnp.float32))
-
-    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
-    assert _has_kernel(_compile(fn, x, g, g))
 
 
 # -- whole train steps, as chip_smoke.py runs them ----------------------------
@@ -592,35 +607,52 @@ def test_gpt_small_flash_step_fits_one_chip(topo, monkeypatch):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_internlm2_block_step_names_its_device_work(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def internlm2_block_step(topo):
     """One block at InternLM2-1.8B's widths (2048 = 16 x 128 heads, 8 kv
-    heads, SwiGLU 8192) through the train step, 1 x 4096 tokens: each
-    flash kernel is an instruction of its own name (before PR 24 all
-    three read ``flash_attention.<n>``, the enclosing jit's), and every
-    phase scope of training.py reaches the instructions' ``op_name`` —
-    what ``trace/device.py`` and the benchmark's per-kernel metrics read
-    off a capture (docs/TRACING.md, "Device names")."""
-    import re
-
-    from horovod_tpu import trace
-    from horovod_tpu.trace import device
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    heads, SwiGLU 8192) through the train step, 1 x 4096 tokens, compiled
+    once for the tests below: what ``trace/device.py`` and the benchmark's
+    per-kernel metrics read off a capture (docs/TRACING.md, "Device names")."""
     mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
     cfg = TransformerConfig(
         vocab_size=1024, num_layers=1, num_heads=16, num_kv_heads=8,
         head_dim=128, mlp_ratio=4, max_seq_len=4096, dtype=jnp.bfloat16,
         attention_impl="flash")
-    text = _step_compiled(
-        Transformer(cfg), optax.adamw(1e-3), mesh,
-        jnp.zeros((1, 4096), jnp.int32),
-        ((1, 4096), jnp.int32), ((1, 4096), jnp.int32)).as_text()
-    kernels = re.findall(r"%(flash_attention\w*)\.\d+ = [^\n]*tpu_custom_call",
-                         text)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return _step_compiled(
+            Transformer(cfg), optax.adamw(1e-3), mesh,
+            jnp.zeros((1, 4096), jnp.int32),
+            ((1, 4096), jnp.int32), ((1, 4096), jnp.int32))
+
+
+def _flash_kernels(text):
+    return re.findall(r"%(flash_attention\w*)\.\d+ = [^\n]*tpu_custom_call",
+                      text)
+
+
+def test_internlm2_block_step_has_one_name_a_flash_kernel(internlm2_block_step):
+    """Each flash kernel is an instruction of its own name (before PR 24 all
+    three read ``flash_attention.<n>``, the enclosing jit's)."""
+    from horovod_tpu import trace
+
+    kernels = _flash_kernels(internlm2_block_step.as_text())
     assert sorted(kernels) == sorted(trace.DEVICE_KERNELS[:3])
-    op_names = set(re.findall(r'op_name="([^"]+)"', text))
+
+
+def test_internlm2_block_step_s_phase_scopes_reach_the_op_names(internlm2_block_step):
+    """Every phase scope of training.py reaches the instructions' ``op_name``."""
+    op_names = set(re.findall(r'op_name="([^"]+)"', internlm2_block_step.as_text()))
     for component in ("jvp(forward)", "transpose(jvp(forward))", "optimizer"):
         assert any(component in o.split("/") for o in op_names), component
+
+
+def test_internlm2_block_step_s_phase_table_puts_each_kernel_in_its_phase(
+        internlm2_block_step):
+    from horovod_tpu.trace import device
+
+    text = internlm2_block_step.as_text()
+    kernels = _flash_kernels(text)
     table = device.phase_table(text)
     phase_of = {k: table[next(n for n in table if n.startswith(k + "."))][0]
                 for k in kernels}
@@ -650,22 +682,39 @@ def _internlm2_step(topo, monkeypatch, n_devices, depth):
         ((n_devices, 4096), jnp.int32), ((n_devices, 4096), jnp.int32))
 
 
-def test_dp4_step_all_reduces_are_asynchronous(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def dp4_step(topo):
+    """The dp4 cell's step over the four described chips at two of its layers,
+    compiled once for the tests below."""
+    with pytest.MonkeyPatch.context() as patch:
+        return _internlm2_step(topo, patch, 4, depth=2)
+
+
+def test_dp4_step_s_options_are_the_builder_s_own(topo):
     """Over four described chips the builder attaches the options itself
-    (``spmd_ops.exchange_compile_options``): the compiled schedule holds
-    asynchronous collective pairs, and of the synchronous all-reduces
-    (26 at the cell's depth with no option, one a leaf or tuple) only the
-    loss's scalar and at most one tuple of small leaves are left."""
+    (``spmd_ops.exchange_compile_options``)."""
     from horovod_tpu.ops import spmd_ops
-    from horovod_tpu.ops.comm_model import compiled_collective_counts
 
     mesh = Mesh(np.array(topo.devices), (WORLD_AXIS,))
     assert spmd_ops.exchange_compile_options(mesh) \
         == spmd_ops._ASYNC_ALL_REDUCE_OPTIONS
-    compiled = _internlm2_step(topo, monkeypatch, 4, depth=2)
+
+
+def test_dp4_step_all_reduces_are_asynchronous(dp4_step):
+    """The compiled schedule holds asynchronous collective pairs, and of the
+    synchronous all-reduces (26 at the cell's depth with no option, one a leaf
+    or tuple) only the loss's scalar and at most one tuple of small leaves are
+    left."""
+    from horovod_tpu.ops.comm_model import compiled_collective_counts
+
+    compiled = dp4_step
     counts = compiled_collective_counts(compiled.as_text())
     assert counts["async_pairs"] >= 1
     assert counts["sync_all_reduces"] <= 2 < 26
+
+
+def test_dp4_step_fits_a_chip(dp4_step):
+    compiled = dp4_step
     assert _device_bytes(compiled) < HBM_BYTES
 
 
@@ -692,24 +741,16 @@ def test_one_chip_step_gets_no_option(topo, monkeypatch):
 # -- layers of two mixers in one compiled step (PR 35) ------------------------
 
 
-def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def two_mixer_step(topo):
     """One Gated DeltaNet layer and one gated-attention layer at the widths of
     qwen3-next-80b-a3b-s8192-1chip (8,192 tokens; 32 value heads of 128; 16
     query heads over 2 key/value heads of 256; top-10 of 512 with 32 held, a
-    gated shared expert) through the train step: the gated delta rule's carry
-    and the 256-wide attention are Mosaic calls by their names, each under the
-    scope its metric reads (``gated_delta``, never ``gdn``; forward and
-    backward, each once: since PR 41 nothing of the mixer is made again), the
-    projections under ``gdn``, and the routed layer still holds no scatter.
-    Since PR 38 the linear layer is kernels and projections:
-    ``gdn_conv_norm_*`` and ``gdn_gated_norm_*`` under ``gdn``, q and k 16
-    heads wide into the rule, and no copy, reshape, pad, slice or broadcast of
-    XLA's of an activation between the projections."""
+    gated shared expert), the train step compiled once for the tests below."""
     import functools
 
     from horovod_tpu.models.transformer import next_token_loss
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
     cfg = TransformerConfig(
         vocab_size=1024, num_layers=2, num_heads=16, num_kv_heads=2, head_dim=256,
@@ -720,69 +761,111 @@ def test_two_mixer_step_holds_its_kernels_under_their_scopes(topo, monkeypatch):
         linear_key_head_dim=128, linear_num_value_heads=32, linear_value_head_dim=128,
         num_experts=512, num_experts_per_tok=10, moe_intermediate_size=512,
         held_experts=(0, 32), num_shared_experts=1, shared_expert_gate=True)
-    model, optimizer = Transformer(cfg), optax.adamw(1e-7)
-    replicated = NamedSharding(mesh, P())
-    batch = NamedSharding(mesh, P(WORLD_AXIS))
-    state = jax.eval_shape(lambda: training.create_train_state(
-        model, optimizer, jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)))
-    state = jax.tree_util.tree_map(lambda s: _sds(s.shape, s.dtype, replicated), state)
-    step = training.data_parallel_train_step(
-        model, optimizer, mesh=mesh,
-        loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
-    tokens = _sds((1, 8192), jnp.int32, batch)
-    compiled = step.lower(state, tokens, tokens).compile()
-    # 6.630 GB at PR 37, whose elementwise passes kept float32 copies; 6.072
-    # at PR 38-40 with the mixer made again; since PR 41 the rule's residuals
-    # (0.47 GB a linear layer) are kept and nothing of the mixer is made
-    # again: 6.545 GB (6.511 with the mixer traced under no checkpoint at all)
-    assert _device_bytes(compiled) <= 6_560_000_000
-    text = compiled.as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return _step_compiled(
+            Transformer(cfg), optax.adamw(1e-7), mesh, jnp.zeros((1, 8192), jnp.int32),
+            ((1, 8192), jnp.int32), ((1, 8192), jnp.int32),
+            loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
+
+
+def _kernel_calls(text):
+    """``{kernel name: [op_name of each of its calls]}`` of a compiled text."""
     calls = {}
     for line in text.splitlines():
         m = re.search(r"%(\w+?)\.\d+ = [^\n]*tpu_custom_call", line)
         if m:
             calls.setdefault(m.group(1), []).append(
                 re.search(r'op_name="([^"]*)"', line).group(1))
-    # the rule's forward (``L``'s kernel, then the chunks') ONCE (until PR 41
-    # twice: the mixer was made again in the backward) and its backward once;
-    # the three attention kernels once each; of the rule's products XLA keeps
-    # the inverse's alone, and runs it once
+    return calls
+
+
+def test_two_mixer_step_s_bytes(two_mixer_step):
+    compiled = two_mixer_step
+    # 6.630 GB at PR 37, whose elementwise passes kept float32 copies; 6.072
+    # at PR 38-40 with the mixer made again; since PR 41 the rule's residuals
+    # (0.47 GB a linear layer) are kept and nothing of the mixer is made
+    # again: 6.545 GB (6.511 with the mixer traced under no checkpoint at all)
+    assert _device_bytes(compiled) <= 6_560_000_000
+
+
+def test_two_mixer_step_runs_the_rule_s_kernels_once_each_and_makes_nothing_again(
+        two_mixer_step):
+    """The gated delta rule's carry is Mosaic calls by their names under the scope
+    its metric reads (``gated_delta``, never ``gdn``): the rule's forward
+    (``L``'s kernel, then the chunks') ONCE (until PR 41 twice: the mixer was
+    made again in the backward) and its backward once."""
+    text = two_mixer_step.as_text()
+    calls = _kernel_calls(text)
     assert [len(calls[k]) for k in ("gated_delta_kkt", "gated_delta_fwd",
                                     "gated_delta_bwd")] == [1, 1, 1]
     assert "rematted_computation" not in text
-    assert all("jit(_block_inverse)" in line for line in _xla_products(text, "/gated_delta/"))
-    assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                                    "flash_attention_bwd_dkv")] == [1, 1, 1]
     for name in ("gated_delta_kkt", "gated_delta_fwd", "gated_delta_bwd"):
         assert all("/linear_attn/" in o and "/gated_delta/" in o and "/gdn/" not in o
                    for o in calls[name]), calls[name]
-    # the input pass and the output pass once each way, all under ``gdn``
+
+
+def test_two_mixer_step_leaves_xla_the_inverse_alone_under_gated_delta(two_mixer_step):
+    """Of the rule's products XLA keeps the inverse's alone."""
+    text = two_mixer_step.as_text()
+    assert all("jit(_block_inverse)" in line for line in _xla_products(text, "/gated_delta/"))
+
+
+def test_two_mixer_step_runs_the_flash_kernels_once_each_at_256_wide_heads(two_mixer_step):
+    """The 256-wide attention is the three flash kernels, once each, under the
+    attention layer's scope."""
+    text = two_mixer_step.as_text()
+    calls = _kernel_calls(text)
+    assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                                    "flash_attention_bwd_dkv")] == [1, 1, 1]
+    assert all("/layer_1/attn/" in o for k in calls if k.startswith("flash") for o in calls[k])
+    assert "bf16[16,8192,256]" in text            # the 256-wide heads reach the kernels
+
+
+def test_two_mixer_step_runs_the_gdn_passes_once_each_under_gdn(two_mixer_step):
+    """Since PR 38 the linear layer is kernels and projections: the input pass
+    and the output pass once each way, all under ``gdn``."""
+    calls = _kernel_calls(two_mixer_step.as_text())
     assert [len(calls[k]) for k in ("gdn_conv_norm_fwd", "gdn_conv_norm_bwd",
                                     "gdn_gated_norm_fwd", "gdn_gated_norm_bwd")] == [1, 1, 1, 1]
     for name in (k for k in calls if k.startswith("gdn_")):
         assert all("/linear_attn/" in o and "/gdn/" in o and "/gated_delta/" not in o
                    for o in calls[name]), calls[name]
-    # q and k reach the rule's kernels 16 heads wide
-    for line in text.splitlines():
+
+
+def test_two_mixer_step_s_projections_lie_under_gdn_forward_and_backward(two_mixer_step):
+    op_names = set(re.findall(r'op_name="([^"]+)"', two_mixer_step.as_text()))
+    assert any("/gdn/" in o and "in_proj_qkvz" in o for o in op_names)
+    assert any("/gdn/" in o and "transpose(jvp(forward))" in o for o in op_names)
+
+
+def test_two_mixer_step_hands_the_rule_q_and_k_16_heads_wide(two_mixer_step):
+    for line in two_mixer_step.as_text().splitlines():
         if re.search(r"%gated_delta_(fwd|bwd)\.\d+ = ", line):
             operands = re.search(
                 r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", line).group(1)
             assert operands.startswith("bf16[1,8192,2048]{2,1,0}, bf16[1,8192,2048]{2,1,0}, "
                                        "bf16[1,8192,4096]{2,1,0}"), operands
-    # between the projections XLA moves no activation (bf16 here): what is
-    # left under /linear_attn/ of these operations is the gates' float32 (B,
-    # T, 32) rows a chunk and the inverse's own blocks
+
+
+def test_two_mixer_step_moves_no_bf16_activation_under_linear_attn(two_mixer_step):
+    """Between the projections XLA moves no activation (bf16 here): no copy,
+    reshape, pad, slice or broadcast of XLA's.  What is left under
+    /linear_attn/ of these operations is the gates' float32 (B, T, 32) rows a
+    chunk and the inverse's own blocks."""
+    text = two_mixer_step.as_text()
     moved = [l for l in _entry_instructions(text)
              if "/linear_attn/" in l and re.search(
                  r"= bf16\S+ (copy|reshape|pad|slice|broadcast|transpose|concatenate)\(", l)]
     assert not moved, moved
-    assert all("/layer_1/attn/" in o for k in calls if k.startswith("flash") for o in calls[k])
+
+
+def test_two_mixer_step_scatters_nothing_in_its_routed_or_linear_layer(two_mixer_step):
+    """The step's only scatters are the embedding's and the loss's gradients,
+    and the grouped products run under ``experts``."""
+    text = two_mixer_step.as_text()
+    calls = _kernel_calls(text)
     assert all("/experts/" in o for k in calls if k.startswith("grouped") for o in calls[k])
-    op_names = set(re.findall(r'op_name="([^"]+)"', text))
-    assert any("/gdn/" in o and "in_proj_qkvz" in o for o in op_names)
-    assert any("/gdn/" in o and "transpose(jvp(forward))" in o for o in op_names)
-    assert "bf16[16,8192,256]" in text            # the 256-wide heads reach the kernels
-    # the step's only scatters are the embedding's and the loss's gradients
     scatters = [l for l in text.splitlines() if re.search(r"= (\(.*?\)|\S+) scatter\(", l)]
     assert not [l for l in scatters if "/moe/" in l or "/linear_attn/" in l], scatters
 
@@ -827,15 +910,18 @@ def by_layer_step(topo):
             loss_fn=functools.partial(next_token_loss, aux_coef=0.001))
 
 
+def test_window_and_full_layers_step_fits_a_chip(by_layer_step):
+    compiled = by_layer_step
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(by_layer_step):
     """Laguna's two kinds of layer through the train step: each of
     the three flash kernels is called once unwindowed at 48 heads under
     ``attn_full`` and once at 64 heads under ``attn_window``; the dK/dV kernel
     holds a whole group's q and dO and states its VMEM (48 + 16 MiB at the group
-    of 6, 64 + 16 MiB at the group of 8: the compiler's own limit is 16 MiB); the
-    rotary step and the gate lie under their scopes, forward and backward."""
+    of 6, 64 + 16 MiB at the group of 8: the compiler's own limit is 16 MiB)."""
     compiled = by_layer_step
-    assert _device_bytes(compiled) < HBM_BYTES
     calls = {}
     for line in compiled.as_text().splitlines():
         m = re.search(r"%(flash_attention\w*?)\.\d+ = [^\n]*tpu_custom_call", line)
@@ -861,6 +947,11 @@ def test_window_and_full_layers_are_their_own_kernel_calls_on_the_chip(by_layer_
         if name == "flash_attention_bwd_dkv":
             assert '"size":"67108864"' in by_scope["attn_full"][2]
             assert '"size":"83886080"' in by_scope["attn_window"][2]
+
+
+def test_window_and_full_layers_rotary_step_and_gate_lie_under_their_scopes(by_layer_step):
+    """The rotary step and the gate lie under their scopes, forward and backward."""
+    compiled = by_layer_step
     op_names = set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
     for scope in ("attn_rope", "attn_gate"):
         for phase in ("jvp(forward)", "transpose(jvp(forward))"):

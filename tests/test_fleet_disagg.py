@@ -1,6 +1,9 @@
 """Disaggregated prefill/decode fleet (ROADMAP item 2, docs/FLEET.md).
 
-The two-tier pipeline's properties, each pinned where it is cheapest:
+The two-tier pipeline's properties, each pinned where it is cheapest (the
+handoff's wire, deadlines and edge cases are tests/test_fleet_disagg_handoff.py,
+per-tier scaling tests/test_fleet_disagg_scaling.py; what they share is
+tests/fleet_disagg_helpers.py):
 
 * engine ``role="prefill"``: warmup compiles the mixed chunk menu ONLY
   (the structural proof the tier can never run a decode step) and
@@ -9,65 +12,17 @@ The two-tier pipeline's properties, each pinned where it is cheapest:
 * the tentpole oracle: decode on MIGRATED blocks is bit-identical to
   decode on locally-prefilled blocks, at shards 1 and 2, with zero
   post-warmup compiles on both tiers and warm handoffs observed;
-* kvsnap ``source`` tag: import rejections name the exporting replica
-  (and untagged snapshots stay importable — backward compatible);
-* the two-hop deadline filter: remaining-budget checks charge prefill
-  queue + handoff + decode-tier delay, not one replica's queue alone;
-* edge cases: decode replica dies mid-decode post-handoff (PR-18
-  replica-loss recovery, watermark semantics), prefill
-  retire-while-draining holds the engine until its handoffs are
-  collected, hedged dispatch resolves first-handoff-wins within the
-  prefill tier;
-* chaos ``serve.handoff``: a corrupted wire degrades every handoff to
-  the cold path — outputs stay token-identical, never wrong — and the
-  handoff span reaches the flight-recorder bundle on the chaos path;
 * modeled == measured: ``modeled_kvsnap_bytes`` reproduces the warm
-  handoffs' measured wire bytes exactly (comm_model idiom);
-* per-tier scaling: TTFT breaches grow the prefill tier, a
-  decode-tokens/s floor breach grows the decode tier, independently.
+  handoffs' measured wire bytes exactly (comm_model idiom).
 """
-
-import time
 
 import numpy as np
 import pytest
 
 from horovod_tpu.metrics import instruments as _instr
-
-
-@pytest.fixture(scope="module")
-def disagg_pieces():
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.models.transformer import (
-        Transformer, TransformerConfig,
-    )
-    from horovod_tpu.serving import ServeConfig, ServingEngine
-
-    cfg = TransformerConfig(
-        vocab_size=97, num_layers=1, num_heads=2, num_kv_heads=2,
-        head_dim=8, max_seq_len=48, dtype=jnp.float32,
-        attention_impl="dot", causal=True)
-    model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32), train=False)["params"]
-    serve = ServeConfig(block_size=8, num_blocks=0, token_budget=128,
-                        watermark=2, prefill_tiers=(32,),
-                        decode_tiers=(1, 2), prefill_chunk=8)
-
-    def build(role="both"):
-        return ServingEngine(cfg, params, serve=serve, role=role)
-
-    return cfg, params, serve, build
-
-
-def _prompts(seed, n, lo=9, hi=14):
-    """>= 9 tokens each: at least one FULL block at block_size=8, so
-    prefill-complete exports always have a warm-path chain."""
-    rs = np.random.RandomState(seed)
-    return [rs.randint(1, 90, size=rs.randint(lo, hi)).astype(np.int32)
-            for _ in range(n)]
+from tests.fleet_disagg_helpers import (  # noqa: F401  (disagg_pieces: fixture)
+    _prompts, disagg_pieces,
+)
 
 
 # -- engine: the prefill role ------------------------------------------------
@@ -207,338 +162,3 @@ def test_handoff_bytes_modeled_equals_measured(disagg_pieces):
     assert router.migrated_bytes == sum(r["bytes"] for r in warm)
     assert _instr.SERVE_MIGRATED_BYTES.get() - before == \
         router.migrated_bytes
-
-
-# -- satellite: the kvsnap source tag ----------------------------------------
-
-
-def test_kvsnap_source_tag_names_sender(disagg_pieces):
-    _cfg, _params, _serve, build = disagg_pieces
-    src, dst = build(role="prefill"), build()
-    src.warmup()
-    dst.warmup()
-    src.snap_source = "prefill7"  # what ServingReplica.spawn sets
-    src.submit(np.arange(1, 18, dtype=np.int32), max_new_tokens=4)
-    src.run()
-    (_stream, snap, _arr), = src.handoffs.values()
-    assert snap["source"] == "prefill7"
-    # corrupt one verified token: the chain-hash reject names the sender
-    bad = dict(snap)
-    bad["tokens"] = np.array(snap["tokens"], np.int32).copy()
-    bad["tokens"][3] ^= 1
-    with pytest.raises(ValueError, match=r"from replica prefill7"):
-        dst.import_kv(bad)
-    # format reject names it too
-    worse = dict(snap)
-    worse["format"] = "bogus/9"
-    with pytest.raises(ValueError, match=r"from replica prefill7"):
-        dst.import_kv(worse)
-    # the clean tagged snapshot imports fine
-    assert dst.import_kv(dict(snap)) == len(snap["hashes"])
-
-
-def test_kvsnap_untagged_snapshot_backward_compatible(disagg_pieces):
-    _cfg, _params, _serve, build = disagg_pieces
-    src, dst = build(), build()
-    src.warmup()
-    dst.warmup()
-    assert src.snap_source is None  # no replica wrapper: untagged
-    rid = src.submit(np.arange(2, 19, dtype=np.int32), max_new_tokens=9)
-    while not any(s.req.id == rid and s.tokens_in_cache >= 16
-                  for s in src.scheduler.running):
-        src.step()
-    snap = src.export_requests(rids=[rid])[rid][1]
-    assert snap is not None and "source" not in snap
-    assert dst.import_kv(dict(snap)) == len(snap["hashes"])
-    # an untagged corrupt snapshot still rejects — just anonymously
-    bad = dict(snap)
-    bad["tokens"] = np.array(snap["tokens"], np.int32).copy()
-    bad["tokens"][0] ^= 1
-    with pytest.raises(ValueError, match=r"mismatch at block 0(?!.*from "
-                                         r"replica)"):
-        dst.import_kv(bad)
-    src.cancel(rid)
-
-
-# -- satellite: the two-hop deadline filter ----------------------------------
-
-
-def test_two_hop_deadline_filter(disagg_pieces):
-    """A cache-hot prefill replica whose queue ALONE fits the budget
-    must still be skipped when queue + handoff + decode delay does not
-    — and with no handoff cost on the books, affinity wins as before."""
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    router = FleetRouter(build, replicas=1, prefill_replicas=2)
-    template = np.arange(5, 29, dtype=np.int32)
-    g0 = router.submit(np.concatenate([template, [3, 4]]), 4)
-    p_hot = router._placed[g0].replica
-    assert p_hot.tier == "prefill"
-    router.run_until_drained()
-    assert p_hot.cached_prefix_blocks(template) > 0
-    p_cold = next(r for r in router.replicas
-                  if r.tier == "prefill" and r is not p_hot)
-    # fabricate load on the hot replica: 1 queued request x 0.5 s steps
-    p_hot.avg_step_s = 0.5
-    p_hot.engine.submit(np.arange(40, 60, dtype=np.int32),
-                        max_new_tokens=4)
-    assert p_hot.est_queue_delay() >= 0.5
-    # no handoff cost booked yet: queue 0.5 fits the 1.0 s budget and
-    # affinity routes to the cached replica (the pre-fix behavior)
-    router._handoff_ema = None
-    now = time.perf_counter()
-    g1 = router.submit(np.concatenate([template, [7, 8]]), 4,
-                       arrival=now, deadline_s=1.0)
-    assert router._placed[g1].replica is p_hot
-    # 0.6 s of handoff EMA: 0.5 + 0.6 > 1.0 — the two-hop total blows
-    # the budget, so the filter must exclude the hot replica even
-    # though its own queue fits
-    router._handoff_ema = 0.6
-    g2 = router.submit(np.concatenate([template, [9, 1]]), 4,
-                       arrival=time.perf_counter(), deadline_s=1.0)
-    assert router._placed[g2].replica is p_cold, \
-        "deadline filter ignored the handoff + decode hop"
-    assert router._two_hop_overhead() == pytest.approx(0.6)
-    router.run_until_drained()
-
-
-# -- satellite: handoff edge cases -------------------------------------------
-
-
-def test_decode_replica_death_after_handoff(disagg_pieces, monkeypatch,
-                                            tmp_path):
-    """A decode replica dying mid-decode falls back to the PR-18
-    replica-loss recovery: its handed-off requests re-route (watermark
-    prepended exactly once), outputs stay bit-identical, and the
-    bundle dumped on the chaos path carries the serve.handoff span."""
-    from horovod_tpu.fleet.router import FleetRouter
-    from horovod_tpu.trace import flight as _flight
-
-    monkeypatch.setenv("HVD_TPU_FLEET_REPLICA_ERRORS", "1")
-    monkeypatch.setenv("HVD_TPU_TRACE_BUNDLE_DIR", str(tmp_path))
-    _flight._last_dump.clear()
-    _cfg, _params, _serve, build = disagg_pieces
-    prompts = _prompts(23, 4)
-    ref = build()
-    ref.warmup()
-    rids = [ref.submit(p, max_new_tokens=12) for p in prompts]
-    want = ref.run()
-
-    router = FleetRouter(build, replicas=2, prefill_replicas=1)
-    gids = [router.submit(p, 12) for p in prompts]
-    # run until a decode replica is actually decoding handed-off work
-    victim = None
-    deadline = time.time() + 60
-    while time.time() < deadline:
-        router.step()
-        victim = next(
-            (r for r in router.replicas if r.tier == "decode"
-             and r.engine is not None
-             and any(len(s.generated) >= 2
-                     for s in r.engine.scheduler.running)), None)
-        if victim is not None:
-            break
-    assert victim is not None, "no decode replica reached mid-decode"
-
-    def boom():
-        raise RuntimeError("injected decode-step failure")
-
-    victim.engine.step = boom
-    got = router.run_until_drained()
-    for i, (r, g) in enumerate(zip(rids, gids)):
-        np.testing.assert_array_equal(want[r], got[g], err_msg=f"req {i}")
-    assert router.recovery, "replica loss must book a recovery"
-    assert victim.state == "retired"
-    assert router.all_compile_free()
-    bundles = list(tmp_path.glob("bundle-replica_loss-*.json"))
-    assert bundles, "no flight bundle on the chaos path"
-    names = {ev.get("name") for b in bundles
-             for ev in _flight.read_bundle(str(b))["trace"]["traceEvents"]}
-    assert "serve.handoff" in names, \
-        "handoff span missing from the flight recorder"
-
-
-def test_prefill_retire_while_draining(disagg_pieces):
-    """A draining prefill replica finishes its in-flight prefill,
-    hands the request off, and only THEN retires — the handoff-aware
-    ``drained`` gate keeps the parked snapshot alive until the router
-    collects it."""
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    prompts = _prompts(24, 2)
-    ref = build()
-    ref.warmup()
-    rids = [ref.submit(p, max_new_tokens=8) for p in prompts]
-    want = ref.run()
-    router = FleetRouter(build, replicas=1, prefill_replicas=2)
-    gids = [router.submit(p, 8) for p in prompts]
-    pre = [r for r in router.replicas if r.tier == "prefill"]
-    loaded = next(r for r in pre if r.has_work)
-    loaded.drain()
-    # step the ENGINE directly (not the router) so the parked handoff
-    # is observable before the router's collection pass
-    for _ in range(32):
-        if loaded.engine.handoffs:
-            break
-        loaded.engine.step()
-    assert loaded.engine.handoffs, "prefill never reached the boundary"
-    assert not loaded.has_work
-    assert not loaded.drained, \
-        "a parked handoff must count as in-flight work"
-    got = router.run_until_drained()
-    assert loaded.state == "retired"
-    for i, (r, g) in enumerate(zip(rids, gids)):
-        np.testing.assert_array_equal(want[r], got[g], err_msg=f"req {i}")
-
-
-def test_hedged_dispatch_within_prefill_tier(disagg_pieces, monkeypatch):
-    """Hedging in a disaggregated fleet stays tier-matched (the second
-    dispatch lands on the OTHER prefill replica) and resolves
-    first-handoff-wins: exactly one copy crosses into the decode tier,
-    the loser's parked handoff is discarded."""
-    from horovod_tpu.fleet.router import FleetRouter
-
-    monkeypatch.setenv("HVD_TPU_SERVE_HEDGE", "1")
-    _cfg, _params, _serve, build = disagg_pieces
-    prompt = np.arange(3, 20, dtype=np.int32)
-    ref = build()
-    ref.warmup()
-    rid = ref.submit(prompt, max_new_tokens=6)
-    want = ref.run()[rid]
-
-    t = [100.0]
-    router = FleetRouter(build, replicas=1, prefill_replicas=2,
-                         clock=lambda: t[0])
-    router.hedge_budget = 1.0
-    router._ttfts.extend([0.001] * 16)  # a stable, tiny p99 estimate
-    gid = router.submit(prompt, 6)
-    p = router._placed[gid]
-    t[0] += 1.0  # stalled far past p99 TTFT, still pre-first-token
-    router._maybe_hedge()
-    assert p.hedge is not None and p.hedge[0].tier == "prefill"
-    assert p.hedge[0] is not p.replica
-    got = router.run_until_drained()
-    np.testing.assert_array_equal(want, got[gid])
-    assert router.hedges["won"] + router.hedges["lost"] == 1
-    dec = next(r for r in router.replicas if r.tier == "decode")
-    assert dec.engine._next_id == 1, \
-        "both hedge copies crossed the tier boundary"
-
-
-def test_handoff_chaos_corrupt_degrades_cold(disagg_pieces):
-    """serve.handoff corruption: every chain-hash verification fails,
-    every handoff lands cold — and outputs are STILL token-identical
-    (deterministic re-prefill, never wrong tokens)."""
-    from horovod_tpu import chaos
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    prompts = _prompts(25, 5)
-    ref = build()
-    ref.warmup()
-    rids = [ref.submit(p, max_new_tokens=8) for p in prompts]
-    want = ref.run()
-    chaos.configure("serve.handoff:corrupt,prob=1", seed=7)
-    try:
-        router = FleetRouter(build, replicas=1, prefill_replicas=1)
-        gids = [router.submit(p, 8) for p in prompts]
-        got = router.run_until_drained()
-        fired = chaos.injection_trace()
-    finally:
-        chaos.clear()
-    for i, (r, g) in enumerate(zip(rids, gids)):
-        np.testing.assert_array_equal(want[r], got[g], err_msg=f"req {i}")
-    assert router.handoffs["warm"] == 0
-    assert router.handoffs["cold"] == len(prompts)
-    assert router.migrated_bytes == 0
-    assert any(ev["site"] == "serve.handoff" for ev in fired)
-
-
-# -- per-tier scaling --------------------------------------------------------
-
-
-def test_per_tier_scaling_signals_drive_their_tier(disagg_pieces,
-                                                   monkeypatch):
-    from horovod_tpu.fleet.policy import Target, TargetTrackingPolicy
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    mk = dict(min_size=1, max_size=3, hysteresis=1, cooldown_s=0.0)
-    router = FleetRouter(
-        build, replicas=1, prefill_replicas=1,
-        policy=TargetTrackingPolicy([Target("p99_ttft", 0.5)], **mk),
-        decode_policy=TargetTrackingPolicy(
-            [Target("decode_tokens_per_s", 100.0, invert=True)], **mk))
-    # TTFT breach + decode floor met: ONLY the prefill tier grows
-    monkeypatch.setattr(router, "signals", lambda: {
-        "p99_ttft": 1.0, "decode_tokens_per_s": 500.0})
-    router._maybe_scale()
-    assert router.tier_size("prefill") == 2
-    assert router.tier_size("decode") == 1
-    assert ("out", 2, "prefill") in router.scale_events
-    grown = router.replicas[-1]
-    assert grown.tier == "prefill" and grown.engine.role == "prefill"
-    # decode floor breach + TTFT healthy: ONLY the decode tier grows
-    monkeypatch.setattr(router, "signals", lambda: {
-        "p99_ttft": 0.2, "decode_tokens_per_s": 10.0})
-    router._maybe_scale()
-    assert router.tier_size("decode") >= 2
-    assert any(ev[2] == "decode" and ev[0] == "out"
-               for ev in router.scale_events if len(ev) == 3)
-    assert router.replicas[-1].engine.role == "both"
-
-
-def test_decode_tokens_rate_signal(disagg_pieces):
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    t = [50.0]
-    router = FleetRouter(build, replicas=2, prefill_replicas=1,
-                         clock=lambda: t[0])
-    assert "decode_tokens_per_s" not in router.signals()  # baseline pin
-    router._decode_tokens += 120
-    t[0] += 2.0
-    s = router.signals()
-    # 120 tokens / 2 s / 2 accepting decode replicas
-    assert s["decode_tokens_per_s"] == pytest.approx(30.0)
-
-
-def test_env_knobs_arm_disagg_and_decode_policy(disagg_pieces,
-                                                monkeypatch):
-    from horovod_tpu.fleet.policy import decode_policy_from_env
-    from horovod_tpu.fleet.router import FleetRouter
-
-    _cfg, _params, _serve, build = disagg_pieces
-    assert decode_policy_from_env() is None
-    monkeypatch.setenv("HVD_TPU_FLEET_DECODE_TPS_FLOOR", "50")
-    pol = decode_policy_from_env()
-    t = pol.targets()["decode_tokens_per_s"]
-    assert t.value == 50.0 and t.invert
-    monkeypatch.setenv("HVD_TPU_FLEET_PREFILL_REPLICAS", "1")
-    router = FleetRouter(build, replicas=1)
-    assert router.disagg and router.decode_policy is not None
-    assert router.tier_size("prefill") == 1
-    assert router.tier_size("decode") == 1
-    assert {r.name for r in router.replicas} == {"decode0", "prefill1"}
-
-
-def test_endpoint_signal_source_decode_rate(monkeypatch):
-    """The scrape-side twin of the router's in-process signal: token
-    emissions (latency histogram ``_count``) rated between scrapes,
-    per endpoint."""
-    from horovod_tpu.fleet.autoscaler import EndpointSignalSource
-
-    t = [10.0]
-    src = EndpointSignalSource(["http://a", "http://b"],
-                               clock=lambda: t[0])
-    name = src.LATENCY + "_count"
-    samples = [{(name, ("first",)): 100.0},
-               {(name, ("first",)): 400.0}]
-    monkeypatch.setattr(src, "_fetch", lambda: dict(samples.pop(0)))
-    assert "decode_tokens_per_s" not in src()
-    t[0] += 3.0
-    out = src()
-    # (400 - 100) / 3 s / 2 endpoints
-    assert out["decode_tokens_per_s"] == pytest.approx(50.0)

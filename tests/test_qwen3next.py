@@ -391,8 +391,8 @@ def test_the_model_s_tree_and_its_layers():
     dot = Transformer(_config(attention_impl="dot")).apply({"params": params}, tokens)[0]
     np.testing.assert_allclose(logits, dot, atol=5e-4)
     remat = Transformer(_config(attention_impl="flash", remat_policy="full"))
-    grads = [jax.grad(lambda p: transformer.next_token_loss(
-        m.apply({"params": p}, tokens), tokens, 0.001))(params)
+    grads = [jax.jit(jax.grad(lambda p: transformer.next_token_loss(
+        m.apply({"params": p}, tokens), tokens, 0.001)))(params)
         for m in (Transformer(cfg), remat)]
     for a, b in zip(*map(jax.tree_util.tree_leaves, grads)):
         np.testing.assert_allclose(a, b, atol=1e-5 * max(1.0, float(jnp.max(jnp.abs(b)))))

@@ -34,7 +34,7 @@ drives them through ``horovod_tpu.serving``:
                load — token-identity asserted, per-chip decode read
                bytes and psum stream both modeled AND measured from
                the lowered StableHLO (modeled == measured or the leg
-               fails).  The full run writes MULTICHIP_r06.json.
+               fails).
   spec_base_* / spec_on_*
                the round-15 speculative-decoding A/B: the same load
                driven through a plain engine and one with the
@@ -339,7 +339,7 @@ def kv_model_leg(cfg, serve_cfg, context_len, page_tiers):
     }
 
 
-def run_multichip_leg(shards, n_requests, seed, write_json):
+def run_multichip_leg(shards, n_requests, seed):
     """The tensor-sharded A/B (ISSUE 12): ONE model over ``shards``
     chips of the ICI mesh — kv heads + the paged pool head-sharded,
     Megatron FFN, one psum per sublayer — against a single-device
@@ -442,14 +442,6 @@ def run_multichip_leg(shards, n_requests, seed, write_json):
         "pool_bytes_per_shard": eng.pool_bytes_per_shard,
         "shard_psum_bytes_total": eng.shard_psum_bytes,
     }
-    if write_json:
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "MULTICHIP_r06.json")
-        with open(path, "w") as f:
-            json.dump({"n_devices": jax.device_count(), "ok": True,
-                       "leg": row}, f, indent=2)
-            f.write("\n")
-        print(f"wrote {path}", file=sys.stderr)
     return row
 
 
@@ -1101,8 +1093,7 @@ def main():
         2 if args.smoke else 8)
     mc_rows = []
     if shards > 1:
-        mc = run_multichip_leg(shards, 12 if args.smoke else 32,
-                               args.seed, write_json=not args.smoke)
+        mc = run_multichip_leg(shards, 12 if args.smoke else 32, args.seed)
         if mc is None:
             return 1
         mc_rows.append(mc)
