@@ -626,7 +626,7 @@ def block_diffusion_mask(half: int, block: int):
 
 
 def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
-                         window=None, mask=None):
+                         window=None, mask=None, documents=None):
     """Standard attention; offsets support sequence-sharded blocks.
 
     q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv | H (``v`` may be of
@@ -641,6 +641,9 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
     sliding window — each token attends the last ``window`` positions,
     itself included (see ``sliding_mask``).  ``mask``: an explicit (Sq, Sk)
     bool mask (``block_diffusion_mask``) in place of ``causal``/``window``.
+    ``documents``: a packed row's document ids (B, S), of queries and keys
+    alike: a query sees a key only of its own document (equal ids), under
+    ``causal`` and ``window`` as they are.
     """
     b, s_q, h, d = q.shape
     s_k, h_kv = k.shape[1], k.shape[2]
@@ -666,6 +669,9 @@ def causal_dot_attention(q, k, v, *, q_offset=0, k_offset=0, causal=True,
             causal=causal, window=window,
         )
         logits = jnp.where(mask[None, None], logits, -1e30)
+    if documents is not None:
+        same = documents[:, :, None] == documents[:, None, :]
+        logits = jnp.where(same[:, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     if h_kv != h:
         return jnp.einsum(
@@ -824,7 +830,8 @@ class Attention(nn.Module):
         return q, k, v
 
     @nn.compact
-    def __call__(self, x, positions, paged=None, layer: int = 0):
+    def __call__(self, x, positions, paged=None, layer: int = 0,
+                 documents=None):
         cfg = self.cfg
         dense = functools.partial(
             nn.DenseGeneral, dtype=cfg.dtype, use_bias=False
@@ -945,11 +952,11 @@ class Attention(nn.Module):
 
             with _own_scope(cfg, core):
                 out = flash_attention(q, k, v, causal=cfg.causal,
-                                      window=window)
+                                      window=window, documents=documents)
         else:
             with _own_scope(cfg, core):
                 out = causal_dot_attention(q, k, v, causal=cfg.causal,
-                                           window=window)
+                                           window=window, documents=documents)
         if gate is not None:
             out = (out.astype(jnp.float32)
                    * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
@@ -1176,7 +1183,8 @@ class Block(nn.Module):
     heads: Optional[int] = None
 
     @nn.compact
-    def __call__(self, x, positions, paged=None, layer: int = 0):
+    def __call__(self, x, positions, paged=None, layer: int = 0,
+                 documents=None):
         cfg = self.cfg
         norm = _rms_norm(cfg)
         if self.linear:
@@ -1189,7 +1197,8 @@ class Block(nn.Module):
         else:
             x = x + Attention(cfg, sliding=self.sliding, heads=self.heads,
                               name="attn")(
-                norm(name="ln1")(x), positions, paged=paged, layer=layer)
+                norm(name="ln1")(x), positions, paged=paged, layer=layer,
+                documents=documents)
         if not self.routed:
             x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
             return x
@@ -1222,23 +1231,91 @@ class Block(nn.Module):
         return x + y, stats
 
 
+def _packed_input(tokens):
+    """A model's input as ``(token ids, document ids or None)``: the ids (B, S)
+    alone, or, for rows packed of several documents, the ids with each
+    position's document id: a pair ``(ids, documents)``, or ONE integer array
+    (B, 2, S) of the two (``[:, 0]`` the tokens, ``[:, 1]`` the documents: what
+    a loader that hands the step one array a batch gives)."""
+    if isinstance(tokens, (tuple, list)):
+        ids, documents = tokens
+        return ids, documents
+    if tokens.ndim == 3:
+        return tokens[:, 0], tokens[:, 1]
+    return tokens, None
+
+
+def document_starts(documents):
+    """(B, S) bool: the positions where a document of a packed row begins (the
+    first, and every position whose id differs from the one before)."""
+    return jnp.concatenate(
+        [jnp.ones_like(documents[:, :1], bool),
+         documents[:, 1:] != documents[:, :-1]], axis=1)
+
+
+def document_positions(documents):
+    """Positions that restart at each document of a packed row: a position's
+    distance from where its document begins, (B, S) from the ids (B, S)."""
+    index = jnp.arange(documents.shape[1])
+    first = jax.lax.cummax(
+        jnp.where(document_starts(documents), index, 0), axis=1)
+    return index - first
+
+
 class Transformer(nn.Module):
     """Decoder-only LM.  ``__call__(tokens, positions=None) -> logits``;
     ``(logits, aux)`` with a routed feed-forward (``cfg.num_experts``; the
     statistics are over the routed layers, ``aux_loss`` in the form
     ``cfg.router_seq_aux`` names); with
     ``cfg.block_diffusion`` the tokens are ``[noisy || clean]`` (B, 2L) and
-    the logits those of the noisy half, (B, L, V)."""
+    the logits those of the noisy half, (B, L, V).  ``tokens`` with document
+    ids (``_packed_input``: rows packed of several documents): every attention
+    layer keeps a query to the keys of its own document, under the layer's
+    causal mask and window, and positions restart at each document
+    (``document_positions``) where none are given; the ids are data, so one
+    compiled step serves every layout of the same shapes."""
 
     cfg: TransformerConfig
+
+    def _refuse_documents(self, paged):
+        """The paths that cannot honour document ids, each with its reason."""
+        cfg = self.cfg
+        for refused, why in (
+                (paged is not None,
+                 "paged serving: a cache row is one sequence"),
+                (cfg.attention_impl not in ("dot", "flash"),
+                 f"attention_impl {cfg.attention_impl!r}: the ring rotates "
+                 "keys and values without their ids"),
+                (cfg.shard_axis is not None,
+                 "shard_axis: the head-sharded path is the serving engine's, "
+                 "whose rows are one sequence each"),
+                (cfg.kv_lora_rank is not None,
+                 "latent attention: the kernels at its two widths take no ids"),
+                (cfg.block_diffusion is not None,
+                 "block_diffusion: its mask is of one document's [noisy || "
+                 "clean] rows"),
+                (cfg.has_linear_attention,
+                 "'linear_attention' layer: a recurrence's state and its "
+                 "convolution's taps are not reset at a boundary yet")):
+            if refused:
+                raise ValueError(f"document ids (packed rows) take no {why}")
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = True,
                  paged=None):
         cfg = self.cfg
+        tokens, documents = _packed_input(tokens)
         routed = cfg.num_experts is not None
         if paged is not None and routed:
             raise ValueError("paged serving takes no routed feed-forward")
+        if documents is not None:
+            self._refuse_documents(paged)
+            # what the ids become outside the kernels, under one name
+            with jax.named_scope("attn_docmask"):
+                documents = jnp.asarray(documents, jnp.int32)
+                documents_a_row = jnp.sum(document_starts(documents), axis=1)
+                if positions is None:
+                    positions = document_positions(documents)
         if positions is None and cfg.block_diffusion is not None:
             half = jnp.arange(tokens.shape[1] // 2)
             positions = jnp.broadcast_to(
@@ -1258,7 +1335,9 @@ class Transformer(nn.Module):
         if _trace.enabled() and (
                 cfg.has_sliding_attention or cfg.rope_parameters is not None
                 or cfg.num_heads_per_layer is not None):
-            _trace.event("attn.layers", layers=cfg.attention_layers())
+            _trace.event("attn.layers", layers=[
+                dict(layer, documents=documents is not None)
+                for layer in cfg.attention_layers()])
         emb = nn.Embed(
             cfg.vocab_size, cfg.d_model,
             dtype=cfg.dtype, name="embed",
@@ -1293,6 +1372,9 @@ class Transformer(nn.Module):
                 # threads through every block, each addressing its own
                 # pool layer; never composes with remat (train=False)
                 x = block(x, positions, paged, i)
+            elif documents is not None:
+                # a packed row: every block's attention takes the ids
+                x = block(x, positions, None, i, documents)
             else:
                 x = block(x, positions)
             if routed_here:
@@ -1315,7 +1397,10 @@ class Transformer(nn.Module):
         if routed:
             per = {k: jnp.stack([s[k] for s in layer_stats])
                    for k in layer_stats[0]}
+            packed = ({} if documents is None
+                      else {"documents": documents_a_row})  # (B,): a row's count
             return logits, {
+                **packed,
                 "aux_loss": jnp.mean(per["aux_loss"]),
                 "expert_assignments": jnp.sum(per["assigned"]),
                 "expert_load_max_over_mean": jnp.max(
@@ -1359,8 +1444,16 @@ def next_token_loss(outputs, labels, aux_coef: float = 0.0):
     """Mean softmax cross-entropy in float32 of a routed model's ``(logits,
     aux)`` (or of the logits alone) against integer ``labels``, plus
     ``aux_coef`` x the router's auxiliary loss: a ``loss_fn`` for the train
-    step."""
-    return _cross_entropy_plus_aux(outputs, labels, None, aux_coef)
+    step.  ``labels`` may be ``(targets, weights)``, both (B, S): the mean is
+    then over the weighted positions, ``sum(w x ce) / sum(w)`` (a packed row
+    gives weight 0 to a document's last position, whose next token is another
+    document's)."""
+    if not isinstance(labels, (tuple, list)):
+        return _cross_entropy_plus_aux(outputs, labels, None, aux_coef)
+    targets, weights = labels
+    weights = weights.astype(jnp.float32)
+    return _cross_entropy_plus_aux(
+        outputs, targets, weights * (weights.size / jnp.sum(weights)), aux_coef)
 
 
 def modeled_activation_bytes(cfg: TransformerConfig, batch: int,

@@ -73,6 +73,13 @@ head and shared by the whole query-head group — K/V HBM reads and the
 dK/dV accumulation shrink by ``num_heads/num_kv_heads`` with no
 materialized repeat.
 
+Rows packed of several documents: all three kernels (both dK/dV forms) take
+a row's document ids as DATA beside the causal mask and the window
+(``flash_attention(..., documents=)``) and keep a query to the keys of its
+own document.  The ids are laid out outside the kernels so that a tile's
+compare moves no lane (``_document_ids``, ``_same_document``); every tile the
+other masks reach is still visited; a call without ids is the call it was.
+
 All three kernels also take a traced ``kv_offset`` scalar (SMEM): the
 global position of the K block's first key minus the global position of
 the Q block's first query.  Ring attention passes the per-step shard
@@ -486,14 +493,16 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
     return {"fwd": fwd, "bwd_dq": fwd, "bwd_dkv": dkv}
 
 
-def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
+def _note_tiles(kernels, kv_offset, d_qk, d_v, documents=False, **shape):
     """One ``flash.tiles`` instant a kernel as it is traced: its name, the
     ``visited`` tiles and loop ``iterations`` (``tile_counts``: a query
     head's at the ``query_tiles_a_program`` a forward or dQ program walks as
     one, and for a dK/dV kernel those of the ``heads_a_program`` query heads a
     program holds, the whole group or 1; either is given beside them), the
     ``window`` of its mask (None: none) and the widths of a tile's products
-    (``d_qk`` of queries and keys, ``d_v`` of values).
+    (``d_qk`` of queries and keys, ``d_v`` of values) and whether the call took
+    ``documents`` (the tiles visited are the other masks' all the same: no tile
+    is skipped by document).
     ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
     bookkeeping at trace time; a traced ``kv_offset`` (a ring step) has no
     count to give."""
@@ -508,7 +517,7 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, **shape):
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
                      iterations=iterations, d_qk=d_qk, d_v=d_v,
-                     window=shape.get("window"), **held)
+                     window=shape.get("window"), documents=documents, **held)
 
 
 _LANES = 128
@@ -525,9 +534,66 @@ def _lanes(x, width):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
+def _document_ids(documents, n_rows, n_lanes):
+    """A packed row's document ids (B, S) as the kernels take them, laid out so
+    that no visit moves a lane: for the side on a tile's rows ``(B, n_rows,
+    128)``, a position's id on all 128 lanes (as the forward's running sums: a
+    (rows, 1) column cost a lane broadcast a visit), and for the side on its
+    lanes ``(B, 1, n_lanes)``, the ids along the lanes.  Both padded to the
+    padded lengths (the padding's ids are masked as its positions are)."""
+    with jax.named_scope("attn_docmask"):
+        ids = jnp.asarray(documents, jnp.int32)
+        pad = lambda n: jnp.pad(ids, ((0, 0), (0, n - ids.shape[1])))
+        on_rows = jnp.broadcast_to(pad(n_rows)[:, :, None],
+                                   (ids.shape[0], n_rows, _LANES))
+        return on_rows, pad(n_lanes)[:, None, :]
+
+
+def _document_bytes(rows, lanes):
+    """What a program holds in VMEM of ``_document_ids``' two blocks, twice
+    buffered: ``rows`` positions on 128 lanes, ``lanes`` on 8 sublanes."""
+    return 2 * 4 * (rows * _LANES + 8 * lanes)
+
+
+def _same_document(docs, row_off, rows, col_off, cols):
+    """(rows, cols) bool: whether the tile's row and column positions are of
+    one document.  ``docs``: the refs of ``_document_ids``' two operands, the
+    first a block of the side on the tile's rows (``row_off`` inside it), the
+    second the whole other side."""
+    on_rows, on_lanes = docs
+    r = on_rows[0, pl.ds(row_off, rows), :]      # (rows, 128)
+    c = on_lanes[0, :, pl.ds(col_off, cols)]     # (1, cols)
+    return _lanes(r, cols) == c
+
+
+def _document_call(kernel, n_in, documents, folded, block_rows, n_rows,
+                   n_lanes, **static):
+    """What a packed row's ids add to a kernel's call, as ``(kernel, operands,
+    block specs, VMEM bytes)``: ``_document_ids``' two operands after the
+    call's ``n_in`` inputs, which reach ``kernel`` as ``docs`` (the side on a
+    tile's rows ``block_rows`` a program along the grid's second axis, the
+    other side whole; a sequence's for all its heads along the first, whose
+    ``folded`` entries are sequences x heads).  Without ``documents`` the
+    kernel with its ``static`` arguments and nothing else: the call it was,
+    refs and all."""
+    if documents is None:
+        return functools.partial(kernel, **static), (), [], 0
+    heads = folded // documents.shape[0]
+
+    def with_documents(*refs):
+        return kernel(*refs[:n_in], *refs[n_in + 2:],
+                      docs=refs[n_in:n_in + 2], **static)
+
+    specs = [pl.BlockSpec((1, block_rows, _LANES),
+                          lambda b, i, *_: (b // heads, i, 0)),
+             pl.BlockSpec((1, 1, n_lanes), lambda b, i, *_: (b // heads, 0, 0))]
+    return (with_documents, _document_ids(documents, n_rows, n_lanes), specs,
+            _document_bytes(block_rows, n_lanes))
+
+
 def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s,
                 l_s, m_s, *, sm_scale, causal, block_q, block_k, seq_len,
-                window=None, off_div=None, bd=None):
+                window=None, off_div=None, bd=None, docs=None):
     """The forward of the ``acc_s.shape[0]`` consecutive query tiles of one
     head that a program holds (``_query_tiles_a_program``), walked as one
     sequence of tile visits (``_run_query_tiles``).  Each query tile has its
@@ -571,6 +637,9 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s,
                 jnp.int32, (block_q, block_k), 1
             )
             mask = _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off)
+            if docs is not None:
+                mask = jnp.logical_and(mask, _same_document(
+                    docs, j * block_q, block_q, k_off, block_k))
         else:
             mask = _bd_tile_mask(q_off, k_off, block_q, block_k, seq_len,
                                  bd, True)
@@ -662,7 +731,8 @@ def _off_arr(kv_offset):
 
 
 def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
-                  with_lse=False, window=None, kv_offset=None, bd=None):
+                  with_lse=False, window=None, kv_offset=None, bd=None,
+                  documents=None):
     b, s, h, d = q.shape
     dv = v.shape[-1]  # the values' own width (latent attention: 192 / 128)
     group = _group_of(q, k)
@@ -678,8 +748,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
     vf = _fold(vp, b, h_kv, dv)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    kernel = functools.partial(
-        _fwd_kernel,
+    static = dict(
         sm_scale=1.0 / (d ** 0.5),
         causal=causal,
         block_q=block_q,
@@ -692,8 +761,12 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
                  seq_len=orig_s, causal=causal, window=window, bd=bd)
     tiles = _query_tiles_a_program(kv_off=kv_offset, **shape)
     _note_tiles({"flash_attention_fwd": "fwd"}, kv_offset, d_qk=d, d_v=dv,
+                documents=documents is not None,
                 query_tiles_a_program=tiles, **shape)
     rows = tiles * block_q  # a program's: its query tiles, walked as one
+    # with ids: the queries' a program's rows, the keys' whole
+    kernel, ids, id_specs, id_bytes = _document_call(
+        _fwd_kernel, 4, documents, b * h, rows, s_q, s_k, **static)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
@@ -708,7 +781,7 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
                          lambda bh, qi: (bh // group, 0, 0)),
             pl.BlockSpec((1, s_k, dv),
                          lambda bh, qi: (bh // group, 0, 0)),
-        ],
+        ] + id_specs,
         out_specs=[
             pl.BlockSpec((1, rows, dv), lambda bh, qi: (bh, qi, 0)),
             # trailing singleton: TPU block tiling requires the last two
@@ -721,9 +794,9 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=_fwd_scratch(tiles, block_q, d, dv),
         interpret=interpret,
-        **_kv_params(s_k, d, dv, k.dtype, _query_side_bytes(
+        **_kv_params(s_k, d, dv, k.dtype, id_bytes + _query_side_bytes(
             tiles, block_q, d, dv, q.dtype.itemsize)),
-    )(_off_arr(kv_offset), qf, kf, vf)
+    )(_off_arr(kv_offset), qf, kf, vf, *ids)
     out = _unfold(out, b, h, s_q, dv)[:, :orig_s]
     if with_lse:
         return out, lse  # lse stays folded+padded: (B*H, S_q_padded)
@@ -731,12 +804,14 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
 
 
 def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
-                 seq_len, block_q, block_k, window=None, kv_off=0, bd=None):
+                 seq_len, block_q, block_k, window=None, kv_off=0, bd=None,
+                 same=None):
     """Exact softmax probabilities of one (block_q, block_k) tile from
     the saved logsumexp (the dQ kernel's; the dK/dV kernels compute the
     same tile transposed).  Masked entries are zeroed EXPLICITLY (not via
     the lse sentinel), so padded rows and wholly-out-of-window rows stay
-    inert whatever their lse."""
+    inert whatever their lse.  ``same``: the tile's ``_same_document``, where
+    the call took documents."""
     s = jax.lax.dot_general(
         q_blk.astype(jnp.float32) * sm_scale, k_blk.astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
@@ -756,12 +831,14 @@ def _recompute_p(q_blk, k_blk, lse_blk, q_off, k_off, *, sm_scale, causal,
         _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
         q_pos < seq_len,
     )
+    if same is not None:
+        mask = jnp.logical_and(mask, same)
     return jnp.where(mask, jnp.exp(s - lse_blk[:, None]), 0.0)
 
 
 def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_s, *, sm_scale, causal, block_q,
-                   block_k, seq_len, window=None, bd=None):
+                   block_k, seq_len, window=None, bd=None, docs=None):
     """dQ of the ``dq_s.shape[0]`` consecutive query tiles of one head that a
     program holds, walked as one sequence of tile visits as the forward's
     (``_run_query_tiles``); each tile's sum in VMEM scratch at its number."""
@@ -781,6 +858,8 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             first + j * block_q, k_off, sm_scale=sm_scale, causal=causal,
             seq_len=seq_len, block_q=block_q, block_k=block_k,
             window=window, kv_off=kv_off, bd=bd,
+            same=None if docs is None else _same_document(
+                docs, j * block_q, block_q, k_off, block_k),
         )
         dp = jax.lax.dot_general(
             do, v_blk.astype(jnp.float32),
@@ -806,7 +885,7 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
               s_q, *, sm_scale, causal, block_q, block_k, seq_len, window,
-              bd=None):
+              bd=None, docs=None):
     """One tile visit of the dK/dV kernels, as a body for
     ``_run_group_tiles``: query head ``g`` of those the program holds has its
     ``s_q`` rows at ``g * s_q`` of the q-side blocks; ``(dk, dv)`` is the
@@ -814,7 +893,8 @@ def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
     ``p^T`` and ``dS^T`` are what the two sums take, and computing ``p``
     first meant transposing two (block_q, block_k) float32 tiles a visit.
     ``bd`` chooses the mask (``_bd_tile_mask`` on the transposed tile; else
-    ``_tile_mask``)."""
+    ``_tile_mask``, and with ``docs`` ``_same_document``: the key tile's ids on
+    the rows, a sequence's query ids, alike for its heads, on the lanes)."""
 
     def body(g, qb, carry):
         dk, dv = carry
@@ -840,6 +920,9 @@ def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
                 _tile_mask(q_pos, k_pos, causal, window, seq_len, kv_off),
                 q_pos < seq_len,
             )
+            if docs is not None:
+                mask = jnp.logical_and(mask, _same_document(
+                    docs, 0, block_k, q_off, block_q))
         else:
             mask = _bd_tile_mask(k_off, q_off, block_k, block_q, seq_len, bd,
                                  False)
@@ -867,7 +950,8 @@ def _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off, kv_off,
 
 def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                    block_q, block_k, seq_len, window=None, bd=None, group=1):
+                    block_q, block_k, seq_len, window=None, bd=None, group=1,
+                    docs=None):
     """dK/dV for ONE kv head's K block: the q-side operands arrive with
     the whole query-head group concatenated on the row axis
     ((1, group*s_q, d) blocks, constant along the key-tile axis and so
@@ -890,7 +974,7 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     body = _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off,
                      kv_off, s_q, sm_scale=sm_scale, causal=causal,
                      block_q=block_q, block_k=block_k, seq_len=seq_len,
-                     window=window, bd=bd)
+                     window=window, bd=bd, docs=docs)
     dk, dv = _run_group_tiles(
         ranges, group, body, (jnp.zeros((block_k, d), jnp.float32),
                               jnp.zeros((block_k, dv), jnp.float32)))
@@ -901,7 +985,7 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                          sm_scale, causal, block_q, block_k, seq_len,
-                         window=None, bd=None, group=1):
+                         window=None, bd=None, group=1, docs=None):
     """``_bwd_dkv_kernel`` with ONE query head of the group a program: the
     grid's last axis walks the group and the (block_k, d) sums live in VMEM
     scratch across it, so a program fetches that head's whole q and dO anew
@@ -923,7 +1007,7 @@ def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         q_ref, do_ref, lse_ref, delta_ref, k_ref[0].astype(jnp.float32),
         v_ref[0].astype(jnp.float32), k_off, kv_off, s_q, sm_scale=sm_scale,
         causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
-        window=window, bd=bd)
+        window=window, bd=bd, docs=docs)
     dk_acc[...], dv_acc[...] = _run_group_tiles(
         _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
                      causal=causal, window=window, kv_off=kv_off, bd=bd,
@@ -946,7 +1030,7 @@ def _dkv_heads_a_program(group, s_q, d, dv, itemsize):
 
 def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
                      block_q, block_k, interpret, window=None,
-                     kv_offset=None, bd=None):
+                     kv_offset=None, bd=None, documents=None):
     """Backward kernels over already folded+padded operands — the ring
     calls this directly so the fold/pad of the step-invariant q/g/lse/
     delta happens once, not once per ring step.  Shapes: qf/gf
@@ -954,7 +1038,9 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     lse_f/delta_f (B*H, s_q, 1).  Returns folded (dq, dk, dv) with
     dk/dv per KV head.  ``vf`` and ``gf`` may be of another width than
     ``qf`` and ``kf`` (latent attention), under the causal mask only; the
-    scale is that of the query-key width."""
+    scale is that of the query-key width.  ``documents``: a packed row's ids
+    (B, S) of self-attention, laid out for each kernel's tiles here
+    (``_document_ids``)."""
     bh, s_q, d = qf.shape
     dv_w = vf.shape[-1]   # the values' width: that of gf and of dv too
     bh_kv = kf.shape[0]
@@ -970,13 +1056,15 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
               block_k=block_k, seq_len=orig_s, window=window)
     mask = dict(s_q=s_q, s_k=s_k, block_q=block_q, block_k=block_k,
                 seq_len=orig_s, causal=causal, window=window, bd=bd)
-    shape = dict(d_qk=d, d_v=dv_w, **mask)
+    shape = dict(d_qk=d, d_v=dv_w, documents=documents is not None, **mask)
     tiles = _query_tiles_a_program(kv_off=kv_offset, **mask)
     _note_tiles({"flash_attention_bwd_dq": "bwd_dq"}, kv_offset,
                 query_tiles_a_program=tiles, **shape)
     q_rows = tiles * block_q  # a program's: its query tiles, walked as one
+    dq_kernel, ids, id_specs, id_bytes = _document_call(
+        _bwd_dq_kernel, 7, documents, bh, q_rows, s_q, s_k, bd=bd, **kw)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, bd=bd, **kw),
+        dq_kernel,
         name="flash_attention_bwd_dq",
         grid=(bh, s_q // q_rows),
         in_specs=[
@@ -987,14 +1075,14 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             pl.BlockSpec((1, q_rows, dv_w), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, q_rows, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, q_rows, 1), lambda bh, qi: (bh, qi, 0)),
-        ],
+        ] + id_specs,
         out_specs=pl.BlockSpec((1, q_rows, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qf.dtype),
         scratch_shapes=[_pltpu.VMEM((tiles, block_q, d), jnp.float32)],
         interpret=interpret,
-        **_kv_params(s_k, d, dv_w, kf.dtype, _query_side_bytes(
+        **_kv_params(s_k, d, dv_w, kf.dtype, id_bytes + _query_side_bytes(
             tiles, block_q, d, dv_w, qf.dtype.itemsize)),
-    )(off, qf, kf, vf, gf, lse_f, delta_f)
+    )(off, qf, kf, vf, gf, lse_f, delta_f, *ids)
     heads, head_bytes = _dkv_heads_a_program(
         group, s_q, d, dv_w, qf.dtype.itemsize)
     if heads == group:
@@ -1018,6 +1106,11 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     rows = heads * s_q
     _note_tiles({"flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
                  "bwd_dkv"}, kv_offset, heads_a_program=heads, **shape)
+    # with ids: the key tile's on the tile's rows, the sequence's query ids,
+    # alike for every head of the group, on its lanes
+    kernel, ids, id_specs, id_bytes = _document_call(
+        kernel, 7, documents, bh_kv, block_k, s_k, s_q, group=group, bd=bd,
+        **kw)
     call = dict(
         grid=grid,
         in_specs=[
@@ -1028,7 +1121,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
             pl.BlockSpec((1, rows, dv_w), q_at),
             pl.BlockSpec((1, 1, rows), q_at),
             pl.BlockSpec((1, 1, rows), q_at),
-        ],
+        ] + id_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), kv_at),
             pl.BlockSpec((1, block_k, dv_w), kv_at),
@@ -1044,9 +1137,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
         # at 10 MiB resident (192 / 128 at 8,192 rows) is past the
         # compiler's own 16 MiB
         compiler_params=_pltpu.CompilerParams(
-            vmem_limit_bytes=heads * head_bytes + _VMEM_HEADROOM),
+            vmem_limit_bytes=heads * head_bytes + _VMEM_HEADROOM + id_bytes),
     )
-    kernel = functools.partial(kernel, group=group, bd=bd, **kw)
     # one name a mask kind, whichever form (the traces read it)
     if bd is None:
         dkv = pl.pallas_call(kernel, name="flash_attention_bwd_dkv", **call)
@@ -1055,7 +1147,7 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     dk, dv = dkv(off, qf.reshape(bh // heads, rows, d), kf, vf,
                  gf.reshape(bh // heads, rows, dv_w),
                  lse_f.reshape(bh // heads, 1, rows),
-                 delta_f.reshape(bh // heads, 1, rows))
+                 delta_f.reshape(bh // heads, 1, rows), *ids)
     return dq, dk, dv
 
 
@@ -1076,7 +1168,7 @@ def _fold_bwd_invariants(q, out, lse, g, block_q):
 
 
 def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
-                   interpret, window=None, bd=None):
+                   interpret, window=None, bd=None, documents=None):
     b, s, h, d = q.shape
     h_kv, dv_w = k.shape[2], v.shape[-1]
     orig_s = s
@@ -1091,7 +1183,7 @@ def _backward_impl(q, k, v, out, lse, g, causal, block_q, block_k,
     dq, dk, dv = _backward_folded(
         qf, kf, vf, gf, lse_f, delta_f, orig_s=orig_s, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window, bd=bd,
+        window=window, bd=bd, documents=documents,
     )
     dq = _unfold(dq, b, h, s_q, d)[:, :orig_s]
     dk = _unfold(dk, b, h_kv, s_k, d)[:, :orig_s]
@@ -1274,18 +1366,20 @@ def flash_decode_attention(q, k, v, kv_lens, *, window=None, kv_start=None,
         block_q=block_q, block_k=block_k, interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, block_q, block_k, interpret, window, bd=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, documents, causal, block_q, block_k, interpret, window,
+           bd=None):
     return _forward_impl(q, k, v, causal, block_q, block_k, interpret,
-                         window=window, bd=bd)
+                         window=window, bd=bd, documents=documents)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window, bd):
+def _flash_fwd(q, k, v, documents, causal, block_q, block_k, interpret,
+               window, bd):
     out, lse = _forward_impl(
         q, k, v, causal, block_q, block_k, interpret, with_lse=True,
-        window=window, bd=bd,
+        window=window, bd=bd, documents=documents,
     )
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, documents, out, lse)
 
 
 def _flash_bwd(causal, block_q, block_k, interpret, window, bd, residuals,
@@ -1294,11 +1388,12 @@ def _flash_bwd(causal, block_q, block_k, interpret, window, bd, residuals,
     # recompute the probability tiles from the forward's saved logsumexp
     # — no (S x S) materialization, so training keeps the memory win too.
     # causal_dot_attention is the numerics oracle in the tests.
-    q, k, v, out, lse = residuals
-    return _backward_impl(
+    q, k, v, documents, out, lse = residuals
+    # the ids are data and have no gradient (None: none, with ids or without)
+    return (*_backward_impl(
         q, k, v, out, lse, g, causal, block_q, block_k, interpret,
-        window=window, bd=bd,
-    )
+        window=window, bd=bd, documents=documents,
+    ), None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1319,6 +1414,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     block_diffusion: Optional[tuple] = None,
+    documents: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention over (B, S, H, D) tensors (same layout and
     numerics contract as ``models.transformer.causal_dot_attention``:
@@ -1350,6 +1446,15 @@ def flash_attention(
     clean]`` in blocks of ``B``; takes the place of ``causal`` and
     ``window``.  The three kernels visit only the tiles the mask reaches
     (``_bd_ranges``): about ``L^2 + L B`` of the ``4 L^2`` entries.
+
+    ``documents``: a packed row's document ids, (B, S) integers, DATA of the
+    call (one compiled program serves every layout of the same shapes): a
+    query sees a key only of its own document, under ``causal`` and
+    ``window`` as they are.  Any ids do: two positions are of one document
+    where their ids are equal.  All three kernels take them
+    (``_document_ids``, ``_same_document``); every tile the other masks
+    reach is still visited.  Keys and values of one width only, and no
+    ``block_diffusion``; the ring and the serving kernels take none.
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
@@ -1364,8 +1469,24 @@ def flash_attention(
             "attention) take no window and no block_diffusion: those "
             "kernels are not widened")
     _group_of(q, k)  # validate the GQA head split early
+    if documents is not None:
+        if k.shape[-1] != v.shape[-1]:
+            raise ValueError(
+                f"keys {k.shape[-1]} wide and values {v.shape[-1]} wide (latent "
+                "attention) take no documents: those kernels are not widened")
+        if block_diffusion is not None:
+            raise ValueError(
+                "block_diffusion takes no documents: its mask is of one "
+                "document's [noisy || clean] rows")
+        if (documents.shape != q.shape[:2] or k.shape[1] != q.shape[1]
+                or not jnp.issubdtype(documents.dtype, jnp.integer)):
+            raise ValueError(
+                f"documents are a row's integer ids, one a position of q and "
+                f"k alike {q.shape[:2]}, got {documents.dtype}"
+                f"{documents.shape} and {k.shape[1]} keys")
     if block_diffusion is None:
-        return _flash(q, k, v, causal, block_q, block_k, interpret, window)
+        return _flash(q, k, v, documents, causal, block_q, block_k, interpret,
+                      window)
     half, blk = (int(x) for x in block_diffusion)
     if window is not None:
         raise ValueError("block_diffusion takes no window")
@@ -1373,5 +1494,5 @@ def flash_attention(
         raise ValueError(
             f"block_diffusion=(L, B) needs L, B >= 1 and 2 L = {2 * half} "
             f"rows of queries and keys, got {q.shape[1]} and {k.shape[1]}")
-    return _flash(q, k, v, False, block_q, block_k, interpret, None,
+    return _flash(q, k, v, None, False, block_q, block_k, interpret, None,
                   (half, blk))

@@ -107,7 +107,8 @@ SITES = (
     "guard.exchange",      # cross-rank digest/vote exchange (cadence)
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
-    "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths
+    "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths,
+                           # whether the call took document ids
     "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring,
                            # the grouped products' tiles and row-tile visits
     "gdn.chunks",          # the gated delta rule traced: rows, value and key heads,
@@ -119,7 +120,8 @@ SITES = (
                            # value heads, row tile, programs, bytes
     "attn.layers",         # a model whose attention differs by layer traced: each
                            # layer's kind, heads, key/value heads, window, rotary
-                           # columns and RoPE type
+                           # columns and RoPE type, and whether it takes the
+                           # call's document ids
     "rope.rotate",         # a layer's rotary step of q and k traced: rows, heads,
                            # key/value heads, head width, rotated columns, RoPE type,
                            # whether the kernel pair engaged, its row tile, programs
@@ -144,8 +146,8 @@ DEVICE_SCOPES = (
 #: their time beside the phases' (``subscopes``), and the benchmark's
 #: ``router_ms`` / ``expert_ffn_ms`` / ``mla_proj_ms`` / ``shared_expert_ms``
 #: / ``gdn_proj_ms`` / ``gated_delta_ms`` / ``window_attention_ms`` /
-#: ``full_attention_ms`` / ``attn_rope_ms`` / ``attn_gate_ms`` read them by
-#: ``op_name`` pattern.
+#: ``full_attention_ms`` / ``attn_rope_ms`` / ``attn_gate_ms`` /
+#: ``attn_docmask_ms`` read them by ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
@@ -185,6 +187,14 @@ DEVICE_SUBSCOPES = (
                     # names with a sliding layer's are told apart by this
     "attn_gate",    # the gate a head: the stream times (d_model, heads),
                     # sigmoid, times the attention's output
+    # models/transformer.py Transformer and ops/flash_attention.py, only in a
+    # call that took document ids with its tokens (a packed row)
+    "attn_docmask",  # what the ids become outside the flash kernels: the
+                     # positions that restart at each document and the
+                     # documents counted, once a model call; the ids laid out
+                     # for the kernels' tiles (a position's id on 128 lanes,
+                     # the ids along the lanes), a kernel call.  The mask
+                     # itself is inside the kernels and in their time
 )
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of

@@ -256,6 +256,35 @@ def test_flash_block_diffusion_beyond_the_group_s_bytes_is_one_head_a_program(on
     assert operands[5] == operands[6] == "f32[16,1,8192]"
 
 
+def test_flash_with_document_ids_compiles_at_the_cell_s_shapes(one_chip):
+    """The three kernels with a packed row's ids under the 1,024 window at the
+    shape of mellum2-12b-a2.5b-pack8192-1chip's sliding layers (1 x 8,192, 32
+    query heads over 4 of 128; PR 45), forward and backward in one program: each
+    call takes the ids laid out for its tiles (a position's id on 128 lanes for
+    the side on a tile's rows, the ids along the lanes for the other), and the
+    dK/dV call holds the whole group of 8 and states its VMEM with theirs (64 +
+    16 MiB + 0.75 MiB)."""
+    q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, 4, 128), jnp.bfloat16, one_chip)
+    ids = _sds((1, 8192), jnp.int32, one_chip)
+
+    def loss(q, k, v, ids):
+        return jnp.sum(flash_attention(q, k, v, window=1024, documents=ids,
+                                       interpret=False).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, ids).as_text()
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r"%(flash_attention\w*?)\.\d+ = [^\n]*tpu_custom_call[^\n]*"
+        r"operand_layout_constraints=\{(.*?)\}, frontend", text)}
+    assert sorted(calls) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                             "flash_attention_fwd"]
+    for name, operands in calls.items():
+        assert operands.endswith("s32[1,8192,128]{2,1,0}, s32[1,1,8192]{2,1,0}"), (name, operands)
+    assert "bf16[4,65536,128]" in calls["flash_attention_bwd_dkv"]      # the group's rows
+    (dkv,) = [l for l in text.splitlines() if re.search(r"%flash_attention_bwd_dkv\.\d+ = ", l)]
+    assert f'"size":"{(64 + 16) * 2 ** 20 + 2 * 4 * (256 * 128 + 8 * 8192)}"' in dkv
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_256_wide_heads_compile_at_the_cell_s_shapes(one_chip, backward):
     """qwen3-next-80b-a3b-s8192-1chip: one sequence of 8,192 positions, 16

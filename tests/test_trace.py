@@ -1314,7 +1314,7 @@ def test_subscope_catalogue_matches_the_code_and_names_no_phase():
     assert tuple(trace_sites.catalogue(repo, "DEVICE_SUBSCOPES")) == \
         trace.DEVICE_SUBSCOPES == ("router", "experts", "mla", "shared_experts",
                                    "gdn", "gated_delta", "attn_rope", "attn_window",
-                                   "attn_full", "attn_gate")
+                                   "attn_full", "attn_gate", "attn_docmask")
     assert "flash_attention_bwd_dkv_bd" in trace.DEVICE_KERNELS
     assert not set(trace.DEVICE_SUBSCOPES) & set(trace.DEVICE_SCOPES)
     path = "jit(_step)/shard_map/transpose(jvp(forward))/T/layer_0/moe/experts/x"
