@@ -77,8 +77,14 @@ Rows packed of several documents: all three kernels (both dK/dV forms) take
 a row's document ids as DATA beside the causal mask and the window
 (``flash_attention(..., documents=)``) and keep a query to the keys of its
 own document.  The ids are laid out outside the kernels so that a tile's
-compare moves no lane (``_document_ids``, ``_same_document``); every tile the
-other masks reach is still visited; a call without ids is the call it was.
+compare moves no lane (``_document_ids``, ``_same_document``).  The tiles no
+document reaches are skipped: once a call, on the device, the ids become each
+tile's first and one-past-last tile of the other side that can hold one of its
+documents (``_document_bounds``: safe for any ids, exact for a packed row),
+small int32 arrays in SMEM beside ``kv_offset``, and every program joins its
+own with the static mask's range (``_tile_ranges(..., doc=)``): the same
+walk under tighter bounds, the same sums bit for bit (a tile in which
+everything is masked added zeros).  A call without ids is the call it was.
 
 All three kernels also take a traced ``kv_offset`` scalar (SMEM): the
 global position of the K block's first key minus the global position of
@@ -293,27 +299,33 @@ def _bd_ranges(off, rows, other_block, n_other, seq_len, bd, rows_are_queries,
 
 
 def _tile_ranges(off, rows, other_block, n_other, seq_len, *, causal, window,
-                 kv_off, bd, rows_are_queries, xp=jnp):
+                 kv_off, bd, rows_are_queries, doc=None, xp=jnp):
     """One program's loop bounds, whatever the mask kind and the side: the
     tile of ``rows`` positions at ``off`` (queries in the forward and dQ
     kernels, keys in the dK/dV kernels) against the other side's tiles of
     ``other_block`` positions, as half-open tile ranges visited in rising
-    order."""
+    order.  ``doc``: in a call that took document ids, the tile's own
+    ``(first, one past last)`` tile of the other side that can hold one of its
+    documents (``_document_bounds``), joined with the one range a causal or
+    window mask has."""
     if bd is not None:
         return _bd_ranges(off, rows, other_block, n_other, seq_len, bd,
                           rows_are_queries, xp)
     if rows_are_queries:
-        return (_kb_range(off, rows, other_block, n_other, causal, window,
-                          kv_off, xp),)
-    # Which Q blocks can see this K block = _kb_range with the q/k roles
-    # transposed (the offset flips sign, the window reach is symmetric).
-    # Causality is NOT symmetric: it becomes a LOWER bound here (the
-    # first Q block at or after the shifted diagonal), joined by max.
-    lo, hi = _kb_range(off, rows, other_block, n_other, False, window,
-                       -kv_off, xp)
-    if causal:
-        lo = xp.maximum(lo, xp.maximum(
-            0, xp.floor_divide(off + kv_off, other_block)))
+        lo, hi = _kb_range(off, rows, other_block, n_other, causal, window,
+                           kv_off, xp)
+    else:
+        # Which Q blocks can see this K block = _kb_range with the q/k roles
+        # transposed (the offset flips sign, the window reach is symmetric).
+        # Causality is NOT symmetric: it becomes a LOWER bound here (the
+        # first Q block at or after the shifted diagonal), joined by max.
+        lo, hi = _kb_range(off, rows, other_block, n_other, False, window,
+                           -kv_off, xp)
+        if causal:
+            lo = xp.maximum(lo, xp.maximum(
+                0, xp.floor_divide(off + kv_off, other_block)))
+    if doc is not None:
+        lo, hi = xp.maximum(lo, doc[0]), xp.minimum(hi, doc[1])
     return ((lo, hi),)
 
 
@@ -337,12 +349,17 @@ def _walk(total, visit, state):
     return state
 
 
-def _query_tiles_ranges(first, tiles, block_q, block_k, n_k, seq_len, **mask):
+def _query_tiles_ranges(first, tiles, block_q, block_k, n_k, seq_len,
+                        docs=None, **mask):
     """``_run_query_tiles``' ranges for the ``tiles`` consecutive query tiles
     of ``block_q`` rows from row ``first`` on: each tile's own
-    (``_tile_ranges`` at its offset)."""
-    return [_tile_ranges(first + j * block_q, block_q, block_k, n_k, seq_len,
-                         rows_are_queries=True, **mask) for j in range(tiles)]
+    (``_tile_ranges`` at its offset, and with ``docs`` under its own document
+    bounds: tile ``first // block_q + j`` of the program's sequence)."""
+    return [_tile_ranges(
+        first + j * block_q, block_q, block_k, n_k, seq_len,
+        rows_are_queries=True,
+        doc=None if docs is None else docs[2](first // block_q + j), **mask)
+        for j in range(tiles)]
 
 
 def _run_query_tiles(ranges, body, carry):
@@ -408,17 +425,15 @@ def _run_group_tiles(ranges, group, body, carry):
     return _walk(group * n, visit, (jnp.int32(0), jnp.int32(0), carry))[2]
 
 
-def _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal=True,
-                       window=None, kv_off=0, bd=None):
-    """The tile visits of each query tile of one head, an array of
-    ``s_q // block_q``: the kernels' own bounds (``_tile_ranges`` on numpy)."""
-    tiles = s_q // block_q
-    ranges = _tile_ranges(
-        np.arange(tiles) * block_q, block_q, block_k, s_k // block_k, seq_len,
-        causal=causal, window=window, kv_off=kv_off, bd=bd,
-        rows_are_queries=True, xp=np)
-    return sum(np.broadcast_to(np.maximum(hi - lo, 0), (tiles,))
-               for lo, hi in ranges)
+def _tile_visits(tiles, block, other_block, n_other, seq_len, **ranges):
+    """The visits of each of one head's ``tiles`` tiles of ``block`` positions
+    to the other side's tiles, an array of ``tiles`` (of ``(rows, tiles)``
+    under ``doc`` bounds of several rows): the kernels' own bounds
+    (``_tile_ranges`` on numpy)."""
+    return sum(np.maximum(hi - lo, 0) + np.zeros(tiles, np.int64)
+               for lo, hi in _tile_ranges(
+                   np.arange(tiles) * block, block, other_block, n_other,
+                   seq_len, xp=np, **ranges))
 
 
 def _query_side_bytes(tiles, block_q, d, dv, itemsize):
@@ -440,13 +455,15 @@ def _query_tiles_a_program(s_q, s_k, block_q, block_k, seq_len, causal=True,
     that gives a program ``_VISITS_A_PROGRAM`` tile visits at the mask's mean
     visits a query tile, at most ``_QUERY_TILES_MOST``, and a divisor of the
     head's query tiles (no operand is padded for it).  The one place that
-    decides it, from the call's shapes and mask alone; a traced ``kv_off`` (a
+    decides it, from the call's shapes and mask alone (document ids are data:
+    they cut a program's walk and do not move this); a traced ``kv_off`` (a
     ring step) counts as 0."""
     if not isinstance(kv_off, int):
         kv_off = 0
     tiles = s_q // block_q
-    visits = _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal,
-                                window, kv_off, bd).mean()
+    visits = _tile_visits(
+        tiles, block_q, block_k, s_k // block_k, seq_len, causal=causal,
+        window=window, kv_off=kv_off, bd=bd, rows_are_queries=True).mean()
     held = 1
     while (held < _QUERY_TILES_MOST and tiles % (2 * held) == 0
            and held * visits < _VISITS_A_PROGRAM):
@@ -456,7 +473,7 @@ def _query_tiles_a_program(s_q, s_k, block_q, block_k, seq_len, causal=True,
 
 def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
                 window=None, kv_off=0, bd=None, heads_a_program=1,
-                query_tiles_a_program=1):
+                query_tiles_a_program=1, documents=None):
     """Tile visits and the loop iterations they take, by kernel: ``{"fwd":
     (visited, iterations), "bwd_dq": ..., "bwd_dkv": ...}`` for padded
     lengths ``s_q``, ``s_k`` in tiles of ``block_q`` x ``block_k``: one query
@@ -468,11 +485,21 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
     (``_run_group_tiles``).  Host arithmetic on the kernels' own bounds
     (``_tile_ranges`` on numpy) and the loops' steps: where ``visited /
     iterations`` is near 1 (a sequence of two tiles) walking several tiles an
-    iteration wins nothing."""
-    by_keys = _tile_ranges(
-        np.arange(s_k // block_k) * block_k, block_k, block_q,
-        s_q // block_q, seq_len, causal=causal, window=window, kv_off=kv_off,
-        bd=bd, rows_are_queries=False, xp=np)
+    iteration wins nothing.  ``documents``: concrete ids (numpy) of the
+    ``seq_len`` positions of one packed row, or of several rows ``(B,
+    seq_len)`` whose counts add up: the visits the kernels make of a call that
+    takes these ids (``_document_bounds`` on numpy, joined as the kernels join
+    them); without, the static masks' own, which bound them."""
+    doc_q = doc_k = None
+    if documents is not None:
+        doc_q, doc_k = _document_bounds(
+            np.atleast_2d(np.asarray(documents)), block_q, block_k, xp=np)
+    mask = dict(causal=causal, window=window, kv_off=kv_off, bd=bd)
+    n_q, n_k = s_q // block_q, s_k // block_k
+    by_query = _tile_visits(n_q, block_q, block_k, n_k, seq_len, doc=doc_q,
+                            rows_are_queries=True, **mask)
+    by_key = _tile_visits(n_k, block_k, block_q, n_q, seq_len, doc=doc_k,
+                          rows_are_queries=False, **mask)
 
     def count(walks):
         """``walks``: the visits of each program's one walk."""
@@ -482,14 +509,8 @@ def tile_counts(s_q, s_k, block_q, block_k, seq_len, causal=True,
             walks = walks % n
         return visited, iterations
 
-    fwd = count(
-        _query_tile_visits(s_q, s_k, block_q, block_k, seq_len, causal,
-                           window, kv_off, bd).reshape(
-                               -1, query_tiles_a_program).sum(axis=1))
-    dkv = count(
-        heads_a_program * sum(
-            np.broadcast_to(np.maximum(hi - lo, 0), (s_k // block_k,))
-            for lo, hi in by_keys))
+    fwd = count(by_query.reshape(-1, query_tiles_a_program).sum(axis=1))
+    dkv = count(heads_a_program * by_key.reshape(-1))
     return {"fwd": fwd, "bwd_dq": fwd, "bwd_dkv": dkv}
 
 
@@ -501,8 +522,10 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, documents=False, **shape):
     program holds, the whole group or 1; either is given beside them), the
     ``window`` of its mask (None: none) and the widths of a tile's products
     (``d_qk`` of queries and keys, ``d_v`` of values) and whether the call took
-    ``documents`` (the tiles visited are the other masks' all the same: no tile
-    is skipped by document).
+    ``documents``.  A call with ids cuts every program's walk to the tiles its
+    documents reach, on the device, from data no trace sees: its event's counts
+    are the other masks' own, ``at_most`` says that they only bound the visits
+    made, and ``tile_counts(..., documents=ids)`` gives a layout's.
     ``kernels`` maps a kernel's name to its key in ``tile_counts``.  Host
     bookkeeping at trace time; a traced ``kv_offset`` (a ring step) has no
     count to give."""
@@ -517,7 +540,8 @@ def _note_tiles(kernels, kv_offset, d_qk, d_v, documents=False, **shape):
         visited, iterations = counts[key]
         _trace.event("flash.tiles", kernel=name, visited=visited,
                      iterations=iterations, d_qk=d_qk, d_v=d_v,
-                     window=shape.get("window"), documents=documents, **held)
+                     window=shape.get("window"), documents=documents,
+                     **({"at_most": True} if documents else {}), **held)
 
 
 _LANES = 128
@@ -549,6 +573,56 @@ def _document_ids(documents, n_rows, n_lanes):
         return on_rows, pad(n_lanes)[:, None, :]
 
 
+def _document_bounds(documents, block_q, block_k, xp=jnp):
+    """The tiles a packed row's ids (B, S) let meet, as loop bounds: ``((lo,
+    hi) of each query tile over the key tiles, (lo, hi) of each key tile over
+    the query tiles)``, int32 ``(B, tiles)`` each, half-open.  A tile's
+    documents lie between its smallest and its largest id; two tiles can hold
+    a pair of equal ids only where those two intervals overlap, and a tile's
+    bound is the first and one past the last tile of the other side whose
+    interval overlaps its own.  SAFE for any ids (equal ids in two tiles put
+    each tile's interval over the other's, and a range from the first such tile
+    to the last leaves none out; a tile it keeps for nothing is masked inside
+    as before) and exact for a packed row (ids that rise run by run: the tiles
+    between a tile's first document's start and its last document's end).  The
+    last tile's padding counts as its last position's document.  At square
+    tiles the two sides' bounds are one pair.  ``xp=numpy``: the same on the
+    host (``tile_counts``)."""
+    rows, s = documents.shape
+
+    def spans(block):
+        ids = xp.pad(documents, ((0, 0), (0, (-s) % block)), mode="edge")
+        ids = ids.reshape(rows, -1, block)
+        return ids.min(axis=-1), ids.max(axis=-1)
+
+    (q_min, q_max), (k_min, k_max) = spans(block_q), spans(block_k)
+    meet = xp.logical_and(q_min[:, :, None] <= k_max[:, None, :],
+                          k_min[:, None, :] <= q_max[:, :, None])
+
+    def ends(meet):
+        n = meet.shape[-1]
+        some = meet.any(axis=-1)
+        lo = xp.argmax(meet, axis=-1)
+        hi = n - xp.argmax(meet[..., ::-1], axis=-1)
+        return (xp.where(some, lo, 0).astype(xp.int32),
+                xp.where(some, hi, 0).astype(xp.int32))
+
+    by_query = ends(meet)
+    if block_q == block_k:  # the intervals meet or do not, whichever side asks
+        return by_query, by_query
+    return by_query, ends(xp.swapaxes(meet, 1, 2))
+
+
+def _document_operands(documents, block_q, block_k):
+    """What a call's kernels take of a packed row's ids (B, S), made once a
+    call under the ``attn_docmask`` scope: ``(ids, bounds by query tile, bounds
+    by key tile)`` (``_document_bounds`` at the call's tiles: the forward and
+    dQ kernels walk under the first, both dK/dV forms under the second)."""
+    with jax.named_scope("attn_docmask"):
+        ids = jnp.asarray(documents, jnp.int32)
+        return (ids, *_document_bounds(ids, block_q, block_k))
+
+
 def _document_bytes(rows, lanes):
     """What a program holds in VMEM of ``_document_ids``' two blocks, twice
     buffered: ``rows`` positions on 128 lanes, ``lanes`` on 8 sublanes."""
@@ -560,34 +634,42 @@ def _same_document(docs, row_off, rows, col_off, cols):
     one document.  ``docs``: the refs of ``_document_ids``' two operands, the
     first a block of the side on the tile's rows (``row_off`` inside it), the
     second the whole other side."""
-    on_rows, on_lanes = docs
+    on_rows, on_lanes = docs[:2]
     r = on_rows[0, pl.ds(row_off, rows), :]      # (rows, 128)
     c = on_lanes[0, :, pl.ds(col_off, cols)]     # (1, cols)
     return _lanes(r, cols) == c
 
 
-def _document_call(kernel, n_in, documents, folded, block_rows, n_rows,
+def _document_call(kernel, n_in, documents, bounds, folded, block_rows, n_rows,
                    n_lanes, **static):
     """What a packed row's ids add to a kernel's call, as ``(kernel, operands,
-    block specs, VMEM bytes)``: ``_document_ids``' two operands after the
-    call's ``n_in`` inputs, which reach ``kernel`` as ``docs`` (the side on a
-    tile's rows ``block_rows`` a program along the grid's second axis, the
-    other side whole; a sequence's for all its heads along the first, whose
-    ``folded`` entries are sequences x heads).  Without ``documents`` the
-    kernel with its ``static`` arguments and nothing else: the call it was,
-    refs and all."""
+    block specs, VMEM bytes)``: four operands after the call's ``n_in``
+    inputs, which reach ``kernel`` as ``docs``: ``_document_ids``' two (the
+    side on a tile's rows ``block_rows`` a program along the grid's second
+    axis, the other side whole; a sequence's for all its heads along the first,
+    whose ``folded`` entries are sequences x heads) and, in SMEM beside
+    ``kv_offset``, the ``bounds`` ``(lo, hi)`` of that side's tiles
+    (``_document_bounds``), as a function of a tile's number that reads the
+    program's sequence's pair.  Without ``documents`` the kernel with its
+    ``static`` arguments and nothing else: the call it was, refs and all."""
     if documents is None:
         return functools.partial(kernel, **static), (), [], 0
     heads = folded // documents.shape[0]
 
     def with_documents(*refs):
-        return kernel(*refs[:n_in], *refs[n_in + 2:],
-                      docs=refs[n_in:n_in + 2], **static)
+        on_rows, on_lanes, lo, hi = refs[n_in:n_in + 4]
+        row = pl.program_id(0) // heads
+        return kernel(
+            *refs[:n_in], *refs[n_in + 4:],
+            docs=(on_rows, on_lanes, lambda tile: (lo[row, tile], hi[row, tile])),
+            **static)
 
     specs = [pl.BlockSpec((1, block_rows, _LANES),
                           lambda b, i, *_: (b // heads, i, 0)),
-             pl.BlockSpec((1, 1, n_lanes), lambda b, i, *_: (b // heads, 0, 0))]
-    return (with_documents, _document_ids(documents, n_rows, n_lanes), specs,
+             pl.BlockSpec((1, 1, n_lanes), lambda b, i, *_: (b // heads, 0, 0)),
+             _SCALAR_SPEC, _SCALAR_SPEC]
+    return (with_documents,
+            (*_document_ids(documents, n_rows, n_lanes), *bounds), specs,
             _document_bytes(block_rows, n_lanes))
 
 
@@ -660,8 +742,9 @@ def _fwd_kernel(kvoff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s,
 
     _run_query_tiles(
         _query_tiles_ranges(first, tiles, block_q, block_k,
-                            k_ref.shape[1] // block_k, seq_len, causal=causal,
-                            window=window, kv_off=kv_off, bd=bd), body, ())
+                            k_ref.shape[1] // block_k, seq_len, docs,
+                            causal=causal, window=window, kv_off=kv_off,
+                            bd=bd), body, ())
     for j in range(tiles):
         rows = slice(j * block_q, (j + 1) * block_q)
         l, m = l_s[j], m_s[j]
@@ -764,9 +847,11 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret,
                 documents=documents is not None,
                 query_tiles_a_program=tiles, **shape)
     rows = tiles * block_q  # a program's: its query tiles, walked as one
-    # with ids: the queries' a program's rows, the keys' whole
+    # with ids: the queries' a program's rows, the keys' whole, and each query
+    # tile's bounds over the key tiles
+    doc_ids, by_query, _ = documents or (None,) * 3
     kernel, ids, id_specs, id_bytes = _document_call(
-        _fwd_kernel, 4, documents, b * h, rows, s_q, s_k, **static)
+        _fwd_kernel, 4, doc_ids, by_query, b * h, rows, s_q, s_k, **static)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
@@ -876,8 +961,9 @@ def _bwd_dq_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     _run_query_tiles(
         _query_tiles_ranges(first, tiles, block_q, block_k,
-                            k_ref.shape[1] // block_k, seq_len, causal=causal,
-                            window=window, kv_off=kv_off, bd=bd), body, ())
+                            k_ref.shape[1] // block_k, seq_len, docs,
+                            causal=causal, window=window, kv_off=kv_off,
+                            bd=bd), body, ())
     for j in range(tiles):
         dq_ref[0, j * block_q:(j + 1) * block_q, :] = (
             dq_s[j] * sm_scale).astype(dq_ref.dtype)
@@ -969,7 +1055,8 @@ def _bwd_dkv_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     s_q = q_ref.shape[1] // group  # per-query-head padded length
     ranges = _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
                           causal=causal, window=window, kv_off=kv_off,
-                          bd=bd, rows_are_queries=False)
+                          bd=bd, rows_are_queries=False,
+                          doc=None if docs is None else docs[2](ki))
 
     body = _dkv_tile(q_ref, do_ref, lse_ref, delta_ref, k_blk, v_blk, k_off,
                      kv_off, s_q, sm_scale=sm_scale, causal=causal,
@@ -1011,7 +1098,8 @@ def _bwd_dkv_head_kernel(kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     dk_acc[...], dv_acc[...] = _run_group_tiles(
         _tile_ranges(k_off, block_k, block_q, s_q // block_q, seq_len,
                      causal=causal, window=window, kv_off=kv_off, bd=bd,
-                     rows_are_queries=False),
+                     rows_are_queries=False,
+                     doc=None if docs is None else docs[2](ki)),
         1, body, (dk_acc[...], dv_acc[...]))
 
     @pl.when(g == group - 1)
@@ -1039,7 +1127,8 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     dk/dv per KV head.  ``vf`` and ``gf`` may be of another width than
     ``qf`` and ``kf`` (latent attention), under the causal mask only; the
     scale is that of the query-key width.  ``documents``: a packed row's ids
-    (B, S) of self-attention, laid out for each kernel's tiles here
+    (B, S) of self-attention with their tile bounds by query and by key tile
+    (``_document_operands``); the ids are laid out for each kernel's tiles here
     (``_document_ids``)."""
     bh, s_q, d = qf.shape
     dv_w = vf.shape[-1]   # the values' width: that of gf and of dv too
@@ -1061,8 +1150,9 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     _note_tiles({"flash_attention_bwd_dq": "bwd_dq"}, kv_offset,
                 query_tiles_a_program=tiles, **shape)
     q_rows = tiles * block_q  # a program's: its query tiles, walked as one
+    doc_ids, by_query, by_key = documents or (None,) * 3
     dq_kernel, ids, id_specs, id_bytes = _document_call(
-        _bwd_dq_kernel, 7, documents, bh, q_rows, s_q, s_k, bd=bd, **kw)
+        _bwd_dq_kernel, 7, doc_ids, by_query, bh, q_rows, s_q, s_k, bd=bd, **kw)
     dq = pl.pallas_call(
         dq_kernel,
         name="flash_attention_bwd_dq",
@@ -1107,10 +1197,11 @@ def _backward_folded(qf, kf, vf, gf, lse_f, delta_f, *, orig_s, causal,
     _note_tiles({"flash_attention_bwd_dkv" + ("" if bd is None else "_bd"):
                  "bwd_dkv"}, kv_offset, heads_a_program=heads, **shape)
     # with ids: the key tile's on the tile's rows, the sequence's query ids,
-    # alike for every head of the group, on its lanes
+    # alike for every head of the group, on its lanes, and each key tile's
+    # bounds over the query tiles, alike for every head too
     kernel, ids, id_specs, id_bytes = _document_call(
-        kernel, 7, documents, bh_kv, block_k, s_k, s_q, group=group, bd=bd,
-        **kw)
+        kernel, 7, doc_ids, by_key, bh_kv, block_k, s_k, s_q, group=group,
+        bd=bd, **kw)
     call = dict(
         grid=grid,
         in_specs=[
@@ -1452,9 +1543,14 @@ def flash_attention(
     query sees a key only of its own document, under ``causal`` and
     ``window`` as they are.  Any ids do: two positions are of one document
     where their ids are equal.  All three kernels take them
-    (``_document_ids``, ``_same_document``); every tile the other masks
-    reach is still visited.  Keys and values of one width only, and no
-    ``block_diffusion``; the ring and the serving kernels take none.
+    (``_document_ids``, ``_same_document``), and skip the tiles that no
+    document of theirs reaches: every program walks the static masks' range
+    cut to its tile's document bounds (``_document_bounds``, made once a
+    call on the device: never a tile dropped in which equal ids meet,
+    whatever the ids, and none kept for nothing at a range's ends where the
+    ids rise run by run), so the time follows the layout and the result does
+    not.  Keys and values of one width only, and no ``block_diffusion``; the
+    ring and the serving kernels take none.
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
@@ -1485,6 +1581,9 @@ def flash_attention(
                 f"k alike {q.shape[:2]}, got {documents.dtype}"
                 f"{documents.shape} and {k.shape[1]} keys")
     if block_diffusion is None:
+        if documents is not None:
+            documents = _document_operands(
+                documents, *_clamp_blocks(q.shape[1], block_q, block_k))
         return _flash(q, k, v, documents, causal, block_q, block_k, interpret,
                       window)
     half, blk = (int(x) for x in block_diffusion)

@@ -108,7 +108,8 @@ SITES = (
     "chaos.inject",        # a chaos rule fired (instant, first-class)
     "elastic.restart",     # exec-restart about to replace the image
     "flash.tiles",         # a flash kernel traced: tile visits, iterations, widths,
-                           # whether the call took document ids
+                           # whether the call took document ids (its counts then
+                           # bound the visits: at_most)
     "moe.rows",            # RoutedExperts traced: rows, slots, chunk, gathers, scoring,
                            # the grouped products' tiles and row-tile visits
     "gdn.chunks",          # the gated delta rule traced: rows, value and key heads,

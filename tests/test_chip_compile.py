@@ -256,14 +256,12 @@ def test_flash_block_diffusion_beyond_the_group_s_bytes_is_one_head_a_program(on
     assert operands[5] == operands[6] == "f32[16,1,8192]"
 
 
-def test_flash_with_document_ids_compiles_at_the_cell_s_shapes(one_chip):
+@pytest.fixture(scope="module")
+def document_ids_calls(one_chip):
     """The three kernels with a packed row's ids under the 1,024 window at the
     shape of mellum2-12b-a2.5b-pack8192-1chip's sliding layers (1 x 8,192, 32
-    query heads over 4 of 128; PR 45), forward and backward in one program: each
-    call takes the ids laid out for its tiles (a position's id on 128 lanes for
-    the side on a tile's rows, the ids along the lanes for the other), and the
-    dK/dV call holds the whole group of 8 and states its VMEM with theirs (64 +
-    16 MiB + 0.75 MiB)."""
+    query heads over 4 of 128; PR 45), forward and backward in one program,
+    compiled once: the compiled text, and each flash call's operands by name."""
     q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
     kv = _sds((1, 8192, 4, 128), jnp.bfloat16, one_chip)
     ids = _sds((1, 8192), jnp.int32, one_chip)
@@ -273,16 +271,53 @@ def test_flash_with_document_ids_compiles_at_the_cell_s_shapes(one_chip):
                                        interpret=False).astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, ids).as_text()
-    calls = {m.group(1): m.group(2) for m in re.finditer(
+    return text, {m.group(1): m.group(2) for m in re.finditer(
         r"%(flash_attention\w*?)\.\d+ = [^\n]*tpu_custom_call[^\n]*"
         r"operand_layout_constraints=\{(.*?)\}, frontend", text)}
+
+
+def test_flash_with_document_ids_compiles_at_the_cell_s_shapes(document_ids_calls):
+    """Each of the three calls takes the ids laid out for its tiles (a
+    position's id on 128 lanes for the side on a tile's rows, the ids along the
+    lanes for the other) and, since PR 46, its side's tile bounds, two (rows,
+    tiles) scalars' arrays (SMEM, beside ``kv_offset``): each query tile's first
+    and one-past-last key tile in the forward and dQ calls, each key tile's
+    over the query tiles in dK/dV."""
+    _, calls = document_ids_calls
     assert sorted(calls) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
                              "flash_attention_fwd"]
     for name, operands in calls.items():
-        assert operands.endswith("s32[1,8192,128]{2,1,0}, s32[1,1,8192]{2,1,0}"), (name, operands)
+        assert operands.startswith("s32[1]{0}, "), (name, operands)
+        assert operands.endswith("s32[1,8192,128]{2,1,0}, s32[1,1,8192]{2,1,0}, "
+                                 "s32[1,32]{1,0}, s32[1,32]{1,0}"), (name, operands)
+
+
+def test_flash_with_document_ids_holds_the_group_and_states_its_vmem(document_ids_calls):
+    """The dK/dV call holds the whole group of 8 and states its VMEM with the
+    ids' blocks (64 + 16 MiB + 0.75 MiB); the bounds are scalars and add
+    nothing to it."""
+    text, calls = document_ids_calls
     assert "bf16[4,65536,128]" in calls["flash_attention_bwd_dkv"]      # the group's rows
     (dkv,) = [l for l in text.splitlines() if re.search(r"%flash_attention_bwd_dkv\.\d+ = ", l)]
     assert f'"size":"{(64 + 16) * 2 ** 20 + 2 * 4 * (256 * 128 + 8 * 8192)}"' in dkv
+
+
+def test_flash_with_document_ids_makes_its_bounds_once_a_call(document_ids_calls):
+    """All three calls read ONE pair of bound arrays, made once under
+    ``attn_docmask``: at square tiles a key tile's bounds over the query tiles
+    are that query tile's over the key tiles (two tiles' id intervals overlap
+    or do not, whichever side asks)."""
+    text, calls = document_ids_calls
+    bounds = {}
+    for name in calls:
+        (line,) = [l for l in text.splitlines() if re.search(rf"%{name}\.\d+ = ", l)]
+        operands = re.sub(r"/\*index=\d+\*/", "", re.search(
+            r"custom-call\((.*?)\), custom_call_target", line).group(1))
+        bounds[name] = operands.split(", ")[-2:]
+    assert bounds["flash_attention_fwd"] == bounds["flash_attention_bwd_dq"] \
+        == bounds["flash_attention_bwd_dkv"]
+    made = [l for l in text.splitlines() if "/attn_docmask/" in l and " fusion(" in l]
+    assert made, "no fusion under attn_docmask"
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])

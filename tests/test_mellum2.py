@@ -13,6 +13,7 @@ import copy
 import functools
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -181,7 +182,8 @@ def test_a_packed_row_equals_its_documents_run_alone(short_walk, tiny_params):
                                  ("full_attention", None, "yarn", 16, True)]
     tiles = [args for name, args in events if name == "flash.tiles"]
     assert {t["kernel"] for t in tiles} == {"flash_attention_fwd"}
-    assert all(t["documents"] for t in tiles) and {t["window"] for t in tiles} == {None, WINDOW}
+    assert all(t["documents"] and t["at_most"] for t in tiles)
+    assert {t["window"] for t in tiles} == {None, WINDOW}
 
 
 def test_positions_restart_at_each_document_whatever_the_ids_are():
@@ -210,34 +212,203 @@ def _masked_dot(q, k, v, documents, window):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-@pytest.mark.parametrize("window,form", [(None, "group"), (40, "head")],
-                         ids=["causal-group", "window-head"])
-def test_kernels_with_ids_give_the_masked_dot_attention_s_gradients(window, form, monkeypatch,
-                                                                    short_walk):
+def _runs(*layouts):
+    return jnp.concatenate([_ids(lengths) for lengths in layouts])
+
+
+# ids that are no packed row's: a document that comes back after another (A B
+# A), ids that fall run by run, ids shuffled position by position, three ids
+# drawn a position.  A bound taken from where a row's runs start would drop
+# tiles of these in which equal ids meet
+_NOT_RUNS = (
+    jnp.stack([jnp.asarray(np.repeat([5, 2, 5], [170, 130, 200])),
+               jnp.asarray(np.repeat([9, 7, 4, 1, 0], [83, 160, 7, 164, 86]))]),
+    jnp.stack([jnp.asarray(np.random.default_rng(1).permutation(
+                   np.repeat(np.arange(4), 125))),
+               jnp.asarray(np.random.default_rng(2).integers(0, 3, 500))]))
+
+
+@pytest.mark.parametrize("window,form,block,layouts", [
+    (None, "group", 128, (_runs((37, 110, 53), (129, 3, 68)), _runs((200,), (1, 198, 1)))),
+    (40, "head", 128, (_runs((37, 110, 53), (129, 3, 68)), _runs((200,), (1, 198, 1)))),
+    (None, "group", 32, _NOT_RUNS), (None, "head", 32, _NOT_RUNS),
+    (40, "group", 32, _NOT_RUNS), (40, "head", 32, _NOT_RUNS)],
+    ids=["causal-group", "window-head", "causal-group-not-runs", "causal-head-not-runs",
+         "window-group-not-runs", "window-head-not-runs"])
+def test_kernels_with_ids_give_the_masked_dot_attention_s_gradients(window, form, block, layouts,
+                                                                    monkeypatch, short_walk):
     """The forward, dQ and dK/dV kernels with ids against ``jax.grad`` of the
     masked dot attention: 200 rows in 128-tiles (the second partial), 2 query
     heads over 1, boundaries inside tiles, two rows of two layouts; the dK/dV
     kernel in both forms (the whole group a program, or a head a program where
     the group's bytes pass ``_DKV_GROUP_BYTES``).  The ids are arguments of the
-    compiled call: other layouts run through it and compile nothing."""
+    compiled call: other layouts run through it and compile nothing.  Since
+    PR 46 the ids bound every program's walk, safely for ANY ids: the cases
+    that are no runs (``_NOT_RUNS``) walk sixteen 32-tiles under both masks in
+    both forms, 500 rows (under the window a forward or dQ program is the walk
+    of four query tiles, each under its own document bounds)."""
     if form == "head":
         monkeypatch.setattr(fa, "_DKV_GROUP_BYTES", 0)
     assert fa._dkv_heads_a_program(2, 256, 16, 16, 4)[0] == (2 if form == "group" else 1)
+    rows = layouts[0].shape[1]
     keys = jax.random.split(jax.random.PRNGKey(4), 4)
-    q = jax.random.normal(keys[0], (2, 200, 2, 16))
-    k, v = (jax.random.normal(key, (2, 200, 1, 16)) for key in keys[1:3])
+    q = jax.random.normal(keys[0], (2, rows, 2, 16))
+    k, v = (jax.random.normal(key, (2, rows, 1, 16)) for key in keys[1:3])
     weight = jax.random.normal(keys[3], q.shape)
     with_ids = jax.jit(jax.value_and_grad(lambda q, k, v, documents: jnp.sum(
         weight * fa.flash_attention(q, k, v, window=window, documents=documents,
-                                    block_q=128, block_k=128)), argnums=(0, 1, 2)))
+                                    block_q=block, block_k=block)), argnums=(0, 1, 2)))
     plain = jax.jit(jax.value_and_grad(lambda q, k, v, documents: jnp.sum(
         weight * _masked_dot(q, k, v, documents, window)), argnums=(0, 1, 2)))
-    for layouts in (((37, 110, 53), (129, 3, 68)), ((200,), (1, 198, 1))):
-        documents = jnp.concatenate([_ids(lengths) for lengths in layouts])
+    for documents in layouts:
         got, want = with_ids(q, k, v, documents), plain(q, k, v, documents)
         for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
     assert with_ids._cache_size() == 1
+
+
+# -- the tiles a row's ids let meet: every program's bounds (PR 46) ----------------------
+
+
+def _allowed_tiles(ids, block_q, block_k, causal, window):
+    """(query tiles, key tiles) bool by brute force over every (query, key)
+    pair: whether the tile holds a pair that the whole mask allows."""
+    s = len(ids)
+    back = np.arange(s)[:, None] - np.arange(s)[None, :]
+    seen = ids[:, None] == ids[None, :]
+    if causal:
+        seen &= back >= 0
+    if window is not None:
+        seen &= (back < window) & (back > -window)
+    pad = lambda n, block: (-n) % block
+    seen = np.pad(seen, ((0, pad(s, block_q)), (0, pad(s, block_k))))
+    return seen.reshape(-1, block_q, seen.shape[1] // block_k, block_k).any(axis=(1, 3))
+
+
+def _joined_ranges(ids, block_q, block_k, causal, window):
+    """Each query tile's and each key tile's joined ``(lo, hi)``: the kernels'
+    own arithmetic (``_document_bounds`` and ``_tile_ranges`` on numpy)."""
+    s = len(ids)
+    n_q, n_k = -(-s // block_q), -(-s // block_k)
+    by_query, by_key = fa._document_bounds(ids[None], block_q, block_k, xp=np)
+    mask = dict(causal=causal, window=window, kv_off=0, bd=None, xp=np)
+    ((q_lo, q_hi),) = fa._tile_ranges(np.arange(n_q) * block_q, block_q, block_k, n_k, s,
+                                      rows_are_queries=True, doc=by_query, **mask)
+    ((k_lo, k_hi),) = fa._tile_ranges(np.arange(n_k) * block_k, block_k, block_q, n_q, s,
+                                      rows_are_queries=False, doc=by_key, **mask)
+    return (q_lo[0], q_hi[0]), (k_lo[0], k_hi[0])
+
+
+def test_the_cell_s_row_visits_144_causal_and_113_window_tiles_a_head():
+    """The benchmark's eleven lengths at 256-tiles: of a head's 528 causal tile
+    visits 144 hold a pair its documents allow and of the 1,024 window's 150,
+    113; brute force over every pair, the bound arithmetic on the query side
+    (forward, dQ) and on the key side (dK/dV), and ``tile_counts`` with the
+    ids all say so."""
+    ids = document_ids(harness.load_cell(CELL).traffic).astype(np.int32)
+    for window, bound, want in ((None, 528, 144), (1024, 150, 113)):
+        assert int(_allowed_tiles(ids, 256, 256, True, window).sum()) == want
+        for lo, hi in _joined_ranges(ids, 256, 256, True, window):
+            assert int(np.maximum(hi - lo, 0).sum()) == want
+        shape = dict(s_q=8192, s_k=8192, block_q=256, block_k=256, seq_len=8192, window=window)
+        counts = fa.tile_counts(documents=ids, **shape)
+        assert [counts[k][0] for k in ("fwd", "bwd_dq", "bwd_dkv")] == [want] * 3
+        assert fa.tile_counts(**shape)["fwd"][0] == bound
+        # the heads and query tiles a program walks as one, and rows that add up
+        held = fa.tile_counts(documents=np.stack([ids, ids]), heads_a_program=8,
+                              query_tiles_a_program=2, **shape)
+        assert (held["fwd"][0], held["bwd_dkv"][0]) == (2 * want, 2 * 8 * want)
+
+
+def _layout(kind, seed, s=200):
+    rng = np.random.default_rng(seed)
+    if kind == "one":
+        return np.zeros(s, np.int64)
+    if kind == "tokens":                        # every document one token
+        return np.arange(s)
+    if kind == "on-tiles":                      # every boundary on a 32-tile
+        cuts = np.sort(rng.choice(np.arange(1, s // 32 + 1), 3, replace=False)) * 32
+    elif kind == "off-tiles":
+        cuts = np.sort(rng.choice(np.arange(1, s), rng.integers(1, 12), replace=False))
+    if kind in ("on-tiles", "off-tiles"):
+        return np.repeat(np.arange(len(cuts) + 1), np.diff([0, *cuts, s]))
+    if kind == "came-back":                     # A B A
+        a, b = sorted(rng.choice(np.arange(1, s), 2, replace=False))
+        return np.repeat([3, 1, 3], [a, b - a, s - b])
+    if kind == "falling":
+        return _layout("off-tiles", seed, s)[::-1].copy()
+    return rng.integers(0, 4, s)                # "any"
+
+
+@pytest.mark.parametrize("kind", ["one", "tokens", "on-tiles", "off-tiles", "came-back",
+                                  "falling", "any"])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 40), (False, None), (False, 40)],
+                         ids=["causal", "causal-window", "none", "window"])
+def test_the_joined_ranges_keep_every_tile_in_which_the_mask_allows_a_pair(kind, causal, window):
+    """Whatever the ids: every tile pair in which the whole mask (ids, causal,
+    window, padding) allows an element lies inside its query tile's joined
+    range and inside its key tile's, by brute force over the pairs, at square
+    and at unequal tiles; and for a packed row (ids that rise run by run) a
+    range that is not empty holds no empty tile at either end."""
+    for seed in range(3):
+        ids = _layout(kind, seed)
+        for block_q, block_k in ((32, 32), (16, 64)):
+            allowed = _allowed_tiles(ids, block_q, block_k, causal, window)
+            by_query, by_key = _joined_ranges(ids, block_q, block_k, causal, window)
+            for (lo, hi), sees in ((by_query, allowed), (by_key, allowed.T)):
+                other = np.arange(sees.shape[1])
+                inside = (other >= lo[:, None]) & (other < hi[:, None])
+                assert not (sees & ~inside).any(), (kind, seed, block_q, block_k)
+                if kind in ("one", "tokens", "on-tiles", "off-tiles"):
+                    some = hi > lo
+                    assert sees[some, lo[some]].all() and sees[some, hi[some] - 1].all()
+                    assert (some == sees.any(axis=1)).all()
+
+
+def test_the_tiles_event_of_a_call_with_ids_keeps_the_static_counts_and_says_at_most():
+    """The visits of a call with ids are data: its ``flash.tiles`` events keep
+    the other masks' counts (what the same call without ids records) and say
+    that they only bound the walk; a call without ids says nothing new."""
+    def traced(documents):
+        t0 = trace.now()
+        q = jnp.ones((1, 384, 2, 16), jnp.float32)
+        jax.make_jaxpr(jax.grad(lambda a: fa.flash_attention(
+            a, a[:, :, :1], a[:, :, :1], block_q=128, block_k=128, window=130,
+            documents=documents).sum()))(q)
+        return {r[3]["kernel"]: r[3] for r in trace.snapshot(t0) if r[0] == "flash.tiles"}
+
+    with_ids, without = traced(_ids((188, 196))), traced(None)
+    assert sorted(with_ids) == sorted(without) == [
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"]
+    for name, event in with_ids.items():
+        assert event["documents"] and event["at_most"] is True
+        assert not without[name]["documents"] and "at_most" not in without[name]
+        assert (event["visited"], event["iterations"]) == (
+            without[name]["visited"], without[name]["iterations"])
+    made = fa.tile_counts(384, 384, 128, 128, 384, window=130,
+                          documents=np.asarray(_ids((188, 196)))[0])
+    assert made["fwd"][0] == 5 < with_ids["flash_attention_fwd"]["visited"] == 6
+
+
+def test_the_device_s_bounds_are_the_host_s_and_lie_under_the_docmask_scope():
+    """``_document_operands`` under ``jit`` (what ``flash_attention`` hands its
+    kernels: the ids and both sides' bounds, once a call) against
+    ``_document_bounds`` on numpy, for rows of every kind at once; the
+    comparison's operations carry the ``attn_docmask`` scope."""
+    rows = np.stack([_layout(kind, 1) for kind in
+                     ("one", "tokens", "on-tiles", "off-tiles", "came-back", "falling", "any")])
+    on_device = jax.jit(functools.partial(fa._document_operands, block_q=32, block_k=64))
+    ids, by_query, by_key = on_device(jnp.asarray(rows, jnp.int32))
+    want = fa._document_bounds(rows, 32, 64, xp=np)
+    np.testing.assert_array_equal(ids, rows)
+    for got, bounds in zip((by_query, by_key), want):
+        for a, b in zip(got, bounds):
+            assert a.dtype == jnp.int32 and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+    assert by_query[0].shape == (7, 7) and by_key[0].shape == (7, 4)
+    text = on_device.lower(jnp.asarray(rows, jnp.int32)).compile().as_text()
+    named = re.findall(r'op_name="(jit[^"]*)"', text)    # a reduction's body has a bare name
+    assert named and all("/attn_docmask/" in name for name in named)
 
 
 # -- YaRN on the whole head, the share of the experts, the count -----------------------

@@ -49,13 +49,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 from horovod_tpu.common.retry import env_int  # noqa: E402
 from horovod_tpu.models.transformer import causal_dot_attention  # noqa: E402
 from horovod_tpu.ops import grouped_matmul as gm  # noqa: E402
 from horovod_tpu.ops.flash_attention import (  # noqa: E402
-    _backward_impl, _clamp_blocks, _dkv_heads_a_program, _forward_impl,
-    _query_tiles_a_program, flash_attention, tile_counts,
+    _backward_impl, _clamp_blocks, _dkv_heads_a_program, _document_operands,
+    _forward_impl, _query_tiles_a_program, flash_attention, tile_counts,
 )
 
 
@@ -246,10 +247,13 @@ def leg_window(b, s, h, d, windows, iters, warmup, interpret,
         )
 
 
-# (b, s, h, h_kv, d, causal, block_diffusion[, window]) of the benchmark's
-# transformer cells (benchmark/configs/): what a layer's attention sees.
-# ``d`` = (d_qk, d_v) where keys and values differ in width (latent
-# attention: 128 + 64 rotary against 128)
+# (b, s, h, h_kv, d, causal, block_diffusion[, window[, documents]]) of the
+# benchmark's transformer cells (benchmark/configs/): what a layer's attention
+# sees.  ``d`` = (d_qk, d_v) where keys and values differ in width (latent
+# attention: 128 + 64 rotary against 128); ``documents``: the lengths of a
+# packed row's documents (benchmark/traffic/pack8192-1chip.json), whose ids
+# the call takes and whose tiles the counts are (``tile_counts(documents=)``)
+_PACK8192 = (557, 2909, 131, 1087, 293, 1523, 72, 811, 241, 389, 179)
 CELL_SHAPES = {
     "internlm2-1.8b-s4096-1chip": (1, 4096, 16, 8, 128, True, None),
     "sdar-30b-a3b-bd4-s4096-1chip": (1, 8192, 32, 4, 128, False, (4096, 4)),
@@ -257,6 +261,10 @@ CELL_SHAPES = {
     "qwen3-next-80b-a3b-s8192-1chip": (1, 8192, 16, 2, 256, True, None),
     "laguna-xs.2-s8192-1chip/sliding": (1, 8192, 64, 8, 128, True, None, 512),
     "laguna-xs.2-s8192-1chip/full": (1, 8192, 48, 8, 128, True, None),
+    "mellum2-12b-a2.5b-pack8192-1chip/sliding": (
+        1, 8192, 32, 4, 128, True, None, 1024, _PACK8192),
+    "mellum2-12b-a2.5b-pack8192-1chip/full": (
+        1, 8192, 32, 4, 128, True, None, None, _PACK8192),
 }
 
 
@@ -271,17 +279,26 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
     dK/dV: the kernels' own rules) with a program's mean visits, and the time a
     tile visit, which PERF.md §5 holds against the 0.085 us a 256 x 256 x 128
     product needs on the v5e's MXU."""
-    for cell, (b, s, h, h_kv, d, causal, bd, *window) in shapes.items():
-        window = window[0] if window else None
+    for cell, (b, s, h, h_kv, d, causal, bd, *more) in shapes.items():
+        window, lengths = (*more, None, None)[:2]
         q, k, v = _qkv(b, s, h, h_kv, d)
         g = _qkv(b, s, h, h, d, seed=1)[2]  # dO: every query head, as wide as v
+        bq, bk = _clamp_blocks(s, block, block)
+        ids = None if lengths is None else np.tile(
+            np.repeat(np.arange(len(lengths)), lengths), (b, 1))
+        # with ids: the bounds made inside each timed program, as a step's are
+        documents = lambda: None if ids is None else _document_operands(
+            ids, bq, bk)
+
         def fwd(q, k, v):
             return _forward_impl(q, k, v, causal, block, block, interpret,
-                                 with_lse=True, window=window, bd=bd)
+                                 with_lse=True, window=window, bd=bd,
+                                 documents=documents())
 
         def bwd(q, k, v, out, lse, g):
             return _backward_impl(q, k, v, out, lse, g, causal, block, block,
-                                  interpret, window=window, bd=bd)
+                                  interpret, window=window, bd=bd,
+                                  documents=documents())
 
         def timed(fn, *args):
             fn = jax.jit(fn)
@@ -298,7 +315,6 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
         ms = {"fwd": timed(fwd, q, k, v),
               "bwd_dq": timed(lambda *a: bwd(*a)[0], q, k, v, out, lse, g),
               "bwd_dkv": timed(lambda *a: bwd(*a)[1:], q, k, v, out, lse, g)}
-        bq, bk = _clamp_blocks(s, block, block)
         mask = dict(s_q=_pad(s, bq), s_k=_pad(s, bk), block_q=bq, block_k=bk,
                     seq_len=s, causal=causal, window=window, bd=bd)
         # what a program walks as one, by the kernels' own rules: its counts
@@ -307,14 +323,17 @@ def leg_cells(shapes, iters, warmup, interpret, block=256):
         heads = _dkv_heads_a_program(
             h // h_kv, mask["s_q"], q.shape[-1], v.shape[-1],
             q.dtype.itemsize)[0]
+        # with ids, the visits a head makes on that layout (every row's here)
         tiles = tile_counts(heads_a_program=heads,
-                            query_tiles_a_program=query_tiles, **mask)
+                            query_tiles_a_program=query_tiles,
+                            documents=None if ids is None else ids[0], **mask)
         walks = {"fwd": b * h, "bwd_dq": b * h, "bwd_dkv": b * h // heads}
         q_programs = mask["s_q"] // (query_tiles * bq)
         programs = {"fwd": q_programs, "bwd_dq": q_programs,
                     "bwd_dkv": mask["s_k"] // bk}
         rec = {"bench": "flash_cells", "cell": cell, "b": b, "s": s, "h": h,
                "h_kv": h_kv, "d": d, "window": window, "block": [bq, bk],
+               "documents": None if lengths is None else len(lengths),
                "query_tiles_a_program": query_tiles, "heads_a_program": heads}
         for name, t in ms.items():
             visited, iterations = tiles[name]
@@ -676,6 +695,8 @@ def main(argv=None):
         leg_window(1, 384, 2, 64, (None, 128), 2, 1, True,
                    block_q=128, block_k=128)
         leg_cells({"causal-gqa": (1, 512, 4, 2, 32, True, None),
+                   "packed": (1, 512, 4, 2, 32, True, None, 192,
+                              (60, 200, 30, 222)),
                    "block-diffusion": (1, 512, 4, 1, 32, False, (256, 4)),
                    "latent": (1, 512, 4, 4, (48, 32), True, None)},
                   2, 1, True, block=128)
