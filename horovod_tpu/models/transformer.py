@@ -207,7 +207,9 @@ class TransformerConfig:
     # the router's ``aux_loss`` (mean over layers) for the step's loss,
     # the counters ``expert_assignments`` (sum over layers),
     # ``expert_load_max_over_mean`` (largest over layers),
-    # ``dropped_assignments`` (sum; 0) and ``expert_index`` (layers, T, k).
+    # ``dropped_assignments`` (sum; 0), ``expert_chunks`` (sum of the chunks
+    # in use: the routed layers' number where none overflows) and
+    # ``expert_index`` (layers, T, k).
     num_experts: Optional[int] = None
     num_experts_per_tok: int = 1
     moe_intermediate_size: Optional[int] = None
@@ -1406,6 +1408,7 @@ class Transformer(nn.Module):
                 "expert_load_max_over_mean": jnp.max(
                     per["load_max_over_mean"]),
                 "dropped_assignments": jnp.sum(per["dropped"]),
+                "expert_chunks": jnp.sum(per["chunks"]),
                 "expert_index": per["expert_index"],
             }
         return logits

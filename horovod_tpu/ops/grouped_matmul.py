@@ -19,7 +19,7 @@ Two kernels, tied by one ``jax.custom_vjp``:
     tile under one group: a tile that two groups share is visited once a
     group and each visit stores only its own group's rows; a tile beyond
     ``sum(sizes)`` is never visited (the number of visits is a grid bound
-    read on the device), so the padded half of a chunk costs nothing.  Which
+    read on the device), so a chunk's rows beyond them cost nothing.  Which
     tile and which group a visit has comes from scalar-prefetched metadata
     computed from ``sizes`` on the device (``_visits``).  Consecutive visits
     of one group keep the same block of ``w``: with ``k`` whole in a tile an
@@ -79,20 +79,20 @@ def _divisor_tile(size: int, limit: int) -> int:
     return size
 
 
-def tiles(rows: int, k: int, n: int, groups: int, dtype) -> Tiles:
+def tiles(rows: int, k: int, n: int, expected: int, dtype) -> Tiles:
     """The tile sizes of ``grouped_matmul`` at these shapes (``k`` the
     contraction, ``n`` the columns; the weight gradient runs on the same
-    tiles).  Row tiles of 256 where the expected group (``rows / 2 /
-    groups``: a chunk is twice the expected load) is at least two of them,
-    else 128: a group's first and last tile are shared with its neighbours
-    and computed twice.  ``k`` whole where a (k, n tile) block of an expert's
-    matrix fits ``_W_BLOCK_BYTES``, so the block stays put while a group's
-    row tiles go by; ``n`` whole where it fits, else the widest multiple of
-    128 that does (a last partial column tile is fine: columns do not mix).
+    tiles).  Row tiles of 256 where the ``expected`` group (the rows the
+    caller expects a group to hold: the routed layer's slots an expert) is at
+    least two of them, else 128: a group's first and last tile are shared
+    with its neighbours and computed twice.  ``k`` whole where a (k, n tile)
+    block of an expert's matrix fits ``_W_BLOCK_BYTES``, so the block stays
+    put while a group's row tiles go by; ``n`` whole where it fits, else the
+    widest multiple of 128 that does (a last partial column tile is fine:
+    columns do not mix).
     A contraction that needs tiles and has no divisor among the multiples of
     128 is refused: a partial tile of it would add what is not there."""
     itemsize = jnp.dtype(dtype).itemsize
-    expected = max(rows // 2 // max(groups, 1), 1)
     tm = 256 if expected >= 512 else 128
     tm = min(tm, _round_up(rows, 16))
     words = _W_BLOCK_BYTES // itemsize
@@ -307,27 +307,27 @@ def _tgmm(x, dy, sizes, groups: int, dtype, tile: Tiles, interpret: bool):
     )(offsets, group, row_tile, xp, dyp)
 
 
-def _product(x, w, sizes, transpose_w, interpret):
+def _product(x, w, sizes, transpose_w, expected, interpret):
     n = w.shape[1] if transpose_w else w.shape[2]
-    tile = tiles(x.shape[0], x.shape[1], n, w.shape[0], x.dtype)
+    tile = tiles(x.shape[0], x.shape[1], n, expected, x.dtype)
     return _gmm(x, w, sizes, transpose_w, tile, interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _grouped(x, w, sizes, transpose_w, interpret):
-    return _product(x, w, sizes, transpose_w, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped(x, w, sizes, transpose_w, expected, interpret):
+    return _product(x, w, sizes, transpose_w, expected, interpret)
 
 
-def _grouped_fwd(x, w, sizes, transpose_w, interpret):
-    return _product(x, w, sizes, transpose_w, interpret), (x, w, sizes)
+def _grouped_fwd(x, w, sizes, transpose_w, expected, interpret):
+    return _product(x, w, sizes, transpose_w, expected, interpret), (x, w, sizes)
 
 
-def _grouped_bwd(transpose_w, interpret, residuals, dy):
+def _grouped_bwd(transpose_w, expected, interpret, residuals, dy):
     x, w, sizes = residuals
-    dx = _product(dy, w, sizes, not transpose_w, interpret)
+    dx = _product(dy, w, sizes, not transpose_w, expected, interpret)
     # w is (groups, k, n), or transposed (groups, n, k): dy[g]^T @ x[g] then
     rows, cols = (dy, x) if transpose_w else (x, dy)
-    tile = tiles(x.shape[0], rows.shape[1], cols.shape[1], w.shape[0], x.dtype)
+    tile = tiles(x.shape[0], rows.shape[1], cols.shape[1], expected, x.dtype)
     dw = _tgmm(rows, cols, sizes, w.shape[0], w.dtype, tile, interpret)
     return dx, dw, None
 
@@ -335,11 +335,13 @@ def _grouped_bwd(transpose_w, interpret, residuals, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(x, w, sizes, *, transpose_w: bool = False,
-                   interpret: Optional[bool] = None):
+def grouped_matmul(x, w, sizes, *, expected: Optional[int] = None,
+                   transpose_w: bool = False, interpret: Optional[bool] = None):
     """``x[r] @ w[g]`` for every row ``r`` of group ``g``: ``x`` (rows, k),
     ``w`` (groups, k, n) or, with ``transpose_w``, (groups, n, k) read
-    transposed, ``sizes`` (groups,) int32 with ``sum(sizes) <= rows``.
+    transposed, ``sizes`` (groups,) int32 with ``sum(sizes) <= rows``;
+    ``expected``: the rows a group is expected to hold, for the tiles
+    (``tiles``; the rows over the groups where none is given).
     Returns (rows, n) in ``x``'s dtype; undefined beyond ``sum(sizes)``.
     Differentiable in ``x`` and ``w`` (the module's text)."""
     if x.ndim != 2 or w.ndim != 3 or sizes.shape != (w.shape[0],):
@@ -353,4 +355,6 @@ def grouped_matmul(x, w, sizes, *, transpose_w: bool = False,
                          f"{transpose_w}) do not contract")
     if interpret is None:   # here, not under the wrappers' jit: a trace is kept
         interpret = jax.default_backend() != "tpu"
-    return _grouped(x, w, sizes.astype(jnp.int32), transpose_w, interpret)
+    if expected is None:
+        expected = x.shape[0] // w.shape[0]
+    return _grouped(x, w, sizes.astype(jnp.int32), transpose_w, expected, interpret)

@@ -53,11 +53,16 @@ scalars takes 0.57 ms, a sort of them 0.05.
 The grouped products (PR 33).  A chunk's rows times their experts'
 matrices, three products forward and six backward, are
 ``ops.grouped_matmul.grouped_matmul``: Mosaic kernels whose grid walks only
-the row tiles the groups cover, so the half of a chunk that holds no
-assignment costs nothing, and a tile that two experts share is computed
-once an expert under a row mask.  XLA's own kernels for the ragged product,
-which the layer called before, ran at a quarter of the MXU on groups of
-512-768 rows (PERF.md §6).
+the row tiles the groups cover, so the rows of a chunk that hold no
+assignment cost the products nothing, and a tile that two experts share is
+computed once an expert under a row mask.  XLA's own kernels for the ragged
+product, which the layer called before, ran at a quarter of the MXU on
+groups of 512-768 rows (PERF.md §6).
+
+The chunks' sizes (PR 47).  What is no grouped product (the gathers, the
+masks, SwiGLU, their backward) is XLA over a chunk's static rows, assigned or
+not: so the first chunk is nine eighths of the assignments balanced routing
+gives the held share and a later one a quarter of them (``_Chunks``).
 """
 
 from __future__ import annotations
@@ -306,7 +311,7 @@ def _combine_bwd(residuals, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route):
+def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route, group):
     """One chunk of the sorted assignments through the held experts.
 
     ``weight`` (C,) each assignment's combine weight, ``valid`` (C,) whether
@@ -315,14 +320,15 @@ def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route):
     rows move between token order and expert order (``_chunk_out``).  Rows
     that hold no assignment are zeroed on the way in, between the products
     and on the way out: the grouped product leaves what lies beyond its
-    groups undefined.  Returns the chunk's part of the output, (T, D)
+    groups undefined.  ``group``: the rows balanced routing gives an expert,
+    for the products' tiles.  Returns the chunk's part of the output, (T, D)
     float32."""
     keep = valid[:, None]
     x = jnp.where(keep, _dispatch(z, route), 0)
-    gate = grouped_matmul(x, w_gate, sizes)
-    up = grouped_matmul(x, w_up, sizes)
+    gate = grouped_matmul(x, w_gate, sizes, expected=group)
+    up = grouped_matmul(x, w_up, sizes, expected=group)
     h = jnp.where(keep, nn.silu(gate) * up, 0)
-    y = jnp.where(keep, grouped_matmul(h, w_down, sizes), 0)
+    y = jnp.where(keep, grouped_matmul(h, w_down, sizes, expected=group), 0)
     return _combine(y, weight, route)
 
 
@@ -332,7 +338,38 @@ def _chunk_sizes(lo, starts, ends, chunk):
     return jnp.clip(ends - lo, 0, chunk) - jnp.clip(starts - lo, 0, chunk)
 
 
-def _chunk_out(lo, z, mats, weight, index, k, chunk):
+class _Chunks(NamedTuple):
+    """The static sizes of a layer's chunks of sorted rows: chunk 0 holds
+    ``first`` rows and chunk ``c >= 1`` the ``later`` rows from ``first +
+    (c - 1) * later``.  ``group``: the rows balanced routing gives an expert."""
+
+    first: int
+    later: int
+    group: int
+
+    def start(self, c):
+        return self.first + (c - 1) * self.later
+
+
+def _active_chunks(index, chunks):
+    """The chunks the sort reached: the first, which always runs, and the
+    later ones that hold an assignment."""
+    over = jnp.maximum(index.assigned - chunks.first, 0)
+    return 1 + (over + chunks.later - 1) // chunks.later
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _counted(index, chunks, n_later):
+    """``(chunks in use, assignments they computed)``, from the chunks' own
+    group sizes; one program, for ``model.init``'s sake (``_to_tokens``)."""
+    active = _active_chunks(index, chunks)
+    c = jnp.arange(n_later + 1)[:, None]
+    per_chunk = _chunk_sizes(jnp.where(c > 0, chunks.start(c), 0), index.starts,
+                             index.ends, jnp.where(c > 0, chunks.later, chunks.first))
+    return active, jnp.sum(jnp.where(c < active, per_chunk, 0))
+
+
+def _chunk_out(lo, z, mats, weight, index, k, chunk, group):
     """The part of the output that the sorted rows ``[lo, lo + chunk)``
     give.  ``weight``: the combine weights in the order of the sort;
     ``index``: the layer's ``_Sort``."""
@@ -342,53 +379,51 @@ def _chunk_out(lo, z, mats, weight, index, k, chunk):
     held = (at >= 0) & (at < chunk) & (index.rank < index.assigned)
     route = _Route(sel // k, jnp.where(held, at, 0), held, index.chosen)
     return _expert_chunk(z, *mats, jax.lax.dynamic_slice_in_dim(weight, lo, chunk),
-                         valid, _chunk_sizes(lo, index.starts, index.ends, chunk), route)
+                         valid, _chunk_sizes(lo, index.starts, index.ends, chunk),
+                         route, group)
 
 
-def _active_chunks(index, chunk):
-    return (index.assigned + chunk - 1) // chunk
-
-
-def _later_chunks(out, z, mats, weight, index, k, chunk):
+def _later_chunks(out, z, mats, weight, index, k, chunks):
     """``out`` plus the parts of the chunks after the first that the sort
     reached: a loop whose trip count is read on the device."""
     return jax.lax.fori_loop(
-        1, _active_chunks(index, chunk),
-        lambda c, out: out + _chunk_out(c * chunk, z, mats, weight, index,
-                                        k, chunk), out)
+        1, _active_chunks(index, chunks),
+        lambda c, out: out + _chunk_out(chunks.start(c), z, mats, weight, index,
+                                        k, chunks.later, chunks.group), out)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _routed_sum(z, mats, weight, index, k, chunk):
+def _routed_sum(z, mats, weight, index, k, chunks):
     """All chunks' parts of the output, summed.  The first chunk always
     runs, as ordinary code with an ordinary backward pass.  A later chunk
     runs only if the sort reached it, so a chunk that holds nothing costs
     nothing, forward or backward (a ``lax.cond`` a chunk would still fill
     and add a zero gradient for every expert matrix).  The backward pass
     of a later chunk recomputes it."""
-    out = _chunk_out(0, z, mats, weight, index, k, chunk)
-    return _later_chunks(out, z, mats, weight, index, k, chunk)
+    out = _chunk_out(0, z, mats, weight, index, k, chunks.first, chunks.group)
+    return _later_chunks(out, z, mats, weight, index, k, chunks)
 
 
-def _routed_sum_fwd(z, mats, weight, index, k, chunk):
+def _routed_sum_fwd(z, mats, weight, index, k, chunks):
     out, first_vjp = jax.vjp(
         lambda z, mats, weight: _chunk_out(0, z, mats, weight, index, k,
-                                           chunk), z, mats, weight)
-    out = _later_chunks(out, z, mats, weight, index, k, chunk)
+                                           chunks.first, chunks.group),
+        z, mats, weight)
+    out = _later_chunks(out, z, mats, weight, index, k, chunks)
     return out, (first_vjp, z, mats, weight, index)
 
 
-def _routed_sum_bwd(k, chunk, residuals, g):
+def _routed_sum_bwd(k, chunks, residuals, g):
     first_vjp, z, mats, weight, index = residuals
 
     def later(c, grads):
         _, vjp = jax.vjp(
-            lambda z, mats, weight: _chunk_out(c * chunk, z, mats, weight,
-                                               index, k, chunk),
-            z, mats, weight)
+            lambda z, mats, weight: _chunk_out(
+                chunks.start(c), z, mats, weight, index, k, chunks.later,
+                chunks.group), z, mats, weight)
         return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
 
-    grads = jax.lax.fori_loop(1, _active_chunks(index, chunk), later,
+    grads = jax.lax.fori_loop(1, _active_chunks(index, chunks), later,
                               first_vjp(g))
     return (*grads, None)   # the index: integers, and a copy of the weights
 
@@ -431,23 +466,27 @@ class RoutedExperts(nn.Module):
     first chunk always runs; a later one runs only if
     the sort reached it (``_routed_sum``: a loop over the chunks in use,
     a later chunk recomputed in the backward pass, so an idle one costs
-    neither time nor memory).  The default chunk is twice the expected
-    load of the held share, so balanced routing is one chunk and a router
-    that sends everything to one expert still drops nothing.
+    neither time nor memory).  By default the first chunk holds nine
+    eighths of the assignments balanced routing gives the held share and a
+    later one a quarter of them: balanced routing is one chunk with little
+    padding, an overflow costs its quarters and a router that sends everything
+    to one expert still drops nothing; ``chunk_rows``: every chunk that size.
 
     Returns ``(y, stats)``: ``aux_loss`` (Switch / Hugging Face form:
     ``E * sum_e (n_e / (k T)) * mean_T g_e``, no gradient through the
     counts; or the sequence-wise form above), and the counters ``assigned`` (assignments to held experts),
     ``load_max_over_mean`` (largest held expert's load over the mean held
     load), ``dropped`` (assignments to held experts no chunk computed: 0 by
-    construction, counted from the chunks' own group sizes) and
-    ``expert_index`` (T, k), the chosen ids.
+    construction, counted from the chunks' own group sizes), ``chunks`` (the
+    chunks in use: 1 without overflow) and ``expert_index`` (T, k), the
+    chosen ids.
 
     Traced into a program (never in a step) it leaves one ``moe.rows``
-    event: the rows, slots and chunk rows, the held assignments balanced
-    routing gives, the row gathers a chunk makes, forward and backward, and
-    the grouped products' tile sizes with the row-tile visits one product
-    makes at balanced sizes against the row tiles of a whole chunk.
+    event: the rows, slots and chunk rows (``first``, ``later``), the held
+    assignments balanced routing gives, the row gathers a chunk makes,
+    forward and backward, and the grouped products' tile sizes with the
+    row-tile visits one product makes at balanced sizes against the row
+    tiles of the whole first chunk.
     """
 
     num_experts: int
@@ -528,27 +567,29 @@ class RoutedExperts(nn.Module):
             ends = jnp.cumsum(sizes)
             starts = ends - sizes
 
-        chunk = self.chunk_rows
-        if chunk is None:
-            chunk = 2 * math.ceil(slots * n_held / n_exp)
-        chunk = min(_round_up(max(chunk, 1), 8), _round_up(slots, 8))
-        n_chunks = -(-slots // chunk)
+        expected = slots * n_held / n_exp
+        first, later = (self.chunk_rows,) * 2 if self.chunk_rows is not None else (
+            math.ceil(9 * expected / 8), math.ceil(expected / 4))
+        first, later = (min(_round_up(max(n, 1), 8), _round_up(slots, 8))
+                        for n in (first, later))
+        chunks = _Chunks(first, later, slots // n_exp)
+        n_later = -(-max(slots - first, 0) // later)
+        padding = chunks.start(n_later + 1) - slots
         if _trace.enabled():
             # shape arithmetic: a chunk gathers its own rows twice (x; g in
             # the backward) and every token's slots twice (y; dx); a grouped
             # product visits the row tiles that balanced groups cover, not
             # the chunk's
-            expected = slots * n_held / n_exp
-            tile = _tiles(chunk, d, self.d_ff, n_held, self.dtype)
+            tile = _tiles(first, d, self.d_ff, chunks.group, self.dtype)
             visits, chunk_tiles = visit_counts(
-                chunk, n_held, tile.m, int(min(expected, chunk)) // n_held)
+                first, n_held, tile.m, int(min(expected, first)) // n_held)
             _trace.event(
-                "moe.rows", rows=rows, slots=slots, chunk=chunk,
-                expected=expected, dtype=jnp.dtype(self.dtype).name,
-                gathered=2 * chunk + 2 * slots, scoring=self.scoring,
+                "moe.rows", rows=rows, slots=slots, chunk=first, first=first,
+                later=later, expected=expected, dtype=jnp.dtype(self.dtype).name,
+                gathered=2 * first + 2 * slots, scoring=self.scoring,
                 tiles=list(tile), visits=visits, chunk_tiles=chunk_tiles)
-        order = jnp.pad(order, (0, n_chunks * chunk - slots))
-        weight = jnp.pad(weight, (0, n_chunks * chunk - slots))
+        order = jnp.pad(order, (0, padding))
+        weight = jnp.pad(weight, (0, padding))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         w_gate = self.param("w_gate", init, (n_held, d, self.d_ff))
@@ -559,14 +600,9 @@ class RoutedExperts(nn.Module):
             mats = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
             sort = _Sort(order, rank.reshape(rows, k), jax.lax.stop_gradient(chosen),
                          starts, ends, assigned)
-            out = _routed_sum(z.astype(self.dtype), mats, weight, sort, k, chunk)
+            out = _routed_sum(z.astype(self.dtype), mats, weight, sort, k, chunks)
             y = out.astype(self.dtype).reshape(x.shape)
-        # what the chunks in use computed, from their own group sizes
-        active = _active_chunks(sort, chunk)
-        computed = sum(
-            jnp.where(c < active,
-                      jnp.sum(_chunk_sizes(c * chunk, starts, ends, chunk)), 0)
-            for c in range(n_chunks))
+        active, computed = _counted(sort, chunks, n_later)
 
         mean_load = jnp.maximum(assigned, 1) / n_held
         stats = {
@@ -574,6 +610,7 @@ class RoutedExperts(nn.Module):
             "assigned": assigned,
             "load_max_over_mean": jnp.max(sizes) / mean_load,
             "dropped": assigned - computed,
+            "chunks": active,
             "expert_index": index,
         }
         return y, stats

@@ -426,37 +426,41 @@ def test_gdn_passes_are_kernels_on_the_chip(one_chip):
     assert not re.search(r"= bf16\[1,8192,(8192|4096)\]\S* slice\(", text)
 
 
-# the routed cells' grouped products: (rows of a chunk, k, n, held experts, row tile)
+# the routed cells' grouped products: (rows of the first chunk, k, n, held experts,
+# the rows an expert expects, row tile)
 GROUPED_CASES = {
-    "sdar_gate_up": (16384, 2048, 768, 16, 256),
-    "sdar_down": (16384, 768, 2048, 16, 256),
-    "kimi_gate_up": (12288, 2048, 1408, 8, 256),
-    "kimi_down": (12288, 1408, 2048, 8, 256),
-    "qwen3next_gate_up": (10240, 2048, 512, 32, 128),
-    "qwen3next_down": (10240, 512, 2048, 32, 128),
+    "sdar_gate_up": (9216, 2048, 768, 16, 512, 256),
+    "sdar_down": (9216, 768, 2048, 16, 512, 256),
+    "kimi_gate_up": (6912, 2048, 1408, 8, 768, 256),
+    "kimi_down": (6912, 1408, 2048, 8, 768, 256),
+    "qwen3next_gate_up": (5760, 2048, 512, 32, 160, 128),
+    "qwen3next_down": (5760, 512, 2048, 32, 160, 128),
+    "mellum2_gate_up": (18432, 2304, 896, 16, 1024, 256),
+    "mellum2_down": (18432, 896, 2304, 16, 1024, 256),
 }
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("case", GROUPED_CASES)
 def test_grouped_products_are_a_kernel_on_the_chip(one_chip, case, backward):
-    """``ops.grouped_matmul`` at both routed cells' chunks (SDAR: 16,384 sorted
-    rows, 16 held experts of 2,048 x 768 and back; Kimi: 12,288 rows, 8 of
-    2,048 x 1,408 and back; Qwen3-Next: 10,240 rows, 32 of 2,048 x 512 and
-    back, 160-row groups on 128-row tiles) as the v5e compiler takes it: the package's own
+    """``ops.grouped_matmul`` at the routed cells' first chunks, nine eighths of
+    the expected assignments (SDAR: 9,216 sorted rows, 16 held experts of 2,048
+    x 768 and back; Kimi: 6,912 rows, 8 of 2,048 x 1,408 and back; Qwen3-Next:
+    5,760 rows, 32 of 2,048 x 512 and back, 160-row groups on 128-row tiles;
+    Mellum 2: 18,432 rows, 16 of 2,304 x 896 and back) as the v5e compiler takes it: the package's own
     Mosaic kernels, forward and both gradients, by their names; no
     ``ragged-dot``; an expert's whole matrix in a tile fits the fast memory
     the kernels ask for (the compile refuses what does not)."""
     from horovod_tpu.ops.grouped_matmul import grouped_matmul, tiles
 
-    rows, k, n, held, tm = GROUPED_CASES[case]
-    assert tuple(tiles(rows, k, n, held, jnp.bfloat16)) == (tm, k, n)
+    rows, k, n, held, group, tm = GROUPED_CASES[case]
+    assert tuple(tiles(rows, k, n, group, jnp.bfloat16)) == (tm, k, n)
     x = _sds((rows, k), jnp.bfloat16, one_chip)
     w = _sds((held, k, n), jnp.bfloat16, one_chip)
     sizes = _sds((held,), jnp.int32, one_chip)
 
     def fwd(x, w, sizes):
-        return grouped_matmul(x, w, sizes, interpret=False)
+        return grouped_matmul(x, w, sizes, expected=group, interpret=False)
 
     def loss(x, w, sizes):
         return jnp.sum(fwd(x, w, sizes).astype(jnp.float32) ** 2)
@@ -479,31 +483,40 @@ _ROUTED_CASES = {"sdar": (768, 128, 8, 16), "qwen3next": (512, 512, 10, 32)}
 @pytest.fixture(scope="module", params=list(_ROUTED_CASES))
 def routed_layer(request, one_chip):
     """``RoutedExperts`` forward and backward at the SDAR cell's shapes (8,192
-    rows of 2,048, top-8 of 128, 16 held, the default chunk of 16,384) and at
-    Qwen3-Next's (top-10 of 512, 32 held, a chunk of 10,240) as the v5e compiler
-    leaves it, compiled once a shape for the tests below: ``(compiled text,
-    chunk, top_k)``."""
+    rows of 2,048, top-8 of 128, 16 held: 8,192 expected assignments, the
+    default chunks of 9,216 and 2,048) and at Qwen3-Next's (top-10 of 512, 32
+    held: 5,120 expected, chunks of 5,760 and 1,280) as the v5e compiler leaves
+    it, compiled once a shape for the tests below: ``(compiled text, (first
+    chunk, later chunk), top_k)``.  The output's cotangent is an argument, as
+    it is inside a model.  (Until PR 47 the loss was ``sum(y ** 2)``, which keeps
+    the layer's own output for its backward: with chunks of two sizes the
+    compiler's memory-space assignment then repacks a sliced prefetch among the
+    kernels' 64 MiB reservations and dies there, a segmentation fault in
+    ``BestFitRepacker::Finish`` at SDAR's and Kimi's shapes; the cells' whole
+    steps and this program compile: PERF.md section 7.)"""
     from horovod_tpu.parallel.moe import RoutedExperts
 
     ff, experts, top_k, held = _ROUTED_CASES[request.param]
     rows, d = _ROUTED_ROWS, _ROUTED_WIDTH
-    chunk = 2 * rows * top_k * held // experts
+    expected = rows * top_k * held // experts
+    chunks = (9 * expected // 8, expected // 4)
     layer = RoutedExperts(experts, top_k, d, ff, held=(0, held), dtype=jnp.bfloat16)
     x = _sds((1, rows, d), jnp.bfloat16, one_chip)
+    g = _sds((1, rows, d), jnp.float32, one_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, rows, d), jnp.bfloat16)))
     params = jax.tree_util.tree_map(
         lambda s: _sds(s.shape, s.dtype, one_chip), params["params"])
 
-    def loss(p, x):
+    def loss(p, x, g):
         y, stats = layer.apply({"params": p}, x)
-        return jnp.sum(y.astype(jnp.float32) ** 2) + stats["aux_loss"]
+        return jnp.sum(g * y.astype(jnp.float32)) + stats["aux_loss"]
 
     with pytest.MonkeyPatch.context() as patch:
         # the layer asks the backend whether its kernels are interpreted
         patch.setattr(jax, "default_backend", lambda: "tpu")
-        text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
-    return text, chunk, top_k
+        text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params, x, g).as_text()
+    return text, chunks, top_k
 
 
 def _moved_shapes(text, kind):
@@ -523,22 +536,25 @@ def test_routed_layer_holds_no_scatter_on_the_chip(routed_layer):
 
 def test_routed_layer_moves_rows_by_gathers_on_the_chip(routed_layer):
     """The rows move by bf16 gathers and no float32 gather of rows."""
-    text, chunk, top_k = routed_layer
+    text, (first, later), top_k = routed_layer
     rows, d = _ROUTED_ROWS, _ROUTED_WIDTH
     gathers = _moved_shapes(text, "gather")
     assert not [s for s in gathers if s.startswith("f32") and f",{d}]" in s], gathers
     # the first chunk, and the loop over the later ones (recomputed backward)
-    assert gathers.count(f"bf16[{chunk},{d}]") == 2 + 3
+    assert gathers.count(f"bf16[{first},{d}]") == 2
+    assert gathers.count(f"bf16[{later},{d}]") == 3
     assert gathers.count(f"bf16[{rows},{d}]") == 4 * top_k
+    # nothing is as long as the chunk was before PR 47: twice the expected rows
+    assert f"[{8 * later}," not in text
 
 
 def test_routed_layer_sorts_only_what_it_asks_for_on_the_chip(routed_layer):
     """No sort but the four the layer asks for (top-k, the assignments by held
     expert, its inverse, the weights' cotangent back)."""
-    text, chunk, top_k = routed_layer
+    text, chunks, top_k = routed_layer
     rows = _ROUTED_ROWS
     sorts = _moved_shapes(text, "sort")
-    assert not [s for s in sorts if f"[{chunk}]" in s], sorts
+    assert not [s for s in sorts if any(f"[{chunk}]" in s for chunk in chunks)], sorts
     assert len(sorts) == 4 and sum(f"[{rows * top_k}]" in s for s in sorts) == 3
 
 
@@ -851,6 +867,20 @@ def test_two_mixer_step_s_bytes(two_mixer_step):
     # (0.47 GB a linear layer) are kept and nothing of the mixer is made
     # again: 6.545 GB (6.511 with the mixer traced under no checkpoint at all)
     assert _device_bytes(compiled) <= 6_560_000_000
+
+
+def test_two_mixer_step_s_routed_chunks_follow_the_load(two_mixer_step):
+    """PR 47: 5,120 assignments are expected a layer; the first chunk holds
+    5,760 sorted rows and a later one 1,280 where every chunk held 10,240.
+    Nothing under ``experts`` is 10,240 rows long any more, and the step is
+    smaller by more than what the first chunk's backward keeps of the 4,480
+    rows that went (x, gate, up and h in bf16, two routed layers: 64.2 MB):
+    6,544,909,824 B at the parent, 6,465,671,680 now, 79.2 MB less."""
+    compiled = two_mixer_step
+    under = [l for l in compiled.as_text().splitlines() if "/experts/" in l]
+    assert under and not [l for l in under if "[10240," in l]
+    assert [l for l in under if "[5760," in l] and [l for l in under if "[1280," in l]
+    assert _device_bytes(compiled) <= 6_544_909_824 - 2 * 4480 * (2048 + 3 * 512) * 2
 
 
 def test_two_mixer_step_runs_the_rule_s_kernels_once_each_and_makes_nothing_again(

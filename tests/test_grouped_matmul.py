@@ -37,7 +37,7 @@ SIZES = {
 def small_tiles(monkeypatch):
     """Row tiles of 16, k and n in tiles of 128: two k tiles and three column
     tiles at the shapes below."""
-    monkeypatch.setattr(gm, "tiles", lambda rows, k, n, groups, dtype: Tiles(16, 128, 128))
+    monkeypatch.setattr(gm, "tiles", lambda rows, k, n, expected, dtype: Tiles(16, 128, 128))
 
 
 def _plain(x, w, sizes, transpose_w=False):
@@ -215,28 +215,50 @@ def test_the_walk_of_nothing_is_empty():
 
 
 @pytest.mark.parametrize("shape,want", [
-    # the cells' chunks: an expert's matrix whole, row tiles of 256
-    ((12288, 2048, 1408, 8), (256, 2048, 1408)),
-    ((12288, 1408, 2048, 8), (256, 1408, 2048)),
-    ((16384, 2048, 768, 16), (256, 2048, 768)),
-    ((16384, 768, 2048, 16), (256, 768, 2048)),
+    # (rows, k, n, the rows a group is expected to hold).  The cells' first
+    # chunks, nine eighths of the expected assignments (Kimi: 768 rows an expert
+    # of 8, SDAR: 512 of 16, Mellum 2: 1,024 of 16): an expert's matrix whole,
+    # row tiles of 256
+    ((6912, 2048, 1408, 768), (256, 2048, 1408)),
+    ((6912, 1408, 2048, 768), (256, 1408, 2048)),
+    ((9216, 2048, 768, 512), (256, 2048, 768)),
+    ((9216, 768, 2048, 512), (256, 768, 2048)),
+    ((18432, 2304, 896, 1024), (256, 2304, 896)),
+    # a later chunk, a quarter of them: the tiles are the layer's, not the chunk's
+    ((2048, 2048, 768, 512), (256, 2048, 768)),
     # eight times the rows a group: the same tiles, the length of a group is device data
-    ((98304, 2048, 1408, 8), (256, 2048, 1408)),
-    # short groups: row tiles of 128
-    ((4096, 2048, 768, 16), (128, 2048, 768)),
+    ((98304, 2048, 1408, 6144), (256, 2048, 1408)),
+    # short groups: row tiles of 128 (Qwen3-Next: 160 rows an expert, Laguna: 256)
+    ((5760, 2048, 512, 160), (128, 2048, 512)),
+    ((4608, 2048, 512, 256), (128, 2048, 512)),
+    ((4096, 2048, 768, 128), (128, 2048, 768)),
 ])
 def test_tiles_follow_from_the_shapes(shape, want):
     assert tuple(gm.tiles(*shape, jnp.bfloat16)) == want
 
 
+def test_the_expected_group_is_the_rows_over_the_groups_where_none_is_given(monkeypatch):
+    """``grouped_matmul`` without ``expected`` takes the rows over the groups, and
+    with it the caller's: the argument reaches ``tiles`` forward and backward."""
+    seen, tiles = [], gm.tiles
+    monkeypatch.setattr(gm, "tiles", lambda rows, k, n, expected, dtype: (
+        seen.append(expected), tiles(rows, k, n, expected, dtype))[1])
+    x, w = jnp.ones((64, 32), jnp.float32), jnp.ones((4, 32, 24), jnp.float32)
+    sizes = jnp.asarray([16, 16, 16, 16], jnp.int32)
+    for expected, want in ((None, 16), (8, 8)):
+        del seen[:]
+        jax.grad(lambda x: jnp.sum(grouped_matmul(x, w, sizes, expected=expected)))(x)
+        assert len(seen) == 3 and set(seen) == {want}     # forward, dx, dw
+
+
 def test_tiles_of_a_matrix_too_large_for_a_block():
     """A (k, n) too large to hold whole: columns in tiles first, then k in a
     divisor that is a multiple of 128; a k that has none is refused."""
-    tm, tk, tn = gm.tiles(16384, 4096, 14336, 8, jnp.bfloat16)
+    tm, tk, tn = gm.tiles(16384, 4096, 14336, 1024, jnp.bfloat16)
     assert 4096 % tk == 0 and tk % 128 == 0 and tn % 128 == 0
     assert tk * tn * 2 <= gm._W_BLOCK_BYTES
     with pytest.raises(ValueError, match="multiple of 128"):
-        gm.tiles(16384, 100000, 4096, 8, jnp.bfloat16)
+        gm.tiles(16384, 100000, 4096, 1024, jnp.bfloat16)
 
 
 def test_visit_counts_at_the_cells_shapes():

@@ -415,12 +415,17 @@ def test_moe_rows_event_carries_the_scoring():
     from horovod_tpu import trace
 
     x = jnp.ones((1, 16, 32))
-    for layer, scoring in ((_layer(), "sigmoid"),
-                           (RoutedExperts(16, 3, 32, 12, dtype=jnp.float32), "softmax")):
+    # 48 slots.  4 of 16 held: 12 expected, chunks of 16 (nine eighths, in whole
+    # eights) and 8; every expert held: the first chunk is all the slots
+    for layer, scoring, chunks in (
+            (_layer(), "sigmoid", (16, 8)),
+            (RoutedExperts(16, 3, 32, 12, dtype=jnp.float32), "softmax", (48, 16))):
         t0 = trace.now()
         jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x))
         events = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"]
         assert events and all(e["scoring"] == scoring for e in events)
+        assert all((e["chunk"], e["first"], e["later"]) == (chunks[0], *chunks)
+                   for e in events)
 
 
 # -- the model -----------------------------------------------------------------
@@ -516,8 +521,10 @@ _DEFAULTS = dict(kv_lora_rank=None, qk_nope_head_dim=None, qk_rope_head_dim=None
 # 32's parent's; SDAR's f916682c...a577), and PR 43's since the forward and dQ
 # kernels walk a program's query tiles as one with their sums in VMEM scratch,
 # eight tiles an iteration first (before: the LM's 036f9338...f5dc, SDAR's
-# ecd6688a...0e1b)
-_PARENT_TEXT = {"lm": "cc9b24840038ca14b23ce0dd5bed4aed58c54a3e1700c939a71bddbd833b7d0b", "sdar": "8451fea377b69d0437bf23c9f4f9007e6dd1417395eb5753a0ce87653f2a9bc5"}
+# ecd6688a...0e1b).  SDAR's is PR 47's: its routed layer's chunks are nine
+# eighths and a quarter of the expected assignments where one size was twice
+# them, another program by design (before: 8451fea3...9bc5); the LM's stays
+_PARENT_TEXT = {"lm": "cc9b24840038ca14b23ce0dd5bed4aed58c54a3e1700c939a71bddbd833b7d0b", "sdar": "a69f593eee0cfd737aebeb3cfe29ea9bdcd4bdb81370ea52dac0912b7ba26a35"}
 
 
 @pytest.mark.parametrize("name", ["lm", "sdar"])

@@ -328,11 +328,11 @@ def test_many_small_experts_are_dropless_and_tiled_by_their_groups():
     """The cell's routed shape: tiles of 128 rows for groups of 160 (the first
     time on a chip), an expert's whole matrix a tile; the two shapes the
     benchmark had keep theirs."""
-    assert tuple(tiles(10240, 2048, 512, 32, jnp.bfloat16)) == (128, 2048, 512)
-    assert tuple(tiles(10240, 512, 2048, 32, jnp.bfloat16)) == (128, 512, 2048)
-    assert tuple(tiles(16384, 2048, 768, 16, jnp.bfloat16)) == (256, 2048, 768)     # SDAR
-    assert tuple(tiles(12288, 2048, 1408, 8, jnp.bfloat16)) == (256, 2048, 1408)    # Kimi
-    assert visit_counts(10240, 32, 128, 160) == (64, 80)
+    assert tuple(tiles(5760, 2048, 512, 160, jnp.bfloat16)) == (128, 2048, 512)
+    assert tuple(tiles(5760, 512, 2048, 160, jnp.bfloat16)) == (128, 512, 2048)
+    assert tuple(tiles(9216, 2048, 768, 512, jnp.bfloat16)) == (256, 2048, 768)     # SDAR
+    assert tuple(tiles(6912, 2048, 1408, 768, jnp.bfloat16)) == (256, 2048, 1408)   # Kimi
+    assert visit_counts(5760, 32, 128, 160) == (64, 45)
     # top-3 of 8 with every row on one held expert: nothing is dropped
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32)))
     layer = RoutedExperts(8, 3, 32, 12, held=(2, 4), chunk_rows=32, dtype=jnp.float32)
@@ -343,9 +343,10 @@ def test_many_small_experts_are_dropless_and_tiled_by_their_groups():
 
 
 def test_events_carry_the_cell_s_shapes():
-    """``moe.rows`` at 8,192 rows, top-10 of 512, 32 held: 81,920 slots, a chunk
-    of 10,240, 128-row tiles, 64 visits of a balanced step against the chunk's
-    80 row tiles; ``flash.tiles`` at 256-wide heads."""
+    """``moe.rows`` at 8,192 rows, top-10 of 512, 32 held: 81,920 slots, a first
+    chunk of 5,760 (nine eighths of the 5,120 expected) and later ones of 1,280,
+    128-row tiles, 64 visits of a balanced step against the chunk's 45 row
+    tiles; ``flash.tiles`` at 256-wide heads."""
     from horovod_tpu.ops.flash_attention import flash_attention
 
     layer = RoutedExperts(512, 10, 2048, 512, held=(0, 32), dtype=jnp.bfloat16)
@@ -354,8 +355,10 @@ def test_events_carry_the_cell_s_shapes():
                                       jnp.zeros((1, 8192, 2048), jnp.bfloat16)))
     event = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"][-1]
     assert (event["rows"], event["slots"], event["chunk"], event["expected"]) == \
-        (8192, 81920, 10240, 5120.0)
-    assert (event["tiles"], event["visits"], event["chunk_tiles"]) == ([128, 2048, 512], 64, 80)
+        (8192, 81920, 5760, 5120.0)
+    assert (event["first"], event["later"], event["gathered"]) == \
+        (5760, 1280, 2 * 5760 + 2 * 81920)
+    assert (event["tiles"], event["visits"], event["chunk_tiles"]) == ([128, 2048, 512], 64, 45)
     t0 = trace.now()
     q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16)

@@ -4,6 +4,7 @@ float32 reference, at small sizes on the CPU."""
 
 import functools
 import json
+import math
 import os
 import sys
 
@@ -408,10 +409,29 @@ def _choices(rows, top_k, rng, always=()):
                      for _ in range(rows)])
 
 
+def _choices_held(rng, held_a_row, held=(4, 4), top_k=4):
+    """Row ``t`` chooses ``held_a_row[t]`` of the held experts and the rest of
+    its ``top_k`` among the others, in a random order."""
+    inside = list(range(held[0], held[0] + held[1]))
+    outside = [e for e in range(16) if e not in inside]
+    return np.array([rng.permutation(list(rng.permutation(inside)[:n])
+                                     + list(rng.permutation(outside)[:top_k - n]))
+                     for n in held_a_row])
+
+
+def _chunk_rows(slots, n_held, chunk_rows=None, experts=16):
+    """``(first, later)``: the rows of the layer's first chunk and of each
+    later one, the rule written out: ``chunk_rows`` where it is set, else nine
+    eighths and a quarter of the expected assignments, in whole eights."""
+    expected = slots * n_held / experts
+    sizes = (chunk_rows,) * 2 if chunk_rows else (9 * expected / 8, expected / 4)
+    return tuple(min(-(-max(math.ceil(n), 1) // 8) * 8, -(-slots // 8) * 8) for n in sizes)
+
+
 def _routing(case):
     """``(choices (T, 4), held, chunk_rows, chunks in use)`` of a named case."""
     rng = np.random.default_rng(31)
-    if case == "balanced":                      # the default chunk, one in use
+    if case == "balanced":                      # the default chunks, one in use
         return _choices(24, 4, rng), (4, 4), None, 1
     if case == "one_expert_many_chunks":        # every row onto held expert 5
         return _choices(24, 4, rng, always=(5,)), (4, 4), 8, None
@@ -423,11 +443,21 @@ def _routing(case):
         return _choices(24, 4, rng), (8, 2), None, 1
     if case == "chunk_not_a_multiple_of_the_load":
         return _choices(24, 4, rng, always=(6,)), (4, 4), 16, None
+    # the default sizes under load: 96 slots, 24 expected, a first chunk of 32
+    # rows (nine eighths, in whole eights) and later ones of 8
+    if case == "default_just_over_the_first_chunk":     # 36 held: one quarter chunk
+        return _choices_held(rng, [2] * 12 + [1] * 12), (4, 4), None, 2
+    if case == "default_twice_the_expected":            # 48 held: two quarter chunks
+        return _choices_held(rng, [2] * 24), (4, 4), None, 3
+    if case == "default_every_slot_held":               # 96 held: every chunk there is
+        return _choices_held(rng, [4] * 24), (4, 4), None, 9
     raise KeyError(case)
 
 
 _ROUTING_CASES = ["balanced", "one_expert_many_chunks", "all_held_and_none_held",
-                  "held_range_from_8", "chunk_not_a_multiple_of_the_load"]
+                  "held_range_from_8", "chunk_not_a_multiple_of_the_load",
+                  "default_just_over_the_first_chunk", "default_twice_the_expected",
+                  "default_every_slot_held"]
 
 
 def _close(got, want, what):
@@ -455,16 +485,18 @@ def test_rows_moved_by_gathers_give_the_plain_definition(case):
     assert int(stats["dropped"]) == 0
     assert float(stats["load_max_over_mean"]) == float(plain["load_max_over_mean"])
     np.testing.assert_allclose(stats["aux_loss"], plain["aux_loss"], rtol=1e-6)
-    chunk = chunk_rows or 2 * 96 * held[1] // 16
+    first, later = _chunk_rows(96, held[1], chunk_rows)
+    chunks = 1 + -(-max(int(inside.sum()) - first, 0) // later)
+    assert int(stats["chunks"]) == chunks
     if in_use is None:
-        assert inside.sum() > chunk                        # a later chunk runs
+        assert chunks > 1                                  # a later chunk runs
     else:
-        assert -(-int(inside.sum()) // chunk) == in_use
+        assert chunks == in_use
     if case == "all_held_and_none_held":
         assert inside[0].all() and not inside[1].any()
         assert float(jnp.max(jnp.abs(y[0, 1]))) == 0.0
     if case == "chunk_not_a_multiple_of_the_load":
-        assert inside.sum() % chunk
+        assert inside.sum() % first
     _close(y, want, "output")
     assert float(jnp.max(jnp.abs(want))) > 1e-2
 
@@ -550,12 +582,14 @@ def test_moe_rows_event_says_what_a_chunk_moves():
     t0 = trace.now()
     jax.make_jaxpr(lambda p: layer.apply({"params": p}, x)[0])(params)
     events = [r[3] for r in trace.snapshot(t0) if r[0] == "moe.rows"]
-    # the grouped products: one 48-row tile holds the chunk, and each of the
-    # four balanced groups of 6 rows visits it
-    assert events == [{"rows": 24, "slots": 96, "chunk": 48, "expected": 24.0,
-                       "dtype": "bfloat16", "gathered": 2 * 48 + 2 * 96,
-                       "scoring": "softmax", "tiles": [48, 32, 24], "visits": 4,
-                       "chunk_tiles": 1}]
+    # the first chunk: nine eighths of the 24 expected, in whole eights; a later
+    # one a quarter of them.  The grouped products: one 32-row tile holds the
+    # chunk, and each of the four balanced groups of 6 rows visits it
+    assert (32, 8) == _chunk_rows(96, 4)
+    assert events == [{"rows": 24, "slots": 96, "chunk": 32, "first": 32, "later": 8,
+                       "expected": 24.0, "dtype": "bfloat16",
+                       "gathered": 2 * 32 + 2 * 96, "scoring": "softmax",
+                       "tiles": [32, 32, 24], "visits": 4, "chunk_tiles": 1}]
 
 
 # -- the model's keys -----------------------------------------------------------
@@ -626,6 +660,10 @@ def test_block_diffusion_model_trains_through_the_normal_path():
             model.init(jax.random.PRNGKey(2), tokens[:1]), tokens[:1])
         assert logits.shape == (1, 24, 50) and int(aux["dropped_assignments"]) == 0
         assert aux["expert_index"].shape == (2, 48, 2)
+        # 48 rows x 2 slots, 4 of 8 held: 48 expected a layer, chunks of 56 and 16
+        held = np.sum((aux["expert_index"] >= 2) & (aux["expert_index"] < 6), axis=(1, 2))
+        assert int(aux["expert_assignments"]) == held.sum()
+        assert int(aux["expert_chunks"]) == sum(1 + -(-max(int(n) - 56, 0) // 16) for n in held)
         state = training.create_train_state(
             model, optax.adamw(1e-2), jax.random.PRNGKey(2), np.asarray(tokens[:1]))
         state = training.replicate_state(state, hvd.world_mesh())

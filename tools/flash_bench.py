@@ -593,7 +593,8 @@ def grouped_variants(interpret):
     products of one expert matrix (forward, the gradient with respect to the
     rows, and to the matrices; what a program does not use the compiler
     drops) as ``jax.lax.ragged_dot`` and autodiff make them, and as the
-    layer's kernels do at the tiles the shapes give."""
+    layer's kernels do at the tiles the shapes give (``routed_sizes`` holds
+    half the rows: the expected group is half the rows over the groups)."""
     def three(product):
         return {
             "fwd": lambda x, w, dy, sizes: product(x, w, sizes),
@@ -605,7 +606,8 @@ def grouped_variants(interpret):
 
     return {"ragged_dot": three(jax.lax.ragged_dot),
             "kernel": three(lambda x, w, sizes: gm.grouped_matmul(
-                x, w, sizes, interpret=interpret))}
+                x, w, sizes, expected=x.shape[0] // 2 // w.shape[0],
+                interpret=interpret))}
 
 
 def leg_grouped(shapes, iters, warmup, interpret):
@@ -631,7 +633,7 @@ def leg_grouped(shapes, iters, warmup, interpret):
         rec = {"bench": "grouped_matmul", "shape": shape_name, "rows": rows,
                "k": k, "n": n, "groups": groups, "held_rows": held,
                "sizes_min_max": [int(sizes.min()), int(sizes.max())],
-               "tiles": list(gm.tiles(rows, k, n, groups, x.dtype)),
+               "tiles": list(gm.tiles(rows, k, n, rows // 2 // groups, x.dtype)),
                "flops": 2 * held * k * n, "variants": {}}
         expected = {}
         for variant, products in grouped_variants(interpret).items():
