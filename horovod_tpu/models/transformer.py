@@ -122,6 +122,8 @@ class RopeParameters:
 
 
 ATTENTION_KINDS = ("full_attention", "sliding_attention")
+# what a layer of one sublayer may be (``TransformerConfig.sublayers``)
+SUBLAYERS = ("mamba", "attention", "moe", "mlp")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,7 +202,8 @@ class TransformerConfig:
     # RoPE (the Qwen3 family).
     qk_norm: bool = False
     # Routed feed-forward (parallel/moe.py RoutedExperts): a router over
-    # ``num_experts`` SwiGLU experts of width ``moe_intermediate_size``,
+    # ``num_experts`` experts of width ``moe_intermediate_size`` (SwiGLU, or
+    # ``mlp_hidden_act``'s form),
     # ``num_experts_per_tok`` a token, of which this chip holds
     # ``held_experts = (first, count)`` (None: all).  None = the dense
     # MlpBlock.  With it the model returns ``(logits, aux)``: ``aux`` has
@@ -240,8 +243,9 @@ class TransformerConfig:
     intermediate_size: Optional[int] = None
     # With a routed feed-forward: the first ``first_dense_layers`` layers
     # keep the dense MlpBlock; ``num_shared_experts`` > 0 adds, in every
-    # routed layer, one SwiGLU of width num_shared_experts x
-    # moe_intermediate_size beside the routed sum, whole on every chip.
+    # routed layer, one feed-forward (SwiGLU, or ``mlp_hidden_act``'s form) of
+    # width num_shared_experts x moe_intermediate_size beside the routed sum,
+    # whole on every chip.
     first_dense_layers: int = 0
     num_shared_experts: int = 0
     # The router (parallel/moe.py RoutedExperts): ``router_scoring``
@@ -303,6 +307,37 @@ class TransformerConfig:
     num_heads_per_layer: Optional[Any] = None
     rope_parameters: Optional[Any] = None
     attn_head_gate: bool = False
+    # Layers of ONE sublayer (the ``nemotron_h`` configs' keys, each under its
+    # own name).  ``sublayers``: a tuple a layer of ``SUBLAYERS``: ``"mamba"``
+    # (``Mamba2``), ``"attention"`` (``Attention``), ``"moe"`` (the routed
+    # feed-forward) or ``"mlp"`` (the dense one); such a layer is ``x + f(norm(x))``
+    # with that one ``f`` (``hybrid_override_pattern``'s ``M``, ``*``, ``E``, ``-``;
+    # the family maps the letters).  None = every layer a mixer and a
+    # feed-forward, as ``layer_types`` and ``first_dense_layers`` say; the two
+    # forms together are refused.  Mamba-2's sizes: ``mamba_num_heads`` heads of
+    # ``mamba_head_dim``, a state of ``ssm_state_size`` a head, B and C in
+    # ``n_groups`` groups (a divisor of the heads), a causal depthwise
+    # convolution of ``conv_kernel`` taps with a bias, and ``chunk_size`` tokens a
+    # chunk of the scan (``ops/ssd.py``).  A Mamba layer trains on 'dot' and
+    # 'flash' models only: no paged serving (no recurrent-state cache yet), no
+    # bound ``shard_axis``, no ring, no block diffusion, no document ids.
+    # ``mlp_hidden_act``: ``"silu"`` (SwiGLU: gate, up, down) or ``"relu2"``
+    # (``down(relu(up x)^2)``, no gate matrix) for the dense feed-forward, the
+    # shared expert and the routed experts alike.  ``moe_latent_size``: the
+    # routed experts work in a latent that wide (``RoutedExperts.latent``);
+    # ``moe_shared_expert_intermediate_size``: the shared expert's width where it
+    # is no multiple of ``moe_intermediate_size`` (in place of
+    # ``num_shared_experts``).
+    sublayers: Optional[Any] = None
+    mamba_num_heads: Optional[int] = None
+    mamba_head_dim: Optional[int] = None
+    ssm_state_size: Optional[int] = None
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    mlp_hidden_act: str = "silu"
+    moe_latent_size: Optional[int] = None
+    moe_shared_expert_intermediate_size: Optional[int] = None
 
     def __post_init__(self):
         kv = self.num_kv_heads
@@ -369,7 +404,7 @@ class TransformerConfig:
         if self.shared_expert_gate and not self.num_shared_experts:
             raise ValueError("shared_expert_gate needs num_shared_experts")
         if self.partial_rotary_factor != 1.0 and (
-                not 0.0 < self.partial_rotary_factor < 1.0
+                not 0.0 <= self.partial_rotary_factor < 1.0
                 or int(self.head_dim * self.partial_rotary_factor) % 2):
             raise ValueError(
                 f"partial_rotary_factor {self.partial_rotary_factor} of "
@@ -406,6 +441,61 @@ class TransformerConfig:
                     "block_diffusion (a recurrence has no block-diffusion "
                     "mask)")
         self._check_per_layer_attention()
+        self._check_sublayers()
+
+    def _check_sublayers(self):
+        """The fields of layers of one sublayer and of Mamba-2, each refusal
+        with its reason; the tuple stored hashable."""
+        if self.mlp_hidden_act not in ("silu", "relu2"):
+            raise ValueError(
+                f"mlp_hidden_act is 'silu' (SwiGLU) or 'relu2' (squared ReLU, no "
+                f"gate matrix), got {self.mlp_hidden_act!r}")
+        if self.num_experts is None and (
+                self.moe_latent_size or self.moe_shared_expert_intermediate_size):
+            raise ValueError(
+                "moe_latent_size and moe_shared_expert_intermediate_size need "
+                "num_experts")
+        if self.sublayers is None:
+            return
+        object.__setattr__(self, "sublayers", tuple(self.sublayers))
+        if (len(self.sublayers) != self.num_layers
+                or any(s not in SUBLAYERS for s in self.sublayers)):
+            raise ValueError(
+                f"sublayers names one of {SUBLAYERS} for each of the "
+                f"{self.num_layers} layers, got {self.sublayers}")
+        for refused, why in (
+                (self.layer_types is not None,
+                 "layer_types: it names the mixers of layers that have a "
+                 "feed-forward too"),
+                (self.num_heads_per_layer is not None,
+                 "num_heads_per_layer: it counts a mixer a layer"),
+                (bool(self.first_dense_layers),
+                 "first_dense_layers: an 'mlp' layer is the dense feed-forward"),
+                (("moe" in self.sublayers) != (self.num_experts is not None),
+                 "num_experts without a 'moe' layer, and no 'moe' layer without "
+                 f"num_experts (got {self.num_experts})")):
+            if refused:
+                raise ValueError(
+                    f"sublayers (layers of one sublayer) takes no {why}")
+        if not self.has_mamba:
+            return
+        sizes = (self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size,
+                 self.n_groups)
+        if not all(sizes) or sizes[0] % sizes[3] or min(
+                self.conv_kernel, self.chunk_size) < 1:
+            raise ValueError(
+                "sublayers with a 'mamba' layer needs mamba_num_heads, "
+                "mamba_head_dim, ssm_state_size, n_groups (a divisor of the "
+                f"heads) (got {sizes}), conv_kernel >= 1 and chunk_size >= 1")
+        for refused, why in (
+                (self.attention_impl not in ("dot", "flash"),
+                 f"attention_impl {self.attention_impl!r}: the ring shards the "
+                 "sequence and the scan's state is not handed from shard to "
+                 "shard yet"),
+                (self.block_diffusion is not None,
+                 "block_diffusion: a recurrence has no block-diffusion mask")):
+            if refused:
+                raise ValueError(f"a 'mamba' layer (sublayers) takes no {why}")
 
     def _check_per_layer_attention(self):
         """The fields of attention that differs by layer, each refusal with
@@ -442,7 +532,7 @@ class TransformerConfig:
                         "original_max_position_embeddings, beta_fast, "
                         f"beta_slow and attention_factor, all or none, got "
                         f"{p.yarn}")
-                if (not 0.0 < p.partial_rotary_factor <= 1.0
+                if (not 0.0 <= p.partial_rotary_factor <= 1.0
                         or int(self.head_dim * p.partial_rotary_factor) % 2):
                     raise ValueError(
                         f"rope_parameters[{kind!r}]: partial_rotary_factor "
@@ -495,6 +585,10 @@ class TransformerConfig:
                 and "linear_attention" in self.layer_types)
 
     @property
+    def has_mamba(self) -> bool:
+        return self.sublayers is not None and "mamba" in self.sublayers
+
+    @property
     def has_sliding_attention(self) -> bool:
         return (self.layer_types is not None
                 and "sliding_attention" in self.layer_types)
@@ -526,6 +620,12 @@ class TransformerConfig:
                 "rotary_columns": int(self.head_dim * own.partial_rotary_factor),
                 "rope_type": own.rope_type})
         return layers
+
+    @property
+    def shared_expert_hidden(self) -> int:
+        """Width of the shared expert beside a routed sum (0: none)."""
+        return (self.moe_shared_expert_intermediate_size
+                or self.num_shared_experts * (self.moe_intermediate_size or 0))
 
     @property
     def mlp_hidden(self) -> int:
@@ -733,6 +833,8 @@ def _rotary_qk(cfg: TransformerConfig, q, k, positions, own: RopeParameters):
     head-major layout; every other shape and model keeps ``rope``."""
     from ..ops import rope_kernel
 
+    if not own.partial_rotary_factor:
+        return q, k    # no column is rotated: attention without positions
     rot, freqs, scale = _rotary_terms(cfg, own)
     kernel = cfg.attention_impl == "flash" and all(
         rope_kernel.engages(x.shape, rot) for x in (q, k))
@@ -749,12 +851,15 @@ def _rotary_qk(cfg: TransformerConfig, q, k, positions, own: RopeParameters):
     return rope_kernel.rotate(q, c, s, rot), rope_kernel.rotate(k, c, s, rot)
 
 
-def causal_depthwise_conv(u, w):
-    """``silu(sum_j w[j] * u[t - (K - 1) + j])`` a channel, zeros before the
-    sequence: ``u`` (B, T, C), ``w`` (K, C); the sum in float32."""
+def causal_depthwise_conv(u, w, bias=None):
+    """``silu(sum_j w[j] * u[t - (K - 1) + j] [+ bias])`` a channel, zeros before
+    the sequence: ``u`` (B, T, C), ``w`` (K, C), ``bias`` (C,) or None; the sum in
+    float32."""
     taps, t = w.shape[0], u.shape[1]
     padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
     acc = sum(padded[:, j:j + t].astype(jnp.float32) * w[j] for j in range(taps))
+    if bias is not None:
+        acc = acc + bias
     return nn.silu(acc).astype(u.dtype)
 
 
@@ -1145,10 +1250,121 @@ class GatedDeltaNet(nn.Module):
                             name="out_proj")(o.reshape(b, t, value_dim))
 
 
+def _mamba_a_log_init(key, shape, dtype=jnp.float32):
+    """``log(A)``, ``A`` uniform over (1, 16): Mamba-2's published
+    initialisation."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The bias whose softplus is a step log-uniform over (0.001, 0.1), floor
+    1e-4: the published ``time_step_min``, ``time_step_max``,
+    ``time_step_floor``."""
+    low, high = math.log(1e-3), math.log(1e-1)
+    dt = jnp.maximum(
+        jnp.exp(jax.random.uniform(key, shape, dtype) * (high - low) + low), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class _Kernel(nn.Module):
+    """``nn.Dense``'s one parameter without a bias, under the name and with the
+    initialisation it has there, for a product taken of its columns in parts."""
+
+    @nn.compact
+    def __call__(self, shape):
+        return self.param("kernel", nn.initializers.lecun_normal(), shape, jnp.float32)
+
+
+class Mamba2(nn.Module):
+    """The state-space mixer of the ``nemotron_h`` family (Mamba-2, Dao and Gu,
+    arXiv:2405.21060): ``u`` (B, T, d_model) ->
+
+      1. ``in_proj``: ``[z | xBC | dt] = u W_in``, ``z`` and ``x`` of
+         ``mamba_num_heads x mamba_head_dim`` columns, ``B`` and ``C`` of
+         ``n_groups x ssm_state_size`` each, ``dt`` one number a head (summed
+         in float32), no bias;
+      2. ``xBC <- silu(conv(xBC) + conv_bias)``, a causal depthwise convolution
+         of ``conv_kernel`` taps (``conv_kernel`` (taps, channels));
+      3. ``dt <- softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the scan
+         (``ops/ssd.py``): a head's state ``S_t = exp(dt_t A) S_{t-1} + dt_t B_t
+         x_t^T`` (``ssm_state_size x mamba_head_dim``, head ``h`` reading group
+         ``h // (heads / n_groups)``), ``y_t = S_t^T C_t + D x_t``, in chunks
+         of ``chunk_size`` with the carry a Mosaic kernel ('flash' models) or a
+         ``lax.scan`` ('dot' models);
+      4. ``RMSNorm over each group's columns of (y * silu(z))`` with a learned
+         scale a column (``norm``), then ``out_proj``.
+
+    'flash' models run step 2 and step 4's gated norm as one Mosaic kernel pair
+    each (``ops/gdn_kernels.py`` ``conv_bias_silu``, ``gated_group_norm``) on
+    token-major rows; 'dot' models keep ``jnp``.  The whole traces under
+    ``jax.named_scope("mamba")`` with ``in_proj``, ``conv``, ``ssd``,
+    ``gated_norm`` and ``out_proj`` inside.  A share of the heads (a
+    tensor-parallel rank) is this module built at the share's heads and groups:
+    ``out_proj`` then gives the rank's summand.  Training only: no
+    recurrent-state cache, no bound ``shard_axis`` (``TransformerConfig.sublayers``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.gdn_kernels import conv_bias_silu, gated_group_norm
+        from ..ops.ssd import ssd_scan
+        from ..parallel._mesh_utils import axis_size_or_1
+
+        cfg = self.cfg
+        if axis_size_or_1(cfg.shard_axis) > 1:
+            raise ValueError(
+                f"shard_axis {cfg.shard_axis!r} takes no 'mamba' layer "
+                "(sublayers): the sum over head-parallel ranks of a Mamba mixer "
+                "is not written yet")
+        h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
+        g, n = cfg.n_groups, cfg.ssm_state_size
+        inner, mixed = h * p, h * p + 2 * g * n
+        b, t, _ = u.shape
+        f32 = jnp.float32
+        kernels = cfg.attention_impl == "flash"
+        with jax.named_scope("mamba"):
+            with jax.named_scope("in_proj"):
+                w_in = _Kernel(name="in_proj")(
+                    (cfg.d_model, inner + mixed + h)).astype(cfg.dtype)
+                u = u.astype(cfg.dtype)
+                zx = jnp.dot(u, w_in[:, :inner + mixed])
+                dt = jnp.dot(u, w_in[:, inner + mixed:], preferred_element_type=f32)
+            conv_w = self.param("conv_kernel", _conv_init, (cfg.conv_kernel, mixed), f32)
+            conv_b = self.param("conv_bias", nn.initializers.zeros, (mixed,), f32)
+            a_log = self.param("A_log", _mamba_a_log_init, (h,), f32)
+            skip = self.param("D", nn.initializers.ones, (h,), f32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h,), f32)
+            with jax.named_scope("conv"):
+                xbc = zx[..., inner:]
+                xbc = (conv_bias_silu(xbc, conv_w, conv_b) if kernels
+                       else causal_depthwise_conv(xbc, conv_w, conv_b))
+            with jax.named_scope("ssd"):
+                y = ssd_scan(
+                    xbc[..., :inner].reshape(b, t, h, p),
+                    jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log),
+                    xbc[..., inner:inner + g * n].reshape(b, t, g, n),
+                    xbc[..., inner + g * n:].reshape(b, t, g, n), skip,
+                    chunk=cfg.chunk_size, impl="kernel" if kernels else "jnp")
+            with jax.named_scope("gated_norm"):
+                y, z = y.reshape(b, t, inner), zx[..., :inner]
+                scale = _NormScale(name="norm")(inner)
+                if kernels:
+                    y = gated_group_norm(y, z, scale, groups=g, eps=cfg.rms_norm_eps)
+                else:
+                    gated = (y.astype(f32) * nn.silu(z.astype(f32))).reshape(b, t, g, -1)
+                    gated = gated * jax.lax.rsqrt(jnp.mean(
+                        jnp.square(gated), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+                    y = (gated.reshape(b, t, inner) * scale).astype(cfg.dtype)
+            with jax.named_scope("out_proj"):
+                return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                                name="out_proj")(y)
+
+
 class MlpBlock(nn.Module):
     cfg: TransformerConfig
-    # the SwiGLU's width where it is not the config's dense one (the shared
-    # experts beside a routed sum)
+    # the feed-forward's width where it is not the config's dense one (the
+    # shared experts beside a routed sum)
     hidden: Optional[int] = None
 
     @nn.compact
@@ -1162,14 +1378,72 @@ class MlpBlock(nn.Module):
         # unsharded program verbatim.
         tp = _shard_size(cfg)
         hidden = (self.hidden or cfg.mlp_hidden) // tp
-        gate = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="gate")(x)
-        up = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="up")(x)
+        if cfg.mlp_hidden_act == "relu2":
+            # no gate matrix: down(relu(up x)^2), column- and row-split alike
+            up = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="up")(x)
+            inner = jnp.square(nn.relu(up))
+        else:
+            gate = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="gate")(x)
+            up = nn.Dense(hidden, dtype=cfg.dtype, use_bias=False, name="up")(x)
+            inner = nn.silu(gate) * up
         out = nn.Dense(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="down"
-        )(nn.silu(gate) * up)
+        )(inner)
         if tp > 1:
             out = spmd_ops.allreduce(out, op=Sum, axis=cfg.shard_axis)
         return out
+
+
+def _routed_feed_forward(cfg: TransformerConfig, z):
+    """The routed feed-forward of a block's normed rows ``z``: (the routed sum
+    plus the shared expert, the layer's routing statistics).  A plain function
+    inside the block's ``compact`` call, not a method: a module's method would
+    put its own name into every ``op_name`` under it."""
+    from ..parallel.moe import RoutedExperts
+
+    y, stats = RoutedExperts(
+        num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
+        d_model=cfg.d_model, d_ff=cfg.moe_intermediate_size,
+        held=cfg.held_experts, dtype=cfg.dtype,
+        scoring=cfg.router_scoring,
+        scaling_factor=cfg.routed_scaling_factor,
+        selection_bias=cfg.router_selection_bias,
+        seq_aux=cfg.router_seq_aux, latent=cfg.moe_latent_size,
+        gated=cfg.mlp_hidden_act != "relu2", name="moe",
+    )(z)
+    if cfg.shared_expert_hidden:
+        # an ordinary feed-forward beside the routed sum, every chip the
+        # whole of it; a scope of its own, not the routed layer's ``experts``
+        with jax.named_scope("shared_experts"):
+            shared = MlpBlock(
+                cfg, hidden=cfg.shared_expert_hidden,
+                name="shared_experts")(z)
+            if cfg.shared_expert_gate:
+                shared = shared * jax.nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=cfg.dtype,
+                    name="shared_expert_gate")(z))
+            y = y + shared
+    return y, stats
+
+
+def _one_sublayer(cfg: TransformerConfig, sublayer, x, positions, paged, layer, documents):
+    """``x + f(norm(x))`` with the layer's one ``f`` (``cfg.sublayers`` of the
+    layer); a 'moe' layer hands its routing statistics on as a two-sublayer
+    routed layer does."""
+    z = _rms_norm(cfg)(name="norm")(x)
+    if sublayer == "mamba":
+        if paged is not None:
+            raise ValueError(
+                "paged serving takes no 'mamba' layer (sublayers): the "
+                "cache holds keys and values, no recurrent state yet")
+        return x + Mamba2(cfg, name="mixer")(z)
+    if sublayer == "attention":
+        return x + Attention(cfg, name="attn")(
+            z, positions, paged=paged, layer=layer, documents=documents)
+    if sublayer == "mlp":
+        return x + MlpBlock(cfg, name="mlp")(z)
+    y, stats = _routed_feed_forward(cfg, z)
+    return x + y, stats
 
 
 class Block(nn.Module):
@@ -1183,11 +1457,16 @@ class Block(nn.Module):
     # THIS layer's attention where it differs by layer (``Attention``'s)
     sliding: bool = False
     heads: Optional[int] = None
+    # THIS layer's ONE sublayer (``cfg.sublayers`` of the layer); None: a mixer
+    # and a feed-forward, as the fields above say
+    sublayer: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, paged=None, layer: int = 0,
                  documents=None):
         cfg = self.cfg
+        if self.sublayer is not None:
+            return _one_sublayer(cfg, self.sublayer, x, positions, paged, layer, documents)
         norm = _rms_norm(cfg)
         if self.linear:
             if paged is not None:
@@ -1205,31 +1484,7 @@ class Block(nn.Module):
             x = x + MlpBlock(cfg, name="mlp")(norm(name="ln2")(x))
             return x
         # routed feed-forward: (x, the layer's routing statistics)
-        from ..parallel.moe import RoutedExperts
-
-        z = norm(name="ln2")(x)
-        y, stats = RoutedExperts(
-            num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
-            d_model=cfg.d_model, d_ff=cfg.moe_intermediate_size,
-            held=cfg.held_experts, dtype=cfg.dtype,
-            scoring=cfg.router_scoring,
-            scaling_factor=cfg.routed_scaling_factor,
-            selection_bias=cfg.router_selection_bias,
-            seq_aux=cfg.router_seq_aux, name="moe",
-        )(z)
-        if cfg.num_shared_experts:
-            # an ordinary SwiGLU beside the routed sum, every chip the whole
-            # of it; a scope of its own, not the routed layer's ``experts``
-            with jax.named_scope("shared_experts"):
-                shared = MlpBlock(
-                    cfg, hidden=(cfg.num_shared_experts
-                                 * cfg.moe_intermediate_size),
-                    name="shared_experts")(z)
-                if cfg.shared_expert_gate:
-                    shared = shared * jax.nn.sigmoid(nn.Dense(
-                        1, use_bias=False, dtype=cfg.dtype,
-                        name="shared_expert_gate")(z))
-                y = y + shared
+        y, stats = _routed_feed_forward(cfg, norm(name="ln2")(x))
         return x + y, stats
 
 
@@ -1298,7 +1553,10 @@ class Transformer(nn.Module):
                  "clean] rows"),
                 (cfg.has_linear_attention,
                  "'linear_attention' layer: a recurrence's state and its "
-                 "convolution's taps are not reset at a boundary yet")):
+                 "convolution's taps are not reset at a boundary yet"),
+                (cfg.has_mamba,
+                 "'mamba' layer: the scan's state and its convolution's taps "
+                 "are not reset at a boundary yet")):
             if refused:
                 raise ValueError(f"document ids (packed rows) take no {why}")
 
@@ -1362,6 +1620,9 @@ class Transformer(nn.Module):
             # a routed model's leading dense layers keep the dense MlpBlock
             routed_here = routed and i >= cfg.first_dense_layers
             kinds = {}
+            if cfg.sublayers is not None:
+                kinds["sublayer"] = cfg.sublayers[i]
+                routed_here = cfg.sublayers[i] == "moe"
             if cfg.layer_types is not None:
                 kinds["linear"] = cfg.layer_types[i] == "linear_attention"
                 kinds["sliding"] = cfg.layer_types[i] == "sliding_attention"
@@ -1488,6 +1749,10 @@ def modeled_activation_bytes(cfg: TransformerConfig, batch: int,
         ("intermediate_size", cfg.intermediate_size is not None),
         ("a routed feed-forward (num_experts)", cfg.num_experts is not None),
         ("layers of two mixers (layer_types)", cfg.layer_types is not None),
+        ("layers of one sublayer (sublayers: a Mamba-2 mixer, attention, a "
+         "routed or a dense feed-forward alone)", cfg.sublayers is not None),
+        ("a feed-forward without a gate matrix (mlp_hidden_act 'relu2')",
+         cfg.mlp_hidden_act != "silu"),
     ) if is_set]
     if uncounted:
         raise ValueError(

@@ -43,6 +43,15 @@ returns (B, T, H dv) rows for the output projection:
 scale`` accumulated over the tiles in float32; ``n`` made again, cotangents
 float32 throughout), a program a (sequence, tile of tokens) as above.
 
+**Mamba-2's forms of the two passes** (the same tiles, halo, carry and
+accumulators): ``conv_bias_silu(u, conv_kernel, bias)``, the input side with a
+bias a channel and no norm, ``silu(acc + bias)`` over all of ``u``'s columns, a
+lane tile of channels at a time where the delta rule's pass goes a head at a
+time (kernels ``conv_bias_silu_fwd`` / ``_bwd``); and ``gated_group_norm(y, z,
+scale, groups=)``, the output side with the gate BEFORE the norm and the norm
+over a GROUP's columns with a scale a column: ``rmsnorm_group(y * silu(z)) *
+scale`` (``gated_group_norm_fwd`` / ``_bwd``).
+
 A length that is no multiple of the tile is padded with zero rows.  On non-TPU
 backends the kernels run in interpret mode.  Traced into a program each pass
 leaves one event, ``gdn.conv_norm`` or ``gdn.gated_norm`` (``horovod_tpu.trace``).
@@ -126,7 +135,6 @@ def _bwd_kernel(u_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, du_ref, dw_ref, 
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
     top = i == steps - 1
-    rows = u_ref.shape[1]
     douts = (dq_ref, dk_ref, dv_ref)
     for cols, which, out_cols in _slabs(*heads):
         w = w_ref[:, cols]
@@ -140,16 +148,23 @@ def _bwd_kernel(u_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, du_ref, dw_ref, 
             along = jnp.sum(dy * y32, axis=1, keepdims=True)
             dy = (inv * (scale if which == 0 else 1.0)) * (dy - y32 * (inv * inv * along))
         dacc = dy * (sig * (1.0 + acc * (1.0 - sig)))
-        # du[t] = sum_j w[j] dacc[t + K - 1 - j]: the rows past the tile's end
-        # are the first rows of the tile after it, visited just before
-        ext = jnp.concatenate([dacc, carry[:, cols]], axis=0)
-        du = dacc * w[taps - 1:taps]
-        for j in range(taps - 1):
-            du = du + _pltpu.roll(ext, ext.shape[0] - (taps - 1 - j), 0)[:rows] * w[j:j + 1]
-        du_ref[0, :, cols] = du.astype(dtype)
-        carry[:, cols] = dacc[:carry.shape[0]]
-        for j in range(taps):
-            dw_ref[0, j:j + 1, cols] += jnp.sum(dacc * shifted[j], axis=0, keepdims=True)
+        _conv_cotangents(dacc, w, shifted, carry, cols, du_ref, dw_ref, taps)
+
+
+def _conv_cotangents(dacc, w, shifted, carry, cols, du_ref, dw_ref, taps):
+    """From ``acc``'s cotangent of a tile's columns: ``du`` written, the taps'
+    gradient added to, the first rows kept for the tile before.  du[t] = sum_j
+    w[j] dacc[t + K - 1 - j]: the rows past the tile's end are the first rows
+    of the tile after it, visited just before."""
+    rows = dacc.shape[0]
+    ext = jnp.concatenate([dacc, carry[:, cols]], axis=0)
+    du = dacc * w[taps - 1:taps]
+    for j in range(taps - 1):
+        du = du + _pltpu.roll(ext, ext.shape[0] - (taps - 1 - j), 0)[:rows] * w[j:j + 1]
+    du_ref[0, :, cols] = du.astype(du_ref.dtype)
+    carry[:, cols] = dacc[:carry.shape[0]]
+    for j in range(taps):
+        dw_ref[0, j:j + 1, cols] += jnp.sum(dacc * shifted[j], axis=0, keepdims=True)
 
 
 def _params(carried: bool):
@@ -431,3 +446,270 @@ def gdn_gated_norm(o, gate, scale, *, heads: int, eps: float = 1e-6,
             hbm_bytes=b * tiles * 3 * rows * width * jnp.dtype(o.dtype).itemsize)
     return _gated_padded(o, gate, scale.astype(jnp.float32).reshape(1, dv),
                          (heads, float(eps), rows, interpret))
+
+
+# -- Mamba-2's input side: silu(conv(u) + bias), no norm -------------------------
+
+
+def _lane_slabs(width):
+    """The columns a lane tile at a time; all of them where they are not whole
+    tiles (interpret mode's small shapes)."""
+    if width % 128:
+        return [slice(0, width)]
+    return [slice(lo, lo + 128) for lo in range(0, width, 128)]
+
+
+def _plain_fwd_kernel(u_ref, halo_ref, w_ref, bias_ref, out_ref, *, taps):
+    f32 = jnp.float32
+    top = pl.program_id(1) == 0
+    for cols in _lane_slabs(u_ref.shape[2]):
+        shifted = _taps(u_ref[0, :, cols].astype(f32), _halo_rows(halo_ref, cols, top), taps)
+        acc = _conv(shifted, w_ref[:, cols]) + bias_ref[:, cols]
+        out_ref[0, :, cols] = (acc * jax.nn.sigmoid(acc)).astype(out_ref.dtype)
+
+
+def _plain_bwd_kernel(u_ref, halo_ref, w_ref, bias_ref, dout_ref, du_ref, dw_ref,
+                      dbias_ref, carry, *, taps, steps):
+    f32 = jnp.float32
+    i = pl.program_id(1)                     # the tiles last to first
+
+    @pl.when(i == 0)
+    def _():
+        carry[...] = jnp.zeros_like(carry)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    top = i == steps - 1
+    for cols in _lane_slabs(u_ref.shape[2]):
+        w = w_ref[:, cols]
+        shifted = _taps(u_ref[0, :, cols].astype(f32), _halo_rows(halo_ref, cols, top), taps)
+        acc = _conv(shifted, w) + bias_ref[:, cols]
+        sig = jax.nn.sigmoid(acc)
+        dacc = dout_ref[0, :, cols].astype(f32) * (sig * (1.0 + acc * (1.0 - sig)))
+        _conv_cotangents(dacc, w, shifted, carry, cols, du_ref, dw_ref, taps)
+        dbias_ref[0, :, cols] += jnp.sum(dacc, axis=0, keepdims=True)
+
+
+_PLAIN_STATIC = ("rows", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_PLAIN_STATIC)
+def _plain_fwd_call(u, w, bias, rows, interpret):
+    b, t, width = u.shape
+    taps = w.shape[0]
+    return pl.pallas_call(
+        functools.partial(_plain_fwd_kernel, taps=taps),
+        name="conv_bias_silu_fwd",
+        grid=(b, t // rows),
+        in_specs=_in_specs(rows, width, taps, lambda i: i) + [
+            pl.BlockSpec((1, width), lambda b, i: (0, 0))],
+        out_specs=pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
+        compiler_params=_params(carried=False),
+        interpret=interpret,
+    )(u, u, w, bias)
+
+
+@functools.partial(jax.jit, static_argnames=_PLAIN_STATIC)
+def _plain_bwd_call(u, w, bias, dout, rows, interpret):
+    b, t, width = u.shape
+    taps = w.shape[0]
+    steps = t // rows
+    at = lambda i: steps - 1 - i
+    tile = pl.BlockSpec((1, rows, width), lambda b, i: (b, at(i), 0))
+    return pl.pallas_call(
+        functools.partial(_plain_bwd_kernel, taps=taps, steps=steps),
+        name="conv_bias_silu_bwd",
+        grid=(b, steps),
+        in_specs=_in_specs(rows, width, taps, at) + [
+            pl.BlockSpec((1, width), lambda b, i: (0, 0)), tile],
+        out_specs=[tile, pl.BlockSpec((1, taps, width), lambda b, i: (b, 0, 0)),
+                   pl.BlockSpec((1, 1, width), lambda b, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((b, taps, width), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, width), jnp.float32)],
+        scratch_shapes=[_pltpu.VMEM((8, width), jnp.float32)],
+        compiler_params=_params(carried=True),
+        interpret=interpret,
+    )(u, u, w, bias, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _plain(u, w, bias, static):
+    return _plain_fwd_call(u, w, bias, *static)
+
+
+def _plain_fwd(u, w, bias, static):
+    return _plain_fwd_call(u, w, bias, *static), (u, w, bias)
+
+
+def _plain_bwd(static, residuals, dout):
+    du, dw, dbias = _plain_bwd_call(*residuals, dout, *static)
+    return du, jnp.sum(dw, axis=0), jnp.sum(dbias, axis=0)
+
+
+_plain.defvjp(_plain_fwd, _plain_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def _plain_padded(u, w, bias, static):
+    t, rows = u.shape[1], static[0]
+    pad = -t % rows
+    if pad:
+        u = jnp.pad(u, ((0, 0), (0, pad), (0, 0)))
+    return _plain(u, w, bias, static)[:, :t]
+
+
+def conv_bias_silu(u, conv_kernel, bias, *, row_tile: Optional[int] = None,
+                   interpret: Optional[bool] = None):
+    """``silu(sum_j conv_kernel[j] * u[t - (K - 1) + j] + bias)`` a channel, zeros
+    before the sequence, the sum in float32: ``u`` (B, T, C), ``conv_kernel`` (K,
+    C) and ``bias`` (C,) float32 -> (B, T, C) in ``u``'s dtype.  Differentiable
+    in all three (cotangents float32 from the output's to ``du``)."""
+    if (u.ndim != 3 or conv_kernel.ndim != 2 or conv_kernel.shape[1] != u.shape[-1]
+            or bias.shape != u.shape[-1:] or not 1 <= conv_kernel.shape[0] <= 9):
+        raise ValueError(
+            f"conv_bias_silu takes u (B, T, C), conv_kernel (K <= 9, C) and bias (C,), "
+            f"got {u.shape}, {conv_kernel.shape}, {bias.shape}")
+    b, t, width = u.shape
+    rows, interpret = _tile_and_mode(row_tile, interpret, channels=width)
+    if _trace.enabled():
+        tiles = -(-t // rows)
+        _trace.event(
+            "gdn.conv_norm", rows=b * t, channels=width, taps=conv_kernel.shape[0],
+            key_heads=0, value_heads=0, row_tile=rows, programs=b * tiles,
+            hbm_bytes=b * tiles * (2 * rows + _HALO) * width * jnp.dtype(u.dtype).itemsize)
+    f32 = jnp.float32
+    return _plain_padded(u, conv_kernel.astype(f32), bias.astype(f32).reshape(1, width),
+                         (rows, interpret))
+
+
+# -- Mamba-2's output side: rmsnorm over a group of (y * silu(z)) -----------------
+
+
+def _group_fwd_kernel(y_ref, z_ref, scale_ref, out_ref, *, groups, eps):
+    f32 = jnp.float32
+    width = y_ref.shape[2] // groups
+    for j in range(groups):
+        cols = slice(j * width, (j + 1) * width)
+        y, z = y_ref[0, :, cols].astype(f32), z_ref[0, :, cols].astype(f32)
+        u = y * (z * jax.nn.sigmoid(z))
+        inv = jax.lax.rsqrt(jnp.sum(u * u, axis=1, keepdims=True) / width + eps)
+        out_ref[0, :, cols] = (u * (inv * scale_ref[:, cols])).astype(out_ref.dtype)
+
+
+def _group_bwd_kernel(y_ref, z_ref, scale_ref, dout_ref, dy_ref, dz_ref, dscale_ref,
+                      *, groups, eps):
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dscale_ref[...] = jnp.zeros_like(dscale_ref)
+
+    width = y_ref.shape[2] // groups
+    for j in range(groups):
+        cols = slice(j * width, (j + 1) * width)
+        y, z = y_ref[0, :, cols].astype(f32), z_ref[0, :, cols].astype(f32)
+        g, scale = dout_ref[0, :, cols].astype(f32), scale_ref[:, cols]
+        sig = jax.nn.sigmoid(z)
+        gate = z * sig
+        u = y * gate
+        inv = jax.lax.rsqrt(jnp.sum(u * u, axis=1, keepdims=True) / width + eps)
+        dscale_ref[0, :, cols] += jnp.sum(g * u * inv, axis=0, keepdims=True)
+        along = jnp.sum(g * scale * u, axis=1, keepdims=True)
+        du = inv * (g * scale - u * (inv * inv / width * along))
+        dy_ref[0, :, cols] = (du * gate).astype(dy_ref.dtype)
+        dz_ref[0, :, cols] = (du * y * (sig * (1.0 + z * (1.0 - sig)))).astype(dz_ref.dtype)
+
+
+_GROUP_STATIC = ("groups", "eps", "rows", "interpret")
+
+
+def _group_specs(rows, width):
+    tile = pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0))
+    return tile, [tile, tile, pl.BlockSpec((1, width), lambda b, i: (0, 0))]
+
+
+@functools.partial(jax.jit, static_argnames=_GROUP_STATIC)
+def _group_fwd_call(y, z, scale, groups, eps, rows, interpret):
+    b, t, width = y.shape
+    tile, in_specs = _group_specs(rows, width)
+    return pl.pallas_call(
+        functools.partial(_group_fwd_kernel, groups=groups, eps=eps),
+        name="gated_group_norm_fwd",
+        grid=(b, t // rows),
+        in_specs=in_specs,
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        compiler_params=_params(carried=False),
+        interpret=interpret,
+    )(y, z, scale)
+
+
+@functools.partial(jax.jit, static_argnames=_GROUP_STATIC)
+def _group_bwd_call(y, z, scale, dout, groups, eps, rows, interpret):
+    b, t, width = y.shape
+    tile, in_specs = _group_specs(rows, width)
+    return pl.pallas_call(
+        functools.partial(_group_bwd_kernel, groups=groups, eps=eps),
+        name="gated_group_norm_bwd",
+        grid=(b, t // rows),
+        in_specs=in_specs + [tile],
+        out_specs=[tile, tile, pl.BlockSpec((1, 1, width), lambda b, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct((b, 1, width), jnp.float32)],
+        compiler_params=_params(carried=True),
+        interpret=interpret,
+    )(y, z, scale, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _group_norm(y, z, scale, static):
+    return _group_fwd_call(y, z, scale, *static)
+
+
+def _group_norm_fwd(y, z, scale, static):
+    return _group_fwd_call(y, z, scale, *static), (y, z, scale)
+
+
+def _group_norm_bwd(static, residuals, dout):
+    dy, dz, dscale = _group_bwd_call(*residuals, dout, *static)
+    return dy, dz, jnp.sum(dscale, axis=0)
+
+
+_group_norm.defvjp(_group_norm_fwd, _group_norm_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def _group_padded(y, z, scale, static):
+    t, rows = y.shape[1], static[2]
+    pad = -t % rows
+    if pad:
+        y, z = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (y, z))
+    return _group_norm(y, z, scale, static)[:, :t]
+
+
+def gated_group_norm(y, z, scale, *, groups: int, eps: float = 1e-5,
+                     row_tile: Optional[int] = None, interpret: Optional[bool] = None):
+    """``u = y * silu(z)``; ``u * rsqrt(mean over a group's columns of u^2 + eps) *
+    scale``, float32, rounded once: ``y``, ``z`` (B, T, W) in one dtype, ``scale``
+    (W,) float32, ``groups`` a divisor of ``W`` -> (B, T, W) in ``y``'s dtype.
+    Differentiable in all three."""
+    width = y.shape[-1] if y.ndim == 3 else 0
+    if (y.ndim != 3 or z.shape != y.shape or z.dtype != y.dtype or groups < 1
+            or width % groups or scale.shape != (width,)):
+        raise ValueError(
+            f"gated_group_norm takes y, z (B, T, W) in one dtype and scale (W,) with "
+            f"{groups} groups a divisor of W, got {y.shape} {y.dtype}, {z.shape} "
+            f"{z.dtype}, {scale.shape}")
+    rows, interpret = _tile_and_mode(row_tile, interpret, group=width // groups)
+    b, t, _ = y.shape
+    if _trace.enabled():
+        tiles = -(-t // rows)
+        _trace.event(
+            "gdn.gated_norm", rows=b * t, channels=width, value_heads=groups, row_tile=rows,
+            programs=b * tiles,
+            hbm_bytes=b * tiles * 3 * rows * width * jnp.dtype(y.dtype).itemsize)
+    return _group_padded(y, z, scale.astype(jnp.float32).reshape(1, width),
+                         (groups, float(eps), rows, interpret))

@@ -27,7 +27,8 @@ Two layers live here, and they are different mechanisms:
   * :class:`RoutedExperts`: top-k over ALL experts of the model with the
     chosen weights renormalised, SwiGLU experts, of which this chip holds
     a share (a range of expert ids) and computes only that share's part
-    of the sum; DROPLESS (assignments sorted by expert, grouped matrix
+    of the sum (or, ``gated=False``, squared-ReLU experts of two matrices, and
+    ``latent``: in a latent narrower than the stream); DROPLESS (assignments sorted by expert, grouped matrix
     products by the layer's own Mosaic kernels, ``ops/grouped_matmul.py``,
     no capacity), the router in float32.  This is the feed-forward
     ``models/transformer.py`` builds when
@@ -332,6 +333,17 @@ def _expert_chunk(z, w_gate, w_up, w_down, weight, valid, sizes, route, group):
     return _combine(y, weight, route)
 
 
+def _expert_chunk_relu2(z, w_up, w_down, weight, valid, sizes, route, group):
+    """``_expert_chunk`` for experts without a gate matrix: ``down_e(relu(up_e
+    z)^2)``, two grouped products forward."""
+    keep = valid[:, None]
+    x = jnp.where(keep, _dispatch(z, route), 0)
+    up = grouped_matmul(x, w_up, sizes, expected=group)
+    h = jnp.where(keep, jnp.square(nn.relu(up)), 0)
+    y = jnp.where(keep, grouped_matmul(h, w_down, sizes, expected=group), 0)
+    return _combine(y, weight, route)
+
+
 def _chunk_sizes(lo, starts, ends, chunk):
     """How many of the sorted rows ``[lo, lo + chunk)`` each held expert
     owns: its run ``[start, end)`` of the sort, clipped to the chunk."""
@@ -378,7 +390,9 @@ def _chunk_out(lo, z, mats, weight, index, k, chunk, group):
     at = index.rank - lo
     held = (at >= 0) & (at < chunk) & (index.rank < index.assigned)
     route = _Route(sel // k, jnp.where(held, at, 0), held, index.chosen)
-    return _expert_chunk(z, *mats, jax.lax.dynamic_slice_in_dim(weight, lo, chunk),
+    # three matrices an expert (SwiGLU) or two (squared ReLU, no gate)
+    chunk_fn = _expert_chunk if len(mats) == 3 else _expert_chunk_relu2
+    return chunk_fn(z, *mats, jax.lax.dynamic_slice_in_dim(weight, lo, chunk),
                          valid, _chunk_sizes(lo, index.starts, index.ends, chunk),
                          route, group)
 
@@ -432,7 +446,8 @@ _routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
 
 
 class RoutedExperts(nn.Module):
-    """Top-k routed SwiGLU experts of which this chip holds a share.
+    """Top-k routed experts (SwiGLU, or squared ReLU without a gate matrix) of
+    which this chip holds a share.
 
     ``g = softmax(W_r z)`` over all ``num_experts`` in float32; the
     ``top_k`` largest are chosen and their weights renormalised over the
@@ -441,6 +456,15 @@ class RoutedExperts(nn.Module):
     z)``.  ``held = (first, count)`` names the expert ids this chip holds
     (``None``: all of them); the stacked expert matrices have ``count``
     leading entries.  What the absent experts would add is left out.
+
+    ``gated=False``: an expert is ``down_e(relu(up_e z)^2)``, two stacked
+    matrices and no ``w_gate``.  ``latent``: the experts work in a latent
+    narrower than the stream the router reads (LatentMoE): ``l = z W_down``
+    (``latent_down``, ``d_model -> latent``) before the gather, the experts'
+    matrices ``latent x d_ff``, and ``W_up`` (``latent_up``) after the weighted
+    sum, so the gathers, the grouped products and the sums move ``latent``
+    columns, not ``d_model``; the router still reads ``z``.  Each projection
+    traces under a scope of its own name, outside ``experts``.
 
     The router's other forms (DeepSeek-V3's; all inside the ``router``
     scope, everything after the chosen ids and weights is the same code):
@@ -486,7 +510,9 @@ class RoutedExperts(nn.Module):
     assignments balanced routing gives, the row gathers a chunk makes,
     forward and backward, and the grouped products' tile sizes with the
     row-tile visits one product makes at balanced sizes against the row
-    tiles of the whole first chunk.
+    tiles of the whole first chunk; ``width``, the columns of a row the
+    gathers move (``latent`` where the experts have one, else ``d_model``), and
+    ``gated``.
     """
 
     num_experts: int
@@ -500,6 +526,8 @@ class RoutedExperts(nn.Module):
     scaling_factor: float = 1.0
     selection_bias: bool = False
     seq_aux: bool = False
+    latent: Optional[int] = None
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -567,6 +595,8 @@ class RoutedExperts(nn.Module):
             ends = jnp.cumsum(sizes)
             starts = ends - sizes
 
+        # the columns of a row the experts read and write
+        width = d if self.latent is None else self.latent
         expected = slots * n_held / n_exp
         first, later = (self.chunk_rows,) * 2 if self.chunk_rows is not None else (
             math.ceil(9 * expected / 8), math.ceil(expected / 4))
@@ -580,28 +610,40 @@ class RoutedExperts(nn.Module):
             # the backward) and every token's slots twice (y; dx); a grouped
             # product visits the row tiles that balanced groups cover, not
             # the chunk's
-            tile = _tiles(first, d, self.d_ff, chunks.group, self.dtype)
+            tile = _tiles(first, width, self.d_ff, chunks.group, self.dtype)
             visits, chunk_tiles = visit_counts(
                 first, n_held, tile.m, int(min(expected, first)) // n_held)
             _trace.event(
                 "moe.rows", rows=rows, slots=slots, chunk=first, first=first,
                 later=later, expected=expected, dtype=jnp.dtype(self.dtype).name,
                 gathered=2 * first + 2 * slots, scoring=self.scoring,
-                tiles=list(tile), visits=visits, chunk_tiles=chunk_tiles)
+                tiles=list(tile), visits=visits, chunk_tiles=chunk_tiles,
+                width=width, gated=self.gated)
         order = jnp.pad(order, (0, padding))
         weight = jnp.pad(weight, (0, padding))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
-        w_gate = self.param("w_gate", init, (n_held, d, self.d_ff))
-        w_up = self.param("w_up", init, (n_held, d, self.d_ff))
-        w_down = self.param("w_down", init, (n_held, self.d_ff, d))
+        names = ("w_gate",) * self.gated + ("w_up", "w_down")
+        mats = tuple(self.param(
+            name, init, (n_held, self.d_ff, width) if name == "w_down"
+            else (n_held, width, self.d_ff)) for name in names)
+        if self.latent is not None:
+            with jax.named_scope("latent_down"):
+                z = nn.Dense(width, use_bias=False, dtype=self.dtype,
+                             name="latent_down")(z.astype(self.dtype))
 
         with jax.named_scope("experts"):
-            mats = tuple(w.astype(self.dtype) for w in (w_gate, w_up, w_down))
+            mats = tuple(w.astype(self.dtype) for w in mats)
             sort = _Sort(order, rank.reshape(rows, k), jax.lax.stop_gradient(chosen),
                          starts, ends, assigned)
             out = _routed_sum(z.astype(self.dtype), mats, weight, sort, k, chunks)
-            y = out.astype(self.dtype).reshape(x.shape)
+            y = out.astype(self.dtype)
+            if self.latent is None:
+                y = y.reshape(x.shape)
+        if self.latent is not None:
+            with jax.named_scope("latent_up"):
+                y = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                             name="latent_up")(y).reshape(x.shape)
         active, computed = _counted(sort, chunks, n_later)
 
         mean_load = jnp.maximum(assigned, 1) / n_held

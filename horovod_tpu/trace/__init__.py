@@ -119,6 +119,9 @@ SITES = (
                            # norms): rows, channels, taps, heads, row tile, programs, bytes
     "gdn.gated_norm",      # its output pass traced (norm(o) * silu(z)): rows, channels,
                            # value heads, row tile, programs, bytes
+    "ssd.chunks",          # Mamba-2's scan traced: rows, heads, groups, chunk, chunks a
+                           # sequence, head width and state size, the kernel's
+                           # programs, the state bytes a program carries
     "attn.layers",         # a model whose attention differs by layer traced: each
                            # layer's kind, heads, key/value heads, window, rotary
                            # columns and RoPE type, and whether it takes the
@@ -148,7 +151,8 @@ DEVICE_SCOPES = (
 #: ``router_ms`` / ``expert_ffn_ms`` / ``mla_proj_ms`` / ``shared_expert_ms``
 #: / ``gdn_proj_ms`` / ``gated_delta_ms`` / ``window_attention_ms`` /
 #: ``full_attention_ms`` / ``attn_rope_ms`` / ``attn_gate_ms`` /
-#: ``attn_docmask_ms`` read them by ``op_name`` pattern.
+#: ``attn_docmask_ms`` / ``ssd_scan_ms`` / ``mamba_proj_ms`` /
+#: ``latent_proj_ms`` read them by ``op_name`` pattern.
 DEVICE_SUBSCOPES = (
     "router",   # parallel/moe.py RoutedExperts: router product, softmax,
                 # top-k, counts, the sort by held expert and its inverse
@@ -175,6 +179,20 @@ DEVICE_SUBSCOPES = (
                     # key heads), XLA's unit-triangular inverse and the gates'
                     # rows a chunk; a SIBLING of ``gdn``, not nested in it, so
                     # that one pattern reads each
+    # models/transformer.py Mamba2 (a layer whose ``TransformerConfig.sublayers``
+    # entry is 'mamba'): ``mamba`` around the whole mixer, the five inside it
+    "mamba",       # the mixer, in_proj to out_proj
+    "in_proj",     # [z | xBC] in the layer's dtype and dt in float32, two
+                   # products of one kernel's columns
+    "conv",        # the causal depthwise convolution with its bias and SiLU
+                   # (the kernel pair conv_bias_silu_* in a 'flash' model)
+    "ssd",         # the scan (ops/ssd.py): dt's softplus, A, the gates' rows a
+                   # chunk and the kernels ssd_scan_fwd / ssd_scan_bwd
+    "gated_norm",  # rmsnorm over a group of (y * silu(z)) (gated_group_norm_*)
+    "out_proj",    # the mixer's output projection
+    # parallel/moe.py RoutedExperts with ``latent``: outside ``experts``
+    "latent_down",  # the stream down to the latent the experts work in
+    "latent_up",    # the weighted sum back up to the stream
     # models/transformer.py Attention, only in a model with a
     # 'sliding_attention' layer (``TransformerConfig.layer_types``): other
     # models' ``op_name``s are what they were
@@ -200,7 +218,7 @@ DEVICE_SUBSCOPES = (
 
 #: Pallas kernel names — every ``pl.pallas_call(..., name="...")`` of
 #: ops/flash_attention.py, ops/grouped_matmul.py, ops/gated_delta.py,
-#: ops/gdn_kernels.py and ops/rope_kernel.py, one name a kernel; the
+#: ops/gdn_kernels.py, ops/ssd.py and ops/rope_kernel.py, one name a kernel; the
 #: HLO instruction (and the profiler's event) is ``%<name>.<n>``.  The
 #: attention kernels all start with ``flash_attention`` so one pattern still
 #: reads them together; the routed experts' grouped products do not, and are
@@ -237,6 +255,17 @@ DEVICE_KERNELS = (
     "gdn_gated_norm_fwd",  # norm(o) * silu(z) on the rule's rows, z read out of
                            # the projection's rows in place
     "gdn_gated_norm_bwd",  # do, dz and the norm's scale's gradient
+    "conv_bias_silu_fwd",    # ops/gdn_kernels.py, Mamba-2's input pass: silu(conv +
+                             # bias) over all the channels, a lane tile at a time
+    "conv_bias_silu_bwd",    # du, the taps' and the bias's gradient
+    "gated_group_norm_fwd",  # rmsnorm over a group's columns of (y * silu(z)),
+                             # a scale a column
+    "gated_group_norm_bwd",  # dy, dz and the scale's gradient
+    "ssd_scan_fwd",  # ops/ssd.py: Mamba-2's scan in chunks, a program a (sequence,
+                     # group): C B^T once a group, the state of the group's
+                     # heads in VMEM from chunk to chunk; y and a state a chunk
+    "ssd_scan_bwd",  # the same walk last to first, the state's cotangent in
+                     # VMEM: dx, dB, dC (summed over the group's heads), dg, ddt, dD
     "rope_fwd",  # ops/rope_kernel.py: q or k rotated on whole heads, head-major in
                  # and out, one pass (models whose heads are 128 lanes wide)
     "rope_bwd",  # the cotangent's pass: the same rotation with sin negated

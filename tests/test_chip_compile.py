@@ -1148,6 +1148,77 @@ def test_rotary_step_under_q_k_norms_and_block_diffusion_is_one_pass_a_direction
 _LATENT_STEP_AT_THE_PARENT = "cdba4e1ac5008fa1407a4168b87d537b98e69cbfda892c9ae40796bea49f3c30"
 
 
+# -- layers of one sublayer: the Nemotron 3 Super cell's own step (PR 48) ------
+
+
+@pytest.fixture(scope="module")
+def nemotronh_cell_step(topo):
+    """The train step of nemotron3-super-120b-a12b-s8192-1chip AS THE BENCHMARK
+    BUILDS IT (its configuration file through its family: eleven layers
+    ``MEMEMEMEM*E`` at the published widths, one of eight head-parallel ranks, 8
+    of 512 experts, 8,192 tokens), compiled once for the described v5e."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import families, harness
+
+    cell = harness.load_cell("nemotron3-super-120b-a12b-s8192-1chip")
+    config, traffic = cell.config, cell.traffic
+    mesh = Mesh(np.array(topo.devices[:1]), (WORLD_AXIS,))
+    tokens = ((1, traffic["seq_len"]), jnp.int32)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = _step_compiled(
+            families.family(config).model(config), families.optimizer(config["optimizer"]),
+            mesh, jnp.zeros((1, traffic["seq_len"]), jnp.int32), tokens, tokens,
+            **families.step_options(config, traffic))
+    return config, compiled
+
+
+def test_nemotronh_cell_step_fits_a_chip_at_the_bytes_its_file_states(nemotronh_cell_step):
+    """700,862,960 parameters are 11.21 GB at 16 B; the step with 8,192 tokens'
+    activations is what the configuration's file states, under the chip's 16 GB
+    and over a quarter of it."""
+    config, compiled = nemotronh_cell_step
+    assert 0.25 * HBM_BYTES < _device_bytes(compiled) < HBM_BYTES
+    assert abs(_device_bytes(compiled) - config["compiled_step_bytes"]) < 0.01 * HBM_BYTES
+
+
+def test_nemotronh_cell_step_runs_each_mamba_kernel_once_a_layer_under_its_scope(
+        nemotronh_cell_step):
+    """Five Mamba-2 layers: the scan, the convolution and the gated norm are
+    Mosaic calls by their names, forward and backward once a layer each,
+    under the scopes their metrics read; the flash kernels once (one ``*``
+    layer); no block is made again."""
+    text = nemotronh_cell_step[1].as_text()
+    calls = _kernel_calls(text)
+    for kernel, scope in (("ssd_scan", "ssd"), ("conv_bias_silu", "conv"),
+                          ("gated_group_norm", "gated_norm")):
+        for name in (kernel + "_fwd", kernel + "_bwd"):
+            assert len(calls[name]) == 5, (name, len(calls.get(name, ())))
+            assert all(f"/mixer/mamba/{scope}/" in o for o in calls[name]), calls[name]
+    assert [len(calls[k]) for k in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                                    "flash_attention_bwd_dkv")] == [1, 1, 1]
+    assert "rematted_computation" not in text
+
+
+def test_nemotronh_cell_step_s_experts_move_the_latent_s_columns(nemotronh_cell_step):
+    """The gathers, the grouped products and the sums under ``experts`` move
+    1,024 columns (the latent), never the stream's 4,096; the two latent
+    projections lie under scopes of their own outside ``experts``; the first
+    chunk holds nine eighths of the 2,816 expected assignments."""
+    text = nemotronh_cell_step[1].as_text()
+    under = [l for l in text.splitlines() if "/experts/" in l and " = " in l]
+    assert under and not [l for l in under if re.search(r"\[\d+,4096\]", l.split(" = ")[1].split("(")[0])]
+    assert [l for l in under if "[3168,1024]" in l] and [l for l in under if "[3168,2688]" in l]
+    for scope in ("latent_down", "latent_up"):
+        lines = [l for l in text.splitlines() if f"/moe/{scope}/" in l]
+        assert lines and not [l for l in lines if "/experts/" in l], scope
+    assert len(_kernel_calls(text)["grouped_matmul"]) >= 5 * 2
+
+
 def test_latent_attention_s_step_is_the_parent_s_to_the_byte():
     """Kimi's kind of layer at its head widths (128 + 64 rotary columns a query
     head, ONE 64-wide rotary key for all heads, 'flash'): a 64-wide rotary slice

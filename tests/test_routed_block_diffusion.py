@@ -589,7 +589,8 @@ def test_moe_rows_event_says_what_a_chunk_moves():
     assert events == [{"rows": 24, "slots": 96, "chunk": 32, "first": 32, "later": 8,
                        "expected": 24.0, "dtype": "bfloat16",
                        "gathered": 2 * 32 + 2 * 96, "scoring": "softmax",
-                       "tiles": [32, 32, 24], "visits": 4, "chunk_tiles": 1}]
+                       "tiles": [32, 32, 24], "visits": 4, "chunk_tiles": 1,
+                       "width": 32, "gated": True}]
 
 
 # -- the model's keys -----------------------------------------------------------

@@ -1299,6 +1299,8 @@ def test_device_names_catalogue_matches_the_code():
         == ["grouped_matmul", "grouped_matmul_t", "gated_delta_kkt",
             "gated_delta_fwd", "gated_delta_bwd", "gdn_conv_norm_fwd",
             "gdn_conv_norm_bwd", "gdn_gated_norm_fwd", "gdn_gated_norm_bwd",
+            "conv_bias_silu_fwd", "conv_bias_silu_bwd", "gated_group_norm_fwd",
+            "gated_group_norm_bwd", "ssd_scan_fwd", "ssd_scan_bwd",
             "rope_fwd", "rope_bwd"]
     assert not os.path.exists(
         os.path.join(repo, "horovod_tpu", "utils", "profiler.py"))
@@ -1313,7 +1315,9 @@ def test_subscope_catalogue_matches_the_code_and_names_no_phase():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert tuple(trace_sites.catalogue(repo, "DEVICE_SUBSCOPES")) == \
         trace.DEVICE_SUBSCOPES == ("router", "experts", "mla", "shared_experts",
-                                   "gdn", "gated_delta", "attn_rope", "attn_window",
+                                   "gdn", "gated_delta", "mamba", "in_proj", "conv",
+                                   "ssd", "gated_norm", "out_proj", "latent_down",
+                                   "latent_up", "attn_rope", "attn_window",
                                    "attn_full", "attn_gate", "attn_docmask")
     assert "flash_attention_bwd_dkv_bd" in trace.DEVICE_KERNELS
     assert not set(trace.DEVICE_SUBSCOPES) & set(trace.DEVICE_SCOPES)
